@@ -1,4 +1,4 @@
-# libsplinter-tpu — top-level bootstrap (VERDICT r3 #8).
+# libsplinter-tpu — top-level bootstrap.
 #
 # One command from a clean checkout to a green suite:
 #

@@ -279,7 +279,7 @@ class Embedder:
         # rows believed to need embedding: fed by the dirty mask (hot
         # path) and by label sweeps (cold start + periodic reconcile).
         # Raced/torn rows stay here and retry next drain — so the hot
-        # path never needs the O(nslots) label scan (VERDICT r1 item 6).
+        # path never needs the O(nslots) label scan.
         self._pending: set[int] = set()
         # failure-domain state: a failed encode/commit batch halves
         # the effective batch cap (the bucket) for subsequent drains —

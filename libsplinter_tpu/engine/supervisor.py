@@ -989,8 +989,9 @@ def main(argv: list[str] | None = None) -> int:
                     help="per-lane device pin, e.g. "
                          "'prefill=0,decode=1' lands the two "
                          "disaggregated lanes on disjoint chips "
-                         "(children see SPTPU_CHIP_PIN; off-range "
-                         "pins degrade to a warning on small hosts)")
+                         "(children see SPTPU_CHIP_PIN; an ordinal "
+                         "the child does not have is an error "
+                         "there)")
     for lane in LANES:
         ap.add_argument(f"--{lane}-args", default="",
                         help=f"extra argv for the {lane} child "

@@ -7,7 +7,7 @@ tokenizer) as a self-describing GGUF that the framework's own loader
 (`gguf.load_encoder_params` / `gguf.load_tokenizer` /
 `gguf.encoder_config_from_gguf`) — or llama.cpp-lineage tooling — can
 open cold.  Used by the pinned end-to-end golden fixture
-(tests/fixtures/, VERDICT r2 #5) and by `scripts/make_golden_fixture.py`.
+(tests/fixtures/) and by `scripts/make_golden_fixture.py`.
 
 Layout notes (GGUF v3, little-endian):
   header | metadata kv* | tensor infos | pad to `align` | tensor data

@@ -315,7 +315,7 @@ def causal_flash_attention(q, kk, vv, pos, start=None, *,
     if mesh is not None and mesh.shape.get("tp", 1) > 1:
         from jax.sharding import PartitionSpec as SP
 
-        from ..parallel.mesh import shard_map
+        from jax import shard_map
 
         body = functools.partial(_causal_flash_host, block_q=block_q,
                                  interpret=interpret)

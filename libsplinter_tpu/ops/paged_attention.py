@@ -36,13 +36,15 @@ on the already-f32 MXU output, and V dequantizes on its VMEM block
 before the probability matmul.  HBM traffic per page drops to 1/2 of
 bf16 (1/4 of f32) + a scalar, which is the whole point: decode is
 memory-bound, so cache bytes ARE tokens/sec (ROADMAP item 4;
-PowerInfer arxiv 2312.12456, CPU-inference arxiv 2406.07553).  Note
-the scale tables live in SMEM for the whole dispatch — at f32 per
-(block, kv head) that is n_blocks*KH*4 bytes per side, fine for
-serving-sized pools (a 4096-page pool with 8 kv heads is 128 KiB),
-but a pathological million-page pool would need a VMEM spill; the
-layout (separate scale arrays, int8 values) deliberately leaves room
-for an int4-packed value pool later without touching the scales.
+PowerInfer arxiv 2312.12456, CPU-inference arxiv 2406.07553).  SMEM
+is 1 MiB on a v5e and pads the minor axis of a 2-D operand to 128
+lanes, so the whole (n_blocks, KH) table there would cost
+n_blocks*512 B per side whatever KH is — the v5e compiler refuses it
+from 1,024 pages.  What the dispatch prefetches instead is the scale
+of each page its block tables name, gathered by XLA before the call
+and flattened to 1-D: B*P*KH*4 bytes per side (batch 64 x 16 pages x
+12 kv heads = 48 KiB), independent of the pool's size and bounded
+like the block tables themselves.
 
 MULTI-QUERY verify (q_tokens > 1): the speculative-decode verifier
 scores gamma+1 draft positions in ONE forward.  The kernel already
@@ -54,8 +56,12 @@ the S tokens' K/V before the call (models/decoder.CausalAttention)
 makes this exactly a batched draft verification through the paged
 pool — no serial fallback, no dense window.
 
-Page size must be a multiple of the 128-lane tile on real TPU
-hardware; interpret mode (CPU parity tests) accepts any page size.
+Page size: the page is a WHOLE dimension of the kv block, so any size
+works on the chip — `page % 128` was never a rule.  The v5e compiler
+takes 1..256 (tests/test_chip_compile.py holds 16, 64 and 256) and
+pages of 16 and 64 matched the jnp reference on a v5e for bf16, int8
+and int4 pools (PR 21).  128 is the serving default and the size
+chip_smoke.py runs.
 Block 0 of the pool is reserved by convention as the TRASH block
 (models/decoder.PagedKVCache): unallocated table entries point at it,
 so gathers of unused pages read garbage that the length mask excludes
@@ -81,7 +87,7 @@ Tensor-parallel serving (parallel/serve.py) passes mesh= and the whole
 dispatch runs under shard_map: pools sharded on the kv-head axis over
 `tp`, each device executing the same program over its KH/tp local
 heads — the scales shard WITH their kv heads (axis 1 of (n_blocks,
-KH)), so the per-device SMEM tables shrink by tp too.
+KH)), so the per-device SMEM scales shrink by tp too.
 """
 from __future__ import annotations
 
@@ -139,8 +145,9 @@ def _paged_kernel(*refs, page: int, scale: float, rep: int,
       k_ref/v_ref: (1, 1, page, D) the page the table routed here
       out_ref: (1, 1, R, D)
       m_s/l_s: (R, 1) f32 running max / sum;  acc_s: (R, D) f32
-    refs (quantized=True) insert ksc_ref/vsc_ref — (n_blocks, KH) f32
-    per-page per-kv-head scales in SMEM — after len_ref.
+    refs (quantized=True) insert ksc_ref/vsc_ref — (B*P*KH,) f32 in
+    SMEM, the scale of row b's page p for kv head h at
+    (b*P + p)*KH + h — after len_ref.
 
     The page axis is innermost, so the scratch carries the online
     softmax across a row's pages and the output block (revisited per
@@ -173,14 +180,18 @@ def _paged_kernel(*refs, page: int, scale: float, rep: int,
         q = q_ref[0, 0]                                 # (R, D)
         R = q.shape[0]
         if quantized:
-            bid = tab_ref[b, p]
-            ks = ksc_ref[bid, h]
-            vs = vsc_ref[bid, h]
+            n_kh = pl.num_programs(1)
+            si = (b * n_pages + p) * n_kh + h
+            ks = ksc_ref[si]
+            vs = vsc_ref[si]
             if packed:
                 # int4: nibble-unpack the (page, D//2) uint8 block in
-                # register — two unsigned ops + a lane concatenate —
-                # then the int8 path's scale folding applies unchanged
-                ku, vu = k_ref[0, 0], v_ref[0, 0]
+                # register — two mask/shift ops + a lane concatenate —
+                # then the int8 path's scale folding applies unchanged.
+                # The bytes widen to int32 first: Mosaic lowers no
+                # uint8 -> f32 cast.
+                ku = k_ref[0, 0].astype(jnp.int32)
+                vu = v_ref[0, 0].astype(jnp.int32)
                 k = jnp.concatenate(
                     [(ku & 0xF).astype(jnp.float32),
                      (ku >> 4).astype(jnp.float32)], axis=-1) - 8.0
@@ -293,9 +304,13 @@ def _paged_pallas_quant(q4, k_pool, v_pool, k_scales, v_scales,
                         tables, lengths, *, interpret: bool,
                         q_tokens: int):
     """Quantized variant: int8 pools + (n_blocks, KH) f32 per-page
-    per-kv-head scales riding the scalar prefetch with the tables."""
+    per-kv-head scales.  Only the scales of the pages the tables name
+    ride the scalar prefetch, gathered here and flattened to
+    (B*P*KH,) — see the module docstring for the SMEM arithmetic."""
     return _pallas_call(q4, k_pool, v_pool,
-                        (tables, lengths, k_scales, v_scales),
+                        (tables, lengths,
+                         k_scales[tables].reshape(-1),
+                         v_scales[tables].reshape(-1)),
                         interpret=interpret, q_tokens=q_tokens,
                         quantized=True)
 
@@ -413,8 +428,8 @@ def paged_attention(q, k_pool, v_pool, tables, lengths, *,
     causal_flash_attention);
     k_scales/v_scales: None for float pools, or (n_blocks, KH) f32
     per-page per-kv-head scales for quantized pools — the kernel
-    dequantizes in register inside the page loop (the scales ride
-    scalar prefetch with the tables).  Quantized pools are int8, or
+    dequantizes in register inside the page loop (the scales of the
+    tables' pages ride scalar prefetch with the tables).  Quantized pools are int8, or
     int4-PACKED when the pool dtype is uint8: (n_blocks, KH, page,
     D//2) bytes holding two offset-8 nibbles each (split-half layout,
     pack_int4/unpack_int4), nibble-unpacked in register;
@@ -438,7 +453,7 @@ def paged_attention(q, k_pool, v_pool, tables, lengths, *,
     if mesh is not None and mesh.shape.get("tp", 1) > 1:
         from jax.sharding import PartitionSpec as SP
 
-        from ..parallel.mesh import shard_map
+        from jax import shard_map
 
         q_spec = SP(None, None, "tp", None) if q.ndim == 4 \
             else SP(None, "tp", None)

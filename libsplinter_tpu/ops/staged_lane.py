@@ -45,7 +45,7 @@ _UPDATE_BUCKETS = (64, 512, 4096, 32768)
 # upload streams the lane chunk-by-chunk instead of materialising a
 # host copy of the whole (nslots, dim) matrix: at the 1M x 768 target
 # the old full-copy path peaked at ~4x the 6.4 GB lane in host RSS
-# (VERDICT r4 #10); streaming peaks at ~1x (the device copy) plus one
+#; streaming peaks at ~1x (the device copy) plus one
 # chunk.
 _CHUNK_BYTES = 128 << 20
 
@@ -172,7 +172,7 @@ class StagedLane:
         bytes (upcast to f32 on-device; ~1e-3 component quantization,
         ranking-equivalent for cosine top-k).  f16 pays a host-side
         astype per chunk, so it wins when link bandwidth is the
-        bottleneck (tunneled/remote runtimes, DCN-attached hosts) and
+        bottleneck (remote runtimes, DCN-attached hosts) and
         loses nothing but exactness on fast PCIe — hence opt-in.
         Resolved from SPTPU_LANE_WIRE when not passed."""
         if store.vec_dim == 0:
@@ -213,7 +213,10 @@ class StagedLane:
         st = self._st
         view = st.vectors
         n, d = view.shape
-        dev = self._device or jax.devices()[0]
+        # the process's default device (a chip pin sets it), not
+        # unconditionally device 0
+        dev = (self._device or jax.config.jax_default_device
+               or jax.devices()[0])
         # the populate pass (or previous reads) may have the whole lane
         # resident; detach it up front so peak RSS during the upload is
         # one device copy + one chunk, not lane + device copy

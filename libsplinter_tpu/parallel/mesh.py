@@ -19,33 +19,6 @@ import numpy as np
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:
-    from jax import shard_map as _shard_map
-except ImportError:                           # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-import inspect
-
-_HAS_VMA = "check_vma" in inspect.signature(_shard_map).parameters
-
-
-def shard_map(f, *args, **kw):
-    """Version-portable shard_map: newer jax renamed check_rep to
-    check_vma.  Call sites in this tree use the NEW name; this shim
-    translates for older jax so the parallel tier runs on both."""
-    if "check_vma" in kw and not _HAS_VMA:
-        kw["check_rep"] = kw.pop("check_vma")
-    return _shard_map(f, *args, **kw)
-
-
-def axis_size(name: str) -> int:
-    """Static size of a bound mesh axis, portable across jax versions:
-    jax.lax.axis_size is newer; on older jax, psum of the literal 1
-    constant-folds to the axis size as a Python int."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    return jax.lax.psum(1, name)
-
 
 def make_mesh(dp: int | None = None, tp: int = 1, sp: int = 1,
               ep: int = 1, pp: int = 1, devices=None) -> Mesh:

@@ -31,10 +31,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from flax import linen as nn
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models.encoder import EncoderConfig, EncoderLayer, pool_normalize
-from .mesh import shard_map
 
 
 def stack_layer_params(params, cfg: EncoderConfig):

@@ -77,8 +77,7 @@ def ring_attention(q, k, v, kv_mask, *, axis_name: str,
     if axis_size is not None:
         n = axis_size
     else:
-        from .mesh import axis_size as _axis_size
-        n = _axis_size(axis_name)
+        n = jax.lax.axis_size(axis_name)
     B, S, H, D = q.shape
     if scale is None:
         scale = 1.0 / (D ** 0.5)
@@ -136,7 +135,7 @@ def ring_attention_sharded(mesh, q, k, v, kv_mask, *, axis: str = "sp",
     mesh has one."""
     from jax.sharding import PartitionSpec as P
 
-    from .mesh import shard_map
+    from jax import shard_map
 
     batch_ax = "dp" if "dp" in mesh.axis_names else None
     qkv_spec = P(batch_ax, axis)

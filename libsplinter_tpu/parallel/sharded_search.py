@@ -11,10 +11,10 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.similarity import FUSED_K_MAX, _fused_topk_fn, cosine_scores
-from .mesh import shard_map
 
 
 @functools.lru_cache(maxsize=64)
@@ -90,7 +90,12 @@ def sharded_topk(mesh: Mesh, vectors, query, k: int, mask=None,
     fn = _topk_program(mesh, axis, local_n, d, query.shape[0],
                        k_local, k_final, bool(use_pallas),
                        bool(mxu_bf16), bool(interpret))
-    s, i = fn(jnp.asarray(vectors, jnp.float32), query,
+    if not isinstance(vectors, jax.Array):
+        # a host matrix goes shard by shard to its devices; through
+        # jnp.asarray the whole lane would land on device 0 first
+        vectors = shard_vectors(
+            mesh, np.asarray(vectors, np.float32), axis)
+    s, i = fn(vectors.astype(jnp.float32), query,
               jnp.asarray(mask, jnp.float32))
     return np.asarray(s), np.asarray(i)
 
@@ -119,7 +124,7 @@ class PodSearch:
     scalar DCN allgather and touches no device data; updates scatter
     only the changed rows into the donated device matrix (same economy
     as ops.StagedLane).  The multi-process path is collectively
-    incremental (VERDICT r2 #2): hosts allgather their dirty COUNTS,
+    incremental: hosts allgather their dirty COUNTS,
     agree on a shared padded bucket, and every host runs ONE scatter
     program carrying its own changed rows (out-of-bounds sentinel rows
     from less-dirty hosts are dropped by the scatter) — O(max dirty)

@@ -15,12 +15,12 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 import optax
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..models import Encoder, EncoderConfig
 from .mesh import (batch_sharding, param_shardings, replicated,
-                   shard_map, shard_params)
+                   shard_params)
 
 
 class TrainState(NamedTuple):

@@ -265,11 +265,14 @@ class Store:
         n = self.nslots
         buf = C.create_string_buffer(n * N.KEY_MAX)
         count = _ck(self._lib.spt_list(self._h, buf, n))
-        out = []
-        for i in range(count):
-            raw = buf.raw[i * N.KEY_MAX:(i + 1) * N.KEY_MAX]
-            out.append(raw.split(b"\0", 1)[0].decode(errors="replace"))
-        return out
+        # ONE copy out of the ctypes buffer: every `.raw` access
+        # copies all n * KEY_MAX bytes, which made this walk quadratic
+        # (minutes at 262,144 slots — the search daemon's first
+        # heartbeat sits behind it, sweep_results)
+        raw = buf.raw
+        km = N.KEY_MAX
+        return [raw[i * km:(i + 1) * km].split(b"\0", 1)[0]
+                .decode(errors="replace") for i in range(count)]
 
     def __contains__(self, key: str) -> bool:
         return self._lib.spt_find_index(self._h, key.encode()) >= 0

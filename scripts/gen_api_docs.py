@@ -1,5 +1,5 @@
 """Generate docs/api/ — the per-function API reference for the sptpu.h
-C ABI (VERDICT r4 #9; reference ships ~60 per-function pages,
+C ABI (reference ships ~60 per-function pages,
 /root/reference/docs/api/index.md).
 
 The header's comments ARE the documentation source; this script turns

@@ -11,7 +11,7 @@ Produces (committed under tests/fixtures/):
 The e2e test (tests/test_golden_e2e.py) opens the .gguf with NO
 side-channel configuration — config, tokenizer, and weights all come
 from the file — and must reproduce both ids and vectors exactly
-(VERDICT r2 #5; reference analog: executing a published checkpoint,
+(reference analog: executing a published checkpoint,
 splinference.cpp:423-447).
 
 Determinism: the HF `tokenizers` WordPiece trainer is NOT run-to-run

@@ -1,5 +1,5 @@
-"""docs/api/ is generated from sptpu.h (scripts/gen_api_docs.py,
-VERDICT r4 #9) — these tests keep it complete and in sync."""
+"""docs/api/ is generated from sptpu.h (scripts/gen_api_docs.py) —
+these tests keep it complete and in sync."""
 from __future__ import annotations
 
 import os

@@ -1,4 +1,4 @@
-"""Real-export parity pack (VERDICT r4 #6).
+"""Real-export parity pack.
 
 The model path had only ever loaded GGUF files produced by this repo's
 own writer — a mirrored misunderstanding of the format or of llama.cpp's

@@ -1,4 +1,4 @@
-"""Pinned end-to-end checkpoint golden (VERDICT r2 #5).
+"""Pinned end-to-end checkpoint golden.
 
 tests/fixtures/golden_encoder.gguf is a committed checkpoint: tiny
 nomic-geometry encoder weights + a REAL trained HF WordPiece vocab, all

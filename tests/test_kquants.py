@@ -6,7 +6,7 @@ the C reference loops do) — the vectorized production decoders in
 models/gguf.py must agree bit-exactly on random block bytes.  llama.cpp
 itself is not installable in this image; agreement between two
 independently-written decoders over random data is the strongest
-offline check available (VERDICT r1 item 4).
+offline check available.
 """
 from __future__ import annotations
 

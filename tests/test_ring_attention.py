@@ -13,7 +13,7 @@ import optax
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from libsplinter_tpu.parallel.mesh import shard_map
+from jax import shard_map
 
 from libsplinter_tpu.models import Encoder, EncoderConfig
 from libsplinter_tpu.parallel import (dense_reference, make_mesh,

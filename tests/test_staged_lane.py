@@ -1,6 +1,6 @@
 """StagedLane: device-resident vector-lane cache with O(dirty) re-staging.
 
-Covers VERDICT r1 item 2: a second search after k dirty writes must
+A second search after k dirty writes must
 transfer O(k) rows, not the whole lane (the round-1 CLI re-uploaded the
 full (nslots, dim) matrix per query) — and the r05 dirty-refresh cliff:
 large dirty sets chunk through the fixed bucket set (padding waste <=

@@ -5,7 +5,7 @@ the same algorithms llama.cpp mirrors).
 Real checkpoints are unreachable in this offline image, so realistic
 vocabularies are TRAINED here with HF trainers on a fixed corpus, then
 both implementations must produce identical token ids on held-out text
-(VERDICT r1 item 4: tokenizer parity evidence).  Training is
+(tokenizer parity evidence).  Training is
 deterministic for a fixed corpus, so these are stable goldens.
 """
 from __future__ import annotations

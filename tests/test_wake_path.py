@@ -1,5 +1,5 @@
 """Embedder wake-path scaling: hot drains are dirty-mask + pending-set
-driven, never an O(nslots) label sweep (VERDICT r1 item 6)."""
+driven, never an O(nslots) label sweep."""
 from __future__ import annotations
 
 import time

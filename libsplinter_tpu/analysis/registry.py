@@ -247,6 +247,11 @@ class ProtocolRegistry:
     # discover replica-suffixed keys via the protocol helper instead
     # of the one-key-per-lane read.
     replica_suffix: str = ""
+    # *_PHASES tuples: span names of a daemon's run loop, outside the
+    # per-request stage contract (SPL107 reads them, nothing sizes by
+    # them)
+    phases: dict[str, tuple[str, ...]] = dataclasses.field(
+        default_factory=dict)
 
     def masks(self) -> dict[str, int]:
         """name -> mask for every label AND field."""
@@ -348,6 +353,7 @@ def extract_registry(path: str | None = None,
     fields: dict[str, LabelDef] = {}
     bit_indices: dict[str, int] = {}
     stages: dict[str, tuple[str, ...]] = {}
+    phases: dict[str, tuple[str, ...]] = {}
     keys: dict[str, str] = {}
     prefixes: dict[str, str] = {}
     replica_suffix = ""
@@ -374,6 +380,8 @@ def extract_registry(path: str | None = None,
             bit_indices[name] = value
         elif name.endswith("_STAGES") and isinstance(value, tuple):
             stages[name] = tuple(str(s) for s in value)
+        elif name.endswith("_PHASES") and isinstance(value, tuple):
+            phases[name] = tuple(str(s) for s in value)
         elif name.startswith("KEY_") and isinstance(value, str):
             keys[name] = value
         elif name.endswith("_PREFIX") and isinstance(value, str):
@@ -383,7 +391,8 @@ def extract_registry(path: str | None = None,
     return ProtocolRegistry(path=path, labels=labels, fields=fields,
                             bit_indices=bit_indices, stages=stages,
                             keys=keys, prefixes=prefixes,
-                            replica_suffix=replica_suffix)
+                            replica_suffix=replica_suffix,
+                            phases=phases)
 
 
 # --- fault-site discovery -------------------------------------------------

@@ -319,13 +319,14 @@ def check_doc_tables(ctx: Context) -> list[Finding]:
 _EXTRA_SPANS = {"e2e", "drain_cycle"}
 _PREFIX_FAMILIES = {"embed": ("PIPELINE_STAGES",),
                     "infer": ("INFER_STAGES", "CONT_INFER_STAGES"),
-                    "search": ("SEARCH_STAGES",),
+                    "search": ("SEARCH_STAGES", "SEARCH_LOOP_PHASES"),
                     "script": ("SCRIPT_STAGES",)}
 
 
 @rule("SPL107", "registry", "unknown stage name in tracer span",
       "stage-name literals recorded to tracers must come from the "
-      "pinned `*_STAGES` tuples (plus e2e/drain_cycle aggregates) — "
+      "pinned `*_STAGES` tuples, or a lane's `*_PHASES` run-loop "
+      "tuple (plus e2e/drain_cycle aggregates) — "
       "a typo silently creates a histogram no dashboard reads")
 def check_stage_names(ctx: Context) -> list[Finding]:
     reg = ctx.registry
@@ -337,8 +338,9 @@ def check_stage_names(ctx: Context) -> list[Finding]:
                 continue
             fn = node.func
             # tracer.record("prefix.stage", ...) / tracer.span(...)
+            # / tracer.annotation(...)
             if isinstance(fn, ast.Attribute) and \
-                    fn.attr in ("record", "span") and \
+                    fn.attr in ("record", "span", "annotation") and \
                     isinstance(fn.value, ast.Name) and \
                     fn.value.id == "tracer" and node.args:
                 arg = node.args[0]
@@ -351,6 +353,7 @@ def check_stage_names(ctx: Context) -> list[Finding]:
                         continue      # not a stage histogram family
                     ok = stage in _EXTRA_SPANS or any(
                         stage in reg.stages.get(f, ())
+                        or stage in reg.phases.get(f, ())
                         for f in fams)
                     if not ok:
                         out.append(Finding(

@@ -271,7 +271,8 @@ def cmd_metrics(ses, args):
                 if not isinstance(row, dict):
                     continue
                 lab_p = {"daemon": daemon, "program": str(prog)}
-                for field in ("n", "compiles", "runtime_compiles"):
+                for field in ("n", "total_ms", "compiles",
+                              "runtime_compiles"):
                     v = row.get(field)
                     if isinstance(v, (int, float)) \
                             and not isinstance(v, bool):

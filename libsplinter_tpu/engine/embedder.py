@@ -44,7 +44,7 @@ from ..obs.spans import SpanWriter
 from ..store import Store
 from ..utils import faults
 from ..utils.faults import fault
-from ..utils.trace import device_profile, tracer
+from ..utils.trace import tracer
 from . import protocol as P
 from .qos import (AdmissionController, TenantLedger, WaitingRow,
                   parse_tenant_weights, prune_idle_counters)
@@ -1096,11 +1096,7 @@ class Embedder:
             if not rows:
                 self._had_deferred = False    # nothing pending: the
                 return 0                      # redrain loop must end
-            # device profile only around real work: a busy daemon runs
-            # many empty sweep drains per second — capturing those
-            # would pile up trace dirs with nothing in them
-            with device_profile("drain"):
-                return self.process_rows(sorted(rows))
+            return self.process_rows(sorted(rows))
 
     def run_once(self) -> int:
         """One full drain cycle (--oneshot): dirty mask + label sweep.

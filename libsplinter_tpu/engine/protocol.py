@@ -206,6 +206,19 @@ CONT_INFER_STAGES = ("join", "sample", "decode", "collect", "flush",
 # filtering + __sr_<idx> result writes + label clears + bumps
 SEARCH_STAGES = ("wake", "drain", "score", "select", "commit")
 
+# the search daemon's RUN LOOP, fully accounted (spans only: not in
+# SEARCH_STAGES, which sizes every span record and the `quantiles`
+# section).  Every pass of Searcher.run is one `loop` span, and inside
+# it every second belongs to one of: idle = blocked in signal_wait;
+# search.drain_cycle (whose parts are the SEARCH_STAGES plus, inside
+# score, refresh = lane.refresh() and mask = the candidate-mask
+# builds); sweep_results / sweep_stages = the two heartbeat-cadence
+# key walks; publish = publish_stats.  All but `loop` are LEAF phases:
+# they also ride the profiler's clock (utils/trace.py), as do the
+# drain, select and commit stages.
+SEARCH_LOOP_PHASES = ("loop", "idle", "refresh", "mask",
+                      "sweep_results", "sweep_stages", "publish")
+
 # the pipeline lane's per-script decomposition: parse = source fetch
 # (inline or stored) + chunk compile + sandbox construction; exec =
 # host-interpreter wall (every coroutine resume slice of the script's
@@ -985,22 +998,34 @@ DEADLINE_EXPIRED_DIAGNOSTIC = json.dumps(
     {"err": ERR_DEADLINE}).encode()
 
 
+# optional heartbeat sections in the order they go when the record
+# passes max_val: the bulkiest and least-read first.  What is not
+# named here goes next, largest first; `startup_ms`, `devtime` and
+# `spans` — the sections benchmarks and `spt metrics` read from — go
+# last.
+HEARTBEAT_DROP_FIRST = ("quantiles", "slow_log", "recorder")
+HEARTBEAT_DROP_LAST = ("startup_ms", "devtime", "spans")
+
+
 def publish_heartbeat(store, key: str, payload: dict) -> None:
     """Write a timestamped JSON stats snapshot into a debug-labeled
     key.  Telemetry must never wedge serving: a concurrently deleted
     key (KeyError) or a failed store op (OSError) is swallowed — but a
     snapshot too big for the store's max_val degrades SECTION BY
-    SECTION (largest optional dict/list dropped first, marked
-    truncated) so whatever telemetry fits still lands, instead of
-    all-or-nothing removal the moment tracing is enabled.
+    SECTION in a FIXED order (HEARTBEAT_DROP_FIRST, then the other
+    optional dict/list sections, HEARTBEAT_DROP_LAST at the very end;
+    marked truncated) so whatever telemetry fits still lands, and a
+    section something reads never goes because it grew past a bulkier
+    one nothing reads.
 
     Every heartbeat carries the publisher's pid: liveness probes
     (heartbeat_live) kill-0 it, so a crashed daemon reads as dead the
     moment it dies instead of after max_age_s of heartbeat decay."""
-    rec = {"ts": time.time(), "pid": os.getpid(), **payload}
+    rec = {"ts": round(time.time(), 3), "pid": os.getpid(), **payload}
     for _ in range(2 + len(payload)):
         try:
-            store.set(key, json.dumps(rec))
+            # compact separators: a sixth fewer bytes under max_val
+            store.set(key, json.dumps(rec, separators=(",", ":")))
             store.label_or(key, LBL_DEBUG)
             return
         except KeyError:
@@ -1010,8 +1035,17 @@ def publish_heartbeat(store, key: str, payload: dict) -> None:
                         if isinstance(v, (dict, list))]
             if not sections:
                 return
-            rec.pop(max(sections, key=lambda k: len(json.dumps(rec[k]))))
+            rec.pop(min(sections, key=lambda k: _drop_rank(k, rec[k])))
             rec["truncated"] = True
+
+
+def _drop_rank(name: str, section) -> tuple:
+    """Sort key of a heartbeat section: the smallest goes first."""
+    if name in HEARTBEAT_DROP_FIRST:
+        return (0, HEARTBEAT_DROP_FIRST.index(name))
+    if name in HEARTBEAT_DROP_LAST:
+        return (2, HEARTBEAT_DROP_LAST.index(name))
+    return (1, -len(json.dumps(section)))
 
 
 def bump_generation(store, heartbeat_key: str) -> int:
@@ -1187,10 +1221,14 @@ def attach_trace_sections(payload: dict, tracer, recorder,
     # summaries under the pinned stage names — both full would double
     # the payload for zero extra information (publish_heartbeat
     # degrades by size when max_val bites)
+    # `spans` is what deltas are read from and goes last under
+    # max_val, so it is kept small: no <prefix>e2e (the sum of its
+    # stages; `quantiles` has it for `spt top`), one decimal on totals
     snap = tracer.snapshot()
     payload["spans"] = {
-        k: {f: v[f] for f in ("n", "total_ms", "max_ms") if f in v}
-        for k, v in snap.items()}
+        k: {"n": v["n"], "total_ms": round(v["total_ms"], 1),
+            "max_ms": round(v["max_ms"], 1 if v["max_ms"] >= 100 else 3)}
+        for k, v in snap.items() if k != prefix + "e2e"}
     payload["quantiles"] = {k[len(prefix):]: v
                             for k, v in snap.items()
                             if k.startswith(prefix)}

@@ -49,13 +49,13 @@ from typing import Sequence
 import numpy as np
 
 from .. import _native as N
-from ..obs.devtime import DEVTIME
+from ..obs.devtime import DEVTIME, close_mark
 from ..obs.recorder import FlightRecorder
 from ..obs.spans import SpanWriter, sweep_span_stages
 from ..store import Store
 from ..utils import faults
 from ..utils.faults import fault
-from ..utils.trace import device_profile, tracer
+from ..utils.trace import tracer
 from . import protocol as P
 from .qos import (AdmissionController, TenantLedger, WaitingRow,
                   parse_tenant_weights, prune_idle_counters)
@@ -134,6 +134,7 @@ class SearcherStats:
     req_failures: int = 0        # requests failed with error records
     drain_faults: int = 0        # whole drains failed by the firewall
     results_reaped: int = 0      # orphaned __sr_ rows retired
+    sweep_keys: int = 0          # keys visited by the two sweeps' walks
     # -- multi-tenant QoS (engine/qos.py) ----------------------------
     deadline_expired: int = 0    # fast-failed: client deadline passed
     shed: int = 0                # typed overloaded + retry_after_ms
@@ -143,6 +144,25 @@ class SearcherStats:
         """Requests served per device dispatch (1.0 = no batching win;
         the whole point of the daemon is pushing this toward QB)."""
         return self.served / self.dispatches if self.dispatches else 0.0
+
+
+def _take_mark(fn):
+    """The devtime mark the dispatch of `fn` just left (None when the
+    plane is off: the program is then the bare jit)."""
+    name = getattr(fn, "_devtime_name", None)
+    return DEVTIME.take_mark(name) if name else None
+
+
+def _fetch(fn, *args):
+    """Dispatch one program call and block on its result, closing its
+    devtime window at the fetch (a fetch that raises drops the mark)."""
+    import jax
+
+    pend = fn(*args)
+    mark = _take_mark(fn)
+    out = jax.device_get(pend)
+    close_mark(mark)
+    return out
 
 
 class _Request:
@@ -239,6 +259,10 @@ class Searcher:
         self.recorder = FlightRecorder()
         self.spans = SpanWriter(store, "searcher")
         self._trace_published = 0
+        # one-shot start-up phases, ms (main() brings its own: process,
+        # jax, store_open, attach, warmup); the first full lane upload
+        # is added where it runs.  Published as `startup_ms`.
+        self.startup_ms: dict[str, float] = {}
         self._stage_acc: dict | None = None
         self._bid = -1
         self._running = False
@@ -463,7 +487,10 @@ class Searcher:
             acc["wake"] = wake_ms
         with tracer.span("search.drain_cycle"):
             t0 = time.perf_counter()
-            reqs = self._admit(self._gather_requests())
+            # the annotation covers every gather; the histogram below
+            # counts serviced drains only
+            with tracer.annotation("search.drain"):
+                reqs = self._admit(self._gather_requests())
             if acc is not None:
                 acc["drain"] = (time.perf_counter() - t0) * 1e3
             if not reqs:
@@ -480,28 +507,27 @@ class Searcher:
                     st.shard_rebid(self._bid)
                 except OSError:
                     pass
-            with device_profile("search"):
-                try:
-                    served = self._service(reqs)
-                except Exception as ex:
-                    # drain-level firewall: _service already contains
-                    # per-batch failures, so anything landing here
-                    # (lane refresh, mask build, an exhausted retry
-                    # budget) fails the WHOLE drain's requests with
-                    # error records — clients unblock, the run loop
-                    # never unwinds
-                    log.exception("drain failed; failing %d requests",
-                                  len(reqs))
-                    self.stats.drain_faults += 1
-                    for r in reqs:
-                        try:
-                            self._fail(r.idx, r.epoch,
-                                       f"drain failed: {ex}",
-                                       counter="req_failures")
-                        except Exception:
-                            pass      # store down too: retried next drain
-                        self._fail_span(r)
-                    served = 0
+            try:
+                served = self._service(reqs)
+            except Exception as ex:
+                # drain-level firewall: _service already contains
+                # per-batch failures, so anything landing here
+                # (lane refresh, mask build, an exhausted retry
+                # budget) fails the WHOLE drain's requests with
+                # error records — clients unblock, the run loop
+                # never unwinds
+                log.exception("drain failed; failing %d requests",
+                              len(reqs))
+                self.stats.drain_faults += 1
+                for r in reqs:
+                    try:
+                        self._fail(r.idx, r.epoch,
+                                   f"drain failed: {ex}",
+                                   counter="req_failures")
+                    except Exception:
+                        pass      # store down too: retried next drain
+                    self._fail_span(r)
+                served = 0
         self._end_trace(reqs)
         self.stats.served += served
         return served
@@ -517,12 +543,23 @@ class Searcher:
         whose dispatch or fetch raises degrades through
         _score_degraded (unfused retry, then request-by-request) while
         its siblings commit normally — a device failure mid-service
-        must never unwind the run loop or starve unrelated requests."""
+        must never unwind the run loop or starve unrelated requests.
+
+        The score stage's two all-slot parts are bracketed where they
+        run (search.refresh, search.mask); what is left of score is
+        batching and dispatch."""
         acc = self._stage_acc
         t0 = time.perf_counter()
         full0 = self.lane.full_uploads
-        arr = self.lane.refresh()
-        self.stats.full_refreshes += self.lane.full_uploads - full0
+        with tracer.span("search.refresh", leaf=True):
+            arr = self.lane.refresh()
+        fulls = self.lane.full_uploads - full0
+        self.stats.full_refreshes += fulls
+        if fulls and "first_refresh" not in self.startup_ms:
+            # the staging of the lane: the daemon's largest start-up
+            # cost, paid by the first request
+            self.startup_ms["first_refresh"] = \
+                (time.perf_counter() - t0) * 1e3
         req_rows = np.asarray([r.idx for r in reqs], np.int64)
 
         # select/commit wall + served count accrued by the window's
@@ -545,8 +582,9 @@ class Searcher:
         # one mask per BLOOM value: the fast/exact split shares it, and
         # the default mask's O(nslots) epochs() snapshot runs once per
         # drain, not once per precision group
-        masks = {bloom: self._mask_for(bloom, req_rows)
-                 for bloom in {b for b, _ in groups}}
+        with tracer.span("search.mask", leaf=True):
+            masks = {bloom: self._mask_for(bloom, req_rows)
+                     for bloom in {b for b, _ in groups}}
         for (bloom, fast), group in groups.items():
             mask = masks[bloom]
             lo = 0
@@ -565,18 +603,22 @@ class Searcher:
                     q[i] = r.qvec
                 # dispatch failures defer to the select stage's
                 # degradation ladder (pend=None) so sibling batches
-                # still queue on the device back to back
+                # still queue on the device back to back.  The
+                # program's devtime mark rides with pend and closes
+                # where the result is fetched.
+                mark = None
                 try:
                     fault("searcher.dispatch")
                     fn = self._program(k_fetch, mxu_bf16=fast)
                     pend = fn(arr, q, mask, self.lane.norms)
+                    mark = _take_mark(fn)
                 except Exception as ex:
                     log.warning("batch dispatch failed: %s", ex)
                     pend = None
                 self.stats.dispatches += 1
                 self.stats.coalesced_max = max(
                     self.stats.coalesced_max, len(chunk))
-                win.push((chunk, k_fetch, mask, q), pend)
+                win.push((chunk, k_fetch, mask, q, mark), pend)
         win.flush()
         self.stats.inflight_peak = max(self.stats.inflight_peak,
                                        win.inflight_peak)
@@ -603,31 +645,36 @@ class Searcher:
         runs while sibling batches still compute on-device."""
         import jax
 
-        chunk, k_fetch, mask, q = payload
+        chunk, k_fetch, mask, q, mark = payload
         t1 = time.perf_counter()
-        try:
-            fault("searcher.select")
-            if pend is None:
-                raise RuntimeError("batch dispatch failed")
-            s_all, i_all = jax.device_get(pend)
-            ok = None
-        except Exception as ex:
-            s_all, i_all, ok = self._score_degraded(
-                arr, chunk, q, mask, k_fetch, ex)
+        with tracer.annotation("search.select"):
+            try:
+                fault("searcher.select")
+                if pend is None:
+                    raise RuntimeError("batch dispatch failed")
+                s_all, i_all = jax.device_get(pend)
+                ok = None
+            except Exception as ex:
+                s_all, i_all, ok = self._score_degraded(
+                    arr, chunk, q, mask, k_fetch, ex)
+            # dispatch -> collect; after a degraded batch the window
+            # runs to the retry's result (a ceiling, never short)
+            close_mark(mark)
         t2 = time.perf_counter()
         state["select_ms"] += (t2 - t1) * 1e3
-        for i, r in enumerate(chunk):
-            if ok is not None and not ok[i]:
-                continue           # already failed with an error record
-            try:
-                state["served"] += self._commit_hits(
-                    r, np.asarray(s_all[i]), np.asarray(i_all[i]),
-                    k_fetch)
-            except Exception as ex:
-                self._fail(r.idx, r.epoch,
-                           f"result commit failed: {ex}",
-                           counter="req_failures")
-                self._fail_span(r)
+        with tracer.annotation("search.commit"):
+            for i, r in enumerate(chunk):
+                if ok is not None and not ok[i]:
+                    continue       # already failed with an error record
+                try:
+                    state["served"] += self._commit_hits(
+                        r, np.asarray(s_all[i]), np.asarray(i_all[i]),
+                        k_fetch)
+                except Exception as ex:
+                    self._fail(r.idx, r.epoch,
+                               f"result commit failed: {ex}",
+                               counter="req_failures")
+                    self._fail_span(r)
         state["commit_ms"] += (time.perf_counter() - t2) * 1e3
 
     def _score_degraded(self, arr, chunk: list[_Request], q, mask,
@@ -640,8 +687,6 @@ class Searcher:
         served queries beat an unwound daemon.  Returns
         (s_all, i_all, ok_rows); ok_rows[i] False = row i already
         failed terminally."""
-        import jax
-
         from ..ops.similarity import topk_program
 
         self.stats.batch_faults += 1
@@ -654,7 +699,7 @@ class Searcher:
                               use_pallas=self.use_pallas,
                               mxu_bf16=False, block_n=self.block_n,
                               fused=False, interpret=self.interpret)
-            s_all, i_all = jax.device_get(fn(arr, q, mask, norms))
+            s_all, i_all = _fetch(fn, arr, q, mask, norms)
             self.stats.retried_unfused += 1
             return s_all, i_all, None
         except Exception as ex2:
@@ -673,7 +718,7 @@ class Searcher:
                                   use_pallas=self.use_pallas,
                                   mxu_bf16=False, block_n=self.block_n,
                                   fused=False, interpret=self.interpret)
-                s1, i1 = jax.device_get(fn(arr, q1, mask, norms))
+                s1, i1 = _fetch(fn, arr, q1, mask, norms)
                 s_out[i], i_out[i] = s1[0], i1[0]
                 ok[i] = True
                 self.stats.retried_single += 1
@@ -850,7 +895,9 @@ class Searcher:
         now = time.time() if now is None else now
         pfx = P.SEARCH_RESULT_PREFIX
         reaped = 0
-        for key in st.list():
+        keys = st.list()
+        self.stats.sweep_keys += len(keys)
+        for key in keys:
             if not key.startswith(pfx):
                 continue
             try:
@@ -879,10 +926,17 @@ class Searcher:
                 except (KeyError, OSError):
                     pass
         self.stats.results_reaped += reaped
-        # the pending-span staging rows share the same reaper cadence
-        # (orphans: raced rewrites, crashed drains nobody re-ran)
-        sweep_span_stages(st, ttl_s=ttl_s, now=now)
         return reaped
+
+    def sweep_stages(self, *, ttl_s: float = RESULT_TTL_S,
+                     now: float | None = None) -> int:
+        """The pending-span staging rows share the result rows' reaper
+        cadence (orphans: raced rewrites, crashed drains nobody
+        re-ran): a second O(nslots) key walk, right after the first.
+        It is the tracing plane's own housekeeping and is paid whether
+        or not any request was ever stamped."""
+        return sweep_span_stages(self.store, ttl_s=ttl_s, now=now,
+                                 stats=self.stats)
 
     def publish_stats(self) -> None:
         """Heartbeat: JSON stats snapshot into __searcher_stats (the
@@ -921,6 +975,10 @@ class Searcher:
                           or tenants))
         if faults.armed():
             payload["faults"] = faults.stats()
+        if self.startup_ms:
+            payload["startup_ms"] = {
+                **{k: round(v, 1) for k, v in self.startup_ms.items()},
+                "total": round(sum(self.startup_ms.values()), 1)}
         payload["compile_events"] = DEVTIME.compile_events("searcher")
         devtime = DEVTIME.heartbeat_section("searcher")
         if devtime:
@@ -941,73 +999,106 @@ class Searcher:
         """The daemon loop: block on the signal group, drain, repeat.
         The heartbeat doubles as the liveness signal the CLI's
         dispatch check reads, so it publishes on an interval even
-        when idle."""
+        when idle.
+
+        Every pass is one `search.loop` span and every second of it
+        belongs to one child (protocol.SEARCH_LOOP_PHASES): idle,
+        drain_cycle, the two sweeps, publish.  A beat's publish runs
+        at the head of the NEXT pass — the same place in time, right
+        after the sweeps — so a heartbeat's snapshot holds whole
+        passes only and `search.loop` equals its children's sum plus
+        the loop's own bookkeeping at every heartbeat."""
         self._running = True
         st = self.store
         last = st.signal_count(self.group)
         deadline = (time.monotonic() + stop_after) if stop_after else None
         next_beat = 0.0                       # publish immediately
         next_retire_check = 0.0
+        publish_due = False
         while self._running:
-            got = st.signal_wait(self.group, last,
-                                 timeout_ms=idle_timeout_ms)
-            t_wake = time.perf_counter()
-            # loop-level exception firewall: the drain already fails
-            # requests instead of raising, so anything landing here is
-            # a gather/store-level surprise — log it and keep serving
-            # (the crash-only discipline: the loop never unwinds, and
-            # a real crash is the supervisor's job to absorb)
-            try:
-                if got is not None:
-                    last = got
-                    self.stats.wakes += 1
-                    if self.coalesce_window_ms > 0:
-                        time.sleep(self.coalesce_window_ms / 1e3)
-                    self.drain(
-                        wake_ms=(time.perf_counter() - t_wake) * 1e3)
-                    # work-conserving under admit_cap: a drain that
-                    # deferred backlog (fairness granularity, not a
-                    # throughput cap) re-drains immediately — each
-                    # pass re-plans admission with accumulated stride
-                    # credit, so the backlog clears in fair slices
-                    # instead of waiting out the heartbeat cadence
-                    redrains = 0
-                    while self._had_deferred and self._running \
-                            and redrains < 256:
-                        redrains += 1
-                        self.drain()
-                now = time.monotonic()
-                if now >= next_beat:
-                    if got is None:
-                        # reconciliation on the heartbeat cadence,
-                        # never per idle timeout: a request whose
-                        # pulse raced a prior drain (or a torn row
-                        # left pending) retries here without an
-                        # O(nslots) label scan every idle wakeup.  A
-                        # restarted daemon's FIRST pass through here
-                        # reclaims the stranded requests (label bit
-                        # set, no inflight owner) a crashed
-                        # predecessor left behind.
-                        self.drain()
-                    self.sweep_results()
-                    self.publish_stats()
-                    next_beat = now + heartbeat_interval_s
-                if self.replica and now >= next_retire_check:
-                    # scale-down drain: stripes closed by the
-                    # supervisor; the drain above finished in-flight
-                    # work, so exit cleanly and let it reap us
-                    next_retire_check = now + 1.0
-                    if self.stripes.poll_retired():
-                        log.info("replica %d destriped — retiring",
-                                 self.replica)
-                        self.publish_stats()
-                        break
-            except Exception:
-                self.stats.drain_faults += 1
-                log.exception("run loop cycle failed; continuing")
-                now = time.monotonic()
-            if deadline and now > deadline:
-                break
+            with tracer.span("search.loop"):
+                if publish_due:
+                    publish_due = False
+                    self._publish_beat()
+                with tracer.span("search.idle", leaf=True):
+                    got = st.signal_wait(self.group, last,
+                                         timeout_ms=idle_timeout_ms)
+                t_wake = time.perf_counter()
+                # loop-level exception firewall: the drain already
+                # fails requests instead of raising, so anything
+                # landing here is a gather/store-level surprise — log
+                # it and keep serving (the crash-only discipline: the
+                # loop never unwinds, and a real crash is the
+                # supervisor's job to absorb)
+                try:
+                    if got is not None:
+                        last = got
+                        self.stats.wakes += 1
+                        if self.coalesce_window_ms > 0:
+                            time.sleep(self.coalesce_window_ms / 1e3)
+                        self.drain(
+                            wake_ms=(time.perf_counter() - t_wake) * 1e3)
+                        # work-conserving under admit_cap: a drain
+                        # that deferred backlog (fairness granularity,
+                        # not a throughput cap) re-drains immediately
+                        # — each pass re-plans admission with
+                        # accumulated stride credit, so the backlog
+                        # clears in fair slices instead of waiting out
+                        # the heartbeat cadence
+                        redrains = 0
+                        while self._had_deferred and self._running \
+                                and redrains < 256:
+                            redrains += 1
+                            self.drain()
+                    now = time.monotonic()
+                    if now >= next_beat:
+                        if got is None:
+                            # reconciliation on the heartbeat cadence,
+                            # never per idle timeout: a request whose
+                            # pulse raced a prior drain (or a torn row
+                            # left pending) retries here without an
+                            # O(nslots) label scan every idle wakeup.
+                            # A restarted daemon's FIRST pass through
+                            # here reclaims the stranded requests
+                            # (label bit set, no inflight owner) a
+                            # crashed predecessor left behind.
+                            self.drain()
+                        with tracer.span("search.sweep_results",
+                                         leaf=True):
+                            self.sweep_results()
+                        with tracer.span("search.sweep_stages",
+                                         leaf=True):
+                            self.sweep_stages()
+                        publish_due = True
+                        next_beat = now + heartbeat_interval_s
+                    if self.replica and now >= next_retire_check:
+                        # scale-down drain: stripes closed by the
+                        # supervisor; the drain above finished
+                        # in-flight work, so exit cleanly and let it
+                        # reap us
+                        next_retire_check = now + 1.0
+                        if self.stripes.poll_retired():
+                            log.info("replica %d destriped — retiring",
+                                     self.replica)
+                            publish_due = True
+                            break
+                except Exception:
+                    self.stats.drain_faults += 1
+                    log.exception("run loop cycle failed; continuing")
+                    now = time.monotonic()
+                if deadline and now > deadline:
+                    break
+        if publish_due:
+            self._publish_beat()
+
+    def _publish_beat(self) -> None:
+        """The beat's heartbeat, behind the loop's firewall."""
+        try:
+            with tracer.span("search.publish", leaf=True):
+                self.publish_stats()
+        except Exception:
+            self.stats.drain_faults += 1
+            log.exception("heartbeat publish failed; continuing")
 
     def stop(self) -> None:
         self._running = False
@@ -1092,6 +1183,29 @@ def consume_result(store: Store, key: str) -> None:
         pass
 
 
+class _Lap:
+    """ms since the last lap (main()'s start-up phases)."""
+
+    def __init__(self):
+        self._t = time.perf_counter()
+
+    def lap(self) -> float:
+        t, self._t = self._t, time.perf_counter()
+        return (self._t - t) * 1e3
+
+
+def _process_age_ms() -> float | None:
+    """ms since this process was created (Linux: /proc/self/stat's
+    starttime against the boot clock); None where that is unknown."""
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        return (time.clock_gettime(time.CLOCK_BOOTTIME)
+                - ticks / os.sysconf("SC_CLK_TCK")) * 1e3
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
+
+
 def main(argv: list[str] | None = None) -> int:
     """CLI entry: python -m libsplinter_tpu.engine.searcher --store NAME"""
     import argparse
@@ -1140,12 +1254,21 @@ def main(argv: list[str] | None = None) -> int:
     args = ap.parse_args(argv)
 
     logging.basicConfig(level=logging.INFO)
+    # one-shot start-up phases, ms -> the heartbeat's `startup_ms`
+    boot: dict[str, float] = {}
+    age = _process_age_ms()
+    if age is not None:
+        boot["process"] = age   # exec to here: interpreter, imports and
+    t = _Lap()                  # whatever a hosting process did first
+    import jax
     if os.environ.get("SPTPU_FORCE_CPU") == "1":
-        import jax
         jax.config.update("jax_platforms", "cpu")
     from ..utils.jaxplatform import enable_compile_cache
     enable_compile_cache()
+    jax.devices()               # open the device here, where it is timed
+    boot["jax"] = t.lap()
     store = Store.open(args.store, persistent=args.persistent)
+    boot["store_open"] = t.lap()
     sr = Searcher(store, mxu_bf16=args.fast,
                   inflight_depth=args.inflight_depth,
                   coalesce_window_ms=args.coalesce_window_ms,
@@ -1156,10 +1279,12 @@ def main(argv: list[str] | None = None) -> int:
                       args.tenant_weights),
                   replica=args.replica)
     sr.attach()
+    boot["attach"] = t.lap()        # Searcher() + attach()
     if args.warmup:
-        t0 = time.monotonic()
         sr.warmup()
-        log.info("warmup compiled in %.1fs", time.monotonic() - t0)
+        boot["warmup"] = t.lap()
+        log.info("warmup compiled in %.1fs", boot["warmup"] / 1e3)
+    sr.startup_ms.update(boot)
     if args.oneshot:
         n = sr.run_once()
         log.info("oneshot served %d searches", n)

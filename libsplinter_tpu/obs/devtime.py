@@ -310,8 +310,11 @@ class DevtimeRegistry:
             ent = {"n": p.hist.n, "compiles": p.compiles,
                    "runtime_compiles": p.runtime_compiles}
             if p.hist.n:
-                ent["p50_ms"] = round(p.hist.quantile(0.50), 4)
-                ent["p99_ms"] = round(p.hist.quantile(0.99), 4)
+                # n and total_ms never reset: a window's mean is the
+                # difference of two heartbeats
+                ent["total_ms"] = round(p.hist.total_ms, 1)
+                ent["p50_ms"] = round(p.hist.quantile(0.50), 3)
+                ent["p99_ms"] = round(p.hist.quantile(0.99), 3)
             out[p.short] = ent
         return out
 

@@ -404,16 +404,20 @@ class SpanWriter:
 # -- sweeps ----------------------------------------------------------------
 
 def sweep_span_stages(store, *, ttl_s: float = STAGE_TTL_S,
-                      now: float | None = None) -> int:
+                      now: float | None = None, stats=None) -> int:
     """Retire orphaned pending-span staging rows: slot gone, slot
     epoch moved past the staged one (raced rewrite — the new occupant
     stages its own span), or TTL expired (a crashed chain nobody ever
     re-drained).  Heartbeat-cadence work, mirroring the `__sr_`
-    reaper; returns the reaped count."""
+    reaper; returns the reaped count.  `stats`, where given, has a
+    `sweep_keys` counter that grows by the keys the walk visits."""
     now = time.time() if now is None else now
     pfx = P.SPAN_STAGE_PREFIX
     reaped = 0
-    for key in store.list():
+    keys = store.list()
+    if stats is not None:
+        stats.sweep_keys += len(keys)
+    for key in keys:
         if not key.startswith(pfx):
             continue
         try:

@@ -20,9 +20,17 @@ text exposition for `spt metrics`.
 
 Enabled with SPTPU_TRACE=1 (default off: span() returns a shared
 no-op, and the disabled hot path pays one dict lookup and nothing
-else).  SPTPU_JAX_PROFILE=<dir> additionally wraps whole drains in
-jax.profiler traces for device-level timelines (TensorBoard-loadable);
-that one is for deliberate profiling sessions, not production.
+else).
+
+One span, two clocks.  A LEAF phase — `tracer.span(name, leaf=True)`,
+or `tracer.annotation(name)` where the call site accumulates the
+histogram itself — also opens a jax.profiler.TraceAnnotation, so the
+phase lands on the host plane of whatever profiler capture is running,
+on the device trace's clock: one capture of a steady window then says
+what the host did in every device-idle gap.  Enclosing spans record
+the histogram only: a gap is named after the single host event that
+overlaps it longest, so an annotation around others would win every
+gap and say nothing.  jax is imported on the enabled leaf path alone.
 """
 from __future__ import annotations
 
@@ -57,8 +65,18 @@ class Tracer:
 
     _NOOP = contextlib.nullcontext()
 
-    def span(self, name: str):
-        return _Span(self, name) if self.enabled else self._NOOP
+    def span(self, name: str, *, leaf: bool = False):
+        """Histogram span; leaf=True adds the profiler annotation (the
+        module docstring's rule: leaves are disjoint in time on their
+        thread, enclosing spans stay off the profiler's clock)."""
+        if not self.enabled:
+            return self._NOOP
+        return _LeafSpan(self, name) if leaf else _Span(self, name)
+
+    def annotation(self, name: str):
+        """The profiler-clock half of a leaf span alone, for call
+        sites that sum a stage over a drain and record() it once."""
+        return _annotation(name) if self.enabled else self._NOOP
 
     def snapshot(self) -> dict:
         """{name: {n, total_ms, max_ms, p50_ms, p90_ms, p95_ms,
@@ -125,21 +143,28 @@ class _Span:
         return False
 
 
+def _annotation(name: str):
+    import jax.profiler             # enabled leaf path only
+
+    return jax.profiler.TraceAnnotation(name)
+
+
+class _LeafSpan(_Span):
+    """A leaf phase: the histogram span plus a TraceAnnotation of the
+    same name and duration."""
+
+    __slots__ = ("_ann",)
+
+    def __enter__(self):
+        self._ann = _annotation(self._name)
+        self._ann.__enter__()
+        return super().__enter__()
+
+    def __exit__(self, *exc):
+        super().__exit__(*exc)
+        self._ann.__exit__(*exc)
+        return False
+
+
 tracer = Tracer()                     # process-wide default
 
-
-@contextlib.contextmanager
-def device_profile(tag: str):
-    """jax.profiler capture into $SPTPU_JAX_PROFILE/<tag>-<ts> when the
-    env var names a directory; otherwise free."""
-    root = os.environ.get("SPTPU_JAX_PROFILE")
-    if not root:
-        yield
-        return
-    import jax
-
-    # perf_counter_ns: unique per capture — second-resolution names
-    # collide across the many drains a busy daemon runs per second
-    path = os.path.join(root, f"{tag}-{time.perf_counter_ns()}")
-    with jax.profiler.trace(path):
-        yield

@@ -285,9 +285,52 @@ this is the structured counterpart the TPU port adds.
 
 | var | effect |
 |---|---|
-| `SPTPU_TRACE=1` | enable span histograms + flight recording in the daemons (off: the hot path pays one dict lookup) |
+| `SPTPU_TRACE=1` | enable span histograms + flight recording in the daemons, and put the search daemon's leaf phases on the profiler's clock as `jax.profiler.TraceAnnotation`s (off: the hot path pays one dict lookup, and `utils/trace.py` imports no jax) |
 | `SPTPU_TRACE_SLOW_MS=<ms>` | explicit slow-log promotion threshold; unset → 5× the recorder's live e2e p50 (arms after 20 samples) |
-| `SPTPU_JAX_PROFILE=<dir>` | additionally capture jax.profiler device timelines per drain |
+
+### The search daemon's run loop, span by span
+
+With `SPTPU_TRACE=1` every pass of `Searcher.run` is one `search.loop`
+span, and every second of it belongs to exactly one child
+(`protocol.SEARCH_LOOP_PHASES` beside `SEARCH_STAGES`; splint SPL107
+reads both tuples):
+
+| span | what it brackets | leaf |
+|---|---|---|
+| `search.loop` | one pass of the run loop | no |
+| `search.idle` | blocked in `signal_wait` | yes |
+| `search.drain_cycle` | one drain, serviced or idle (the beat's idle drain too) | no |
+| `search.wake` / `search.drain` | signal → drain entry; gather + admit (the histogram counts serviced drains only) | `drain` yes |
+| `search.score` | host wall of the service outside select and commit; its self time, score − refresh − mask, is batching and dispatch | no |
+| `search.refresh` / `search.mask` | `lane.refresh()` (a pass that does a full upload is the staging of the lane); the candidate-mask builds (all-slot epoch snapshot) | yes |
+| `search.select` / `search.commit` | blocked in `jax.device_get`; result rows + label clears | yes |
+| `search.sweep_results` / `search.sweep_stages` | the two heartbeat-cadence key walks (`sweep_keys` counts the keys they visit, `results_reaped` what the first retires); the second is the span plane's own housekeeping and runs with tracing off too | yes |
+| `search.publish` | `publish_stats`: serialisation, `DEVTIME.flush`, `spans.flush` | yes |
+
+A beat's publish runs at the head of the next pass, so a heartbeat
+holds whole passes only: `search.loop` equals its children's sum plus
+the loop's own bookkeeping in every snapshot, and the difference of
+two heartbeats accounts for the time between them.  Leaf phases are
+disjoint in time on the daemon's thread and also open a
+`TraceAnnotation`; enclosing spans do not, because a device-idle gap
+is named after the single host event that overlaps it longest.
+
+**One capture of a steady window.**  Start the daemon with
+`SPTPU_TRACE=1`, let it reach steady state, then from a thread of the
+same process (only the process that holds the chip can trace it) call
+`jax.profiler.start_trace(dir)`, sleep a few seconds, `stop_trace()` —
+`benchmark/host.py:trace_watcher` does exactly this at
+`host_tracer_level=2`, `python_tracer_level=0`, and
+`benchmark/tracereduce.py` reduces the capture to busy/idle and names
+each idle gap after a `search.*` phase.  There is no per-drain
+capture: a timeline cut at every drain is not one.
+
+**Start-up.**  Every search heartbeat carries `startup_ms`, one-shot
+phases in ms: `process` (exec to `main()`: interpreter, imports, and
+whatever a hosting process did first), `jax` (import + device open
+inside `main()`), `store_open`, `attach`, `warmup` (with `--warmup`),
+`first_refresh` (the first full lane upload, paid by the first
+request without `--warmup`), and their sum `total`.
 
 ### Trace-id convention (`engine/protocol.py`)
 
@@ -343,9 +386,14 @@ With tracing on, `__embedder_stats` / `__completer_stats` gain:
   `{id, key, wall_ms, ts, slow_threshold_ms,
   events: [[stage, ms], ...]}` (bounded deque; survives ring wrap).
 
-Oversized heartbeats degrade section by section (largest first,
-`truncated: true`): the slow log goes before the quantiles, and the
-scalar counters always land.
+The search heartbeat's `spans` names every loop phase above; `e2e`
+(the sum of a drain's stages) rides `quantiles` only.  The record is
+written with compact separators.  Oversized heartbeats degrade
+section by section in a FIXED order (`truncated: true`):
+`quantiles`, `slow_log`, `recorder`, then the other optional
+sections largest first, and last `startup_ms`, `devtime`, `spans` —
+the sections deltas are read from never go because they outgrew a
+bulkier one nothing reads.  The scalar counters always land.
 
 ### Prometheus exposition
 
@@ -416,7 +464,11 @@ switch, and warmup dispatches never open device windows):
   beside the queue/service split — "slow because device" vs "slow
   because the lane sat on it" is now readable per request — and each
   lane heartbeat gains a `devtime` section (per program `{n,
-  compiles, runtime_compiles, p50_ms, p99_ms}`, rendered as
+  compiles, runtime_compiles, total_ms, p50_ms, p99_ms}`; `n` and
+  `total_ms` never reset, so a window's mean is a difference of two
+  heartbeats; the search daemon takes the program's mark right after
+  each dispatch and closes it at that batch's fetch, so `n` counts
+  dispatches; rendered as
   `sptpu_<lane>_devtime_*{program=...}`).  The bench ledger rows
   carry `compile_events` + `device_ms_share`.
 

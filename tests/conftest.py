@@ -40,6 +40,17 @@ def store():
 
 
 @pytest.fixture
+def store_2k():
+    """As `store`, with 2 KiB values: room for a whole daemon
+    heartbeat, which the 1 KiB fixture truncates section by section."""
+    name = f"/spt-test-{os.getpid()}-{uuid.uuid4().hex[:8]}"
+    st = Store.create(name, nslots=256, max_val=2048, vec_dim=32)
+    yield st
+    st.close()
+    Store.unlink(name)
+
+
+@pytest.fixture
 def store_novec():
     name = f"/spt-test-{os.getpid()}-{uuid.uuid4().hex[:8]}"
     st = Store.create(name, nslots=64, max_val=256, vec_dim=0)

@@ -345,3 +345,105 @@ class TestGateScript:
         progs = {g["program"] for g in rec["guilty"]}
         assert any(pr.startswith("completer.") for pr in progs)
         assert all(g["shapes_key"] for g in rec["guilty"])
+
+
+# ------------------------------------- the search daemon's windows
+
+class TestSearcherWindows:
+    """The daemon path takes the program's mark right after the
+    dispatch and closes it at the fetch (searcher._service /
+    _resolve_batch): windows count dispatches, span records carry a
+    real device_ms, and no failure leaves a mark dangling."""
+
+    @staticmethod
+    def _searcher(store, n_docs=12):
+        from libsplinter_tpu.engine.searcher import Searcher
+        rng = np.random.default_rng(5)
+        vecs = rng.normal(size=(n_docs, store.vec_dim)).astype(np.float32)
+        for i in range(n_docs):
+            store.set(f"doc/{i}", f"text {i}")
+            store.vec_set(f"doc/{i}", vecs[i])
+        sr = Searcher(store)
+        sr.attach()
+        sr.run_once()                 # stage the lane, no request yet
+        return sr, vecs
+
+    @staticmethod
+    def _ask(store, key, qvec, *, stamp=False):
+        store.set(key, json.dumps({"k": 3}))
+        store.vec_set(key, qvec)
+        store.label_or(key, P.LBL_SEARCH_REQ | P.LBL_WAITING)
+        tid = P.stamp_trace(store, key) if stamp else None
+        store.bump(key)
+        return tid
+
+    @staticmethod
+    def _program(sr):
+        """(registry entry, short name) of the program this searcher
+        dispatches for a k=3 request."""
+        from libsplinter_tpu.engine import searcher as M
+        from libsplinter_tpu.obs.devtime import DEVTIME
+        fn = sr._program(M._k_bucket(3 + M.K_CUSHION))
+        name = fn._devtime_name
+        return DEVTIME._progs[name], name.split(".", 1)[1]
+
+    def test_windows_count_daemon_dispatches(self, store_2k):
+        from libsplinter_tpu.obs.devtime import DEVTIME
+        store = store_2k
+        sr, vecs = self._searcher(store)
+        prog, short = self._program(sr)
+        n0, total0 = prog.hist.n, prog.hist.total_ms
+        d0 = sr.stats.dispatches
+        for i in range(5):
+            self._ask(store, f"__sqtmp_w{i}", vecs[i])
+            assert sr.run_once() == 1
+        n = sr.stats.dispatches - d0
+        assert n == 5
+        assert prog.hist.n - n0 == n
+        assert prog.hist.total_ms > total0
+        assert prog.last_mark is None            # every mark taken
+        sec = DEVTIME.heartbeat_section("searcher")[short]
+        assert sec["n"] == prog.hist.n
+        assert sec["total_ms"] == pytest.approx(prog.hist.total_ms,
+                                                abs=0.06)
+        # and the heartbeat carries it: a window's mean is a
+        # difference of two heartbeats
+        sr.publish_stats()
+        snap = json.loads(store.get(P.KEY_SEARCH_STATS).rstrip(b"\0"))
+        assert snap["devtime"][short]["total_ms"] == sec["total_ms"]
+        assert snap["dispatches"] == sr.stats.dispatches
+
+    def test_committed_span_carries_device_ms(self, store_2k):
+        store = store_2k
+        sr, vecs = self._searcher(store)
+        tid = self._ask(store, "__sqtmp_sp", vecs[2], stamp=True)
+        assert sr.run_once() == 1                # flushes the span
+        rec = S.collect_spans(store, tid)[0]
+        assert rec["device_ms"] > 0
+        assert rec["dispatch_queue"] >= 0
+
+    def test_failed_dispatch_leaves_no_mark(self, store_2k):
+        """A dispatch that raises leaves no mark; a fetch that raises
+        closes the batch's mark after the degraded retry, whose own
+        dispatch closes its own: nothing dangles, every request is
+        served, and the windows still add up."""
+        from libsplinter_tpu.obs.devtime import DEVTIME
+        from libsplinter_tpu.utils import faults
+        store = store_2k
+        sr, vecs = self._searcher(store)
+        prog, _ = self._program(sr)
+        for site in ("searcher.dispatch", "searcher.select"):
+            n0 = prog.hist.n
+            faults.arm(f"{site}:raise@1")
+            try:
+                self._ask(store, f"__sqtmp_f_{site[-6:]}", vecs[1])
+                assert sr.run_once() == 1        # degraded, served
+            finally:
+                faults.disarm()
+            assert sr.stats.retried_unfused >= 1
+            assert all(p.last_mark is None
+                       for p in DEVTIME._progs.values()
+                       if p.lane == "searcher" and "topk" in p.short)
+            # dispatch failed: the retry's window only; fetch failed:
+            # the batch's own window and the retry's
+            assert prog.hist.n - n0 == (1 if "dispatch" in site else 2)

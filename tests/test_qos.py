@@ -367,7 +367,8 @@ class TestSearcherQoS:
         assert light == 6, (heavy, light)
         assert heavy >= light            # unused share flowed onward
 
-    def test_heartbeat_carries_tenants_and_qos(self, store):
+    def test_heartbeat_carries_tenants_and_qos(self, store_2k):
+        store = store_2k          # the whole heartbeat has to fit
         _seed_docs(store)
         sr = Searcher(store, admit_cap=2, queue_high_water=0)
         sr.attach()
@@ -720,7 +721,8 @@ class TestCompleterQoS:
 
 # ---------------------------------------------------------------- heartbeat
 
-def test_metrics_renders_tenant_series(store, capsys):
+def test_metrics_renders_tenant_series(store_2k, capsys):
+    store = store_2k              # the whole heartbeat has to fit
     from libsplinter_tpu.cli.main import Session
     from libsplinter_tpu.cli.metrics import cmd_metrics
 

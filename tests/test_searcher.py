@@ -608,3 +608,80 @@ def test_cli_search_local_flag_bypasses_daemon(store):
     assert rows and rows[0]["key"] == "doc/2"
     assert sr.stats.served == 0                # daemon untouched
     assert rows[0]["distance"] is not None     # local path scores both
+
+
+# ------------------------------------------- the run loop, accounted
+
+@pytest.mark.obs
+def test_run_loop_is_fully_accounted(traced):
+    """With tracing on, every pass of run() is one search.loop span
+    and every second of it belongs to one child: the heartbeat names
+    every phase, the children's totals add up to the loop's, and the
+    stage spans still count serviced drains only."""
+    import os
+    import uuid
+
+    tracer.reset()
+    name = f"/spt-srloop-{os.getpid()}-{uuid.uuid4().hex[:6]}"
+    Store.unlink(name)
+    store = Store.create(name, nslots=256, max_val=4096, vec_dim=32)
+    try:
+        rng = np.random.default_rng(21)
+        vecs = _fill_docs(store, 16, rng)
+        sr = Searcher(store)
+        sr.attach()
+        t = threading.Thread(target=sr.run, kwargs={
+            "stop_after": 2.0, "idle_timeout_ms": 20,
+            "heartbeat_interval_s": 0.4})       # several beats
+        t.start()
+        try:
+            for i in range(6):
+                key = f"__sqtmp_loop{i}"
+                store.set(key, "placeholder")
+                store.vec_set(key, vecs[i])
+                rec = submit_search(store, key, 3, timeout_ms=8000)
+                assert rec is not None and rec["keys"][0] == f"doc/{i}"
+        finally:
+            t.join()
+        snap = json.loads(store.get(P.KEY_SEARCH_STATS).rstrip(b"\0"))
+        assert "truncated" not in snap
+        spans = snap["spans"]
+        want = {f"search.{p}" for p in (*P.SEARCH_LOOP_PHASES,
+                                        *P.SEARCH_STAGES, "drain_cycle")}
+        assert want <= set(spans), want - set(spans)
+        assert "search.e2e" not in spans        # quantiles has it
+        assert "e2e" in snap["quantiles"]
+
+        def total(phase):
+            return spans[f"search.{phase}"]["total_ms"]
+
+        loop = total("loop")
+        children = sum(total(p) for p in (
+            "idle", "drain_cycle", "sweep_results", "sweep_stages",
+            "publish"))
+        assert loop > 1500                       # ~2 s of passes
+        assert abs(loop - children) <= 0.05 * loop, (loop, children)
+        # two beats at least, each with both walks; sweep_keys counts
+        # the keys of both
+        beats = spans["search.sweep_results"]["n"]
+        assert beats >= 2
+        assert spans["search.sweep_stages"]["n"] == beats
+        assert spans["search.publish"]["n"] >= beats - 1
+        assert snap["sweep_keys"] >= 2 * beats * 16
+        # stage spans count serviced drains, as before: one record per
+        # drain that had requests, idle drains only in drain_cycle
+        served = spans["search.score"]["n"]
+        assert 1 <= served <= 6
+        for stage in ("wake", "drain", "select", "commit", "refresh",
+                      "mask"):
+            assert spans[f"search.{stage}"]["n"] == served, stage
+        assert spans["search.drain_cycle"]["n"] == snap["drains"] > served
+        assert snap["served"] == 6
+        # the first full upload is the start-up phase a bare Searcher
+        # records itself
+        assert snap["startup_ms"]["first_refresh"] > 0
+        assert snap["startup_ms"]["total"] >= \
+            snap["startup_ms"]["first_refresh"]
+    finally:
+        store.close()
+        Store.unlink(name)

@@ -309,6 +309,48 @@ def test_stage_typo_flagged(splint, R, core, runner):
     assert len(fs) == 1 and "scoree" in fs[0].message
 
 
+PROTO_PHASES = PROTO_OK + (
+    'SEARCH_LOOP_PHASES = ("loop", "idle", "refresh", "mask",\n'
+    '                      "sweep_results", "sweep_stages", "publish")\n')
+
+
+@pytest.mark.parametrize("call", [
+    "tracer.span('search.sweep_stages', leaf=True)",
+    "tracer.span('search.loop')",
+    "tracer.annotation('search.select')",
+    "tracer.record('search.mask', 1.0)"])
+def test_loop_phase_from_the_tuple_passes(splint, R, core, runner, call):
+    """The search daemon's run-loop phases are pinned in
+    SEARCH_LOOP_PHASES, beside SEARCH_STAGES: SPL107 reads both."""
+    fs = run_rule(splint, R, core, runner, "SPL107", proto=PROTO_PHASES,
+                  files={"libsplinter_tpu/engine/foo.py":
+                         f"def f(tracer):\n    {call}\n"})
+    assert fs == []
+
+
+@pytest.mark.parametrize("call,typo", [
+    ("tracer.span('search.sweep_stage', leaf=True)", "sweep_stage"),
+    ("tracer.annotation('search.selct')", "selct"),
+    ("tracer.span('embed.idle', leaf=True)", "idle")])
+def test_misspelt_loop_phase_flagged(splint, R, core, runner, call, typo):
+    """A typo in a phase name, or a search phase under another lane's
+    prefix, still fails the lint."""
+    fs = run_rule(splint, R, core, runner, "SPL107", proto=PROTO_PHASES,
+                  files={"libsplinter_tpu/engine/foo.py":
+                         f"def f(tracer):\n    {call}\n"})
+    assert len(fs) == 1 and typo in fs[0].message
+
+
+def test_live_registry_has_the_loop_phases(splint):
+    reg = splint.extract_registry()
+    assert reg.phases["SEARCH_LOOP_PHASES"] == (
+        "loop", "idle", "refresh", "mask", "sweep_results",
+        "sweep_stages", "publish")
+    # phases are span names only: nothing sizes by them
+    assert "SEARCH_LOOP_PHASES" not in reg.stages
+    assert "idle" not in reg.stage_names()
+
+
 def test_span_helper_stage_checked(splint, R, core, runner):
     src = ("def f(span, r):\n"
            "    span(r, 'wake', 1.0)\n"

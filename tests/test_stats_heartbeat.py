@@ -97,10 +97,11 @@ def _traced_payload():
 
 
 @pytest.mark.obs
-def test_heartbeat_drop_order_slow_log_then_quantiles(tmp_path):
-    """Section-by-section degradation drops the LARGEST section first:
-    for the traced heartbeat that is the slow log, then quantiles —
-    and the scalar core counters always land last-resort."""
+def test_heartbeat_drop_order_quantiles_then_slow_log(tmp_path):
+    """Section-by-section degradation follows a FIXED order, not the
+    sections' sizes: quantiles first (smaller than the slow log here),
+    then the slow log — and the scalar core counters always land
+    last-resort."""
     # max_val sized so BOTH optional sections must go (core counters
     # + recorder accounting still fit)
     name = f"/spt-stats-order-{tmp_path.name}"
@@ -108,21 +109,24 @@ def test_heartbeat_drop_order_slow_log_then_quantiles(tmp_path):
     st = Store.create(name, nslots=64, max_val=320, vec_dim=8)
     try:
         spy = _SetSpy(st)
-        P.publish_heartbeat(spy, "__hb", _traced_payload())
-        # attempt 0 carried everything; slow_log (largest) went first;
-        # quantiles only after it; core counters never dropped
+        payload = _traced_payload()
+        assert len(json.dumps(payload["quantiles"])) \
+            < len(json.dumps(payload["slow_log"]))
+        P.publish_heartbeat(spy, "__hb", payload)
+        # attempt 0 carried everything; quantiles went first, the slow
+        # log only after it; core counters never dropped
         assert "slow_log" in spy.attempts[0]
         assert "quantiles" in spy.attempts[0]
         dropped_slow = next(i for i, a in enumerate(spy.attempts)
                             if "slow_log" not in a)
         dropped_q = next(i for i, a in enumerate(spy.attempts)
                          if "quantiles" not in a)
-        assert dropped_slow < dropped_q, spy.attempts
+        assert dropped_q < dropped_slow, spy.attempts
         assert all("embedded" in a and "wakes" in a
                    for a in spy.attempts)
         snap = json.loads(st.get("__hb").rstrip(b"\0"))
         assert snap.get("truncated") is True
-        assert "slow_log" not in snap
+        assert "slow_log" not in snap and "quantiles" not in snap
         assert snap["embedded"] == 8
     finally:
         st.close()
@@ -130,19 +134,119 @@ def test_heartbeat_drop_order_slow_log_then_quantiles(tmp_path):
 
 
 @pytest.mark.obs
-def test_heartbeat_quantiles_survive_slow_log_drop(tmp_path):
-    """With room for everything but the slow log, quantiles stay: the
-    bench's stage table degrades LAST among the optional sections."""
+def test_heartbeat_spans_survive_the_bulky_sections(tmp_path):
+    """The sections deltas are read from (`spans`, `devtime`) go LAST:
+    with quantiles and a slow log that cannot fit, both of those go
+    and `spans`/`devtime` stay, though `spans` is the largest section
+    left."""
     name = f"/spt-stats-q-{tmp_path.name}"
     Store.unlink(name)
     st = Store.create(name, nslots=64, max_val=2048, vec_dim=8)
     try:
-        P.publish_heartbeat(st, "__hb", _traced_payload())
+        payload = _traced_payload()
+        payload["spans"] = {
+            f"embed.{s}": {"n": 30, "total_ms": 99.9, "max_ms": 9.9}
+            for s in (*P.PIPELINE_STAGES, "drain_cycle", "e2e")}
+        payload["devtime"] = {"ring": {"n": 30, "compiles": 1,
+                                       "runtime_compiles": 0}}
+        payload["lane"] = {"full_uploads": 1}
+        P.publish_heartbeat(st, "__hb", payload)
         snap = json.loads(st.get("__hb").rstrip(b"\0"))
         assert snap.get("truncated") is True
-        assert "slow_log" not in snap
-        assert set(P.PIPELINE_STAGES) <= set(snap["quantiles"])
+        assert "slow_log" not in snap and "quantiles" not in snap
+        assert set(snap["spans"]) == set(payload["spans"])
+        assert snap["devtime"] == payload["devtime"]
+        assert snap["lane"] == payload["lane"]      # never reached
         assert snap["embedded"] == 8
+    finally:
+        st.close()
+        Store.unlink(name)
+
+
+def _searcher_payload():
+    """The search daemon's traced heartbeat with every section filled
+    at the magnitudes of a long-running 1.5M-slot deployment: counts
+    to 1e5, totals to 1e5 ms (the shape of searcher.publish_stats +
+    attach_trace_sections)."""
+    from libsplinter_tpu.engine.searcher import SearcherStats
+    import dataclasses
+
+    stats = {f.name: 98765 for f in dataclasses.fields(SearcherStats)}
+    stats["sweep_keys"] = 98765 * 1_572_864
+    names = [f"search.{p}" for p in (*P.SEARCH_LOOP_PHASES,
+                                     *P.SEARCH_STAGES, "drain_cycle")]
+    spans = {n: {"n": 98765, "total_ms": 98765.4, "max_ms": 17232.9}
+             for n in names}
+    quant = {n[len("search."):]: {
+        "n": 98765, "total_ms": 98765.432, "max_ms": 17232.912,
+        "p50_ms": 21.2471, "p90_ms": 33.5127, "p95_ms": 40.1234,
+        "p99_ms": 71.4682} for n in (*names, "search.e2e")}
+    prog = {"n": 98765, "compiles": 2, "runtime_compiles": 2,
+            "total_ms": 98765.4, "p50_ms": 21.247, "p99_ms": 71.468}
+    idle = {"n": 0, "compiles": 2, "runtime_compiles": 2}
+    return {
+        **stats,
+        "spans_obs": {"committed": 98765, "recovered": 0, "dropped": 0,
+                      "pending": 0},
+        "coalesce_ratio": 19.888, "generation": 1, "inflight_depth": 2,
+        "lane": {"full_uploads": 1, "refreshes": 98765,
+                 "rows_staged": 9876543, "rows_padded": 9876543,
+                 "scatter_chunks": 98765, "ring_dispatches": 98765,
+                 "ring_chunks": 98765, "chunks_bucket_64": 98765},
+        "startup_ms": {"process": 13185.3, "jax": 0.0, "store_open": 3.7,
+                       "attach": 2984.4, "warmup": 98765.4,
+                       "first_refresh": 16244.2, "total": 131183.0},
+        "compile_events": 6,
+        "devtime": {"stage_update": idle, "fused_topk": prog,
+                    "scatter_ring": idle, "scatter": idle},
+        "spans": spans, "quantiles": quant,
+        "recorder": {"recorded": 98765, "dropped": 98765,
+                     "slow_promoted": 98765,
+                     "slow_threshold_ms": 98765.432},
+        "slow_log": [{"id": (1 << 24) | i, "key": "<drain>",
+                      "wall_ms": 2793.429, "ts": 1790550501.957,
+                      "slow_threshold_ms": 98765.432,
+                      "events": [[s, 98765.432]
+                                 for s in P.SEARCH_STAGES]}
+                     for i in range(4)]}
+
+
+@pytest.mark.obs
+def test_searcher_heartbeat_keeps_what_is_read_at_2k(tmp_path):
+    """At the benchmark cell's max_val (2,048) the full search
+    heartbeat cannot fit.  `quantiles` goes first, then the slow log
+    and the recorder's accounting; `spans`, `devtime`, `startup_ms`
+    and every scalar counter survive, and the record parses."""
+    from libsplinter_tpu.engine.searcher import SearcherStats
+    import dataclasses
+
+    name = f"/spt-stats-sr-{tmp_path.name}"
+    Store.unlink(name)
+    st = Store.create(name, nslots=64, max_val=2048, vec_dim=8)
+    try:
+        spy = _SetSpy(st)
+        payload = _searcher_payload()
+        P.publish_heartbeat(spy, "__hb", payload)
+        gone = [next(i for i, a in enumerate(spy.attempts)
+                     if sec not in a)
+                for sec in ("quantiles", "slow_log", "recorder")]
+        assert gone == [1, 2, 3], spy.attempts   # the fixed order
+        raw = st.get("__hb").rstrip(b"\0")
+        assert len(raw) <= 2048
+        snap = json.loads(raw)
+        assert snap.get("truncated") is True
+        for sec in ("spans", "devtime", "startup_ms"):
+            assert snap[sec] == payload[sec], sec
+        for f in dataclasses.fields(SearcherStats):
+            assert snap[f.name] == payload[f.name], f.name
+        for k in ("coalesce_ratio", "generation", "inflight_depth",
+                  "compile_events", "ts", "pid"):
+            assert k in snap
+        # what the benchmark's metrics read
+        assert snap["spans"]["search.drain"]["n"] == 98765
+        assert snap["spans"]["search.commit"]["total_ms"] == 98765.4
+        assert snap["devtime"]["fused_topk"]["total_ms"] == 98765.4
+        assert snap["startup_ms"]["total"] == 131183.0
     finally:
         st.close()
         Store.unlink(name)

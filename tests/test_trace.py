@@ -83,3 +83,84 @@ def test_embedder_heartbeat_carries_spans(tmp_path, monkeypatch):
     finally:
         st.close()
         Store.unlink(name)
+
+
+# ------------------------------------------- one span, two clocks
+
+class _AnnotationLog:
+    """Stands in for jax.profiler.TraceAnnotation: records which names
+    were opened and that each was closed."""
+
+    def __init__(self):
+        self.opened: list[str] = []
+        self.closed: list[str] = []
+
+    def __call__(self, name):
+        log = self
+
+        class _Ann:
+            def __enter__(self):
+                log.opened.append(name)
+                return self
+
+            def __exit__(self, *exc):
+                log.closed.append(name)
+                return False
+
+        return _Ann()
+
+
+def test_leaf_span_opens_a_trace_annotation(monkeypatch):
+    import jax.profiler
+
+    log = _AnnotationLog()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", log)
+    t = Tracer(enabled=True)
+    with t.span("search.idle", leaf=True):
+        time.sleep(0.002)
+    assert log.opened == log.closed == ["search.idle"]
+    snap = t.snapshot()["search.idle"]
+    assert snap["n"] == 1 and snap["total_ms"] >= 1.5
+
+
+def test_enclosing_span_stays_off_the_profilers_clock(monkeypatch):
+    import jax.profiler
+
+    log = _AnnotationLog()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", log)
+    t = Tracer(enabled=True)
+    with t.span("search.loop"):
+        with t.span("search.drain_cycle"):
+            with t.annotation("search.drain"):
+                pass
+    # only the leaf rides the profiler's clock; the annotation alone
+    # records no histogram (its call site sums the stage and records)
+    assert log.opened == log.closed == ["search.drain"]
+    assert set(t.snapshot()) == {"search.loop", "search.drain_cycle"}
+
+
+def test_disabled_tracer_imports_no_jax():
+    """With SPTPU_TRACE unset every span form is the shared no-op, no
+    TraceAnnotation is constructed and utils/trace.py pulls in no jax
+    (a fresh interpreter: this one has jax loaded already)."""
+    import os
+    import subprocess
+    import sys
+
+    code = (
+        "import sys\n"
+        "from libsplinter_tpu.utils.trace import Tracer, tracer\n"
+        "assert tracer.enabled is False\n"
+        "noop = Tracer._NOOP\n"
+        "assert tracer.span('search.idle', leaf=True) is noop\n"
+        "assert tracer.span('search.loop') is noop\n"
+        "assert tracer.annotation('search.drain') is noop\n"
+        "with tracer.span('search.idle', leaf=True):\n"
+        "    pass\n"
+        "assert tracer.snapshot() == {}\n"
+        "assert 'jax' not in sys.modules, 'jax imported'\n")
+    env = {k: v for k, v in os.environ.items() if k != "SPTPU_TRACE"}
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    p = subprocess.run([sys.executable, "-c", code], cwd=repo, env=env,
+                       capture_output=True, text=True, timeout=120)
+    assert p.returncode == 0, p.stderr[-2000:]

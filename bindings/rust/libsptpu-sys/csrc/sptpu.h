@@ -190,6 +190,14 @@ int      spt_get_labels(spt_store *st, const char *key, uint64_t *out);
 /* slot indices whose (labels & mask) == mask; returns count */
 int      spt_enumerate(spt_store *st, uint64_t mask, uint32_t *idx_out,
                        uint32_t max_out);
+/* slot indices of live keys that start with prefix ("" matches every
+ * key); returns count.  One pass over the slots, no key is copied: a key
+ * is compared in place, unvalidated, so resolve each index with
+ * spt_key_at and check the prefix again.  idx_out NULL counts only.
+ * live_out (optional) receives the live keys the pass went over. */
+int      spt_enumerate_prefix(spt_store *st, const char *prefix,
+                              uint32_t *idx_out, uint32_t max_out,
+                              uint32_t *live_out);
 
 /* ---- signal arena (64 cache-line counters, pub/sub) -------------------- */
 int      spt_watch_register(spt_store *st, const char *key, uint32_t group);

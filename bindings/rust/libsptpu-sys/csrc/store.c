@@ -803,6 +803,33 @@ int spt_enumerate(spt_store *st, uint64_t mask, uint32_t *idx_out,
   return (int)out;
 }
 
+int spt_enumerate_prefix(spt_store *st, const char *prefix,
+                         uint32_t *idx_out, uint32_t max_out,
+                         uint32_t *live_out) {
+  if (!st || !prefix) return -EINVAL;
+  size_t plen = strlen(prefix);
+  uint32_t n = st->h->nslots, out = 0, live = 0;
+  for (uint32_t i = 0; i < n; i++) {
+    const spt_slot *s = &st->slots[i];
+    uint64_t sh = atomic_load_explicit(&s->hash, memory_order_acquire);
+    if (sh <= SPT_TOMBSTONE) continue;
+    live++;
+    /* unvalidated read of a key a writer may be replacing: the caller
+     * resolves each index through spt_key_at and re-checks the prefix.
+     * The first byte shares the slot's first cache line with hash. */
+    if (plen >= SPT_KEY_MAX ||
+        (plen && (s->key[0] != prefix[0] || memcmp(s->key, prefix, plen))))
+      continue;
+    if (idx_out) {
+      if (out >= max_out) break;
+      idx_out[out] = i;
+    }
+    out++;
+  }
+  if (live_out) *live_out = live;
+  return (int)out;
+}
+
 /* ------------------------------------------------------------ mop / purge */
 
 int spt_set_mop(spt_store *st, uint32_t mode) {

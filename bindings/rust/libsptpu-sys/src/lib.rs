@@ -200,6 +200,9 @@ extern "C" {
                           out: *mut u64) -> c_int;
     pub fn spt_enumerate(st: *mut spt_store, mask: u64, idx_out: *mut u32,
                          max_out: u32) -> c_int;
+    pub fn spt_enumerate_prefix(st: *mut spt_store, prefix: *const c_char,
+                                idx_out: *mut u32, max_out: u32,
+                                live_out: *mut u32) -> c_int;
 
     // signal arena
     pub fn spt_watch_register(st: *mut spt_store, key: *const c_char,

@@ -145,6 +145,8 @@ def _declare(lib: C.CDLL) -> None:
         "spt_label_andnot": (i32, [P, cs, u64]),
         "spt_get_labels": (i32, [P, cs, C.POINTER(u64)]),
         "spt_enumerate": (i32, [P, u64, C.POINTER(u32), u32]),
+        "spt_enumerate_prefix": (i32, [P, cs, C.POINTER(u32), u32,
+                                       C.POINTER(u32)]),
         "spt_watch_register": (i32, [P, cs, u32]),
         "spt_watch_unregister": (i32, [P, cs, u32]),
         "spt_watch_label_register": (i32, [P, u32, u32]),

@@ -21,8 +21,7 @@ _NAME_RE = re.compile(r"^[A-Za-z0-9_.-]{1,64}$")
 
 def _script_names(store) -> list[str]:
     pfx = P.SCRIPT_STORE_PREFIX
-    return sorted(k[len(pfx):] for k in store.list()
-                  if k.startswith(pfx))
+    return sorted(k[len(pfx):] for k in store.keys_with_prefix(pfx))
 
 
 @command("pipeline",

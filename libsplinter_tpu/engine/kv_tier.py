@@ -256,9 +256,8 @@ class TierPersist:
         st = self.store
         prefix_keep = f"__tier_e{keep}."
         try:
-            for key in st.list():
-                if key.startswith("__tier_e") \
-                        and not key.startswith(prefix_keep):
+            for key in st.keys_with_prefix("__tier_e"):
+                if not key.startswith(prefix_keep):
                     try:
                         st.unset(key)
                     except (KeyError, OSError):

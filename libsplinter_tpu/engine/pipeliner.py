@@ -787,9 +787,7 @@ class Pipeliner:
         now = time.time() if now is None else now
         pfx = P.SCRIPT_RESULT_PREFIX
         reaped = 0
-        for key in st.list():
-            if not key.startswith(pfx):
-                continue
+        for key in st.keys_with_prefix(pfx):
             try:
                 idx = int(key[len(pfx):])
             except ValueError:

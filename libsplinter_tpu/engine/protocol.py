@@ -701,8 +701,7 @@ def read_scale_targets(store) -> dict[str, dict]:
     read-modify-write to race), applied by the supervisor."""
     out: dict[str, dict] = {}
     try:
-        keys = [k for k in store.list()
-                if k.startswith(SCALE_TARGET_PREFIX)]
+        keys = store.keys_with_prefix(SCALE_TARGET_PREFIX)
     except (KeyError, OSError):
         return out
     for k in keys:

@@ -134,7 +134,8 @@ class SearcherStats:
     req_failures: int = 0        # requests failed with error records
     drain_faults: int = 0        # whole drains failed by the firewall
     results_reaped: int = 0      # orphaned __sr_ rows retired
-    sweep_keys: int = 0          # keys visited by the two sweeps' walks
+    sweep_keys: int = 0          # live keys the two sweeps' scans passed
+    sweep_rows: int = 0          # __sr_/__sp_ rows the sweeps opened
     # -- multi-tenant QoS (engine/qos.py) ----------------------------
     deadline_expired: int = 0    # fast-failed: client deadline passed
     shed: int = 0                # typed overloaded + retry_after_ms
@@ -887,7 +888,8 @@ class Searcher:
         epoch moved past the one the result was committed under (a
         NEW request owns the slot; its service will write a fresh
         row), or it outlived ttl_s.  Runs on the heartbeat cadence
-        (O(nslots) key walk — never on the wake path); a restarted
+        (one native prefix scan of the slots, never on the wake path;
+        Python opens only the __sr_ rows); a restarted
         daemon's first sweep reclaims the previous generation's
         leftovers.  Returns the reaped count."""
         fault("searcher.sweep")
@@ -895,11 +897,10 @@ class Searcher:
         now = time.time() if now is None else now
         pfx = P.SEARCH_RESULT_PREFIX
         reaped = 0
-        keys = st.list()
-        self.stats.sweep_keys += len(keys)
+        keys, scanned = st.scan_prefix(pfx)
+        self.stats.sweep_keys += scanned
+        self.stats.sweep_rows += len(keys)
         for key in keys:
-            if not key.startswith(pfx):
-                continue
             try:
                 idx = int(key[len(pfx):])
             except ValueError:
@@ -932,7 +933,7 @@ class Searcher:
                      now: float | None = None) -> int:
         """The pending-span staging rows share the result rows' reaper
         cadence (orphans: raced rewrites, crashed drains nobody
-        re-ran): a second O(nslots) key walk, right after the first.
+        re-ran): a second prefix scan, right after the first.
         It is the tracing plane's own housekeeping and is paid whether
         or not any request was ever stamped."""
         return sweep_span_stages(self.store, ttl_s=ttl_s, now=now,

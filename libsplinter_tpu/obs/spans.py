@@ -409,17 +409,17 @@ def sweep_span_stages(store, *, ttl_s: float = STAGE_TTL_S,
     epoch moved past the staged one (raced rewrite — the new occupant
     stages its own span), or TTL expired (a crashed chain nobody ever
     re-drained).  Heartbeat-cadence work, mirroring the `__sr_`
-    reaper; returns the reaped count.  `stats`, where given, has a
-    `sweep_keys` counter that grows by the keys the walk visits."""
+    reaper; returns the reaped count.  `stats`, where given, has the
+    searcher's counters: `sweep_keys` grows by the live keys the
+    native prefix scan passed, `sweep_rows` by the rows it matched."""
     now = time.time() if now is None else now
     pfx = P.SPAN_STAGE_PREFIX
     reaped = 0
-    keys = store.list()
+    keys, scanned = store.scan_prefix(pfx)
     if stats is not None:
-        stats.sweep_keys += len(keys)
+        stats.sweep_keys += scanned
+        stats.sweep_rows += len(keys)
     for key in keys:
-        if not key.startswith(pfx):
-            continue
         try:
             idx = int(key[len(pfx):])
         except ValueError:

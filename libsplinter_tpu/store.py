@@ -267,12 +267,38 @@ class Store:
         count = _ck(self._lib.spt_list(self._h, buf, n))
         # ONE copy out of the ctypes buffer: every `.raw` access
         # copies all n * KEY_MAX bytes, which made this walk quadratic
-        # (minutes at 262,144 slots — the search daemon's first
-        # heartbeat sits behind it, sweep_results)
+        # (minutes at 262,144 slots).  Still one copy + one str per
+        # key: a caller that wants a few keys uses keys_with_prefix
         raw = buf.raw
         km = N.KEY_MAX
         return [raw[i * km:(i + 1) * km].split(b"\0", 1)[0]
                 .decode(errors="replace") for i in range(count)]
+
+    def scan_prefix(self, prefix: str) -> tuple[list[str], int]:
+        """(keys that start with `prefix`, live keys scanned).  The
+        native library compares every live slot's key in place and
+        hands back only the matching slot indices — what a
+        heartbeat-cadence sweep wants, where list() would copy and
+        decode every key of the store.  Each index is resolved through
+        the seqlock-validated key_at and the prefix checked again, so
+        a key a concurrent writer tore or replaced is dropped."""
+        n = self.nslots
+        out = (C.c_uint32 * n)()
+        live = C.c_uint32()
+        count = _ck(self._lib.spt_enumerate_prefix(
+            self._h, prefix.encode(), out, n, C.byref(live)))
+        keys = []
+        for idx in out[:count]:
+            try:
+                k = self.key_at(idx)
+            except OSError:           # still torn after the retries
+                continue
+            if k is not None and k.startswith(prefix):
+                keys.append(k)
+        return keys, live.value
+
+    def keys_with_prefix(self, prefix: str) -> list[str]:
+        return self.scan_prefix(prefix)[0]
 
     def __contains__(self, key: str) -> bool:
         return self._lib.spt_find_index(self._h, key.encode()) >= 0

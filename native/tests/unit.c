@@ -130,6 +130,38 @@ static void suite(const char *name, uint32_t flags) {
   TEST(spt_label_andnot(st, "lab", 0x4) == 0 &&
        spt_enumerate(st, 0x4, hits, 64) == 0, "label clear");
 
+  /* ---- prefix enumeration ---- */
+  uint32_t live = 0, all = (uint32_t)spt_list(st, NULL, 0);
+  spt_set(st, "__sr_7", "r", 1);
+  spt_set(st, "__sr_70", "r", 1);
+  spt_set(st, "__sp_7", "p", 1);
+  spt_set(st, "__sr_", "whole", 5);
+  all += 4;
+  TEST(spt_enumerate_prefix(st, "__sr_", hits, 64, &live) == 3 &&
+       live == all, "prefix enumerate: three rows, every live key passed");
+  int seen7 = 0;
+  for (int i = 0; i < 3; i++)
+    seen7 += hits[i] == (uint32_t)spt_find_index(st, "__sr_7");
+  TEST(seen7 == 1, "prefix enumerate returns slot indices");
+  TEST(spt_enumerate_prefix(st, "__sr_7", NULL, 0, NULL) == 2,
+       "prefix enumerate counts without a buffer");
+  TEST(spt_enumerate_prefix(st, "__sr_", hits, 2, NULL) == 2,
+       "prefix enumerate stops at max_out");
+  TEST(spt_enumerate_prefix(st, "", NULL, 0, &live) == (int)all &&
+       live == all, "empty prefix matches every live key");
+  spt_unset(st, "__sr_7");
+  TEST(spt_enumerate_prefix(st, "__sr_7", hits, 64, &live) == 1 &&
+       live == all - 1 &&
+       hits[0] == (uint32_t)spt_find_index(st, "__sr_70"),
+       "prefix enumerate skips a tombstone");
+  char toolong[SPT_KEY_MAX + 8];
+  memset(toolong, 'k', sizeof toolong - 1);
+  toolong[sizeof toolong - 1] = '\0';
+  TEST(spt_enumerate_prefix(st, toolong, hits, 64, NULL) == 0 &&
+       spt_enumerate_prefix(st, NULL, hits, 64, NULL) == -EINVAL,
+       "prefix longer than a key matches nothing; NULL is EINVAL");
+  spt_unset(st, "__sr_70"); spt_unset(st, "__sp_7"); spt_unset(st, "__sr_");
+
   /* ---- signal arena + bump ---- */
   uint64_t c0 = spt_signal_count(st, 7);
   TEST(spt_watch_register(st, "lab", 7) == 0, "watch register");

@@ -304,7 +304,7 @@ reads both tuples):
 | `search.score` | host wall of the service outside select and commit; its self time, score − refresh − mask, is batching and dispatch | no |
 | `search.refresh` / `search.mask` | `lane.refresh()` (a pass that does a full upload is the staging of the lane); the candidate-mask builds (all-slot epoch snapshot) | yes |
 | `search.select` / `search.commit` | blocked in `jax.device_get`; result rows + label clears | yes |
-| `search.sweep_results` / `search.sweep_stages` | the two heartbeat-cadence key walks (`sweep_keys` counts the keys they visit, `results_reaped` what the first retires); the second is the span plane's own housekeeping and runs with tracing off too | yes |
+| `search.sweep_results` / `search.sweep_stages` | the two heartbeat-cadence sweeps: each finds its `__sr_` / `__sp_` rows with one native prefix scan of the slots (`spt_enumerate_prefix`) and opens only those (`sweep_keys` counts the live keys the scans pass, `sweep_rows` the rows they match, `results_reaped` what the first retires); the second is the span plane's own housekeeping and runs with tracing off too | yes |
 | `search.publish` | `publish_stats`: serialisation, `DEVTIME.flush`, `spans.flush` | yes |
 
 A beat's publish runs at the head of the next pass, so a heartbeat

@@ -418,6 +418,29 @@ class TestLaneProtocol:
         assert pl.sweep_results() >= 1
         assert _result(store, "orphan") is None
 
+    def test_sweep_finds_rows_by_prefix_scan(self, store, monkeypatch):
+        """The reaper names its __pr_ rows (and the span plane's
+        __sp_ rows) with the native prefix scan: Store.list() is
+        never called, a live row stays, an orphan goes."""
+        pl = Pipeliner(store)
+        pl.attach()
+        for key in ("orphan", "live"):
+            _submit(store, key, script="return 1")
+        assert _pump_until(
+            pl, lambda: _done(store, "orphan") and _done(store, "live"),
+            5.0)
+        rows = {k: P.script_result_key(store.find_index(k))
+                for k in ("orphan", "live")}
+        store.set("orphan", "something else")
+        monkeypatch.setattr(
+            type(store), "list",
+            lambda self: pytest.fail("the sweep walked every key"))
+        assert pl.sweep_results() == 1
+        assert pl.stats.results_reaped == 1
+        assert rows["orphan"] not in store and rows["live"] in store
+        assert pl.sweep_results(now=time.time() + 600) == 1
+        assert rows["live"] not in store
+
     def test_tenant_rides_verbs(self, store):
         pl = Pipeliner(store)
         pl.attach()

@@ -330,6 +330,16 @@ def test_raced_rewrite_not_committed(store):
     assert sr.run_once() == 1                  # retried clean
 
 
+def _wait_heartbeat(store, timeout_s: float = 10.0) -> None:
+    """Block until the daemon thread has published once: it is in
+    its loop, so the next request reaches it by signal and the CLI
+    finds it alive — not by whichever thread the scheduler ran first."""
+    deadline = time.monotonic() + timeout_s
+    while P.KEY_SEARCH_STATS not in store:
+        assert time.monotonic() < deadline, "no heartbeat"
+        time.sleep(0.005)
+
+
 def test_submit_search_round_trip(store):
     """Client helper against a live daemon thread: label, wait, read."""
     rng = np.random.default_rng(10)
@@ -341,6 +351,7 @@ def test_submit_search_round_trip(store):
                                  "idle_timeout_ms": 20})
     t.start()
     try:
+        _wait_heartbeat(store)
         key = "__sqtmp_cli"
         store.set(key, "placeholder")
         store.vec_set(key, vecs[3])
@@ -382,6 +393,7 @@ def test_cli_search_dispatches_to_daemon(store, monkeypatch):
     t = threading.Thread(target=daemons)
     t.start()
     try:
+        _wait_heartbeat(store)
         ses = Session(store.name)
         fn, _, _ = COMMANDS["search"]
         buf = io.StringIO()
@@ -462,6 +474,86 @@ def test_sweep_fault_site_contained(store):
         assert faults.stats()["searcher.sweep"]["fired"] == 1
     finally:
         faults.disarm()
+
+
+def _no_list(monkeypatch):
+    monkeypatch.setattr(
+        Store, "list",
+        lambda self: pytest.fail("a sweep walked every key: list()"))
+
+
+def test_sweeps_find_their_rows_by_prefix_scan(store, monkeypatch):
+    """Both heartbeat sweeps name their candidate rows with the
+    native prefix scan and never call Store.list(); they retire the
+    rows the predicate names and no others.  `sweep_keys` grows by
+    the live keys each scan went over, `sweep_rows` by the rows the
+    sweeps opened."""
+    from libsplinter_tpu.obs import spans as S
+
+    rng = np.random.default_rng(25)
+    _fill_docs(store, 12, rng)
+    sr = Searcher(store)
+    sr.attach()
+    names = ("__sqtmp_a", "__sqtmp_b", "__sqtmp_c")
+    for name in names:
+        _request(store, name, rng.normal(size=store.vec_dim)
+                 .astype(np.float32))
+    assert sr.run_once() == 3
+    result_row = {n: P.search_result_key(store.find_index(n))
+                  for n in names}
+    stage_row = {}
+    for key in ("st_a", "st_b"):
+        store.set(key, "req")
+        P.stamp_trace(store, key)
+        idx = store.find_index(key)
+        w = S.SpanWriter(store, "searcher", staged=True)
+        assert w.begin(idx, store.epoch_at(idx)) is not None
+        stage_row[key] = P.span_stage_key(idx)
+    assert all(r in store for r in (*result_row.values(),
+                                    *stage_row.values()))
+    # one request slot and one staged slot are rewritten: epoch moved
+    store.set("__sqtmp_b", "a new request owns this slot")
+    store.set("st_b", "rewritten")
+    live = store.header().used_slots
+    _no_list(monkeypatch)
+
+    assert sr.sweep_results() == 1
+    assert sr.stats.results_reaped == 1
+    assert (sr.stats.sweep_keys, sr.stats.sweep_rows) == (live, 3)
+    assert result_row["__sqtmp_b"] not in store
+    assert result_row["__sqtmp_a"] in store
+    assert result_row["__sqtmp_c"] in store
+
+    assert sr.sweep_stages() == 1
+    assert (sr.stats.sweep_keys, sr.stats.sweep_rows) == (2 * live - 1, 5)
+    assert stage_row["st_b"] not in store and stage_row["st_a"] in store
+
+    # TTL: ten minutes on, every leftover of both kinds goes
+    later = time.time() + 600
+    assert sr.sweep_results(now=later) == 2
+    assert sr.sweep_stages(now=later) == 1
+    assert not any(r in store for r in (*result_row.values(),
+                                        *stage_row.values()))
+    assert sr.stats.sweep_rows == 5 + 2 + 1
+
+
+def test_first_sweep_reclaims_a_predecessors_rows(store, monkeypatch):
+    """A restarted daemon's first sweep retires what the previous
+    generation left: rows whose request slot is gone, and rows in the
+    pre-TTL format nobody owns."""
+    store.set("req", "placeholder")
+    idx = store.find_index("req")
+    store.set(P.search_result_key(idx), json.dumps({"keys": []}))
+    store.set(P.search_result_key(store.nslots + 5), json.dumps(
+        {"e": 2, "ts": time.time()}))
+    store.set("__sr_notanindex", "{}")
+    _no_list(monkeypatch)
+    sr = Searcher(store)
+    sr.attach()
+    assert sr.sweep_results() == 2
+    assert sr.stats.sweep_rows == 3
+    assert P.search_result_key(idx) not in store
+    assert "__sr_notanindex" in store      # not a result row: left
 
 
 def test_result_ttl_sweep_reaps_orphans(store):
@@ -661,13 +753,15 @@ def test_run_loop_is_fully_accounted(traced):
             "publish"))
         assert loop > 1500                       # ~2 s of passes
         assert abs(loop - children) <= 0.05 * loop, (loop, children)
-        # two beats at least, each with both walks; sweep_keys counts
-        # the keys of both
+        # two beats at least, each with both scans: sweep_keys counts
+        # the live keys both went over, sweep_rows only the __sr_ /
+        # __sp_ rows they opened (six answers, each consumed at once)
         beats = spans["search.sweep_results"]["n"]
         assert beats >= 2
         assert spans["search.sweep_stages"]["n"] == beats
         assert spans["search.publish"]["n"] >= beats - 1
         assert snap["sweep_keys"] >= 2 * beats * 16
+        assert snap["sweep_rows"] <= 2 * beats * 6 < snap["sweep_keys"]
         # stage spans count serviced drains, as before: one record per
         # drain that had requests, idle drains only in drain_cycle
         served = spans["search.score"]["n"]

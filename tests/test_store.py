@@ -114,6 +114,99 @@ def test_list(store):
     assert set(iter(store)) >= keys
 
 
+def _fill_tombstoned(st):
+    for i in range(40):
+        st.set(f"__sr_{i}", b"r")
+        st.set(f"doc/{i}", b"d")
+    for i in range(0, 40, 3):
+        st.unset(f"__sr_{i}")          # tombstones inside the runs
+    for i in range(0, 40, 6):
+        st.set(f"__sr_{i}", b"again")  # re-set: a slot reused
+
+
+def _fill_whole_key(st):
+    for k in ("__sp_", "__sp_7", "__sp", "__sq_7", "_", "x__sp_7"):
+        st.set(k, b"v")
+
+
+def _fill_long_key(st):
+    st.set("k" * 127, b"longest")
+    st.set("k" * 126 + "j", b"differs in the last byte")
+    st.set("k" * 64, b"half")
+
+
+def _fill_loaded(st):
+    n = int(st.nslots * 0.91)
+    for i in range(n):
+        st.set(f"vec/{i}", b"")
+    for i in range(0, n, 4099):
+        st.set(f"__sr_{i}", b"r")
+    for i in range(0, n, 8191):
+        st.unset(f"vec/{i}")
+
+
+_PREFIX_CASES = {
+    # case: (nslots, fill, prefixes)
+    "tombstoned_and_reset": (256, _fill_tombstoned,
+                             ("__sr_", "__sr_1", "doc/", "doc/3")),
+    "empty_prefix": (256, _fill_tombstoned, ("",)),
+    "no_key_that_long": (256, _fill_tombstoned,
+                         ("__sr_" + "9" * 40, "q" * 127, "q" * 300)),
+    "prefix_is_a_whole_key": (64, _fill_whole_key,
+                              ("__sp_", "__sp_7", "__sp", "_", "x")),
+    "key_of_127_bytes": (64, _fill_long_key,
+                         ("k" * 127, "k" * 126, "k" * 65, "k")),
+    "empty_store": (64, lambda st: None, ("", "__sr_")),
+    "load_over_90pct_262144_slots": (262_144, _fill_loaded,
+                                     ("__sr_", "vec/26214", "__sp_")),
+}
+
+
+@pytest.mark.parametrize("case", _PREFIX_CASES)
+def test_keys_with_prefix_matches_list_filter(case):
+    """The native prefix scan names exactly the keys a list() walk
+    filtered with startswith would, in the same (slot) order, and
+    counts every live key it went over."""
+    nslots, fill, prefixes = _PREFIX_CASES[case]
+    name = f"/spt-pfx-{os.getpid()}-{uuid.uuid4().hex[:6]}"
+    st = Store.create(name, nslots=nslots, max_val=16, vec_dim=0)
+    try:
+        fill(st)
+        every = st.list()
+        if nslots > 100_000:
+            assert len(every) > 0.9 * nslots
+        for pfx in prefixes:
+            want = [k for k in every if k.startswith(pfx)]
+            assert st.keys_with_prefix(pfx) == want, pfx
+            keys, scanned = st.scan_prefix(pfx)
+            assert keys == want and scanned == len(every), pfx
+    finally:
+        st.close()
+        Store.unlink(name)
+
+
+@pytest.mark.parametrize("then", ["gone", "replaced"])
+def test_keys_with_prefix_drops_a_slot_that_changed_under_the_scan(
+        store, monkeypatch, then):
+    """The scan compares keys in place, unvalidated; each index is
+    resolved through key_at and the prefix checked again, so a slot
+    whose key went or was replaced between the two is dropped, as the
+    get() -> KeyError path dropped it after a list() walk."""
+    store.set("__sr_1", b"r")
+    store.set("__sr_2", b"r")
+    raced = store.find_index("__sr_1")
+    real = store.key_at
+
+    def key_at(idx):
+        if idx != raced:
+            return real(idx)
+        store.unset("__sr_1")         # the writer got there first
+        return None if then == "gone" else "doc/7"
+
+    monkeypatch.setattr(store, "key_at", key_at)
+    assert store.keys_with_prefix("__sr_") == ["__sr_2"]
+
+
 def test_contains(store):
     store.set("here", b"x")
     assert "here" in store

@@ -136,6 +136,16 @@ class CompleterStats:
     # deadline expired at a chunk edge, retired with the typed
     # DEADLINE_EXPIRED record and their pages freed immediately
     killed_mid_decode: int = 0
+    # -- the continuous lane's work counters: decode steps dispatched
+    # and the live rows they carried (rows a step = their ratio),
+    # prompt tokens admitted and how many of them a prefix-cache hit
+    # mapped instead of prefilling, and (expert models) the
+    # (token, expert) slots decode steps routed to experts held here
+    decode_steps: int = 0
+    decode_rows: int = 0
+    prompt_tokens: int = 0
+    prefix_tokens: int = 0
+    expert_slots: int = 0
 
 
 class Completer:
@@ -176,7 +186,8 @@ class Completer:
                  prefix_default_quota: int | None = None,
                  kv_tier_pages: int = 0,
                  kv_tier_persist: str | None = None,
-                 replica: int = 0):
+                 replica: int = 0,
+                 audit: dict | None = None):
         self.store = store
         # elastic lanes (protocol.StripeView): replica r drains only
         # its own slot-index stripe; stranded-SERVICING reclaim is
@@ -307,6 +318,22 @@ class Completer:
         # edges + heartbeats, reset only at attach (generation scope)
         self._pages_used_peak = 0
         self._pool_mb_peak = 0.0
+        # one-shot start-up phases (ms), filled by main(); per-expert
+        # slot totals of the decode steps (expert models only)
+        self.startup_ms: dict[str, float] = {}
+        self._expert_totals = None
+        # audit records (engine/audit.py): for a sample of the
+        # continuous lane's requests, the prompt ids, the ids generated
+        # and the logits behind the first and the last of them — what
+        # a plain reference needs to hold the served path to account
+        self.audit = None
+        if audit:
+            if not getattr(model, "audit_supported", False):
+                raise ValueError(
+                    "audit records need a model whose decode chunks "
+                    "keep a row's logits (audit_supported)")
+            from .audit import AuditLog
+            self.audit = AuditLog(**audit)
         self.generation = 0            # bumped at attach (restart marker)
         self._bid = -1
         self._running = False
@@ -610,6 +637,21 @@ class Completer:
             return None
         fit = [b for b in usable if b + self.max_new <= m.cfg.max_len]
         return fit[-1] if fit else usable[-1]
+
+    def _paged_budget(self) -> int:
+        """The PAGED lane's prompt budget: the window less max_new.  A
+        paged row keeps its full prompt (paged_prefill_row has a
+        bucket for every length inside the window) and ends at its
+        own window edge, so nothing ties it to a dense padding
+        bucket; a long prompt keeps its HEAD and can hit a shared
+        prefix."""
+        m = self._model
+        budget = m.cfg.max_len - self.max_new
+        return budget if budget >= 1 else m.cfg.max_len // 2
+
+    def _clip_paged(self, ids: list[int]) -> list[int]:
+        budget = self._paged_budget()
+        return ids[-budget:] if len(ids) > budget else ids
 
     def _model_generate(self, prompt: str) -> Iterator[bytes]:
         m, tok = self._model, self._tok
@@ -980,12 +1022,13 @@ class Completer:
 
     def _paged_ok(self) -> bool:
         """True when the model can serve the block-paged continuous
-        lane (paged_supported) with a usable bucket geometry."""
+        lane (paged_supported).  Any window does: a paged row keeps
+        its prompt up to the window less max_new (_paged_budget) and
+        every model has a prefill bucket that reaches it."""
         m = getattr(self, "_model", None)
         return (m is not None
                 and getattr(m, "paged_supported", False)
-                and self.paged_batch_cap >= 2
-                and self._batched_budget() is not None)
+                and self.paged_batch_cap >= 2)
 
     def _ensure_paged_cache(self):
         if self._paged_cache is None:
@@ -1055,7 +1098,7 @@ class Completer:
         cache = self._ensure_paged_cache()
         self._model.warmup_paged(cache,
                                  chunk=max(1, self.flush_tokens),
-                                 max_prompt=self._batched_budget())
+                                 max_prompt=self._paged_budget())
         if self.kv_tier is not None:
             # spill/readmit ride the handoff gather/scatter programs —
             # warm both so tier traffic never compiles post-warmup
@@ -1244,8 +1287,7 @@ class Completer:
                 peek = self._read_rendered(idx)
                 if peek is None:
                     continue
-                ids = self._clip_context(tok_izer.encode(peek[1]),
-                                         bucketed=True)
+                ids = self._clip_paged(tok_izer.encode(peek[1]))
                 # radix-tree walk BEFORE the page math: every hit
                 # page is a page the pool does not need free — the
                 # admission reservation (and the backpressure memo)
@@ -1371,6 +1413,8 @@ class Completer:
                 # the uncached tail AFTER tier readmission: a partial
                 # readmit lengthens the suffix the prefill must cover
                 suffix = ids[match:]
+                self.stats.prompt_tokens += len(ids)
+                self.stats.prefix_tokens += match
                 if not cache.ensure(r, reserve):
                     # defensive: the pinned-aware gate above makes
                     # this unreachable, but a seated row WITHOUT its
@@ -1414,6 +1458,10 @@ class Completer:
                         if ins and tenant:
                             self.tenants.bump(
                                 tenant, "prefix_cached_pages", ins)
+                    if self.audit is not None and self.audit.wants():
+                        rows[r]["audit"] = self.audit.open(
+                            key, ids, match, logits)
+                        m.audit_row = r
                     # splint: ignore[SPL201] reason=the documented host "sample" stage (CONT_INFER_STAGES): one scalar draw per JOIN so the row's first token emits before the next chunk, not per decode step
                     t = int(m.sample(logits))
                     if traced:
@@ -1443,6 +1491,8 @@ class Completer:
         def emit(r: int, t: int) -> None:
             """One sampled token for row r: eos / flush / budget."""
             row = rows[r]
+            if row.get("audit") is not None:
+                row["audit"].tokens.append(t)
             if t == tok_izer.eos_id:
                 finish(r)
                 return
@@ -1467,6 +1517,9 @@ class Completer:
         def finish(r: int, truncated: bool = False,
                    vanished: bool = False) -> None:
             row = rows[r]
+            if row.get("audit") is not None:
+                self.audit.close(row["audit"])
+                m.audit_row = -1
             if row["pending"] and not truncated and not vanished:
                 res = self._flush(row["key"], row["pending"])
                 truncated = res == "full"
@@ -1517,6 +1570,9 @@ class Completer:
                 except (KeyError, OSError):
                     pass
                 self.spans.commit(span_rec, status=P.ERR_DEADLINE)
+                if row.get("audit") is not None:
+                    self.audit.drop()
+                    m.audit_row = -1
                 self._lane_row_done(row)
                 cache.free_row(r)     # pool pages back NOW
                 rows[r] = None
@@ -1554,10 +1610,25 @@ class Completer:
                     if row is not None and row["serial"] == ser \
                             and row.get("spans") is not None:
                         row["spans"].append(["collect", round(ms, 3)])
+            slots = getattr(pend, "slots", None)
+            if slots is not None:
+                # fetched with the step's tokens: block() above was the
+                # wait, this copies (count,) integers
+                slots = np.asarray(slots)
+                # splint: ignore[SPL201] reason=a NumPy sum of the counts copied above, after the chunk's own block(); no device scalar is fetched
+                self.stats.expert_slots += int(slots.sum())
+                self._expert_totals = slots.astype(np.int64) + (
+                    0 if self._expert_totals is None
+                    else self._expert_totals)
             for c in range(pend.n):
                 for r, ser in live:
                     row = rows[r]
                     if row is not None and row["serial"] == ser:
+                        if row.get("audit") is not None:
+                            # the logits this step sampled from stay
+                            # on the device; the record fetches them
+                            # when the row finishes
+                            row["audit"].steps.append((pend.audit, c))
                         emit(r, int(blk[r, c]))
 
         def abort_all(reason: str) -> None:
@@ -1702,6 +1773,8 @@ class Completer:
                                     ["decode", round(ms, 3)])
                     carry = pend.last
                     fresh[:] = -1
+                    self.stats.decode_steps += step
+                    self.stats.decode_rows += step * len(live)
                     for r, _ in live:
                         rows[r]["disp_left"] -= step
                     window.append((pend, live))
@@ -2077,6 +2150,15 @@ class Completer:
                     int(mesh.shape["tp"]))
                 if shards:
                     payload["pages_shard"] = shards
+        if self._expert_totals is not None:
+            payload["expert_totals"] = [int(x) for x in
+                                        self._expert_totals]
+        if self.startup_ms:
+            payload["startup_ms"] = {
+                **{k: round(v, 1) for k, v in self.startup_ms.items()},
+                "total": round(sum(self.startup_ms.values()), 1)}
+        if self.audit is not None:
+            payload["audit_records"] = self.audit.written
         if faults.armed():
             payload["faults"] = faults.stats()
         payload["compile_events"] = DEVTIME.compile_events(self.LANE)
@@ -2156,6 +2238,37 @@ class Completer:
         self._running = False
 
 
+def refuse_for_model(args, model_cls) -> None:
+    """Stop main() with ONE typed message when an option is set that
+    `model_cls` declares it cannot serve (its `refused_options`: name
+    -> reason) — before any weight is made or request read, never by
+    falling through to another cache or a wrong page layout."""
+    asked = {
+        "kv_dtype": args.kv_dtype in ("int8", "int4")
+        and f"--kv-dtype {args.kv_dtype}",
+        "kv_tier_pages": (args.kv_tier_pages or args.kv_tier_persist)
+        and "--kv-tier-pages/--kv-tier-persist",
+        "phase": args.phase != "unified" and f"--phase {args.phase}",
+        "tp": args.tp > 1 and f"--tp {args.tp}",
+        "ep": args.ep > 1 and f"--ep {args.ep}",
+        "draft": (args.draft_layers or args.draft_weights)
+        and "--draft-layers/--draft-weights",
+        "weights": args.weights and f"--weights {args.weights}",
+        "weight_quant": (args.quantized or args.weights_int8)
+        and "--quantized/--weights-int8",
+    }
+    reasons = getattr(model_cls, "refused_options", {})
+    for name, flag in asked.items():
+        if flag and name in reasons:
+            raise SystemExit(
+                f"unsupported_option: {flag} cannot be served with "
+                f"--model ({model_cls.__name__}): {reasons[name]}")
+    if not (args.continuous or args.phase != "unified"):
+        raise SystemExit(
+            "unsupported_option: --model serves the paged lane only; "
+            "add --continuous")
+
+
 def main(argv: list[str] | None = None) -> int:
     """CLI entry: python -m libsplinter_tpu.engine.completer --store NAME"""
     import argparse
@@ -2180,6 +2293,31 @@ def main(argv: list[str] | None = None) -> int:
                          "map assigns this replica; heartbeat "
                          "publishes replica-suffixed "
                          "(__completer_stats.rN)")
+    ap.add_argument("--model", default=None, metavar="FILE",
+                    help="a model DESCRIPTION file (JSON): a public "
+                         "architecture's config.json keys verbatim "
+                         "under 'architecture' plus this chip's "
+                         "'share' of the deployment (layers kept, "
+                         "experts held, vocabulary slice) and 'seed' "
+                         "— models/mla.load_model_description.  Serves "
+                         "the latent-attention (MLA) + shared-expert "
+                         "MoE block with seeded bfloat16 weights on "
+                         "the --continuous lane, --n-ctx as its "
+                         "window.  Refused with it, each with its "
+                         "reason, before the first request: "
+                         "--kv-dtype int8|int4, --kv-tier-pages, "
+                         "--phase prefill|decode, --tp/--ep > 1, "
+                         "--draft-layers/--draft-weights, --weights, "
+                         "--quantized/--weights-int8")
+    ap.add_argument("--audit-dir", default=None, metavar="DIR",
+                    help="write audit records there (engine/audit.py): "
+                         "for one --continuous admission in "
+                         "--audit-every, the prompt ids, the ids "
+                         "generated and the logits behind each of "
+                         "them, for a plain reference to check.  "
+                         "Needs a model whose decode chunks keep a "
+                         "row's logits (--model)")
+    ap.add_argument("--audit-every", type=int, default=16, metavar="N")
     ap.add_argument("--weights",
                     help="decoder checkpoint: .safetensors (HF llama "
                          "naming) or .gguf (llama.cpp naming; geometry "
@@ -2356,8 +2494,19 @@ def main(argv: list[str] | None = None) -> int:
     args = ap.parse_args(argv)
 
     logging.basicConfig(level=logging.INFO)
+    if args.model:
+        from ..models.mla import (LatentCompletionModel,
+                                  load_model_description)
+        refuse_for_model(args, LatentCompletionModel)
+    # one-shot start-up phases, ms -> the heartbeat's `startup_ms`
+    from .searcher import _Lap, _process_age_ms
+    boot: dict[str, float] = {}
+    age = _process_age_ms()
+    if age is not None:
+        boot["process"] = age
+    lap = _Lap()
+    import jax
     if os.environ.get("SPTPU_FORCE_CPU") == "1":
-        import jax
         jax.config.update("jax_platforms", "cpu")
     from ..utils.jaxplatform import apply_chip_pin, enable_compile_cache
     if os.environ.get("SPTPU_CHIP_PIN"):
@@ -2366,6 +2515,8 @@ def main(argv: list[str] | None = None) -> int:
         # runtime opens all of them — see apply_chip_pin)
         apply_chip_pin(os.environ["SPTPU_CHIP_PIN"])
     enable_compile_cache()
+    jax.devices()                 # open the device here, where it is timed
+    boot["jax"] = lap.lap()
     store = Store.open(args.store, persistent=args.persistent)
     from ..models import CompletionModel, DecoderConfig
     tokenizer = None
@@ -2417,6 +2568,15 @@ def main(argv: list[str] | None = None) -> int:
         # the supervisor sees the crash BEFORE any program compiles
         fault("completer.weight_quant")
         cfg = dataclasses.replace(cfg, weights_int8=True)
+    if args.model:
+        cfg, seed = load_model_description(args.model,
+                                           max_len=args.n_ctx)
+        log.info("model description %s: %d layers (%d dense), experts "
+                 "%d..+%d of %d, vocabulary %d..+%d, window %d",
+                 args.model, cfg.layers, cfg.dense_layers,
+                 cfg.experts_first, cfg.experts_held,
+                 cfg.n_routed_experts, cfg.vocab_first, cfg.vocab_size,
+                 cfg.max_len)
     mesh = None
     if args.tp > 1 or args.ep > 1:
         from ..parallel.mesh import make_mesh
@@ -2424,7 +2584,13 @@ def main(argv: list[str] | None = None) -> int:
         log.info("sharded decode: tp=%d ep=%d", args.tp, args.ep)
     mkw = dict(weights=args.weights, top_p=args.top_p, temp=args.temp)
     from ..models import MoeDecoderConfig, moe_completion_model
-    if isinstance(cfg, MoeDecoderConfig):
+    if args.model:
+        model = LatentCompletionModel(cfg, seed=seed, top_p=args.top_p,
+                                      temp=args.temp)
+        jax.block_until_ready(model.params)
+        log.info("resident weights: %.2f GB",
+                 model.resident_bytes() / 1e9)
+    elif isinstance(cfg, MoeDecoderConfig):
         # a Mixtral-family GGUF resolves to the MoE config; the same
         # daemon stack serves it (models/moe.py)
         log.info("MoE checkpoint: %d experts, top-%d routing",
@@ -2494,8 +2660,12 @@ def main(argv: list[str] | None = None) -> int:
                          f"{args.store}-kvtier"
                          if args.kv_tier_persist == "auto"
                          else args.kv_tier_persist),
-                     replica=args.replica)
+                     replica=args.replica,
+                     audit=args.audit_dir and {
+                         "dir": args.audit_dir,
+                         "every": args.audit_every})
     comp.attach()
+    boot["weights"] = lap.lap()   # the model's tree + Completer + attach
     continuous = args.continuous or args.phase != "unified"
     if args.warmup:
         t0 = time.monotonic()
@@ -2517,6 +2687,13 @@ def main(argv: list[str] | None = None) -> int:
             model.warmup(chunk=comp.flush_tokens, **kw)
         log.info("warmup compiled in %.1fs (.xla_cache persists "
                  "programs across restarts)", time.monotonic() - t0)
+        boot["warmup"] = lap.lap()
+        for ev in DEVTIME.pending_events():
+            # the compile ledger's own durations, by program (a cached
+            # program reads as the seconds its fetch took)
+            log.info("warmup: %s %s took %.1fs", ev["program"],
+                     ev["shapes_key"][-48:], ev["duration_ms"] / 1e3)
+    comp.startup_ms.update(boot)
     if args.oneshot:
         n = comp.run_once()
         log.info("oneshot serviced %d completions", n)

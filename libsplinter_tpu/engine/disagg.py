@@ -154,7 +154,7 @@ class PrefillLane(Completer):
         peek = self._read_rendered(idx)
         if peek is None:
             return False
-        ids = self._clip_context(tok.encode(peek[1]), bucketed=True)
+        ids = self._clip_paged(tok.encode(peek[1]))
         pc = getattr(cache, "prefix_cache", None)
         hit_bids: list[int] = []
         match = 0

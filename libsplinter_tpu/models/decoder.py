@@ -238,6 +238,32 @@ def _pool_zeros(shape, dtype, sharding):
     return _sharded_zeros_prog(tuple(shape), dtype, sharding)
 
 
+@dataclasses.dataclass(frozen=True)
+class PageLayout:
+    """What ONE layer keeps in the paged pool, as the model describes
+    it: named pools, each (n_blocks, *block shape), and the values a
+    token occupies across them.  The llama-geometry decoders keep a
+    key and a value pool of (kv_heads, page, head_dim); a latent-
+    attention model (models/mla.py) one pool of (page, latent width).
+    Allocation, tables, refcounts, COW bookkeeping and the prefix
+    tree never look inside a page, so they serve any layout."""
+    pools: tuple[tuple[str, tuple[int, ...]], ...]
+    token_values: int
+
+    @property
+    def key_value(self) -> bool:
+        return tuple(n for n, _ in self.pools) == ("k", "v")
+
+
+def kv_page_layout(cfg, page: int, packed: bool = False) -> PageLayout:
+    """The key/value pair of (kv_heads, page, head_dim) — head_dim/2
+    bytes when int4-packed."""
+    shape = (cfg.kv_heads, page,
+             cfg.head_dim // 2 if packed else cfg.head_dim)
+    return PageLayout((("k", shape), ("v", shape)),
+                      token_values=2 * cfg.kv_heads * cfg.head_dim)
+
+
 class PagedKVCache:
     """Block-paged KV pool for the continuous-batching decode lane.
 
@@ -249,6 +275,10 @@ class PagedKVCache:
 
         k_pool / v_pool: (n_blocks, kv_heads, page, head_dim)
 
+    — or whatever `cfg.page_layout(page)` describes (PageLayout: a
+    latent-attention model keeps ONE pool of (n_blocks, width, page));
+    `pools` holds a list of per-layer buffers for each pool of the
+    layout, `k_pools` / `v_pools` name the key/value pair's —
     plus a host-side (batch, pages_per_row) int32 block table and a
     (batch,) lengths vector.  Block 0 is the reserved TRASH block:
     never allocated, every unused table entry points at it, so dead
@@ -322,7 +352,13 @@ class PagedKVCache:
                 f"pool_pages {pool_pages} cannot hold even one full "
                 f"window ({self.pages_per_row} pages)")
         self.n_blocks = pool_pages + 1               # + the trash block
-        if sharding is not None and cfg.kv_heads % _tp_of(sharding):
+        # the per-layer page layout is the MODEL's to describe
+        # (cfg.page_layout); a config without one keeps the key/value
+        # pair this pool was written for
+        describe = getattr(cfg, "page_layout", None)
+        key_value = describe is None
+        if key_value and sharding is not None \
+                and cfg.kv_heads % _tp_of(sharding):
             raise ValueError(
                 f"the sharding's tp={_tp_of(sharding)} axis must "
                 f"divide kv_heads={cfg.kv_heads} (pools split on the "
@@ -335,17 +371,24 @@ class PagedKVCache:
         # layout) — tables, lengths, scales, and the whole host-side
         # allocator are identical to int8's
         self.packed = store_dtype == jnp.uint8
+        if not key_value and (self.quantized or sharding is not None):
+            raise ValueError(
+                "quantized (int8/int4) and kv-head-sharded pools need "
+                "the key/value page layout; this model describes "
+                f"{[n for n, _ in describe(page).pools]}")
         if self.packed and cfg.head_dim % 2:
             raise ValueError(
                 f"kv_dtype=\"int4\" packs two codes per byte along "
                 f"head_dim; head_dim={cfg.head_dim} must be even")
-        shape = (self.n_blocks, cfg.kv_heads, page,
-                 cfg.head_dim // 2 if self.packed else cfg.head_dim)
-        # distinct buffers per layer/side: the paged programs donate
+        self.layout = (kv_page_layout(cfg, page, self.packed)
+                       if key_value else describe(page))
+        # distinct buffers per layer/pool: the paged programs donate
         # the pools, and XLA rejects donating one buffer twice
-        zeros = _pool_zeros(shape, store_dtype, sharding)
-        self.k_pools = [zeros() for _ in range(cfg.layers)]
-        self.v_pools = [zeros() for _ in range(cfg.layers)]
+        self.pools = []
+        for _, block in self.layout.pools:
+            zeros = _pool_zeros((self.n_blocks, *block), store_dtype,
+                                sharding)
+            self.pools.append([zeros() for _ in range(cfg.layers)])
         if self.quantized:
             szeros = _pool_zeros((self.n_blocks, cfg.kv_heads),
                                  jnp.float32, scale_sharding)
@@ -368,6 +411,24 @@ class PagedKVCache:
         self.refcounts = np.zeros((self.n_blocks,), np.int64)
         self.prefix_cache = None
         self._ever_shared = False
+
+    # the key/value layout's two pools by name (every llama-geometry
+    # program reads and reassigns them)
+    @property
+    def k_pools(self):
+        return self.pools[0]
+
+    @k_pools.setter
+    def k_pools(self, pools):
+        self.pools[0] = pools
+
+    @property
+    def v_pools(self):
+        return self.pools[1]
+
+    @v_pools.setter
+    def v_pools(self, pools):
+        self.pools[1] = pools
 
     @property
     def free_pages(self) -> int:
@@ -469,17 +530,14 @@ class PagedKVCache:
             pc.stats.cow_copies += 1
 
     def kv_bytes_per_token(self) -> int:
-        """KV bytes one token occupies across every layer (k + v) —
-        the factor behind the prefix cache's bytes_saved gauge.
-        int4-packed pools store half a byte per value."""
+        """Cache bytes one token occupies across every layer and pool
+        of the layout — the factor behind the prefix cache's
+        bytes_saved gauge.  int4-packed pools store half a byte per
+        value."""
+        values = self.cfg.layers * self.layout.token_values
         if self.packed:
-            return (self.cfg.layers * 2 * self.cfg.kv_heads
-                    * (self.cfg.head_dim // 2))
-        itemsize = np.dtype(
-            "int8" if self.quantized else
-            "float32" if self.kv_dtype == "f32" else "uint16").itemsize
-        return (self.cfg.layers * 2 * self.cfg.kv_heads
-                * self.cfg.head_dim * itemsize)
+            return values // 2
+        return values * np.dtype(self.pools[0][0].dtype).itemsize
 
     @property
     def used_pages(self) -> int:
@@ -533,7 +591,7 @@ class PagedKVCache:
         addressable shards (on a single chip that is simply the full
         buffers; under tp each chip holds 1/tp — the per-shard view
         rides the completer's pages_shard section)."""
-        arrs = list(self.k_pools) + list(self.v_pools)
+        arrs = [a for pool in self.pools for a in pool]
         if self.quantized:
             arrs += list(self.k_scales) + list(self.v_scales)
         total = 0
@@ -1571,11 +1629,11 @@ class CompletionModel:
             args = (self.params, cache.k_pools, cache.v_pools)
             if cache.quantized:
                 args += (cache.k_scales, cache.v_scales)
-            # copies, not views: lengths is bumped in place right
-            # after this asynchronous dispatch (see
+            # host-side copies, not views: lengths is bumped in place
+            # right after this asynchronous dispatch (see
             # paged_decode_chunk_async)
-            args += (jnp.array(table),
-                     jnp.array(cache.lengths[row: row + 1]),
+            args += (jnp.asarray(np.array(table)),
+                     jnp.asarray(np.array(cache.lengths[row: row + 1])),
                      jnp.asarray(chunk), jnp.int32(n))
             out = self._paged_suffix_program(sb, cache.quantized)(*args)
             if cache.quantized:
@@ -2106,14 +2164,17 @@ class CompletionModel:
             fresh_mask = toks >= 0
             toks = np.maximum(toks, 0)
         self._rng, sub = jax.random.split(self._rng)
-        # COPIES of the host bookkeeping, never views: the dispatch is
-        # asynchronous and cache.lengths/tables are mutated in place
-        # right below (and by the next join).  jnp.asarray may alias a
-        # NumPy buffer without copying (the CPU backend does, for
-        # aligned buffers), so a queued chunk would read lengths that
-        # already count chunks dispatched after it
-        tables = jnp.array(cache.tables)
-        lengths = jnp.array(cache.lengths)
+        # HOST-SIDE COPIES of the bookkeeping, never views: the
+        # dispatch is asynchronous and cache.lengths/tables are mutated
+        # in place right below (and by the next join).  The CPU backend
+        # aliases an aligned NumPy buffer without copying, and
+        # jnp.array's own copy is a device program that queues BEHIND
+        # the work in flight — it then reads lengths that already
+        # count chunks dispatched after it (PR 26: a row-0 joiner
+        # under an in-flight chunk got another row's positions).  A
+        # fresh NumPy array nobody writes again may be aliased freely
+        tables = jnp.asarray(np.array(cache.tables))
+        lengths = jnp.asarray(np.array(cache.lengths))
         if cache.quantized:
             kp, vp, ks, vs, out, last = self._paged_chunk_program(
                 n, bp, True)(

@@ -1,0 +1,912 @@
+"""Latent-attention (MLA) + sparse shared-expert MoE decoder block, as
+the DeepSeek-V3 family of public configs describes it (openPangu-Ultra-
+MoE among them), served through the completion daemon's paged lane as
+ONE CHIP'S SHARE of an expert-parallel deployment.
+
+The layer, from the published keys (x: hidden; all matrices without
+bias; RMSNorm eps `rms_norm_eps`):
+
+    h = x + N2(Attn(N1(x)))          sandwich_norm: four norms a layer
+    y = h + N4(FFN(N3(h)))           (without it: h = x + Attn(N1(x)) ...)
+
+    Attn:  cq = Nq(x W_DQ)                        (q_lora_rank)
+           q_h = cq W_UQ_h = [q_nope_h | q_rope_h]   (nope | rope) a head
+           [c | k_r] = x W_DKV;  c = Nkv(c)       (kv_lora_rank | rope)
+           RoPE(rope_theta) on q_rope_h and on the ONE shared k_r
+           [k_nope_h | v_h] = c W_UKV_h           (nope | v) a head
+           score_h = (q_nope_h.k_nope_h + q_rope_h.k_r) / sqrt(nope+rope)
+           o = concat_h(softmax(score_h) v_h) W_O
+    FFN:   layers 0..first_k_dense_replace-1: SwiGLU(intermediate_size);
+           after them: shared SwiGLU expert + sum of the top-k routed
+           SwiGLU experts (models/moe.sparse_moe: float32 router over
+           ALL n_routed_experts, sigmoid scores, normalised over the
+           selection, times routed_scaling_factor).
+
+**The cache holds `[c | RoPE(k_r)]` a token a layer and nothing else**
+(`LatentMoeConfig.page_layout`: one pool of (n_blocks, kv_rank + rope,
+page) a layer — a token a column, the layout the chip gives a page
+anyway, ops/latent_attention.py).  Prefill expands k_nope and v from the latents of its
+own bucket and attends blockwise; decode and the suffix prefill of a
+prefix-cache hit attend IN THE LATENT SPACE over the paged pool
+(ops/latent_attention.py): W_UK folds into the query, W_UV into the
+output, the pages are never expanded in HBM.
+
+The share: `experts_first` / `experts_held` say which routed experts
+live here (the router keeps its published width and top-k, the layer
+computes its own experts' part plus the shared expert and leaves the
+rest out), `vocab_first` / `vocab_size` which rows of the vocabulary,
+`layers` / `dense_layers` how deep the stack kept here is.  Nothing
+stands in for the absent chips.
+
+Departures from the published model, each also in the configuration
+file that uses it: the multi-token-prediction module
+(`num_nextn_predict_layers`) is not served; the router's score
+function is not in the config and is taken as sigmoid without
+group-limited routing or a selection bias (the key set's family
+convention); the RoPE pairing is this repo's split-half
+`_apply_rotary`.
+
+WEIGHTS are made from a seed, tensor by tensor, directly in their
+resident dtype (matrices and the embedding bfloat16; norm scales and
+the router float32) — no float32 tree ever exists.  The recipe
+(`seed_tensor`) is written so that a plain reference can make the
+same values without importing this module:
+
+    key   = jax.random.fold_in(jax.random.PRNGKey(seed % (2**31 - 1)),
+                               zlib.crc32(name) & 0x7fffffff)
+    bits  = jax.random.bits(key, shape, uint32)
+    u     = (bits >> 8) * 2**-24                   in [0, 1), exact
+    value = mean + (u - 0.5) * sqrt(12) * std      one f32 rounding
+    value.astype(dtype)
+
+with std = 1/sqrt(fan_in) for a matrix (fan_in = its first axis), 1
+for the embedding, and mean 1, std 0.1 for a norm scale.  Names are
+`layers.<i>.<tensor>` with i the layer's index in the kept stack, and
+`layers.<i>.experts.<e>.<gate|up|down>` with e the expert's index in
+the WHOLE model — so every share of a layer draws the same weights
+the uncut layer would.
+"""
+from __future__ import annotations
+
+import dataclasses
+import functools
+import json
+import math
+import zlib
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..obs.devtime import DEVTIME, close_mark
+from .decoder import (PageLayout, PagedKVCache, PendingChunk,
+                      _sample_rows, sample_top_p)
+from .encoder import _apply_rotary, _rotary_angles_at
+from ..ops.latent_attention import latent_append, latent_paged_attention
+from .moe import sparse_moe
+
+# the published keys a description may carry (a DeepSeek-V3-family
+# config.json without the keys that say nothing about the block)
+PUBLISHED_KEYS = frozenset((
+    "attention_bias", "first_k_dense_replace", "hidden_act",
+    "hidden_size", "intermediate_size", "kv_lora_rank",
+    "max_position_embeddings", "model_type", "moe_intermediate_size",
+    "n_routed_experts", "n_shared_experts", "norm_topk_prob",
+    "num_attention_heads", "num_experts_per_tok", "num_hidden_layers",
+    "num_key_value_heads", "num_nextn_predict_layers", "q_lora_rank",
+    "qk_nope_head_dim", "qk_rope_head_dim", "rms_norm_eps",
+    "rope_theta", "routed_scaling_factor", "sandwich_norm",
+    "scoring_func", "tie_word_embeddings", "v_head_dim", "vocab_size"))
+DESCRIPTION_KEYS = frozenset(("architecture", "share", "seed", "note"))
+SHARE_KEYS = frozenset(("layers", "dense_layers", "experts", "vocab"))
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentMoeConfig:
+    vocab_size: int               # rows of the vocabulary held here
+    hidden: int
+    layers: int                   # layers kept here
+    heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    dense_layers: int             # leading dense layers among `layers`
+    dense_mlp_dim: int
+    moe_mlp_dim: int
+    n_routed_experts: int         # the router's width: ALL experts
+    top_k: int
+    experts_first: int = 0        # routed experts held here:
+    experts_held: int | None = None   # first .. first + held - 1
+    n_shared_experts: int = 1
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    score_fn: str = "sigmoid"
+    sandwich_norm: bool = True
+    vocab_first: int = 0
+    rope_base: float = 10000.0
+    rms_eps: float = 1e-5
+    max_len: int = 2048
+    dtype: Any = jnp.bfloat16
+
+    def __post_init__(self):
+        if self.experts_held is None:
+            object.__setattr__(self, "experts_held",
+                               self.n_routed_experts - self.experts_first)
+        if not 0 <= self.experts_first \
+                <= self.experts_first + self.experts_held \
+                <= self.n_routed_experts:
+            raise ValueError(
+                f"experts {self.experts_first}..+{self.experts_held} "
+                f"lie outside the router's {self.n_routed_experts}")
+        if not 0 <= self.dense_layers <= self.layers:
+            raise ValueError("dense_layers must lie in 0..layers")
+        if self.qk_rope_head_dim % 2:
+            raise ValueError("qk_rope_head_dim must be even (RoPE pairs)")
+
+    @classmethod
+    def tiny(cls, **kw) -> "LatentMoeConfig":
+        """Small config for tests and CPU rehearsals."""
+        kw = {"vocab_size": 512, "hidden": 64, "layers": 3, "heads": 4,
+              "q_lora_rank": 32, "kv_lora_rank": 32,
+              "qk_nope_head_dim": 16, "qk_rope_head_dim": 8,
+              "v_head_dim": 16, "dense_layers": 1, "dense_mlp_dim": 128,
+              "moe_mlp_dim": 32, "n_routed_experts": 8, "top_k": 2,
+              "routed_scaling_factor": 2.5, "max_len": 128, **kw}
+        return cls(**kw)
+
+    @property
+    def latent_width(self) -> int:
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    def page_layout(self, page: int) -> PageLayout:
+        """One pool a layer: a token's `[c | RoPE(k_r)]` row."""
+        return PageLayout((("latent", (self.latent_width, page)),),
+                          token_values=self.latent_width)
+
+
+def load_model_description(path: str, *, max_len: int | None = None):
+    """A model description file -> (LatentMoeConfig, seed).
+
+    {"architecture": {published keys verbatim, at their published
+                      values},
+     "share": {"layers": n, "dense_layers": n, "experts": [first,
+               count], "vocab": [first, count]},      (default: whole)
+     "seed": n}
+    An unknown key anywhere is an error, as is a published value this
+    block cannot honour (a bias, tied embeddings, another activation,
+    grouped kv heads)."""
+    with open(path) as f:
+        d = json.load(f)
+    extra = set(d) - DESCRIPTION_KEYS
+    if extra:
+        raise ValueError(f"unknown key(s) in {path}: {sorted(extra)}")
+    arch = d.get("architecture")
+    if not isinstance(arch, dict):
+        raise ValueError(f"{path} has no 'architecture' section")
+    extra = set(arch) - PUBLISHED_KEYS
+    if extra:
+        raise ValueError(
+            f"unknown architecture key(s) in {path}: {sorted(extra)} "
+            f"(known: {sorted(PUBLISHED_KEYS)})")
+    share = d.get("share", {})
+    extra = set(share) - SHARE_KEYS
+    if extra:
+        raise ValueError(f"unknown share key(s) in {path}: "
+                         f"{sorted(extra)}")
+
+    def need(key):
+        if key not in arch:
+            raise ValueError(f"{path}: architecture lacks {key!r}")
+        return arch[key]
+
+    if arch.get("hidden_act", "silu") != "silu":
+        raise ValueError("only the SwiGLU (silu) activation is served")
+    if arch.get("attention_bias", False):
+        raise ValueError("attention_bias is not served")
+    if arch.get("tie_word_embeddings", False):
+        raise ValueError("tied embeddings are not served")
+    heads = int(need("num_attention_heads"))
+    if int(arch.get("num_key_value_heads", heads)) != heads:
+        raise ValueError("latent attention has no grouped kv heads: "
+                         "num_key_value_heads must equal "
+                         "num_attention_heads")
+    if int(arch.get("n_shared_experts", 0)) not in (0, 1):
+        raise ValueError("0 or 1 shared expert is served")
+    n_layers = int(need("num_hidden_layers"))
+    first_dense = int(arch.get("first_k_dense_replace", 0))
+    layers = int(share.get("layers", n_layers))
+    dense = int(share.get("dense_layers", min(first_dense, layers)))
+    n_exp = int(need("n_routed_experts"))
+    e_first, e_held = share.get("experts", [0, n_exp])
+    vocab = int(need("vocab_size"))
+    v_first, v_held = share.get("vocab", [0, vocab])
+    if not 0 <= v_first <= v_first + v_held <= vocab:
+        raise ValueError("the vocabulary slice lies outside vocab_size")
+    if layers > n_layers or dense > first_dense:
+        raise ValueError("the share keeps more layers than the model has")
+    window = int(max_len or arch.get("max_position_embeddings", 2048))
+    if window > int(arch.get("max_position_embeddings", window)):
+        raise ValueError("the window exceeds max_position_embeddings")
+    cfg = LatentMoeConfig(
+        vocab_size=int(v_held), vocab_first=int(v_first),
+        hidden=int(need("hidden_size")), layers=layers, heads=heads,
+        q_lora_rank=int(need("q_lora_rank")),
+        kv_lora_rank=int(need("kv_lora_rank")),
+        qk_nope_head_dim=int(need("qk_nope_head_dim")),
+        qk_rope_head_dim=int(need("qk_rope_head_dim")),
+        v_head_dim=int(need("v_head_dim")),
+        dense_layers=dense,
+        dense_mlp_dim=int(need("intermediate_size")),
+        moe_mlp_dim=int(need("moe_intermediate_size")),
+        n_routed_experts=n_exp, top_k=int(need("num_experts_per_tok")),
+        experts_first=int(e_first), experts_held=int(e_held),
+        n_shared_experts=int(arch.get("n_shared_experts", 0)),
+        norm_topk_prob=bool(arch.get("norm_topk_prob", True)),
+        routed_scaling_factor=float(
+            arch.get("routed_scaling_factor", 1.0)),
+        score_fn=str(arch.get("scoring_func", "sigmoid")),
+        sandwich_norm=bool(arch.get("sandwich_norm", False)),
+        rope_base=float(arch.get("rope_theta", 10000.0)),
+        rms_eps=float(arch.get("rms_norm_eps", 1e-5)),
+        max_len=window)
+    return cfg, int(d.get("seed", 0))
+
+
+# ------------------------------------------------------------- weights
+
+# splint: ignore[SPL205] reason=start-up only: makes one weight tensor from the seed before any request; the serving programs are registered in LatentCompletionModel._program
+@functools.partial(jax.jit, static_argnames=("shape", "dtype"))
+def _seeded(key, mean, std, *, shape, dtype):
+    bits = jax.random.bits(key, shape, jnp.uint32)
+    u = (bits >> 8).astype(jnp.float32) * jnp.float32(2.0 ** -24)
+    return (mean + (u - 0.5) * (jnp.float32(math.sqrt(12.0)) * std)
+            ).astype(dtype)
+
+
+def seed_tensor(seed: int, name: str, shape, std: float,
+                dtype=jnp.bfloat16, mean: float = 0.0):
+    """THE weight recipe (module docstring): one named tensor from the
+    seed, made on the device directly in `dtype`."""
+    key = jax.random.fold_in(
+        jax.random.PRNGKey(int(seed) % (2 ** 31 - 1)),
+        zlib.crc32(name.encode()) & 0x7FFFFFFF)
+    return _seeded(key, jnp.float32(mean), jnp.float32(std),
+                   shape=tuple(int(s) for s in shape), dtype=dtype)
+
+
+def init_params(cfg: LatentMoeConfig, seed: int) -> dict:
+    """The resident tree of this share, tensor by tensor."""
+    H, dt = cfg.hidden, cfg.dtype
+
+    def mat(name, shape):
+        return seed_tensor(seed, name, shape, 1.0 / math.sqrt(shape[0]),
+                           dt)
+
+    def norm(name, n):
+        return seed_tensor(seed, name, (n,), 0.1, jnp.float32, 1.0)
+
+    layers = []
+    for i in range(cfg.layers):
+        p = f"layers.{i}."
+        lp = {
+            "ln_attn_in": norm(p + "ln_attn_in", H),
+            "ln_mlp_in": norm(p + "ln_mlp_in", H),
+            "w_dq": mat(p + "w_dq", (H, cfg.q_lora_rank)),
+            "ln_q": norm(p + "ln_q", cfg.q_lora_rank),
+            "w_uq": mat(p + "w_uq", (cfg.q_lora_rank,
+                                     cfg.heads * cfg.qk_head_dim)),
+            "w_dkv": mat(p + "w_dkv", (H, cfg.latent_width)),
+            "ln_kv": norm(p + "ln_kv", cfg.kv_lora_rank),
+            "w_ukv": mat(p + "w_ukv", (
+                cfg.kv_lora_rank,
+                cfg.heads * (cfg.qk_nope_head_dim + cfg.v_head_dim))),
+            "w_o": mat(p + "w_o", (cfg.heads * cfg.v_head_dim, H)),
+        }
+        if cfg.sandwich_norm:
+            lp["ln_attn_out"] = norm(p + "ln_attn_out", H)
+            lp["ln_mlp_out"] = norm(p + "ln_mlp_out", H)
+        if i < cfg.dense_layers:
+            I = cfg.dense_mlp_dim
+            lp["w_gate"] = mat(p + "w_gate", (H, I))
+            lp["w_up"] = mat(p + "w_up", (H, I))
+            lp["w_down"] = mat(p + "w_down", (I, H))
+        else:
+            M = cfg.moe_mlp_dim
+            lp["router"] = seed_tensor(
+                seed, p + "router", (H, cfg.n_routed_experts),
+                1.0 / math.sqrt(H), jnp.float32)
+            if cfg.n_shared_experts:
+                lp["shared_gate"] = mat(p + "shared.gate", (H, M))
+                lp["shared_up"] = mat(p + "shared.up", (H, M))
+                lp["shared_down"] = mat(p + "shared.down", (M, H))
+            held = range(cfg.experts_first,
+                         cfg.experts_first + cfg.experts_held)
+            for part, shape in (("gate", (H, M)), ("up", (H, M)),
+                                ("down", (M, H))):
+                lp["exp_" + part] = jnp.stack([
+                    mat(f"{p}experts.{e}.{part}", shape) for e in held])
+        layers.append(lp)
+    return {
+        "tok_emb": seed_tensor(seed, f"tok_emb.{cfg.vocab_first}",
+                               (cfg.vocab_size, H), 1.0, dt),
+        "layers": layers,
+        "ln_out": norm("ln_out", H),
+        "lm_head": mat(f"lm_head.{cfg.vocab_first}",
+                       (H, cfg.vocab_size)),
+    }
+
+
+# -------------------------------------------------------------- forward
+
+def _rms(x, scale, eps: float):
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)
+    return (y * scale).astype(x.dtype)
+
+
+def _attn_inputs(cfg: LatentMoeConfig, lp, x, pos):
+    """x: (B, S, H) normed; pos: (B, S) int32 positions.  Returns
+    (q_nope (B, S, heads, nope), q_rope (B, S, heads, rope) rotated,
+    latent (B, S, kv_rank + rope) = [Nkv(c) | RoPE(k_r)])."""
+    B, S, _ = x.shape
+    cq = _rms(jnp.dot(x, lp["w_dq"]), lp["ln_q"], cfg.rms_eps)
+    q = jnp.dot(cq, lp["w_uq"]).reshape(B, S, cfg.heads, cfg.qk_head_dim)
+    q_nope = q[..., :cfg.qk_nope_head_dim]
+    q_rope = q[..., cfg.qk_nope_head_dim:]
+    ckr = jnp.dot(x, lp["w_dkv"])
+    c = _rms(ckr[..., :cfg.kv_lora_rank], lp["ln_kv"], cfg.rms_eps)
+    cos, sin = _rotary_angles_at(pos.reshape(-1), cfg.qk_rope_head_dim,
+                                 cfg.rope_base)
+    cos = cos.reshape(B, S, -1)
+    sin = sin.reshape(B, S, -1)
+    q_rope = _apply_rotary(q_rope, cos, sin)
+    k_r = _apply_rotary(ckr[..., None, cfg.kv_lora_rank:], cos, sin)
+    return q_nope, q_rope, jnp.concatenate([c, k_r[:, :, 0]], -1)
+
+
+def _up_kv(cfg: LatentMoeConfig, lp):
+    """W_UKV as (kv_rank, heads, nope | v) -> (W_UK, W_UV)."""
+    w = lp["w_ukv"].reshape(cfg.kv_lora_rank, cfg.heads,
+                            cfg.qk_nope_head_dim + cfg.v_head_dim)
+    return w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
+
+
+# query rows one block of the expanded prefill attends with: bounds
+# the (heads, block, S) float32 score tile XLA materialises
+PREFILL_BLOCK_Q = 128
+
+
+def _expanded_attention(cfg: LatentMoeConfig, lp, q_nope, q_rope,
+                        latent):
+    """PREFILL: causal attention of one row's S tokens over its own
+    latents, with k_nope and v expanded from them (never cached).
+    q_*: (1, S, heads, .); latent: (1, S, W).  Returns (1, S, heads,
+    v).  Blockwise over queries so that no (heads, S, S) tile exists."""
+    S = q_nope.shape[1]
+    w_uk, w_uv = _up_kv(cfg, lp)
+    c = latent[0, :, :cfg.kv_lora_rank]
+    k_r = latent[0, :, cfg.kv_lora_rank:]
+    k_nope = jnp.einsum("sr,rhd->shd", c, w_uk)
+    v = jnp.einsum("sr,rhd->shd", c, w_uv)
+    bq = min(PREFILL_BLOCK_Q, S)
+    pad = (-S) % bq
+    qn, qr = q_nope[0], q_rope[0]
+    if pad:
+        qn = jnp.pad(qn, ((0, pad), (0, 0), (0, 0)))
+        qr = jnp.pad(qr, ((0, pad), (0, 0), (0, 0)))
+    nb = (S + pad) // bq
+    scale = 1.0 / math.sqrt(cfg.qk_head_dim)
+
+    def block(args):
+        qn_b, qr_b, i0 = args
+        s = (jnp.einsum("qhd,khd->hqk", qn_b, k_nope,
+                        preferred_element_type=jnp.float32)
+             + jnp.einsum("qhr,kr->hqk", qr_b, k_r,
+                          preferred_element_type=jnp.float32)) * scale
+        qi = i0 + jnp.arange(bq)[:, None]
+        s = jnp.where((jnp.arange(S)[None, :] <= qi)[None], s, -1e30)
+        p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+        return jnp.einsum("hqk,khd->qhd", p, v)
+
+    out = jax.lax.map(block, (
+        qn.reshape(nb, bq, *qn.shape[1:]),
+        qr.reshape(nb, bq, *qr.shape[1:]),
+        jnp.arange(nb, dtype=jnp.int32) * bq))
+    return out.reshape(nb * bq, cfg.heads, cfg.v_head_dim)[None, :S]
+
+
+def _absorbed_attention(cfg: LatentMoeConfig, lp, q_nope, q_rope, pool,
+                        tables, att_len, interpret: bool):
+    """DECODE / SUFFIX: attention in the latent space over the paged
+    pool, whose rows for these S tokens are appended already.
+    q_*: (B, S, heads, .).  Returns (B, S, heads, v)."""
+    w_uk, w_uv = _up_kv(cfg, lp)
+    q_lat = jnp.einsum("bshd,rhd->bshr", q_nope, w_uk)
+    o_lat = latent_paged_attention(
+        jnp.concatenate([q_lat, q_rope], -1), pool, tables, att_len,
+        kv_rank=cfg.kv_lora_rank,
+        scale=1.0 / math.sqrt(cfg.qk_head_dim), interpret=interpret)
+    return jnp.einsum("bshr,rhd->bshd", o_lat, w_uv)
+
+
+def _ffn(cfg: LatentMoeConfig, lp, x, live, interpret: bool):
+    """The layer's feed-forward on normed x: dense SwiGLU, or the
+    shared expert + this share of the routed ones.  Returns (out,
+    slots each held expert received | None)."""
+    if "router" not in lp:
+        return jnp.dot(jax.nn.silu(jnp.dot(x, lp["w_gate"]))
+                       * jnp.dot(x, lp["w_up"]), lp["w_down"]), None
+    shared = None
+    if "shared_gate" in lp:
+        shared = (lp["shared_gate"], lp["shared_up"], lp["shared_down"])
+    return sparse_moe(
+        x, lp["router"], lp["exp_gate"], lp["exp_up"], lp["exp_down"],
+        top_k=cfg.top_k, first=cfg.experts_first, score=cfg.score_fn,
+        norm_topk=cfg.norm_topk_prob, scale=cfg.routed_scaling_factor,
+        shared=shared, live=live, interpret=interpret)
+
+
+def _layer(cfg: LatentMoeConfig, lp, x, pos, attend, live,
+           interpret: bool):
+    """One block.  attend(q_nope, q_rope, latent) -> ((B, S, heads, v),
+    whatever the cache path hands back)."""
+    B, S, _ = x.shape
+    q_nope, q_rope, latent = _attn_inputs(
+        cfg, lp, _rms(x, lp["ln_attn_in"], cfg.rms_eps), pos)
+    o, kept = attend(q_nope, q_rope, latent)
+    a = jnp.dot(o.reshape(B, S, cfg.heads * cfg.v_head_dim), lp["w_o"])
+    if cfg.sandwich_norm:
+        a = _rms(a, lp["ln_attn_out"], cfg.rms_eps)
+    h = x + a
+    f, slots = _ffn(cfg, lp, _rms(h, lp["ln_mlp_in"], cfg.rms_eps),
+                    live, interpret)
+    if cfg.sandwich_norm:
+        f = _rms(f, lp["ln_mlp_out"], cfg.rms_eps)
+    return h + f, kept, slots
+
+
+def _logits(cfg: LatentMoeConfig, params, x):
+    """Final norm + untied head over the vocabulary slice, float32."""
+    return jnp.dot(_rms(x, params["ln_out"], cfg.rms_eps),
+                   params["lm_head"],
+                   preferred_element_type=jnp.float32)
+
+
+def _sum_slots(cfg: LatentMoeConfig, per_layer):
+    got = [s for s in per_layer if s is not None]
+    if not got:
+        return jnp.zeros((max(cfg.experts_held, 1),), jnp.int32)
+    return functools.reduce(jnp.add, got)
+
+
+def forward_prefill(cfg: LatentMoeConfig, params, ids, n_valid, *,
+                    interpret: bool = False):
+    """One row's prompt from position 0, expanded attention.  ids:
+    (1, S) padded to a bucket; n_valid: how many are real.  Returns
+    (hidden (1, S, H), [latent rows (S, W) a layer])."""
+    S = ids.shape[1]
+    pos = jnp.arange(S, dtype=jnp.int32)[None]
+    live = (jnp.arange(S) < n_valid)[None]
+    x = params["tok_emb"][ids]
+    latents = []
+    for lp in params["layers"]:
+        x, lat, _ = _layer(
+            cfg, lp, x, pos,
+            lambda qn, qr, lat, lp=lp: (
+                _expanded_attention(cfg, lp, qn, qr, lat), lat[0]),
+            live, interpret)
+        latents.append(lat)
+    return x, latents
+
+
+def forward_paged(cfg: LatentMoeConfig, params, ids, pools, tables,
+                  lengths, n_valid=None, *, interpret: bool = False):
+    """S new tokens a row atop what its table maps, absorbed
+    attention over the latent pages (S == 1: a decode step; S > 1: the
+    suffix prefill of a prefix-cache hit, pad appends past n_valid
+    routed to the trash block).  ids: (B, S); pools: a latent pool a
+    layer; tables: (B, P); lengths: (B,).  Returns (hidden (B, S, H),
+    new pools, slots each held expert received)."""
+    B, S = ids.shape
+    page = pools[0].shape[2]
+    pos = jnp.minimum(lengths[:, None] + jnp.arange(S)[None, :],
+                      cfg.max_len - 1).astype(jnp.int32)
+    bids = jnp.take_along_axis(tables, pos // page, axis=1)
+    live = (lengths > 0)[:, None] & jnp.ones((1, S), bool)
+    if n_valid is not None:
+        ok = jnp.arange(S)[None, :] < n_valid
+        bids = jnp.where(ok, bids, 0)
+        live = live & ok
+    offs = pos % page
+    att_len = pos[:, 0] + 1
+    x = params["tok_emb"][ids]
+    new_pools, slots = [], []
+    for lp, pool in zip(params["layers"], pools):
+        def attend(qn, qr, lat, lp=lp, pool=pool):
+            pool = latent_append(pool, lat, bids, offs,
+                                 interpret=interpret)
+            return _absorbed_attention(cfg, lp, qn, qr, pool, tables,
+                                       att_len, interpret), pool
+        x, pool, s = _layer(cfg, lp, x, pos, attend, live, interpret)
+        new_pools.append(pool)
+        slots.append(s)
+    return x, new_pools, _sum_slots(cfg, slots)
+
+
+# ------------------------------------------------------------- front end
+
+class LatentPendingChunk(PendingChunk):
+    """A paged decode chunk of the latent model: beside the sampled
+    block, `slots` — (count,) int32 expert-slots the chunk's LIVE rows
+    sent to each held expert, all steps and layers — and `audit`, the
+    (n, V) float32 logits of the one row the dispatch was told to
+    keep.  Both stay on the device until asked for; after block()
+    reading them waits for nothing."""
+
+    __slots__ = ("slots", "audit")
+
+    def __init__(self, out, last, n, mark, slots, audit):
+        super().__init__(out, last, n, mark)
+        self.slots = slots
+        self.audit = audit
+
+
+def prefill_buckets(max_len: int, page: int) -> tuple[int, ...]:
+    """The paged lane's prefill buckets, from the window and the page
+    size alone: the window in whole pages, then a quarter of it
+    (rounded up to pages) at a time down to four pages or fewer — four
+    programs for a 66-page window (66, 17, 5, 2 pages)."""
+    p = -(-max_len // page)
+    out = []
+    while True:
+        out.append(p * page)
+        if p <= 4:
+            break
+        p = -(-p // 4)
+    return tuple(sorted(out))
+
+
+class LatentCompletionModel:
+    """The paged serving surface the continuous lane drives
+    (init_paged / paged_prefill_row / paged_append_prefill /
+    paged_decode_chunk_async / warmup_paged / sample) over the latent
+    block, and nothing of CompletionModel's dense-cache surface:
+    completer.main refuses the lanes that would need it."""
+
+    paged_supported = True
+    audit_supported = True
+    # what completer.main refuses for a model of this class, and why
+    refused_options = {
+        "kv_dtype": "latent pages are stored in the model's dtype: "
+                    "the int8/int4 page codecs are per (page, kv "
+                    "head) and a latent row has no kv heads",
+        "kv_tier_pages": "the host tier's page wire carries key/value "
+                         "pools only",
+        "phase": "the disaggregated hand-off's page wire carries "
+                 "key/value pools only",
+        "tp": "latent pools have no kv-head axis to shard; attention "
+              "is data-parallel in this deployment",
+        "ep": "this share is told the experts it holds by the model "
+              "description, not by a mesh",
+        "draft": "the speculative wrapper pairs key/value pools",
+        "weights": "the description serves seeded weights; no "
+                   "checkpoint loader maps onto this tree",
+        "weight_quant": "the int8 weight residencies cover the dense "
+                        "llama projections only",
+    }
+
+    def __init__(self, cfg: LatentMoeConfig, *, seed: int = 0,
+                 params: Any = None, top_p: float = 0.9,
+                 temp: float = 0.7,
+                 suffix_buckets: tuple[int, ...] = (16, 64),
+                 interpret: bool = False):
+        self.cfg = cfg
+        self.suffix_buckets = tuple(sorted(
+            b for b in suffix_buckets if 0 < b < cfg.max_len)) or (
+            min(16, max(1, cfg.max_len - 1)),)
+        self.buckets = prefill_buckets(cfg.max_len, 128)
+        self.top_p, self.temp = top_p, temp
+        self.interpret = interpret
+        self.params = init_params(cfg, seed) if params is None else params
+        self.devtime_lane = "completer"
+        self._rng = jax.random.PRNGKey(seed + 1)
+        self._paged_progs: dict[tuple, Any] = {}
+        # the batch row whose per-step logits the next decode chunks
+        # keep (LatentPendingChunk.audit); -1: none
+        self.audit_row = -1
+
+    def resident_bytes(self) -> int:
+        return sum(a.nbytes for a in jax.tree_util.tree_leaves(
+            self.params))
+
+    def compile_count(self) -> int:
+        total = 0
+        for f in self._paged_progs.values():
+            f = getattr(f, "__wrapped__", f)
+            try:
+                total += int(f._cache_size())
+            except Exception:   # private jax API: absence isn't an error
+                return -1
+        return total
+
+    def _devname(self, short: str) -> str:
+        return f"{self.devtime_lane}.{short}"
+
+    def bucket_for(self, length: int) -> int:
+        return next((b for b in self.buckets if length <= b),
+                    self.buckets[-1])
+
+    def sample(self, logits: np.ndarray) -> int:
+        """One host-side draw from a join's logits (V,)."""
+        self._rng, sub = jax.random.split(self._rng)
+        return int(sample_top_p(sub, jnp.asarray(logits),
+                                top_p=self.top_p, temp=self.temp))
+
+    # -- paged serving -----------------------------------------------------
+
+    def init_paged(self, batch: int, *, page: int = 128,
+                   pool_pages: int | None = None,
+                   kv_dtype: str | None = None) -> PagedKVCache:
+        self.buckets = prefill_buckets(self.cfg.max_len, page)
+        return PagedKVCache(self.cfg, batch, page=page,
+                            pool_pages=pool_pages, kv_dtype=kv_dtype)
+
+    def _program(self, key: tuple, short: str, build, donate=(1,)):
+        """The jitted, devtime-registered program under `key`; build()
+        makes its function, named latent_<short> — what a device trace
+        shows as jit_latent_<short> (benchmark/readers count on it)."""
+        fn = self._paged_progs.get(key)
+        if fn is None:
+            run = build()
+            run.__name__ = f"latent_{short}"
+            fn = DEVTIME.register(
+                self._devname(short),
+                jax.jit(run, donate_argnums=donate))
+            self._paged_progs[key] = fn
+        return fn
+
+    def _prefill_program(self, bucket: int, page: int):
+        cfg, interp = self.cfg, self.interpret
+
+        def build():
+            def run(params, pools, ids, bids, n_valid):
+                x, latents = forward_prefill(cfg, params, ids, n_valid,
+                                             interpret=interp)
+                # whole pages, a token a column
+                pools = [p.at[bids].set(
+                    lat.reshape(-1, page, lat.shape[-1])
+                    .transpose(0, 2, 1).astype(p.dtype))
+                    for p, lat in zip(pools, latents)]
+                last = jax.lax.dynamic_index_in_dim(
+                    x[0], n_valid - 1, 0, keepdims=False)
+                return pools, _logits(cfg, params, last)
+            return run
+        return self._program(("prefill", bucket, page), "bucket_prefill",
+                             build)
+
+    def paged_prefill_row(self, cache: PagedKVCache,
+                          prompt_ids: np.ndarray, row: int) -> np.ndarray:
+        """Prefill one row's whole prompt (expanded attention over a
+        bucket) and commit its latent rows into the row's pages.
+        Returns the last real token's logits (V,)."""
+        P = len(prompt_ids)
+        if P == 0:
+            raise ValueError("empty prompt")
+        if P >= self.cfg.max_len:
+            raise ValueError("prompt exceeds context window")
+        if not cache.ensure(row, P):
+            raise RuntimeError(
+                f"paged pool exhausted: row {row} needs "
+                f"{cache.pages_needed(P)} pages, {cache.free_pages} free")
+        b = self.bucket_for(P)
+        ids = np.zeros((1, b), np.int32)
+        ids[0, :P] = np.asarray(prompt_ids[:P], np.int32)
+        # entries past the prompt's pages are 0 = trash: the bucket's
+        # excess rows land there
+        bids = np.zeros((b // cache.page,), np.int32)
+        n_own = min(len(bids), cache.tables.shape[1])
+        bids[:n_own] = cache.tables[row, :n_own]
+        fn = self._prefill_program(b, cache.page)
+        pools, logits = fn(self.params, cache.pools[0], jnp.asarray(ids),
+                           jnp.asarray(bids), jnp.int32(P))
+        mark = DEVTIME.take_mark(self._devname("bucket_prefill"))
+        cache.pools[0] = list(pools)
+        cache.lengths[row] = P
+        out = np.asarray(logits)
+        close_mark(mark)
+        return out
+
+    def _suffix_program(self, sb: int):
+        cfg, interp = self.cfg, self.interpret
+
+        def build():
+            def run(params, pools, table, length, ids, n_valid):
+                x, pools, _ = forward_paged(
+                    cfg, params, ids, pools, table, length, n_valid,
+                    interpret=interp)
+                last = jax.lax.dynamic_index_in_dim(
+                    x[0], n_valid - 1, 0, keepdims=False)
+                return pools, _logits(cfg, params, last)
+            return run
+        return self._program(("suffix", sb), "suffix_prefill", build)
+
+    def paged_append_prefill(self, cache: PagedKVCache, suffix_ids,
+                             row: int) -> np.ndarray:
+        """Prefill ONLY the uncached suffix of row's prompt atop the
+        cache.lengths[row] tokens its table already maps, attending
+        in the latent space.  Suffixes longer than the largest suffix
+        bucket loop it.  Returns the last real token's logits (V,)."""
+        ids = np.asarray(suffix_ids, np.int32)
+        if ids.size == 0:
+            raise ValueError("empty suffix")
+        pos = int(cache.lengths[row])
+        if pos + ids.size >= self.cfg.max_len:
+            raise ValueError("suffix exceeds context window")
+        if not cache.ensure(row, pos + ids.size):
+            raise RuntimeError(
+                f"paged pool exhausted: row {row} suffix needs "
+                f"{cache.pages_needed(pos + ids.size)} pages")
+        table = cache.tables[row: row + 1]
+        logits, mark, off = None, None, 0
+        while off < ids.size:
+            rem = ids.size - off
+            sb = next((b for b in self.suffix_buckets if b >= rem),
+                      self.suffix_buckets[-1])
+            n = min(rem, sb)
+            chunk = np.zeros((1, sb), np.int32)
+            chunk[0, :n] = ids[off: off + n]
+            pools, logits = self._suffix_program(sb)(
+                self.params, cache.pools[0],
+                # host-side copies: lengths is bumped right below, and
+                # an aliased view would be read after it
+                # (decoder.paged_decode_chunk_async)
+                jnp.asarray(np.array(table)),
+                jnp.asarray(np.array(cache.lengths[row: row + 1])),
+                jnp.asarray(chunk), jnp.int32(n))
+            close_mark(mark)
+            mark = DEVTIME.take_mark(self._devname("suffix_prefill"))
+            cache.pools[0] = list(pools)
+            cache.lengths[row] += n
+            off += n
+        out = np.asarray(logits)
+        close_mark(mark)
+        return out
+
+    def _cow_fixups(self, cache) -> int:
+        """Copy-on-write pass before a decode dispatch (see
+        CompletionModel._cow_fixups): one page copy a layer."""
+        n = 0
+        for row, p_idx in cache.cow_targets():
+            src = int(cache.tables[row, p_idx])
+            dst = cache._alloc_page()
+            cache.pools[0] = list(self._cow_program()(
+                cache.pools[0], jnp.int32(src), jnp.int32(dst)))
+            cache.commit_cow(row, p_idx, dst)
+            n += 1
+        return n
+
+    def _cow_program(self):
+        def build():
+            def run(pools, src, dst):
+                return [p.at[dst].set(p[src]) for p in pools]
+            return run
+        return self._program(("cow",), "cow_copy", build, donate=(0,))
+
+    def _chunk_program(self, n: int, bp: int):
+        cfg, interp = self.cfg, self.interpret
+        top_p, temp = self.top_p, self.temp
+
+        def build():
+            def run(params, pools, tables, lengths, rng, fresh,
+                    fresh_mask, carry, audit_row):
+                toks0 = jnp.where(fresh_mask, fresh, carry)
+                row = jnp.clip(audit_row, 0, bp - 1)
+
+                def step(carry_s, _):
+                    pools, lengths, rng, toks, slots = carry_s
+                    x, pools, s = forward_paged(
+                        cfg, params, toks.reshape(-1, 1), pools, tables,
+                        lengths, interpret=interp)
+                    logits = _logits(cfg, params, x[:, 0])
+                    rng, sub = jax.random.split(rng)
+                    nxt = _sample_rows(sub, logits, top_p, temp)
+                    return ((pools, lengths + 1, rng, nxt, slots + s),
+                            (nxt, logits[row]))
+
+                zero = jnp.zeros((max(cfg.experts_held, 1),), jnp.int32)
+                (pools, _, _, _, slots), (out, kept) = jax.lax.scan(
+                    step, (pools, lengths, rng, toks0, zero), None,
+                    length=n)
+                return pools, out, out[-1], slots, kept
+            return run
+        return self._program(("chunk", n, bp, top_p, temp),
+                             "paged_chunk", build)
+
+    def paged_decode_chunk_async(self, cache: PagedKVCache, tokens,
+                                 n: int, carry=None
+                                 ) -> LatentPendingChunk:
+        """CompletionModel.paged_decode_chunk_async's contract, over
+        latent pages; the chunk also carries its expert-slot counts
+        and the audited row's logits."""
+        bp = cache.batch
+        for r in range(bp):
+            length = int(cache.lengths[r])
+            if length > 0 and not cache.ensure(
+                    r, min(length + n, self.cfg.max_len)):
+                raise RuntimeError(
+                    f"paged pool exhausted mid-decode: row {r} "
+                    f"(admission must reserve prompt + max_new)")
+        self._cow_fixups(cache)
+        toks = np.full((bp,), -1, np.int32)
+        toks[: len(tokens)] = np.asarray(tokens, np.int32)
+        if carry is None:
+            fresh_mask = np.ones((bp,), bool)
+            # a device array like every later carry (the previous
+            # chunk's .last): a NumPy one is another call signature,
+            # which the compile ledger counts as a program
+            carry = jnp.zeros((bp,), jnp.int32)
+        else:
+            fresh_mask = toks >= 0
+        toks = np.maximum(toks, 0)
+        self._rng, sub = jax.random.split(self._rng)
+        pools, out, last, slots, kept = self._chunk_program(n, bp)(
+            self.params, cache.pools[0],
+            jnp.asarray(np.array(cache.tables)),
+            jnp.asarray(np.array(cache.lengths)), sub, jnp.asarray(toks),
+            jnp.asarray(fresh_mask), carry, jnp.int32(self.audit_row))
+        cache.pools[0] = list(pools)
+        live = cache.lengths > 0
+        cache.lengths[live] = np.minimum(cache.lengths[live] + n,
+                                         self.cfg.max_len)
+        return LatentPendingChunk(
+            out, last, n, DEVTIME.take_mark(self._devname("paged_chunk")),
+            slots, kept)
+
+    def paged_decode_chunk(self, cache: PagedKVCache, tokens, n: int
+                           ) -> np.ndarray:
+        return self.paged_decode_chunk_async(cache, tokens, n).block()
+
+    def warmup_paged(self, cache: PagedKVCache, chunk: int = 8,
+                     max_prompt: int | None = None) -> None:
+        """Every program the lane can dispatch for this window: the
+        prefill buckets up to the prompt budget, the sampler, the
+        decode chunk, and (with a prefix tree attached) the suffix
+        buckets and the page copy."""
+        with DEVTIME.warmup_phase():
+            self._warmup_paged_impl(cache, chunk, max_prompt)
+
+    def _warmup_paged_impl(self, cache: PagedKVCache, chunk: int,
+                           max_prompt: int | None) -> None:
+        cap = (self.bucket_for(max_prompt) if max_prompt is not None
+               else self.buckets[-1])
+        chunk_done = False
+        for b in self.buckets:
+            if b > cap:
+                break
+            n = max(1, min(b, self.cfg.max_len - 1) - 1)
+            self.sample(self.paged_prefill_row(
+                cache, np.ones((n,), np.int32), 0))
+            if not chunk_done and n + chunk < self.cfg.max_len:
+                self.paged_decode_chunk(
+                    cache, np.ones((cache.batch,), np.int32), chunk)
+                chunk_done = True
+            cache.free_row(0)
+        if getattr(cache, "prefix_cache", None) is not None:
+            for sb in self.suffix_buckets:
+                if sb + chunk >= self.cfg.max_len:
+                    break
+                self.paged_append_prefill(
+                    cache, np.ones((sb,), np.int32), 0)
+                cache.free_row(0)
+            src, dst = cache._alloc_page(), cache._alloc_page()
+            cache.pools[0] = list(self._cow_program()(
+                cache.pools[0], jnp.int32(src), jnp.int32(dst)))
+            cache._decref(src)
+            cache._decref(dst)

@@ -1,24 +1,36 @@
-"""Mixture-of-Experts decoder family (Mixtral-style) with expert
-parallelism.
+"""Mixture-of-Experts layers: sparse token -> expert dispatch over the
+experts THIS device holds, in two settings of one layer.
 
 The reference serves only dense llama-family GGUF checkpoints through
 llama.cpp (splainference.cpp:414-448); MoE is a net-new model family on
-the TPU side, designed for how XLA actually schedules it:
+the TPU side.  `sparse_moe` is the layer:
 
-  - the expert FFNs are STACKED weight tensors (E, hidden, mlp) and the
-    whole layer is three einsums over the expert axis — dense compute,
-    every expert runs for every token, the router's top-k gates weight
-    the combine.  For the expert counts this framework targets (4-16)
-    that is the MXU-friendly formulation: one big batched matmul per
-    projection instead of gather/scatter dispatch (sparse dispatch
-    kernels pay off only at much larger E; documented non-goal here);
-  - expert parallelism = shard the stacked tensors' E axis over the
-    mesh's `ep` axis (parallel/serve.moe_param_pspec).  Each device
-    computes its local experts' outputs; the gated combine's einsum
-    reduces over E, so GSPMD closes each layer with one psum over ep —
-    the canonical dense-MoE sharding;
-  - the router is tiny and replicated; gates renormalize over the
-    selected top-k (Mixtral convention).
+  - the router scores EVERY expert of the model (its published width)
+    in float32 and keeps the top-k: softmax scores renormalised over
+    the selection (the Mixtral convention, `MoeMlp`), or sigmoid
+    scores normalised over the selection and scaled (the DeepSeek-V3
+    family's, models/mla.py), with an optional shared expert that
+    every token passes through;
+  - the layer is TOLD which experts it holds — `first` and the leading
+    axis of the stacked (count, hidden, width) tensors — and computes
+    the part of the result its own experts give, for only the tokens
+    routed to them: (token, expert) slots are sorted by held expert,
+    the rows gathered, three GROUPED matrix products run over the
+    ragged groups (jax.experimental.pallas.ops.tpu.megablox on a TPU,
+    jax.lax.ragged_dot elsewhere), and the gated rows scatter-add
+    back.  Work follows the slots that landed here, not tokens x
+    experts.  Nothing is dropped under any routing: the row buffer
+    holds the worst case (every token choosing min(k, count) held
+    experts) and long sequences go through in chunks;
+  - what the absent experts would have added is left out, here and in
+    the plain reference alike (models/mla.py's share of an
+    expert-parallel deployment).  With first == 0 and every expert
+    held the layer is the whole model's.
+
+Expert parallelism for the Mixtral form stays what it was: shard the
+stacked tensors' E axis over the mesh's `ep` axis
+(parallel/serve.moe_param_pspec) and let GSPMD place the grouped
+products; `--ep` serves it.
 
 MoeDecoder is call-compatible with Decoder (ids, cache, pos) ->
 (logits, cache): the SAME CompletionModel / ShardedCompletionModel /
@@ -50,26 +62,170 @@ class MoeDecoderConfig(DecoderConfig):
         return cls(**kw)
 
 
+# tokens one dispatch takes: longer sequences go through in chunks of
+# this many, so the worst-case row buffer (tokens x min(k, count) rows
+# of hidden width) stays bounded while nothing is ever dropped
+MOE_CHUNK_TOKENS = 2048
+# megablox tiles (rows, contraction, output columns); rows are padded
+# to the first, the others shrink to divide the weight's shape
+GMM_TILES = (128, 1024, 1024)
+
+
+def router_gates(x, router, *, top_k: int, score: str = "softmax",
+                 norm_topk: bool = True, scale: float = 1.0):
+    """The routing every share of a layer computes alike, in float32
+    over ALL of the model's experts.  x: (T, H); router: (H, E).
+    Returns (ids (T, k) int32, gates (T, k) f32): the k best experts
+    a token and the weight of each — softmax or sigmoid scores,
+    normalised over the selection (norm_topk), times `scale`."""
+    logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    if score == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    elif score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(f"unknown router score function {score!r}")
+    topv, topi = jax.lax.top_k(scores, top_k)
+    if norm_topk:
+        topv = topv / jnp.maximum(topv.sum(-1, keepdims=True), 1e-20)
+    return topi.astype(jnp.int32), topv * scale
+
+
+def _tile(dim: int, want: int) -> int:
+    """The largest multiple of 128 at or under `want` that divides
+    `dim`, or `dim` itself."""
+    t = min(want, dim) // 128 * 128
+    while t >= 128:
+        if dim % t == 0:
+            return t
+        t -= 128
+    return dim
+
+
+def grouped_matmul(lhs, rhs, group_sizes, *, interpret: bool = False):
+    """lhs[rows of group g] @ rhs[g] for consecutive row groups.
+    lhs: (M, K); rhs: (G, K, N); group_sizes: (G,) int32, sum <= M;
+    rows past the last group come back zero.  The megablox Pallas
+    kernel on a TPU (its grid follows the rows present, not M x G),
+    jax.lax.ragged_dot elsewhere."""
+    if interpret or jax.default_backend() == "tpu":
+        from jax.experimental.pallas.ops.tpu.megablox import gmm
+        M, K = lhs.shape
+        N = rhs.shape[2]
+        tm = GMM_TILES[0]
+        pad = (-M) % tm
+        if pad:
+            lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
+        out = gmm(lhs, rhs, group_sizes, preferred_element_type=lhs.dtype,
+                  tiling=(tm, _tile(K, GMM_TILES[1]),
+                          _tile(N, GMM_TILES[2])),
+                  interpret=interpret)
+        return out[:M] if pad else out
+    return jax.lax.ragged_dot(lhs, rhs, group_sizes)
+
+
+def _dispatch(x, ids, gates, live, wg, wu, wd, first: int,
+              interpret: bool):
+    """One chunk of tokens through the held experts.  x: (T, H);
+    ids/gates: (T, k); live: (T,) bool.  Returns ((T, H) f32 partial
+    sum, (count,) int32 slots each held expert received)."""
+    T, H = x.shape
+    k = ids.shape[1]
+    count = wg.shape[0]
+    local = ids - first
+    held = (local >= 0) & (local < count) & live[:, None]
+    # slots sorted by held expert; the rest sort behind under `count`
+    group = jnp.where(held, local, count).reshape(-1)        # (T*k,)
+    order = jnp.argsort(group, stable=True)
+    sizes = jnp.bincount(group, length=count + 1)[:count].astype(
+        jnp.int32)
+    # worst case: every token picks min(k, count) experts held here
+    rows = T * min(k, count)
+    order = order[:rows]
+    tok = order // k
+    xs = x[tok]                                              # (rows, H)
+    h = grouped_matmul(xs, wg, sizes, interpret=interpret)
+    u = grouped_matmul(xs, wu, sizes, interpret=interpret)
+    y = grouped_matmul((nn.silu(h) * u).astype(x.dtype), wd, sizes,
+                       interpret=interpret)
+    # rows past the last group belong to no expert held here, and the
+    # grouped product leaves them unwritten: mask, do not multiply
+    mine = (jnp.arange(rows) < sizes.sum())[:, None]
+    y = jnp.where(mine, y.astype(jnp.float32)
+                  * gates.reshape(-1)[order][:, None], 0.0)
+    return jnp.zeros((T, H), jnp.float32).at[tok].add(y), sizes
+
+
+def sparse_moe(x, router, wg, wu, wd, *, top_k: int, first: int = 0,
+               score: str = "softmax", norm_topk: bool = True,
+               scale: float = 1.0, shared=None, live=None,
+               interpret: bool = False):
+    """The expert layer over the experts held here.
+
+    x: (..., H) activations; router: (H, E) over ALL E experts of the
+    model; wg/wu: (count, H, M), wd: (count, M, H) — experts
+    first..first+count-1; shared: None or (gate (H, Ms), up (H, Ms),
+    down (Ms, H)) of an expert every token passes through, counted
+    here in full; live: None or a bool mask of x's leading shape —
+    tokens outside it are routed nowhere and counted nowhere (the
+    dead rows of a paged decode step).
+    Returns (out (..., H) in x's dtype — the shared expert plus the
+    gated sum over the HELD experts among each token's top-k, (count,)
+    int32 — the slots each held expert received)."""
+    lead, H = x.shape[:-1], x.shape[-1]
+    x2 = x.reshape(-1, H)
+    T = x2.shape[0]
+    live2 = jnp.ones((T,), bool) if live is None else live.reshape(-1)
+    ids, gates = router_gates(x2, router, top_k=top_k, score=score,
+                              norm_topk=norm_topk, scale=scale)
+    if T <= MOE_CHUNK_TOKENS:
+        out, sizes = _dispatch(x2, ids, gates, live2, wg, wu, wd,
+                               first, interpret)
+    else:
+        n = -(-T // MOE_CHUNK_TOKENS)
+        pad = n * MOE_CHUNK_TOKENS - T
+
+        def chunks(a):
+            a = jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))
+            return a.reshape(n, MOE_CHUNK_TOKENS, *a.shape[1:])
+
+        out, sizes = jax.lax.map(
+            lambda c: _dispatch(*c, wg, wu, wd, first, interpret),
+            (chunks(x2), chunks(ids), chunks(gates), chunks(live2)))
+        out = out.reshape(-1, H)[:T]
+        sizes = sizes.sum(0)
+    if shared is not None:
+        sg, su, sd = shared
+        out = out + jnp.dot(nn.silu(jnp.dot(x2, sg)) * jnp.dot(x2, su),
+                            sd).astype(jnp.float32)
+    return out.astype(x.dtype).reshape(*lead, H), sizes
+
+
+class _Router(nn.Module):
+    """The router's (hidden, experts) float32 kernel, under the
+    parameter path an nn.Dense named the same would give it."""
+    n_experts: int
+
+    @nn.compact
+    def __call__(self, hidden: int):
+        return self.param("kernel", nn.initializers.lecun_normal(),
+                          (hidden, self.n_experts))
+
+
 class MoeMlp(nn.Module):
-    """Top-k routed SwiGLU experts, computed densely over stacked
-    (E, ...) weights and combined with renormalized gates."""
+    """The Mixtral setting of `sparse_moe`: softmax gates renormalised
+    over the top-k, no shared expert, every expert held (stacked
+    (E, ...) weights; `ep` shards their E axis)."""
     cfg: MoeDecoderConfig
 
     @nn.compact
     def __call__(self, x):
         cfg = self.cfg
         E, H, M = cfg.n_experts, cfg.hidden, cfg.mlp_dim
-
-        # routing in f32 for stable softmax/top-k
-        logits = nn.Dense(E, use_bias=False, dtype=jnp.float32,
-                          name="router")(x.astype(jnp.float32))
-        probs = jax.nn.softmax(logits, axis=-1)            # (B, S, E)
-        topv, topi = jax.lax.top_k(probs, cfg.top_k)
-        gates = (jax.nn.one_hot(topi, E, dtype=probs.dtype)
-                 * topv[..., None]).sum(axis=-2)           # (B, S, E)
-        gates = gates / jnp.maximum(
-            gates.sum(-1, keepdims=True), 1e-9)            # renormalize
-        gates = gates.astype(cfg.dtype)
+        # the nn.Dense parameter tree (router/kernel) checkpoints and
+        # the GGUF loader map onto
+        router = _Router(E, name="router")(H)
 
         if cfg.quantized:
             # int8-resident expert stacks (models/quant.py): same HBM
@@ -86,14 +242,9 @@ class MoeMlp(nn.Module):
                 cfg.dtype)
             wd = self.param("down_experts", init, (E, M, H)).astype(
                 cfg.dtype)
-
-        xd = x.astype(cfg.dtype)
-        g = jnp.einsum("bsh,ehm->bsem", xd, wg)
-        u = jnp.einsum("bsh,ehm->bsem", xd, wu)
-        y = nn.silu(g) * u                                 # (B, S, E, M)
-        out = jnp.einsum("bsem,emh->bseh", y, wd)
-        # gated combine reduces over E -> one psum over ep when sharded
-        return jnp.einsum("bseh,bse->bsh", out, gates)
+        out, _ = sparse_moe(x.astype(cfg.dtype), router, wg, wu, wd,
+                            top_k=cfg.top_k)
+        return out
 
 
 def MoeDecoder(cfg: MoeDecoderConfig, mesh=None):

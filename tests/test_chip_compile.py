@@ -218,3 +218,101 @@ def test_paged_attention_tp4(topo, kind):
                          specs[i].shape, specs[i].dtype)
     compiled = _compile(functools.partial(_paged, mesh=mesh), *specs)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# ---- the latent-attention (MLA) + expert block at published widths ----
+# (benchmark/configs/openpangu-ultra-moe-718b-ep16.json: 128 heads over
+# a 512 + 64 latent, pages of 128 tokens, 2,560 of them, a 66-page
+# window, 16 held experts of 7,680 x 2,048)
+
+LAT_W, LAT_RANK, LAT_HEADS, LAT_POOL = 576, 512, 128, (2561, 576, 128)
+
+
+@pytest.mark.parametrize("q_tokens, rows", [(1, 64), (16, 1), (64, 1)],
+                         ids=["decode-64-rows", "suffix-16", "suffix-64"])
+def test_latent_attention_kernel(one_chip, q_tokens, rows):
+    """The latent decode kernel (S == 1, all 128 heads a program) and
+    the suffix stacks (S x 64 / 16 heads), over transposed pages: the
+    576-wide contraction and the 512-wide output are no multiples of
+    what interpret mode checks."""
+    from libsplinter_tpu.ops.latent_attention import (_latent_pallas,
+                                                      head_group)
+    g = head_group(LAT_HEADS, q_tokens)
+    compiled = _compile(
+        lambda q4, pool, t, l: _latent_pallas(
+            q4, pool, t, l, kv_rank=LAT_RANK, scale=192 ** -0.5, group=g,
+            interpret=False),
+        _spec(one_chip, (rows, LAT_HEADS // g, q_tokens * g, LAT_W),
+              jnp.bfloat16),
+        _spec(one_chip, LAT_POOL, jnp.bfloat16),
+        _spec(one_chip, (rows, 66), jnp.int32),
+        _spec(one_chip, (rows,), jnp.int32))
+    txt = compiled.as_text()
+    assert "tpu_custom_call" in txt
+    # the pool reaches the kernel in the layout it is kept in: no copy
+    assert not [ln for ln in txt.split("ENTRY")[1].splitlines()
+                if "2561,576,128" in ln and " copy(" in ln]
+
+
+def test_latent_append_kernel(one_chip):
+    """A decode step's 64 new latent columns written in place."""
+    from libsplinter_tpu.ops.latent_attention import _append_pallas
+    compiled = _compile(
+        lambda pool, new, b, o: _append_pallas(pool, new, b, o,
+                                               interpret=False),
+        _spec(one_chip, LAT_POOL, jnp.bfloat16),
+        _spec(one_chip, (64, LAT_W, 1), jnp.bfloat16),
+        _spec(one_chip, (64,), jnp.int32),
+        _spec(one_chip, (64,), jnp.int32))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("rows", [512, 16384],
+                         ids=["decode-64x8", "prefill-chunk-2048x8"])
+def test_expert_grouped_matmul(one_chip, monkeypatch, rows):
+    """The grouped product over the 16 held experts, at the row counts
+    a decode step and a prefill chunk bring (worst case: every token's
+    8 slots held here)."""
+    from libsplinter_tpu.models.moe import grouped_matmul
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    for k, n in ((7680, 2048), (2048, 7680)):
+        compiled = _compile(
+            grouped_matmul,
+            _spec(one_chip, (rows, k), jnp.bfloat16),
+            _spec(one_chip, (16, k, n), jnp.bfloat16),
+            _spec(one_chip, (16,), jnp.int32))
+        assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_latent_decode_chunk_program(one_chip, monkeypatch):
+    """The whole 8-step decode chunk of the benchmark's configuration
+    (5 layers, 64 rows, 9.85 GB of weights + 1.89 GB of pages): it
+    compiles, fits the chip beside its arguments, and keeps the pools
+    in place."""
+    from libsplinter_tpu.models import mla
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = mla.LatentMoeConfig(
+        vocab_size=19200, hidden=7680, layers=5, heads=LAT_HEADS,
+        q_lora_rank=1536, kv_lora_rank=LAT_RANK, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, dense_layers=1,
+        dense_mlp_dim=18432, moe_mlp_dim=2048, n_routed_experts=256,
+        top_k=8, experts_first=48, experts_held=16,
+        routed_scaling_factor=2.5, rope_base=25.6e6, max_len=8448)
+    params = jax.tree_util.tree_map(
+        lambda a: _spec(one_chip, a.shape, a.dtype),
+        jax.eval_shape(lambda: mla.init_params(cfg, 0)))
+    m = mla.LatentCompletionModel(cfg, params=params)
+    fn = m._chunk_program(8, 64)
+    compiled = getattr(fn, "__wrapped__", fn).lower(
+        params, [_spec(one_chip, LAT_POOL, jnp.bfloat16)] * 5,
+        _spec(one_chip, (64, 66), jnp.int32),
+        _spec(one_chip, (64,), jnp.int32),
+        _spec(one_chip, (2,), jnp.uint32),
+        _spec(one_chip, (64,), jnp.int32),
+        _spec(one_chip, (64,), jnp.bool_),
+        _spec(one_chip, (64,), jnp.int32),
+        _spec(one_chip, (), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes > 11.5e9          # weights + pool
+    assert mem.temp_size_in_bytes < 1.5e9               # no pool copies
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15e9

@@ -134,7 +134,9 @@ def test_live_tree_is_clean(runner):
     assert reasons == {"libsplinter_tpu/engine/completer.py",
                        "libsplinter_tpu/engine/embedder.py",
                        "libsplinter_tpu/models/decoder.py",
+                       "libsplinter_tpu/models/mla.py",
                        "libsplinter_tpu/ops/flash_attention.py",
+                       "libsplinter_tpu/ops/latent_attention.py",
                        "libsplinter_tpu/ops/paged_attention.py",
                        "libsplinter_tpu/ops/similarity.py"}
 

@@ -63,13 +63,22 @@ from .resident import CallbackWindow
 
 log = logging.getLogger("libsplinter_tpu.searcher")
 
-# query-count pad buckets: a drain's requests batch into the smallest
-# bucket that holds them (chunked through the largest otherwise), so
-# the daemon compiles a handful of programs, not one per concurrency
-# level.  The floor of 8 matches the kernel's lane-width query pad —
-# a single query already computes 8 columns, so coalescing up to 8 is
-# literally free.
-QB_BUCKETS = (8, 32, 256)
+# query-count pad buckets: a drain's requests batch into ONE program
+# of the smallest bucket that holds them (chunked through the largest
+# beyond it), so the daemon compiles three programs per (k, precision),
+# not one per concurrency level.  What a dispatch costs is one scan of
+# the whole lane, nearly flat in the query count up to the fused
+# kernel's lane width: the floor of 8 is the kernel's sublane query
+# pad, the middle bucket IS that lane width
+# (ops.similarity.FUSED_Q_LANE: 9 to 128 requests ride one scan), and
+# only the last step pays a second lane tile.  The kernel's module imports jax and this one must not
+# (clients import submit_search from it), so the schedule is resolved
+# where it is used, daemon side.
+def qb_buckets() -> tuple[int, int, int]:
+    from ..ops.similarity import FUSED_Q_LANE
+
+    return (8, FUSED_Q_LANE, 256)
+
 
 # fetch-k pad buckets (candidates pulled per query).  Bounded by
 # ops.similarity.FUSED_K_MAX — the cushion above the request's k
@@ -91,23 +100,19 @@ def _k_bucket(k: int) -> int:
 
 
 def _qb_chunks(nq: int) -> list[int]:
-    """Decompose a drain's query count into QB bucket sizes with
-    padding waste bounded at 2x (the StagedLane _chunk_plan
-    discipline): 40 queries batch as [32, 8], never one 256-query
-    dispatch scoring 216 zero rows."""
+    """Decompose a drain's query count into QB bucket sizes by what a
+    dispatch costs — one scan of the lane whatever its width: the
+    largest bucket while more than it remains, then ONE cover bucket
+    for the tail.  40 queries are one 128-row dispatch whose zero
+    rows are sliced away: a second, narrower dispatch would read the
+    whole lane again for a handful of answers."""
+    buckets = qb_buckets()
     out: list[int] = []
-    smallest, largest = QB_BUCKETS[0], QB_BUCKETS[-1]
-    while nq > 0:
-        if nq >= largest:
-            out.append(largest)
-            nq -= largest
-            continue
-        cover = next(b for b in QB_BUCKETS if nq <= b)
-        if cover <= 2 * nq or cover == smallest:
-            out.append(cover)                 # tail: waste <= 2x
-            break
-        out.append(max(b for b in QB_BUCKETS if b <= nq))
-        nq -= out[-1]
+    while nq > buckets[-1]:
+        out.append(buckets[-1])
+        nq -= buckets[-1]
+    if nq > 0:
+        out.append(next(b for b in buckets if nq <= b))
     return out
 
 
@@ -310,7 +315,7 @@ class Searcher:
                 # fresh compile
                 for fast in (False, True):
                     fn = self._program(k_fetch, mxu_bf16=fast)
-                    for qb in QB_BUCKETS:
+                    for qb in qb_buckets():
                         fn(arr, np.zeros((qb, d), np.float32), mask,
                            self.lane.norms)
 
@@ -706,7 +711,7 @@ class Searcher:
         except Exception as ex2:
             log.warning("unfused retry failed (%s); degrading to "
                         "single-query dispatches", ex2)
-        qb0 = QB_BUCKETS[0]
+        qb0 = qb_buckets()[0]
         s_out = np.full((len(chunk), k_fetch), -np.inf, np.float32)
         i_out = np.full((len(chunk), k_fetch), -1, np.int64)
         ok = [False] * len(chunk)

@@ -276,6 +276,18 @@ def _topk_fn(k: int, batch: bool, use_pallas: bool, mxu_bf16: bool,
 # schedule (8, 64, 512) crosses this at its third step.
 FUSED_K_MAX = 128
 
+# Up to this many queries a fused dispatch costs ONE scan of the lane
+# whatever its width.  Q is the minor (lane) dimension of everything
+# the kernel computes after the matmul — the (TN, Q) scores, the
+# (K, Q) accumulator, the k_pad selection passes — and a vector
+# register is 8 x 128, so 8 query rows and 128 fill the same
+# registers; the second lane tile at 256 is what costs.  On the v5e
+# over 1,572,864 x 768 rows a dispatch takes 17.5 ms at 8, 32 or 64
+# queries, 20.0 at 128 and 31.3 at 256 (PERF.md section 6, PR 27).
+# The search daemon's middle batch width is this name
+# (engine/searcher.qb_buckets).
+FUSED_Q_LANE = 128
+
 
 def _fused_topk_kernel(vec_ref, q_ref, qnorm_ref, mask_ref,
                        out_s_ref, out_i_ref, *, k_pad: int,

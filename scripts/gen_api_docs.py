@@ -248,8 +248,11 @@ private round trips.
 | labels | `LBL_SEARCH_REQ` (bit 57) + optionally `LBL_WAITING`, then bump |
 
 The daemon drains every pending request per wake
-(signal group 4), groups by bloom mask, coalesces each group into
-QB-bucketed batches {8, 32, 256} against pre-compiled programs of the
+(signal group 4), groups by bloom mask, sends each group as ONE
+QB-bucketed batch {8, 128, 256} (a dispatch costs one scan of the
+lane, nearly flat in the query count up to the kernel's lane width
+`FUSED_Q_LANE` = 128; beyond 256 requests, 256-row batches and one
+cover batch for the tail) against pre-compiled programs of the
 **fused streaming top-k kernel** (`ops/similarity.topk_program`:
 block-local select + merge in VMEM, O(k*Q) off-chip, k <=
 `FUSED_K_MAX` = 128), and commits per-request results to the

@@ -29,6 +29,8 @@ import pytest
 from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
+from libsplinter_tpu.engine.searcher import qb_buckets
+
 H, D, PAGE = 12, 64, 128           # the default decoder's attention
 
 
@@ -75,10 +77,11 @@ def test_cosine_scores_kernel(one_chip):
 
 
 @pytest.mark.parametrize("k", [10, 64])
-@pytest.mark.parametrize("nq", [8, 256])
+@pytest.mark.parametrize("nq", qb_buckets())
 def test_fused_topk_kernel(one_chip, k, nq):
     """The search daemon's program over the smoke's 262,144 x 768
-    lane."""
+    lane, at each width of its batch schedule: the middle one is the
+    program a coalesced drain of 9-128 requests runs."""
     from libsplinter_tpu.ops.similarity import _fused_topk_fn
     n, d = 262_144, 768
     txt = _compile(

@@ -15,8 +15,8 @@ import pytest
 
 from libsplinter_tpu import Store
 from libsplinter_tpu.engine import protocol as P
-from libsplinter_tpu.engine.searcher import (QB_BUCKETS, Searcher,
-                                             daemon_live, submit_search)
+from libsplinter_tpu.engine.searcher import (Searcher, daemon_live,
+                                             qb_buckets, submit_search)
 from libsplinter_tpu.utils.trace import tracer
 
 
@@ -61,25 +61,27 @@ def _dense_ref(lane, q, exclude=()):
     return s
 
 
-def test_coalesces_concurrent_requests(store):
-    """Acceptance: 32 in-flight queries -> device dispatch count <=
-    ceil(32 / QB), with every per-request result correct."""
+@pytest.mark.parametrize("n", [32, 40])
+def test_coalesces_concurrent_requests(store, n):
+    """Acceptance: n in-flight queries that fit one program of the
+    kernel's lane width -> ONE device dispatch (a second, narrower
+    one would read the whole lane again), with every per-request
+    result equal to the dense scan with the request rows masked out."""
     rng = np.random.default_rng(1)
     _fill_docs(store, 64, rng)
     sr = Searcher(store)
     sr.attach()
-    qs = rng.normal(size=(32, store.vec_dim)).astype(np.float32)
-    keys = [f"__sqtmp_{1000 + i}" for i in range(32)]
+    qs = rng.normal(size=(n, store.vec_dim)).astype(np.float32)
+    keys = [f"__sqtmp_{1000 + i}" for i in range(n)]
     for key, q in zip(keys, qs):
         _request(store, key, q)
     req_slots = {store.find_index(k) for k in keys}
 
     served = sr.run_once()
-    assert served == 32
-    assert sr.stats.dispatches <= -(-32 // max(QB_BUCKETS)) + 1
-    assert sr.stats.dispatches == 1            # 32 fits one bucket
-    assert sr.stats.coalesced_max == 32
-    assert sr.stats.coalesce_ratio() == 32.0
+    assert served == n
+    assert sr.stats.dispatches == 1
+    assert sr.stats.coalesced_max == n
+    assert sr.stats.coalesce_ratio() == float(n)
 
     lane = np.array(store.vectors)
     for key, q in zip(keys, qs):
@@ -92,21 +94,33 @@ def test_coalesces_concurrent_requests(store):
         assert not store.labels(key) & (P.LBL_SEARCH_REQ | P.LBL_WAITING)
 
 
-def test_qb_chunk_plan():
-    """Query-count decomposition stays on the bucket schedule with
-    padding waste <= 2x — 40 queries must NOT pad to one 256 batch."""
+@pytest.mark.parametrize("nq,plan", [
+    (0, []), (1, [8]), (8, [8]), (9, [128]), (32, [128]), (40, [128]),
+    (64, [128]), (128, [128]), (129, [256]), (200, [256]),
+    (256, [256]), (257, [256, 8]), (300, [256, 128]),
+    (600, [256, 256, 128]), (700, [256, 256, 256])])
+def test_qb_chunk_plan(nq, plan):
+    """The plan follows what a dispatch costs — one scan of the lane
+    whatever its width: the largest bucket while more than it
+    remains, then ONE cover bucket for the tail."""
     from libsplinter_tpu.engine.searcher import _qb_chunks
-    assert _qb_chunks(1) == [8]
-    assert _qb_chunks(8) == [8]
-    assert _qb_chunks(32) == [32]
-    assert _qb_chunks(40) == [32, 8]
-    assert _qb_chunks(200) == [256]            # waste 1.28x: one batch
-    assert _qb_chunks(300) == [256, 32, 8, 8]
-    assert _qb_chunks(600) == [256, 256, 32, 32, 32]
-    for nq in range(1, 700):
+    assert _qb_chunks(nq) == plan
+
+
+def test_qb_chunk_plan_holds_for_every_count():
+    from libsplinter_tpu.engine.searcher import _qb_chunks
+    from libsplinter_tpu.ops.similarity import FUSED_Q_LANE
+    buckets = qb_buckets()
+    assert len(buckets) == 3 and buckets[1] == FUSED_Q_LANE
+    for nq in range(1, 701):
         plan = _qb_chunks(nq)
-        assert sum(plan) >= nq
-        assert sum(plan) <= max(2 * nq, 8), (nq, plan)
+        assert sum(plan) >= nq, (nq, plan)
+        assert set(plan) <= set(buckets), (nq, plan)
+        # at most one bucket below the largest, and it comes last
+        assert all(b == buckets[-1] for b in plan[:-1]), (nq, plan)
+        assert sum(plan[:-1]) < nq, (nq, plan)
+        if nq <= FUSED_Q_LANE:
+            assert len(plan) == 1, (nq, plan)
 
 
 def test_system_rows_never_surface(store):

@@ -7,7 +7,6 @@
 #   make check      the native check tier (TAP + MRSW stress + MRMW
 #                   chi-sao) + full pytest
 #   make memcheck   valgrind (if installed) or ASan/UBSan native tier
-#   make bench-cpu  quick host-CPU bench (embed + store_ops phases)
 #   make obs-check  observability tier: tracing-overhead budget
 #                   (scripts/obs_overhead_check.py, <3% vs disabled)
 #                   + the `-m obs` pytest group
@@ -27,9 +26,6 @@
 #   make dispatch-check  dispatch-floor tier: resident-ring /
 #                   K-overlap parity vs the per-call paths (byte-
 #                   identical vectors, search results, decode tokens)
-#                   + the depth-amortization smoke (per-drain host
-#                   overhead must shrink monotonically with depth;
-#                   scripts/dispatch_amortization_check.py)
 #   make pod-check  pod-sharded paged decode tier (fast, CPU
 #                   8-device mesh): sharded-paged vs single-chip-
 #                   paged vs serial token-exact parity, the
@@ -47,10 +43,11 @@
 #   make pipeline-check  pipeline-lane tier (fast, CPU): sandbox
 #                   containment (hostile scripts die typed while
 #                   siblings complete), scripted-chain end-to-end
-#                   parity, and the script-vs-client-chaining latency
-#                   smoke (stored-script rag-churn p50 >= 30% below
-#                   the client-side chain;
-#                   scripts/pipeline_latency_check.py)
+#                   parity, and the round-trip gate (the client-
+#                   side rag-churn chain costs one client round trip
+#                   per hop, the stored script ONE request, zero
+#                   admitted loss either way;
+#                   scripts/pipeline_roundtrip_check.py)
 #   make trace-check  cross-lane tracing + telemetry tier (fast,
 #                   CPU): trace-context stamp round-trips, span-ring
 #                   wire protocol (staging, crash recovery with
@@ -79,18 +76,18 @@
 #                   int8, single-chip + tp=2), >= 4x rows per page
 #                   budget, LRU eviction + tenant quotas, mid-flight
 #                   joiner parity, loadgen --shared-prefix, and the
-#                   hot-vs-cold admission-to-first-token gate
-#                   (scripts/prefix_speedup_check.py, >= 5x on the
-#                   in-process CPU stack)
+#                   hot-admission gate (scripts/prefix_hit_check.py:
+#                   greedy bytes identical with and without the
+#                   cache, every hot admission maps all of its
+#                   prompt pages and dispatches no prefill)
 #   make disagg-check  disaggregated prefill/decode tier (fast,
 #                   CPU): PrefillLane + DecodeLane on one store,
-#                   driven through loadgen's prefill-burst scenario —
-#                   the decode floor's inter-chunk p99 under a 10x
-#                   prefill rate step must stay within 1.2x of the
-#                   prefill-idle baseline (plus a small absolute
-#                   slack), with zero admitted loss and the page
-#                   handoff running the real wire export/import path
-#                   (scripts/disagg_check.py) + the test_disagg.py
+#                   driven through loadgen's prefill-burst scenario
+#                   with a 10x prefill rate step — zero admitted
+#                   loss, and the page handoff runs the real wire
+#                   export/import path (handoffs exported and
+#                   adopted, none refilled;
+#                   scripts/disagg_check.py) + the test_disagg.py
 #                   fast tier (byte-exactness vs the unified
 #                   completer, handoff crash drills both directions)
 #   make warm-check  tiered-KV warm-restart tier (fast, CPU): one
@@ -100,9 +97,7 @@
 #                   WARM (index restored, hot set readmitted from the
 #                   tier instead of re-prefilled, greedy bytes
 #                   identical across the restart), with zero admitted
-#                   loss and post-restart first-token p50 <= 2x the
-#                   pre-restart baseline
-#                   (scripts/warm_restart_check.py) + the
+#                   loss (scripts/warm_restart_check.py) + the
 #                   test_kv_tier.py fast tier (write-through spill /
 #                   readmit byte-exactness, torn-snapshot taxonomy,
 #                   capacity-drop pruning)
@@ -163,11 +158,10 @@ check: native
 	$(MAKE) -C native check
 	$(PY) scripts/splint_check.py
 	$(PY) scripts/obs_overhead_check.py
-	JAX_PLATFORMS=cpu $(PY) scripts/dispatch_amortization_check.py
 	JAX_PLATFORMS=cpu $(PY) scripts/quant_pool_bytes_check.py
 	JAX_PLATFORMS=cpu $(PY) scripts/qos_fairness_check.py
-	JAX_PLATFORMS=cpu $(PY) scripts/pipeline_latency_check.py
-	JAX_PLATFORMS=cpu $(PY) scripts/prefix_speedup_check.py
+	JAX_PLATFORMS=cpu $(PY) scripts/pipeline_roundtrip_check.py
+	JAX_PLATFORMS=cpu $(PY) scripts/prefix_hit_check.py
 	JAX_PLATFORMS=cpu $(PY) scripts/scale_step_check.py
 	JAX_PLATFORMS=cpu $(PY) scripts/disagg_check.py
 	JAX_PLATFORMS=cpu $(PY) scripts/warm_restart_check.py
@@ -193,7 +187,6 @@ chaos-check: native
 dispatch-check: native
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/test_resident.py -q \
 		-m "not chaos"
-	JAX_PLATFORMS=cpu $(PY) scripts/dispatch_amortization_check.py
 
 pod-check: native
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/test_sharded_paged.py \
@@ -222,7 +215,7 @@ quant-check: native
 prefix-check: native
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/test_prefix_cache.py -q \
 		-m "not slow and not chaos"
-	JAX_PLATFORMS=cpu $(PY) scripts/prefix_speedup_check.py
+	JAX_PLATFORMS=cpu $(PY) scripts/prefix_hit_check.py
 
 # no `native` dep: splint is stdlib-ast only and must be runnable
 # before (or without) any build step — the cheapest pre-commit gate
@@ -252,14 +245,10 @@ compile-check: native
 pipeline-check: native
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/test_pipeliner.py -q \
 		-m "not slow and not chaos"
-	JAX_PLATFORMS=cpu $(PY) scripts/pipeline_latency_check.py
+	JAX_PLATFORMS=cpu $(PY) scripts/pipeline_roundtrip_check.py
 
 memcheck: native
 	$(MAKE) -C native memcheck
-
-bench-cpu:
-	BENCH_CPU=1 BENCH_TEXTS=256 BENCH_BATCH=64 \
-	    BENCH_PHASES=embed,store_ops $(PY) bench.py
 
 clean:
 	$(MAKE) -C native clean
@@ -267,4 +256,4 @@ clean:
 .PHONY: all native quick check obs-check search-check decode-check \
 	chaos-check dispatch-check pod-check quant-check prefix-check \
 	qos-check pipeline-check trace-check lint-check scale-check \
-	disagg-check warm-check compile-check memcheck bench-cpu clean
+	disagg-check warm-check compile-check memcheck clean

@@ -1,6 +1,6 @@
 """`spt loadgen` — the open-loop multi-tenant traffic generator.
 
-Every bench phase before this was a CLOSED loop: N well-behaved
+Every load driver before this was a CLOSED loop: N well-behaved
 clients each waiting for their last request before issuing the next,
 so offered load could never exceed service rate and the admission /
 fairness / shedding machinery (engine/qos.py) had nothing to survive.
@@ -208,8 +208,8 @@ class _Req:
 
 
 class LoadGenerator:
-    """Programmatic surface (tests and the bench phase drive this
-    directly; `spt loadgen` is a thin flag parser over it)."""
+    """Programmatic surface (tests and the `make check` gates drive
+    this directly; `spt loadgen` is a thin flag parser over it)."""
 
     def __init__(self, store, tenants: list[TenantSpec], *,
                  duration_s: float = 5.0,

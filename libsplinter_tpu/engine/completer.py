@@ -2450,8 +2450,8 @@ def main(argv: list[str] | None = None) -> int:
                          "prompt prefixes map refcounted pool pages "
                          "into the joiner's block table instead of "
                          "re-prefilling — engine/prefix_cache.py; "
-                         "the A/B knob scripts/prefix_speedup_check "
-                         "measures against)")
+                         "the A/B knob scripts/prefix_hit_check.py "
+                         "compares against)")
     ap.add_argument("--prefix-cache-pages", type=int, default=None,
                     help="global cap on pool pages the prefix cache "
                          "may retain (default: unlimited — zero-ref "
@@ -2524,7 +2524,7 @@ def main(argv: list[str] | None = None) -> int:
     if args.weights == "int8":
         # `--weights int8` sentinel: no checkpoint file — run the
         # seeded-random decoder with per-output-channel int8 weight
-        # residency (the bench/docs spelling of --weights-int8)
+        # residency (the docs' spelling of --weights-int8)
         args.weights = None
         args.weights_int8 = True
     if args.weights and args.weights.endswith(".gguf"):

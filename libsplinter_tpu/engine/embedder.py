@@ -81,7 +81,7 @@ class EmbedderStats:
     shed: int = 0               # unblocked label-only past high water
     deferred: int = 0           # held for a later drain (fairness)
     # -- commit-pipeline telemetry (the overlap is measured, not
-    # asserted: bench.py's p50 stage table reads these) --------------
+    # asserted: the heartbeat and `spt metrics` carry these) ---------
     futures_dispatched: int = 0
     futures_resolved: int = 0
     ready_commits: int = 0      # future already complete at commit time
@@ -123,7 +123,7 @@ class CommitPipeline(InflightWindow):
     ready — back-pressure, not a synchronous round-trip per batch.
     The old path forced each batch FIFO with a blocking device_get
     inside the wake handler: wake->commit paid the full device
-    round-trip every time (BENCH_r05: 62.2 of the 67.2 ms p50).
+    round-trip every time.
     """
 
     def __init__(self, commit_fn, stats: EmbedderStats, depth: int,
@@ -1168,8 +1168,8 @@ class Embedder:
             payload[k] = round(payload[k], 3)
         if tracer.enabled:
             # histogram-sourced per-stage quantiles under the
-            # PIPELINE_STAGES names — what bench.py's stage table and
-            # `spt metrics` consume (true percentiles, never means)
+            # PIPELINE_STAGES names — what `spt metrics` consumes
+            # (true percentiles, never means)
             P.attach_trace_sections(payload, tracer, self.recorder,
                                     "embed.")
         P.publish_heartbeat(self.store, self._hb_key, payload)

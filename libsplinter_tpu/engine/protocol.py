@@ -159,8 +159,8 @@ CTX_GUARD_FRACTION = 0.9
 
 # --- commit-pipeline stage contract --------------------------------------
 # The wake->commit path decomposes into these stages; every stats
-# surface (the embedder heartbeat's quantiles section, bench's
-# stage_quantiles, flight-recorder event sequences) uses these names
+# surface (the embedder heartbeat's quantiles section,
+# flight-recorder event sequences) uses these names
 # so dashboards and before/after comparisons line up.  device_wait is
 # the time the host BLOCKED on a
 # device future; overlapped device time (future in flight while the

@@ -1,8 +1,8 @@
 """Resident device loops + K-deep dispatch overlap — the common
 machinery that breaks the per-drain runtime dispatch floor.
 
-Every dispatch pays a per-call XLA runtime round trip (the bench's
-null_dispatch_ms; not measured on the current machine), whatever the
+Every dispatch pays a per-call XLA runtime round trip (PERF.md §6,
+PR 27, read dispatch + fetch at ~2.5 ms on the v5e), whatever the
 kernels cost.  One dispatch per drain therefore floors EVERY hot-lane
 latency at that round trip.  Two complementary mechanisms amortize it,
 both defined here so the three lane daemons share one contract:

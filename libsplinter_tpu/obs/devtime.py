@@ -24,8 +24,7 @@ wrapper buys two things the span plane cannot see:
     host observing the result: on a saturated device it converges on
     device execution time (jax's async dispatch returns immediately);
     under light load it includes device idle — a ceiling, never an
-    undercount, and exactly the number the dispatch-amortization
-    analysis needs per program.
+    undercount.
 
 Everything here is host-side stdlib + store calls — no jax import —
 so lanes, the CLI, and tests import it freely.  The plane is ON by
@@ -295,7 +294,7 @@ class DevtimeRegistry:
 
     def device_ms_share(self) -> float:
         """Device-window ms as a share of wall time since the registry
-        started — the bench ledger's attribution column."""
+        started."""
         wall_ms = max(time.time() - self._t0, 1e-9) * 1e3
         return min(self._device_ms_total / wall_ms, 1.0)
 

@@ -130,9 +130,8 @@ def _chunk_plan(n: int) -> list[int]:
     a fresh program) with padding waste bounded at 2x.
 
     The old single-scatter path padded n up to one bucket: 8,192 dirty
-    rows became one 32,768-row scatter — a 4x transfer cliff that
-    measured 53x in wall time at scale (BENCH_r05: 46.7 ms at 128 dirty
-    -> 2,473 ms at 8,192).  Chunking keeps cost piecewise-linear: take
+    rows became one 32,768-row scatter — a 4x transfer cliff.
+    Chunking keeps cost piecewise-linear: take
     the largest bucket that fits while the remainder is big, stop as
     soon as padding the tail wastes no more than 2x.
 

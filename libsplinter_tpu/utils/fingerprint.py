@@ -1,11 +1,10 @@
 """Deterministic text -> vector oracle for MRMW integrity harnesses.
 
-Shared by tests/test_mrmw_embed.py (CI scale) and
-scripts/bench_mrmw_embed.py (sustained) so both validate against the
-SAME oracle: a committed vector must equal the fingerprint of a
-version the key actually held — a torn or mixed read yields a vector
-matching no version (the TPU-framework analog of the reference MRMW
-harness's validated payload format, splinter_stress.c parse_ver).
+Used by tests/test_mrmw_embed.py: a committed vector must equal the
+fingerprint of a version the key actually held — a torn or mixed read
+yields a vector matching no version (the TPU-framework analog of the
+reference MRMW harness's validated payload format, splinter_stress.c
+parse_ver).
 """
 from __future__ import annotations
 

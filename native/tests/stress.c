@@ -137,8 +137,7 @@ static void *reader(void *arg) {
   return NULL;
 }
 
-/* --json: emit one machine-readable line for the bench ledger
- * (scripts/bench_store_ops / bench_series store_ops phase).  CPO
+/* --json: emit one machine-readable line.  CPO
  * (cycles per op) is measured separately from the contended run: a
  * single-threaded spt_set loop over pre-rendered keys, timed with the
  * store's own tick clock (spt_now = rdtsc/cntvct), so the number is

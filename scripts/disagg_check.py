@@ -1,31 +1,27 @@
 #!/usr/bin/env python
-"""CI gate: decode isolation under a prefill burst, in-process.
+"""CI gate: the disaggregated pair loses nothing under a prefill
+burst and hands its pages over the wire, in-process.
 
-Runs the disaggregated pair — PrefillLane + DecodeLane on one shared
-store (ISSUE 18) — and drives `spt loadgen`'s prefill-burst scenario
-through a 1x -> 10x -> 1x prompt-heavy rate step while a steady
-decode-floor tenant streams underneath.  Asserts the tentpole's
-serving contract at smoke scale:
+Runs PrefillLane + DecodeLane on one shared store (ISSUE 18) and
+drives `spt loadgen`'s prefill-burst scenario through a 1x -> 10x ->
+1x prompt-heavy rate step while a steady decode-floor tenant streams
+underneath.  It counts, it does not time: how far a burst moves the
+decode floor's inter-chunk latency is a device question, and no
+benchmark cell runs the split pair yet (PERF.md §7).  Asserted:
 
-  - the decode floor's inter-chunk p99 during the 10x prefill burst
-    stays within 1.2x of the prefill-idle baseline (plus a small
-    absolute slack so a 1-core CI box's scheduler jitter cannot flake
-    the ratio on a ~5 ms baseline);
   - ZERO admitted-request loss (loadgen's `lost` classification is
     the drain-protocol contract, same as scale_step_check);
   - the handoff plane actually ran: prefill handed off wire pages and
     decode adopted them (handoff_refill == 0 — the store is sized so
-    real page export/import is what gets measured, not the re-prefill
+    real page export/import is what runs, not the re-prefill
     fallback).
-
-The baseline run and the burst run share one warm lane pair, so
-compile time never lands inside a measured gap.
 
 Run: JAX_PLATFORMS=cpu python scripts/disagg_check.py
 (make disagg-check wires it into make check.)
 """
 from __future__ import annotations
 
+import json
 import os
 import sys
 import threading
@@ -48,17 +44,7 @@ from libsplinter_tpu.models.decoder import (CompletionModel,  # noqa: E402
 
 STORE = f"/spt-disagg-check-{os.getpid()}"
 RATE = 2.0                          # 1x offered rate per class (req/s)
-IDLE_PROFILE = [(1.0, 4.0)]
 BURST_PROFILE = [(1.0, 2.0), (10.0, 6.0), (1.0, 2.0)]
-RATIO = 1.2                         # the ISSUE 18 acceptance bound
-SLACK_MS = 50.0                     # absolute floor for tiny baselines
-
-
-def _floor_p99(report: dict, phase: int) -> float | None:
-    for row in report.get("prefill_burst", []):
-        if row.get("phase") == phase:
-            return row.get("decode-floor", {}).get("interchunk_p99_ms")
-    return None
 
 
 def main() -> int:
@@ -84,7 +70,7 @@ def main() -> int:
             th.start()
 
         # warm the pair end-to-end (prefill bucket + decode chunk
-        # compiles) before anything is measured
+        # compiles) so the burst meets a serving pair
         for i in range(3):
             key = f"__warm/{i}"
             store.set(key, f"warm {i} up")
@@ -100,61 +86,39 @@ def main() -> int:
             print("FAIL: warmup requests never completed")
             return 1
 
-        def run(profile, seed):
-            gen = LoadGenerator(
-                store, [TenantSpec(tenant=1, rate=RATE,
-                                   deadline_ms=60_000)],
-                scenario="prefill-burst", rate_profile=profile,
-                corpus=16, seed=seed, drain_s=45.0)
-            return gen.run()
-
-        idle_rep = run(IDLE_PROFILE, seed=11)
-        burst_rep = run(BURST_PROFILE, seed=12)
-
-        p99_idle = _floor_p99(idle_rep, 0)
-        p99_burst = _floor_p99(burst_rep, 1)
+        rep = LoadGenerator(
+            store, [TenantSpec(tenant=1, rate=RATE,
+                               deadline_ms=60_000)],
+            scenario="prefill-burst", rate_profile=BURST_PROFILE,
+            corpus=16, seed=12, drain_s=45.0).run()
         pf, dl = lanes[0]._lane_stats, lanes[1]._lane_stats
-        lost = idle_rep["lost"] + burst_rep["lost"]
-
-        print(f"disagg_check: idle floor inter-chunk p99 = "
-              f"{p99_idle} ms; burst (10x prefill) = {p99_burst} ms; "
-              f"lost={lost}")
-        print(f"  prefill: handoffs={pf.get('handoffs')} "
-              f"failed={pf.get('handoff_failed')} "
-              f"wire_mb={pf.get('handoff_wire_mb')}")
-        print(f"  decode: adopted={dl.get('adopted')} "
-              f"readopted={dl.get('readopted')} "
-              f"refill={dl.get('handoff_refill')} "
-              f"backpressure={dl.get('adopt_backpressure')}")
+        counts = {"issued": rep["issued"],
+                  "completed": rep["ok"] + rep["ok_late"],
+                  "lost": rep["lost"],
+                  "handoffs": pf.get("handoffs", 0),
+                  "handoff_failed": pf.get("handoff_failed", 0),
+                  "adopted": dl.get("adopted", 0),
+                  "readopted": dl.get("readopted", 0),
+                  "handoff_refill": dl.get("handoff_refill", 0),
+                  "adopt_backpressure": dl.get("adopt_backpressure", 0)}
 
         fails = []
-        if p99_idle is None or p99_burst is None:
-            fails.append("missing inter-chunk quantiles (floor tenant "
-                         "streamed no multi-chunk completions)")
-        else:
-            bound = max(RATIO * p99_idle, p99_idle + SLACK_MS)
-            if p99_burst > bound:
-                fails.append(
-                    f"decode p99 degraded under prefill burst: "
-                    f"{p99_burst:.1f} ms > bound {bound:.1f} ms "
-                    f"(idle {p99_idle:.1f} ms)")
-        if lost:
-            fails.append(f"{lost} admitted requests LOST "
+        if counts["lost"]:
+            fails.append(f"{counts['lost']} admitted requests LOST "
                          "(zero-loss contract)")
-        if not pf.get("handoffs"):
+        if not counts["completed"]:
+            fails.append("no request completed")
+        if not counts["handoffs"]:
             fails.append("prefill lane recorded zero handoffs")
-        if not dl.get("adopted"):
+        if not counts["adopted"]:
             fails.append("decode lane adopted zero rows")
-        if dl.get("handoff_refill"):
-            fails.append(f"{dl['handoff_refill']} adoptions fell back "
-                         "to re-prefill (wire path not exercised)")
-        if fails:
-            print("disagg_check: FAIL — " + "; ".join(fails))
-            return 1
-        print("disagg_check: PASS — decode floor held its inter-chunk "
-              "p99 through a 10x prefill burst with zero admitted "
-              "loss")
-        return 0
+        if counts["handoff_refill"]:
+            fails.append(f"{counts['handoff_refill']} adoptions fell "
+                         "back to re-prefill (wire path not "
+                         "exercised)")
+        print(json.dumps({"check": "disagg", "ok": not fails,
+                          "fails": fails, **counts}))
+        return 1 if fails else 0
     finally:
         for d in lanes:
             d.stop()

@@ -381,8 +381,7 @@ With tracing on, `__embedder_stats` / `__completer_stats` gain:
   aggregate shape, kept for old consumers);
 - `quantiles` — histogram-sourced `{n, total_ms, max_ms, p50_ms,
   p90_ms, p95_ms, p99_ms}` keyed by the pinned stage names (prefix
-  stripped) — what `bench.py`'s stage table and `spt metrics`
-  consume;
+  stripped) — what `spt metrics` consumes;
 - `recorder` — `{recorded, dropped, slow_promoted,
   slow_threshold_ms}`;
 - `slow_log` — promoted slow requests, each
@@ -472,8 +471,7 @@ switch, and warmup dispatches never open device windows):
   heartbeats; the search daemon takes the program's mark right after
   each dispatch and closes it at that batch's fetch, so `n` counts
   dispatches; rendered as
-  `sptpu_<lane>_devtime_*{program=...}`).  The bench ledger rows
-  carry `compile_events` + `device_ms_share`.
+  `sptpu_<lane>_devtime_*{program=...}`).
 
 HBM watermarks ride the completer heartbeat beside the live gauges:
 `pool_mb_peak` (measured placed-buffer MB high-water) and

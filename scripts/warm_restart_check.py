@@ -4,20 +4,20 @@
 Runs one tiered completer lane (`--kv-tier-pages` + a persistent
 `--kv-tier-persist` segment, ISSUE 19) under `spt supervise`, drives
 `spt loadgen` through it, SIGKILLs the lane MID-LOAD, and asserts the
-warm-restart contract at smoke scale:
+warm-restart contract at smoke scale.  It counts, it does not time:
+what a readmission saves over a re-prefill in milliseconds is the
+chip's to say, and no benchmark cell restarts a lane yet (PERF.md §7).
 
   - zero admitted-request loss through the kill (the respawned lane
     reclaims every stranded claim — loadgen's `lost` classification);
   - the respawn attaches WARM: the persistent radix index restores
-    (heartbeat tier_restored > 0, no typed tier_restore_reason) and
-    the hot prompts served before the kill come back via DRAM/file
-    readmission (tier_readmits > 0, prefix_hits > 0) — not re-prefill;
-  - greedy bytes for those prompts are identical across the restart;
-  - post-restart first-token p50 stays within 2x of the pre-restart
-    baseline (plus a small absolute slack so a 1-core CI box's
-    scheduler jitter cannot flake a ~5 ms baseline).  Both measured
-    windows run against a warmed lane — compile time never lands
-    inside a measured TTFT.
+    (heartbeat tier_restored > 0, no typed tier_restore_reason);
+  - the hot prompts served before the kill come back via DRAM/file
+    readmission, not re-prefill: over the three hot prompts the
+    respawn counts tier_readmits > 0, no prefix miss, and every full
+    page of every prompt mapped (`prefix_tokens`, as the completer
+    counts them);
+  - greedy bytes for those prompts are identical across the restart.
 
 Run: JAX_PLATFORMS=cpu python scripts/warm_restart_check.py
 (make warm-check wires it into make check.)
@@ -36,8 +36,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 STORE = f"/spt-warm-check-{os.getpid()}"
-RATIO = 2.0                         # the ISSUE 19 acceptance bound
-SLACK_MS = 50.0                     # absolute floor for tiny baselines
+PAGE = 8
 WARM_PROMPTS = [f"the warm set prompt number {i} stays hot"
                 for i in range(3)]
 
@@ -56,21 +55,16 @@ def child(store_name: str, persist_name: str) -> int:
     model = CompletionModel(DecoderConfig.tiny(dtype=jnp.float32),
                             buckets=(32,), temp=0.0, seed=1,
                             suffix_buckets=(8,))
+    # the tier holds the hot set AND everything the kill window's own
+    # prompts spill (~220 pages): a tier the load overflows drops the
+    # hot set before the respawn can readmit it
     comp = Completer(st, model=model, max_new_tokens=10,
                      flush_tokens=2, template="none", batch_cap=4,
-                     page_size=8, kv_tier_pages=64,
+                     page_size=PAGE, kv_tier_pages=1024,
                      kv_tier_persist=persist_name)
     comp.attach()
     comp.run_continuous(idle_timeout_ms=10, stop_after=900.0)
     return 0
-
-
-def _ttft_p50(report: dict) -> float | None:
-    for row in report.get("prefill_burst", []):
-        sect = row.get("prefill-burst") or {}
-        if "ttft_p50_ms" in sect:
-            return sect["ttft_p50_ms"]
-    return None
 
 
 def main() -> int:
@@ -114,13 +108,20 @@ def main() -> int:
             time.sleep(0.05)
         return False
 
-    def run_loadgen(seed):
-        gen = LoadGenerator(
-            store, [TenantSpec(tenant=1, rate=2.0,
-                               deadline_ms=120_000)],
-            scenario="prefill-burst", rate_profile=[(1.0, 8.0)],
-            corpus=16, seed=seed, drain_s=90.0)
-        return gen.run()
+    def heartbeat():
+        return json.loads(
+            store.get(P.KEY_COMPLETE_STATS).rstrip(b"\0"))
+
+    def wait_heartbeat(generation, ok, pause, timeout=60):
+        """The first heartbeat of lane generation `generation` or later
+        that `ok` accepts, read every `pause` seconds."""
+        deadline = time.monotonic() + timeout
+        while time.monotonic() < deadline:
+            snap = heartbeat()
+            if snap.get("generation", 0) >= generation and ok(snap):
+                return snap
+            time.sleep(pause)
+        return None
 
     try:
         # warm the lane AND plant the hot set the restart must revive
@@ -131,17 +132,20 @@ def main() -> int:
             print("FAIL: warmup requests never completed")
             return 1
         pre_bytes = [store.get(k).rstrip(b"\0") for k in warm_keys]
-
-        rep_pre = run_loadgen(seed=31)
-        # let one more dirty-gated checkpoint beat land (5s cadence)
-        # so the snapshot covers the loadgen window's inserts too
+        gen_hb = heartbeat().get("generation", 0)
+        # let a dirty-gated checkpoint beat land so the snapshot
+        # covers the hot set's inserts
         time.sleep(6.0)
 
-        # SIGKILL mid-load: a third loadgen window is in flight when
-        # the lane dies — the respawn must reclaim every claim
+        # SIGKILL mid-load: a loadgen window is in flight when the
+        # lane dies — the respawn must reclaim every claim
         holder: dict = {}
         kt = threading.Thread(
-            target=lambda: holder.update(rep=run_loadgen(seed=32)))
+            target=lambda: holder.update(rep=LoadGenerator(
+                store, [TenantSpec(tenant=1, rate=2.0,
+                                   deadline_ms=120_000)],
+                scenario="prefill-burst", rate_profile=[(1.0, 8.0)],
+                corpus=16, seed=32, drain_s=90.0).run()))
         kt.start()
         time.sleep(2.0)
         lane = sup.lanes["completer"]
@@ -163,9 +167,22 @@ def main() -> int:
             print("FAIL: supervisor never respawned the lane")
             return 1
 
+        # every request of the kill window is terminal, so the
+        # respawn's counters stand still: two reads more than one 2 s
+        # beat apart that agree are the state the hot set meets
+        seen: list[int] = []
+
+        def still(snap):
+            seen.append(snap.get("completions", 0))
+            return len(seen) > 1 and seen[-1] == seen[-2]
+
+        before = wait_heartbeat(gen_hb + 1, still, 2.2)
+        if before is None:
+            print("FAIL: the respawned lane published no heartbeat")
+            return 1
+
         # the SAME prompts through the respawned lane: must come back
-        # byte-identical via the restored index + readmission (and
-        # re-warm the new process so measured TTFT excludes compiles)
+        # byte-identical via the restored index + readmission
         rewarm_keys = [f"__rewarm/{i}"
                        for i in range(len(WARM_PROMPTS))]
         for k, p in zip(rewarm_keys, WARM_PROMPTS):
@@ -174,61 +191,68 @@ def main() -> int:
             print("FAIL: post-restart requests never completed")
             return 1
         post_bytes = [store.get(k).rstrip(b"\0") for k in rewarm_keys]
+        served = before.get("completions", 0) + len(WARM_PROMPTS)
+        snap = wait_heartbeat(
+            gen_hb + 1, lambda s: s.get("completions", 0) >= served, 0.2)
+        if snap is None:
+            print("FAIL: the heartbeat never counted the hot set")
+            return 1
 
-        rep_post = run_loadgen(seed=33)
-        snap = json.loads(
-            store.get(P.KEY_COMPLETE_STATS).rstrip(b"\0"))
+        def hot(key):
+            return snap.get(key, 0) - before.get(key, 0)
 
-        p50_pre = _ttft_p50(rep_pre)
-        p50_post = _ttft_p50(rep_post)
-        lost = (rep_pre["lost"] + rep_kill["lost"]
-                + rep_post["lost"])
-        print(f"warm_check: ttft p50 pre={p50_pre} ms "
-              f"post={p50_post} ms; lost={lost}; "
-              f"restarts={lane.restarts}")
-        print(f"  tier: restored={snap.get('tier_restored')} "
-              f"readmits={snap.get('tier_readmits')} "
-              f"pages={snap.get('tier_pages')} "
-              f"reason={snap.get('tier_restore_reason', '')!r} "
-              f"prefix_hits={snap.get('prefix_hits')}")
+        # byte tokenizer: BOS + one token per char; only FULL pages
+        # are shared, the tail of each prompt is suffix-prefilled
+        hot_tokens = sum(len(p) + 1 for p in WARM_PROMPTS)
+        hot_mapped = sum((len(p) + 1) // PAGE * PAGE
+                         for p in WARM_PROMPTS)
+        counts = {
+            "lost": rep_kill["lost"], "issued": rep_kill["issued"],
+            "restarts": lane.restarts,
+            "bytes_identical": post_bytes == pre_bytes,
+            "tier_restored": snap.get("tier_restored", 0),
+            "tier_restore_reason": snap.get("tier_restore_reason", ""),
+            "hot_prompt_tokens": hot("prompt_tokens"),
+            "hot_prefix_tokens": hot("prefix_tokens"),
+            "hot_tier_readmits": hot("tier_readmits"),
+            "hot_prefix_hits": hot("prefix_hits"),
+            "hot_prefix_misses": hot("prefix_misses"),
+        }
 
         fails = []
-        if lost:
-            fails.append(f"{lost} admitted requests LOST "
+        if counts["lost"]:
+            fails.append(f"{counts['lost']} admitted requests LOST "
                          "(zero-loss contract)")
         if lane.restarts < 1:
             fails.append("the lane never restarted (kill not seen)")
         if post_bytes != pre_bytes:
             fails.append("hot-prompt bytes changed across the "
                          "restart (greedy must be identical)")
-        if not snap.get("tier_restored"):
+        if not counts["tier_restored"]:
             fails.append("respawn attached COLD (tier_restored == 0 "
                          "— persistent index not restored)")
-        if snap.get("tier_restore_reason"):
+        if counts["tier_restore_reason"]:
             fails.append("typed cold fallback: tier_restore_reason="
-                         f"{snap['tier_restore_reason']!r}")
-        if not snap.get("tier_readmits"):
+                         f"{counts['tier_restore_reason']!r}")
+        if counts["hot_prompt_tokens"] != hot_tokens:
+            fails.append(f"the heartbeat counted "
+                         f"{counts['hot_prompt_tokens']} hot prompt "
+                         f"tokens, expected {hot_tokens}")
+        if counts["hot_tier_readmits"] < 1:
             fails.append("no readmissions: the warm set was "
                          "re-prefilled, not readmitted")
-        if not snap.get("prefix_hits"):
-            fails.append("radix hit rate did not recover post-"
-                         "restart (prefix_hits == 0)")
-        if p50_pre is None or p50_post is None:
-            fails.append("missing TTFT quantiles in a loadgen window")
-        else:
-            bound = max(RATIO * p50_pre, p50_pre + SLACK_MS)
-            if p50_post > bound:
-                fails.append(
-                    f"post-restart first-token p50 degraded: "
-                    f"{p50_post:.1f} ms > bound {bound:.1f} ms "
-                    f"(pre {p50_pre:.1f} ms)")
-        if fails:
-            print("warm_check: FAIL — " + "; ".join(fails))
-            return 1
-        print("warm_check: PASS — supervised kill-and-restart came "
-              "back warm (index restored, hot set readmitted, bytes "
-              "identical, first-token p50 within bound, zero loss)")
-        return 0
+        if counts["hot_prefix_misses"] or \
+                counts["hot_prefix_hits"] != len(WARM_PROMPTS):
+            fails.append(f"hot set: {counts['hot_prefix_hits']} hits, "
+                         f"{counts['hot_prefix_misses']} misses of "
+                         f"{len(WARM_PROMPTS)} prompts")
+        if counts["hot_prefix_tokens"] != hot_mapped:
+            fails.append(f"hot set re-prefilled: mapped "
+                         f"{counts['hot_prefix_tokens']} of "
+                         f"{hot_mapped} full-page tokens")
+        print(json.dumps({"check": "warm_restart", "ok": not fails,
+                          "fails": fails, **counts}))
+        return 1 if fails else 0
     finally:
         sup.stop()
         sup_t.join(timeout=30)

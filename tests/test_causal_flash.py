@@ -18,14 +18,17 @@ def _rand(shape, seed=0):
         0, 1, shape).astype(np.float32)
 
 
-@pytest.mark.parametrize("S,T,bq,pos,starts,kh", [
-    (32, 64, 16, 0, (0, 5), 2),   # prefill at slot 0, left-padded rows
-    (24, 64, 16, 8, (0, 0), 2),   # joiner-style offset prefill, padded S
-    (16, 32, 16, 16, (4, 12), 2),  # chunk at the window tail
-    (32, 64, 16, 0, (0, 3), 1),   # GQA: 4 query heads share 1 kv head
+@pytest.mark.parametrize("S,T,bq,pos,starts,kh,H,D", [
+    (32, 64, 16, 0, (0, 5), 2, 4, 8),   # slot 0, left-padded rows
+    (24, 64, 16, 8, (0, 0), 2, 4, 8),   # joiner-style offset, padded S
+    (16, 32, 16, 16, (4, 12), 2, 4, 8),  # chunk at the window tail
+    (32, 64, 16, 0, (0, 3), 1, 4, 8),   # GQA: 4 query heads, 1 kv head
+    # serving head width: 8 query heads on 2 kv heads of 64, the tail
+    # half of the window, default block
+    (64, 128, 256, 64, (0, 7), 2, 8, 64),
 ])
-def test_causal_kernel_matches_naive(S, T, bq, pos, starts, kh):
-    B, H, D = 2, 4, 8
+def test_causal_kernel_matches_naive(S, T, bq, pos, starts, kh, H, D):
+    B = 2
     q = jnp.asarray(_rand((B, S, H, D), 1))
     kk = jnp.asarray(_rand((B, T, kh, D), 2))     # UNREPEATED kv heads
     vv = jnp.asarray(_rand((B, T, kh, D), 3))

@@ -1,6 +1,6 @@
 """The commit pipeline: wake->commit must never park on a device
-round-trip it could overlap (BENCH_r05: 62.2 of the 67.2 ms p50
-set->vector was a synchronous device wait inside the old fused commit).
+round-trip it could overlap (the old fused commit waited on the device
+synchronously inside the wake handler).
 
 Three tiers:
   - CommitPipeline unit tests with hand-rolled futures (completion-order
@@ -205,7 +205,7 @@ def test_pipeline_microbench_no_blocking_fetch_in_wake_handler(store):
         lat = []
         try:
             # three bulk waves with latency probes in between — the
-            # shape of the bench's p50 loop, shrunk for CI
+            # shape of a set->vector p50 loop, shrunk for CI
             for wave in range(3):
                 for i in range(40):
                     _request(client, f"w{wave}/k{i}",

@@ -21,6 +21,7 @@ def _rand(shape, seed=0):
     (2, 64, 4, 16, 32),      # multi-block
     (1, 128, 2, 8, 128),     # single block
     (3, 48, 1, 32, 32),      # S not a multiple of block_q: padded
+    (4, 128, 12, 64, 256),   # the encoder's head geometry, default block
 ])
 def test_kernel_matches_naive(B, S, H, D, bq):
     q, k, v = (_rand((B, S, H, D), s) for s in (1, 2, 3))
@@ -88,12 +89,13 @@ def test_flash_gradients_match_naive():
                                    rtol=2e-4, atol=2e-4)
 
 
-@pytest.mark.parametrize("S,bq,lens", [
-    (48, 32, (48, 20, 1)),    # S not a block multiple: padded backward
-    (32, 16, (32, 0, 7)),     # one fully-masked row in the batch
-    (48, 32, (48, 20, 0)),    # BOTH: padding + a fully-masked row
+@pytest.mark.parametrize("S,bq,lens,hi", [
+    (48, 32, (48, 20, 1), False),  # S not a block multiple: padded bwd
+    (32, 16, (32, 0, 7), False),   # one fully-masked row in the batch
+    (48, 32, (48, 20, 0), False),  # BOTH: padding + a fully-masked row
+    (48, 32, (48, 20, 1), True),   # every MXU dot at Precision.HIGHEST
 ])
-def test_flash_gradients_padded_and_masked(S, bq, lens):
+def test_flash_gradients_padded_and_masked(S, bq, lens, hi):
     """Gradient parity under the module's contract: fully-masked rows
     are pooling-excluded don't-cares, so the loss (like the encoder's
     pool_normalize) multiplies outputs by row validity — their
@@ -106,7 +108,8 @@ def test_flash_gradients_padded_and_masked(S, bq, lens):
 
     def lf(q, k, v):
         return jnp.sum((flash_attention(q, k, v, mask, block_q=bq,
-                                        interpret=True) * roww) ** 2)
+                                        interpret=True, hi_prec=hi)
+                        * roww) ** 2)
 
     def ln(q, k, v):
         return jnp.sum((_mha_jnp(q, k, v, mask) * roww) ** 2)

@@ -147,16 +147,26 @@ def test_bf16_fused_matches_bf16_reference():
     _assert_parity(vectors, queries, None, 10, mxu_bf16=True)
 
 
-def test_single_query_contract():
+@pytest.mark.parametrize("n,d,k,near", [
+    (100, 40, 6, None),
+    # the lane's width (six 128-lane tiles) and the search default
+    # k, the query a perturbed lane row: that row must win
+    (2048, 768, 10, 1234),
+])
+def test_single_query_contract(n, d, k, near):
     rng = np.random.default_rng(23)
-    vectors = rng.normal(size=(100, 40)).astype(np.float32)
-    q = rng.normal(size=40).astype(np.float32)
-    s, i = cosine_topk(vectors, q, 6, fused=True, interpret=True,
+    vectors = rng.normal(size=(n, d)).astype(np.float32)
+    q = rng.normal(size=d).astype(np.float32)
+    if near is not None:
+        q = vectors[near] + 0.05 * q
+    s, i = cosine_topk(vectors, q, k, fused=True, interpret=True,
                        use_pallas=True, block_n=BLOCK)
-    ref_s, ref_i = _ref_topk(vectors, q[None, :], None, 6)
+    ref_s, ref_i = _ref_topk(vectors, q[None, :], None, k)
     np.testing.assert_allclose(s, ref_s[0], rtol=1e-5, atol=1e-6)
     np.testing.assert_array_equal(i, ref_i[0])
-    assert s.shape == (6,) and i.shape == (6,)
+    assert s.shape == (k,) and i.shape == (k,)
+    if near is not None:
+        assert i[0] == near
 
 
 def test_program_selection():

@@ -10,8 +10,8 @@ multiplier, LRU eviction + tenant quotas, the mid-flight joiner that
 maps a prefix another live row is still decoding from, the
 bp-memo staleness-eviction regression, heartbeat gauges, the loadgen
 shared-prefix knob, and the supervised completer.prefix_map chaos
-drill.  `make prefix-check` runs this file + the speedup gate
-(scripts/prefix_speedup_check.py).
+drill.  `make prefix-check` runs this file + the hot-admission gate
+(scripts/prefix_hit_check.py).
 """
 from __future__ import annotations
 

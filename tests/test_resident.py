@@ -1,5 +1,5 @@
 """Resident device loop + K-deep dispatch overlap (engine/resident.py;
-`make dispatch-check` runs this file + the depth-amortization smoke).
+`make dispatch-check` runs this file).
 
 The PR-7 contract: the hot lanes stop paying one runtime dispatch per
 drain, and BOTH mechanisms are byte-exact against the per-call paths —

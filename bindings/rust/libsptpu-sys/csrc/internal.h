@@ -1,12 +1,20 @@
 /* internal.h — in-memory layout of the splinter-tpu store (not installed).
  *
- * Region layout (one mmap, shm or file):
- *   [ header 8192B | slot table nslots*192B | value arena nslots*max_val
+ * Region layout (one mmap, shm or file; format version 2):
+ *   [ header 8192B | change journal SPT_JOURNAL_CAP*8B
+ *     | slot table nslots*192B | value arena nslots*max_val
  *     | vector lane nslots*vec_dim*4B (256-aligned) ]
  *
  * The vector lane is deliberately last and 256-byte aligned so the Python
  * tier can wrap it as one contiguous (nslots, dim) float32 numpy array and
  * stage dirty row-blocks to TPU HBM without gather-copies.
+ *
+ * The change journal (sptpu.h, "change journal") is a ring of 64-bit
+ * entries at journal_off; entry p lives at ring[p % SPT_JOURNAL_CAP] and
+ * holds (lap tag << 32 | slot index), so a reader tells a written entry
+ * from a claimed one and from an older lap's by the value alone.  Its
+ * head (the number of entries ever claimed) has a cache line of the
+ * header's first page to itself.
  */
 #ifndef SPTPU_INTERNAL_H
 #define SPTPU_INTERNAL_H
@@ -56,8 +64,15 @@ typedef struct {
    * is set on a written slot */
   _Atomic uint64_t bloom_groups[SPT_BLOOM_BITS];
   spt_bid bids[SPT_MAX_BIDS];                      /* 2048B */
+  /* change journal: where the ring is, how many entries it holds (a
+   * constant of the format, kept here for readers in other languages),
+   * and — on a cache line of its own, every writer adds to it — how many
+   * entries were ever claimed */
+  uint64_t journal_off, journal_cap;
+  uint8_t pad_to_jhead[8];
+  spt_sigctr journal_head;
   /* pad to 4096 then the signal arena fills the second 4K page */
-  uint8_t pad_to_sig[4096 - 2048
+  uint8_t pad_to_sig[4096 - 2048 - (2*8 + 8 + 64)
                      - (2*4 + 8 + 4*4 + 3*8 + 8 + 2*4 + 2*8 + 8 + 4 + 4
                         + SPT_DIRTY_WORDS*8 + SPT_BLOOM_BITS*8)];
   spt_sigctr signals[SPT_SIGNAL_GROUPS];           /* 4096B */
@@ -79,6 +94,7 @@ struct spt_store {
   spt_slot *slots;
   uint8_t  *values;
   float    *vectors;             /* NULL if vec_dim == 0 */
+  _Atomic uint64_t *journal;     /* SPT_JOURNAL_CAP entries */
   uint8_t  *base;
   uint64_t  map_size;
   int       fd;
@@ -93,6 +109,11 @@ _Static_assert(sizeof(spt_sigctr) == 64, "sigctr cache line");
 _Static_assert(sizeof(spt_bid) == 64, "bid size");
 _Static_assert(sizeof(spt_slot) == SPT_SLOT_BYTES, "slot size");
 _Static_assert(sizeof(spt_hdr) == SPT_HDR_BYTES, "header size");
+_Static_assert(offsetof(spt_hdr, journal_head) % 64 == 0,
+               "journal head on its own cache line");
+_Static_assert(offsetof(spt_hdr, signals) == 4096, "signal arena page");
+_Static_assert((SPT_JOURNAL_CAP & (SPT_JOURNAL_CAP - 1)) == 0,
+               "journal capacity is a power of two");
 
 /* FNV-1a 64-bit; 0/1 are reserved sentinels so remap them. */
 static inline uint64_t spt_hash_key(const char *k) {
@@ -120,9 +141,24 @@ int spt__probe_find(spt_store *st, const char *key, uint64_t h);
  * *existed set to 1 when the key was already present. */
 int spt__probe_claim(spt_store *st, const char *key, uint64_t h, int *existed);
 
-/* Seqlock helpers.  Acquire CASes even->odd (else -EAGAIN); release
- * publishes even = acquired+1 and fires the post-write fanout. */
-int  spt__lock(spt_slot *s, uint64_t *e_out);
+/* Change journal.  The tag of position p is its lap + 1: never that of
+ * the zero-filled ring, and the same again only 2^32 laps later. */
+static inline uint64_t spt__journal_entry(uint64_t p, uint32_t idx) {
+  return ((uint64_t)(uint32_t)(p / SPT_JOURNAL_CAP + 1) << 32) | idx;
+}
+/* Record that slot idx's epoch moves: claim a position, then fill it. */
+static inline void spt__journal(spt_store *st, uint32_t idx) {
+  uint64_t p = atomic_fetch_add_explicit(&st->h->journal_head.v, 1,
+                                         memory_order_acq_rel);
+  atomic_store_explicit(&st->journal[p & (SPT_JOURNAL_CAP - 1)],
+                        spt__journal_entry(p, idx), memory_order_release);
+}
+
+/* Seqlock helpers.  Acquire CASes even->odd (else -EAGAIN) and, the slot
+ * now odd, journals it: every path that holds the lock has a record, the
+ * aborted ones too, and a writer killed at any instruction leaves the
+ * slot either odd or journaled.  Release publishes even = acquired+2. */
+int  spt__lock(spt_store *st, uint32_t idx, uint64_t *e_out);
 void spt__unlock(spt_slot *s, uint64_t e_acquired);
 void spt__fanout(spt_store *st, uint32_t idx, spt_slot *s);
 

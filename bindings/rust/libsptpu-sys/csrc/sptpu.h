@@ -27,6 +27,18 @@
  *   -EAGAIN is a SIGNAL, not an error: the caller retries.
  *   A writer that dies mid-write leaves an odd epoch; spt_retrain() is the
  *   sanctioned recovery (drives the epoch backward — "revalidate me").
+ *
+ * Change journal (format version 2): no slot's epoch moves without a
+ *   record.  A ring of SPT_JOURNAL_CAP slot indices in the shared mapping
+ *   with a 64-bit head that only grows; taking a slot's seqlock appends
+ *   the slot WHILE IT IS ODD (so do spt_retrain's two stores), before the
+ *   new even epoch is published.  Consumers clear nothing: each keeps its
+ *   own cursor and asks spt_changed_since().  A record says "look at this
+ *   slot", not what happened: a consumer compares the slot's epoch with
+ *   the one it holds, finds nothing to do for a spurious record (an
+ *   aborted lock journals too), and keeps a slot it saw odd for its next
+ *   pass — the writer's record is already behind its cursor.  Label and
+ *   flag operations move no epoch and are not journaled.
  */
 #ifndef SPTPU_H
 #define SPTPU_H
@@ -39,13 +51,17 @@ extern "C" {
 #endif
 
 #define SPT_MAGIC           0x53505455u /* "SPTU" */
-#define SPT_FORMAT_VERSION  1u
+#define SPT_FORMAT_VERSION  2u   /* 2: the change journal (a region and
+                                   three header fields); spt_open refuses
+                                   a store of another version */
 
 #define SPT_KEY_MAX         128   /* bytes incl. NUL */
 #define SPT_SIGNAL_GROUPS   64
 #define SPT_MAX_BIDS        32
 #define SPT_DIRTY_WORDS     16    /* 1024 dirty bits: slot_idx % 1024 */
 #define SPT_BLOOM_BITS      64
+#define SPT_JOURNAL_CAP     65536u /* change-journal entries: a constant of
+                                     the format, not an option */
 
 /* --- open/create flags ------------------------------------------------- */
 #define SPT_BACKEND_SHM     0u        /* POSIX shm (default) */
@@ -312,6 +328,28 @@ int spt_epochs(spt_store *st, uint64_t *out);
 #define SPT_GATHER_TORN UINT64_MAX
 int spt_vec_gather(spt_store *st, const uint32_t *rows, uint32_t n,
                    float *out, uint64_t *epochs_out);
+
+/* ---- change journal ------------------------------------------------------ */
+/* Entries ever appended: the cursor of a consumer that starts now.  Take it
+ * BEFORE the first epoch snapshot, so a write during the snapshot is found
+ * afterwards. */
+uint64_t spt_journal_head(spt_store *st);
+/* The slot indices appended at positions [cursor, head), oldest first and
+ * with repeats, at most max_out of them; *cursor_out = the position after
+ * the last one returned.  Returns the count, or
+ *   -EOVERFLOW  the writers lapped the cursor (head - cursor >
+ *               SPT_JOURNAL_CAP), or the cursor is not this store's;
+ *   -EAGAIN     an entry in the range was claimed and not written within a
+ *               short wait (its writer is descheduled, or dead);
+ * with *cursor_out = the head as it was when the call began.  Either way
+ * the range cannot be trusted: scan every slot (spt_epochs) AFTER this
+ * call and go on from *cursor_out. */
+int spt_changed_since(spt_store *st, uint64_t cursor, uint32_t *rows_out,
+                      uint32_t max_out, uint64_t *cursor_out);
+/* Epochs of n listed slots, one acquire load each (0 for an index out of
+ * range): what a journal consumer compares its own with. */
+int spt_epochs_at(spt_store *st, const uint32_t *rows, uint32_t n,
+                  uint64_t *out);
 
 /* ---- diagnostics ------------------------------------------------------- */
 int spt_report_parse_failure(spt_store *st);

@@ -8,6 +8,7 @@
 #include "internal.h"
 
 #include <fcntl.h>
+#include <sched.h>
 #include <stdio.h>
 #include <stdlib.h>
 #include <sys/mman.h>
@@ -23,8 +24,10 @@ int spt_last_error(void) { return spt_errno_tl; }
 /* ---------------------------------------------------------------- layout */
 
 static uint64_t layout_size(uint32_t nslots, uint32_t max_val,
-                            uint32_t vec_dim, uint64_t off[3]) {
+                            uint32_t vec_dim, uint64_t off[4]) {
   uint64_t o = SPT_HDR_BYTES;
+  off[3] = o;                              /* change journal */
+  o += (uint64_t)SPT_JOURNAL_CAP * sizeof(uint64_t);
   off[0] = o;                              /* slots */
   o += (uint64_t)nslots * SPT_SLOT_BYTES;
   o = (o + 63) & ~63ull;
@@ -39,6 +42,7 @@ static uint64_t layout_size(uint32_t nslots, uint32_t max_val,
 static void wire(spt_store *st) {
   st->h = (spt_hdr *)st->base;
   st->slots = (spt_slot *)(st->base + st->h->slots_off);
+  st->journal = (_Atomic uint64_t *)(st->base + st->h->journal_off);
   st->values = st->base + st->h->values_off;
   st->vectors = st->h->vec_dim
                     ? (float *)(st->base + st->h->vectors_off)
@@ -87,7 +91,7 @@ spt_store *spt_create(const char *name, uint32_t nslots, uint32_t max_val,
                       uint32_t vec_dim, uint32_t flags) {
   if (!name || !nslots || !max_val) { set_err(EINVAL); return NULL; }
   max_val = (max_val + 63) & ~63u;   /* mop slop granularity */
-  uint64_t off[3];
+  uint64_t off[4];
   uint64_t sz = layout_size(nslots, max_val, vec_dim, off);
 
   int fd = -1, rc = open_backing(name, flags, 1, &fd);
@@ -116,6 +120,8 @@ spt_store *spt_create(const char *name, uint32_t nslots, uint32_t max_val,
   h->slots_off = off[0];
   h->values_off = off[1];
   h->vectors_off = off[2];
+  h->journal_off = off[3];
+  h->journal_cap = SPT_JOURNAL_CAP;
   atomic_store(&h->mop_mode, SPT_MOP_HYBRID);
   atomic_store(&h->bus_fd, -1);
   atomic_thread_fence(memory_order_release);
@@ -139,7 +145,8 @@ spt_store *spt_open(const char *name, uint32_t flags) {
 
   spt_hdr *h = (spt_hdr *)base;
   if (h->magic != SPT_MAGIC || h->version != SPT_FORMAT_VERSION ||
-      h->map_size != (uint64_t)sb.st_size) {
+      h->map_size != (uint64_t)sb.st_size ||
+      h->journal_cap != SPT_JOURNAL_CAP) {
     set_err(EPROTO);
     munmap(base, (size_t)sb.st_size);
     close(fd);
@@ -267,13 +274,15 @@ int spt__probe_claim(spt_store *st, const char *key, uint64_t h,
 
 /* ---------------------------------------------------------------- seqlock */
 
-int spt__lock(spt_slot *s, uint64_t *e_out) {
+int spt__lock(spt_store *st, uint32_t idx, uint64_t *e_out) {
+  spt_slot *s = &st->slots[idx];
   uint64_t e = atomic_load_explicit(&s->epoch, memory_order_acquire);
   if (e & 1) return -EAGAIN;                 /* writer active */
   if (!atomic_compare_exchange_strong_explicit(&s->epoch, &e, e + 1,
                                                memory_order_acq_rel,
                                                memory_order_acquire))
     return -EAGAIN;                          /* lost the race */
+  spt__journal(st, idx);                     /* odd, not yet published */
   *e_out = e;
   return 0;
 }
@@ -293,7 +302,7 @@ static int lock_key(spt_store *st, const char *key, uint32_t *idx_out,
   if (idx < 0) return idx;
   spt_slot *s = &st->slots[idx];
   uint64_t e;
-  int rc = spt__lock(s, &e);
+  int rc = spt__lock(st, (uint32_t)idx, &e);
   if (rc < 0) return rc;
   uint64_t cur = atomic_load_explicit(&s->hash, memory_order_relaxed);
   if (cur <= SPT_TOMBSTONE) {
@@ -340,7 +349,7 @@ int spt_set(spt_store *st, const char *key, const void *val, uint32_t len) {
   spt_slot *s = &st->slots[idx];
 
   uint64_t e;
-  int rc = spt__lock(s, &e);
+  int rc = spt__lock(st, (uint32_t)idx, &e);
   if (rc < 0) return rc;
 
   /* the slot may have been claimed for a different key — or our key may
@@ -851,7 +860,7 @@ int spt_purge(spt_store *st) {
     if (sh == SPT_TOMBSTONE) {
       /* compact: a tombstone whose chain-successor region is empty can
        * revert to truly-empty; conservatively just scrub its value */
-      if (spt__lock(s, &e) == 0) {
+      if (spt__lock(st, i, &e) == 0) {
         memset(slot_val(st, i), 0, st->h->max_val);
         spt__unlock(s, e);
         swept++;
@@ -859,7 +868,7 @@ int spt_purge(spt_store *st) {
       continue;
     }
     if (sh == 0) continue;
-    if (spt__lock(s, &e) == 0) {
+    if (spt__lock(st, i, &e) == 0) {
       uint32_t len = s->val_len;
       if (len < st->h->max_val)
         memset(slot_val(st, i) + len, 0, st->h->max_val - len);
@@ -877,8 +886,11 @@ int spt_retrain(spt_store *st, const char *key) {
   int idx = spt__probe_find(st, key, spt_hash_key(key));
   if (idx < 0) return idx;
   spt_slot *s = &st->slots[idx];
-  /* deliberately NOT CAS-guarded: this works on a slot stuck odd */
+  /* deliberately NOT CAS-guarded: this works on a slot stuck odd.  The
+   * epoch may move BACKWARD here, so the record matters as much as after
+   * any write: appended while the slot is odd, like spt__lock's */
   atomic_store_explicit(&s->epoch, 3, memory_order_release);
+  spt__journal(st, (uint32_t)idx);
   if (st->vectors)
     memset(slot_vec(st, (uint32_t)idx), 0,
            (size_t)st->h->vec_dim * sizeof(float));
@@ -972,7 +984,7 @@ int spt_vec_set_at(spt_store *st, uint32_t idx, const float *vec,
   if (dim != st->h->vec_dim) return -EMSGSIZE;
   spt_slot *s = &st->slots[idx];
   uint64_t e;
-  int rc = spt__lock(s, &e);
+  int rc = spt__lock(st, idx, &e);
   if (rc < 0) return rc;
   memcpy(slot_vec(st, idx), vec, (size_t)dim * sizeof(float));
   spt__unlock(s, e);
@@ -1038,7 +1050,7 @@ int spt_vec_commit_batch(spt_store *st, const uint32_t *rows,
     } else {
       spt_slot *s = &st->slots[idx];
       uint64_t e;
-      int rc = spt__lock(s, &e);
+      int rc = spt__lock(st, idx, &e);
       if (rc < 0) {
         r = -ESTALE;          /* contended now => text may have changed */
       } else if (e != epochs[i]) {
@@ -1059,6 +1071,54 @@ int spt_vec_commit_batch(spt_store *st, const uint32_t *rows,
     if (results) results[i] = r;
   }
   return committed;
+}
+
+/* --------------------------------------------------------- change journal */
+
+uint64_t spt_journal_head(spt_store *st) {
+  if (!st) return 0;
+  return atomic_load_explicit(&st->h->journal_head.v, memory_order_acquire);
+}
+
+int spt_changed_since(spt_store *st, uint64_t cursor, uint32_t *rows_out,
+                      uint32_t max_out, uint64_t *cursor_out) {
+  if (!st || !cursor_out || (!rows_out && max_out)) return -EINVAL;
+  _Atomic uint64_t *head_p = &st->h->journal_head.v;
+  uint64_t head = atomic_load_explicit(head_p, memory_order_acquire);
+  *cursor_out = head;
+  if (cursor > head || head - cursor > SPT_JOURNAL_CAP) return -EOVERFLOW;
+  uint32_t n = 0;
+  for (uint64_t p = cursor; p < head && n < max_out; p++, n++) {
+    _Atomic uint64_t *slot = &st->journal[p & (SPT_JOURNAL_CAP - 1)];
+    uint64_t want = spt__journal_entry(p, 0) >> 32;
+    uint64_t e = atomic_load_explicit(slot, memory_order_acquire);
+    /* another tag: a writer claimed p and has not filled it, or one a lap
+     * ahead already overwrote it.  The first is two instructions wide in
+     * a live writer: wait it out, briefly */
+    for (int spin = 0; (e >> 32) != want; spin++) {
+      if (atomic_load_explicit(head_p, memory_order_acquire) - cursor >
+          SPT_JOURNAL_CAP)
+        return -EOVERFLOW;
+      if (spin >= 256) return -EAGAIN;
+      if (spin >= 16) sched_yield();
+      e = atomic_load_explicit(slot, memory_order_acquire);
+    }
+    rows_out[n] = (uint32_t)e;
+  }
+  *cursor_out = cursor + n;
+  return (int)n;
+}
+
+int spt_epochs_at(spt_store *st, const uint32_t *rows, uint32_t n,
+                  uint64_t *out) {
+  if (!st || (!rows && n) || (!out && n)) return -EINVAL;
+  uint32_t nslots = st->h->nslots;
+  for (uint32_t i = 0; i < n; i++)
+    out[i] = rows[i] < nslots
+                 ? atomic_load_explicit(&st->slots[rows[i]].epoch,
+                                        memory_order_acquire)
+                 : 0;
+  return (int)n;
 }
 
 int spt_epochs(spt_store *st, uint64_t *out) {

@@ -26,6 +26,7 @@ pub const SPT_KEY_MAX: usize = 128;
 pub const SPT_SIGNAL_GROUPS: u32 = 64;
 pub const SPT_MAX_BIDS: u32 = 32;
 pub const SPT_DIRTY_WORDS: usize = 16;
+pub const SPT_JOURNAL_CAP: u32 = 65536;
 
 pub const SPT_BACKEND_SHM: u32 = 0;
 pub const SPT_BACKEND_FILE: u32 = 1 << 0;
@@ -284,6 +285,16 @@ extern "C" {
     /* epochs_out[i] == SPT_GATHER_TORN (u64::MAX) => torn row, retry */
     pub fn spt_vec_gather(st: *mut spt_store, rows: *const u32, n: u32,
                           out: *mut f32, epochs_out: *mut u64) -> c_int;
+
+    // change journal (format version 2): rows whose epoch moved since
+    // `cursor`; < 0 (-EOVERFLOW lapped, -EAGAIN torn range) => scan
+    // spt_epochs AFTER the call and go on from *cursor_out
+    pub fn spt_journal_head(st: *mut spt_store) -> u64;
+    pub fn spt_changed_since(st: *mut spt_store, cursor: u64,
+                             rows_out: *mut u32, max_out: u32,
+                             cursor_out: *mut u64) -> c_int;
+    pub fn spt_epochs_at(st: *mut spt_store, rows: *const u32, n: u32,
+                         out: *mut u64) -> c_int;
 
     // diagnostics
     pub fn spt_report_parse_failure(st: *mut spt_store) -> c_int;

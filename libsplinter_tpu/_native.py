@@ -18,6 +18,7 @@ KEY_MAX = 128
 SIGNAL_GROUPS = 64
 MAX_BIDS = 32
 DIRTY_WORDS = 16
+JOURNAL_CAP = 65536
 BLOOM_BITS = 64
 
 # open/create flags
@@ -188,6 +189,10 @@ def _declare(lib: C.CDLL) -> None:
                                        C.c_void_p, u32, u32, i32,
                                        C.POINTER(i32)]),
         "spt_epochs": (i32, [P, C.POINTER(u64)]),
+        "spt_journal_head": (u64, [P]),
+        "spt_changed_since": (i32, [P, u64, C.POINTER(u32), u32,
+                                    C.POINTER(u64)]),
+        "spt_epochs_at": (i32, [P, C.POINTER(u32), u32, C.POINTER(u64)]),
         "spt_vec_gather": (i32, [P, C.POINTER(u32), u32, C.c_void_p,
                                  C.POINTER(u64)]),
         "spt_report_parse_failure": (i32, [P]),

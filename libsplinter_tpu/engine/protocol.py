@@ -211,9 +211,10 @@ SEARCH_STAGES = ("wake", "drain", "score", "select", "commit")
 # section).  Every pass of Searcher.run is one `loop` span, and inside
 # it every second belongs to one of: idle = blocked in signal_wait;
 # search.drain_cycle (whose parts are the SEARCH_STAGES plus, inside
-# score, refresh = lane.refresh() and mask = the candidate-mask
-# builds); sweep_results / sweep_stages = the two heartbeat-cadence
-# key walks; publish = publish_stats.  All but `loop` are LEAF phases:
+# score, refresh = lane.refresh() and mask = bringing the candidate
+# masks up to date); sweep_results / sweep_stages = the two
+# heartbeat-cadence key walks; publish = the beat's lane audit +
+# publish_stats.  All but `loop` are LEAF phases:
 # they also ride the profiler's clock (utils/trace.py), as do the
 # drain, select and commit stages.
 SEARCH_LOOP_PHASES = ("loop", "idle", "refresh", "mask",
@@ -241,20 +242,28 @@ def stored_script_key(name: str) -> str:
     return f"{SCRIPT_STORE_PREFIX}{name}"
 
 
+def live_epochs(eps):
+    """THE liveness rule of a search candidate, on an array of slot
+    epochs: written at least once and not mid-write (even, nonzero).
+    candidate_mask applies it to a snapshot of the store, the search
+    daemon to the epochs its lane staged (Searcher._sync_live)."""
+    import numpy as np
+
+    return (eps != 0) & ((eps & np.uint64(1)) == 0)
+
+
 def candidate_mask(store, bloom: int = 0):
     """THE search candidate mask — one definition the CLI's client-side
     scoring and the search daemon share, so their candidate sets
     cannot diverge: a bloom prefilter enumerates labelled rows; the
-    default is every live row (written at least once, not mid-write —
-    even nonzero epoch)."""
+    default is every live row (live_epochs of a snapshot)."""
     import numpy as np
 
     if bloom:
         mask = np.zeros(store.nslots, np.float32)
         mask[store.enumerate_indices(bloom)] = 1.0
         return mask
-    eps = store.epochs()
-    return ((eps != 0) & ((eps & np.uint64(1)) == 0)).astype(np.float32)
+    return live_epochs(store.epochs()).astype(np.float32)
 
 # latency-probe short-circuit: drains at or below this many candidate
 # rows skip the windowed big-batch machinery and dispatch immediately

@@ -14,8 +14,9 @@ drain/wake structure (engine/embedder.py):
     into QB-bucketed batches against pre-compiled fused top-k
     programs (ops/similarity.topk_program — the streaming Pallas
     kernel: block-local select + merge in VMEM, O(k*Q) off-chip);
-  - scores against its own StagedLane (full upload once, O(dirty)
-    refresh per drain);
+  - scores against its own StagedLane (full upload once, then per
+    drain the rows the store's change journal names) and a liveness
+    mask it patches for the same rows;
   - commits per-request results back as __sr_<idx> rows and clears
     the request label — N concurrent clients cost ceil(N / QB)
     device dispatches, not N.
@@ -127,6 +128,12 @@ class SearcherStats:
     parse_errors: int = 0        # malformed / vectorless requests
     raced: int = 0               # slot changed mid-service; retried
     full_refreshes: int = 0      # lane full uploads
+    # -- how the lane and the mask learn what moved (StagedLane) -----
+    lane_slots_scanned: int = 0  # epochs the drains' refreshes and
+                                 # mask patches looked at (not audits)
+    journal_rows: int = 0        # distinct rows the journal delivered
+    journal_fallbacks: int = 0   # refreshes that scanned every slot
+    lane_audit_rows: int = 0     # rows only the beat's audit found: 0
     # -- K-deep dispatch overlap (engine/resident.py): batch k's
     # select+commit resolve while batches k+1..k+K compute ---------
     inflight_peak: int = 0       # max un-awaited batch dispatches held
@@ -259,6 +266,11 @@ class Searcher:
         self.tenants = TenantLedger()
         self._had_deferred = False
         self.lane = lane or StagedLane(store)
+        # liveness of every row the device holds (float32, nslots):
+        # protocol.live_epochs of the lane's staged epochs, patched per
+        # drain for the rows the lane re-examined (_sync_live)
+        self._live: np.ndarray | None = None
+        self._mask_slots = 0         # rows those patches looked at
         self._all_req_rows: list[int] = []
         self.stats = SearcherStats()
         self.generation = 0          # bumped at attach (restart marker)
@@ -459,25 +471,51 @@ class Searcher:
 
     # -- masks -------------------------------------------------------------
 
-    def _mask_for(self, bloom: int, req_rows: np.ndarray) -> np.ndarray:
-        """Candidate mask for one bloom group (the shared
-        protocol.candidate_mask definition); every CURRENT request row
-        is masked out of every group (request slots hold query vectors
-        — without this, concurrent similar queries would surface each
-        other's scratch rows at the top).  The WHOLE enumeration the
-        drain's gather captured (_all_req_rows) — not just this
-        batch's rows — is what gets masked: under striped replicas a
+    def _sync_live(self) -> np.ndarray:
+        """The liveness mask, brought up to the lane's staged epochs:
+        protocol.live_epochs — candidate_mask's rule — applied to the
+        rows the lane re-examined since the last call (every row
+        after a full upload).  The mask so describes exactly the rows
+        the device holds: a row mid-write when the lane looked is
+        staged odd and reads as not live until its writer is done."""
+        lane = self.lane
+        rows = lane.take_examined()
+        if self._live is None or rows is None:
+            eps = lane.staged_epochs()
+            self._live = P.live_epochs(eps).astype(np.float32)
+            self._mask_slots += eps.size
+        elif rows.size:
+            self._live[rows] = P.live_epochs(lane.staged_epochs(rows))
+            self._mask_slots += rows.size
+        return self._live
+
+    def _mask_for(self, bloom: int, hidden: np.ndarray) -> np.ndarray:
+        """Candidate mask for one bloom group; the `hidden` rows —
+        every CURRENT request row — are masked out of every group
+        (request slots hold query vectors: without this, concurrent
+        similar queries would surface each other's scratch rows at
+        the top).  A bloom prefilter enumerates its labelled rows
+        afresh (label operations move no epoch, so nothing names what
+        changed).  The default mask is the daemon's patched liveness
+        state ITSELF, the same float32 ndarray of nslots every drain
+        and the kind warmup() compiled for: the hidden rows are
+        zeroed in place for the drain and _service restores them when
+        its last dispatch has been fetched — not before, a dispatch
+        in flight may still be reading the array it was handed."""
+        mask = (P.candidate_mask(self.store, bloom) if bloom
+                else self._sync_live())
+        mask[hidden] = 0.0
+        return mask
+
+    def _hidden_rows(self, reqs: list[_Request]) -> np.ndarray:
+        """This drain's request rows plus the WHOLE enumeration its
+        gather captured (_all_req_rows): under striped replicas a
         peer's still-pending request rows are request scratch too,
         and masking only our own stripe would make R=2 results
         diverge from R=1 (caught by tests/test_elastic.py).  Reusing
-        the gather's enumeration costs no extra label scan per bloom
-        group."""
-        mask = P.candidate_mask(self.store, bloom)
-        mask[req_rows] = 0.0
-        pending = getattr(self, "_all_req_rows", None)
-        if pending:
-            mask[np.asarray(pending, np.int64)] = 0.0
-        return mask
+        the gather's enumeration costs no extra label scan."""
+        return np.unique(np.asarray(
+            [r.idx for r in reqs] + self._all_req_rows, np.int64))
 
     # -- the drain ---------------------------------------------------------
 
@@ -551,9 +589,12 @@ class Searcher:
         its siblings commit normally — a device failure mid-service
         must never unwind the run loop or starve unrelated requests.
 
-        The score stage's two all-slot parts are bracketed where they
-        run (search.refresh, search.mask); what is left of score is
-        batching and dispatch."""
+        The score stage's two parts that follow the store are
+        bracketed where they run — search.refresh (the lane reads the
+        change journal and re-stages the rows it names) and
+        search.mask (the liveness mask patched for the same rows, the
+        pending request rows hidden until the last fetch); what is
+        left of score is batching and dispatch."""
         acc = self._stage_acc
         t0 = time.perf_counter()
         full0 = self.lane.full_uploads
@@ -566,7 +607,7 @@ class Searcher:
             # cost, paid by the first request
             self.startup_ms["first_refresh"] = \
                 (time.perf_counter() - t0) * 1e3
-        req_rows = np.asarray([r.idx for r in reqs], np.int64)
+        hidden = self._hidden_rows(reqs)
 
         # select/commit wall + served count accrued by the window's
         # resolver as batches complete (out of lockstep with dispatch)
@@ -585,12 +626,53 @@ class Searcher:
         groups: dict[tuple, list[_Request]] = {}
         for r in reqs:
             groups.setdefault((r.bloom, r.fast), []).append(r)
-        # one mask per BLOOM value: the fast/exact split shares it, and
-        # the default mask's O(nslots) epochs() snapshot runs once per
-        # drain, not once per precision group
-        with tracer.span("search.mask", leaf=True):
-            masks = {bloom: self._mask_for(bloom, req_rows)
-                     for bloom in {b for b, _ in groups}}
+        # one mask per BLOOM value: the fast/exact split shares it
+        try:
+            with tracer.span("search.mask", leaf=True):
+                masks = {bloom: self._mask_for(bloom, hidden)
+                         for bloom in {b for b, _ in groups}}
+            self._dispatch_groups(arr, groups, masks, win)
+            win.flush()
+        finally:
+            # every dispatch that read the default mask has been
+            # fetched (or the drain is failing): its hidden rows are
+            # candidates again, as live as the lane staged them
+            if self._live is not None:
+                self._live[hidden] = P.live_epochs(
+                    self.lane.staged_epochs(hidden))
+                self._mask_slots += hidden.size
+            self._note_lane()
+        self.stats.inflight_peak = max(self.stats.inflight_peak,
+                                       win.inflight_peak)
+        self.stats.ready_selects += win.ready_resolves
+        self.stats.blocking_selects += win.blocking_resolves
+        t3 = time.perf_counter()
+        if acc is not None:
+            # the resolver accrued select/commit; score is the
+            # remaining host-side wall of the service (refresh, mask
+            # build, batching, dispatch) — the stages stay disjoint
+            acc["select"] = state["select_ms"]
+            acc["commit"] = state["commit_ms"]
+            acc["score"] = max(
+                (t3 - t0) * 1e3 - state["select_ms"]
+                - state["commit_ms"], 0.0)
+            for stage in ("score", "select", "commit"):
+                tracer.record(f"search.{stage}", acc[stage])
+        return state["served"]
+
+    def _note_lane(self) -> None:
+        """The lane's journal counters into the heartbeat's own."""
+        lane, stats = self.lane, self.stats
+        stats.lane_slots_scanned = (lane.lane_slots_scanned
+                                    + self._mask_slots)
+        stats.journal_rows = lane.journal_rows
+        stats.journal_fallbacks = lane.journal_fallbacks
+        stats.lane_audit_rows = lane.lane_audit_rows
+
+    def _dispatch_groups(self, arr, groups: dict, masks: dict,
+                         win) -> None:
+        """Bucket each (bloom, precision) group's queries and push one
+        dispatch a bucket into the window."""
         for (bloom, fast), group in groups.items():
             mask = masks[bloom]
             lo = 0
@@ -625,24 +707,6 @@ class Searcher:
                 self.stats.coalesced_max = max(
                     self.stats.coalesced_max, len(chunk))
                 win.push((chunk, k_fetch, mask, q, mark), pend)
-        win.flush()
-        self.stats.inflight_peak = max(self.stats.inflight_peak,
-                                       win.inflight_peak)
-        self.stats.ready_selects += win.ready_resolves
-        self.stats.blocking_selects += win.blocking_resolves
-        t3 = time.perf_counter()
-        if acc is not None:
-            # the resolver accrued select/commit; score is the
-            # remaining host-side wall of the service (refresh, mask
-            # build, batching, dispatch) — the stages stay disjoint
-            acc["select"] = state["select_ms"]
-            acc["commit"] = state["commit_ms"]
-            acc["score"] = max(
-                (t3 - t0) * 1e3 - state["select_ms"]
-                - state["commit_ms"], 0.0)
-            for stage in ("score", "select", "commit"):
-                tracer.record(f"search.{stage}", acc[stage])
-        return state["served"]
 
     def _resolve_batch(self, arr, payload, pend, ready: bool,
                        state: dict) -> None:
@@ -951,7 +1015,8 @@ class Searcher:
         quantiles and the flight-recorder ring ride along — same
         section contract as the other daemons."""
         self.spans.flush()            # heartbeat cadence, off the
-        payload = {**dataclasses.asdict(self.stats),  # wake path
+        self._note_lane()             # wake path
+        payload = {**dataclasses.asdict(self.stats),
                    "spans_obs": self.spans.counters(),
                    "coalesce_ratio": round(
                        self.stats.coalesce_ratio(), 4),
@@ -1013,7 +1078,8 @@ class Searcher:
         at the head of the NEXT pass — the same place in time, right
         after the sweeps — so a heartbeat's snapshot holds whole
         passes only and `search.loop` equals its children's sum plus
-        the loop's own bookkeeping at every heartbeat."""
+        the loop's own bookkeeping at every heartbeat.  The publish
+        begins with the lane's audit (StagedLane.audit)."""
         self._running = True
         st = self.store
         last = st.signal_count(self.group)
@@ -1098,9 +1164,12 @@ class Searcher:
             self._publish_beat()
 
     def _publish_beat(self) -> None:
-        """The beat's heartbeat, behind the loop's firewall."""
+        """The beat's audit of the lane (the one full comparison left:
+        what it finds, the journal missed) and its heartbeat, which
+        carries the count; behind the loop's firewall."""
         try:
             with tracer.span("search.publish", leaf=True):
+                self.lane.audit()
                 self.publish_stats()
         except Exception:
             self.stats.drain_faults += 1

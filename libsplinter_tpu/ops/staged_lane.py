@@ -9,11 +9,17 @@ the 1M x 768 target that is ~3 GB of transfer per query.
 StagedLane makes the lane resident in HBM:
 
   - first use uploads the full lane once;
-  - every refresh() takes a bulk epoch snapshot (spt_epochs — one
-    acquire load per slot in C), diffs it against the epochs the rows
-    were staged at, gathers ONLY the changed rows torn-safely
-    (spt_vec_gather), and scatters them into the device array in place
-    (donated buffers, jit'd at a few padded update-size buckets);
+  - every refresh() asks the store's change journal which slots moved
+    since the lane's own cursor (Store.changed_since), compares only
+    those rows' epochs against the epochs they were staged at, gathers
+    ONLY the changed rows torn-safely (spt_vec_gather), and scatters
+    them into the device array in place (donated buffers, jit'd at a
+    few padded update-size buckets).  When the journal cannot answer
+    (the writers lapped the cursor, or an entry was claimed and never
+    written) the refresh is the full comparison instead: a bulk epoch
+    snapshot (spt_epochs — one acquire load per slot in C) diffed
+    against every staged epoch.  The journal's own answer chooses;
+    nothing else does;
     large dirty sets are CHUNKED through the same fixed bucket set —
     the gather of chunk i+1 overlaps the async device scatter of
     chunk i, padding waste is bounded at 2x, and no dirty count ever
@@ -23,14 +29,20 @@ StagedLane makes the lane resident in HBM:
   - searches read the device array directly — zero host->device traffic
     for an unchanged lane, O(changed rows) otherwise.
 
-Rows mid-write at gather time (odd epoch / seqlock race) simply stay
-dirty and are picked up on the next refresh — same retry discipline as
-every reader of the store (sptpu.h EAGAIN contract).
+Rows mid-write when the lane looks (odd epoch / seqlock race) are
+REMEMBERED and looked at again on the next refresh: their writer's
+journal record is behind the cursor already, so no later read of the
+journal would bring them back — same retry discipline as every reader
+of the store (sptpu.h EAGAIN contract).  Once a heartbeat the owner
+runs audit(): the full comparison, counting what the journal had not
+delivered (`lane_audit_rows`; anything but 0 is a missing record).
 """
 from __future__ import annotations
 
 import functools
 import os
+
+import logging
 
 import numpy as np
 
@@ -50,6 +62,14 @@ _UPDATE_BUCKETS = (64, 512, 4096, 32768)
 _CHUNK_BYTES = 128 << 20
 
 _MADV_DONTNEED = 4
+
+# staged "epoch" of a row the device does not hold in a stable state
+# (mid-write when the lane looked): odd, so it equals no published
+# epoch and reads as not live
+_UNSTAGED = np.uint64(1)
+_NO_ROWS = np.empty(0, np.int64)
+
+log = logging.getLogger("libsplinter_tpu.staged_lane")
 
 
 @functools.lru_cache(maxsize=1)
@@ -186,6 +206,15 @@ class StagedLane:
         self._arr = None                 # jax.Array (nslots, dim) f32
         self._norms = None               # jax.Array (nslots,) f32
         self._staged = None              # np.uint64 epoch per staged row
+        self._cursor = 0                 # change-journal position read to
+        self._carry = _NO_ROWS           # rows seen mid-write: next pass
+        self._examined: np.ndarray | None = _NO_ROWS  # take_examined()
+        # how the lane learns what moved (always on; the search
+        # daemon's heartbeat carries them)
+        self.lane_slots_scanned = 0      # epochs a refresh looked at
+        self.journal_rows = 0            # distinct rows the journal gave
+        self.journal_fallbacks = 0       # refreshes that scanned instead
+        self.lane_audit_rows = 0         # rows only audit() found
         # transfer accounting (tests + perf docs read these)
         self.full_uploads = 0
         self.rows_staged = 0             # incremental rows transferred
@@ -220,6 +249,9 @@ class StagedLane:
         # resident; detach it up front so peak RSS during the upload is
         # one device copy + one chunk, not lane + device copy
         _advise_dontneed(view)
+        # the cursor BEFORE the first snapshot: a write during the
+        # upload is journaled behind it and found by the next refresh
+        self._cursor = st.journal_head()
         e1 = st.epochs()
         chunk = max(4096, _CHUNK_BYTES // max(1, d * 4))
         with jax.default_device(dev):
@@ -246,9 +278,13 @@ class StagedLane:
         # sharding-committedness is part of jax's cache key)
         self._arr = jax.device_put(arr, dev)
         self._norms = jax.device_put(norms_host, dev)
-        # rows that moved mid-copy get an odd sentinel so the next
-        # refresh re-stages them (a stable epoch is always even)
-        self._staged = np.where(stable, e1, np.uint64(1))
+        # rows that moved mid-copy get the odd sentinel and are looked
+        # at again by the next refresh (a stable epoch is always even)
+        self._staged = np.where(stable, e1, _UNSTAGED)
+        self._carry = np.nonzero(~stable)[0]
+        self._examined = None
+        self.lane_slots_scanned += 2 * n
+        self.journal_fallbacks += 1      # first attach: nothing to read
         self.full_uploads += 1
 
     def refresh(self):
@@ -257,12 +293,94 @@ class StagedLane:
         if self._arr is None:
             self._full_upload()
             return self._arr
-        changed = np.nonzero(self._st.epochs() != self._staged)[0]
-        if changed.size:
-            self._stage_rows(changed)
+        rows, self._cursor, complete = \
+            self._st.changed_since(self._cursor)
+        if complete:
+            self.journal_rows += rows.size
+            if rows.size or self._carry.size:
+                cand = np.union1d(rows, self._carry)
+                self.lane_slots_scanned += cand.size
+                self._settle(cand, self._st.epochs_at(cand))
+        else:
+            self.journal_fallbacks += 1
+            self._scan(self._st.epochs())
         return self._arr
 
-    def _stage_rows(self, changed: np.ndarray) -> None:
+    def _scan(self, eps: np.ndarray) -> None:
+        """The full comparison: every slot's epoch against its staged
+        one.  The cursor was taken before `eps` (changed_since's
+        contract), so what moves after the snapshot is journaled."""
+        self.lane_slots_scanned += eps.size
+        cand = np.union1d(np.nonzero(eps != self._staged)[0],
+                          self._carry)
+        self._settle(cand, eps[cand])
+
+    def _settle(self, cand: np.ndarray, eps: np.ndarray) -> None:
+        """Bring `cand` rows (sorted, distinct) whose store epochs read
+        `eps` up to date: an odd row is marked unstaged and carried to
+        the next refresh, an even one that differs is re-staged, an
+        equal one (a spurious record) costs the comparison."""
+        odd = (eps & np.uint64(1)) != 0
+        moved = cand[~odd & (eps != self._staged[cand])]
+        self._staged[cand[odd]] = _UNSTAGED
+        # remembered BEFORE anything is dispatched: a refresh that dies
+        # mid-stage must look at all of them again, and the journal
+        # names a row once
+        self._carry = cand
+        if self._examined is not None:
+            self._examined = np.union1d(self._examined, cand)
+        torn = self._stage_rows(moved) if moved.size else _NO_ROWS
+        self._carry = np.union1d(cand[odd], torn)
+
+    def audit(self) -> int:
+        """The full comparison, run where the journal is trusted: find
+        every row whose epoch is not the staged one and count those
+        the journal had NOT delivered (even, changed, in no record
+        since the cursor and not carried).  A missing record is the
+        one fault that reads as a right answer, and only this can see
+        it.  Whatever it finds is staged, counted (`lane_audit_rows`)
+        and logged.  Returns the count; 0 on a lane not yet
+        uploaded."""
+        if self._arr is None:
+            return 0
+        eps = self._st.epochs()
+        # read AFTER the snapshot: a row that moved before it and was
+        # journaled is in this range or was settled earlier
+        rows, self._cursor, complete = \
+            self._st.changed_since(self._cursor)
+        if not complete:
+            self.journal_fallbacks += 1
+            self._scan(self._st.epochs())
+            return 0
+        self.journal_rows += rows.size
+        differ = np.nonzero(eps != self._staged)[0]
+        known = np.union1d(rows, self._carry)
+        found = np.setdiff1d(differ, known, assume_unique=True)
+        found = found[(eps[found] & np.uint64(1)) == 0]
+        if found.size:
+            self.lane_audit_rows += found.size
+            log.error("lane audit: %d rows moved without a journal "
+                      "record (first: %s)", found.size, found[:8])
+        cand = np.union1d(differ, known)
+        if cand.size:
+            self._settle(cand, self._st.epochs_at(cand))
+        return int(found.size)
+
+    def take_examined(self) -> np.ndarray | None:
+        """Rows whose staged epoch may have changed since the last
+        call (sorted, distinct), or None for "any of them" (a full
+        upload).  For ONE consumer that keeps state derived from the
+        staged epochs — the search daemon's liveness mask."""
+        got, self._examined = self._examined, _NO_ROWS
+        return got
+
+    def staged_epochs(self, rows: np.ndarray | None = None) -> np.ndarray:
+        """The epochs the device's rows were staged at (all of them,
+        or the listed rows'); odd = the device holds no stable state
+        of that row."""
+        return self._staged if rows is None else self._staged[rows]
+
+    def _stage_rows(self, changed: np.ndarray) -> np.ndarray:
         """Incremental re-stage of `changed` rows, chunked through the
         fixed bucket set (_chunk_plan).  Each chunk's scatter is a
         fused vals+norms update on donated buffers
@@ -285,6 +403,7 @@ class StagedLane:
         # a ring fills (or the gather ends) — chunks touch disjoint
         # rows, so applying them out of plan order is safe
         staged: dict[int, list[tuple]] = {}
+        torn: list[np.ndarray] = []
 
         def flush(b: int, group: list[tuple]) -> None:
             """Dispatch one scatter (ring or per-call) and ONLY THEN
@@ -317,8 +436,9 @@ class StagedLane:
         for off, vecs, eps in st.vec_gather_iter(changed, plan):
             ok = eps != Store.GATHER_TORN
             n = int(ok.sum())
+            if n < ok.size:
+                torn.append(changed[off: off + ok.size][~ok])
             if not n:
-                # torn rows: staged epoch untouched -> dirty next pass
                 continue
             rows = changed[off: off + ok.size][ok]
             g = vecs if n == ok.size else vecs[ok]
@@ -351,6 +471,11 @@ class StagedLane:
         for b, group in staged.items():
             if group:
                 flush(b, group)
+        if not torn:
+            return _NO_ROWS
+        out = np.concatenate(torn)
+        self._staged[out] = _UNSTAGED
+        return out
 
     def counters(self) -> dict:
         """Transfer/chunk accounting as flat numerics — the shape
@@ -362,7 +487,11 @@ class StagedLane:
                "rows_padded": self.rows_padded,
                "scatter_chunks": self.scatter_chunks,
                "ring_dispatches": self.ring_dispatches,
-               "ring_chunks": self.ring_chunks}
+               "ring_chunks": self.ring_chunks,
+               "lane_slots_scanned": self.lane_slots_scanned,
+               "journal_rows": self.journal_rows,
+               "journal_fallbacks": self.journal_fallbacks,
+               "lane_audit_rows": self.lane_audit_rows}
         for b, n in sorted(self.chunk_hist.items()):
             out[f"chunks_bucket_{b}"] = n
         return out
@@ -386,6 +515,7 @@ class StagedLane:
         self._arr = None
         self._norms = None
         self._staged = None
+        self._carry = _NO_ROWS
 
     # -- queries -----------------------------------------------------------
 

@@ -707,6 +707,48 @@ class Store:
             self._h, out.ctypes.data_as(C.POINTER(C.c_uint64))))
         return out
 
+    def epochs_at(self, rows: np.ndarray) -> np.ndarray:
+        """Epochs of the listed slots (uint64, one per row): what a
+        consumer of the change journal compares its own with."""
+        rows = np.ascontiguousarray(rows, dtype=np.uint32)
+        out = np.empty(rows.size, dtype=np.uint64)
+        _ck(self._lib.spt_epochs_at(
+            self._h, rows.ctypes.data_as(C.POINTER(C.c_uint32)),
+            rows.size, out.ctypes.data_as(C.POINTER(C.c_uint64))))
+        return out
+
+    # -- change journal ----------------------------------------------------
+
+    def journal_head(self) -> int:
+        """Entries the change journal ever took: the cursor of a
+        consumer that starts now.  Take it BEFORE the first epochs()
+        snapshot, so a write during the snapshot is found afterwards."""
+        return int(self._lib.spt_journal_head(self._h))
+
+    def changed_since(self, cursor: int) -> tuple[np.ndarray, int, bool]:
+        """The distinct slots whose epoch moved since `cursor`, as
+        (rows, new_cursor, complete).  Every operation that moves a
+        slot's epoch appends the slot to a ring in the shared mapping
+        (sptpu.h, "change journal"); a consumer keeps its own cursor
+        and clears nothing.  A row says "look at this slot": it may
+        still be mid-write (odd epoch — look again next time, its
+        record is behind new_cursor already) or unchanged (a spurious
+        record).  `complete` is False when the writers lapped the
+        cursor or an entry was claimed and never written: the rows are
+        then empty, new_cursor is the head as it was when the call
+        began, and the caller scans epochs() AFTER this call."""
+        lib, h = self._lib, self._h
+        want = min(max(int(lib.spt_journal_head(h)) - cursor, 0),
+                   N.JOURNAL_CAP)
+        buf = np.empty(want, dtype=np.uint32)
+        out = C.c_uint64()
+        n = lib.spt_changed_since(
+            h, cursor, buf.ctypes.data_as(C.POINTER(C.c_uint32)), want,
+            C.byref(out))
+        if n < 0:
+            return buf[:0], int(out.value), False
+        return np.unique(buf[:n]), int(out.value), True
+
     GATHER_TORN = np.uint64(0xFFFFFFFFFFFFFFFF)
 
     def vec_gather(self, rows: np.ndarray

@@ -1,5 +1,5 @@
-/* stress.c — MRSW integrity stress: one writer thread hammers a hot key
- * set while N reader threads validate a structured payload on every read.
+/* stress.c — MRSW integrity stress: writer threads hammer a hot key set
+ * while N reader threads validate a structured payload on every read.
  * Any torn read (payload that doesn't parse back to ver|nonce|data) is an
  * integrity failure and a nonzero exit.
  *
@@ -7,8 +7,17 @@
  * same contract — readers count EAGAIN retries (expected under load) and
  * corruption (never acceptable); reports ops/sec.
  *
- * Usage: spt_stress [--readers N] [--keys K] [--duration-ms D]
- *                   [--slots S] [--val-size V] [--scrub MODE]
+ * Beside them one FOLLOWER thread consumes the change journal the way the
+ * device lane does: it keeps the last stable epoch it saw of every slot,
+ * fed by spt_changed_since alone (a slot seen odd is carried to the next
+ * pass; a full scan only when the journal says it was lapped).  When the
+ * writers have stopped, an audit compares every slot's epoch with the
+ * follower's: journal + audit = the slots that moved, and the audit's
+ * share must be 0 — a slot it finds moved without a record.
+ *
+ * Usage: spt_stress [--writers N] [--readers N] [--keys K]
+ *                   [--duration-ms D] [--slots S] [--val-size V]
+ *                   [--scrub MODE]
  */
 #define _GNU_SOURCE
 #include "sptpu.h"
@@ -64,7 +73,7 @@ static void *writer(void *arg) {
   if (g_raw) return writer_raw(arg);
   char key[SPT_KEY_MAX];
   char *payload = malloc((size_t)g_valsz + 64);
-  long nonce = 0;
+  long nonce = (long)(intptr_t)arg * 7919;  /* writers start apart */
   while (!atomic_load_explicit(&g_stop, memory_order_relaxed)) {
     int i = (int)(nonce % g_nkeys);
     key_name(key, i);
@@ -137,6 +146,58 @@ static void *reader(void *arg) {
   return NULL;
 }
 
+/* ---- the journal follower ---- */
+
+static uint32_t g_slots;
+static uint64_t *g_seen;           /* last stable epoch seen, per slot */
+static _Atomic int g_follow_stop;
+static long g_j_rows, g_j_passes, g_j_fallbacks, g_j_carried;
+
+static void *follower(void *arg) {
+  (void)arg;
+  uint32_t *rows = malloc(sizeof(uint32_t) * SPT_JOURNAL_CAP);
+  uint32_t *carry = malloc(sizeof(uint32_t) * g_slots);
+  uint32_t *carried_in = calloc(g_slots, sizeof(uint32_t)); /* pass no. */
+  uint64_t *eps = malloc(sizeof(uint64_t) * g_slots);
+  uint32_t n_carry = 0, pass = 0;
+  uint64_t cursor = 0;             /* a new store's head: before any write */
+  for (;;) {
+    /* read the flag first: a pass that began after the writers were
+     * joined sees everything they appended */
+    int last = atomic_load_explicit(&g_follow_stop, memory_order_acquire);
+    int n = spt_changed_since(g_st, cursor, rows, SPT_JOURNAL_CAP, &cursor);
+    uint32_t kept = 0;
+    g_j_passes++;
+    pass++;
+    if (n < 0) {                    /* lapped: scan, from the new cursor */
+      g_j_fallbacks++;
+      spt_epochs(g_st, eps);
+      for (uint32_t i = 0; i < g_slots; i++) {
+        if (eps[i] & 1) carry[kept++] = i;
+        else g_seen[i] = eps[i];
+      }
+    } else {
+      g_j_rows += n;
+      for (uint32_t j = 0; j < n_carry + (uint32_t)n; j++) {
+        uint32_t idx = j < n_carry ? carry[j] : rows[j - n_carry];
+        uint64_t e = 0;
+        spt_epochs_at(g_st, &idx, 1, &e);
+        if (!(e & 1)) {
+          g_seen[idx] = e;
+        } else if (carried_in[idx] != pass) {  /* rows repeat: once each */
+          carried_in[idx] = pass;
+          carry[kept++] = idx;
+          g_j_carried++;
+        }
+      }
+    }
+    n_carry = kept;
+    if (last && n == 0 && n_carry == 0) break;
+  }
+  free(rows); free(carry); free(carried_in); free(eps);
+  return NULL;
+}
+
 /* --json: emit one machine-readable line.  CPO
  * (cycles per op) is measured separately from the contended run: a
  * single-threaded spt_set loop over pre-rendered keys, timed with the
@@ -175,9 +236,11 @@ static int int_arg(int argc, char **argv, int *i) {
 
 int main(int argc, char **argv) {
   int readers = 7, duration_ms = 5000, slots = 50000, json_out = 0;
+  int writers = 2;
   uint32_t scrub = 1;
   for (int i = 1; i < argc; i++) {
     if (!strcmp(argv[i], "--readers")) readers = int_arg(argc, argv, &i);
+    else if (!strcmp(argv[i], "--writers")) writers = int_arg(argc, argv, &i);
     else if (!strcmp(argv[i], "--keys")) g_nkeys = int_arg(argc, argv, &i);
     else if (!strcmp(argv[i], "--duration-ms"))
       duration_ms = int_arg(argc, argv, &i);
@@ -194,38 +257,65 @@ int main(int argc, char **argv) {
   g_st = spt_create(name, (uint32_t)slots, (uint32_t)g_valsz + 64, 0, 0);
   if (!g_st) { perror("create"); return 2; }
   spt_set_mop(g_st, scrub);
+  if (writers < 1) writers = 1;
+  if (writers > 8) writers = 8;
+  g_slots = (uint32_t)slots;
+  g_seen = calloc(g_slots, sizeof *g_seen);   /* a new store: all 0 */
 
-  pthread_t wt, rt[64];
-  pthread_create(&wt, NULL, writer, NULL);
+  pthread_t ft, wt[8], rt[64];
+  pthread_create(&ft, NULL, follower, NULL);
+  for (int i = 0; i < writers; i++)
+    pthread_create(&wt[i], NULL, writer, (void *)(intptr_t)i);
   for (int i = 0; i < readers && i < 64; i++)
     pthread_create(&rt[i], NULL, reader, NULL);
 
   struct timespec ts = {duration_ms / 1000, (duration_ms % 1000) * 1000000L};
   nanosleep(&ts, NULL);
   atomic_store(&g_stop, 1);
-  pthread_join(wt, NULL);
+  for (int i = 0; i < writers; i++) pthread_join(wt[i], NULL);
   for (int i = 0; i < readers && i < 64; i++) pthread_join(rt[i], NULL);
+  atomic_store_explicit(&g_follow_stop, 1, memory_order_release);
+  pthread_join(ft, NULL);
+
+  /* the audit: every slot's epoch against the follower's */
+  long moved = 0, audit = 0;
+  uint64_t *now = malloc(sizeof(uint64_t) * g_slots);
+  spt_epochs(g_st, now);
+  for (uint32_t i = 0; i < g_slots; i++) {
+    if (now[i]) moved++;
+    if (now[i] != g_seen[i]) audit++;
+  }
+  free(now);
+  free(g_seen);
 
   long w = g_writes, r = g_reads, e = g_eagain, m = g_miss, c = g_corrupt;
   double secs = duration_ms / 1000.0;
-  printf("MRSW: writers=1 readers=%d dur=%.1fs\n", readers, secs);
+  printf("MRSW: writers=%d readers=%d dur=%.1fs\n", writers, readers, secs);
+  printf("  journal: rows=%ld passes=%ld fallbacks=%ld carried=%ld "
+         "moved=%ld audit=%ld\n", g_j_rows, g_j_passes, g_j_fallbacks,
+         g_j_carried, moved, audit);
   printf("  writes=%ld (%.2fM/s)  reads=%ld (%.2fM/s)\n", w, w / secs / 1e6,
          r, r / secs / 1e6);
   printf("  total=%.2fM ops/s  eagain=%ld  miss=%ld  corrupt=%ld\n",
          (w + r) / secs / 1e6, e, m, c);
   if (json_out) {
     double cpo = measure_write_cpo();
-    printf("{\"tool\": \"mrsw\", \"writers\": 1, \"readers\": %d, "
+    printf("{\"tool\": \"mrsw\", \"writers\": %d, \"readers\": %d, "
            "\"duration_s\": %.2f, \"writes\": %ld, \"reads\": %ld, "
            "\"ops_per_sec\": %.0f, \"write_cpo\": %.1f, "
            "\"ticks_per_us\": %llu, \"eagain\": %ld, \"miss\": %ld, "
            "\"corrupt\": %ld, \"raw\": %d}\n",
-           readers, secs, w, r, (w + r) / secs, cpo,
+           writers, readers, secs, w, r, (w + r) / secs, cpo,
            (unsigned long long)spt_ticks_per_us(), e, m, c, g_raw);
   }
   spt_close(g_st);
   spt_unlink(name, 0);
   if (c) { fprintf(stderr, "INTEGRITY FAILURE\n"); return 1; }
+  if (audit || !moved || !g_j_rows) {
+    fprintf(stderr, "JOURNAL FAILURE: %ld slots moved without a record "
+            "(moved=%ld journal rows=%ld)\n", audit, moved, g_j_rows);
+    return 1;
+  }
   printf("OK\n");
   return 0;
 }

@@ -9,7 +9,9 @@
  * epoch-gated batch commit, retrain (backward epoch), the full shard
  * election matrix (priority, expiry, claimed_at/pid tie-breaks, DONTNEED
  * bumper, rebid revival, -ENOSPC on the 33rd bid, sovereign /
- * non-sovereign madvise), and the event bus (init / dirty bits / wait).
+ * non-sovereign madvise), the event bus (init / dirty bits / wait), and
+ * the change journal (every call that can move an epoch leaves its slot
+ * there; a lapped reader is told so; another format version is refused).
  *
  * Like the reference's claim_ex determinism trick (splinter.h:1142-1152),
  * multi-process elections are tested by forging bids — no processes, no
@@ -388,6 +390,175 @@ static void suite(const char *name, uint32_t flags) {
   TEST(spt_open(name, flags) == NULL, "open after unlink fails");
 }
 
+/* ---- change journal ---- */
+
+/* Read the journal from *cursor to its head; 1 if slot idx is in it. */
+static int journaled(spt_store *st, uint64_t *cursor, int idx) {
+  static uint32_t rows[SPT_JOURNAL_CAP];
+  int n = spt_changed_since(st, *cursor, rows, SPT_JOURNAL_CAP, cursor);
+  int hit = 0;
+  for (int i = 0; i < n; i++)
+    if (idx >= 0 && rows[i] == (uint32_t)idx) hit = 1;
+  return n < 0 ? n : hit;
+}
+
+/* One exported call that can move an epoch: the slot's epoch moved (or
+ * the call aborted after taking the lock) and the journal names the
+ * slot, in a range that held nothing before the call. */
+#define MOVES(call, key, name) do {                                      \
+    int ix_ = spt_find_index(st, key);                                   \
+    uint64_t e0_ = ix_ >= 0 ? spt_epoch_at(st, (uint32_t)ix_) : 0;       \
+    TEST(journaled(st, &cur, -1) == 0 && cur == spt_journal_head(st),    \
+         name ": journal read up to its head before the call");          \
+    (void)(call);                                                        \
+    if (ix_ < 0) ix_ = spt_find_index(st, key);                          \
+    TEST(ix_ >= 0 && spt_epoch_at(st, (uint32_t)ix_) != e0_ &&           \
+         spt_epoch_at(st, (uint32_t)ix_) % 2 == 0,                       \
+         name ": the epoch moved and is even");                          \
+    TEST(journaled(st, &cur, ix_) == 1, name ": the slot is journaled"); \
+  } while (0)
+
+static void journal_suite(const char *name, uint32_t flags) {
+  spt_unlink(name, flags);
+  spt_store *st = spt_create(name, 64, 256, 8, flags);
+  TEST(st != NULL, "journal: create");
+  uint64_t cur = spt_journal_head(st);
+  TEST(cur == 0, "journal: a new store's head is 0");
+  uint32_t none[1];
+  uint64_t c2 = 99;
+  TEST(spt_changed_since(st, 0, none, 1, &c2) == 0 && c2 == 0,
+       "journal: nothing appended, nothing returned");
+
+  float v[8] = {1, 2, 3, 4, 5, 6, 7, 8};
+  MOVES(spt_set(st, "a", "1", 1), "a", "set (new key)");
+  MOVES(spt_set(st, "a", "22", 2), "a", "set (rewrite)");
+  MOVES(spt_append(st, "a", "3", 1), "a", "append");
+  MOVES(spt_append(st, "fresh", "x", 1), "fresh", "append (new key)");
+  MOVES(spt_set_type(st, "a", SPT_T_BIGUINT), "a", "set_type");
+  uint64_t r = 0;
+  MOVES(spt_integer_op(st, "a", SPT_IOP_INC, 0, &r), "a", "integer_op");
+  MOVES(spt_tandem_set(st, "t", 0, "p0", 2), "t", "tandem_set (base)");
+  MOVES(spt_tandem_set(st, "t", 1, "p1", 2), "t" SPT_ORDER_SEP "1",
+        "tandem_set (order 1)");
+  MOVES(spt_set_system(st, "a"), "a", "set_system");
+  MOVES(spt_set_system(st, "sys"), "sys", "set_system (new key)");
+  MOVES(spt_stamp(st, "a", 2, 0), "a", "stamp");
+  MOVES(spt_vec_set(st, "a", v, 8), "a", "vec_set");
+  int ia = spt_find_index(st, "a");
+  MOVES(spt_vec_set_at(st, (uint32_t)ia, v, 8), "a", "vec_set_at");
+  {
+    uint32_t rows[1] = {(uint32_t)ia};
+    uint64_t eps[1] = {spt_epoch_at(st, (uint32_t)ia)};
+    int32_t res[1] = {1};
+    MOVES(spt_vec_commit_batch(st, rows, eps, v, 1, 8, 0, res), "a",
+          "vec_commit_batch");
+    TEST(res[0] == 0, "vec_commit_batch committed");
+    /* a lock that aborts: the epoch gate refuses, the lock was held */
+    eps[0] = 2;
+    MOVES(spt_vec_commit_batch(st, rows, eps, v, 1, 8, 0, res), "a",
+          "vec_commit_batch (stale: aborted lock)");
+    TEST(res[0] == -ESTALE, "stale commit refused");
+  }
+  spt_set(st, "txt", "not a number", 12);
+  journaled(st, &cur, -1);
+  MOVES(spt_set_type(st, "txt", SPT_T_BIGUINT), "txt",
+        "set_type -EPROTOTYPE (aborted lock)");
+  MOVES(spt_integer_op(st, "txt", SPT_IOP_INC, 0, &r), "txt",
+        "integer_op -EPROTOTYPE (aborted lock)");
+  char big[250]; memset(big, 'y', sizeof big);
+  MOVES(spt_append(st, "txt", big, sizeof big), "txt",
+        "append -EMSGSIZE (aborted lock)");
+  MOVES(spt_purge(st), "a", "purge (live slot)");
+
+  /* retrain drives the epoch BACKWARD, from a slot stuck anywhere */
+  for (int i = 0; i < 8; i++) spt_set(st, "a", "x", 1);
+  TEST(spt_epoch_at(st, (uint32_t)ia) > 4, "epoch well past 4");
+  MOVES(spt_retrain(st, "a"), "a", "retrain (backward)");
+  TEST(spt_epoch_at(st, (uint32_t)ia) == 4, "retrain left epoch 4");
+
+  /* unset: the slot is journaled though the key no longer resolves */
+  {
+    int it = spt_find_index(st, "txt");
+    uint64_t e0 = spt_epoch_at(st, (uint32_t)it);
+    journaled(st, &cur, -1);
+    TEST(spt_unset(st, "txt") == 0 &&
+         spt_epoch_at(st, (uint32_t)it) == e0 + 2, "unset moved the epoch");
+    TEST(journaled(st, &cur, it) == 1, "unset: the slot is journaled");
+    MOVES(spt_purge(st), "a", "purge");   /* sweeps the tombstone too */
+    journaled(st, &cur, -1);
+    e0 = spt_epoch_at(st, (uint32_t)it);
+    spt_purge(st);
+    TEST(spt_epoch_at(st, (uint32_t)it) == e0 + 2 &&
+         journaled(st, &cur, it) == 1, "purge: a tombstone is journaled");
+    int i1 = spt_find_index(st, "t" SPT_ORDER_SEP "1");
+    TEST(spt_tandem_unset(st, "t", 1) == 2 &&
+         journaled(st, &cur, i1) == 1, "tandem_unset: journaled");
+  }
+
+  /* what moves no epoch leaves no record */
+  journaled(st, &cur, -1);
+  spt_label_or(st, "a", 1); spt_label_andnot(st, "a", 1);
+  spt_bump(st, "a"); spt_slot_usr_set(st, "a", 3);
+  TEST(spt_journal_head(st) == cur, "labels, bump, user flags: no record");
+
+  /* the listed epochs are the slots' own; out of range reads 0 */
+  {
+    uint32_t rows[2] = {(uint32_t)ia, 1u << 30};
+    uint64_t eps[2] = {7, 7};
+    TEST(spt_epochs_at(st, rows, 2, eps) == 2 &&
+         eps[0] == spt_epoch_at(st, (uint32_t)ia) && eps[1] == 0,
+         "epochs_at");
+  }
+
+  /* max_out bounds one read; the cursor says how far it got */
+  for (int i = 0; i < 5; i++) spt_set(st, "a", "x", 1);
+  {
+    uint32_t two[2];
+    uint64_t c = cur;
+    TEST(spt_changed_since(st, c, two, 2, &c) == 2 && c == cur + 2,
+         "a short buffer takes what fits");
+    TEST(journaled(st, &c, ia) == 1 && c == cur + 5, "and the rest next");
+    cur = c;
+  }
+
+  /* a reader the writers lapped is told so, and where to go on from */
+  uint64_t old = cur;
+  for (uint32_t i = 0; i <= SPT_JOURNAL_CAP; i++) spt_set(st, "a", "x", 1);
+  TEST(journaled(st, &cur, ia) == -EOVERFLOW &&
+       cur == spt_journal_head(st) && cur == old + SPT_JOURNAL_CAP + 1,
+       "lapped: -EOVERFLOW, cursor = head");
+  TEST(journaled(st, &cur, ia) == 0, "after the fallback: up to date");
+  uint64_t beyond = cur + 5;
+  TEST(journaled(st, &beyond, ia) == -EOVERFLOW && beyond == cur,
+       "a cursor past the head is not this store's");
+  /* exactly a ring behind is still complete */
+  old = cur;
+  for (uint32_t i = 0; i < SPT_JOURNAL_CAP; i++) spt_set(st, "a", "x", 1);
+  TEST(journaled(st, &cur, ia) == 1 && cur == old + SPT_JOURNAL_CAP,
+       "one whole ring behind: complete");
+
+  /* a second handle has its own cursor on the same journal */
+  spt_store *peer = spt_open(name, flags);
+  TEST(peer && spt_journal_head(peer) == cur, "peer sees the same head");
+  spt_set(peer, "b", "1", 1);
+  TEST(journaled(st, &cur, spt_find_index(st, "b")) == 1,
+       "a peer handle's write is journaled");
+  spt_close(peer);
+  spt_close(st);
+
+  if (flags & SPT_BACKEND_FILE) {
+    /* another format version is refused */
+    FILE *f = fopen(name, "r+b");
+    uint32_t ver = SPT_FORMAT_VERSION - 1;
+    int wrote = f && fseek(f, 4, SEEK_SET) == 0 &&
+                fwrite(&ver, sizeof ver, 1, f) == 1;
+    if (f) fclose(f);
+    TEST(wrote && spt_open(name, flags) == NULL &&
+         spt_last_error() == EPROTO, "format version 1 refused (-EPROTO)");
+  }
+  spt_unlink(name, flags);
+}
+
 int main(void) {
   char shm_name[64], file_name[128];
   snprintf(shm_name, sizeof shm_name, "/spt-unit-%d", (int)getpid());
@@ -396,8 +567,10 @@ int main(void) {
 
   printf("# backend: shm\n");
   suite(shm_name, SPT_BACKEND_SHM);
+  journal_suite(shm_name, SPT_BACKEND_SHM);
   printf("# backend: file (persistent)\n");
   suite(file_name, SPT_BACKEND_FILE);
+  journal_suite(file_name, SPT_BACKEND_FILE);
 
   printf("1..%d\n", n_run);
   printf("# %d run, %d failed\n", n_run, n_fail);

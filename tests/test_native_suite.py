@@ -33,3 +33,25 @@ def test_native_stress_short():
                        capture_output=True, text=True, timeout=120)
     assert r.returncode == 0, r.stdout + r.stderr
     assert "corrupt=0" in r.stdout
+
+
+@pytest.mark.parametrize("writers", [1, 4])
+def test_native_stress_journal_follower(writers):
+    """spt_stress's follower consumes the change journal the way the
+    device lane does, beside concurrent writers; when they stop, an
+    audit of every slot must find nothing that moved without a
+    record (journal + audit = the slots that moved, audit share 0)."""
+    import re
+
+    _build("tests")
+    r = subprocess.run([str(NATIVE / "build" / "spt_stress"),
+                        "--duration-ms", "400", "--writers", str(writers),
+                        "--readers", "2", "--keys", "500"],
+                       capture_output=True, text=True, timeout=120)
+    assert r.returncode == 0, r.stdout + r.stderr
+    m = re.search(r"journal: rows=(\d+) .* moved=(\d+) audit=(\d+)",
+                  r.stdout)
+    assert m, r.stdout
+    rows, moved, audit = map(int, m.groups())
+    assert rows > 0 and moved == 500 and audit == 0
+    assert "corrupt=0" in r.stdout

@@ -216,9 +216,15 @@ class TestPromExposition:
         lane.ring_dispatches = 1
         lane.ring_chunks = 2
         lane.chunk_hist = {64: 2}
+        lane.lane_slots_scanned = 130
+        lane.journal_rows = 66
+        lane.journal_fallbacks = 1
+        lane.lane_audit_rows = 0
         c = lane.counters()
         assert c["chunks_bucket_64"] == 2
         assert c["ring_dispatches"] == 1
+        assert c["lane_slots_scanned"] == 130 and c["journal_rows"] == 66
+        assert c["journal_fallbacks"] == 1 and c["lane_audit_rows"] == 0
         assert all(isinstance(v, (int, float)) for v in c.values())
 
 

@@ -385,9 +385,10 @@ class TestSearcherOverlap:
         assert (sr.stats.ready_selects
                 + sr.stats.blocking_selects) == sr.stats.dispatches
 
-    def test_heartbeat_carries_inflight_gauge(self, store):
+    def test_heartbeat_carries_inflight_gauge(self, store_2k):
         from libsplinter_tpu.engine.searcher import Searcher
 
+        store = store_2k          # room for the whole heartbeat
         sr = Searcher(store, inflight_depth=3)
         sr.attach()
         sr.publish_stats()

@@ -793,3 +793,228 @@ def test_run_loop_is_fully_accounted(traced):
     finally:
         store.close()
         Store.unlink(name)
+
+
+# ---------------------------------- the journal-fed lane and its mask
+
+def _mask_spy(sr):
+    """Record a copy of the mask each dispatch was handed (and the
+    array's identity), through the daemon's own program lookup."""
+    seen = []
+    real = sr._program
+
+    def program(k_fetch, mxu_bf16=False):
+        fn = real(k_fetch, mxu_bf16=mxu_bf16)
+
+        def spy(arr, q, mask, norms):
+            seen.append((mask, mask.copy()))
+            return fn(arr, q, mask, norms)
+
+        spy._devtime_name = getattr(fn, "_devtime_name", None)
+        return spy
+
+    sr._program = program
+    return seen
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_patched_mask_equals_candidate_mask(store, seed):
+    """The liveness mask is state the daemon patches, never rebuilds:
+    after any seeded run of writes, unsets, re-sets and serviced
+    drains it equals candidate_mask() of the store row for row, it
+    is the SAME float32 array drain after drain, and what a dispatch
+    was handed is that array with exactly the pending rows zeroed."""
+    rng = np.random.default_rng(100 + seed)
+    dim = store.vec_dim
+    _fill_docs(store, 24, rng)
+    sr = Searcher(store)
+    sr.attach()
+    seen = _mask_spy(sr)
+    keys = [f"doc/{i}" for i in range(40)]
+    handed = set()
+    for rnd in range(5):
+        for _ in range(int(rng.integers(1, 12))):
+            key = keys[rng.integers(0, len(keys))]
+            op = rng.integers(0, 4)
+            if op == 0 and key in store:
+                store.unset(key)
+            elif op == 1 and key in store:
+                store.set(key, "rewritten")
+            else:
+                if key not in store:
+                    store.set(key, "new")
+                store.vec_set(key, rng.normal(size=dim).astype(np.float32))
+        reqs = [f"__sqtmp_m{seed}_{i}"
+                for i in rng.choice(6, size=int(rng.integers(1, 5)),
+                                    replace=False)]
+        for key in reqs:
+            _request(store, key,
+                     rng.normal(size=dim).astype(np.float32))
+        want = P.candidate_mask(store)
+        want[[store.find_index(k) for k in reqs]] = 0.0
+        before = len(seen)
+        assert sr.run_once() == len(reqs)
+        assert len(seen) == before + 1
+        mask, at_dispatch = seen[-1]
+        handed.add(id(mask))
+        assert at_dispatch.dtype == np.float32
+        assert at_dispatch.shape == (store.nslots,)
+        np.testing.assert_array_equal(at_dispatch, want)
+        # after the drain the request rows are candidates again, and
+        # once the lane has read the drain's own commits the mask is
+        # candidate_mask() of the store
+        sr.lane.refresh()
+        np.testing.assert_array_equal(sr._sync_live(),
+                                      P.candidate_mask(store))
+    assert len(handed) == 1 and handed == {id(sr._live)}
+    assert sr.lane.full_uploads == 1
+    assert sr.lane.journal_fallbacks == 1        # the first attach
+    assert sr.lane.audit() == 0
+
+
+@pytest.mark.parametrize("whose", ["own", "peer_stripe"])
+def test_pending_rows_are_masked_for_the_drain_and_live_after(
+        store, whose):
+    """Request rows hold query vectors: every pending one — a peer
+    replica's stripe too — is out of the candidate set while a drain
+    scores, and live again when its last dispatch has been fetched."""
+    rng = np.random.default_rng(7)
+    dim = store.vec_dim
+    _fill_docs(store, 16, rng)
+    sr = Searcher(store)
+    sr.attach()
+    seen = _mask_spy(sr)
+    q = rng.normal(size=dim).astype(np.float32)
+    _request(store, "__sqtmp_mine", q)
+    _request(store, "__sqtmp_other", q)
+    mine = store.find_index("__sqtmp_mine")
+    other = store.find_index("__sqtmp_other")
+    if whose == "peer_stripe":
+        sr.stripes.owns = lambda idx: idx != other
+    want = P.candidate_mask(store)
+    assert want[mine] == 1.0 and want[other] == 1.0
+    want[[mine, other]] = 0.0
+    served = sr.run_once()
+    assert served == (2 if whose == "own" else 1)
+    _, at_dispatch = seen[-1]
+    np.testing.assert_array_equal(at_dispatch, want)
+    assert sr._live[mine] == 1.0 and sr._live[other] == 1.0
+    rec = _result(store, "__sqtmp_mine")
+    assert mine not in rec["i"] and other not in rec["i"]
+    if whose == "peer_stripe":
+        assert store.labels("__sqtmp_other") & P.LBL_SEARCH_REQ
+
+
+def test_a_failed_drain_leaves_its_request_rows_live(store):
+    rng = np.random.default_rng(8)
+    _fill_docs(store, 8, rng)
+    sr = Searcher(store)
+    sr.attach()
+    q = rng.normal(size=store.vec_dim).astype(np.float32)
+    _request(store, "__sqtmp_a", q)
+    assert sr.run_once() == 1
+    _request(store, "__sqtmp_a", q)
+    row = store.find_index("__sqtmp_a")
+
+    def boom(*a, **k):
+        raise RuntimeError("dispatch plumbing broke")
+
+    sr._dispatch_groups = boom
+    assert sr.run_once() == 0
+    assert sr.stats.drain_faults == 1
+    assert sr._live[row] == 1.0
+
+
+def test_64_dirty_drain_of_100k_slots_scans_under_1000():
+    """A drain that follows 64 rewritten request rows on a 100,000-
+    slot store looks at a few hundred epochs (refresh + mask patch +
+    the hidden rows' restore), not twice 100,000; nothing falls back
+    and the audit finds nothing the journal missed."""
+    import os
+    import uuid
+
+    name = f"/spt-sr100k-{os.getpid()}-{uuid.uuid4().hex[:6]}"
+    Store.unlink(name)
+    st = Store.create(name, nslots=100_000, max_val=2048, vec_dim=8)
+    try:
+        rng = np.random.default_rng(5)
+        _fill_docs(st, 200, rng)
+        sr = Searcher(st)
+        sr.attach()
+        keys = [f"__sqtmp_k{i}" for i in range(64)]
+
+        def ask():
+            for key in keys:
+                _request(st, key, rng.normal(size=8).astype(np.float32))
+
+        ask()
+        assert sr.run_once() == 64
+        first = sr.stats.lane_slots_scanned
+        assert first >= 3 * st.nslots            # upload + first mask
+        ask()
+        assert sr.run_once() == 64
+        per_drain = sr.stats.lane_slots_scanned - first
+        assert 64 <= per_drain < 1000, per_drain
+        assert sr.stats.journal_fallbacks == 1   # the first attach
+        assert sr.stats.journal_rows >= 128
+        sr._publish_beat()
+        snap = json.loads(st.get(P.KEY_SEARCH_STATS).rstrip(b"\0"))
+        assert snap["lane_audit_rows"] == 0
+        assert snap["journal_fallbacks"] == 1
+        assert snap["lane_slots_scanned"] == sr.stats.lane_slots_scanned
+        assert snap["lane_slots_scanned"] - first < 1000   # no audit in it
+        for k in ("lane_slots_scanned", "journal_rows",
+                  "journal_fallbacks", "lane_audit_rows"):
+            assert k in snap["lane"], k
+        rec = _result(st, keys[0])
+        assert len(rec["i"]) == 5
+        assert all(st.key_at(i).startswith("doc/") for i in rec["i"])
+    finally:
+        st.close()
+        Store.unlink(name)
+
+
+def test_the_beat_audits_the_lane_and_publishes_what_it_found(store_2k):
+    """Once a heartbeat the lane runs the full comparison: a row the
+    journal did not deliver is staged, counted and published, and the
+    mask is patched for it like for any row the lane re-examined."""
+    from libsplinter_tpu.ops import StagedLane
+
+    store = store_2k
+    rng = np.random.default_rng(9)
+    _fill_docs(store, 8, rng)
+
+    class LosesARecord:
+        lost = -1
+
+        def __init__(self, st):
+            self._st = st
+
+        def __getattr__(self, name):
+            return getattr(self._st, name)
+
+        def changed_since(self, cursor):
+            rows, cur, complete = self._st.changed_since(cursor)
+            return rows[rows != self.lost], cur, complete
+
+    view = LosesARecord(store)
+    sr = Searcher(store, lane=StagedLane(view))
+    sr.attach()
+    q = rng.normal(size=store.vec_dim).astype(np.float32)
+    _request(store, "__sqtmp_a", q)
+    assert sr.run_once() == 1
+    store.set("doc/late", "a document the journal loses")
+    store.vec_set("doc/late", q)                 # the best hit there is
+    view.lost = row = store.find_index("doc/late")
+    _request(store, "__sqtmp_a", q)
+    assert sr.run_once() == 1
+    assert sr._live[row] == 0.0                  # stale: not a candidate
+    assert row not in _result(store, "__sqtmp_a")["i"]
+    sr._publish_beat()
+    snap = json.loads(store.get(P.KEY_SEARCH_STATS).rstrip(b"\0"))
+    assert snap["lane_audit_rows"] == 1
+    assert snap["lane"]["lane_audit_rows"] == 1
+    _request(store, "__sqtmp_a", q)
+    assert sr.run_once() == 1
+    assert sr._live[row] == 1.0                  # the audit's rows too
+    assert _result(store, "__sqtmp_a")["i"][0] == row

@@ -336,3 +336,337 @@ class TestWireDtype:
         assert lane.wire == "f16"
         monkeypatch.delenv("SPTPU_LANE_WIRE")
         assert StagedLane(store).wire == "f32"
+
+
+# ---------------------------------------------------- the change journal
+
+def _mutate(store, rng, keys, dim):
+    """One seeded write of the kinds a deployment makes: new rows,
+    rewritten vectors and texts, unsets, re-sets of an unset key,
+    typed and appended values."""
+    op = rng.integers(0, 6)
+    key = keys[rng.integers(0, len(keys))]
+    try:
+        if op == 0 or key not in store:
+            store.set(key, f"text {rng.integers(1 << 30)}")
+            store.vec_set(key, rng.normal(size=dim).astype(np.float32))
+        elif op == 1:
+            store.vec_set(key, rng.normal(size=dim).astype(np.float32))
+        elif op == 2:
+            store.unset(key)
+        elif op == 3:
+            store.append(key, "+")
+        elif op == 4:
+            store.set(key, "rewritten")
+        else:
+            store.stamp(key)
+    except OSError:
+        pass                       # value full: the lock still moved
+
+
+def _assert_lane_is_the_store(store, lane):
+    """The journal-fed lane against the full scan it replaced: device
+    rows, staged epochs and the liveness rule, row for row."""
+    from libsplinter_tpu.engine import protocol as P
+
+    np.testing.assert_array_equal(np.asarray(lane.array),
+                                  np.array(store.vectors))
+    np.testing.assert_array_equal(lane.staged_epochs(), store.epochs())
+    np.testing.assert_array_equal(
+        P.live_epochs(lane.staged_epochs()).astype(np.float32),
+        P.candidate_mask(store))
+    np.testing.assert_allclose(
+        np.asarray(lane.norms),
+        np.linalg.norm(np.array(store.vectors), axis=1), rtol=1e-6)
+
+
+class TestChangeJournal:
+    def test_store_names_the_rows_that_moved(self, store):
+        c0 = store.journal_head()
+        rows, c1, complete = store.changed_since(c0)
+        assert complete and rows.size == 0 and c1 == c0
+        store.set("a", "1")
+        store.set("b", "2")
+        store.vec_set("a", np.ones(store.vec_dim, np.float32))
+        store.label_or("a", 1)                 # moves no epoch
+        rows, c2, complete = store.changed_since(c1)
+        assert complete and c2 == c1 + 3
+        assert sorted(rows) == sorted({store.find_index("a"),
+                                       store.find_index("b")})
+        np.testing.assert_array_equal(
+            store.epochs_at(rows), store.epochs()[rows])
+        # every consumer has its own cursor: the first still reads all
+        assert store.changed_since(c0)[0].size == 2
+
+    @pytest.mark.parametrize("seed", range(6))
+    def test_journal_fed_lane_equals_full_scan(self, store, seed):
+        """After any seeded sequence of writes, unsets and re-sets
+        the journal-fed lane equals the store row for row, without a
+        scan: every refresh after the upload reads the journal."""
+        rng = np.random.default_rng(seed)
+        dim = store.vec_dim
+        keys = [f"doc/{i}" for i in range(40)]
+        _fill(store, 24, dim, seed=seed)
+        lane = StagedLane(store)
+        lane.refresh()
+        scanned0 = lane.lane_slots_scanned
+        for _ in range(8):
+            for _ in range(int(rng.integers(1, 30))):
+                _mutate(store, rng, keys, dim)
+            lane.refresh()
+            _assert_lane_is_the_store(store, lane)
+        assert lane.full_uploads == 1
+        assert lane.journal_fallbacks == 1       # the first attach
+        assert lane.journal_rows > 0
+        # it looked at the rows the journal named, nothing like a scan
+        assert lane.lane_slots_scanned - scanned0 <= 8 * 40
+        assert lane.audit() == 0 and lane.lane_audit_rows == 0
+        _assert_lane_is_the_store(store, lane)
+
+    def test_retrain_moves_the_epoch_backward_and_is_restaged(self, store):
+        dim = store.vec_dim
+        _fill(store, 4, dim)
+        lane = StagedLane(store)
+        lane.refresh()
+        for _ in range(3):
+            store.vec_set("doc/1", np.full(dim, 2.0, np.float32))
+        lane.refresh()
+        row = store.find_index("doc/1")
+        assert lane.staged_epochs([row])[0] > 4
+        store.retrain("doc/1")                 # epoch 4, vector zeroed
+        assert store.epoch_at(row) == 4
+        arr = np.asarray(lane.refresh())
+        assert (arr[row] == 0).all()
+        assert lane.journal_fallbacks == 1 and lane.audit() == 0
+        _assert_lane_is_the_store(store, lane)
+
+    def test_unchanged_store_reads_nothing(self, store):
+        _fill(store, 8, store.vec_dim)
+        lane = StagedLane(store)
+        lane.refresh()
+        before = lane.counters()
+        lane.refresh()
+        after = lane.counters()
+        for k in ("lane_slots_scanned", "journal_rows",
+                  "journal_fallbacks", "rows_staged"):
+            assert after[k] == before[k], k
+
+    @pytest.mark.parametrize("how", ["first_attach", "overflow",
+                                     "invalidate"])
+    def test_fallback_scans_and_counts(self, store, how):
+        """No cursor yet, or a cursor the writers lapped: the refresh
+        is the full comparison, counted, and the lane is right."""
+        from libsplinter_tpu import _native as N
+
+        dim = store.vec_dim
+        _fill(store, 12, dim)
+        lane = StagedLane(store)
+        lane.refresh()
+        assert lane.journal_fallbacks == 1 and lane.full_uploads == 1
+        assert lane.lane_slots_scanned == 2 * store.nslots
+        if how == "overflow":
+            store.vec_set("doc/3", np.full(dim, 2.0, np.float32))
+            for i in range(N.JOURNAL_CAP + 1):
+                store.stamp("doc/5")
+            lane.refresh()
+            assert lane.journal_fallbacks == 2
+            assert lane.full_uploads == 1        # a scan, not an upload
+            assert lane.lane_slots_scanned == 3 * store.nslots
+            assert lane.rows_staged == 2
+        elif how == "invalidate":
+            store.vec_set("doc/3", np.full(dim, 2.0, np.float32))
+            lane.invalidate()
+            lane.refresh()
+            assert lane.journal_fallbacks == 2 and lane.full_uploads == 2
+        _assert_lane_is_the_store(store, lane)
+        # and the journal is the way again
+        store.vec_set("doc/4", np.full(dim, 3.0, np.float32))
+        n = lane.journal_fallbacks
+        lane.refresh()
+        assert lane.journal_fallbacks == n
+        _assert_lane_is_the_store(store, lane)
+        assert lane.audit() == 0
+
+    @pytest.mark.parametrize("where", ["odd_at_compare",
+                                       "torn_at_gather"])
+    def test_row_seen_mid_write_is_staged_when_its_writer_is_done(
+            self, store, where):
+        """A writer's record is appended while its slot is still odd.
+        A lane that looks then finds the row mid-write; the record is
+        behind its cursor for good, so the row has to be REMEMBERED:
+        marked not live, and staged by the next refresh although the
+        journal has nothing new to say."""
+        from libsplinter_tpu import Store
+        from libsplinter_tpu.engine import protocol as P
+
+        dim = store.vec_dim
+        _fill(store, 6, dim)
+        row = store.find_index("doc/2")
+
+        class MidWrite:
+            """The store as a reader sees it while doc/2's writer
+            holds the seqlock (once)."""
+
+            def __init__(self, st):
+                self._st, self.armed = st, False
+
+            def __getattr__(self, name):
+                return getattr(self._st, name)
+
+            def epochs_at(self, rows):
+                eps = self._st.epochs_at(rows)
+                if self.armed and where == "odd_at_compare":
+                    self.armed = False
+                    eps[np.asarray(rows) == row] -= np.uint64(1)
+                return eps
+
+            def vec_gather_iter(self, rows, chunks):
+                for off, vecs, eps in self._st.vec_gather_iter(
+                        rows, chunks):
+                    if self.armed and where == "torn_at_gather":
+                        self.armed = False
+                        eps[np.asarray(rows)[off: off + eps.size]
+                            == row] = Store.GATHER_TORN
+                    yield off, vecs, eps
+
+        view = MidWrite(store)
+        lane = StagedLane(view)
+        lane.refresh()
+        new = np.full(dim, 4.5, np.float32)
+        store.vec_set("doc/2", new)
+        store.vec_set("doc/4", new)
+        view.armed = True
+        arr = np.asarray(lane.refresh())
+        # the sibling landed; the row mid-write did not, and reads as
+        # not live (the candidate_mask rule on an odd staged epoch)
+        np.testing.assert_array_equal(arr[store.find_index("doc/4")], new)
+        assert not (arr[row] == new).all()
+        assert lane.staged_epochs([row])[0] % 2 == 1
+        assert not P.live_epochs(lane.staged_epochs([row]))[0]
+        assert lane.rows_staged == 1
+        # nothing new in the journal, and still it is staged now
+        head = store.journal_head()
+        arr = np.asarray(lane.refresh())
+        assert store.journal_head() == head
+        np.testing.assert_array_equal(arr[row], new)
+        assert lane.rows_staged == 2
+        assert lane.journal_fallbacks == 1       # never a scan
+        assert lane.audit() == 0
+        _assert_lane_is_the_store(store, lane)
+
+    @pytest.mark.parametrize("after_snapshot", [1, 2])
+    def test_write_during_the_upload_is_found_afterwards(
+            self, store, after_snapshot):
+        """The cursor is taken BEFORE the upload's first epoch
+        snapshot: a row written while the lane streams up (after the
+        first snapshot: the copy is suspect; after the second: only
+        the journal knows) is found by the next refresh."""
+        dim = store.vec_dim
+        _fill(store, 6, dim)
+        new = np.full(dim, 9.0, np.float32)
+
+        class WritesDuringUpload:
+            def __init__(self, st):
+                self._st, self.snapshots = st, 0
+
+            def __getattr__(self, name):
+                return getattr(self._st, name)
+
+            def epochs(self):
+                eps = self._st.epochs()
+                self.snapshots += 1
+                if self.snapshots == after_snapshot:
+                    self._st.vec_set("doc/1", new)
+                return eps
+
+        lane = StagedLane(WritesDuringUpload(store))
+        lane.refresh()
+        assert lane.full_uploads == 1
+        arr = np.asarray(lane.refresh())
+        np.testing.assert_array_equal(arr[store.find_index("doc/1")], new)
+        assert lane.journal_fallbacks == 1       # the upload alone
+        _assert_lane_is_the_store(store, lane)
+
+    def test_audit_finds_what_the_journal_dropped(self, store):
+        """The audit is the one thing that can tell a missed record
+        from a right answer: drop a row from the journal's answer and
+        the next audit finds it, stages it and counts it."""
+        dim = store.vec_dim
+        _fill(store, 6, dim)
+        row = store.find_index("doc/3")
+
+        class LosesARecord:
+            def __init__(self, st):
+                self._st = st
+
+            def __getattr__(self, name):
+                return getattr(self._st, name)
+
+            def changed_since(self, cursor):
+                rows, cur, complete = self._st.changed_since(cursor)
+                return rows[rows != row], cur, complete
+
+        lane = StagedLane(LosesARecord(store))
+        lane.refresh()
+        new = np.full(dim, 6.0, np.float32)
+        store.vec_set("doc/3", new)
+        store.vec_set("doc/5", new)
+        arr = np.asarray(lane.refresh())
+        assert not (arr[row] == new).all()       # the stale device row
+        scanned = lane.lane_slots_scanned
+        assert lane.audit() == 1
+        assert lane.lane_audit_rows == 1
+        assert lane.lane_slots_scanned == scanned     # a drain's, not its
+        np.testing.assert_array_equal(np.asarray(lane.array)[row], new)
+        assert lane.audit() == 0 and lane.lane_audit_rows == 1
+
+    def test_64_dirty_rows_of_100k_slots(self):
+        """What the journal buys: a refresh after 64 writes on a
+        100,000-slot store looks at 64 epochs, not 100,000, and the
+        audit finds nothing it missed."""
+        from libsplinter_tpu import Store
+
+        name = f"/spt-j100k-{os.getpid()}-{uuid.uuid4().hex[:8]}"
+        st = Store.create(name, nslots=100_000, max_val=64, vec_dim=8)
+        try:
+            rng = np.random.default_rng(3)
+            for i in range(256):
+                st.set(f"d/{i}", "x")
+                st.vec_set(f"d/{i}", rng.normal(size=8).astype(np.float32))
+            lane = StagedLane(st)
+            lane.refresh()
+            base = lane.lane_slots_scanned
+            for i in range(64):
+                st.vec_set(f"d/{i}", rng.normal(size=8).astype(np.float32))
+            lane.refresh()
+            assert lane.rows_staged == 64
+            assert lane.lane_slots_scanned - base == 64 < 1000
+            assert lane.journal_rows == 64
+            assert lane.audit() == 0
+            assert lane.lane_slots_scanned - base == 64
+            np.testing.assert_array_equal(np.asarray(lane.array),
+                                          np.array(st.vectors))
+        finally:
+            st.close()
+            Store.unlink(name)
+
+    def test_take_examined_names_the_rows_to_patch(self, store):
+        _fill(store, 6, store.vec_dim)
+        lane = StagedLane(store)
+        lane.refresh()
+        assert lane.take_examined() is None      # an upload: every row
+        assert lane.take_examined().size == 0
+        store.vec_set("doc/1", np.ones(store.vec_dim, np.float32))
+        store.unset("doc/2")
+        i1 = store.find_index("doc/1")
+        lane.refresh()
+        got = lane.take_examined()
+        assert i1 in got and got.size == 2
+        assert lane.take_examined().size == 0
+        # nobody takes: the rows stay distinct, so it cannot outgrow
+        # the lane
+        for _ in range(5):
+            store.stamp("doc/1")
+            store.stamp("doc/3")
+            lane.refresh()
+        assert lane.take_examined().size == 2

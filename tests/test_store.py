@@ -248,7 +248,7 @@ def test_global_epoch_advances(store):
 def test_header_snapshot(store):
     h = store.header()
     assert h.magic == 0x53505455
-    assert h.version == 1
+    assert h.version == 2
     assert h.nslots == 256
     assert h.vec_dim == 32
     assert h.mop_mode == sp.MOP_HYBRID  # default for new stores

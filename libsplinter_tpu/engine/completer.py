@@ -146,6 +146,12 @@ class CompleterStats:
     prompt_tokens: int = 0
     prefix_tokens: int = 0
     expert_slots: int = 0
+    # -- models with per-row recurrent state (models/kda.py): joins
+    # that resumed from a snapshot, snapshots a join left, and prefix
+    # tokens a hit gave up because no snapshot sat at their end
+    state_restores: int = 0
+    state_snapshots: int = 0
+    state_cut_tokens: int = 0
 
 
 class Completer:
@@ -182,6 +188,7 @@ class Completer:
                  tenant_weights: dict[int, float] | None = None,
                  prefix_cache: bool = True,
                  prefix_cache_pages: int | None = None,
+                 state_snapshots: int | None = None,
                  prefix_quotas: dict[int, int] | None = None,
                  prefix_default_quota: int | None = None,
                  kv_tier_pages: int = 0,
@@ -273,6 +280,9 @@ class Completer:
         # then count only the uncached suffix of each admission.
         self._prefix_enabled = bool(prefix_cache)
         self._prefix_cache_pages = prefix_cache_pages
+        # snapshot slots of a model with recurrent state (None: one a
+        # batch row); PagedKVCache owns them, the prefix tree their use
+        self._state_snapshots = state_snapshots
         self._prefix_quotas = dict(prefix_quotas or {})
         self._prefix_default_quota = prefix_default_quota
         self.prefix_cache = None
@@ -1032,9 +1042,11 @@ class Completer:
 
     def _ensure_paged_cache(self):
         if self._paged_cache is None:
+            kw = ({"state_snapshots": self._state_snapshots}
+                  if getattr(self._model, "needs_state", False) else {})
             self._paged_cache = self._model.init_paged(
                 self.paged_batch_cap, page=self.page_size,
-                pool_pages=self.pool_pages, kv_dtype=self.kv_dtype)
+                pool_pages=self.pool_pages, kv_dtype=self.kv_dtype, **kw)
             cache = self._paged_cache
             if self._prefix_enabled and hasattr(cache, "map_shared"):
                 # (re)bind the radix tree to THIS pool: a rebuilt
@@ -1272,6 +1284,9 @@ class Completer:
             n = 0
             traced = tracer.enabled
             pc = getattr(cache, "prefix_cache", None)
+            # a model with per-row recurrent state (models/kda.py):
+            # a hit resumes from a snapshot, a join leaves one
+            stateful = bool(getattr(cache, "needs_state", False))
             for idx in self._admit_waiting(plannable, len(free)):
                 if not free:
                     break
@@ -1304,7 +1319,12 @@ class Completer:
                     # write) instead of a re-prefill, and the pool
                     # pages they land in come out of the same `need`
                     # budget the uncached suffix would have used
-                    hit_bids, match, tier_nodes = pc.lookup_tiered(ids)
+                    # a model with state resumes STRICTLY below its last
+                    # token and only where a snapshot sits: replaying
+                    # the last token, as a fully cached prompt does
+                    # below, would apply it to the state twice
+                    hit_bids, match, tier_nodes = pc.lookup_tiered(
+                        ids, len(ids) - 1 if stateful else None)
                     if (match + len(tier_nodes) * cache.page
                             == len(ids) and len(ids) < 2):
                         # a fully-covered 1-token prompt would enter
@@ -1312,6 +1332,14 @@ class Completer:
                         # it as a miss (page size 1 is a test-only
                         # geometry anyway)
                         hit_bids, match, tier_nodes = [], 0, []
+                cut = pc.last_cut if stateful and pc is not None else 0
+                # the snapshot this join will leave: the state after
+                # the prompt's last full page, if the hit ends short
+                # of it and the pool keeps snapshots at all
+                snap_at = (len(ids) // cache.page) * cache.page
+                wants_snap = (stateful and pc is not None
+                              and cache.state_snapshots > 0
+                              and snap_at > match)
                 match_all = match + len(tier_nodes) * cache.page
                 full_cover = ((bool(hit_bids) or bool(tier_nodes))
                               and match_all == len(ids))
@@ -1331,7 +1359,9 @@ class Completer:
                     # whose ensure() then comes up short
                     pinned = sum(1 for b in hit_bids
                                  if cache.refcounts[b] == 0)
-                    if need > cache.available_pages - pinned:
+                    if need > cache.available_pages - pinned or (
+                            wants_snap
+                            and not cache.state_slot_available()):
                         self.stats.join_backpressure += 1
                         bp_memo[idx] = (e, need + pinned)
                         self._bound_bp_memo()
@@ -1398,6 +1428,19 @@ class Completer:
                     else:
                         cache.lengths[r] = (len(ids) - 1 if full_cover
                                             else match)
+                        if stateful:
+                            src = pc.state_slot(ids, match)
+                            if src < 0:
+                                raise RuntimeError(
+                                    "a hit ends at a node without a "
+                                    "state snapshot")
+                            t_s = time.perf_counter()
+                            with tracer.annotation("infer.state_restore"):
+                                m.state_restore(cache, src, r)
+                            self.stats.state_restores += 1
+                            if traced:
+                                span(rows[r], "state_restore",
+                                     (time.perf_counter() - t_s) * 1e3)
                         # hit/LRU recorded only now — a denied or
                         # raced admission must not inflate the hit
                         # rate the runbook triages on
@@ -1415,6 +1458,7 @@ class Completer:
                 suffix = ids[match:]
                 self.stats.prompt_tokens += len(ids)
                 self.stats.prefix_tokens += match
+                self.stats.state_cut_tokens += cut
                 if not cache.ensure(r, reserve):
                     # defensive: the pinned-aware gate above makes
                     # this unreachable, but a seated row WITHOUT its
@@ -1439,22 +1483,44 @@ class Completer:
                     # pages (tests/chaos_child.py completer_quant)
                     fault("completer.kv_quant_commit")
                 if suffix:
+                    snap = None
+                    if wants_snap:
+                        # a slot for the snapshot BEFORE the prefill
+                        # that fills it (the tree may give up another
+                        # snapshot for it; the restore above is
+                        # already dispatched)
+                        t_s = time.perf_counter()
+                        with tracer.annotation("infer.state_snapshot"):
+                            slot = cache.alloc_state_slot()
+                        if slot is not None:
+                            snap = (slot, snap_at)
+                        if traced:
+                            span(rows[r], "state_snapshot",
+                                 (time.perf_counter() - t_s) * 1e3)
+                    skw = ({"snap_at": snap[1], "snap_slot": snap[0]}
+                           if snap else {})
                     ta = time.perf_counter()
                     if hit_bids:
                         # uncached tail only, attending the mapped
                         # prefix through the ragged paged kernel
                         logits = m.paged_append_prefill(
-                            cache, np.asarray(suffix, np.int32), r)
+                            cache, np.asarray(suffix, np.int32), r,
+                            **skw)
                     else:
                         logits = m.paged_prefill_row(
-                            cache, np.asarray(ids, np.int32), r)
+                            cache, np.asarray(ids, np.int32), r, **skw)
                     tb = time.perf_counter()
                     if pc is not None:
                         # freshly committed full prompt pages join
                         # the tree NOW, donor still live — the next
                         # identical admission maps them even while
-                        # this row decodes
-                        ins = pc.insert(ids, cache, r, tenant)
+                        # this row decodes; the node the snapshot
+                        # belongs to takes its slot over
+                        ins = pc.insert(ids, cache, r, tenant,
+                                        **({"state": snap} if snap
+                                           else {}))
+                        if snap and pc.holds_snapshot(snap[0]):
+                            self.stats.state_snapshots += 1
                         if ins and tenant:
                             self.tenants.bump(
                                 tenant, "prefix_cached_pages", ins)
@@ -2077,6 +2143,19 @@ class Completer:
             if self._paged_cache.used_pages > self._pages_used_peak:
                 self._pages_used_peak = self._paged_cache.used_pages
             payload["pages_used_peak"] = self._pages_used_peak
+        if getattr(self._paged_cache, "needs_state", False):
+            # state slots (live rows + snapshots) of a model with
+            # recurrent state, beside the page gauges above
+            payload["state_slots_used"] = \
+                self._paged_cache.state_slots_used
+            payload["state_slots"] = self._paged_cache.state_slots - 1
+            payload["state_evictions"] = (
+                self.prefix_cache.stats.state_evictions
+                if self.prefix_cache is not None else 0)
+        else:
+            for k in ("state_restores", "state_snapshots",
+                      "state_cut_tokens"):
+                payload.pop(k, None)  # no state: dead gauges
         pc = self.prefix_cache
         if pc is not None:
             # prefix-cache gauges (sptpu_completer_prefix_* in `spt
@@ -2457,6 +2536,14 @@ def main(argv: list[str] | None = None) -> int:
                          "may retain (default: unlimited — zero-ref "
                          "cached pages are reclaimed LRU-first "
                          "whenever the pool actually needs them)")
+    ap.add_argument("--state-snapshots", type=int, default=None,
+                    help="snapshot slots of a --model whose layers keep "
+                         "recurrent state (models/kda.py): how many "
+                         "page-boundary states the prefix cache may "
+                         "hold for later prompts to resume from, one "
+                         "row's state each, in device memory beside "
+                         "the pages (default: one a batch row; 0: "
+                         "every prompt prefills from its first token)")
     ap.add_argument("--prefix-quota", default=None,
                     help="per-tenant prefix-cache page quotas, "
                          "TENANT:PAGES[,TENANT:PAGES...] (unlisted "
@@ -2495,9 +2582,24 @@ def main(argv: list[str] | None = None) -> int:
 
     logging.basicConfig(level=logging.INFO)
     if args.model:
-        from ..models.mla import (LatentCompletionModel,
+        # the description says which block family it is; what that
+        # family's model cannot serve is refused before anything else
+        from ..models.mla import (completion_model_class,
                                   load_model_description)
-        refuse_for_model(args, LatentCompletionModel)
+        model_cfg, model_seed = load_model_description(
+            args.model, max_len=args.n_ctx)
+        model_cls = completion_model_class(model_cfg)
+        refuse_for_model(args, model_cls)
+        if args.state_snapshots is not None \
+                and not getattr(model_cls, "needs_state", False):
+            raise SystemExit(
+                "unsupported_option: --state-snapshots cannot be "
+                f"served with --model ({model_cls.__name__}): its "
+                "layers keep no recurrent state")
+    elif args.state_snapshots is not None:
+        raise SystemExit(
+            "unsupported_option: --state-snapshots is for a --model "
+            "whose layers keep recurrent state")
     # one-shot start-up phases, ms -> the heartbeat's `startup_ms`
     from .searcher import _Lap, _process_age_ms
     boot: dict[str, float] = {}
@@ -2569,8 +2671,7 @@ def main(argv: list[str] | None = None) -> int:
         fault("completer.weight_quant")
         cfg = dataclasses.replace(cfg, weights_int8=True)
     if args.model:
-        cfg, seed = load_model_description(args.model,
-                                           max_len=args.n_ctx)
+        cfg, seed = model_cfg, model_seed
         log.info("model description %s: %d layers (%d dense), experts "
                  "%d..+%d of %d, vocabulary %d..+%d, window %d",
                  args.model, cfg.layers, cfg.dense_layers,
@@ -2585,8 +2686,8 @@ def main(argv: list[str] | None = None) -> int:
     mkw = dict(weights=args.weights, top_p=args.top_p, temp=args.temp)
     from ..models import MoeDecoderConfig, moe_completion_model
     if args.model:
-        model = LatentCompletionModel(cfg, seed=seed, top_p=args.top_p,
-                                      temp=args.temp)
+        model = model_cls(cfg, seed=seed, top_p=args.top_p,
+                          temp=args.temp)
         jax.block_until_ready(model.params)
         log.info("resident weights: %.2f GB",
                  model.resident_bytes() / 1e9)
@@ -2653,6 +2754,7 @@ def main(argv: list[str] | None = None) -> int:
                          args.tenant_weights),
                      prefix_cache=not args.no_prefix_cache,
                      prefix_cache_pages=args.prefix_cache_pages,
+                     state_snapshots=args.state_snapshots,
                      prefix_quotas=parse_tenant_quotas(
                          args.prefix_quota),
                      kv_tier_pages=args.kv_tier_pages,

@@ -26,6 +26,29 @@ sharing machinery is host-side:
     never rescale, which *removes* the stale-scale hazard
     quantize-on-commit pools otherwise carry.
 
+  - **State snapshots** (models with per-row recurrent state,
+    models/kda.py): pages alone cannot resume such a model — a hit is
+    worth nothing without the recurrent state as it stood at the
+    hit's last token.  A node may therefore own one of the pool's
+    STATE SLOTS (PagedKVCache: `state`), holding the state after the
+    node's last token.  When the bound pool says its model needs
+    state, lookup()/lookup_tiered() return the longest match that
+    ENDS at a node with a snapshot (deeper pages are not mapped;
+    `last_cut` says how many tokens that gave up), insert() attaches
+    the snapshot the prefill took at the prompt's last full page, and
+    evicting a node frees its slot.  One snapshot weighs tens of
+    pages (21.7 MB against 0.44 MB at Kimi-Linear's widths), so a
+    prompt leaves ONE, at its last full page, and the budget is the
+    pool's slot count.  When the pool needs a slot and none is free,
+    evict_snapshot() gives up first a snapshot that is SUPERSEDED —
+    the nodes below it form a chain without a branch that reaches a
+    deeper snapshot, which serves every prompt the shallower one
+    would (a growing session leaves such a chain a turn) — and among
+    those, or failing any, the least recently restored.  A snapshot
+    where paths branch (a shared document under many questions) is
+    what LRU alone would also keep.  For a model without state none
+    of this runs.
+
 Lifecycle: pages enter the tree at admission (after the committing
 row's prefill), while the donor row is still live — a mid-flight
 joiner may map a prefix another row is actively decoding from (the
@@ -68,11 +91,12 @@ class PrefixCacheStats:
     cow_copies: int = 0       # copy-on-write page copies
     quota_rejects: int = 0    # inserts skipped: tenant over quota
     bytes_saved: int = 0      # KV bytes not re-prefilled/committed
+    state_evictions: int = 0  # snapshots given up for their slot
 
 
 class _Node:
     __slots__ = ("toks", "bid", "parent", "children", "lru", "tenant",
-                 "tier")
+                 "tier", "state", "state_lru")
 
     def __init__(self, toks: tuple, bid: int, parent, tenant: int):
         self.toks = toks            # this page's token ids (exact)
@@ -88,6 +112,10 @@ class _Node:
         # root->leaf path the tier-1 nodes are a contiguous SUFFIX —
         # the invariant lookup_tiered and readmit ride.
         self.tier = 0
+        # the pool's state slot holding the recurrent state after this
+        # page's last token (-1: none), and when it was last restored
+        self.state = -1
+        self.state_lru = 0
 
 
 class PrefixCache:
@@ -126,6 +154,31 @@ class PrefixCache:
         self._export_page = None      # (bid) -> (bytes, bytes|None)
         self._import_page = None      # (bid, bytes, bytes|None)
         self._demoted = 0             # tier-1 node count (gauge)
+        # nodes that own a state snapshot, and how many tokens the
+        # last lookup gave up for want of one (module docstring)
+        self._snapshots: dict[int, _Node] = {}    # state slot -> node
+        self.last_cut = 0
+        # the page keys of the prompt being admitted: an admission
+        # walks the tree up to four times over the same ids (lookup,
+        # commit_hit, state_slot, insert), and at ~80 pages of 128
+        # tokens each walk's tuples cost more than the walk
+        self._key_memo: tuple | None = None
+
+    def _keys(self, ids) -> list[tuple]:
+        """The token-id tuple of every full page of `ids`, built once
+        for as long as the caller keeps handing in the same object."""
+        memo = self._key_memo
+        if memo is None or memo[0] is not ids or memo[1] != len(ids):
+            page = self.page
+            flat = [int(t) for t in ids]
+            memo = self._key_memo = (ids, len(ids), [
+                tuple(flat[j * page:(j + 1) * page])
+                for j in range(len(flat) // page)])
+        return memo[2]
+
+    @property
+    def needs_state(self) -> bool:
+        return bool(getattr(self._cache, "needs_state", False))
 
     # -- binding -----------------------------------------------------------
 
@@ -142,6 +195,8 @@ class PrefixCache:
         self._tenant_pages = {}
         self._zero_ref = 0
         self._demoted = 0
+        self._snapshots = {}
+        self.last_cut = 0
         if self.tier is not None:
             self.tier.clear()
 
@@ -158,8 +213,10 @@ class PrefixCache:
 
     # -- lookup / mapping ---------------------------------------------------
 
-    def lookup(self, ids) -> tuple[list[int], int]:
-        """Walk the tree over `ids` at page granularity.  Returns
+    def lookup(self, ids, upto: int | None = None
+               ) -> tuple[list[int], int]:
+        """Walk the tree over `ids` at page granularity (over its
+        first `upto` tokens, where given).  Returns
         (matched block ids in prefix order, matched token count).
         PURE: no stats, no LRU touch — a lookup whose admission is
         then denied (backpressure, raced slot) must neither inflate
@@ -167,19 +224,47 @@ class PrefixCache:
         for a prefix that never got served.  The admitting caller
         records the outcome via commit_hit() / note_miss()."""
         page = self.page
-        n_full = len(ids) // page
         bids: list[int] = []
+        states: list[int] = []
         cur = self._children
-        for j in range(n_full):
-            chunk = tuple(int(t) for t in ids[j * page:(j + 1) * page])
+        for chunk in self._keys(ids)[:(len(ids) if upto is None
+                                       else upto) // page]:
             node = cur.get(chunk)
             if node is None:
                 break
             bids.append(node.bid)
+            states.append(node.state)
             cur = node.children
+        bids = bids[:self._state_cut(states)]
         return bids, len(bids) * page
 
-    def lookup_tiered(self, ids
+    def _state_cut(self, states: list[int]) -> int:
+        """How many of a match's HBM nodes a model with state can use:
+        up to the deepest one that owns a snapshot (all of them for a
+        model without state).  Leaves the tokens given up in
+        `last_cut` — the one thing a lookup writes."""
+        keep = len(states)
+        if self.needs_state:
+            while keep and states[keep - 1] < 0:
+                keep -= 1
+        self.last_cut = (len(states) - keep) * self.page
+        return keep
+
+    def state_slot(self, ids, match: int) -> int:
+        """The state slot of the node a `match`-token hit ends at
+        (-1: none), its restore clock touched."""
+        node, cur = None, self._children
+        for chunk in self._keys(ids)[:match // self.page]:
+            node = cur.get(chunk)
+            if node is None:
+                return -1
+            cur = node.children
+        if node is None or node.state < 0:
+            return -1
+        node.state_lru = next(self._clock)
+        return node.state
+
+    def lookup_tiered(self, ids, upto: int | None = None
                       ) -> tuple[list[int], int, list["_Node"]]:
         """lookup() extended through the DRAM tier: returns
         (hbm_bids, hbm_match_tokens, tier_nodes) where tier_nodes are
@@ -190,13 +275,13 @@ class PrefixCache:
         re-prefill a miss would pay, and readmit() brings them back.
         PURE like lookup(): no stats, no LRU touch."""
         page = self.page
-        n_full = len(ids) // page
         bids: list[int] = []
+        states: list[int] = []
         nodes: list[_Node] = []
         cur = self._children
         tier = self.tier
-        for j in range(n_full):
-            chunk = tuple(int(t) for t in ids[j * page:(j + 1) * page])
+        for chunk in self._keys(ids)[:(len(ids) if upto is None
+                                       else upto) // page]:
             node = cur.get(chunk)
             if node is None:
                 break
@@ -208,19 +293,23 @@ class PrefixCache:
                 break                 # defensive: HBM past a demote
             else:
                 bids.append(node.bid)
+                states.append(node.state)
             cur = node.children
+        if self.needs_state:
+            # no state rides the host tier: demoted pages end the match
+            bids, nodes = bids[:self._state_cut(states)], []
+        else:
+            self.last_cut = 0
         return bids, len(bids) * page, nodes
 
     def commit_hit(self, ids, match: int) -> None:
         """An admission actually mapped `match` tokens of `ids`: count
         the hit and LRU-touch the served path (re-walk — match/page
         node hops, cheap next to the admission it accompanies)."""
-        page = self.page
         tick = next(self._clock)
         cur = self._children
-        for j in range(match // page):
-            node = cur.get(tuple(int(t)
-                                 for t in ids[j * page:(j + 1) * page]))
+        for chunk in self._keys(ids)[:match // self.page]:
+            node = cur.get(chunk)
             if node is None:
                 break                  # evicted mid-admission: stale
             node.lru = tick
@@ -233,24 +322,30 @@ class PrefixCache:
 
     # -- insertion ----------------------------------------------------------
 
-    def insert(self, ids, cache, row: int, tenant: int = 0) -> int:
+    def insert(self, ids, cache, row: int, tenant: int = 0,
+               state: tuple[int, int] | None = None) -> int:
         """Register the FULL prompt pages of `row` (its table entries
         for pages [0, len(ids)//page)) under their token prefix.
         Pages already present are skipped (the hit path mapped them;
         the row's own duplicates stay private).  Returns pages
         inserted.  A page enters FROZEN: the pool will copy-on-write
         before any append could touch it, and for int8 pools its
-        scale never rescales again."""
+        scale never rescales again.
+
+        `state`: (state slot, token count) — the snapshot the prefill
+        took after that many tokens (whole pages).  The node that
+        ends there takes the slot over; if the walk does not reach
+        it, or it has one already, the slot goes back to the pool."""
         if cache is not self._cache:
+            if state is not None:
+                cache.free_state_slot(state[0])
             return 0                  # stale pool: never adopt its ids
         page = self.page
-        n_full = len(ids) // page
         inserted = 0
         parent = None
         cur = self._children
         tick = next(self._clock)
-        for j in range(n_full):
-            chunk = tuple(int(t) for t in ids[j * page:(j + 1) * page])
+        for j, chunk in enumerate(self._keys(ids)):
             node = cur.get(chunk)
             if node is None:
                 bid = int(cache.tables[row, j])
@@ -292,7 +387,51 @@ class PrefixCache:
             node.lru = tick
             parent = node
             cur = node.children
+            if state is not None and (j + 1) * page == state[1] \
+                    and node.state < 0:
+                node.state, node.state_lru = state[0], tick
+                self._snapshots[state[0]] = node
+                state = None
+        if state is not None:
+            cache.free_state_slot(state[0])
         return inserted
+
+    # -- state snapshots ----------------------------------------------------
+
+    def snapshots_held(self) -> int:
+        return len(self._snapshots)
+
+    def holds_snapshot(self, slot: int) -> bool:
+        return slot in self._snapshots
+
+    def _drop_snapshot(self, node) -> None:
+        if node.state >= 0:
+            del self._snapshots[node.state]
+            self._cache.free_state_slot(node.state)
+            node.state = -1
+            self.stats.state_evictions += 1
+
+    @staticmethod
+    def _superseded(node) -> bool:
+        """A chain without a branch leads from `node` to a deeper
+        snapshot (module docstring)."""
+        while len(node.children) == 1:
+            node = next(iter(node.children.values()))
+            if node.state >= 0:
+                return True
+        return False
+
+    def evict_snapshot(self) -> bool:
+        """Give one snapshot's slot back to the pool: a superseded one
+        first, the least recently restored among equals.  The node
+        keeps its page."""
+        victim = min(self._snapshots.values(), default=None,
+                     key=lambda n: (not self._superseded(n),
+                                    n.state_lru))
+        if victim is None:
+            return False
+        self._drop_snapshot(victim)
+        return True
 
     def _spill(self, node) -> bool:
         """Take the host-DRAM shadow of a frozen page (fault site
@@ -409,6 +548,7 @@ class PrefixCache:
             # one more chance to demote instead of drop
             self._spill(victim)
         bid = victim.bid
+        self._drop_snapshot(victim)   # its page goes: so does its state
         if tier is not None and tier.has(victim):
             # DEMOTE: the HBM page returns to the pool, the node
             # survives DRAM-resident — a future hit readmits it with

@@ -195,7 +195,11 @@ INFER_STAGES = ("render", "generate", "commit")
 # handoff = the prefill lane's export + record write + DECODE_READY
 # flip, adopt = the decode lane's claim + page import + row seating.
 CONT_INFER_STAGES = ("join", "sample", "decode", "collect", "flush",
-                     "prefix_hit", "handoff", "adopt")
+                     "prefix_hit", "handoff", "adopt",
+                     # a model with per-row recurrent state: copying a
+                     # snapshot into the joining row, and finding the
+                     # slot its own snapshot goes to (leaf spans)
+                     "state_restore", "state_snapshot")
 
 # the search daemon's per-drain decomposition: wake = signal to drain
 # entry (the coalescing window's scheduling cost); drain = request

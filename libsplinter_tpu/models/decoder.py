@@ -246,9 +246,17 @@ class PageLayout:
     key and a value pool of (kv_heads, page, head_dim); a latent-
     attention model (models/mla.py) one pool of (page, latent width).
     Allocation, tables, refcounts, COW bookkeeping and the prefix
-    tree never look inside a page, so they serve any layout."""
+    tree never look inside a page, so they serve any layout.
+
+    `state`: what a ROW keeps in this layer whatever its length —
+    (name, shape, dtype) arrays, e.g. a gated delta-rule layer's
+    recurrent matrix and convolution tail (models/kda.py).  Such a
+    layer usually has no pools at all; the cache keeps its arrays in
+    STATE SLOTS (PagedKVCache).  A model whose layers differ hands
+    the cache one layout a layer."""
     pools: tuple[tuple[str, tuple[int, ...]], ...]
     token_values: int
+    state: tuple[tuple[str, tuple[int, ...], Any], ...] = ()
 
     @property
     def key_value(self) -> bool:
@@ -319,6 +327,20 @@ class PagedKVCache:
     buffers, which is exactly why packing changed only the value
     layout.
 
+    STATE SLOTS.  Where a layer's layout names per-row `state`
+    (PageLayout.state), the cache owns, for each such layer and array,
+    one buffer of (state_slots, *shape): slot b < batch is live row
+    b's, slots batch .. batch + state_snapshots - 1 are SNAPSHOTS —
+    the state as it stood at a page boundary, owned by a prefix-tree
+    node (engine/prefix_cache.py) so that a later prompt can resume
+    there — and the last slot is a spare that programs with nothing
+    to snapshot write to.  Snapshot slots are allocated and freed on
+    the host like pages (alloc_state_slot / free_state_slot; when
+    none is free the prefix tree gives up its least useful one), and
+    count in the same device budget: `state_slot_bytes` beside
+    `kv_bytes_per_token`.  A model without state has zero slots and
+    none of this runs.
+
     `sharding` (a NamedSharding, normally P(None, "tp", None, None)
     from ShardedCompletionModel) places the pools sharded on their
     KV-HEAD axis across a tensor-parallel mesh: each device holds
@@ -335,7 +357,8 @@ class PagedKVCache:
     def __init__(self, cfg: DecoderConfig, batch: int, *,
                  page: int = 128, pool_pages: int | None = None,
                  kv_dtype: str | None = None,
-                 sharding=None, scale_sharding=None):
+                 sharding=None, scale_sharding=None,
+                 state_snapshots: int | None = None):
         if page < 1:
             raise ValueError("page must be >= 1")
         self.cfg = cfg
@@ -380,15 +403,42 @@ class PagedKVCache:
             raise ValueError(
                 f"kv_dtype=\"int4\" packs two codes per byte along "
                 f"head_dim; head_dim={cfg.head_dim} must be even")
-        self.layout = (kv_page_layout(cfg, page, self.packed)
-                       if key_value else describe(page))
+        layouts = (kv_page_layout(cfg, page, self.packed)
+                   if key_value else describe(page))
+        if isinstance(layouts, PageLayout):
+            layouts = (layouts,) * cfg.layers
+        # a layout a layer; the layers that keep pages share ONE page
+        # layout (`layout`), the others keep state only
+        self.layouts = tuple(layouts)
+        paged = [lo for lo in self.layouts if lo.pools]
+        if len(self.layouts) != cfg.layers or not paged \
+                or any(lo.pools != paged[0].pools for lo in paged):
+            raise ValueError(
+                "the model must describe one layout a layer, and the "
+                "layers that keep pages the same pools")
+        self.layout = paged[0]
+        self.paged_layers = len(paged)
         # distinct buffers per layer/pool: the paged programs donate
         # the pools, and XLA rejects donating one buffer twice
         self.pools = []
         for _, block in self.layout.pools:
             zeros = _pool_zeros((self.n_blocks, *block), store_dtype,
                                 sharding)
-            self.pools.append([zeros() for _ in range(cfg.layers)])
+            self.pools.append([zeros() for _ in range(self.paged_layers)])
+        # state slots (class docstring): rows, snapshots, one spare
+        stateful = [lo for lo in self.layouts if lo.state]
+        self.needs_state = bool(stateful)
+        self.state_snapshots = (
+            (batch if state_snapshots is None else int(state_snapshots))
+            if stateful else 0)
+        if self.state_snapshots < 0:
+            raise ValueError("state_snapshots must be >= 0")
+        self.state_slots = (batch + self.state_snapshots + 1
+                            if stateful else 0)
+        self.states = [
+            [jnp.zeros((self.state_slots, *shape), dtype)
+             for _, shape, dtype in lo.state] for lo in stateful]
+        self._free_state = list(range(self.state_slots - 2, batch - 1, -1))
         if self.quantized:
             szeros = _pool_zeros((self.n_blocks, cfg.kv_heads),
                                  jnp.float32, scale_sharding)
@@ -529,12 +579,58 @@ class PagedKVCache:
         if pc is not None:
             pc.stats.cow_copies += 1
 
+    # -- state slots --------------------------------------------------------
+
+    @property
+    def state_spare(self) -> int:
+        """The slot a program writes to when it has nothing to keep."""
+        return self.state_slots - 1
+
+    @property
+    def state_slot_bytes(self) -> int:
+        """Device bytes of one state slot across every layer: what a
+        live row costs beside its pages, and as much a snapshot."""
+        return sum(a.nbytes // self.state_slots
+                   for layer in self.states for a in layer)
+
+    @property
+    def state_slots_used(self) -> int:
+        """Live rows + snapshots held (the heartbeat's gauge)."""
+        if not self.needs_state:
+            return 0
+        return int((self.lengths > 0).sum()) + self.state_snapshots \
+            - len(self._free_state)
+
+    def state_slot_available(self) -> bool:
+        """A snapshot slot is free, or the prefix tree can give one
+        up — what admission asks before it promises a snapshot."""
+        pc = self.prefix_cache
+        return bool(self._free_state) or (
+            pc is not None and pc.snapshots_held() > 0)
+
+    def alloc_state_slot(self) -> int | None:
+        """A snapshot slot, evicting the prefix tree's least useful
+        snapshot when none is free; None when there is none to give
+        (a budget of zero)."""
+        if not self._free_state:
+            pc = self.prefix_cache
+            if pc is None or not pc.evict_snapshot():
+                return None
+        return self._free_state.pop()
+
+    def free_state_slot(self, slot: int) -> None:
+        if not self.batch <= slot < self.state_spare \
+                or slot in self._free_state:
+            raise RuntimeError(f"bad or double-freed state slot {slot}")
+        self._free_state.append(slot)
+
     def kv_bytes_per_token(self) -> int:
         """Cache bytes one token occupies across every layer and pool
         of the layout — the factor behind the prefix cache's
         bytes_saved gauge.  int4-packed pools store half a byte per
-        value."""
-        values = self.cfg.layers * self.layout.token_values
+        value.  (A layer that keeps state costs a token nothing:
+        state_slot_bytes.)"""
+        values = self.paged_layers * self.layout.token_values
         if self.packed:
             return values // 2
         return values * np.dtype(self.pools[0][0].dtype).itemsize
@@ -592,6 +688,7 @@ class PagedKVCache:
         buffers; under tp each chip holds 1/tp — the per-shard view
         rides the completer's pages_shard section)."""
         arrs = [a for pool in self.pools for a in pool]
+        arrs += [a for layer in self.states for a in layer]
         if self.quantized:
             arrs += list(self.k_scales) + list(self.v_scales)
         total = 0
@@ -864,9 +961,16 @@ def _nucleus_logits(logits, top_p: float, temp: float):
     (_sample_graph) and the speculative verifier's explicit
     distribution (speculative._filtered_probs) — the acceptance rule
     is only distribution-exact while both read the SAME chain.
-    Returns (order, masked sorted logits)."""
-    order = jnp.argsort(-logits)
-    sorted_logits = logits[order] / temp
+    Returns (order, masked sorted logits).
+
+    One stable sort of (-logits, iota) that keeps its sorted keys: an
+    argsort is that sort with the keys thrown away, and gathering them
+    back (`logits[order]`) under vmap over (64, 20k) logits was 13 of
+    a 36 ms decode step on a v5e (PERF.md)."""
+    neg, order = jax.lax.sort_key_val(
+        -logits, jnp.arange(logits.shape[-1], dtype=jnp.int32),
+        is_stable=True)
+    sorted_logits = -neg / temp
     probs = jax.nn.softmax(sorted_logits)
     cum = jnp.cumsum(probs)
     keep = (cum - probs) < top_p          # always keeps the top token

@@ -86,18 +86,6 @@ from .encoder import _apply_rotary, _rotary_angles_at
 from ..ops.latent_attention import latent_append, latent_paged_attention
 from .moe import sparse_moe
 
-# the published keys a description may carry (a DeepSeek-V3-family
-# config.json without the keys that say nothing about the block)
-PUBLISHED_KEYS = frozenset((
-    "attention_bias", "first_k_dense_replace", "hidden_act",
-    "hidden_size", "intermediate_size", "kv_lora_rank",
-    "max_position_embeddings", "model_type", "moe_intermediate_size",
-    "n_routed_experts", "n_shared_experts", "norm_topk_prob",
-    "num_attention_heads", "num_experts_per_tok", "num_hidden_layers",
-    "num_key_value_heads", "num_nextn_predict_layers", "q_lora_rank",
-    "qk_nope_head_dim", "qk_rope_head_dim", "rms_norm_eps",
-    "rope_theta", "routed_scaling_factor", "sandwich_norm",
-    "scoring_func", "tie_word_embeddings", "v_head_dim", "vocab_size"))
 DESCRIPTION_KEYS = frozenset(("architecture", "share", "seed", "note"))
 SHARE_KEYS = frozenset(("layers", "dense_layers", "experts", "vocab"))
 
@@ -171,17 +159,168 @@ class LatentMoeConfig:
                           token_values=self.latent_width)
 
 
+_REQUIRED = object()
+
+
+@dataclasses.dataclass(frozen=True)
+class Key:
+    """How ONE published key of a family is honoured: the config
+    field it fills (None: read by the family's own lines, or checked
+    only), its default (absent: the key is required), the cast, and —
+    where only some values can be served — `only` with the refusal."""
+    field: str | None = None
+    default: Any = _REQUIRED
+    cast: Any = int
+    only: tuple | None = None
+    why: str = ""
+
+
+def _served(why: str, value=False, cast=bool):
+    """A key whose one served value is its default."""
+    return Key(None, value, cast, only=(value,), why=why)
+
+
+# Published key -> Key, a table a `model_type`.  Keys with field None
+# and no `only` are structural: depth, experts, vocabulary and window
+# are cut by the share, and the family's `finish` reads what is its
+# own (layer kinds, grouped heads).
+_COMMON = {
+    "model_type": Key(None, cast=str),
+    "hidden_act": _served("only the SwiGLU (silu) activation is served",
+                          "silu", str),
+    "attention_bias": _served("attention_bias is not served"),
+    "tie_word_embeddings": _served("tied embeddings are not served"),
+    "hidden_size": Key("hidden"),
+    "num_attention_heads": Key("heads"),
+    "num_key_value_heads": Key(None, None),
+    "kv_lora_rank": Key("kv_lora_rank"),
+    "qk_nope_head_dim": Key("qk_nope_head_dim"),
+    "qk_rope_head_dim": Key("qk_rope_head_dim"),
+    "v_head_dim": Key("v_head_dim"),
+    "intermediate_size": Key("dense_mlp_dim"),
+    "moe_intermediate_size": Key("moe_mlp_dim"),
+    "routed_scaling_factor": Key("routed_scaling_factor", 1.0, float),
+    "rms_norm_eps": Key("rms_eps", 1e-5, float),
+    "num_hidden_layers": Key(None),
+    "first_k_dense_replace": Key(None, 0),
+    "vocab_size": Key(None),
+    "num_nextn_predict_layers": Key(None, 0),
+}
+_SHARED_EXPERT = dict(default=0, only=(0, 1),
+                      why="0 or 1 shared expert is served")
+FAMILIES: dict[str, dict] = {
+    # the DeepSeek-V3 key set (openPangu-Ultra-MoE): models/mla.py
+    "pangu_ultra_moe": {
+        "window": "max_position_embeddings", "experts": "n_routed_experts",
+        "keys": {
+            **_COMMON,
+            "max_position_embeddings": Key(None, None),
+            "q_lora_rank": Key("q_lora_rank"),
+            "n_routed_experts": Key("n_routed_experts"),
+            "num_experts_per_tok": Key("top_k"),
+            "n_shared_experts": Key("n_shared_experts", **_SHARED_EXPERT),
+            "norm_topk_prob": Key("norm_topk_prob", True, bool),
+            "scoring_func": Key("score_fn", "sigmoid", str),
+            "sandwich_norm": Key("sandwich_norm", False, bool),
+            "rope_theta": Key("rope_base", 10000.0, float),
+        }},
+    # the Kimi-Linear key set: models/kda.py
+    "kimi_linear": {
+        "window": "model_max_length", "experts": "num_experts",
+        "keys": {
+            **_COMMON,
+            "model_max_length": Key(None, None),
+            "head_dim": Key(None, None),     # the config's, unused by the block
+            "q_lora_rank": Key(None, None, lambda v: v, only=(None,),
+                               why="this family's queries have no "
+                                   "low-rank step: q_lora_rank must be "
+                                   "null"),
+            "mla_use_nope": _served("the latent layers of this family "
+                                    "carry no positions: mla_use_nope "
+                                    "must be true", True),
+            "rope_theta": Key(None, None, float),    # nothing rotates
+            "rope_scaling": Key(None, None, lambda v: v, only=(None,),
+                                why="rope_scaling is not served"),
+            "linear_attn_config": Key(None, cast=dict),
+            "num_experts": Key("n_routed_experts"),
+            "num_experts_per_token": Key("top_k"),
+            "num_shared_experts": Key("n_shared_experts",
+                                      **_SHARED_EXPERT),
+            "moe_renormalize": Key("norm_topk_prob", True, bool),
+            "moe_router_activation_func": Key("score_fn", "sigmoid", str),
+            "moe_layer_freq": Key(None, 1, only=(1,),
+                                  why="every layer past the dense ones "
+                                      "is an expert layer: "
+                                      "moe_layer_freq must be 1"),
+            # grouped top-k over ONE group of which ONE is kept is
+            # plain top-k; anything else is another router
+            "num_expert_group": Key(None, 1, only=(1,),
+                                    why="group-limited routing is not "
+                                        "served: num_expert_group 1"),
+            "topk_group": Key(None, 1, only=(1,),
+                              why="group-limited routing is not "
+                                  "served: topk_group 1"),
+            "use_grouped_topk": Key(None, False, bool),
+        }},
+}
+LINEAR_ATTN_KEYS = frozenset(("full_attn_layers", "kda_layers", "head_dim",
+                              "num_heads", "short_conv_kernel_size"))
+
+
+def _no_grouped_heads(arch, heads: int) -> None:
+    if int(arch.get("num_key_value_heads") or heads) != heads:
+        raise ValueError("latent attention has no grouped kv heads: "
+                         "num_key_value_heads must equal "
+                         "num_attention_heads")
+
+
+def _finish_latent(arch, fields, path):
+    _no_grouped_heads(arch, fields["heads"])
+    return LatentMoeConfig(**fields)
+
+
+def _finish_hybrid(arch, fields, path):
+    from .kda import HybridMoeConfig
+    _no_grouped_heads(arch, fields["heads"])
+    lin = arch["linear_attn_config"]
+    extra = set(lin) - LINEAR_ATTN_KEYS
+    if extra or set(lin) != LINEAR_ATTN_KEYS:
+        raise ValueError(
+            f"{path}: linear_attn_config must hold exactly "
+            f"{sorted(LINEAR_ATTN_KEYS)} (unknown: {sorted(extra)})")
+    n_layers = int(arch["num_hidden_layers"])
+    kda, full = set(lin["kda_layers"]), set(lin["full_attn_layers"])
+    if kda & full or kda | full != set(range(1, n_layers + 1)):
+        raise ValueError(
+            f"{path}: kda_layers and full_attn_layers must split layers "
+            f"1..{n_layers} between them")
+    # the lists count layers from 1; the share keeps the first `layers`
+    kinds = tuple("kda" if i + 1 in kda else "mla"
+                  for i in range(fields.pop("layers")))
+    return HybridMoeConfig(
+        kinds=kinds, model_layers=n_layers,
+        kda_heads=int(lin["num_heads"]),
+        kda_head_dim=int(lin["head_dim"]),
+        conv_kernel=int(lin["short_conv_kernel_size"]), **fields)
+
+
+FAMILIES["pangu_ultra_moe"]["finish"] = _finish_latent
+FAMILIES["kimi_linear"]["finish"] = _finish_hybrid
+
+
 def load_model_description(path: str, *, max_len: int | None = None):
-    """A model description file -> (LatentMoeConfig, seed).
+    """A model description file -> (config, seed): a LatentMoeConfig
+    or a models/kda.HybridMoeConfig, by the architecture's
+    `model_type` (FAMILIES).
 
     {"architecture": {published keys verbatim, at their published
                       values},
      "share": {"layers": n, "dense_layers": n, "experts": [first,
                count], "vocab": [first, count]},      (default: whole)
      "seed": n}
-    An unknown key anywhere is an error, as is a published value this
-    block cannot honour (a bias, tied embeddings, another activation,
-    grouped kv heads)."""
+    An unknown key anywhere or an unknown model_type is an error, as is
+    a published value the block cannot honour (a bias, tied
+    embeddings, another activation, grouped kv heads)."""
     with open(path) as f:
         d = json.load(f)
     extra = set(d) - DESCRIPTION_KEYS
@@ -190,73 +329,63 @@ def load_model_description(path: str, *, max_len: int | None = None):
     arch = d.get("architecture")
     if not isinstance(arch, dict):
         raise ValueError(f"{path} has no 'architecture' section")
-    extra = set(arch) - PUBLISHED_KEYS
+    family = FAMILIES.get(arch.get("model_type"))
+    if family is None:
+        raise ValueError(
+            f"{path}: model_type {arch.get('model_type')!r} is not "
+            f"served (known: {sorted(FAMILIES)})")
+    keys = family["keys"]
+    extra = set(arch) - set(keys)
     if extra:
         raise ValueError(
             f"unknown architecture key(s) in {path}: {sorted(extra)} "
-            f"(known: {sorted(PUBLISHED_KEYS)})")
+            f"(known: {sorted(keys)})")
     share = d.get("share", {})
     extra = set(share) - SHARE_KEYS
     if extra:
         raise ValueError(f"unknown share key(s) in {path}: "
                          f"{sorted(extra)}")
-
-    def need(key):
-        if key not in arch:
-            raise ValueError(f"{path}: architecture lacks {key!r}")
-        return arch[key]
-
-    if arch.get("hidden_act", "silu") != "silu":
-        raise ValueError("only the SwiGLU (silu) activation is served")
-    if arch.get("attention_bias", False):
-        raise ValueError("attention_bias is not served")
-    if arch.get("tie_word_embeddings", False):
-        raise ValueError("tied embeddings are not served")
-    heads = int(need("num_attention_heads"))
-    if int(arch.get("num_key_value_heads", heads)) != heads:
-        raise ValueError("latent attention has no grouped kv heads: "
-                         "num_key_value_heads must equal "
-                         "num_attention_heads")
-    if int(arch.get("n_shared_experts", 0)) not in (0, 1):
-        raise ValueError("0 or 1 shared expert is served")
-    n_layers = int(need("num_hidden_layers"))
+    fields = {}
+    for name, key in keys.items():
+        if name not in arch:
+            if key.default is _REQUIRED:
+                raise ValueError(f"{path}: architecture lacks {name!r}")
+            value = key.default
+        else:
+            value = arch[name] if arch[name] is None else \
+                key.cast(arch[name])
+        if key.only is not None and value not in key.only:
+            raise ValueError(key.why)
+        if key.field is not None:
+            fields[key.field] = value
+    n_layers = int(arch["num_hidden_layers"])
     first_dense = int(arch.get("first_k_dense_replace", 0))
     layers = int(share.get("layers", n_layers))
     dense = int(share.get("dense_layers", min(first_dense, layers)))
-    n_exp = int(need("n_routed_experts"))
-    e_first, e_held = share.get("experts", [0, n_exp])
-    vocab = int(need("vocab_size"))
+    e_first, e_held = share.get("experts", [0, fields["n_routed_experts"]])
+    vocab = int(arch["vocab_size"])
     v_first, v_held = share.get("vocab", [0, vocab])
     if not 0 <= v_first <= v_first + v_held <= vocab:
         raise ValueError("the vocabulary slice lies outside vocab_size")
     if layers > n_layers or dense > first_dense:
         raise ValueError("the share keeps more layers than the model has")
-    window = int(max_len or arch.get("max_position_embeddings", 2048))
-    if window > int(arch.get("max_position_embeddings", window)):
-        raise ValueError("the window exceeds max_position_embeddings")
-    cfg = LatentMoeConfig(
-        vocab_size=int(v_held), vocab_first=int(v_first),
-        hidden=int(need("hidden_size")), layers=layers, heads=heads,
-        q_lora_rank=int(need("q_lora_rank")),
-        kv_lora_rank=int(need("kv_lora_rank")),
-        qk_nope_head_dim=int(need("qk_nope_head_dim")),
-        qk_rope_head_dim=int(need("qk_rope_head_dim")),
-        v_head_dim=int(need("v_head_dim")),
-        dense_layers=dense,
-        dense_mlp_dim=int(need("intermediate_size")),
-        moe_mlp_dim=int(need("moe_intermediate_size")),
-        n_routed_experts=n_exp, top_k=int(need("num_experts_per_tok")),
-        experts_first=int(e_first), experts_held=int(e_held),
-        n_shared_experts=int(arch.get("n_shared_experts", 0)),
-        norm_topk_prob=bool(arch.get("norm_topk_prob", True)),
-        routed_scaling_factor=float(
-            arch.get("routed_scaling_factor", 1.0)),
-        score_fn=str(arch.get("scoring_func", "sigmoid")),
-        sandwich_norm=bool(arch.get("sandwich_norm", False)),
-        rope_base=float(arch.get("rope_theta", 10000.0)),
-        rms_eps=float(arch.get("rms_norm_eps", 1e-5)),
-        max_len=window)
-    return cfg, int(d.get("seed", 0))
+    limit = arch.get(family["window"])
+    window = int(max_len or limit or 2048)
+    if limit is not None and window > int(limit):
+        raise ValueError(f"the window exceeds {family['window']}")
+    fields.update(
+        vocab_size=int(v_held), vocab_first=int(v_first), layers=layers,
+        dense_layers=dense, experts_first=int(e_first),
+        experts_held=int(e_held), max_len=window)
+    return family["finish"](arch, fields, path), int(d.get("seed", 0))
+
+
+def completion_model_class(cfg):
+    """The paged serving model of a loaded description's config."""
+    if isinstance(cfg, LatentMoeConfig):
+        return LatentCompletionModel
+    from .kda import HybridCompletionModel
+    return HybridCompletionModel
 
 
 # ------------------------------------------------------------- weights
@@ -279,6 +408,34 @@ def seed_tensor(seed: int, name: str, shape, std: float,
         zlib.crc32(name.encode()) & 0x7FFFFFFF)
     return _seeded(key, jnp.float32(mean), jnp.float32(std),
                    shape=tuple(int(s) for s in shape), dtype=dtype)
+
+
+def ffn_params(cfg, seed: int, p: str, dense: bool, mat, down=None):
+    """A layer's feed-forward tensors under the name prefix `p`: the
+    dense SwiGLU, or the router, the shared expert and the HELD routed
+    experts (names carry the expert's index in the WHOLE model).
+    mat(name, shape) makes a matrix; `down` the ones that write into
+    the residual stream, where a family scales them (models/kda.py)."""
+    H, down = cfg.hidden, down or mat
+    if dense:
+        I = cfg.dense_mlp_dim
+        return {"w_gate": mat(p + "w_gate", (H, I)),
+                "w_up": mat(p + "w_up", (H, I)),
+                "w_down": down(p + "w_down", (I, H))}
+    M = cfg.moe_mlp_dim
+    lp = {"router": seed_tensor(seed, p + "router",
+                                (H, cfg.n_routed_experts),
+                                1.0 / math.sqrt(H), jnp.float32)}
+    if cfg.n_shared_experts:
+        lp["shared_gate"] = mat(p + "shared.gate", (H, M))
+        lp["shared_up"] = mat(p + "shared.up", (H, M))
+        lp["shared_down"] = down(p + "shared.down", (M, H))
+    held = range(cfg.experts_first, cfg.experts_first + cfg.experts_held)
+    for part, shape, make in (("gate", (H, M), mat), ("up", (H, M), mat),
+                              ("down", (M, H), down)):
+        lp["exp_" + part] = jnp.stack([
+            make(f"{p}experts.{e}.{part}", shape) for e in held])
+    return lp
 
 
 def init_params(cfg: LatentMoeConfig, seed: int) -> dict:
@@ -312,26 +469,7 @@ def init_params(cfg: LatentMoeConfig, seed: int) -> dict:
         if cfg.sandwich_norm:
             lp["ln_attn_out"] = norm(p + "ln_attn_out", H)
             lp["ln_mlp_out"] = norm(p + "ln_mlp_out", H)
-        if i < cfg.dense_layers:
-            I = cfg.dense_mlp_dim
-            lp["w_gate"] = mat(p + "w_gate", (H, I))
-            lp["w_up"] = mat(p + "w_up", (H, I))
-            lp["w_down"] = mat(p + "w_down", (I, H))
-        else:
-            M = cfg.moe_mlp_dim
-            lp["router"] = seed_tensor(
-                seed, p + "router", (H, cfg.n_routed_experts),
-                1.0 / math.sqrt(H), jnp.float32)
-            if cfg.n_shared_experts:
-                lp["shared_gate"] = mat(p + "shared.gate", (H, M))
-                lp["shared_up"] = mat(p + "shared.up", (H, M))
-                lp["shared_down"] = mat(p + "shared.down", (M, H))
-            held = range(cfg.experts_first,
-                         cfg.experts_first + cfg.experts_held)
-            for part, shape in (("gate", (H, M)), ("up", (H, M)),
-                                ("down", (M, H))):
-                lp["exp_" + part] = jnp.stack([
-                    mat(f"{p}experts.{e}.{part}", shape) for e in held])
+        lp.update(ffn_params(cfg, seed, p, i < cfg.dense_layers, mat))
         layers.append(lp)
     return {
         "tok_emb": seed_tensor(seed, f"tok_emb.{cfg.vocab_first}",
@@ -582,6 +720,9 @@ class LatentCompletionModel:
 
     paged_supported = True
     audit_supported = True
+    # what a device trace shows the programs as: jit_<prefix>_<short>
+    # (benchmark/readers count on it)
+    program_prefix = "latent"
     # what completer.main refuses for a model of this class, and why
     refused_options = {
         "kv_dtype": "latent pages are stored in the model's dtype: "
@@ -660,12 +801,11 @@ class LatentCompletionModel:
 
     def _program(self, key: tuple, short: str, build, donate=(1,)):
         """The jitted, devtime-registered program under `key`; build()
-        makes its function, named latent_<short> — what a device trace
-        shows as jit_latent_<short> (benchmark/readers count on it)."""
+        makes its function, named <program_prefix>_<short>."""
         fn = self._paged_progs.get(key)
         if fn is None:
             run = build()
-            run.__name__ = f"latent_{short}"
+            run.__name__ = f"{self.program_prefix}_{short}"
             fn = DEVTIME.register(
                 self._devname(short),
                 jax.jit(run, donate_argnums=donate))
@@ -829,12 +969,10 @@ class LatentCompletionModel:
         return self._program(("chunk", n, bp, top_p, temp),
                              "paged_chunk", build)
 
-    def paged_decode_chunk_async(self, cache: PagedKVCache, tokens,
-                                 n: int, carry=None
-                                 ) -> LatentPendingChunk:
-        """CompletionModel.paged_decode_chunk_async's contract, over
-        latent pages; the chunk also carries its expert-slot counts
-        and the audited row's logits."""
+    def _chunk_inputs(self, cache: PagedKVCache, tokens, n: int, carry):
+        """The host half of a chunk's dispatch: every live row's pages
+        for n more tokens, the copy-on-write pass, and (fresh_mask,
+        host-fed tokens, device carry)."""
         bp = cache.batch
         for r in range(bp):
             length = int(cache.lengths[r])
@@ -855,6 +993,22 @@ class LatentCompletionModel:
         else:
             fresh_mask = toks >= 0
         toks = np.maximum(toks, 0)
+        return fresh_mask, toks, carry
+
+    def _advance(self, cache: PagedKVCache, n: int) -> None:
+        live = cache.lengths > 0
+        cache.lengths[live] = np.minimum(cache.lengths[live] + n,
+                                         self.cfg.max_len)
+
+    def paged_decode_chunk_async(self, cache: PagedKVCache, tokens,
+                                 n: int, carry=None
+                                 ) -> LatentPendingChunk:
+        """CompletionModel.paged_decode_chunk_async's contract, over
+        latent pages; the chunk also carries its expert-slot counts
+        and the audited row's logits."""
+        bp = cache.batch
+        fresh_mask, toks, carry = self._chunk_inputs(cache, tokens, n,
+                                                     carry)
         self._rng, sub = jax.random.split(self._rng)
         pools, out, last, slots, kept = self._chunk_program(n, bp)(
             self.params, cache.pools[0],
@@ -862,9 +1016,7 @@ class LatentCompletionModel:
             jnp.asarray(np.array(cache.lengths)), sub, jnp.asarray(toks),
             jnp.asarray(fresh_mask), carry, jnp.int32(self.audit_row))
         cache.pools[0] = list(pools)
-        live = cache.lengths > 0
-        cache.lengths[live] = np.minimum(cache.lengths[live] + n,
-                                         self.cfg.max_len)
+        self._advance(cache, n)
         return LatentPendingChunk(
             out, last, n, DEVTIME.take_mark(self._devname("paged_chunk")),
             slots, kept)
@@ -905,8 +1057,11 @@ class LatentCompletionModel:
                 self.paged_append_prefill(
                     cache, np.ones((sb,), np.int32), 0)
                 cache.free_row(0)
-            src, dst = cache._alloc_page(), cache._alloc_page()
-            cache.pools[0] = list(self._cow_program()(
-                cache.pools[0], jnp.int32(src), jnp.int32(dst)))
-            cache._decref(src)
-            cache._decref(dst)
+            self._warm_cow(cache)
+
+    def _warm_cow(self, cache: PagedKVCache) -> None:
+        src, dst = cache._alloc_page(), cache._alloc_page()
+        cache.pools[0] = list(self._cow_program()(
+            cache.pools[0], jnp.int32(src), jnp.int32(dst)))
+        cache._decref(src)
+        cache._decref(dst)

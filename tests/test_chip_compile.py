@@ -319,3 +319,105 @@ def test_latent_decode_chunk_program(one_chip, monkeypatch):
     assert mem.argument_size_in_bytes > 11.5e9          # weights + pool
     assert mem.temp_size_in_bytes < 1.5e9               # no pool copies
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15e9
+
+
+# ---- the hybrid stack (KDA state slots + NoPE latent pages) at
+# published widths (benchmark/configs/kimi-linear-48b-a3b-ep8-stage0.json:
+# 32 KDA heads of 128, 64 rows + 96 snapshots + the spare = 161 state
+# slots, 8,192 latent pages of 128 tokens, a 128-page window, 32 held
+# experts of 2,304 x 1,024, layers 1-13)
+
+KDA_H, KDA_D, KDA_SLOTS = 32, 128, 161
+
+
+def test_kda_decode_step_kernel(one_chip):
+    """One token a row over the rows' state slots: the 64 KiB state of
+    a head goes through VMEM once, in place (no copy of the 338 MB
+    slot array), the b v column is made by an in-kernel transpose."""
+    from libsplinter_tpu.ops.delta_attention import _decode_pallas
+    row = _spec(one_chip, (64, KDA_H, KDA_D), jnp.float32)
+    compiled = jax.jit(
+        lambda q, k, bk, a, bv, s: _decode_pallas(q, k, bk, a, bv, s,
+                                                  interpret=False),
+        donate_argnums=(5,)).lower(
+        row, row, row, row, row,
+        _spec(one_chip, (KDA_SLOTS, KDA_H, KDA_D, KDA_D),
+              jnp.float32)).compile()
+    txt = compiled.as_text()
+    assert "tpu_custom_call" in txt
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1e6                 # in place
+
+
+@pytest.mark.parametrize("tokens", [128, 640])
+def test_kda_chunk_prefill_kernel(one_chip, tokens):
+    """The chunkwise delta rule over a suffix bucket: the pairwise
+    decays of a chunk (tokens x 32 x 128 a head) stay inside their
+    reduction — nothing of that size reaches HBM."""
+    from libsplinter_tpu.ops.delta_attention import kda_chunk_prefill
+    tok = _spec(one_chip, (tokens, KDA_H, KDA_D), jnp.float32)
+    compiled = _compile(
+        lambda q, k, v, g, b, s, n: kda_chunk_prefill(
+            q, k, v, g, b, s, scale=KDA_D ** -0.5, n_snap=n,
+            force_pallas=True),
+        tok, tok, tok, tok, _spec(one_chip, (tokens, KDA_H), jnp.float32),
+        _spec(one_chip, (KDA_H, KDA_D, KDA_D), jnp.float32),
+        _spec(one_chip, (), jnp.int32))
+    txt = compiled.as_text()
+    assert "tpu_custom_call" in txt
+    big = f"= f32[{tokens // 32},{KDA_H},32,32,{KDA_D}]"
+    assert not [ln for ln in txt.splitlines()
+                if big in ln and " fusion(" in ln]
+
+
+@pytest.mark.parametrize("program", ["chunk", "suffix-640"])
+def test_hybrid_programs_at_published_widths(one_chip, monkeypatch,
+                                             program):
+    """The 8-step decode chunk and the widest suffix prefill of the
+    benchmark's hybrid configuration (13 layers unrolled, 6.92 GB of
+    weights + 3.62 GB of pages + 3.49 GB of state slots): each
+    compiles, fits the chip beside its arguments, and keeps pools and
+    state slots in place."""
+    from libsplinter_tpu.models import kda
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    kinds = tuple("mla" if (i + 1) % 4 == 0 else "kda" for i in range(13))
+    cfg = kda.HybridMoeConfig(
+        vocab_size=20480, hidden=2304, kinds=kinds, heads=32,
+        kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+        v_head_dim=128, kda_heads=KDA_H, kda_head_dim=KDA_D,
+        conv_kernel=4, dense_layers=1, dense_mlp_dim=9216,
+        moe_mlp_dim=1024, n_routed_experts=256, top_k=8,
+        experts_first=0, experts_held=32, routed_scaling_factor=2.446,
+        max_len=16384)
+    params = jax.tree_util.tree_map(
+        lambda a: _spec(one_chip, a.shape, a.dtype),
+        jax.eval_shape(lambda: kda.init_params(cfg, 0)))
+    weights = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                  for a in jax.tree_util.tree_leaves(params))
+    assert 6.90e9 < weights < 6.93e9      # 3,450M parameters, bfloat16
+    m = kda.HybridCompletionModel(cfg, params=params)
+    pools = [_spec(one_chip, (8193, 576, 128), jnp.bfloat16)] * 3
+    states = [[_spec(one_chip, (KDA_SLOTS, KDA_H, KDA_D, KDA_D),
+                     jnp.float32),
+               _spec(one_chip, (KDA_SLOTS, 3, 3 * KDA_H * KDA_D),
+                     jnp.bfloat16)] for _ in range(10)]
+    i32 = _spec(one_chip, (), jnp.int32)
+    if program == "chunk":
+        fn = m._chunk_program(8, 64)
+        args = (_spec(one_chip, (64, 128), jnp.int32),
+                _spec(one_chip, (64,), jnp.int32),
+                _spec(one_chip, (2,), jnp.uint32),
+                _spec(one_chip, (64,), jnp.int32),
+                _spec(one_chip, (64,), jnp.bool_),
+                _spec(one_chip, (64,), jnp.int32), i32)
+    else:
+        fn = m._suffix_program(640)
+        args = (_spec(one_chip, (1, 128), jnp.int32),
+                _spec(one_chip, (1,), jnp.int32),
+                _spec(one_chip, (1, 640), jnp.int32), i32, i32, i32, i32)
+    compiled = getattr(fn, "__wrapped__", fn).lower(
+        params, pools, states, *args).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes > 14.0e9   # weights, pages, slots
+    assert mem.temp_size_in_bytes < 0.6e9        # no pool or slot copies
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15e9

@@ -101,7 +101,7 @@ def test_registry_extracts_live_protocol(splint):
         "drain", "tokenize", "dispatch", "device_wait", "commit")
     assert reg.stages["CONT_INFER_STAGES"] == (
         "join", "sample", "decode", "collect", "flush", "prefix_hit",
-        "handoff", "adopt")
+        "handoff", "adopt", "state_restore", "state_snapshot")
     assert reg.keys["KEY_SEARCH_STATS"] == "__searcher_stats"
     assert reg.prefixes["SEARCH_RESULT_PREFIX"] == "__sr_"
     assert reg.prefixes["DEADLINE_STAMP_PREFIX"] == "__dl_"
@@ -135,6 +135,7 @@ def test_live_tree_is_clean(runner):
                        "libsplinter_tpu/engine/embedder.py",
                        "libsplinter_tpu/models/decoder.py",
                        "libsplinter_tpu/models/mla.py",
+                       "libsplinter_tpu/ops/delta_attention.py",
                        "libsplinter_tpu/ops/flash_attention.py",
                        "libsplinter_tpu/ops/latent_attention.py",
                        "libsplinter_tpu/ops/paged_attention.py",

@@ -146,7 +146,8 @@ int spt__probe_claim(spt_store *st, const char *key, uint64_t h, int *existed);
 static inline uint64_t spt__journal_entry(uint64_t p, uint32_t idx) {
   return ((uint64_t)(uint32_t)(p / SPT_JOURNAL_CAP + 1) << 32) | idx;
 }
-/* Record that slot idx's epoch moves: claim a position, then fill it. */
+/* Record that slot idx's epoch moves, or that a label of it was raised:
+ * claim a position, then fill it. */
 static inline void spt__journal(spt_store *st, uint32_t idx) {
   uint64_t p = atomic_fetch_add_explicit(&st->h->journal_head.v, 1,
                                          memory_order_acq_rel);

@@ -28,17 +28,22 @@
  *   A writer that dies mid-write leaves an odd epoch; spt_retrain() is the
  *   sanctioned recovery (drives the epoch backward — "revalidate me").
  *
- * Change journal (format version 2): no slot's epoch moves without a
- *   record.  A ring of SPT_JOURNAL_CAP slot indices in the shared mapping
- *   with a 64-bit head that only grows; taking a slot's seqlock appends
- *   the slot WHILE IT IS ODD (so do spt_retrain's two stores), before the
- *   new even epoch is published.  Consumers clear nothing: each keeps its
- *   own cursor and asks spt_changed_since().  A record says "look at this
- *   slot", not what happened: a consumer compares the slot's epoch with
- *   the one it holds, finds nothing to do for a spurious record (an
- *   aborted lock journals too), and keeps a slot it saw odd for its next
- *   pass — the writer's record is already behind its cursor.  Label and
- *   flag operations move no epoch and are not journaled.
+ * Change journal (format version 2): no slot's epoch moves, and no label
+ *   is raised, without a record.  A ring of SPT_JOURNAL_CAP slot indices in
+ *   the shared mapping with a 64-bit head that only grows; taking a slot's
+ *   seqlock appends the slot WHILE IT IS ODD (so do spt_retrain's two
+ *   stores), before the new even epoch is published, and spt_label_or
+ *   appends the slot AFTER its bits are readable (and before the caller's
+ *   bump wakes anyone).  Consumers clear nothing: each keeps its own cursor
+ *   and asks spt_changed_since().  A record says "look at this slot", not
+ *   what happened: an epoch consumer compares the slot's epoch with the one
+ *   it holds, finds nothing to do for a spurious record (an aborted lock
+ *   and a label raise journal too), and keeps a slot it saw odd for its
+ *   next pass — the writer's record is already behind its cursor; a label
+ *   consumer reads the slot's labels and keeps the slot for as long as the
+ *   bits it follows are up.  Clearing a label (spt_label_andnot), the bits
+ *   a new key or an unset zeroes, and flag operations are not journaled:
+ *   whoever holds a row re-reads its labels.
  */
 #ifndef SPTPU_H
 #define SPTPU_H
@@ -200,6 +205,9 @@ int spt_tandem_unset(spt_store *st, const char *base, uint32_t max_order);
 int spt_tandem_count(spt_store *st, const char *base);
 
 /* ---- bloom labels ------------------------------------------------------ */
+/* spt_label_or raises the bits and then appends the slot to the change
+ * journal (one record a call, none for a missing key); spt_label_andnot
+ * appends nothing. */
 int      spt_label_or(spt_store *st, const char *key, uint64_t mask);
 int      spt_label_andnot(spt_store *st, const char *key, uint64_t mask);
 int      spt_get_labels(spt_store *st, const char *key, uint64_t *out);
@@ -331,8 +339,8 @@ int spt_vec_gather(spt_store *st, const uint32_t *rows, uint32_t n,
 
 /* ---- change journal ------------------------------------------------------ */
 /* Entries ever appended: the cursor of a consumer that starts now.  Take it
- * BEFORE the first epoch snapshot, so a write during the snapshot is found
- * afterwards. */
+ * BEFORE the first epoch snapshot or label enumeration, so a write or a
+ * raise during the walk is found afterwards. */
 uint64_t spt_journal_head(spt_store *st);
 /* The slot indices appended at positions [cursor, head), oldest first and
  * with repeats, at most max_out of them; *cursor_out = the position after
@@ -342,8 +350,8 @@ uint64_t spt_journal_head(spt_store *st);
  *   -EAGAIN     an entry in the range was claimed and not written within a
  *               short wait (its writer is descheduled, or dead);
  * with *cursor_out = the head as it was when the call began.  Either way
- * the range cannot be trusted: scan every slot (spt_epochs) AFTER this
- * call and go on from *cursor_out. */
+ * the range cannot be trusted: scan every slot (spt_epochs, spt_enumerate)
+ * AFTER this call and go on from *cursor_out. */
 int spt_changed_since(spt_store *st, uint64_t cursor, uint32_t *rows_out,
                       uint32_t max_out, uint64_t *cursor_out);
 /* Epochs of n listed slots, one acquire load each (0 for an index out of

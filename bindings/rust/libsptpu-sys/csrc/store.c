@@ -771,6 +771,9 @@ int spt_label_or(spt_store *st, const char *key, uint64_t mask) {
   if (idx < 0) return idx;
   atomic_fetch_or_explicit(&st->slots[idx].labels, mask,
                            memory_order_acq_rel);
+  /* bits first, record second: a consumer that has read the record sees
+   * the bits (sptpu.h, "change journal"); the caller's bump comes after */
+  spt__journal(st, (uint32_t)idx);
   return 0;
 }
 

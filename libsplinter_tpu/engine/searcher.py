@@ -53,7 +53,7 @@ from .. import _native as N
 from ..obs.devtime import DEVTIME, close_mark
 from ..obs.recorder import FlightRecorder
 from ..obs.spans import SpanWriter, sweep_span_stages
-from ..store import Store
+from ..store import LabelCursor, Store
 from ..utils import faults
 from ..utils.faults import fault
 from ..utils.trace import tracer
@@ -134,6 +134,11 @@ class SearcherStats:
     journal_rows: int = 0        # distinct rows the journal delivered
     journal_fallbacks: int = 0   # refreshes that scanned every slot
     lane_audit_rows: int = 0     # rows only the beat's audit found: 0
+    # -- how the gather learns who asks (store.LabelCursor) ----------
+    gather_slots_scanned: int = 0  # slots whose labels the gathers
+                                   # read (a walk: every slot; not audits)
+    gather_fallbacks: int = 0    # gathers that walked every slot
+    gather_audit_rows: int = 0   # rows only the beat's audit found: 0
     # -- K-deep dispatch overlap (engine/resident.py): batch k's
     # select+commit resolve while batches k+1..k+K compute ---------
     inflight_peak: int = 0       # max un-awaited batch dispatches held
@@ -271,7 +276,13 @@ class Searcher:
         # drain for the rows the lane re-examined (_sync_live)
         self._live: np.ndarray | None = None
         self._mask_slots = 0         # rows those patches looked at
+        # who asks: the rows carrying LBL_SEARCH_REQ, followed through
+        # the change journal (its first use walks every slot: that is
+        # how a restarted daemon finds a crashed predecessor's requests)
+        self._asking = LabelCursor(store, P.LBL_SEARCH_REQ)
         self._all_req_rows: list[int] = []
+        self._audit_adopted = False  # the beat's audit found rows no
+                                     # record named: drain, no wake comes
         self.stats = SearcherStats()
         self.generation = 0          # bumped at attach (restart marker)
         self.recorder = FlightRecorder()
@@ -342,20 +353,24 @@ class Searcher:
     # -- request gathering -------------------------------------------------
 
     def _gather_requests(self) -> list[_Request]:
-        """Drain stage: discover labelled rows, parse params, gather
-        query vectors torn-safely.  Rows mid-write stay labelled and
-        retry next drain; rows with malformed params or no query
-        vector get an error result immediately (they can never
-        succeed, so retrying would spin)."""
+        """Drain stage: discover labelled rows (the rows the change
+        journal names since the last gather + the rows still held
+        labelled: store.LabelCursor — no walk over every slot), parse
+        params, gather query vectors torn-safely.  Rows mid-write
+        stay labelled and retry next drain; rows with malformed
+        params or no query vector get an error result immediately
+        (they can never succeed, so retrying would spin)."""
         fault("searcher.gather")
         st = self.store
         self.stripes.refresh()        # a re-stripe lands HERE, at the
-        rows = st.enumerate_indices(P.LBL_SEARCH_REQ)  # drain boundary
-        # the UNfiltered enumeration doubles as this drain's
-        # request-scratch mask input (_mask_for): a peer replica's
-        # pending request rows hold query vectors too
-        self._all_req_rows = [int(i) for i in rows]
-        rows = [i for i in rows if self.stripes.owns(int(i))]
+        rows = self._asking.rows().tolist()            # drain boundary
+        self._audit_adopted = False
+        self._note_gather()
+        # the UNfiltered set doubles as this drain's request-scratch
+        # mask input (_mask_for): a peer replica's pending request
+        # rows hold query vectors too
+        self._all_req_rows = rows
+        rows = [i for i in rows if self.stripes.owns(i)]
         if not rows:
             return []
         out: list[_Request] = []
@@ -508,12 +523,12 @@ class Searcher:
         return mask
 
     def _hidden_rows(self, reqs: list[_Request]) -> np.ndarray:
-        """This drain's request rows plus the WHOLE enumeration its
+        """This drain's request rows plus the WHOLE labelled set its
         gather captured (_all_req_rows): under striped replicas a
         peer's still-pending request rows are request scratch too,
         and masking only our own stripe would make R=2 results
         diverge from R=1 (caught by tests/test_elastic.py).  Reusing
-        the gather's enumeration costs no extra label scan."""
+        the gather's set costs no label read."""
         return np.unique(np.asarray(
             [r.idx for r in reqs] + self._all_req_rows, np.int64))
 
@@ -659,6 +674,13 @@ class Searcher:
             for stage in ("score", "select", "commit"):
                 tracer.record(f"search.{stage}", acc[stage])
         return state["served"]
+
+    def _note_gather(self) -> None:
+        """The label cursor's counters into the heartbeat's own."""
+        asking, stats = self._asking, self.stats
+        stats.gather_slots_scanned = asking.slots_scanned
+        stats.gather_fallbacks = asking.fallbacks
+        stats.gather_audit_rows = asking.audit_rows
 
     def _note_lane(self) -> None:
         """The lane's journal counters into the heartbeat's own."""
@@ -1079,7 +1101,8 @@ class Searcher:
         after the sweeps — so a heartbeat's snapshot holds whole
         passes only and `search.loop` equals its children's sum plus
         the loop's own bookkeeping at every heartbeat.  The publish
-        begins with the lane's audit (StagedLane.audit)."""
+        begins with the two audits (StagedLane.audit,
+        LabelCursor.audit)."""
         self._running = True
         st = self.store
         last = st.signal_count(self.group)
@@ -1122,18 +1145,22 @@ class Searcher:
                                 and redrains < 256:
                             redrains += 1
                             self.drain()
+                    elif self._audit_adopted:
+                        # rows the beat's audit adopted were raised
+                        # with no record, maybe with no pulse either
+                        self.drain()
                     now = time.monotonic()
                     if now >= next_beat:
                         if got is None:
                             # reconciliation on the heartbeat cadence,
                             # never per idle timeout: a request whose
                             # pulse raced a prior drain (or a torn row
-                            # left pending) retries here without an
-                            # O(nslots) label scan every idle wakeup.
-                            # A restarted daemon's FIRST pass through
-                            # here reclaims the stranded requests
-                            # (label bit set, no inflight owner) a
-                            # crashed predecessor left behind.
+                            # left pending) retries here.  A restarted
+                            # daemon's FIRST pass through here walks
+                            # every slot (LabelCursor's first use) and
+                            # reclaims the stranded requests (label
+                            # bit set, no inflight owner) a crashed
+                            # predecessor left behind.
                             self.drain()
                         with tracer.span("search.sweep_results",
                                          leaf=True):
@@ -1164,12 +1191,16 @@ class Searcher:
             self._publish_beat()
 
     def _publish_beat(self) -> None:
-        """The beat's audit of the lane (the one full comparison left:
-        what it finds, the journal missed) and its heartbeat, which
-        carries the count; behind the loop's firewall."""
+        """The beat's two audits (the full comparisons left: the
+        lane's epochs and the request labels over every slot — what
+        either finds, the journal missed) and its heartbeat, which
+        carries the counts; behind the loop's firewall."""
         try:
             with tracer.span("search.publish", leaf=True):
                 self.lane.audit()
+                if self._asking.audit():
+                    self._audit_adopted = True
+                self._note_gather()
                 self.publish_stats()
         except Exception:
             self.stats.drain_faults += 1

@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import ctypes as C
 import errno
+import logging
 import os
 import time
 from dataclasses import dataclass
@@ -23,6 +24,8 @@ import numpy as np
 
 from . import _native as N
 from .utils.faults import fault
+
+log = logging.getLogger(__name__)
 
 _RETRIES = 1024
 
@@ -726,17 +729,20 @@ class Store:
         return int(self._lib.spt_journal_head(self._h))
 
     def changed_since(self, cursor: int) -> tuple[np.ndarray, int, bool]:
-        """The distinct slots whose epoch moved since `cursor`, as
-        (rows, new_cursor, complete).  Every operation that moves a
-        slot's epoch appends the slot to a ring in the shared mapping
+        """The distinct slots whose epoch moved, or on which a label
+        was raised, since `cursor`, as (rows, new_cursor, complete).
+        Every operation that moves a slot's epoch, and every
+        label_or, appends the slot to a ring in the shared mapping
         (sptpu.h, "change journal"); a consumer keeps its own cursor
         and clears nothing.  A row says "look at this slot": it may
         still be mid-write (odd epoch — look again next time, its
         record is behind new_cursor already) or unchanged (a spurious
-        record).  `complete` is False when the writers lapped the
-        cursor or an entry was claimed and never written: the rows are
-        then empty, new_cursor is the head as it was when the call
-        began, and the caller scans epochs() AFTER this call."""
+        record: to an epoch consumer a label raise is one).
+        `complete` is False when the writers lapped the cursor or an
+        entry was claimed and never written: the rows are then empty,
+        new_cursor is the head as it was when the call began, and the
+        caller scans epochs() — a LabelCursor walks the labels —
+        AFTER this call."""
         lib, h = self._lib, self._h
         want = min(max(int(lib.spt_journal_head(h)) - cursor, 0),
                    N.JOURNAL_CAP)
@@ -812,3 +818,88 @@ class Store:
 
     def report_parse_failure(self) -> None:
         _ck(self._lib.spt_report_parse_failure(self._h))
+
+
+class LabelCursor:
+    """The rows that carry every bit of `mask`, learned from the change
+    journal instead of from a walk over every slot: a consumer of its
+    own (cursor and all; consumers share nothing) beside the device
+    lane's.  label_or appends its slot AFTER the bits are readable, so
+    `rows()` reads the journal from its cursor, unites the rows it
+    names with the rows it still holds (`pending`: seen carrying the
+    bits and not seen without them since) and reads the labels of
+    those alone.  A row stays held for as long as its bits are up —
+    whoever defers or skips it finds it again with no new record — and
+    leaves when they are down; a raise that lands after the read is
+    behind no cursor yet.  Every slot is walked only by the first
+    `rows()`, by one that finds its cursor lapped, and by `audit()`.
+
+    `mask` is any label mask (the search daemon follows
+    LBL_SEARCH_REQ); one thread drives an instance."""
+
+    def __init__(self, store: Store, mask: int):
+        self._st = store
+        self.mask = int(mask)
+        self._cursor: int | None = None      # None: never walked
+        self.pending = np.empty(0, np.uint32)    # sorted, distinct
+        self.slots_scanned = 0   # labels rows() read (a walk: every slot)
+        self.fallbacks = 0       # rows() calls that walked (the first too)
+        self.audit_rows = 0      # rows only audit() found: 0
+
+    def _walk(self) -> np.ndarray:
+        return np.asarray(self._st.enumerate_indices(self.mask), np.uint32)
+
+    def _asking(self, cand: np.ndarray) -> np.ndarray:
+        labels_at, mask = self._st.labels_at, self.mask
+        return np.asarray([i for i in cand.tolist()
+                           if labels_at(i) & mask == mask], np.uint32)
+
+    def rows(self) -> np.ndarray:
+        """The slots that carry the mask now, in slot order (what
+        enumerate_indices would give)."""
+        st = self._st
+        if self._cursor is None:
+            # the head BEFORE the walk: a raise during it is found after
+            cursor = st.journal_head()
+        else:
+            named, cursor, complete = st.changed_since(self._cursor)
+            if complete:
+                cand = np.union1d(named, self.pending)
+                self.slots_scanned += cand.size
+                # the cursor moves with the set: the journal names a
+                # row once
+                self._cursor, self.pending = cursor, self._asking(cand)
+                return self.pending
+        # first use, a lapped cursor or a claimed entry never written:
+        # walk every slot AFTER the cursor was read
+        self.fallbacks += 1
+        self.slots_scanned += st.nslots
+        self.pending = self._walk()
+        self._cursor = cursor
+        return self.pending
+
+    def audit(self) -> int:
+        """One walk over every slot, run where the journal is trusted:
+        count the rows that carry the mask and that neither `pending`
+        nor a record since the cursor names — a label raised without a
+        record (a process on an older library, a bug), the one fault
+        that reads as an idle store.  They are adopted, counted
+        (`audit_rows`) and logged.  Returns the count."""
+        if self._cursor is None:
+            return 0
+        walk = self._walk()
+        # read AFTER the walk: a raise the walk saw is in this range
+        # or was held before
+        named, cursor, complete = self._st.changed_since(self._cursor)
+        if not complete:
+            return 0             # the next rows() finds so too, and walks
+        known = np.union1d(named, self.pending)
+        found = np.setdiff1d(walk, known, assume_unique=True)
+        if found.size:
+            self.audit_rows += found.size
+            log.error("label audit: %d rows carry %#x with no journal "
+                      "record (first: %s)", found.size, self.mask,
+                      found[:8])
+        self._cursor, self.pending = \
+            cursor, self._asking(np.union1d(walk, known))
+        return int(found.size)

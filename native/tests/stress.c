@@ -15,14 +15,25 @@
  * follower's: journal + audit = the slots that moved, and the audit's
  * share must be 0 — a slot it finds moved without a record.
  *
+ * And two RAISER threads raise a label bit on the hot keys (each its own
+ * half, a key again only once its bit is down) against one LABEL FOLLOWER
+ * that learns who asks the way the search daemon's gather does: the rows
+ * the journal names since its cursor, united with the rows it still holds,
+ * their labels read, the bit cleared on the ones it serves (every other
+ * pass a row is deferred: it stays held and is served with no new record);
+ * a lapped cursor (-EOVERFLOW; with --label-lap the follower lets the
+ * writers lap it every 16th pass) walks every slot AFTER the call.  When the raisers have stopped,
+ * served == raised and no slot carries the bit: every raise was named.
+ *
  * Usage: spt_stress [--writers N] [--readers N] [--keys K]
  *                   [--duration-ms D] [--slots S] [--val-size V]
- *                   [--scrub MODE]
+ *                   [--scrub MODE] [--label-lap]
  */
 #define _GNU_SOURCE
 #include "sptpu.h"
 
 #include <pthread.h>
+#include <sched.h>
 #include <stdatomic.h>
 #include <stdio.h>
 #include <stdlib.h>
@@ -198,6 +209,80 @@ static void *follower(void *arg) {
   return NULL;
 }
 
+/* ---- label raisers and their follower ---- */
+
+#define ASK_BIT (1ull << 57)
+static _Atomic long g_raised;
+static _Atomic int g_label_stop;
+static long g_l_served, g_l_passes, g_l_fallbacks, g_l_deferred;
+static int g_label_lap;
+
+static void *raiser(void *arg) {
+  int half = (int)(intptr_t)arg;
+  char key[SPT_KEY_MAX];
+  while (!atomic_load_explicit(&g_stop, memory_order_relaxed)) {
+    for (int i = half; i < g_nkeys; i += 2) {
+      uint64_t l = 0;
+      key_name(key, i);
+      /* -ENOENT until a writer has made the key: nothing raised */
+      if (spt_get_labels(g_st, key, &l) == 0 && !(l & ASK_BIT) &&
+          spt_label_or(g_st, key, ASK_BIT) == 0)
+        atomic_fetch_add_explicit(&g_raised, 1, memory_order_relaxed);
+    }
+  }
+  return NULL;
+}
+
+static void *label_follower(void *arg) {
+  (void)arg;
+  uint32_t *rows = malloc(sizeof(uint32_t) * SPT_JOURNAL_CAP);
+  uint32_t *held = malloc(sizeof(uint32_t) * g_slots);
+  uint32_t *keep = malloc(sizeof(uint32_t) * g_slots);
+  uint32_t *walk = malloc(sizeof(uint32_t) * g_slots);
+  uint32_t *seen_in = calloc(g_slots, sizeof(uint32_t));    /* pass no. */
+  uint32_t n_held = 0, pass = 0;
+  uint64_t cursor = 0;             /* a new store's head: before any raise */
+  char key[SPT_KEY_MAX];
+  for (;;) {
+    int last = atomic_load_explicit(&g_label_stop, memory_order_acquire);
+    if (g_label_lap && pass % 16 == 15)        /* fall a lap behind */
+      while (spt_journal_head(g_st) - cursor <= SPT_JOURNAL_CAP &&
+             !atomic_load_explicit(&g_stop, memory_order_relaxed))
+        sched_yield();
+    int n = spt_changed_since(g_st, cursor, rows, SPT_JOURNAL_CAP, &cursor);
+    const uint32_t *named = rows;
+    g_l_passes++;
+    pass++;
+    if (n < 0) {                    /* lapped: walk, AFTER the call */
+      g_l_fallbacks++;
+      n = spt_enumerate(g_st, ASK_BIT, walk, g_slots);
+      named = walk;
+    }
+    uint32_t kept = 0, total = n_held + (uint32_t)n;
+    for (uint32_t j = 0; j < total; j++) {
+      uint32_t idx = j < n_held ? held[j] : named[j - n_held];
+      if (seen_in[idx] == pass) continue;      /* rows repeat: once each */
+      seen_in[idx] = pass;
+      if (!(spt_labels_at(g_st, idx) & ASK_BIT)) continue;   /* not asking */
+      if (!last && ((idx ^ pass) & 1)) {       /* deferred: stays held */
+        g_l_deferred++;
+        keep[kept++] = idx;
+        continue;
+      }
+      if (spt_key_at(g_st, idx, key) == 0 &&
+          spt_label_andnot(g_st, key, ASK_BIT) == 0)
+        g_l_served++;
+      else
+        keep[kept++] = idx;
+    }
+    uint32_t *t = held; held = keep; keep = t;
+    n_held = kept;
+    if (last && n == 0 && n_held == 0) break;
+  }
+  free(rows); free(held); free(keep); free(walk); free(seen_in);
+  return NULL;
+}
+
 /* --json: emit one machine-readable line.  CPO
  * (cycles per op) is measured separately from the contended run: a
  * single-threaded spt_set loop over pre-rendered keys, timed with the
@@ -248,6 +333,7 @@ int main(int argc, char **argv) {
     else if (!strcmp(argv[i], "--val-size")) g_valsz = int_arg(argc, argv, &i);
     else if (!strcmp(argv[i], "--scrub"))
       scrub = (uint32_t)int_arg(argc, argv, &i);
+    else if (!strcmp(argv[i], "--label-lap")) g_label_lap = 1;
     else if (!strcmp(argv[i], "--raw")) g_raw = 1;
     else if (!strcmp(argv[i], "--json")) json_out = 1;
   }
@@ -262,8 +348,11 @@ int main(int argc, char **argv) {
   g_slots = (uint32_t)slots;
   g_seen = calloc(g_slots, sizeof *g_seen);   /* a new store: all 0 */
 
-  pthread_t ft, wt[8], rt[64];
+  pthread_t ft, lt, at[2], wt[8], rt[64];
   pthread_create(&ft, NULL, follower, NULL);
+  pthread_create(&lt, NULL, label_follower, NULL);
+  for (int i = 0; i < 2; i++)
+    pthread_create(&at[i], NULL, raiser, (void *)(intptr_t)i);
   for (int i = 0; i < writers; i++)
     pthread_create(&wt[i], NULL, writer, (void *)(intptr_t)i);
   for (int i = 0; i < readers && i < 64; i++)
@@ -274,8 +363,12 @@ int main(int argc, char **argv) {
   atomic_store(&g_stop, 1);
   for (int i = 0; i < writers; i++) pthread_join(wt[i], NULL);
   for (int i = 0; i < readers && i < 64; i++) pthread_join(rt[i], NULL);
+  for (int i = 0; i < 2; i++) pthread_join(at[i], NULL);
   atomic_store_explicit(&g_follow_stop, 1, memory_order_release);
+  atomic_store_explicit(&g_label_stop, 1, memory_order_release);
   pthread_join(ft, NULL);
+  pthread_join(lt, NULL);
+  long raised = g_raised, left = spt_enumerate(g_st, ASK_BIT, NULL, 0);
 
   /* the audit: every slot's epoch against the follower's */
   long moved = 0, audit = 0;
@@ -294,6 +387,9 @@ int main(int argc, char **argv) {
   printf("  journal: rows=%ld passes=%ld fallbacks=%ld carried=%ld "
          "moved=%ld audit=%ld\n", g_j_rows, g_j_passes, g_j_fallbacks,
          g_j_carried, moved, audit);
+  printf("  labels: raised=%ld served=%ld passes=%ld fallbacks=%ld "
+         "deferred=%ld left=%ld\n", raised, g_l_served, g_l_passes,
+         g_l_fallbacks, g_l_deferred, left);
   printf("  writes=%ld (%.2fM/s)  reads=%ld (%.2fM/s)\n", w, w / secs / 1e6,
          r, r / secs / 1e6);
   printf("  total=%.2fM ops/s  eagain=%ld  miss=%ld  corrupt=%ld\n",
@@ -314,6 +410,12 @@ int main(int argc, char **argv) {
   if (audit || !moved || !g_j_rows) {
     fprintf(stderr, "JOURNAL FAILURE: %ld slots moved without a record "
             "(moved=%ld journal rows=%ld)\n", audit, moved, g_j_rows);
+    return 1;
+  }
+  if (!g_raw && (!raised || raised != g_l_served || left)) {
+    fprintf(stderr, "LABEL FAILURE: raised=%ld served=%ld, %ld rows still "
+            "labelled: a raise the journal did not name\n", raised,
+            g_l_served, left);
     return 1;
   }
   printf("OK\n");

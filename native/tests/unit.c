@@ -10,8 +10,9 @@
  * election matrix (priority, expiry, claimed_at/pid tie-breaks, DONTNEED
  * bumper, rebid revival, -ENOSPC on the 33rd bid, sovereign /
  * non-sovereign madvise), the event bus (init / dirty bits / wait), and
- * the change journal (every call that can move an epoch leaves its slot
- * there; a lapped reader is told so; another format version is refused).
+ * the change journal (every call that can move an epoch or raise a label
+ * leaves its slot there; a lapped reader is told so; another format
+ * version is refused).
  *
  * Like the reference's claim_ex determinism trick (splinter.h:1142-1152),
  * multi-process elections are tested by forging bids — no processes, no
@@ -495,11 +496,32 @@ static void journal_suite(const char *name, uint32_t flags) {
          journaled(st, &cur, i1) == 1, "tandem_unset: journaled");
   }
 
-  /* what moves no epoch leaves no record */
+  /* a label RAISE is journaled, after its bits are readable: exactly one
+   * record naming the slot, and no epoch moved */
   journaled(st, &cur, -1);
-  spt_label_or(st, "a", 1); spt_label_andnot(st, "a", 1);
+  {
+    uint64_t e0 = spt_epoch_at(st, (uint32_t)ia), head0 = cur;
+    uint32_t row[2] = {~0u, ~0u};
+    TEST(spt_label_or(st, "a", 0x9) == 0 &&
+         spt_journal_head(st) == head0 + 1, "label_or: one record");
+    TEST(spt_changed_since(st, cur, row, 2, &cur) == 1 &&
+         row[0] == (uint32_t)ia && cur == head0 + 1,
+         "label_or: the record names the slot");
+    TEST((spt_labels_at(st, row[0]) & 0x9) == 0x9,
+         "label_or: the bits are readable by whoever read the record");
+    TEST(spt_epoch_at(st, (uint32_t)ia) == e0, "label_or moved no epoch");
+    TEST(spt_label_or(st, "a", 0x9) == 0 &&
+         journaled(st, &cur, ia) == 1 && cur == head0 + 2,
+         "label_or of bits already up: still one record a call");
+    TEST(spt_label_or(st, "no-such-key", 1) == -ENOENT &&
+         spt_journal_head(st) == cur, "label_or on a missing key: no record");
+  }
+
+  /* a label CLEAR and what moves no epoch leave no record */
+  spt_label_andnot(st, "a", 0x9);
   spt_bump(st, "a"); spt_slot_usr_set(st, "a", 3);
-  TEST(spt_journal_head(st) == cur, "labels, bump, user flags: no record");
+  TEST(spt_labels_at(st, (uint32_t)ia) == 0 && spt_journal_head(st) == cur,
+       "label_andnot, bump, user flags: no record");
 
   /* the listed epochs are the slots' own; out of range reads 0 */
   {

@@ -303,12 +303,12 @@ reads both tuples):
 | `search.loop` | one pass of the run loop | no |
 | `search.idle` | blocked in `signal_wait` | yes |
 | `search.drain_cycle` | one drain, serviced or idle (the beat's idle drain too) | no |
-| `search.wake` / `search.drain` | signal → drain entry; gather + admit (the histogram counts serviced drains only) | `drain` yes |
+| `search.wake` / `search.drain` | signal → drain entry; gather + admit — the gather reads the labels of the rows the change journal names since its last look and of the rows it still holds labelled (`store.LabelCursor`; `gather_slots_scanned` counts them, a first-attach or lapped-cursor walk of every slot counts in `gather_fallbacks`); the histogram counts serviced drains only | `drain` yes |
 | `search.score` | host wall of the service outside select and commit; its self time, score − refresh − mask, is batching and dispatch | no |
 | `search.refresh` / `search.mask` | `lane.refresh()`: the rows the store's change journal names, compared and re-staged (a pass that does a full upload is the staging of the lane; a lapped cursor falls back to the all-slot comparison, `journal_fallbacks`); bringing the candidate masks up to date — the liveness mask patched for the rows the lane re-examined, the pending request rows hidden for the drain | yes |
 | `search.select` / `search.commit` | blocked in `jax.device_get`; result rows + label clears | yes |
 | `search.sweep_results` / `search.sweep_stages` | the two heartbeat-cadence sweeps: each finds its `__sr_` / `__sp_` rows with one native prefix scan of the slots (`spt_enumerate_prefix`) and opens only those (`sweep_keys` counts the live keys the scans pass, `sweep_rows` the rows they match, `results_reaped` what the first retires); the second is the span plane's own housekeeping and runs with tracing off too | yes |
-| `search.publish` | the beat's `lane.audit()` (the full epoch comparison: `lane_audit_rows` counts what the journal missed, 0) and `publish_stats`: serialisation, `DEVTIME.flush`, `spans.flush` | yes |
+| `search.publish` | the beat's two audits — `lane.audit()` (the full epoch comparison: `lane_audit_rows` counts what the journal missed, 0) and the label cursor's (one walk of every slot's labels: `gather_audit_rows` counts the requests no record named, 0; they are adopted and drained) — and `publish_stats`: serialisation, `DEVTIME.flush`, `spans.flush` | yes |
 
 A beat's publish runs at the head of the next pass, so a heartbeat
 holds whole passes only: `search.loop` equals its children's sum plus

@@ -182,7 +182,7 @@ def test_disarmed_fault_is_noop_hot_path():
 
 # ---------------------------------------------------- daemon heartbeat
 
-def test_armed_faults_ride_the_searcher_heartbeat(store):
+def test_armed_faults_ride_the_searcher_heartbeat(store_2k):
     """With SPTPU_FAULT armed, the daemon heartbeat carries the site
     accounting so `spt metrics` can show which points a drill hit."""
     import json
@@ -190,8 +190,9 @@ def test_armed_faults_ride_the_searcher_heartbeat(store):
     from libsplinter_tpu.engine import protocol as P
     from libsplinter_tpu.engine.searcher import Searcher
 
+    store = store_2k             # room for the whole heartbeat: the
     faults.arm("searcher.gather:stall1@999")   # armed, never fires
-    sr = Searcher(store)
+    sr = Searcher(store)         # 1 KiB fixture drops sections
     sr.attach()
     sr.run_once()
     sr.publish_stats()

@@ -55,3 +55,29 @@ def test_native_stress_journal_follower(writers):
     rows, moved, audit = map(int, m.groups())
     assert rows > 0 and moved == 500 and audit == 0
     assert "corrupt=0" in r.stdout
+
+
+@pytest.mark.parametrize("writers,lap", [(1, False), (4, False), (4, True)])
+def test_native_stress_label_follower(writers, lap):
+    """spt_stress's raisers raise a label on the hot keys against a
+    follower that learns who asks from the change journal alone, the
+    way the search daemon's gather does (held rows, deferrals); with
+    --label-lap it lets the writers lap its cursor and walks every
+    slot instead (-EOVERFLOW).  Every raise is served: none left."""
+    import re
+
+    _build("tests")
+    r = subprocess.run([str(NATIVE / "build" / "spt_stress"),
+                        "--duration-ms", "600", "--writers", str(writers),
+                        "--readers", "2", "--keys", "500"]
+                       + (["--label-lap"] if lap else []),
+                       capture_output=True, text=True, timeout=120)
+    assert r.returncode == 0, r.stdout + r.stderr
+    m = re.search(r"labels: raised=(\d+) served=(\d+) passes=(\d+) "
+                  r"fallbacks=(\d+) deferred=(\d+) left=(\d+)", r.stdout)
+    assert m, r.stdout
+    raised, served, passes, fallbacks, deferred, left = map(int, m.groups())
+    assert raised == served > 0 and left == 0 and deferred > 0
+    if lap:
+        assert fallbacks > 0, r.stdout
+    assert "LABEL FAILURE" not in r.stderr

@@ -974,6 +974,21 @@ def test_64_dirty_drain_of_100k_slots_scans_under_1000():
         Store.unlink(name)
 
 
+class _LosesARecord:
+    """A store whose journal drops the records of one slot."""
+    lost = -1
+
+    def __init__(self, st):
+        self._st = st
+
+    def __getattr__(self, name):
+        return getattr(self._st, name)
+
+    def changed_since(self, cursor):
+        rows, cur, complete = self._st.changed_since(cursor)
+        return rows[rows != self.lost], cur, complete
+
+
 def test_the_beat_audits_the_lane_and_publishes_what_it_found(store_2k):
     """Once a heartbeat the lane runs the full comparison: a row the
     journal did not deliver is staged, counted and published, and the
@@ -984,20 +999,7 @@ def test_the_beat_audits_the_lane_and_publishes_what_it_found(store_2k):
     rng = np.random.default_rng(9)
     _fill_docs(store, 8, rng)
 
-    class LosesARecord:
-        lost = -1
-
-        def __init__(self, st):
-            self._st = st
-
-        def __getattr__(self, name):
-            return getattr(self._st, name)
-
-        def changed_since(self, cursor):
-            rows, cur, complete = self._st.changed_since(cursor)
-            return rows[rows != self.lost], cur, complete
-
-    view = LosesARecord(store)
+    view = _LosesARecord(store)
     sr = Searcher(store, lane=StagedLane(view))
     sr.attach()
     q = rng.normal(size=store.vec_dim).astype(np.float32)
@@ -1018,3 +1020,375 @@ def test_the_beat_audits_the_lane_and_publishes_what_it_found(store_2k):
     assert sr.run_once() == 1
     assert sr._live[row] == 1.0                  # the audit's rows too
     assert _result(store, "__sqtmp_a")["i"][0] == row
+
+
+# ------------------------- the gather reads the journal (LabelCursor)
+
+def _count_calls(obj, *names):
+    """Wrap the named methods of one object to count their calls."""
+    calls = dict.fromkeys(names, 0)
+    for name in names:
+        def counted(*a, _real=getattr(obj, name), _name=name, **k):
+            calls[_name] += 1
+            return _real(*a, **k)
+
+        setattr(obj, name, counted)
+    return calls
+
+
+def _asking_now(store):
+    """The oracle's own walk (past any call counter on the object)."""
+    return Store.enumerate_indices(store, P.LBL_SEARCH_REQ)
+
+
+def test_steady_drains_walk_no_slots():
+    """Acceptance: on a store of 100,000 slots, with requests raised
+    by submit_search's own sequence against the daemon's run loop, a
+    steady drain calls no all-slot function — enumerate_indices and
+    epochs() are reached only from the first attach — and the gathers
+    read the labels of a few rows a request, not of every slot."""
+    import os
+    import uuid
+
+    name = f"/spt-srgather-{os.getpid()}-{uuid.uuid4().hex[:6]}"
+    Store.unlink(name)
+    st = Store.create(name, nslots=100_000, max_val=2048, vec_dim=8)
+    sr = Searcher(st)
+    sr.attach()
+    t = threading.Thread(target=sr.run, kwargs={
+        "stop_after": 120.0, "idle_timeout_ms": 20,
+        "heartbeat_interval_s": 3600.0})     # one beat: the first
+    try:
+        rng = np.random.default_rng(21)
+        vecs = _fill_docs(st, 64, rng, dim=8)
+        t.start()
+        _wait_heartbeat(st)
+        n_clients, rounds = 8, 6
+        failed = []
+
+        def client(c, n):
+            key = f"__sqtmp_g{c}"
+            for r in range(n):
+                st.set(key, "placeholder")
+                st.vec_set(key, vecs[(c * 7 + r) % 64])
+                rec = submit_search(st, key, 3, timeout_ms=60_000)
+                if rec is None or len(rec["i"]) != 3:
+                    failed.append((c, r))
+
+        def burst(n):
+            ts = [threading.Thread(target=client, args=(c, n))
+                  for c in range(n_clients)]
+            for x in ts:
+                x.start()
+            for x in ts:
+                x.join()
+
+        burst(1)                     # the lane's upload, the first mask
+        assert not failed
+        assert sr.stats.gather_fallbacks == 1    # the first attach
+        scanned0 = sr.stats.gather_slots_scanned
+        assert scanned0 >= st.nslots
+        calls = _count_calls(st, "enumerate_indices", "epochs")
+        served0 = sr.stats.served
+        burst(rounds)
+        assert not failed
+        asked = n_clients * rounds
+        assert sr.stats.served - served0 == asked
+        assert calls == {"enumerate_indices": 0, "epochs": 0}
+        assert 0 < sr.stats.gather_slots_scanned - scanned0 < 8 * asked
+        assert sr.stats.gather_fallbacks == 1
+        assert sr.stats.journal_fallbacks == 1
+    finally:
+        sr.stop()
+        if t.is_alive():
+            t.join()
+        st.close()
+        Store.unlink(name)
+
+
+def test_a_request_labelled_after_the_gather_read_its_set(store):
+    """The race of a gather that reads the ring between a client's
+    `set` and its `label_or`: the row is looked at, found unlabelled
+    and dropped — and comes back from the label_or's own record,
+    which is behind no cursor yet."""
+    rng = np.random.default_rng(22)
+    _fill_docs(store, 8, rng)
+    sr = Searcher(store)
+    sr.attach()
+    assert sr.run_once() == 0                    # the first walk
+    calls = _count_calls(store, "enumerate_indices")
+    key = "__sqtmp_race"
+    store.set(key, json.dumps({"k": 3}))
+    store.vec_set(key, rng.normal(size=store.vec_dim).astype(np.float32))
+    scanned = sr.stats.gather_slots_scanned
+    assert sr.run_once() == 0                    # sees the set alone
+    assert sr.stats.gather_slots_scanned == scanned + 1
+    assert sr._asking.pending.size == 0
+    assert sr._asking._cursor == store.journal_head()
+    store.label_or(key, P.LBL_SEARCH_REQ | P.LBL_WAITING)
+    store.bump(key)
+    assert sr.run_once() == 1
+    assert len(_result(store, key)["i"]) == 3
+    assert calls["enumerate_indices"] == 0
+
+
+@pytest.mark.parametrize("why", ["torn", "deferred", "peer_stripe"])
+def test_a_row_left_labelled_stays_pending_with_no_new_record(store, why):
+    """A request the drain does not serve — its vector torn under the
+    gather, deferred by admission, a peer replica's stripe — stays in
+    the pending set and is looked at again by the next gather, though
+    the journal names it no second time."""
+    rng = np.random.default_rng(23)
+    _fill_docs(store, 8, rng)
+    sr = Searcher(store, admit_cap=2 if why == "deferred" else None)
+    sr.attach()
+    assert sr.run_once() == 0                    # the first walk
+    keys = [f"__sqtmp_p{i}" for i in range(5)]
+    for key in keys:
+        _request(store, key,
+                 rng.normal(size=store.vec_dim).astype(np.float32))
+    rows = sorted(store.find_index(k) for k in keys)
+    held = rows[2]
+    if why == "torn":
+        real = store.vec_gather
+
+        def torn_once(rows_a):
+            vecs, eps = real(rows_a)
+            eps[list(rows_a).index(held)] = Store.GATHER_TORN
+            store.vec_gather = real
+            return vecs, eps
+
+        store.vec_gather = torn_once
+    elif why == "peer_stripe":
+        sr.stripes.owns = lambda idx: idx != held
+    calls = _count_calls(store, "enumerate_indices")
+    first = sr.run_once()
+    assert first == (2 if why == "deferred" else 4)
+    head = store.journal_head()
+    left = _asking_now(store)
+    assert held in left and len(left) == 5 - first
+    # the set as the gather left it: the served rows leave it at the
+    # next look, the held ones stay
+    assert sr._asking.pending.tolist() == rows
+    if why == "deferred":
+        assert sr._had_deferred
+    sr.stripes.owns = lambda idx: True
+    served = first
+    while served < 5:
+        before = _asking_now(store)
+        got = sr.run_once()
+        assert got > 0
+        served += got
+        assert sr._asking.pending.tolist() == before
+    assert served == 5 and not _asking_now(store)
+    for key in keys:
+        assert len(_result(store, key)["i"]) == 5
+    assert sr.run_once() == 0 and sr._asking.pending.size == 0
+    assert calls["enumerate_indices"] == 0
+    # nobody raised a label again: the records since are the drains'
+    # own commits
+    assert sr.stats.gather_fallbacks == 1
+    assert head <= store.journal_head()
+
+
+def test_a_lapped_cursor_walks_once_and_misses_nothing(store):
+    """Writers that lap the gather's cursor: one fallback walk, after
+    the journal read, finds the request whose record was overwritten;
+    the next gather reads the journal again."""
+    from libsplinter_tpu import _native as N
+
+    rng = np.random.default_rng(24)
+    _fill_docs(store, 8, rng)
+    sr = Searcher(store)
+    sr.attach()
+    assert sr.run_once() == 0
+    assert sr.stats.gather_fallbacks == 1
+    q = rng.normal(size=store.vec_dim).astype(np.float32)
+    _request(store, "__sqtmp_lap", q)
+    for i in range(N.JOURNAL_CAP + 1):           # its record is gone
+        store.set("churn", "x")
+    calls = _count_calls(store, "enumerate_indices")
+    scanned = sr.stats.gather_slots_scanned
+    assert sr.run_once() == 1
+    assert sr.stats.gather_fallbacks == 2
+    assert sr.stats.gather_slots_scanned == scanned + store.nslots
+    assert calls["enumerate_indices"] == 1
+    _request(store, "__sqtmp_lap", q)
+    assert sr.run_once() == 1                    # by the journal again
+    assert sr.stats.gather_fallbacks == 2
+    assert calls["enumerate_indices"] == 1
+    assert sr.stats.gather_slots_scanned < scanned + store.nslots + 16
+
+
+@pytest.mark.parametrize("predecessor", ["none", "crashed_mid_drain"])
+def test_a_fresh_daemon_reclaims_requests_raised_before_it(
+        store, predecessor):
+    """The first gather after attach walks every slot (cursor taken
+    first): a request raised before the daemon existed — or gathered
+    by a predecessor that died before serving it — is served."""
+    rng = np.random.default_rng(25)
+    _fill_docs(store, 8, rng)
+    q = rng.normal(size=store.vec_dim).astype(np.float32)
+    if predecessor == "crashed_mid_drain":
+        old = Searcher(store)
+        old.attach()
+        assert old.run_once() == 0
+        _request(store, "__sqtmp_early", q)
+        assert len(old._gather_requests()) == 1  # read; never served
+    else:
+        _request(store, "__sqtmp_early", q)
+    sr = Searcher(store)
+    sr.attach()
+    assert sr.run_once() == 1
+    assert sr.stats.gather_fallbacks == 1
+    assert sr.stats.gather_slots_scanned == store.nslots
+    assert len(_result(store, "__sqtmp_early")["i"]) == 5
+
+
+def test_the_beat_audits_the_labels_and_adopts_what_it_found(store_2k):
+    """A label raised behind the journal's back is invisible to the
+    gathers; the beat's walk finds it, adopts it, counts it in the
+    heartbeat, and the next drain serves it."""
+    from libsplinter_tpu.store import LabelCursor
+
+    store = store_2k
+    rng = np.random.default_rng(26)
+    _fill_docs(store, 8, rng)
+    view = _LosesARecord(store)
+    sr = Searcher(store)
+    sr._asking = LabelCursor(view, P.LBL_SEARCH_REQ)
+    sr.attach()
+    q = rng.normal(size=store.vec_dim).astype(np.float32)
+    _request(store, "__sqtmp_seen", q)
+    assert sr.run_once() == 1
+    sr._publish_beat()
+    assert sr.stats.gather_audit_rows == 0 and not sr._audit_adopted
+    store.set("__sqtmp_lost", "placeholder")
+    view.lost = row = store.find_index("__sqtmp_lost")
+    _request(store, "__sqtmp_lost", q)
+    _request(store, "__sqtmp_seen", q)
+    assert sr.run_once() == 1                    # the other is unseen
+    assert store.labels("__sqtmp_lost") & P.LBL_SEARCH_REQ
+    scanned = sr.stats.gather_slots_scanned
+    sr._publish_beat()
+    assert sr.stats.gather_slots_scanned == scanned   # no audit in it
+    snap = json.loads(store.get(P.KEY_SEARCH_STATS).rstrip(b"\0"))
+    assert snap["gather_audit_rows"] == 1
+    assert snap["gather_fallbacks"] == 1
+    assert snap["gather_slots_scanned"] == scanned
+    assert sr._audit_adopted and row in sr._asking.pending
+    assert sr.run_once() == 1 and not sr._audit_adopted
+    assert len(_result(store, "__sqtmp_lost")["i"]) == 5
+    sr._publish_beat()                           # counted once
+    assert sr.stats.gather_audit_rows == 1
+
+
+def test_a_label_with_no_record_and_no_pulse_is_served_within_a_beat(
+        store_2k):
+    """The run loop: an idle daemon whose beat adopts a row drains in
+    the same pass — no wake is coming for it."""
+    from libsplinter_tpu.store import LabelCursor
+
+    store = store_2k
+    rng = np.random.default_rng(27)
+    _fill_docs(store, 8, rng)
+    view = _LosesARecord(store)
+    sr = Searcher(store)
+    sr._asking = LabelCursor(view, P.LBL_SEARCH_REQ)
+    sr.attach()
+    t = threading.Thread(target=sr.run, kwargs={
+        "stop_after": 60.0, "idle_timeout_ms": 20,
+        "heartbeat_interval_s": 0.25})
+    t.start()
+    try:
+        _wait_heartbeat(store)
+        key = "__sqtmp_quiet"
+        store.set(key, json.dumps({"k": 3}))
+        store.vec_set(key,
+                      rng.normal(size=store.vec_dim).astype(np.float32))
+        view.lost = store.find_index(key)
+        time.sleep(0.05)                         # the set's wake drains
+        store.label_or(key, P.LBL_SEARCH_REQ | P.LBL_WAITING)   # no bump
+        deadline = time.monotonic() + 30.0
+        while store.labels(key) & P.LBL_SEARCH_REQ:
+            assert time.monotonic() < deadline, "never served"
+            time.sleep(0.01)
+        assert len(_result(store, key)["i"]) == 3
+    finally:
+        sr.stop()
+        t.join()
+    assert sr.stats.gather_audit_rows == 1
+    assert sr.stats.gather_fallbacks == 1
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_pending_set_is_the_enumeration_at_every_gather(store, seed):
+    """Oracle: over a seeded interleaving of submits, rewrites,
+    cancels, unsets, commits, deferrals and stripe changes, what a
+    gather returns is enumerate_indices(LBL_SEARCH_REQ) at that
+    moment, `_hidden_rows` is what it was built from before (the
+    drain's own rows + that whole set), and the beat's audit never
+    finds a row the journal did not name."""
+    rng = np.random.default_rng(300 + seed)
+    dim = store.vec_dim
+    _fill_docs(store, 16, rng)
+    sr = Searcher(store, admit_cap=3)
+    sr.attach()
+    gathers = []
+    real_rows = sr._asking.rows
+
+    def rows():
+        got = real_rows()
+        want = _asking_now(store)
+        assert got.tolist() == want
+        gathers.append(want)
+        return got
+
+    sr._asking.rows = rows
+    real_hidden = sr._hidden_rows
+    hidden_seen = []
+
+    def hidden(reqs):
+        got = real_hidden(reqs)
+        want = np.unique(np.asarray(
+            [r.idx for r in reqs] + gathers[-1], np.int64))
+        np.testing.assert_array_equal(got, want)
+        hidden_seen.append(got.size)
+        return got
+
+    sr._hidden_rows = hidden
+    pool = [f"__sqtmp_o{i}" for i in range(10)]
+    blob = json.dumps({"k": 3})
+    for rnd in range(24):
+        for _ in range(int(rng.integers(0, 7))):
+            key = pool[rng.integers(0, len(pool))]
+            op = rng.integers(0, 8)
+            if op <= 3 or key not in store:      # submit (or again)
+                _request(store, key,
+                         rng.normal(size=dim).astype(np.float32), k=3)
+            elif op == 4:                        # rewrite under way
+                store.set(key, blob)
+            elif op == 5:                        # the client gives up
+                store.label_clear(key,
+                                  P.LBL_SEARCH_REQ | P.LBL_WAITING)
+            elif op == 6:                        # the key goes
+                store.unset(key)
+            else:                                # a raise with no pulse
+                store.label_or(key, P.LBL_SEARCH_REQ)
+        mode = rng.integers(0, 3)
+        sr.stripes.owns = (
+            (lambda idx: True) if mode else
+            (lambda idx, p=int(rng.integers(0, 2)): idx % 2 == p))
+        sr.run_once()
+        if rnd % 5 == 4:
+            sr._publish_beat()
+            assert sr._asking.pending.tolist() == _asking_now(store)
+    sr.stripes.owns = lambda idx: True
+    for _ in range(8):
+        sr.run_once()
+    assert not _asking_now(store) and sr._asking.pending.size == 0
+    assert len(gathers) >= 32 and max(map(len, gathers)) >= 3
+    assert hidden_seen and max(hidden_seen) >= 3
+    assert sr.stats.gather_audit_rows == 0
+    assert sr.stats.gather_fallbacks == 1
+    assert sr.stats.served >= 10

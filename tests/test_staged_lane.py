@@ -388,9 +388,10 @@ class TestChangeJournal:
         store.set("a", "1")
         store.set("b", "2")
         store.vec_set("a", np.ones(store.vec_dim, np.float32))
-        store.label_or("a", 1)                 # moves no epoch
+        store.label_or("a", 1)        # moves no epoch; journaled too
+        store.label_clear("a", 1)              # a clear is not
         rows, c2, complete = store.changed_since(c1)
-        assert complete and c2 == c1 + 3
+        assert complete and c2 == c1 + 4
         assert sorted(rows) == sorted({store.find_index("a"),
                                        store.find_index("b")})
         np.testing.assert_array_equal(

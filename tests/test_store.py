@@ -520,3 +520,129 @@ def test_open_numa(store):
     st3, bad_rc = type(store).open_numa(store.name, -1)
     st3.close()
     assert bad_rc == -errno.EINVAL
+
+
+# -- a label raise is journaled; LabelCursor follows a mask through it --
+
+def test_changed_since_names_a_row_after_label_or_alone(store):
+    """label_or moves no epoch and still appends its slot — after the
+    bits are readable; label_clear and a raise on a missing key
+    append nothing."""
+    store.set("asks", b"v")
+    row = store.find_index("asks")
+    cur = store.journal_head()
+    e0 = store.epoch_at(row)
+    store.label_or("asks", 0x5)
+    rows, cur2, complete = store.changed_since(cur)
+    assert complete and rows.tolist() == [row] and cur2 == cur + 1
+    assert store.labels_at(row) & 0x5 == 0x5
+    assert store.epoch_at(row) == e0
+    store.label_clear("asks", 0x5)
+    with pytest.raises(KeyError):
+        store.label_or("no-such-key", 1)
+    rows, cur3, complete = store.changed_since(cur2)
+    assert complete and rows.size == 0 and cur3 == cur2
+
+
+def test_label_cursor_follows_raises_without_walking(store):
+    from libsplinter_tpu.store import LabelCursor
+
+    BIT, OTHER = 1 << 57, 1 << 3
+    for i in range(6):
+        store.set(f"k{i}", b"v")
+    store.label_or("k0", BIT)                    # before the cursor
+    lc = LabelCursor(store, BIT)
+    assert lc.rows().tolist() == [store.find_index("k0")]
+    assert lc.fallbacks == 1 and lc.slots_scanned == store.nslots
+    walks = []
+    real = store.enumerate_indices
+    store.enumerate_indices = lambda m: walks.append(m) or real(m)
+    store.label_or("k3", BIT)
+    store.label_or("k4", OTHER)                  # named, not asking
+    store.set("k5", b"w")                        # an epoch record
+    want = sorted(store.find_index(k) for k in ("k0", "k3"))
+    assert lc.rows().tolist() == want
+    assert lc.slots_scanned == store.nslots + 4
+    assert lc.rows().tolist() == want            # held, no new record
+    assert lc.slots_scanned == store.nslots + 6
+    store.label_clear("k0", BIT)                 # a clear: no record
+    assert lc.rows().tolist() == [store.find_index("k3")]
+    store.unset("k3")                            # labels zeroed
+    assert lc.rows().size == 0 and lc.pending.size == 0
+    assert walks == [] and lc.fallbacks == 1
+
+
+def test_label_cursor_wants_every_bit_of_its_mask(store):
+    from libsplinter_tpu.store import LabelCursor
+
+    store.set("half", b"v")
+    store.set("whole", b"v")
+    lc = LabelCursor(store, 0x6)
+    assert lc.rows().size == 0
+    store.label_or("half", 0x2)
+    store.label_or("whole", 0x6)
+    assert lc.rows().tolist() == [store.find_index("whole")]
+    assert lc.rows().tolist() == store.enumerate_indices(0x6)
+    store.label_or("half", 0x4)                  # the other bit, later
+    assert lc.rows().tolist() == store.enumerate_indices(0x6)
+    assert len(lc.pending) == 2
+
+
+@pytest.mark.parametrize("how", ["lapped", "claimed_never_written"])
+def test_label_cursor_walks_when_the_journal_cannot_answer(store, how):
+    from libsplinter_tpu import _native as N
+    from libsplinter_tpu.store import LabelCursor
+
+    BIT = 1 << 57
+    store.set("a", b"v")
+    store.set("b", b"v")
+    lc = LabelCursor(store, BIT)
+    assert lc.rows().size == 0
+    store.label_or("a", BIT)
+    if how == "lapped":
+        for _ in range(N.JOURNAL_CAP + 1):
+            store.set("b", b"w")
+    else:
+        real = store.changed_since
+        store.changed_since = lambda c: (
+            np.empty(0, np.uint32), store.journal_head(), False)
+    assert lc.rows().tolist() == [store.find_index("a")]
+    assert lc.fallbacks == 2
+    assert lc.slots_scanned == 2 * store.nslots
+    if how != "lapped":
+        store.changed_since = real
+    store.label_or("b", BIT)
+    assert lc.rows().tolist() == sorted(
+        store.find_index(k) for k in "ab")
+    assert lc.fallbacks == 2                     # by the journal again
+
+
+def test_label_cursor_audit_counts_only_what_no_record_named(store):
+    from libsplinter_tpu.store import LabelCursor
+
+    BIT = 1 << 57
+    for k in "abc":
+        store.set(k, b"v")
+    lc = LabelCursor(store, BIT)
+    assert lc.audit() == 0 and lc.fallbacks == 0     # never walked yet
+    lc.rows()
+    store.label_or("a", BIT)          # named by a record not yet read
+    assert lc.audit() == 0 and lc.audit_rows == 0
+    assert lc.pending.tolist() == [store.find_index("a")]
+    scanned = lc.slots_scanned
+    real = store.changed_since
+    lost = store.find_index("b")
+
+    def loses(cursor):
+        rows, cur, complete = real(cursor)
+        return rows[rows != lost], cur, complete
+
+    store.changed_since = loses
+    store.label_or("b", BIT)
+    assert lc.rows().tolist() == [store.find_index("a")]   # unseen
+    assert lc.audit() == 1 and lc.audit_rows == 1
+    assert lc.pending.tolist() == sorted(
+        store.find_index(k) for k in "ab")
+    assert lc.audit() == 0 and lc.audit_rows == 1    # adopted: known
+    assert lc.slots_scanned == scanned + 1       # audits are not in it
+    assert lc.rows().tolist() == lc.pending.tolist()

@@ -152,6 +152,12 @@ class CompleterStats:
     state_restores: int = 0
     state_snapshots: int = 0
     state_cut_tokens: int = 0
+    # -- models with a window page group (models/afmoe.py): prefix
+    # hits that mapped their whole match because the window's tail was
+    # still held, and prefix tokens a hit gave up for want of a tail
+    # page
+    window_resumes: int = 0
+    window_cut_tokens: int = 0
 
 
 class Completer:
@@ -189,6 +195,7 @@ class Completer:
                  prefix_cache: bool = True,
                  prefix_cache_pages: int | None = None,
                  state_snapshots: int | None = None,
+                 window_pool_pages: int | None = None,
                  prefix_quotas: dict[int, int] | None = None,
                  prefix_default_quota: int | None = None,
                  kv_tier_pages: int = 0,
@@ -283,6 +290,7 @@ class Completer:
         # snapshot slots of a model with recurrent state (None: one a
         # batch row); PagedKVCache owns them, the prefix tree their use
         self._state_snapshots = state_snapshots
+        self._window_pool_pages = window_pool_pages
         self._prefix_quotas = dict(prefix_quotas or {})
         self._prefix_default_quota = prefix_default_quota
         self.prefix_cache = None
@@ -343,7 +351,8 @@ class Completer:
                     "audit records need a model whose decode chunks "
                     "keep a row's logits (audit_supported)")
             from .audit import AuditLog
-            self.audit = AuditLog(**audit)
+            self.audit = AuditLog(
+                **audit, lanes=getattr(model, "audit_lanes", 1))
         self.generation = 0            # bumped at attach (restart marker)
         self._bid = -1
         self._running = False
@@ -1044,6 +1053,8 @@ class Completer:
         if self._paged_cache is None:
             kw = ({"state_snapshots": self._state_snapshots}
                   if getattr(self._model, "needs_state", False) else {})
+            if getattr(self._model, "needs_window", False):
+                kw["window_pool_pages"] = self._window_pool_pages
             self._paged_cache = self._model.init_paged(
                 self.paged_batch_cap, page=self.page_size,
                 pool_pages=self.pool_pages, kv_dtype=self.kv_dtype, **kw)
@@ -1287,6 +1298,7 @@ class Completer:
             # a model with per-row recurrent state (models/kda.py):
             # a hit resumes from a snapshot, a join leaves one
             stateful = bool(getattr(cache, "needs_state", False))
+            wgroup = getattr(cache, "window", None)
             for idx in self._admit_waiting(plannable, len(free)):
                 if not free:
                     break
@@ -1333,6 +1345,13 @@ class Completer:
                         # geometry anyway)
                         hit_bids, match, tier_nodes = [], 0, []
                 cut = pc.last_cut if stateful and pc is not None else 0
+                # a pool with a window group (models/afmoe.py): the
+                # hit ends where the window's tail is still held, and
+                # maps that tail beside the global pages
+                windowed = wgroup is not None and pc is not None
+                wtail = list(pc.last_window) if windowed and hit_bids \
+                    else []
+                wcut = pc.last_window_cut if windowed else 0
                 # the snapshot this join will leave: the state after
                 # the prompt's last full page, if the hit ends short
                 # of it and the pool keeps snapshots at all
@@ -1359,9 +1378,20 @@ class Completer:
                     # whose ensure() then comes up short
                     pinned = sum(1 for b in hit_bids
                                  if cache.refcounts[b] == 0)
+                    # the window group's reservation: what the row
+                    # holds at the most while it joins and decodes,
+                    # less the tail it maps (pinned like the hit's
+                    # global pages)
+                    short_w = wgroup is not None and (
+                        wgroup.join_pages(match, reserve) - len(wtail)
+                        + (1 if full_cover else 0)
+                        > wgroup.available_pages - sum(
+                            1 for b in wtail
+                            if wgroup.refcounts[b] == 0))
                     if need > cache.available_pages - pinned or (
                             wants_snap
-                            and not cache.state_slot_available()):
+                            and not cache.state_slot_available()) \
+                            or short_w:
                         self.stats.join_backpressure += 1
                         bp_memo[idx] = (e, need + pinned)
                         self._bound_bp_memo()
@@ -1405,6 +1435,10 @@ class Completer:
                         # an unpinned zero-ref hit page would be fair
                         # game for the very eviction pass serving it
                         cache.map_shared(r, hit_bids)
+                        if wgroup is not None:
+                            wgroup.map_tail(r, len(hit_bids) - len(wtail),
+                                            wtail)
+                            self.stats.window_resumes += int(not wcut)
                     if tier_nodes:
                         # DRAM hit: readmit demoted pages.  They come
                         # back holding refcount 1; drop each to
@@ -1459,6 +1493,8 @@ class Completer:
                 self.stats.prompt_tokens += len(ids)
                 self.stats.prefix_tokens += match
                 self.stats.state_cut_tokens += cut
+                self.stats.window_cut_tokens += wcut
+                w_s0 = wgroup.release_s if wgroup is not None else 0.0
                 if not cache.ensure(r, reserve):
                     # defensive: the pinned-aware gate above makes
                     # this unreachable, but a seated row WITHOUT its
@@ -1510,6 +1546,14 @@ class Completer:
                         logits = m.paged_prefill_row(
                             cache, np.asarray(ids, np.int32), r, **skw)
                     tb = time.perf_counter()
+                    if wgroup is not None:
+                        # the prefill gave the window pages it slid
+                        # past back a piece at a time; what the decode
+                        # still needs of the reservation comes now
+                        cache.ensure(r, reserve)
+                        if traced:
+                            span(rows[r], "window_release",
+                                 (wgroup.release_s - w_s0) * 1e3)
                     if pc is not None:
                         # freshly committed full prompt pages join
                         # the tree NOW, donor still live — the next
@@ -1524,10 +1568,14 @@ class Completer:
                         if ins and tenant:
                             self.tenants.bump(
                                 tenant, "prefix_cached_pages", ins)
-                    if self.audit is not None and self.audit.wants():
+                    # a model with two audit lanes (engine/audit.py)
+                    # keeps one for each way a prompt is served
+                    lane = int(not match) if self.audit is not None \
+                        and self.audit.lanes > 1 else 0
+                    if self.audit is not None and self.audit.wants(lane):
                         rows[r]["audit"] = self.audit.open(
-                            key, ids, match, logits)
-                        m.audit_row = r
+                            key, ids, match, logits, lane)
+                        m.audit_seat(lane, r)
                     # splint: ignore[SPL201] reason=the documented host "sample" stage (CONT_INFER_STAGES): one scalar draw per JOIN so the row's first token emits before the next chunk, not per decode step
                     t = int(m.sample(logits))
                     if traced:
@@ -1585,7 +1633,7 @@ class Completer:
             row = rows[r]
             if row.get("audit") is not None:
                 self.audit.close(row["audit"])
-                m.audit_row = -1
+                m.audit_seat(row["audit"].lane, -1)
             if row["pending"] and not truncated and not vanished:
                 res = self._flush(row["key"], row["pending"])
                 truncated = res == "full"
@@ -1637,8 +1685,8 @@ class Completer:
                     pass
                 self.spans.commit(span_rec, status=P.ERR_DEADLINE)
                 if row.get("audit") is not None:
-                    self.audit.drop()
-                    m.audit_row = -1
+                    self.audit.drop(row["audit"].lane)
+                    m.audit_seat(row["audit"].lane, -1)
                 self._lane_row_done(row)
                 cache.free_row(r)     # pool pages back NOW
                 rows[r] = None
@@ -1818,8 +1866,14 @@ class Completer:
                     td = time.perf_counter()
                     if sharded:
                         fault("completer.sharded_dispatch")
+                    wgroup = getattr(cache, "window", None)
+                    w_s0 = wgroup.release_s if wgroup is not None else 0.0
                     pend = m.paged_decode_chunk_async(
                         cache, fresh, step, carry=carry)
+                    if wgroup is not None and tracer.enabled:
+                        # the chunk's rows slid: what they gave back
+                        tracer.record("infer.window_release",
+                                      (wgroup.release_s - w_s0) * 1e3)
                     live = [(r, rows[r]["serial"]) for r in range(B)
                             if rows[r] is not None]
                     if tracer.enabled:
@@ -2156,6 +2210,26 @@ class Completer:
             for k in ("state_restores", "state_snapshots",
                       "state_cut_tokens"):
                 payload.pop(k, None)  # no state: dead gauges
+        wgroup = getattr(self._paged_cache, "window", None)
+        if wgroup is not None:
+            # the window page group beside the global one: pages rows
+            # gave back as they slid, pages some row maps in either
+            # group at this beat, the window pool's size
+            payload["window_pages_released"] = wgroup.released
+            payload["window_pages_live"] = wgroup.live_pages
+            payload["window_pages_used"] = wgroup.used_pages
+            payload["window_pages_used_peak"] = wgroup.used_peak
+            payload["window_pool_pages"] = wgroup.n_blocks - 1
+            payload["global_pages_live"] = int(
+                (self._paged_cache.refcounts > 0).sum())
+            if self.prefix_cache is not None:
+                payload["window_evictions"] = \
+                    self.prefix_cache.stats.window_evictions
+            # live keys the attention kernels were asked for
+            payload.update(getattr(m_now, "attn_work", {}))
+        else:
+            for k in ("window_resumes", "window_cut_tokens"):
+                payload.pop(k, None)  # one page group: dead gauges
         pc = self.prefix_cache
         if pc is not None:
             # prefix-cache gauges (sptpu_completer_prefix_* in `spt
@@ -2544,6 +2618,14 @@ def main(argv: list[str] | None = None) -> int:
                          "row's state each, in device memory beside "
                          "the pages (default: one a batch row; 0: "
                          "every prompt prefills from its first token)")
+    ap.add_argument("--window-pool-pages", type=int, default=None,
+                    help="pages of the WINDOW page group of a --model "
+                         "whose layers mix sliding-window and global "
+                         "attention (models/afmoe.py): such a model "
+                         "keeps two pools, --pool-pages counts the "
+                         "global layers' and this the window layers', "
+                         "whose pages go back as a row slides past "
+                         "them (default: as many as --pool-pages)")
     ap.add_argument("--prefix-quota", default=None,
                     help="per-tenant prefix-cache page quotas, "
                          "TENANT:PAGES[,TENANT:PAGES...] (unlisted "
@@ -2596,10 +2678,20 @@ def main(argv: list[str] | None = None) -> int:
                 "unsupported_option: --state-snapshots cannot be "
                 f"served with --model ({model_cls.__name__}): its "
                 "layers keep no recurrent state")
+        if args.window_pool_pages is not None \
+                and not getattr(model_cls, "needs_window", False):
+            raise SystemExit(
+                "unsupported_option: --window-pool-pages cannot be "
+                f"served with --model ({model_cls.__name__}): its "
+                "layers keep one page group")
     elif args.state_snapshots is not None:
         raise SystemExit(
             "unsupported_option: --state-snapshots is for a --model "
             "whose layers keep recurrent state")
+    elif args.window_pool_pages is not None:
+        raise SystemExit(
+            "unsupported_option: --window-pool-pages is for a --model "
+            "whose layers mix sliding-window and global attention")
     # one-shot start-up phases, ms -> the heartbeat's `startup_ms`
     from .searcher import _Lap, _process_age_ms
     boot: dict[str, float] = {}
@@ -2755,6 +2847,7 @@ def main(argv: list[str] | None = None) -> int:
                      prefix_cache=not args.no_prefix_cache,
                      prefix_cache_pages=args.prefix_cache_pages,
                      state_snapshots=args.state_snapshots,
+                     window_pool_pages=args.window_pool_pages,
                      prefix_quotas=parse_tenant_quotas(
                          args.prefix_quota),
                      kv_tier_pages=args.kv_tier_pages,

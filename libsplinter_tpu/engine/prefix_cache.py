@@ -49,6 +49,24 @@ sharing machinery is host-side:
     what LRU alone would also keep.  For a model without state none
     of this runs.
 
+  - **Window pages** (models whose layers mix sliding-window and
+    global attention, models/afmoe.py): the pool keeps such layers'
+    pages in a group of their own (PagedKVCache.window) and gives
+    them back as a row slides past them, so a node holds a
+    global-group page and MAY hold a window-group page (`wbid`).  A
+    hit can resume at a node only if the nodes that hold the window
+    of its first new token — ceil(window / page) of them, ending at
+    it — still hold theirs: lookup()/lookup_tiered() return the
+    longest such match (`last_window` the tail's window pages to map,
+    `last_window_cut` the tokens given up), insert() files the window
+    pages the row still holds, and then lets go of those no resume
+    needs any more: on a chain WITHOUT A BRANCH only the newest tail
+    is kept (a growing session leaves a chain a turn; the tail behind
+    the newest turn's tail is superseded).  Window pages at zero
+    references are reclaimed on their own (reclaim_window), a page
+    that never served a hit first: a node that loses its window page
+    keeps its global one.
+
 Lifecycle: pages enter the tree at admission (after the committing
 row's prefill), while the donor row is still live — a mid-flight
 joiner may map a prefix another row is actively decoding from (the
@@ -92,11 +110,12 @@ class PrefixCacheStats:
     quota_rejects: int = 0    # inserts skipped: tenant over quota
     bytes_saved: int = 0      # KV bytes not re-prefilled/committed
     state_evictions: int = 0  # snapshots given up for their slot
+    window_evictions: int = 0  # window pages reclaimed or superseded
 
 
 class _Node:
     __slots__ = ("toks", "bid", "parent", "children", "lru", "tenant",
-                 "tier", "state", "state_lru")
+                 "tier", "state", "state_lru", "wbid", "hot")
 
     def __init__(self, toks: tuple, bid: int, parent, tenant: int):
         self.toks = toks            # this page's token ids (exact)
@@ -116,6 +135,11 @@ class _Node:
         # page's last token (-1: none), and when it was last restored
         self.state = -1
         self.state_lru = 0
+        # the window group's page of the same tokens (0: none, given
+        # back or never filed), and whether the node ever served a hit
+        # or extends a path that did: what eviction keeps longest
+        self.wbid = 0
+        self.hot = False
 
 
 class PrefixCache:
@@ -158,6 +182,13 @@ class PrefixCache:
         # last lookup gave up for want of one (module docstring)
         self._snapshots: dict[int, _Node] = {}    # state slot -> node
         self.last_cut = 0
+        # window-group pages the tree holds, how many of them no row
+        # maps, and what the last lookup found: the tail's window
+        # pages to map and the tokens it gave up for want of one
+        self._by_wbid: dict[int, _Node] = {}
+        self._zero_ref_w = 0
+        self.last_window: list[int] = []
+        self.last_window_cut = 0
         # the page keys of the prompt being admitted: an admission
         # walks the tree up to four times over the same ids (lookup,
         # commit_hit, state_slot, insert), and at ~80 pages of 128
@@ -180,6 +211,11 @@ class PrefixCache:
     def needs_state(self) -> bool:
         return bool(getattr(self._cache, "needs_state", False))
 
+    @property
+    def _window(self):
+        """The bound pool's window group (None: it has none)."""
+        return getattr(self._cache, "window", None)
+
     # -- binding -----------------------------------------------------------
 
     def attach(self, cache) -> None:
@@ -197,6 +233,12 @@ class PrefixCache:
         self._demoted = 0
         self._snapshots = {}
         self.last_cut = 0
+        self._by_wbid = {}
+        self._zero_ref_w = 0
+        self.last_window, self.last_window_cut = [], 0
+        if self._window is not None and self.needs_state:
+            raise ValueError("a pool with state slots AND a window "
+                             "group is not served")
         if self.tier is not None:
             self.tier.clear()
 
@@ -226,6 +268,7 @@ class PrefixCache:
         page = self.page
         bids: list[int] = []
         states: list[int] = []
+        wbids: list[int] = []
         cur = self._children
         for chunk in self._keys(ids)[:(len(ids) if upto is None
                                        else upto) // page]:
@@ -234,9 +277,33 @@ class PrefixCache:
                 break
             bids.append(node.bid)
             states.append(node.state)
+            wbids.append(node.wbid)
             cur = node.children
         bids = bids[:self._state_cut(states)]
+        bids = bids[:self._window_cut(wbids[:len(bids)])]
         return bids, len(bids) * page
+
+    def _window_cut(self, wbids: list[int]) -> int:
+        """How many of a match's HBM nodes a pool with a window group
+        can resume on: the longest prefix whose last nodes — those
+        that hold the window of the token after it — still hold their
+        window pages (all of them for a pool without the group).
+        Leaves that tail in `last_window` and the tokens given up in
+        `last_window_cut`."""
+        w = self._window
+        keep = len(wbids)
+        self.last_window, self.last_window_cut = [], 0
+        if w is None:
+            return keep
+        run = 0                  # nodes in a row, ending here, with one
+        best = 0
+        for k, wb in enumerate(wbids, 1):
+            run = run + 1 if wb > 0 else 0
+            if run >= k - w.first_live(k * self.page):
+                best = k
+        self.last_window = wbids[w.first_live(best * self.page): best]
+        self.last_window_cut = (keep - best) * self.page
+        return best
 
     def _state_cut(self, states: list[int]) -> int:
         """How many of a match's HBM nodes a model with state can use:
@@ -277,6 +344,7 @@ class PrefixCache:
         page = self.page
         bids: list[int] = []
         states: list[int] = []
+        wbids: list[int] = []
         nodes: list[_Node] = []
         cur = self._children
         tier = self.tier
@@ -294,12 +362,16 @@ class PrefixCache:
             else:
                 bids.append(node.bid)
                 states.append(node.state)
+                wbids.append(node.wbid)
             cur = node.children
         if self.needs_state:
             # no state rides the host tier: demoted pages end the match
             bids, nodes = bids[:self._state_cut(states)], []
         else:
             self.last_cut = 0
+        keep = self._window_cut(wbids[:len(bids)])
+        if keep < len(bids):
+            bids, nodes = bids[:keep], []
         return bids, len(bids) * page, nodes
 
     def commit_hit(self, ids, match: int) -> None:
@@ -313,6 +385,7 @@ class PrefixCache:
             if node is None:
                 break                  # evicted mid-admission: stale
             node.lru = tick
+            node.hot = True
             cur = node.children
         self.stats.hits += 1
         self.stats.hit_tokens += match
@@ -344,6 +417,8 @@ class PrefixCache:
         inserted = 0
         parent = None
         cur = self._children
+        window = self._window
+        depth = 0
         tick = next(self._clock)
         for j, chunk in enumerate(self._keys(ids)):
             node = cur.get(chunk)
@@ -354,6 +429,7 @@ class PrefixCache:
                 if not self._admit_page(tenant):
                     break
                 node = _Node(chunk, bid, parent, tenant)
+                node.hot = parent is not None and parent.hot
                 cur[chunk] = node
                 self._by_bid[bid] = node
                 self._tenant_pages[tenant] = \
@@ -385,7 +461,15 @@ class PrefixCache:
                 if self.tier is not None and not self.tier.has(node):
                     self._spill(node)
             node.lru = tick
+            if window is not None and node.wbid <= 0:
+                # the window page the row still holds for these tokens
+                # (none once the row has slid past them)
+                wb = int(window.tables[row, j])
+                if wb > 0 and wb not in self._by_wbid:
+                    node.wbid = wb
+                    self._by_wbid[wb] = node
             parent = node
+            depth += 1
             cur = node.children
             if state is not None and (j + 1) * page == state[1] \
                     and node.state < 0:
@@ -394,7 +478,77 @@ class PrefixCache:
                 state = None
         if state is not None:
             cache.free_state_slot(state[0])
+        if window is not None and parent is not None:
+            self._shed_window(parent, depth)
         return inserted
+
+    # -- window pages -------------------------------------------------------
+
+    def _drop_window(self, node) -> None:
+        """The tree lets go of node's window page: back to the free
+        list if no row maps it (a row that does gives it back itself)."""
+        wb, w = node.wbid, self._window
+        if wb <= 0:
+            return
+        del self._by_wbid[wb]
+        node.wbid = 0
+        if w.refcounts[wb] == 0:
+            self._zero_ref_w -= 1
+            w._free.append(wb)
+        self.stats.window_evictions += 1
+
+    def _shed_window(self, tail, depth: int) -> None:
+        """After an insert that ends at `tail` (`depth` nodes deep):
+        the nodes that hold the window of the token after it keep
+        their window pages; above them, as far up as the path has NO
+        BRANCH, the pages are superseded — whoever resumes on this
+        path resumes at the newer tail — and go."""
+        keep = depth - self._window.first_live(depth * self.page)
+        node = tail
+        while node is not None and len(node.children) <= 1:
+            if keep > 0:
+                keep -= 1
+            elif node.wbid > 0:
+                self._drop_window(node)
+            else:
+                break                 # shed by an earlier insert
+            node = node.parent
+
+    def retains_window(self, bid: int) -> bool:
+        return bid in self._by_wbid
+
+    def on_window_zero_ref(self, bid: int) -> bool:
+        if bid in self._by_wbid:
+            self._zero_ref_w += 1
+            return True
+        return False
+
+    def on_window_ref(self, bid: int) -> None:
+        if bid in self._by_wbid:
+            self._zero_ref_w -= 1
+
+    def window_evictable_count(self) -> int:
+        return self._zero_ref_w if self._cache is not None else 0
+
+    def window_pages(self) -> int:
+        return len(self._by_wbid)
+
+    def reclaim_window(self, n: int) -> int:
+        """Give up to `n` zero-ref window pages back to the window
+        group's free list: a page that never served a hit first, the
+        least recently matched among equals.  The nodes keep their
+        global pages."""
+        w = self._window
+        done = 0
+        while done < n:
+            victim = min((nd for nd in self._by_wbid.values()
+                          if w.refcounts[nd.wbid] == 0), default=None,
+                         key=lambda nd: (nd.hot, nd.lru))
+            if victim is None:
+                break
+            self._drop_window(victim)
+            done += 1
+        return done
 
     # -- state snapshots ----------------------------------------------------
 
@@ -538,7 +692,9 @@ class PrefixCache:
                 continue              # mapped by a live row
             if tenant is not None and node.tenant != tenant:
                 continue
-            if victim is None or node.lru < victim.lru:
+            # a page that never served a hit goes before one that did
+            if victim is None or (node.hot, node.lru) < (victim.hot,
+                                                         victim.lru):
                 victim = node
         if victim is None:
             return False
@@ -549,6 +705,7 @@ class PrefixCache:
             self._spill(victim)
         bid = victim.bid
         self._drop_snapshot(victim)   # its page goes: so does its state
+        self._drop_window(victim)     # and its window page
         if tier is not None and tier.has(victim):
             # DEMOTE: the HBM page returns to the pool, the node
             # survives DRAM-resident — a future hit readmits it with

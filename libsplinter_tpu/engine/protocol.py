@@ -199,7 +199,11 @@ CONT_INFER_STAGES = ("join", "sample", "decode", "collect", "flush",
                      # a model with per-row recurrent state: copying a
                      # snapshot into the joining row, and finding the
                      # slot its own snapshot goes to (leaf spans)
-                     "state_restore", "state_snapshot")
+                     "state_restore", "state_snapshot",
+                     # a model with a window page group: giving back
+                     # the pages a row has slid past, after a join's
+                     # prefill pieces and after a chunk (leaf span)
+                     "window_release")
 
 # the search daemon's per-drain decomposition: wake = signal to drain
 # entry (the coalescing window's scheduling cost); drain = request

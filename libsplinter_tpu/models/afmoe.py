@@ -1,0 +1,721 @@
+"""A decoder stack whose layers MIX sliding-window and global
+attention over grouped key/value heads, with a sparse shared-expert
+MoE, as the AFMoE family of public configs describes it (Trinity-Mini
+among them), served through the completion daemon's paged lane as one
+chip's share of an expert-parallel deployment (models/mla.py holds the
+share's conventions and the weight recipe; this module reuses its
+feed-forward and its chunk hand-off).
+
+The layer (x: hidden; matrices without bias; RMSNorm eps
+`rms_norm_eps`; four norms a layer):
+
+    x0 = E[token] * sqrt(hidden_size)                  (mup_enabled)
+    h = x + N2(Attn(N1(x)));   y = h + N4(FFN(N3(h)))
+
+    Attn(u): q = u W_Q -> heads x d;  k, v = u W_K, u W_V -> kv_heads x d
+             g = sigmoid(u W_G)                        (heads x d)
+             q, k <- RMSNorm over d (q_norm, k_norm), before any rotation
+             sliding layer: RoPE(rope_theta) on q and k; query i sees
+                            keys j with 0 <= i - j < sliding_window
+             full layer:    no positions; query i sees every j <= i
+             o_h = softmax(q_h . k_{h // rep} / sqrt(d)) v_{h // rep}
+             Attn = (concat_h(o_h) * g) W_O
+    FFN:     models/mla._ffn — dense SwiGLU in the leading layers,
+             after them the shared expert + this share of the routed
+             ones (sigmoid scores, normalised over the selection,
+             times route_scale).
+
+PAGES IN TWO GROUPS.  The cache holds K (after its norm and, on a
+sliding layer, its rotation) and V a token a layer.  A sliding layer
+needs the last `sliding_window` tokens' only, so the model describes
+its pages as two groups (`page_layout`, decoder.PageLayout.window):
+the GLOBAL layers' pool (n_blocks, L_global, kv_heads, page, d) and
+the WINDOW layers' pool (n_blocks_w, L_window, kv_heads, page, d),
+each with its own table, page count and free list
+(decoder.PagedKVCache / WindowPages).  The cache gives a window
+group's page back when its row has slid past it; the attention
+kernel (ops/paged_attention.window_paged_attention) walks a window
+layer's pages from the first live one.
+
+PROGRAMS.  ONE prefill program, the suffix prefill over (pages +
+table), in whole-page widths from one page to SUFFIX_PAGES: the new
+tokens start at a page boundary (a prefix hit maps whole pages; a
+prompt the prefix cache does not know is the same program from an
+empty row, looped in its widest bucket, giving window pages back as it
+passes the window — no separate bucket prefill), so their keys and
+values are written a page at a time.  The decode chunk is mla's: n
+steps with the sampler in graph.  The layers after the dense ones
+repeat with the period of the layer pattern, and the periods run as
+ONE compiled body under lax.scan (the stack's weights a period's
+stacked; a layer is told its index in its group's pool by the loop's
+counter): 32 layers compile as 8.
+
+WEIGHTS: mla.seed_tensor, names `layers.<i>.<tensor>`.  Two things are
+this family's own, restated by the plain reference:
+    the embedding has std 1/sqrt(hidden_size), so that after the muP
+    multiplier the stream starts at unit scale;
+    the norms that write INTO the residual stream (the sandwich's
+    second and fourth) are scaled by s = 1/sqrt(2 x the whole model's
+    layers) (a tenth of the mean as std): with unit branches a seeded
+    32-layer stack amplifies a bfloat16 rounding past what a reference
+    can tell from a fault (PR 30's finding, models/kda.py; under a
+    sandwich norm scaling w_o would change nothing).  ln_attn_out has
+    mean ATTN_OUT x s = 2 s and ln_mlp_out MLP_OUT x s = s / 2: at s
+    for both, a routed expert that a rounding flips in or out of a
+    token's top-k moved the logits as far as a window layer that lost
+    a page of its keys (measured on the chip, PERF.md section 6), and
+    the attention over paged keys is what this family's pages are for.
+"""
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..obs.devtime import DEVTIME, close_mark
+from ..ops.paged_attention import kv_append, window_paged_attention
+from .decoder import PageLayout, PagedKVCache, _sample_rows
+from .encoder import _rotary_angles_at
+from .mla import (LatentCompletionModel, LatentPendingChunk, _ffn, _rms,
+                  _sum_slots, ffn_params, seed_tensor)
+
+KINDS = ("window", "full")
+# the seeded post-branch norms' means, in units of 1/sqrt(2 x layers)
+# (module docstring, WEIGHTS)
+ATTN_OUT, MLP_OUT = 2.0, 0.5
+# pages of the widest suffix program: a follow-up turn of a few hundred
+# tokens fits one call, a cold prompt loops in it
+SUFFIX_PAGES = 5
+
+
+@dataclasses.dataclass(frozen=True)
+class WindowMoeConfig:
+    vocab_size: int               # rows of the vocabulary held here
+    hidden: int
+    kinds: tuple[str, ...]        # a kind ("window" | "full") a kept layer
+    heads: int
+    kv_heads: int
+    head_dim: int
+    window: int                   # tokens a window layer attends
+    dense_layers: int             # leading dense layers among `layers`
+    dense_mlp_dim: int
+    moe_mlp_dim: int
+    n_routed_experts: int         # the router's width: ALL experts
+    top_k: int
+    experts_first: int = 0
+    experts_held: int | None = None
+    n_shared_experts: int = 1
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    score_fn: str = "sigmoid"
+    vocab_first: int = 0
+    rope_base: float = 10000.0
+    rms_eps: float = 1e-5
+    mup: bool = True              # the embedding times sqrt(hidden)
+    max_len: int = 2048
+    dtype: Any = jnp.bfloat16
+    # layers of the WHOLE model (the share may keep fewer): what the
+    # norms that write into the residual stream are scaled by
+    model_layers: int | None = None
+
+    def __post_init__(self):
+        object.__setattr__(self, "kinds", tuple(self.kinds))
+        if self.model_layers is None:
+            object.__setattr__(self, "model_layers", len(self.kinds))
+        if self.experts_held is None:
+            object.__setattr__(self, "experts_held",
+                               self.n_routed_experts - self.experts_first)
+        if not 0 <= self.experts_first \
+                <= self.experts_first + self.experts_held \
+                <= self.n_routed_experts:
+            raise ValueError(
+                f"experts {self.experts_first}..+{self.experts_held} "
+                f"lie outside the router's {self.n_routed_experts}")
+        if not 0 <= self.dense_layers <= self.layers:
+            raise ValueError("dense_layers must lie in 0..layers")
+        if set(self.kinds) - set(KINDS) or "full" not in self.kinds:
+            raise ValueError(f"layer kinds must be among {KINDS}, with "
+                             "at least one full layer")
+        if self.heads % self.kv_heads or self.head_dim % 2:
+            raise ValueError("kv_heads must divide heads and head_dim "
+                             "be even")
+        if "window" in self.kinds and self.window < 1:
+            raise ValueError("a window layer needs sliding_window >= 1")
+
+    @classmethod
+    def tiny(cls, **kw) -> "WindowMoeConfig":
+        """Small config for tests and CPU rehearsals: two periods, the
+        second under the scan."""
+        kw = {"vocab_size": 512, "hidden": 64,
+              "kinds": ("window", "window", "window", "full") * 2,
+              "heads": 4, "kv_heads": 2, "head_dim": 16, "window": 32,
+              "dense_layers": 1, "dense_mlp_dim": 128, "moe_mlp_dim": 32,
+              "n_routed_experts": 8, "top_k": 2,
+              "routed_scaling_factor": 2.826, "max_len": 256, **kw}
+        return cls(**kw)
+
+    @property
+    def layers(self) -> int:
+        return len(self.kinds)
+
+    @property
+    def plan(self) -> tuple[int, int, int]:
+        """(head, period, periods): the first `head` layers run
+        unrolled (whole periods of the kind pattern that cover the
+        dense layers), then `periods` identical periods of `period`
+        layers under one scanned body, then whatever is left,
+        unrolled."""
+        n, kinds = self.layers, self.kinds
+        period = next(p for p in range(1, n + 1)
+                      if all(kinds[i] == kinds[i - p]
+                             for i in range(p, n)))
+        head = min(n, -(-self.dense_layers // period) * period)
+        return head, period, (n - head) // period
+
+    def group_index(self, i: int) -> int:
+        """Layer i's index within its group's pool."""
+        return sum(k == self.kinds[i] for k in self.kinds[:i])
+
+    def page_layout(self, page: int) -> tuple[PageLayout, ...]:
+        """A layout a GROUP: the global layers' K and V side by side
+        in one page, and the window layers'."""
+        out = []
+        for kind, window in (("full", 0), ("window", self.window)):
+            n = self.kinds.count(kind)
+            if n:
+                shape = (n, self.kv_heads, page, self.head_dim)
+                out.append(PageLayout(
+                    (("k", shape), ("v", shape)),
+                    token_values=2 * n * self.kv_heads * self.head_dim,
+                    window=window, layers=n))
+        return tuple(out)
+
+
+# ------------------------------------------------------------- weights
+
+def _layer_params(cfg: WindowMoeConfig, seed: int, i: int) -> dict:
+    H, dt, D = cfg.hidden, cfg.dtype, cfg.head_dim
+    p = f"layers.{i}."
+    out_mean = 1.0 / math.sqrt(2.0 * cfg.model_layers)
+
+    def mat(name, shape):
+        return seed_tensor(seed, name, shape, 1.0 / math.sqrt(shape[0]),
+                           dt)
+
+    def norm(name, n, mean=1.0):
+        return seed_tensor(seed, name, (n,), 0.1 * mean, jnp.float32,
+                           mean)
+
+    lp = {"ln_attn_in": norm(p + "ln_attn_in", H),
+          "ln_attn_out": norm(p + "ln_attn_out", H, ATTN_OUT * out_mean),
+          "ln_mlp_in": norm(p + "ln_mlp_in", H),
+          "ln_mlp_out": norm(p + "ln_mlp_out", H, MLP_OUT * out_mean),
+          # kept TRANSPOSED, (out, hidden): the layout the chip's
+          # compiler asks for under both programs — as (hidden, out)
+          # it copied all three at every dispatch
+          # (tests/test_chip_compile.py)
+          "w_q": mat(p + "w_q", (H, cfg.heads * D)).T,
+          "w_k": mat(p + "w_k", (H, cfg.kv_heads * D)).T,
+          "w_v": mat(p + "w_v", (H, cfg.kv_heads * D)).T,
+          "w_g": mat(p + "w_g", (H, cfg.heads * D)),
+          "q_norm": norm(p + "q_norm", D),
+          "k_norm": norm(p + "k_norm", D),
+          "w_o": mat(p + "w_o", (cfg.heads * D, H))}
+    lp.update(ffn_params(cfg, seed, p, i < cfg.dense_layers, mat))
+    return lp
+
+
+def init_params(cfg: WindowMoeConfig, seed: int) -> dict:
+    """The resident tree of this share, tensor by tensor: `head` the
+    unrolled leading layers, `periods` one dict a position of the
+    period with every leaf stacked over the scanned periods, `tail`
+    what is left."""
+    H, dt = cfg.hidden, cfg.dtype
+    head, period, n = cfg.plan
+    periods = []
+    for j in range(period if n else 0):
+        made = [_layer_params(cfg, seed, head + k * period + j)
+                for k in range(n)]
+        periods.append(jax.tree_util.tree_map(
+            lambda *a: jnp.stack(a), *made))
+        del made
+    return {
+        "tok_emb": seed_tensor(seed, f"tok_emb.{cfg.vocab_first}",
+                               (cfg.vocab_size, H), 1.0 / math.sqrt(H),
+                               dt),
+        "head": [_layer_params(cfg, seed, i) for i in range(head)],
+        "periods": periods,
+        "tail": [_layer_params(cfg, seed, i)
+                 for i in range(head + n * period, cfg.layers)],
+        "ln_out": seed_tensor(seed, "ln_out", (H,), 0.1, jnp.float32,
+                              1.0),
+        "lm_head": seed_tensor(seed, f"lm_head.{cfg.vocab_first}",
+                               (H, cfg.vocab_size), 1.0 / math.sqrt(H),
+                               dt),
+    }
+
+
+# -------------------------------------------------------------- forward
+
+def _normed(cfg, x, scale):
+    """RMSNorm of the float32 residual stream, handed to the matrix
+    products in the model's dtype."""
+    return _rms(x, scale, cfg.rms_eps).astype(cfg.dtype)
+
+
+def _rotate(x, cos, sin):
+    """Split-half rotation pairs, in float32.  x: (B, S, heads, d);
+    cos/sin: (B, S, d/2)."""
+    half = x.shape[-1] // 2
+    x1, x2 = x[..., :half], x[..., half:]
+    cos, sin = cos[:, :, None, :], sin[:, :, None, :]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+
+
+BANKED = ("exp_gate", "exp_up", "exp_down")
+
+
+def _layer(cfg: WindowMoeConfig, lp, kind: str, gl, x, pos, live, pools,
+           write, tables, att_len, interpret: bool, bank=None):
+    """One block over its group's pool.  x: (B, S, H) float32; pos:
+    (B, S); pools: {"full": (k, v), "window": (k, v)}; write[kind](pool,
+    new (B, S, kv_heads, d), layer) puts the new tokens' rows into
+    their pages; gl: the layer's index in its group (traced under the
+    scan); bank: under the scan, the period's index in the expert
+    tensors, which stay stacked (moe.sparse_moe).  Returns (x, pools,
+    expert slots | None)."""
+    B, S, _ = x.shape
+    D, f32 = cfg.head_dim, jnp.float32
+    xn = _normed(cfg, x, lp["ln_attn_in"])
+    def proj(w, heads):                 # w: (heads x d, hidden)
+        return jnp.einsum("bsh,xh->bsx", xn, w).reshape(B, S, heads, D)
+    q = proj(lp["w_q"], cfg.heads)
+    k = proj(lp["w_k"], cfg.kv_heads)
+    v = proj(lp["w_v"], cfg.kv_heads)
+    gate = jax.nn.sigmoid(jnp.dot(xn, lp["w_g"]).astype(f32))
+    q = _rms(q.astype(f32), lp["q_norm"], cfg.rms_eps)
+    k = _rms(k.astype(f32), lp["k_norm"], cfg.rms_eps)
+    if kind == "window":
+        cos, sin = _rotary_angles_at(pos.reshape(-1), D, cfg.rope_base)
+        cos, sin = cos.reshape(B, S, -1), sin.reshape(B, S, -1)
+        q, k = _rotate(q, cos, sin), _rotate(k, cos, sin)
+    kp, vp = pools[kind]
+    kp = write[kind](kp, k.astype(kp.dtype), gl)
+    vp = write[kind](vp, v.astype(vp.dtype), gl)
+    pools = {**pools, kind: (kp, vp)}
+    o = window_paged_attention(
+        q.astype(cfg.dtype), kp, vp, tables[kind], att_len, layer=gl,
+        window=cfg.window if kind == "window" else 0,
+        interpret=interpret)
+    a = jnp.dot((o.reshape(B, S, cfg.heads * D).astype(f32) * gate)
+                .astype(cfg.dtype), lp["w_o"],
+                preferred_element_type=f32)
+    h = x + _rms(a, lp["ln_attn_out"], cfg.rms_eps)
+    # the router reads the normed stream unrounded (moe.sparse_moe)
+    hn = _rms(h, lp["ln_mlp_in"], cfg.rms_eps)
+    f, slots = _ffn(cfg, lp, hn.astype(cfg.dtype), live, interpret, bank,
+                    route_x=hn)
+    return h + _rms(f.astype(f32), lp["ln_mlp_out"], cfg.rms_eps), \
+        pools, slots
+
+
+def _stack(cfg: WindowMoeConfig, params, x, pos, live, pools, write,
+           tables, att_len, interpret: bool):
+    """Every kept layer: the head unrolled, the periods under one
+    scanned body, the tail unrolled.  Returns (x, pools, slots)."""
+    head, period, n = cfg.plan
+    slots = []
+
+    def one(lp, i, gl, x, pools, bank=None):
+        return _layer(cfg, lp, cfg.kinds[i], gl, x, pos, live, pools,
+                      write, tables, att_len, interpret, bank)
+
+    for i, lp in enumerate(params["head"]):
+        x, pools, s = one(lp, i, cfg.group_index(i), x, pools)
+        slots.append(s)
+    if n:
+        # layer head + k * period + j of period k: its group index is
+        # the head's count of its kind + k * (the period's) + j's
+        base = [cfg.group_index(head + j) for j in range(period)]
+        per = [cfg.kinds[head:head + period].count(cfg.kinds[head + j])
+               for j in range(period)]
+
+        # the expert tensors stay whole, a period a bank: a slice of
+        # them would be copied for the grouped kernel at every layer
+        banked = [{k: v for k, v in lp.items() if k in BANKED}
+                  for lp in params["periods"]]
+        sliced = [{k: v for k, v in lp.items() if k not in BANKED}
+                  for lp in params["periods"]]
+
+        def body(carry, xs):
+            x, pools, tot = carry
+            lps, k = xs
+            got = []
+            for j, lp in enumerate(lps):
+                x, pools, s = one({**lp, **banked[j]}, head + j,
+                                  base[j] + k * per[j], x, pools, bank=k)
+                got.append(s)
+            return (x, pools, tot + _sum_slots(cfg, got)), None
+
+        zero = jnp.zeros((max(cfg.experts_held, 1),), jnp.int32)
+        (x, pools, tot), _ = jax.lax.scan(
+            body, (x, pools, zero),
+            (sliced, jnp.arange(n, dtype=jnp.int32)))
+        slots.append(tot)
+    for t, lp in enumerate(params["tail"]):
+        i = head + n * period + t
+        x, pools, s = one(lp, i, cfg.group_index(i), x, pools)
+        slots.append(s)
+    return x, pools, _sum_slots(cfg, slots)
+
+
+def _embed(cfg, params, ids):
+    x = params["tok_emb"][ids].astype(jnp.float32)
+    return x * math.sqrt(cfg.hidden) if cfg.mup else x
+
+
+def _head(cfg, params, x):
+    """Final norm + untied head over the vocabulary slice, float32."""
+    return jnp.dot(_normed(cfg, x, params["ln_out"]), params["lm_head"],
+                   preferred_element_type=jnp.float32)
+
+
+def forward_decode(cfg: WindowMoeConfig, params, toks, pools, tables,
+                   lengths, *, interpret: bool = False):
+    """One new token a row over the pages its tables map.  toks: (B,);
+    pools: {"full": (k, v), "window": (k, v)}, each (n_blocks, L,
+    kv_heads, page, d); tables: the same keys, (B, P); lengths: (B,).
+    Returns (hidden (B, H), pools, slots each held expert received)."""
+    page = pools["full"][0].shape[3]
+    pos = jnp.minimum(lengths, cfg.max_len - 1).astype(jnp.int32)
+    offs = pos % page
+    write = {}
+    for kind, tab in tables.items():
+        bids = jnp.take_along_axis(tab, (pos // page)[:, None], axis=1)
+
+        def put(pool, new, gl, bids=bids[:, 0]):
+            return kv_append(pool, new[:, 0], bids, offs, layer=gl,
+                             interpret=interpret)
+        write[kind] = put
+    x, pools, slots = _stack(
+        cfg, params, _embed(cfg, params, toks)[:, None], pos[:, None],
+        (lengths > 0)[:, None], pools, write, tables, pos + 1, interpret)
+    return x[:, 0], pools, slots
+
+
+def forward_suffix(cfg: WindowMoeConfig, params, ids, pools, tables,
+                   length, n_valid, *, interpret: bool = False):
+    """S new tokens of ONE row atop the `length` tokens its tables map
+    — a multiple of the page (a prompt from nothing: length 0).  ids:
+    (1, S) padded to whole pages, n_valid real.  Returns (hidden (1, S,
+    H), pools)."""
+    S = ids.shape[1]
+    page = pools["full"][0].shape[3]
+    n_p = S // page
+    pos = jnp.minimum(length[:, None] + jnp.arange(S)[None, :],
+                      cfg.max_len - 1).astype(jnp.int32)
+    ok = jnp.arange(S)[None, :] < n_valid                 # (1, S)
+    write = {}
+    for kind, tab in tables.items():
+        bids = jax.lax.dynamic_slice_in_dim(tab[0], length[0] // page,
+                                            n_p)
+
+        def put(pool, new, gl, bids=bids):
+            rows = new[0].reshape(n_p, page, *new.shape[2:])
+            return pool.at[bids, gl].set(rows.transpose(0, 2, 1, 3))
+        write[kind] = put
+    x, pools, _ = _stack(cfg, params, _embed(cfg, params, ids), pos, ok,
+                         pools, write, tables, pos[:, 0] + 1, interpret)
+    return x, pools
+
+
+# ------------------------------------------------------------- front end
+
+class WindowCompletionModel(LatentCompletionModel):
+    """LatentCompletionModel's paged serving surface over the mixed
+    window / global stack and its two page groups."""
+
+    needs_window = True
+    # lane 0: a row that resumed from the prefix cache, lane 1: one
+    # that prefilled from nothing (engine/audit.py)
+    audit_lanes = 2
+    program_prefix = "afmoe"
+    refused_options = {
+        **LatentCompletionModel.refused_options,
+        "kv_dtype": "the page groups are stored in the model's dtype: "
+                    "the int8/int4 page codecs know one pool a layer, "
+                    "not a group's",
+        "kv_tier_pages": "the host tier's page wire carries one page "
+                         "group, and this model keeps two",
+        "phase": "the disaggregated hand-off's page wire carries one "
+                 "page group, and this model keeps two",
+        "tp": "the page groups are not sharded on their kv-head axis; "
+              "attention is data-parallel in this deployment",
+    }
+
+    def __init__(self, cfg: WindowMoeConfig, *, seed: int = 0,
+                 params: Any = None, top_p: float = 0.9,
+                 temp: float = 0.7, interpret: bool = False):
+        super().__init__(
+            cfg, seed=seed,
+            params=init_params(cfg, seed) if params is None else params,
+            top_p=top_p, temp=temp, suffix_buckets=(16,),
+            interpret=interpret)
+        self.audit_rows = [-1] * self.audit_lanes
+        # what the attention kernels were asked to do, in LIVE keys —
+        # running totals the heartbeat carries (benchmark/work_gqa.py
+        # turns them into the kernels' rooflines): keys attended by
+        # the decode steps' and the suffix pieces' real tokens in a
+        # global layer and in a window layer, and the distinct tokens
+        # whose K and V a suffix piece read
+        self.attn_work = dict.fromkeys(
+            ("decode_keys", "decode_window_keys", "prefill_keys",
+             "prefill_window_keys", "prefill_kv", "prefill_window_kv"), 0)
+        self._set_page(128)
+
+    def audit_seat(self, lane: int, row: int) -> None:
+        self.audit_rows[lane] = row
+
+    def _set_page(self, page: int) -> None:
+        """Every suffix bucket is whole pages, each width from one
+        page to SUFFIX_PAGES: a suffix pads by less than a page, a
+        longer one (a cold prompt) loops in the widest."""
+        self.suffix_buckets = tuple(
+            n * page for n in range(1, SUFFIX_PAGES + 1)
+            if n * page < self.cfg.max_len) or (page,)
+        self.buckets = self.suffix_buckets
+
+    def init_paged(self, batch: int, *, page: int = 128,
+                   pool_pages: int | None = None,
+                   kv_dtype: str | None = None,
+                   window_pool_pages: int | None = None) -> PagedKVCache:
+        self._set_page(page)
+        # what a row holds of the window group at the most: the
+        # window, the page its oldest key shares, the widest suffix
+        # program's pages, and the page a decode chunk may run into
+        span = -(-self.cfg.window // page) + 2 \
+            + self.suffix_buckets[-1] // page
+        return PagedKVCache(self.cfg, batch, page=page,
+                            pool_pages=pool_pages, kv_dtype=kv_dtype,
+                            window_pool_pages=window_pool_pages,
+                            window_span=span)
+
+    # -- the two groups' buffers -------------------------------------------
+
+    @staticmethod
+    def _pools(cache: PagedKVCache) -> dict:
+        w = cache.window
+        return {"full": (cache.pools[0][0], cache.pools[1][0]),
+                "window": (w.pools[0][0], w.pools[1][0])}
+
+    @staticmethod
+    def _keep(cache: PagedKVCache, pools: dict) -> None:
+        w = cache.window
+        (cache.pools[0][0], cache.pools[1][0]) = pools["full"]
+        (w.pools[0][0], w.pools[1][0]) = pools["window"]
+
+    @staticmethod
+    def _tables(cache: PagedKVCache, row: int | None = None) -> dict:
+        """Host-side copies (lengths and tables move right after a
+        dispatch: mla.paged_append_prefill)."""
+        rows = slice(None) if row is None else slice(row, row + 1)
+        return {"full": jnp.asarray(np.array(cache.tables[rows])),
+                "window": jnp.asarray(np.array(
+                    cache.window.tables[rows]))}
+
+    # -- prefill -----------------------------------------------------------
+
+    def _suffix_program(self, sb: int):
+        cfg, interp = self.cfg, self.interpret
+
+        def build():
+            def run(params, pools, tables, length, ids, n_valid):
+                x, pools = forward_suffix(cfg, params, ids, pools, tables,
+                                          length, n_valid,
+                                          interpret=interp)
+                last = jax.lax.dynamic_index_in_dim(
+                    x[0], n_valid - 1, 0, keepdims=False)
+                return pools, _head(cfg, params, last)
+            return run
+        return self._program(("suffix", sb), "suffix_prefill", build)
+
+    def paged_prefill_row(self, cache: PagedKVCache,
+                          prompt_ids: np.ndarray, row: int) -> np.ndarray:
+        """A whole prompt from nothing: an empty row, and the suffix
+        program over it."""
+        if len(prompt_ids) == 0:
+            raise ValueError("empty prompt")
+        cache.lengths[row] = 0
+        return self.paged_append_prefill(cache, prompt_ids, row)
+
+    def paged_append_prefill(self, cache: PagedKVCache, suffix_ids,
+                             row: int) -> np.ndarray:
+        """Prefill the suffix of row's prompt atop the
+        cache.lengths[row] tokens its tables map — whole pages of
+        them: a prefix hit maps whole pages and a row from nothing
+        has none.  A piece at a time: each piece's pages are ensured
+        in both groups, and the window group's pages the row has slid
+        past go back before the next.  Returns the last real token's
+        logits (V,)."""
+        ids = np.asarray(suffix_ids, np.int32)
+        if ids.size == 0:
+            raise ValueError("empty suffix")
+        pos, page = int(cache.lengths[row]), cache.page
+        if pos % page:
+            raise ValueError(
+                f"a suffix starts at a page boundary; row {row} holds "
+                f"{pos} tokens")
+        if pos + ids.size >= self.cfg.max_len:
+            raise ValueError("suffix exceeds context window")
+        logits, mark, off = None, None, 0
+        while off < ids.size:
+            rem = ids.size - off
+            sb = next((b for b in self.suffix_buckets if b >= rem),
+                      self.suffix_buckets[-1])
+            n = min(rem, sb)
+            if not cache.ensure(row, pos + off + n):
+                raise RuntimeError(
+                    f"paged pool exhausted: row {row} suffix needs "
+                    f"{cache.pages_needed(pos + off + n)} pages")
+            piece = np.zeros((1, sb), np.int32)
+            piece[0, :n] = ids[off: off + n]
+            pools, logits = self._suffix_program(sb)(
+                self.params, self._pools(cache), self._tables(cache, row),
+                jnp.asarray(np.array(cache.lengths[row: row + 1])),
+                jnp.asarray(piece), jnp.int32(n))
+            close_mark(mark)
+            mark = DEVTIME.take_mark(self._devname("suffix_prefill"))
+            self._keep(cache, pools)
+            ctx = pos + off + 1 + np.arange(n)    # keys a token attends
+            W, aw = self.cfg.window, self.attn_work
+            aw["prefill_keys"] += int(ctx.sum())
+            aw["prefill_window_keys"] += int(np.minimum(ctx, W).sum())
+            aw["prefill_kv"] += pos + off + n
+            aw["prefill_window_kv"] += min(pos + off + n, W - 1 + n)
+            cache.lengths[row] += n
+            off += n
+            cache.release_window(row)
+        out = np.asarray(logits)
+        close_mark(mark)
+        return out
+
+    # -- copy-on-write -----------------------------------------------------
+
+    def _cow_program(self):
+        def build():
+            def run(pools, src, dst):
+                return [p.at[dst].set(p[src]) for p in pools]
+            return run
+        return self._program(("cow",), "cow_copy", build, donate=(0,))
+
+    def _copy_page(self, pools, src: int, dst: int) -> None:
+        """One page of a group, every layer of it, K and V."""
+        pools[0][0], pools[1][0] = self._cow_program()(
+            [pools[0][0], pools[1][0]], jnp.int32(src), jnp.int32(dst))
+
+    def _cow_fixups(self, cache) -> int:
+        """Copy-on-write pass before a decode dispatch, a group at a
+        time: one page copy holds every layer of the group."""
+        n, w = 0, cache.window
+        for row, p_idx in cache.cow_targets():
+            dst = cache._alloc_page()
+            self._copy_page(cache.pools, int(cache.tables[row, p_idx]), dst)
+            cache.commit_cow(row, p_idx, dst)
+            n += 1
+        for row, p_idx in cache.window_cow_targets():
+            dst = w._alloc()
+            self._copy_page(w.pools, int(w.tables[row, p_idx]), dst)
+            w.commit_cow(row, p_idx, dst)
+            n += 1
+        return n
+
+    def _warm_cow(self, cache: PagedKVCache) -> None:
+        w = cache.window
+        for pools, alloc, free in (
+                (cache.pools, cache._alloc_page, cache._decref),
+                (w.pools, w._alloc, w._decref)):
+            src, dst = alloc(), alloc()
+            self._copy_page(pools, src, dst)
+            free(src)
+            free(dst)
+
+    # -- decode ------------------------------------------------------------
+
+    def _chunk_program(self, n: int, bp: int):
+        cfg, interp = self.cfg, self.interpret
+        top_p, temp = self.top_p, self.temp
+
+        def build():
+            def run(params, pools, tables, lengths, rng, fresh,
+                    fresh_mask, carry, audit_rows):
+                toks0 = jnp.where(fresh_mask, fresh, carry)
+                row = jnp.clip(audit_rows, 0, bp - 1)     # a row a lane
+
+                def step(carry_s, _):
+                    pools, lengths, rng, toks, slots = carry_s
+                    x, pools, s = forward_decode(
+                        cfg, params, toks, pools, tables, lengths,
+                        interpret=interp)
+                    logits = _head(cfg, params, x)
+                    rng, sub = jax.random.split(rng)
+                    nxt = _sample_rows(sub, logits, top_p, temp)
+                    return ((pools, lengths + 1, rng, nxt, slots + s),
+                            (nxt, logits[row]))
+
+                zero = jnp.zeros((max(cfg.experts_held, 1),), jnp.int32)
+                (pools, _, _, _, slots), (out, kept) = jax.lax.scan(
+                    step, (pools, lengths, rng, toks0, zero), None,
+                    length=n)
+                return pools, out, out[-1], slots, kept
+            return run
+        return self._program(("chunk", n, bp, top_p, temp),
+                             "paged_chunk", build)
+
+    def paged_decode_chunk_async(self, cache: PagedKVCache, tokens,
+                                 n: int, carry=None
+                                 ) -> LatentPendingChunk:
+        bp = cache.batch
+        fresh_mask, toks, carry = self._chunk_inputs(cache, tokens, n,
+                                                     carry)
+        self._rng, sub = jax.random.split(self._rng)
+        live = cache.lengths[cache.lengths > 0].astype(np.int64)
+        ctx = live[:, None] + 1 + np.arange(n)[None, :]
+        self.attn_work["decode_keys"] += int(ctx.sum())
+        self.attn_work["decode_window_keys"] += int(
+            np.minimum(ctx, self.cfg.window).sum())
+        pools, out, last, slots, kept = self._chunk_program(n, bp)(
+            self.params, self._pools(cache), self._tables(cache),
+            jnp.asarray(np.array(cache.lengths)), sub, jnp.asarray(toks),
+            jnp.asarray(fresh_mask), carry,
+            jnp.asarray(self.audit_rows, jnp.int32))
+        self._keep(cache, pools)
+        self._advance(cache, n)
+        # the chunk holds its own copy of the tables: what its rows
+        # slid past goes back now, and a later dispatch that takes the
+        # pages runs after it on the device
+        cache.release_window()
+        return LatentPendingChunk(
+            out, last, n, DEVTIME.take_mark(self._devname("paged_chunk")),
+            slots, kept)
+
+    # -- warm-up -----------------------------------------------------------
+
+    def _warmup_paged_impl(self, cache: PagedKVCache, chunk: int,
+                           max_prompt: int | None) -> None:
+        """Every program the lane can dispatch: the suffix widths, the
+        decode chunk, the page copy of each group."""
+        chunk_done = False
+        for sb in self.suffix_buckets:
+            n = max(1, min(sb, self.cfg.max_len - 1 - chunk))
+            self.sample(self.paged_prefill_row(
+                cache, np.ones((n,), np.int32), 0))
+            if not chunk_done and n + chunk < self.cfg.max_len:
+                self.paged_decode_chunk(
+                    cache, np.ones((cache.batch,), np.int32), chunk)
+                chunk_done = True
+            cache.free_row(0)
+        self._warm_cow(cache)

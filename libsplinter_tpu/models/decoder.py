@@ -25,6 +25,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import os
+import time
 from typing import Any
 
 import jax
@@ -253,10 +254,21 @@ class PageLayout:
     recurrent matrix and convolution tail (models/kda.py).  Such a
     layer usually has no pools at all; the cache keeps its arrays in
     STATE SLOTS (PagedKVCache).  A model whose layers differ hands
-    the cache one layout a layer."""
+    the cache one layout a layer.
+
+    `window`: the layer attends the last `window` tokens only (0: all
+    of them).  Layouts with a window form the cache's WINDOW GROUP
+    (PagedKVCache): pools, page count, table and free list of their
+    own, and pages that go back as the row slides past them.
+    `layers`: how many of the model's layers the layout's pools hold
+    side by side in one page (its block shape then leads with that
+    axis) — models/afmoe.py describes a GROUP of layers at once, so
+    that one table entry names a page of each."""
     pools: tuple[tuple[str, tuple[int, ...]], ...]
     token_values: int
     state: tuple[tuple[str, tuple[int, ...], Any], ...] = ()
+    window: int = 0
+    layers: int = 1
 
     @property
     def key_value(self) -> bool:
@@ -270,6 +282,171 @@ def kv_page_layout(cfg, page: int, packed: bool = False) -> PageLayout:
              cfg.head_dim // 2 if packed else cfg.head_dim)
     return PageLayout((("k", shape), ("v", shape)),
                       token_values=2 * cfg.kv_heads * cfg.head_dim)
+
+
+class WindowPages:
+    """The WINDOW GROUP of a paged cache: the pages of the layers that
+    attend a sliding window (PageLayout.window), kept apart from the
+    global layers' because they weigh otherwise (24 layers a page
+    against 8 in models/afmoe.py) and live a fraction as long.  The
+    discipline is the global group's — block 0 the trash block, a
+    (batch, pages_per_row) table, refcounts, a free list, zero-ref
+    pages that the prefix tree retains until the allocator wants them
+    — with one difference: a row holds pages for a SPAN of its table,
+    `[lo, hi)`, not a prefix.  A prefix hit maps the tail it resumes
+    on (`map_tail`), `ensure` extends the span forward, and `release`
+    gives back every page that lies wholly behind the window of the
+    row's next token — its table entry returns to the trash block and
+    is never read again (ops/paged_attention.window_paged_attention
+    walks from the first live page).
+
+    `span` is the most pages a row holds at once — the window, the
+    page its oldest key shares, and the widest program's new tokens —
+    so `ensure` never runs further ahead than that: a cold prompt
+    longer than the window passes through, a piece at a time."""
+
+    def __init__(self, cache, layout: PageLayout, pool_pages: int,
+                 span: int | None, dtype):
+        self.cache = cache
+        self.window = int(layout.window)
+        self.page = cache.page
+        self.window_pages = -(-self.window // self.page)
+        self.span = int(span) if span else self.window_pages + 2
+        if pool_pages < self.span:
+            raise ValueError(
+                f"window_pool_pages {pool_pages} cannot hold even one "
+                f"row's span ({self.span} pages)")
+        self.layout = layout
+        self.n_blocks = pool_pages + 1
+        self.pools = [[jnp.zeros((self.n_blocks, *block), dtype)]
+                      for _, block in layout.pools]
+        self.tables = np.zeros((cache.batch, cache.pages_per_row),
+                               np.int32)
+        self.refcounts = np.zeros((self.n_blocks,), np.int64)
+        self._free = list(range(self.n_blocks - 1, 0, -1))
+        self._lo = np.zeros((cache.batch,), np.int64)
+        self._hi = np.zeros((cache.batch,), np.int64)
+        self.released = 0             # pages given back as rows slid
+        self.release_s = 0.0          # host seconds spent doing so
+        self.used_peak = 0
+
+    @property
+    def free_pages(self) -> int:
+        return len(self._free)
+
+    @property
+    def used_pages(self) -> int:
+        return self.n_blocks - 1 - len(self._free)
+
+    @property
+    def live_pages(self) -> int:
+        """Pages some row's table names (the tree's zero-ref ones
+        left out)."""
+        return int((self.refcounts > 0).sum())
+
+    @property
+    def available_pages(self) -> int:
+        pc = self.cache.prefix_cache
+        extra = pc.window_evictable_count() if pc is not None else 0
+        return len(self._free) + extra
+
+    def first_live(self, length: int) -> int:
+        """The page of the oldest key the row's NEXT token (position
+        `length`) attends: every page before it is dead for good."""
+        return max(0, int(length) - self.window + 1) // self.page
+
+    def join_pages(self, match: int, tokens: int) -> int:
+        """Pages a row needs at the most while it joins at `match`
+        mapped tokens and runs to `tokens`."""
+        return min(self.cache.pages_needed(tokens)
+                   - self.first_live(match), self.span)
+
+    def _alloc(self) -> int:
+        if not self._free:
+            pc = self.cache.prefix_cache
+            if pc is None or not pc.reclaim_window(1):
+                raise RuntimeError("paged pool exhausted (window group)")
+        bid = self._free.pop()
+        self.refcounts[bid] = 1
+        return bid
+
+    def _decref(self, bid: int) -> None:
+        self.refcounts[bid] -= 1
+        if self.refcounts[bid] < 0:
+            raise RuntimeError(f"window page {bid} refcount underflow")
+        if self.refcounts[bid] == 0:
+            pc = self.cache.prefix_cache
+            if pc is None or not pc.on_window_zero_ref(bid):
+                self._free.append(bid)
+
+    def map_tail(self, row: int, first: int, bids) -> None:
+        """Point `row`'s entries `first`.. at pages the prefix tree
+        holds: the tail a hit resumes on."""
+        if self._hi[row] > self._lo[row]:
+            raise ValueError("a tail is mapped into an empty row")
+        pc = self.cache.prefix_cache
+        for i, bid in enumerate(bids):
+            bid = int(bid)
+            if bid <= 0 or bid >= self.n_blocks:
+                raise ValueError(f"bad shared window page id {bid}")
+            self.refcounts[bid] += 1
+            if self.refcounts[bid] == 1 and pc is not None:
+                pc.on_window_ref(bid)
+            self.tables[row, first + i] = bid
+        self._lo[row], self._hi[row] = first, first + len(bids)
+
+    def ensure(self, row: int, length: int, tokens: int) -> bool:
+        """Extend row's span to cover what a row of `length` tokens
+        needs to reach `tokens`, at most `span` pages from its first
+        live one; False (nothing allocated) when the pool cannot."""
+        lo = self.first_live(length)
+        need = min(self.cache.pages_needed(tokens), lo + self.span)
+        if self._hi[row] <= self._lo[row]:
+            self._lo[row] = self._hi[row] = lo
+        have = int(self._hi[row])
+        if need <= have:
+            return True
+        if need - have > self.available_pages:
+            return False
+        for p in range(have, need):
+            self.tables[row, p] = self._alloc()
+        self._hi[row] = need
+        self.used_peak = max(self.used_peak, self.used_pages)
+        return True
+
+    def release(self, row: int, length: int) -> int:
+        """Give back row's pages wholly behind the window of position
+        `length`.  Returns how many."""
+        lo, upto = int(self._lo[row]), min(self.first_live(length),
+                                           int(self._hi[row]))
+        for p in range(lo, upto):
+            self._decref(int(self.tables[row, p]))
+            self.tables[row, p] = 0
+        if upto > lo:
+            self._lo[row] = upto
+            self.released += upto - lo
+        return max(upto - lo, 0)
+
+    def free_row(self, row: int) -> None:
+        for p in range(int(self._lo[row]), int(self._hi[row])):
+            self._decref(int(self.tables[row, p]))
+        self.tables[row, :] = 0
+        self._lo[row] = self._hi[row] = 0
+
+    def cow_target(self, row: int, p_idx: int) -> bool:
+        """The page row's next append lands in is one the tree or
+        another row also reads."""
+        if not self._lo[row] <= p_idx < self._hi[row]:
+            return False
+        bid = int(self.tables[row, p_idx])
+        pc = self.cache.prefix_cache
+        return bid > 0 and (self.refcounts[bid] > 1 or (
+            pc is not None and pc.retains_window(bid)))
+
+    def commit_cow(self, row: int, p_idx: int, new_bid: int) -> None:
+        old = int(self.tables[row, p_idx])
+        self.tables[row, p_idx] = new_bid
+        self._decref(old)
 
 
 class PagedKVCache:
@@ -341,6 +518,14 @@ class PagedKVCache:
     `kv_bytes_per_token`.  A model without state has zero slots and
     none of this runs.
 
+    PAGE GROUPS.  Where some layers' layouts name a `window`
+    (PageLayout.window), those layers' pages form a second group,
+    `window` (WindowPages): `tables`, `refcounts`, the free list and
+    `pool_pages` here stay the GLOBAL group's; `ensure`, `free_row`
+    and the copy-on-write pass work both, `release_window` gives a
+    window layer's pages back as its row slides.  A model without a
+    window has no such group and none of this runs.
+
     `sharding` (a NamedSharding, normally P(None, "tp", None, None)
     from ShardedCompletionModel) places the pools sharded on their
     KV-HEAD axis across a tensor-parallel mesh: each device holds
@@ -358,7 +543,9 @@ class PagedKVCache:
                  page: int = 128, pool_pages: int | None = None,
                  kv_dtype: str | None = None,
                  sharding=None, scale_sharding=None,
-                 state_snapshots: int | None = None):
+                 state_snapshots: int | None = None,
+                 window_pool_pages: int | None = None,
+                 window_span: int | None = None):
         if page < 1:
             raise ValueError("page must be >= 1")
         self.cfg = cfg
@@ -410,12 +597,16 @@ class PagedKVCache:
         # a layout a layer; the layers that keep pages share ONE page
         # layout (`layout`), the others keep state only
         self.layouts = tuple(layouts)
-        paged = [lo for lo in self.layouts if lo.pools]
-        if len(self.layouts) != cfg.layers or not paged \
-                or any(lo.pools != paged[0].pools for lo in paged):
+        windowed = [lo for lo in self.layouts if lo.pools and lo.window]
+        paged = [lo for lo in self.layouts if lo.pools and not lo.window]
+        if sum(lo.layers for lo in self.layouts) != cfg.layers \
+                or not paged \
+                or any(lo.pools != paged[0].pools for lo in paged) \
+                or len(windowed) > 1:
             raise ValueError(
-                "the model must describe one layout a layer, and the "
-                "layers that keep pages the same pools")
+                "the model must describe one layout a layer, the "
+                "layers that keep pages the same pools, and at most "
+                "one window group beside layers that see everything")
         self.layout = paged[0]
         self.paged_layers = len(paged)
         # distinct buffers per layer/pool: the paged programs donate
@@ -461,6 +652,13 @@ class PagedKVCache:
         self.refcounts = np.zeros((self.n_blocks,), np.int64)
         self.prefix_cache = None
         self._ever_shared = False
+        # the window group (class docstring), sized like the global
+        # one where nobody says otherwise
+        self.window = WindowPages(
+            self, windowed[0],
+            pool_pages if window_pool_pages is None
+            else int(window_pool_pages), window_span,
+            store_dtype) if windowed else None
 
     # the key/value layout's two pools by name (every llama-geometry
     # program reads and reassigns them)
@@ -566,6 +764,34 @@ class PagedKVCache:
                 out.append((r, p_idx))
         return out
 
+    def window_cow_targets(self) -> list[tuple[int, int]]:
+        """cow_targets for the window group's table."""
+        w = self.window
+        if w is None or (not self._ever_shared
+                         and self.prefix_cache is None):
+            return []
+        out = []
+        for r in range(self.batch):
+            length = int(self.lengths[r])
+            p_idx = min(length, self.cfg.max_len - 1) // self.page
+            if length > 0 and w.cow_target(r, p_idx):
+                out.append((r, p_idx))
+        return out
+
+    def release_window(self, row: int | None = None) -> int:
+        """Give back the window group's pages that lie wholly behind
+        the window of `row`'s (every live row's) next token: after a
+        prefill piece, after a decode chunk.  Returns how many."""
+        w = self.window
+        if w is None:
+            return 0
+        t0 = time.perf_counter()
+        rows = range(self.batch) if row is None else (row,)
+        n = sum(w.release(r, int(self.lengths[r])) for r in rows
+                if self.lengths[r] > 0)
+        w.release_s += time.perf_counter() - t0
+        return n
+
     def commit_cow(self, row: int, p_idx: int, new_bid: int) -> None:
         """Host half of a copy-on-write: swap the row's table entry to
         the freshly copied private page and drop its reference on the
@@ -631,6 +857,8 @@ class PagedKVCache:
         value.  (A layer that keeps state costs a token nothing:
         state_slot_bytes.)"""
         values = self.paged_layers * self.layout.token_values
+        if self.window is not None:
+            values += self.window.layout.token_values
         if self.packed:
             return values // 2
         return values * np.dtype(self.pools[0][0].dtype).itemsize
@@ -651,9 +879,12 @@ class PagedKVCache:
         prefix-cache pages when it runs dry."""
         need = self.pages_needed(tokens)
         have = len(self._owned[row])
-        if need <= have:
-            return True
-        if need - have > self.available_pages:
+        if need > have and need - have > self.available_pages:
+            return False
+        # the window group covers the row's live span only, and at
+        # most its `span` ahead (WindowPages.ensure)
+        if self.window is not None and not self.window.ensure(
+                row, int(self.lengths[row]), tokens):
             return False
         for p in range(have, need):
             bid = self._alloc_page()
@@ -671,6 +902,8 @@ class PagedKVCache:
         self._owned[row] = []
         self.tables[row, :] = 0
         self.lengths[row] = 0
+        if self.window is not None:
+            self.window.free_row(row)
 
     def reset(self) -> None:
         for r in range(self.batch):
@@ -688,6 +921,8 @@ class PagedKVCache:
         buffers; under tp each chip holds 1/tp — the per-shard view
         rides the completer's pages_shard section)."""
         arrs = [a for pool in self.pools for a in pool]
+        if self.window is not None:
+            arrs += [a for pool in self.window.pools for a in pool]
         arrs += [a for layer in self.states for a in layer]
         if self.quantized:
             arrs += list(self.k_scales) + list(self.v_scales)

@@ -193,19 +193,26 @@ _COMMON = {
     "hidden_size": Key("hidden"),
     "num_attention_heads": Key("heads"),
     "num_key_value_heads": Key(None, None),
+    "intermediate_size": Key("dense_mlp_dim"),
+    "moe_intermediate_size": Key("moe_mlp_dim"),
+    "rms_norm_eps": Key("rms_eps", 1e-5, float),
+    "num_hidden_layers": Key(None),
+    "vocab_size": Key(None),
+}
+# what the two latent-attention families share beside _COMMON
+_LATENT = {
     "kv_lora_rank": Key("kv_lora_rank"),
     "qk_nope_head_dim": Key("qk_nope_head_dim"),
     "qk_rope_head_dim": Key("qk_rope_head_dim"),
     "v_head_dim": Key("v_head_dim"),
-    "intermediate_size": Key("dense_mlp_dim"),
-    "moe_intermediate_size": Key("moe_mlp_dim"),
     "routed_scaling_factor": Key("routed_scaling_factor", 1.0, float),
-    "rms_norm_eps": Key("rms_eps", 1e-5, float),
-    "num_hidden_layers": Key(None),
     "first_k_dense_replace": Key(None, 0),
-    "vocab_size": Key(None),
     "num_nextn_predict_layers": Key(None, 0),
 }
+_ONE_GROUP = dict(default=1, only=(1,),
+                  why="group-limited routing is not served: n_group, "
+                      "topk_group, num_expert_groups and "
+                      "num_limited_groups must be 1")
 _SHARED_EXPERT = dict(default=0, only=(0, 1),
                       why="0 or 1 shared expert is served")
 FAMILIES: dict[str, dict] = {
@@ -213,7 +220,7 @@ FAMILIES: dict[str, dict] = {
     "pangu_ultra_moe": {
         "window": "max_position_embeddings", "experts": "n_routed_experts",
         "keys": {
-            **_COMMON,
+            **_COMMON, **_LATENT,
             "max_position_embeddings": Key(None, None),
             "q_lora_rank": Key("q_lora_rank"),
             "n_routed_experts": Key("n_routed_experts"),
@@ -228,7 +235,7 @@ FAMILIES: dict[str, dict] = {
     "kimi_linear": {
         "window": "model_max_length", "experts": "num_experts",
         "keys": {
-            **_COMMON,
+            **_COMMON, **_LATENT,
             "model_max_length": Key(None, None),
             "head_dim": Key(None, None),     # the config's, unused by the block
             "q_lora_rank": Key(None, None, lambda v: v, only=(None,),
@@ -262,7 +269,40 @@ FAMILIES: dict[str, dict] = {
                                   "served: topk_group 1"),
             "use_grouped_topk": Key(None, False, bool),
         }},
+    # the AFMoE key set (Trinity-Mini): models/afmoe.py
+    "afmoe": {
+        "window": "max_position_embeddings", "experts": "num_experts",
+        "dense": "num_dense_layers",
+        "keys": {
+            **_COMMON,
+            "max_position_embeddings": Key(None, None),
+            "num_key_value_heads": Key("kv_heads"),
+            "head_dim": Key("head_dim"),
+            "layer_types": Key(None, cast=list),
+            "global_attn_every_n_layers": Key(None),
+            "sliding_window": Key("window"),
+            "num_dense_layers": Key(None, 0),
+            "mup_enabled": Key("mup", False, bool),
+            "rope_theta": Key("rope_base", 10000.0, float),
+            "rope_scaling": Key(None, None, lambda v: v, only=(None,),
+                                why="rope_scaling is not served"),
+            "num_experts": Key("n_routed_experts"),
+            "num_experts_per_tok": Key("top_k"),
+            "num_shared_experts": Key("n_shared_experts",
+                                      **_SHARED_EXPERT),
+            "route_norm": Key("norm_topk_prob", True, bool),
+            "route_scale": Key("routed_scaling_factor", 1.0, float),
+            "score_func": Key("score_fn", "sigmoid", str),
+            "n_group": Key(None, **_ONE_GROUP),
+            "topk_group": Key(None, **_ONE_GROUP),
+            "num_expert_groups": Key(None, **_ONE_GROUP),
+            "num_limited_groups": Key(None, **_ONE_GROUP),
+            # training and implementation hints: accepted, unused
+            "load_balance_coeff": Key(None, None, float),
+            "use_grouped_mm": Key(None, None, bool),
+        }},
 }
+LAYER_TYPES = {"sliding_attention": "window", "full_attention": "full"}
 LINEAR_ATTN_KEYS = frozenset(("full_attn_layers", "kda_layers", "head_dim",
                               "num_heads", "short_conv_kernel_size"))
 
@@ -304,14 +344,32 @@ def _finish_hybrid(arch, fields, path):
         conv_kernel=int(lin["short_conv_kernel_size"]), **fields)
 
 
+def _finish_window(arch, fields, path):
+    from .afmoe import WindowMoeConfig
+    n_layers = int(arch["num_hidden_layers"])
+    types, every = arch["layer_types"], int(
+        arch["global_attn_every_n_layers"])
+    if len(types) != n_layers or set(types) - set(LAYER_TYPES) or any(
+            (t == "full_attention") != ((i + 1) % every == 0)
+            for i, t in enumerate(types)):
+        raise ValueError(
+            f"{path}: layer_types must name {n_layers} layers, each "
+            f"among {sorted(LAYER_TYPES)}, every "
+            f"global_attn_every_n_layers-th ({every}) full_attention")
+    # the share keeps the first `layers`
+    kinds = tuple(LAYER_TYPES[t] for t in types[:fields.pop("layers")])
+    return WindowMoeConfig(kinds=kinds, model_layers=n_layers, **fields)
+
+
 FAMILIES["pangu_ultra_moe"]["finish"] = _finish_latent
 FAMILIES["kimi_linear"]["finish"] = _finish_hybrid
+FAMILIES["afmoe"]["finish"] = _finish_window
 
 
 def load_model_description(path: str, *, max_len: int | None = None):
-    """A model description file -> (config, seed): a LatentMoeConfig
-    or a models/kda.HybridMoeConfig, by the architecture's
-    `model_type` (FAMILIES).
+    """A model description file -> (config, seed): a LatentMoeConfig,
+    a models/kda.HybridMoeConfig or a models/afmoe.WindowMoeConfig, by
+    the architecture's `model_type` (FAMILIES).
 
     {"architecture": {published keys verbatim, at their published
                       values},
@@ -359,7 +417,8 @@ def load_model_description(path: str, *, max_len: int | None = None):
         if key.field is not None:
             fields[key.field] = value
     n_layers = int(arch["num_hidden_layers"])
-    first_dense = int(arch.get("first_k_dense_replace", 0))
+    first_dense = int(arch.get(
+        family.get("dense", "first_k_dense_replace"), 0))
     layers = int(share.get("layers", n_layers))
     dense = int(share.get("dense_layers", min(first_dense, layers)))
     e_first, e_held = share.get("experts", [0, fields["n_routed_experts"]])
@@ -384,6 +443,9 @@ def completion_model_class(cfg):
     """The paged serving model of a loaded description's config."""
     if isinstance(cfg, LatentMoeConfig):
         return LatentCompletionModel
+    from .afmoe import WindowCompletionModel, WindowMoeConfig
+    if isinstance(cfg, WindowMoeConfig):
+        return WindowCompletionModel
     from .kda import HybridCompletionModel
     return HybridCompletionModel
 
@@ -574,10 +636,14 @@ def _absorbed_attention(cfg: LatentMoeConfig, lp, q_nope, q_rope, pool,
     return jnp.einsum("bshr,rhd->bshd", o_lat, w_uv)
 
 
-def _ffn(cfg: LatentMoeConfig, lp, x, live, interpret: bool):
+def _ffn(cfg: LatentMoeConfig, lp, x, live, interpret: bool, bank=None,
+         route_x=None):
     """The layer's feed-forward on normed x: dense SwiGLU, or the
-    shared expert + this share of the routed ones.  Returns (out,
-    slots each held expert received | None)."""
+    shared expert + this share of the routed ones (bank: the layer's
+    index in expert tensors that stack several layers'; route_x: x
+    before it was rounded to the model's dtype, for the router:
+    moe.sparse_moe).  Returns (out, slots each held expert received |
+    None)."""
     if "router" not in lp:
         return jnp.dot(jax.nn.silu(jnp.dot(x, lp["w_gate"]))
                        * jnp.dot(x, lp["w_up"]), lp["w_down"]), None
@@ -588,7 +654,8 @@ def _ffn(cfg: LatentMoeConfig, lp, x, live, interpret: bool):
         x, lp["router"], lp["exp_gate"], lp["exp_up"], lp["exp_down"],
         top_k=cfg.top_k, first=cfg.experts_first, score=cfg.score_fn,
         norm_topk=cfg.norm_topk_prob, scale=cfg.routed_scaling_factor,
-        shared=shared, live=live, interpret=interpret)
+        shared=shared, live=live, interpret=interpret, bank=bank,
+        route_x=route_x)
 
 
 def _layer(cfg: LatentMoeConfig, lp, x, pos, attend, live,
@@ -720,6 +787,8 @@ class LatentCompletionModel:
 
     paged_supported = True
     audit_supported = True
+    # rows whose logits a decode chunk keeps (engine/audit.py)
+    audit_lanes = 1
     # what a device trace shows the programs as: jit_<prefix>_<short>
     # (benchmark/readers count on it)
     program_prefix = "latent"
@@ -762,6 +831,11 @@ class LatentCompletionModel:
         # the batch row whose per-step logits the next decode chunks
         # keep (LatentPendingChunk.audit); -1: none
         self.audit_row = -1
+
+    def audit_seat(self, lane: int, row: int) -> None:
+        """The batch row `lane` audits from the next chunk on (-1:
+        none)."""
+        self.audit_row = row
 
     def resident_bytes(self) -> int:
         return sum(a.nbytes for a in jax.tree_util.tree_leaves(

@@ -126,13 +126,13 @@ def grouped_matmul(lhs, rhs, group_sizes, *, interpret: bool = False):
 
 
 def _dispatch(x, ids, gates, live, wg, wu, wd, first: int,
-              interpret: bool):
+              interpret: bool, bank=None):
     """One chunk of tokens through the held experts.  x: (T, H);
     ids/gates: (T, k); live: (T,) bool.  Returns ((T, H) f32 partial
     sum, (count,) int32 slots each held expert received)."""
     T, H = x.shape
     k = ids.shape[1]
-    count = wg.shape[0]
+    count = wg.shape[-3]
     local = ids - first
     held = (local >= 0) & (local < count) & live[:, None]
     # slots sorted by held expert; the rest sort behind under `count`
@@ -145,9 +145,21 @@ def _dispatch(x, ids, gates, live, wg, wu, wd, first: int,
     order = order[:rows]
     tok = order // k
     xs = x[tok]                                              # (rows, H)
-    h = grouped_matmul(xs, wg, sizes, interpret=interpret)
-    u = grouped_matmul(xs, wu, sizes, interpret=interpret)
-    y = grouped_matmul((nn.silu(h) * u).astype(x.dtype), wd, sizes,
+    groups = sizes
+    if bank is not None:
+        # the layer's experts are bank `bank` of a stack of layers'
+        # (banks, count, ...): every other bank an empty group, so
+        # that the grouped product reads the stack where it lies — a
+        # slice of it would be copied for the kernel at every layer
+        banks = wg.shape[0]
+        groups = jax.lax.dynamic_update_slice(
+            jnp.zeros((banks * count,), jnp.int32), sizes,
+            (bank * count,))
+        wg, wu, wd = (w.reshape(banks * count, *w.shape[2:])
+                      for w in (wg, wu, wd))
+    h = grouped_matmul(xs, wg, groups, interpret=interpret)
+    u = grouped_matmul(xs, wu, groups, interpret=interpret)
+    y = grouped_matmul((nn.silu(h) * u).astype(x.dtype), wd, groups,
                        interpret=interpret)
     # rows past the last group belong to no expert held here, and the
     # grouped product leaves them unwritten: mask, do not multiply
@@ -160,7 +172,7 @@ def _dispatch(x, ids, gates, live, wg, wu, wd, first: int,
 def sparse_moe(x, router, wg, wu, wd, *, top_k: int, first: int = 0,
                score: str = "softmax", norm_topk: bool = True,
                scale: float = 1.0, shared=None, live=None,
-               interpret: bool = False):
+               interpret: bool = False, bank=None, route_x=None):
     """The expert layer over the experts held here.
 
     x: (..., H) activations; router: (H, E) over ALL E experts of the
@@ -169,7 +181,14 @@ def sparse_moe(x, router, wg, wu, wd, *, top_k: int, first: int = 0,
     down (Ms, H)) of an expert every token passes through, counted
     here in full; live: None or a bool mask of x's leading shape —
     tokens outside it are routed nowhere and counted nowhere (the
-    dead rows of a paged decode step).
+    dead rows of a paged decode step); bank: None, or the int32
+    index (traced or not) of THIS layer in wg/wu/wd that stack several
+    layers' experts as (banks, count, ...) — a stack of identical
+    layers under one scanned body (models/afmoe.py); route_x: None,
+    or x as the ROUTER sees it, where the caller has it unrounded
+    (float32, x's shape): which experts a token takes is the layer's
+    one discontinuous decision, and a rounding of its input flips it
+    where two experts score alike.
     Returns (out (..., H) in x's dtype — the shared expert plus the
     gated sum over the HELD experts among each token's top-k, (count,)
     int32 — the slots each held expert received)."""
@@ -177,11 +196,12 @@ def sparse_moe(x, router, wg, wu, wd, *, top_k: int, first: int = 0,
     x2 = x.reshape(-1, H)
     T = x2.shape[0]
     live2 = jnp.ones((T,), bool) if live is None else live.reshape(-1)
-    ids, gates = router_gates(x2, router, top_k=top_k, score=score,
-                              norm_topk=norm_topk, scale=scale)
+    ids, gates = router_gates(
+        x2 if route_x is None else route_x.reshape(-1, H), router,
+        top_k=top_k, score=score, norm_topk=norm_topk, scale=scale)
     if T <= MOE_CHUNK_TOKENS:
         out, sizes = _dispatch(x2, ids, gates, live2, wg, wu, wd,
-                               first, interpret)
+                               first, interpret, bank)
     else:
         n = -(-T // MOE_CHUNK_TOKENS)
         pad = n * MOE_CHUNK_TOKENS - T
@@ -191,7 +211,7 @@ def sparse_moe(x, router, wg, wu, wd, *, top_k: int, first: int = 0,
             return a.reshape(n, MOE_CHUNK_TOKENS, *a.shape[1:])
 
         out, sizes = jax.lax.map(
-            lambda c: _dispatch(*c, wg, wu, wd, first, interpret),
+            lambda c: _dispatch(*c, wg, wu, wd, first, interpret, bank),
             (chunks(x2), chunks(ids), chunks(gates), chunks(live2)))
         out = out.reshape(-1, H)[:T]
         sizes = sizes.sum(0)

@@ -325,13 +325,15 @@ def dequantize_pool(pool, scales):
     return pool.astype(jnp.float32) * scales[:, :, None, None]
 
 
-def _paged_ref(q, k_pool, v_pool, tables, lengths):
+def _paged_ref(q, k_pool, v_pool, tables, lengths, starts=None):
     """Reference math: gather every table page into a dense
     (B, KH, P*page, D) view and run the masked softmax — the
-    correctness mirror the kernel is pinned against (and the non-TPU
+    correctness mirror the kernels are pinned against (and the non-TPU
     serving path; XLA fuses the gather fine on CPU).  q may be
     (B, H, D) (single decode token) or (B, S, H, D) (multi-query
-    verify: token t attends j < lengths + t)."""
+    verify: token t attends j < lengths + t).  `starts` (B,), where
+    given, is each row's FIRST LIVE KEY (window_paged_attention):
+    token t attends starts + t <= j only."""
     squeeze = q.ndim == 3
     if squeeze:
         q = q[:, None]
@@ -349,6 +351,9 @@ def _paged_ref(q, k_pool, v_pool, tables, lengths):
         kseq.astype(jnp.float32)) / np.sqrt(D)
     valid = jnp.arange(T)[None, None, :] \
         < (lengths[:, None, None] + jnp.arange(S)[None, :, None])
+    if starts is not None:
+        valid &= jnp.arange(T)[None, None, :] \
+            >= (starts[:, None, None] + jnp.arange(S)[None, :, None])
     logits = jnp.where(valid[:, :, None, None, :], logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bskrt,bktd->bskrd", probs.astype(vseq.dtype),
@@ -485,3 +490,285 @@ def paged_attention(q, k_pool, v_pool, tables, lengths, *,
     return _paged_host(q, k_pool, v_pool, tables, lengths,
                        k_scales, v_scales,
                        interpret=interpret, force_pallas=force_pallas)
+
+
+# ------------------------------------------------- page groups, windows
+#
+# A model whose layers MIX sliding-window and global attention
+# (models/afmoe.py) keeps its pages in GROUPS — the global layers one
+# pool, the window layers another, each with its own block table
+# (models/decoder.PagedKVCache) — and a group's pool holds all of its
+# layers side by side in a page:
+#
+#     k_pool / v_pool: (n_blocks, L, KH, page, D)        a group
+#
+# so that one table entry names a page of every layer of the group, a
+# page copy is one copy, and a stack of identical layers can run as
+# ONE compiled body that is told its layer by a scalar.  The kernel
+# below reads such a pool.  It differs from `_paged_kernel` in three
+# things: every row carries its FIRST LIVE KEY (`starts`: a window
+# layer's `length - window`, which may be negative; a global layer's
+# lies below every key), the page axis of the grid WALKS FROM the
+# first live page instead of page 0 — a window layer's grid is
+# ceil((window + tokens) / page) + 1 pages long whatever the context,
+# and the pages behind the window, which the cache has given back and
+# whose table entries name the trash block, are never gathered — and
+# one program carries `hb` kv heads (all of them for a decode step:
+# the grid step, not the page's bytes, is what a 32 KiB page costs).
+
+# query rows (tokens x heads of a kv group) one program holds
+WINDOW_Q_ROWS = 1024
+NO_START = -(1 << 30)
+
+
+def stack_block(q_tokens: int, rep: int) -> int:
+    """Query tokens one program of the stack kernel carries: the
+    largest divisor of q_tokens that keeps tokens x rep at or under
+    WINDOW_Q_ROWS."""
+    t = max(1, min(q_tokens, WINDOW_Q_ROWS // max(rep, 1)))
+    while q_tokens % t:
+        t -= 1
+    return t
+
+
+def window_walk_pages(window: int, page: int, block_tokens: int) -> int:
+    """Pages a query block of `block_tokens` tokens of a window layer
+    can touch: window + block_tokens - 1 consecutive keys, at any
+    offset in their first page."""
+    return -(-(window + block_tokens - 1) // page) + 1
+
+
+def _window_kernel(tab_ref, len_ref, start_ref, layer_ref, q_ref, k_ref,
+                   v_ref, out_ref, m_s, l_s, acc_s, *, page: int,
+                   scale: float, rep: int, block_tokens: int,
+                   n_table: int):
+    """One (batch row, kv-head block, query block, walked page)
+    program.
+
+      tab_ref: (B, P) SMEM block table;  len_ref / start_ref: (B,)
+      SMEM — the row's first query attends start <= j < length;
+      layer_ref: (1,) SMEM, the layer of the group's pool
+      q_ref:   (1, hb, R, D), R = block_tokens * rep, token-major
+      k_ref/v_ref: (1, 1, hb, page, D) the page the walk routed here
+      out_ref: (1, hb, R, D)
+      m_s/l_s: (hb, R, 1) f32;  acc_s: (hb, R, D) f32
+
+    Query token t of the whole stack attends start + t <= j <
+    length + t.  The walk's page w of query block qb is page
+    max(0, start + qb * block_tokens) // page + w of the row."""
+    b = pl.program_id(0)
+    qb = pl.program_id(2)
+    w = pl.program_id(3)
+    n_walk = pl.num_programs(3)
+    length = len_ref[b]
+    start = start_ref[b]
+    t0 = qb * block_tokens
+    first = jnp.maximum(start + t0, 0) // page
+    pi = first + w
+    hb, R = q_ref.shape[1], q_ref.shape[2]
+
+    @pl.when(w == 0)
+    def _init():
+        m_s[...] = jnp.full_like(m_s, NEG_INF)
+        l_s[...] = jnp.zeros_like(l_s)
+        acc_s[...] = jnp.zeros_like(acc_s)
+
+    # the block's last token attends j < length + t0 + block_tokens - 1
+    @pl.when(jnp.logical_and(
+        pi < n_table, pi * page < length + t0 + (block_tokens - 1)))
+    def _accumulate():
+        j = pi * page + jax.lax.broadcasted_iota(jnp.int32, (R, page), 1)
+        t = t0 + jax.lax.broadcasted_iota(jnp.int32, (R, page), 0) // rep
+        valid = jnp.logical_and(j < length + t, j >= start + t)
+        for h in range(hb):
+            q = q_ref[0, h]                             # (R, D)
+            k = k_ref[0, 0, h]                          # (page, D)
+            v = v_ref[0, 0, h]
+            logits = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            logits = jnp.where(valid, logits, NEG_INF)
+            m_prev, l_prev = m_s[h], l_s[h]
+            m_new = jnp.maximum(m_prev,
+                                jnp.max(logits, -1, keepdims=True))
+            corr = jnp.exp(m_prev - m_new)
+            pexp = jnp.where(valid, jnp.exp(logits - m_new), 0.0)
+            m_s[h] = m_new
+            l_s[h] = l_prev * corr + jnp.sum(pexp, -1, keepdims=True)
+            acc_s[h] = acc_s[h] * corr + jnp.dot(
+                pexp.astype(v.dtype), v,
+                preferred_element_type=jnp.float32)
+
+    @pl.when(w == n_walk - 1)
+    def _write():
+        l = l_s[...]
+        out = jnp.where(l > 0.0, acc_s[...] / jnp.maximum(l, 1e-30), 0.0)
+        out_ref[0] = out.astype(out_ref.dtype)
+
+
+# splint: ignore[SPL205] reason=runs inside the registered paged programs (completer.paged_chunk / completer.suffix_prefill); the outer program is the attribution point
+@functools.partial(jax.jit, static_argnames=(
+    "n_walk", "block_tokens", "q_tokens", "interpret"))
+def _window_pallas(q4, k_pool, v_pool, tables, lengths, starts, layer, *,
+                   n_walk: int, block_tokens: int, q_tokens: int,
+                   interpret: bool):
+    """q4: (B, KH, q_tokens*rep, D) token-major; pools: (n_blocks, L,
+    KH, page, D); tables (B, P); lengths, starts (B,); layer (1,)."""
+    B, KH, RT, D = q4.shape
+    rep = RT // q_tokens
+    page = k_pool.shape[3]
+    P = tables.shape[1]
+    R = block_tokens * rep
+    # a decode step carries every kv head in one program; a stack of
+    # tokens one kv head (its query block is the 1,024 rows already)
+    hb = KH if q_tokens == 1 else 1
+
+    def _q_map(b, g, qb, w, *pre):
+        return (b, g, qb, 0)
+
+    def _kv_map(b, g, qb, w, tab, lens, sts, lay):
+        first = jnp.maximum(sts[b] + qb * block_tokens, 0) // page
+        return (tab[b, jnp.minimum(first + w, P - 1)], lay[0], g, 0, 0)
+
+    kv_spec = pl.BlockSpec((1, 1, hb, page, D), _kv_map,
+                           memory_space=pltpu.VMEM)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(B, KH // hb, q_tokens // block_tokens, n_walk),
+        in_specs=[pl.BlockSpec((1, hb, R, D), _q_map,
+                               memory_space=pltpu.VMEM),
+                  kv_spec, kv_spec],
+        out_specs=pl.BlockSpec((1, hb, R, D), _q_map,
+                               memory_space=pltpu.VMEM),
+        scratch_shapes=[pltpu.VMEM((hb, R, 1), jnp.float32),
+                        pltpu.VMEM((hb, R, 1), jnp.float32),
+                        pltpu.VMEM((hb, R, D), jnp.float32)],
+    )
+    return pl.pallas_call(
+        functools.partial(_window_kernel, page=page,
+                          scale=1.0 / float(np.sqrt(D)), rep=rep,
+                          block_tokens=block_tokens, n_table=P),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(q4.shape, q4.dtype),
+        interpret=interpret,
+        # the decode step's kernel and the suffix stack's are told
+        # apart by name in a device trace (benchmark/readers)
+        name=("gqa_window_decode" if q_tokens == 1
+              else "gqa_window_stack"),
+    )(tables, lengths, starts, layer, q4, k_pool, v_pool)
+
+
+def window_paged_attention(q, k_pool, v_pool, tables, lengths, *, layer,
+                           window: int = 0, interpret: bool = False,
+                           force_pallas: bool = False):
+    """Ragged paged attention over ONE LAYER of a page group's pool,
+    global or sliding-window (FORWARD only; float pools).
+
+    q: (B, S, H, D) — S new tokens a row, all appended already: token
+    t sits at position lengths[b] - 1 + t and attends keys
+    j < lengths[b] + t — and, where window > 0, only the last `window`
+    of them (0 <= position - j < window);
+    k_pool/v_pool: (n_blocks, L, KH, page, D), kv heads unrepeated;
+    layer: int32 scalar (traced or not), the layer within the group;
+    tables: (B, P) the GROUP's block table; a window group's entries
+    behind the window may name the trash block: they are not read.
+    Returns (B, S, H, D) in q's dtype."""
+    B, S, H, D = q.shape
+    KH, page = k_pool.shape[2], k_pool.shape[3]
+    rep = H // KH
+    tables = jnp.asarray(tables, jnp.int32)
+    lengths = jnp.asarray(lengths, jnp.int32)
+    starts = (lengths - window if window > 0
+              else jnp.full_like(lengths, NO_START))
+    layer = jnp.asarray(layer, jnp.int32)
+    if not (force_pallas or interpret or jax.default_backend() == "tpu"):
+        return _paged_ref(q, k_pool[:, layer], v_pool[:, layer], tables,
+                          lengths, starts)
+    tq = stack_block(S, rep)
+    n_walk = tables.shape[1]
+    if window > 0:
+        n_walk = min(n_walk, window_walk_pages(window, page, tq))
+    q4 = q.reshape(B, S, KH, rep, D).transpose(0, 2, 1, 3, 4) \
+          .reshape(B, KH, S * rep, D)
+    out = _window_pallas(q4, k_pool, v_pool, tables, lengths, starts,
+                         layer.reshape(1), n_walk=n_walk,
+                         block_tokens=tq, q_tokens=S,
+                         interpret=interpret)
+    return out.reshape(B, KH, S, rep, D).transpose(0, 2, 1, 3, 4) \
+              .reshape(B, S, H, D)
+
+
+def _kv_append_kernel(bid_ref, off_ref, lay_ref, new_ref, pool_ref,
+                      out_ref, *, sub: int):
+    """Row i of the batch: the `sub` tokens of its page that hold
+    offset off[i] come in, token off[i] % sub takes the new row in
+    every kv head, the tile goes back.  Consecutive rows of one tile
+    (dead rows, all sent to the trash block) keep writing the block
+    that is already resident."""
+    i = pl.program_id(0)
+    j = jnp.maximum(i - 1, 0)
+    fresh = jnp.logical_or(i == 0, jnp.logical_or(
+        bid_ref[i] != bid_ref[j], off_ref[i] // sub != off_ref[j] // sub))
+
+    @pl.when(fresh)
+    def _load():
+        out_ref[...] = pool_ref[...]
+
+    row = jax.lax.broadcasted_iota(jnp.int32, out_ref.shape[2:], 1)
+    out_ref[0, 0] = jnp.where(
+        row == off_ref[i] % sub,
+        jnp.broadcast_to(new_ref[0], out_ref.shape[2:]), out_ref[0, 0])
+
+
+# splint: ignore[SPL205] reason=runs inside the registered paged programs; the outer program is the attribution point
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _kv_append_pallas(pool, new, bids, offs, layer, *, interpret: bool):
+    """pool: (n_blocks, L, KH, page, D), updated in place (aliased);
+    new: (N, KH, 1, D); bids/offs: (N,) int32; layer: (1,) int32."""
+    N = new.shape[0]
+    _, _, KH, page, D = pool.shape
+    # the tile of tokens a write carries: the dtype's sublane tile
+    # where the page holds whole ones, else the page
+    sub = 16 if page % 16 == 0 else page
+
+    def _tile(i, bid, off, lay):
+        return (bid[i], lay[0], 0, off[i] // sub, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(N,),
+        in_specs=[
+            pl.BlockSpec((1, KH, 1, D), lambda i, *pre: (i, 0, 0, 0),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, 1, KH, sub, D), _tile,
+                         memory_space=pltpu.VMEM),
+        ],
+        out_specs=pl.BlockSpec((1, 1, KH, sub, D), _tile,
+                               memory_space=pltpu.VMEM),
+    )
+    return pl.pallas_call(
+        functools.partial(_kv_append_kernel, sub=sub),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+        input_output_aliases={4: 0},
+        interpret=interpret,
+        name="gqa_window_append",
+    )(bids, offs, layer, new, pool)
+
+
+def kv_append(pool, new, bids, offs, *, layer, interpret: bool = False,
+              force_pallas: bool = False):
+    """Write one new token a row into a page group's pool:
+    pool[bids[i], layer, :, offs[i]] = new[i].  pool: (n_blocks, L, KH,
+    page, D); new: (N, KH, D); bids/offs: (N,) int32; layer: int32
+    scalar.  Rows sent to the trash block 0 may collide freely.  In
+    place on a TPU (an XLA scatter there asks for the pool in another
+    layout and copies it both ways, tests/test_chip_compile.py)."""
+    b = jnp.asarray(bids, jnp.int32).reshape(-1)
+    o = jnp.asarray(offs, jnp.int32).reshape(-1)
+    layer = jnp.asarray(layer, jnp.int32)
+    if force_pallas or interpret or jax.default_backend() == "tpu":
+        return _kv_append_pallas(pool, new.astype(pool.dtype)[:, :, None],
+                                 b, o, layer.reshape(1),
+                                 interpret=interpret)
+    return pool.at[b, layer, :, o].set(new.astype(pool.dtype))

@@ -421,3 +421,100 @@ def test_hybrid_programs_at_published_widths(one_chip, monkeypatch,
     assert mem.argument_size_in_bytes > 14.0e9   # weights, pages, slots
     assert mem.temp_size_in_bytes < 0.6e9        # no pool or slot copies
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15e9
+
+
+# ---- the mixed sliding-window / global stack at published widths
+# (benchmark/configs/trinity-mini-26b-a3b-ep8.json: 32 heads over 4
+# key/value heads of 128, sliding_window 2,048, pages of 128 tokens in
+# two groups — 1,536 global pages of 8 layers, 448 window pages of 24 —
+# a 192-page table, 16 held experts of 2,048 x 1,024, all 32 layers)
+
+GQA_H, GQA_KH, GQA_D, GQA_W, GQA_P = 32, 4, 128, 2048, 192
+GQA_POOLS = {"full": (1537, 8, GQA_KH, PAGE, GQA_D),
+             "window": (449, 24, GQA_KH, PAGE, GQA_D)}
+
+
+@pytest.mark.parametrize("q_tokens, rows, kind", [
+    (1, 32, "window"), (1, 32, "full"), (640, 1, "window"),
+    (640, 1, "full"), (128, 1, "full")],
+    ids=["decode-window", "decode-full", "stack-640-window",
+         "stack-640-full", "stack-128-full"])
+def test_window_attention_kernel(one_chip, q_tokens, rows, kind):
+    """The grouped-query kernel over a page group's pool: a decode step
+    (all 4 kv heads a program) and the suffix stacks (128 tokens x 8
+    heads a program), walking a window layer's 17-18 pages or a global
+    layer's table — the pool reaches it in the layout it is kept in."""
+    from libsplinter_tpu.ops.paged_attention import (
+        _window_pallas, stack_block, window_walk_pages)
+    tq = stack_block(q_tokens, GQA_H // GQA_KH)
+    n_walk = (window_walk_pages(GQA_W, PAGE, tq) if kind == "window"
+              else GQA_P)
+    assert (tq, n_walk) == ((1, 17) if q_tokens == 1 else (128, 18)) \
+        or kind == "full"
+    pool = _spec(one_chip, GQA_POOLS[kind], jnp.bfloat16)
+    i32 = functools.partial(_spec, one_chip, dtype=jnp.int32)
+    compiled = _compile(
+        lambda q4, kp, vp, t, l, s, lay: _window_pallas(
+            q4, kp, vp, t, l, s, lay, n_walk=n_walk, block_tokens=tq,
+            q_tokens=q_tokens, interpret=False),
+        _spec(one_chip, (rows, GQA_KH, q_tokens * GQA_H // GQA_KH, GQA_D),
+              jnp.bfloat16),
+        pool, pool, i32(shape=(rows, GQA_P)), i32(shape=(rows,)),
+        i32(shape=(rows,)), i32(shape=(1,)))
+    txt = compiled.as_text()
+    assert "tpu_custom_call" in txt
+    shape = ",".join(str(d) for d in GQA_POOLS[kind])
+    assert not [ln for ln in txt.split("ENTRY")[1].splitlines()
+                if shape in ln and " copy(" in ln]
+
+
+@pytest.mark.parametrize("program", ["chunk", "suffix-640"])
+def test_window_programs_at_published_widths(one_chip, monkeypatch,
+                                             program):
+    """The 8-step decode chunk and the widest suffix prefill of the
+    benchmark's window / global configuration (32 layers as a 4-layer
+    head + 7 scanned periods; 8.53 GB of weights + 3.22 GB of global
+    pages + 2.82 GB of window pages): each compiles, fits the chip
+    beside its arguments, and keeps both groups' pools in place."""
+    from libsplinter_tpu.models import afmoe
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = afmoe.WindowMoeConfig(
+        vocab_size=25024, hidden=2048,
+        kinds=("window", "window", "window", "full") * 8, heads=GQA_H,
+        kv_heads=GQA_KH, head_dim=GQA_D, window=GQA_W, dense_layers=2,
+        dense_mlp_dim=6144, moe_mlp_dim=1024, n_routed_experts=128,
+        top_k=8, experts_first=0, experts_held=16,
+        routed_scaling_factor=2.826, max_len=24576)
+    assert cfg.plan == (4, 4, 7)
+    params = jax.tree_util.tree_map(
+        lambda a: _spec(one_chip, a.shape, a.dtype),
+        jax.eval_shape(lambda: afmoe.init_params(cfg, 0)))
+    weights = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                  for a in jax.tree_util.tree_leaves(params))
+    assert 8.54e9 < weights < 8.56e9      # 4,267M parameters: 8,550,653,952 B
+    m = afmoe.WindowCompletionModel(cfg, params=params)
+    pools = {k: (_spec(one_chip, s, jnp.bfloat16),) * 2
+             for k, s in GQA_POOLS.items()}
+    i32 = _spec(one_chip, (), jnp.int32)
+    if program == "chunk":
+        fn = m._chunk_program(8, 32)
+        tables = {k: _spec(one_chip, (32, GQA_P), jnp.int32)
+                  for k in GQA_POOLS}
+        args = (_spec(one_chip, (32,), jnp.int32),
+                _spec(one_chip, (2,), jnp.uint32),
+                _spec(one_chip, (32,), jnp.int32),
+                _spec(one_chip, (32,), jnp.bool_),
+                _spec(one_chip, (32,), jnp.int32),
+                _spec(one_chip, (2,), jnp.int32))
+    else:
+        fn = m._suffix_program(640)
+        tables = {k: _spec(one_chip, (1, GQA_P), jnp.int32)
+                  for k in GQA_POOLS}
+        args = (_spec(one_chip, (1,), jnp.int32),
+                _spec(one_chip, (1, 640), jnp.int32), i32)
+    compiled = getattr(fn, "__wrapped__", fn).lower(
+        params, pools, tables, *args).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes > 14.5e9   # weights, both groups
+    assert mem.temp_size_in_bytes < 0.6e9        # no pool copies
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.6e9

@@ -149,6 +149,7 @@ def test_single_guarded_cache_call_site():
     assert sorted(h.split(":")[0] for h in hits) == [
         "benchmark/reference/hybrid_kda_block.py",
         "benchmark/reference/latent_moe_block.py",
+        "benchmark/reference/window_gqa_moe_block.py",
         "libsplinter_tpu/utils/jaxplatform.py"], hits
 
 

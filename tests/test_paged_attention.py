@@ -336,3 +336,64 @@ def test_paged_pool_exhaustion_raises_for_unreserved(model):
     cache.lengths[1] = 15              # parked at its page boundary
     with pytest.raises(RuntimeError, match="pool exhausted"):
         m.paged_decode_chunk(cache, np.array([1, 1], np.int32), 8)
+
+
+# ---------------------------------------------- page groups and windows
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("q_tokens", [1, 16, 48],
+                         ids=["decode", "stack-16", "stack-48"])
+@pytest.mark.parametrize("window", [0, 32, 40],
+                         ids=["global", "window-2-pages", "window-40"])
+def test_window_kernel_starts_match_the_reference(window, q_tokens, dtype):
+    """ops/paged_attention.window_paged_attention — the kernel that
+    walks a page group's pool from each row's FIRST LIVE KEY — against
+    `_paged_ref` with `starts`, in interpret mode: one token a row and
+    stacks of tokens (causal inside the stack, the window sliding a
+    token at a time), rows shorter and longer than the window.  Pages
+    behind the window are given back (their table entries name the
+    trash block) and the trash block is POISONED: not one is read."""
+    from libsplinter_tpu.ops.paged_attention import window_paged_attention
+    rng = np.random.default_rng(0)
+    page, B, KH, rep, D, L, P = 16, 3, 2, 4, 32, 3, 12
+    nb = B * P + 1
+    kp = jnp.asarray(rng.standard_normal((nb, L, KH, page, D)), dtype)
+    vp = jnp.asarray(rng.standard_normal((nb, L, KH, page, D)), dtype)
+    tables = np.arange(1, nb).reshape(B, P).astype(np.int32)
+    lengths = np.minimum(np.array([5, 100, 150], np.int32),
+                         P * page - q_tokens)
+    if window:
+        for b in range(B):
+            tables[b, :max(0, lengths[b] - window) // page] = 0
+    q = jnp.asarray(rng.standard_normal((B, q_tokens, KH * rep, D)), dtype)
+    got = window_paged_attention(
+        q, kp.at[0].set(jnp.nan), vp.at[0].set(jnp.nan), tables, lengths,
+        layer=1, window=window, interpret=True)
+    ref = _paged_ref(q, kp.at[0].set(0)[:, 1], vp.at[0].set(0)[:, 1],
+                     jnp.asarray(tables), jnp.asarray(lengths),
+                     jnp.asarray(lengths - window) if window else None)
+    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(ref, np.float32), atol=tol)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_kv_append_writes_one_token_a_row_in_place(dtype):
+    """ops/paged_attention.kv_append's kernel against the scatter it
+    stands in for: each row's new key lands at (its page, the layer,
+    every kv head, its offset) and nothing else moves; rows sent to
+    the trash block collide freely."""
+    from libsplinter_tpu.ops.paged_attention import kv_append
+    rng = np.random.default_rng(1)
+    pool = jnp.asarray(rng.standard_normal((9, 3, 2, 32, 16)), dtype)
+    new = jnp.asarray(rng.standard_normal((5, 2, 16)), dtype)
+    bids = np.array([3, 0, 0, 7, 1], np.int32)
+    offs = np.array([5, 0, 0, 31, 16], np.int32)
+    got = kv_append(pool, new, bids, offs, layer=2, interpret=True)
+    want = kv_append(pool, new, bids, offs, layer=2)
+    np.testing.assert_array_equal(np.asarray(got[1:], np.float32),
+                                  np.asarray(want[1:], np.float32))
+    assert float(jnp.abs(got[3, 2, :, 5] - new[0]).max()) == 0.0
+    assert float(jnp.abs(got[:, :2] - pool[:, :2]).max()) == 0.0

@@ -262,6 +262,136 @@ def test_refcount_churn_drill(model):
     assert pc.shared_pages() == 0
 
 
+def _check_window_invariants(cache, pc):
+    """_check_invariants for the WINDOW page group of a cache that
+    keeps two (models/afmoe.py): refcounts == table references; a
+    zero-ref page is free XOR retained by the tree; nothing is both;
+    a row's table names pages inside its span only."""
+    w = cache.window
+    refs = np.zeros(w.n_blocks, np.int64)
+    for r in range(cache.batch):
+        lo, hi = int(w._lo[r]), int(w._hi[r])
+        assert not w.tables[r, :lo].any() and not w.tables[r, hi:].any()
+        assert (w.tables[r, lo:hi] > 0).all()
+        assert hi - lo <= w.span
+        for bid in w.tables[r, lo:hi]:
+            refs[bid] += 1
+    assert np.array_equal(refs[1:], w.refcounts[1:])
+    free = set(w._free)
+    assert len(free) == len(w._free), "window free list duplicate"
+    tree = {bid for bid in range(1, w.n_blocks) if pc.retains_window(bid)}
+    assert not free & tree, "window page both free and tree-retained"
+    for bid in range(1, w.n_blocks):
+        if refs[bid] > 0:
+            assert bid not in free, f"window page {bid} live AND free"
+        else:
+            assert (bid in free) != (bid in tree), \
+                f"window page {bid} leaked or doubly held"
+    assert pc.window_evictable_count() == sum(
+        1 for bid in tree if w.refcounts[bid] == 0)
+    assert pc.window_pages() == len(tree)
+
+
+def test_two_group_churn_drill():
+    """The churn drill over a cache with TWO page groups (a window of
+    32 tokens = 2 pages of 16, prompts of 3-9 pages): sessions that
+    grow, fresh prompts, decode chunks that slide rows past pages,
+    finishes, reclaims in either group — both groups' invariants after
+    every step, and every page the window group is not holding
+    POISONED before every dispatch: a read of a page that went back
+    would blow the logits up."""
+    from libsplinter_tpu.models import afmoe
+    cfg = afmoe.WindowMoeConfig.tiny(
+        dtype=jnp.float32, kinds=("window", "full"), dense_layers=0)
+    m = afmoe.WindowCompletionModel(cfg, seed=2)
+    B = 4
+    cache = m.init_paged(B, page=PAGE, pool_pages=40, window_pool_pages=24)
+    pc = _attach_pc(cache)
+    w = cache.window
+    rng = random.Random(11)
+    nrng = np.random.default_rng(3)
+    scripts = [nrng.integers(3, cfg.vocab_size, 150).astype(np.int32)
+               for _ in range(3)]
+    turn = [0, 0, 0]
+    live: dict[int, object] = {}
+
+    def poison():
+        idle = jnp.asarray([0] + list(w._free), jnp.int32)
+        for pool in w.pools:
+            pool[0] = pool[0].at[idle].set(1e30)
+
+    def sound(logits):
+        assert np.isfinite(logits).all() and np.abs(logits).max() < 1e4
+
+    for step in range(90):
+        op = rng.random()
+        free_rows = [r for r in range(B) if r not in live]
+        poison()
+        if op < 0.5 and free_rows:
+            r = free_rows[0]
+            if rng.random() < 0.6:
+                s = rng.randrange(3)
+                if s in live.values():
+                    continue           # a session has one owner
+                turn[s] = min(turn[s] + 1, 5)
+                ids, tag = scripts[s][:40 + 20 * turn[s]], s
+            else:
+                ids = nrng.integers(3, cfg.vocab_size,
+                                    rng.randrange(20, 70)).astype(np.int32)
+                tag = None
+            bids, match, _ = pc.lookup_tiered(ids)
+            tail = list(pc.last_window)
+            reserve = len(ids) + PAGE
+            if match == len(ids):
+                bids, match, tail = [], 0, []     # keep the drill simple
+            if cache.pages_needed(reserve) - len(bids) \
+                    > cache.available_pages \
+                    or w.join_pages(match, reserve) - len(tail) \
+                    > w.available_pages:
+                continue               # backpressure: the honest path
+            if bids:
+                cache.map_shared(r, bids)
+                w.map_tail(r, len(bids) - len(tail), tail)
+                pc.commit_hit(ids, match)
+                cache.lengths[r] = match
+                sound(m.paged_append_prefill(cache, ids[match:], r))
+            else:
+                pc.note_miss()
+                sound(m.paged_prefill_row(cache, ids, r))
+            assert cache.ensure(r, reserve)
+            pc.insert(ids, cache, r)
+            live[r] = tag
+        elif op < 0.75 and live:
+            if all(int(cache.lengths[r]) + 4 <= 150 + PAGE and
+                   cache.pages_needed(int(cache.lengths[r]) + 4)
+                   <= len(cache._owned[r]) for r in live):
+                toks = np.full((B,), -1, np.int32)
+                for r in live:
+                    toks[r] = 9
+                m.audit_seat(0, next(iter(live)))
+                pend = m.paged_decode_chunk_async(cache, toks, 4)
+                pend.block()
+                sound(np.asarray(pend.audit)[:, 0])
+        elif op < 0.9 and live:
+            r = rng.choice(list(live))
+            cache.free_row(r)
+            del live[r]
+        elif op < 0.95:
+            pc.reclaim(rng.randrange(1, 4))
+        else:
+            pc.reclaim_window(rng.randrange(1, 4))
+        _check_invariants(cache, pc)
+        _check_window_invariants(cache, pc)
+    assert w.released > 0 and pc.stats.window_evictions > 0
+    for r in list(live):
+        cache.free_row(r)
+    _check_invariants(cache, pc)
+    _check_window_invariants(cache, pc)
+    pc.reclaim(cache.n_blocks)
+    assert cache.free_pages == cache.n_blocks - 1
+    assert w.free_pages == w.n_blocks - 1 and pc.window_pages() == 0
+
+
 def test_rows_per_envelope_at_least_4x(model):
     """The fixed page budget must seat >= 4x more concurrent rows
     under sharing than under private paging: the admission math

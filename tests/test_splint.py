@@ -101,7 +101,8 @@ def test_registry_extracts_live_protocol(splint):
         "drain", "tokenize", "dispatch", "device_wait", "commit")
     assert reg.stages["CONT_INFER_STAGES"] == (
         "join", "sample", "decode", "collect", "flush", "prefix_hit",
-        "handoff", "adopt", "state_restore", "state_snapshot")
+        "handoff", "adopt", "state_restore", "state_snapshot",
+        "window_release")
     assert reg.keys["KEY_SEARCH_STATS"] == "__searcher_stats"
     assert reg.prefixes["SEARCH_RESULT_PREFIX"] == "__sr_"
     assert reg.prefixes["DEADLINE_STAMP_PREFIX"] == "__dl_"

@@ -166,6 +166,15 @@ int spt_list(spt_store *st, char *keys, uint32_t max_keys);
 /* Block until the slot's epoch changes from its value at call time.
  * timeout_ms<0: wait forever. 0 ok / -ETIMEDOUT / -ENOENT. */
 int spt_poll(spt_store *st, const char *key, int timeout_ms);
+/* Block until (labels & mask) == want on the key's slot: at once when that
+ * holds on entry (level-triggered, no lost wake), and also when the slot's
+ * epoch moves (rewritten or deleted: the caller looks again).  A label
+ * flip moves no epoch, so spt_poll sleeps through it; this is the wait for
+ * a request the daemons acknowledge by a label.  Same wake cadence as
+ * spt_poll: the event bus where it is armed, else a 1 ms sleep.
+ * timeout_ms<0: wait forever. 0 ok / -ETIMEDOUT / -ENOENT. */
+int spt_poll_labels(spt_store *st, const char *key, uint64_t mask,
+                    uint64_t want, int timeout_ms);
 
 /* Zero-copy read protocol: capture a raw pointer + the epoch; compute; then
  * verify the epoch is unchanged (spt_epoch_at) before trusting the bytes. */

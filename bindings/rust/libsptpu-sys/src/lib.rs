@@ -151,6 +151,8 @@ extern "C" {
                     -> c_int;
     pub fn spt_poll(st: *mut spt_store, key: *const c_char, timeout_ms: c_int)
                     -> c_int;
+    pub fn spt_poll_labels(st: *mut spt_store, key: *const c_char, mask: u64,
+                           want: u64, timeout_ms: c_int) -> c_int;
     pub fn spt_get_raw(st: *mut spt_store, key: *const c_char,
                        ptr: *mut *const c_void, len_out: *mut u32,
                        epoch_out: *mut u64) -> c_int;

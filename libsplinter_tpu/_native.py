@@ -123,6 +123,7 @@ def _declare(lib: C.CDLL) -> None:
         "spt_append": (i32, [P, cs, C.c_void_p, u32]),
         "spt_list": (i32, [P, C.c_void_p, u32]),
         "spt_poll": (i32, [P, cs, i32]),
+        "spt_poll_labels": (i32, [P, cs, u64, u64, i32]),
         "spt_get_raw": (i32, [P, cs, C.POINTER(C.c_void_p), C.POINTER(u32),
                               C.POINTER(u64)]),
         "spt_find_index": (i32, [P, cs]),

@@ -23,6 +23,12 @@ here owns that policy once:
     is returned so the caller sees WHY it failed, not just that it
     timed out.
 
+Under the retries sits the one bounded wait (`wait_with_repulse`):
+it blocks on the request key's LABEL word — the thing every lane's
+commit flips (`Store.poll_labels`) — not on the slot's epoch, which a
+`label_clear` + `bump` never moves, and counts how its slices ended
+(`wait_counters()`).
+
 `submit_completion` is the completer-lane client these semantics were
 missing entirely: prompt in, READY-gated value out, typed error
 records surfaced as dicts.  `searcher.submit_search` routes through
@@ -31,6 +37,7 @@ the same wrapper.
 from __future__ import annotations
 
 import random
+import threading
 import time
 from typing import Callable
 
@@ -90,32 +97,77 @@ def call_with_retries(attempt: Callable[[float], object], *,
 # sentinel: "not finished yet" for wait_with_repulse's check()
 PENDING = object()
 
+# what wait_with_repulse did, whole process, always on (plain ints; the
+# submit paths run on many client threads, so they move under one lock
+# and are read with wait_counters()):
+#   waits           calls of wait_with_repulse (one a request attempt)
+#   woken           wait slices ended by the label condition or by the
+#                   slot's epoch moving (the daemon's doing)
+#   slice_timeouts  slices that ran their 50 ms out with nothing changed
+#   repulses        half-budget re-bumps
+# A lane whose commit the wait cannot see reads woken 0 and
+# slice_timeouts >= 1 a request; a healthy one woken ~ waits.
+waits = woken = slice_timeouts = repulses = 0
+_counters_lock = threading.Lock()
 
-def wait_with_repulse(store, key: str, left_ms: float, check):
-    """The shared bounded wait every submit path uses: poll `key`
-    until `check()` returns something other than PENDING, re-bumping
-    ONCE at half budget (the bump may have raced the daemon's
-    signal_wait re-arm — the run-loop sweeps narrow but cannot close
-    that window; one re-pulse costs a signal, silence costs the whole
-    timeout), returning None when the budget runs out.  One
-    definition, so a fix to the re-pulse race can never apply to one
-    lane and miss another."""
+
+def wait_counters() -> dict:
+    """A consistent copy of the wait counters above."""
+    with _counters_lock:
+        return {"waits": waits, "woken": woken,
+                "slice_timeouts": slice_timeouts, "repulses": repulses}
+
+
+def wait_with_repulse(store, key: str, left_ms: float, check, *,
+                      mask: int, want: int):
+    """The shared bounded wait every submit path uses: block until
+    `check()` returns something other than PENDING, re-bumping ONCE
+    at half budget (the bump may have raced the daemon's signal_wait
+    re-arm — the run-loop sweeps narrow but cannot close that window;
+    one re-pulse costs a signal, silence costs the whole timeout),
+    returning None when the budget runs out.
+
+    `(mask, want)` is the label condition `check()` itself tests
+    (`labels(key) & mask == want` = no longer PENDING): every lane
+    acknowledges a request by a label flip on the request key, which
+    moves no epoch, so the wait sleeps on the label word
+    (`Store.poll_labels`: level-triggered, and woken too by the
+    slot's epoch moving — a streamed chunk, a rewrite, an unset), in
+    slices of at most 50 ms.  One definition, so a fix to the
+    re-pulse race can never apply to one lane and miss another."""
+    global waits, woken, slice_timeouts, repulses
     stop = time.monotonic() + left_ms / 1e3
     re_pulsed = False
-    while True:
-        res = check()
-        if res is not PENDING:
-            return res
-        rem_ms = (stop - time.monotonic()) * 1e3
-        if rem_ms <= 0:
-            return None
-        if not re_pulsed and rem_ms * 2 <= left_ms:
+    n_woken = n_timeouts = 0
+    try:
+        while True:
+            res = check()
+            if res is not PENDING:
+                return res
+            rem_ms = (stop - time.monotonic()) * 1e3
+            if rem_ms <= 0:
+                return None
+            if not re_pulsed and rem_ms * 2 <= left_ms:
+                try:
+                    store.bump(key)
+                except (KeyError, OSError):
+                    pass
+                re_pulsed = True
             try:
-                store.bump(key)
-            except (KeyError, OSError):
-                pass
-            re_pulsed = True
-        store.poll(key, timeout_ms=int(min(rem_ms, 50)))
+                hit = store.poll_labels(key, mask, want,
+                                        timeout_ms=int(min(rem_ms, 50)))
+            except KeyError:
+                hit = True                # unset mid-wait: check() says
+            if hit:
+                n_woken += 1
+            else:
+                n_timeouts += 1
+    finally:
+        with _counters_lock:
+            waits += 1
+            woken += n_woken
+            slice_timeouts += n_timeouts
+            repulses += re_pulsed
 
 
 def _stamp_qos(store, key: str, tenant: int,
@@ -178,7 +230,8 @@ def submit_completion(store, key: str, prompt: str | bytes, *,
             rec = P.parse_error_payload(raw)
             return rec if rec is not None else raw.rstrip(b"\0")
 
-        return wait_with_repulse(store, key, left_ms, check)
+        return wait_with_repulse(store, key, left_ms, check,
+                                 mask=P.LBL_READY, want=P.LBL_READY)
 
     if not retry:
         return attempt(timeout_ms)
@@ -256,7 +309,8 @@ def submit_embed(store, key: str, text: str | bytes, *,
             return classify_embed_result(store, key, labels,
                                          deadline_ts=deadline_ts)
 
-        return wait_with_repulse(store, key, left_ms, check)
+        return wait_with_repulse(store, key, left_ms, check,
+                                 mask=P.LBL_EMBED_REQ, want=0)
 
     if not retry:
         return attempt(timeout_ms)

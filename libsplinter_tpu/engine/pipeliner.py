@@ -1020,7 +1020,8 @@ def submit_script(store: Store, key: str, *, script: str | None = None,
             except (KeyError, OSError, ValueError):
                 return None
 
-        return wait_with_repulse(store, key, left_ms, check)
+        return wait_with_repulse(store, key, left_ms, check,
+                                 mask=P.LBL_SCRIPT_REQ, want=0)
 
     if not retry:
         return attempt(timeout_ms)

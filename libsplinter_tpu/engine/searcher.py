@@ -1235,7 +1235,9 @@ def submit_search(store: Store, key: str, k: int, *, bloom: int = 0,
     embedded query) into a search request and wait for the daemon's
     result.  fast requests bf16 MXU scoring server-side (the CLI's
     --fast).  Returns the result record, or None on timeout (callers
-    fall back to client-side scoring).
+    fall back to client-side scoring).  The wait blocks on the
+    request key's label word and ends when the daemon's commit clears
+    LBL_SEARCH_REQ (client.wait_with_repulse).
 
     `tenant` tags the request's label word for per-tenant admission;
     `deadline_ms` (relative) rides the request JSON as an absolute
@@ -1264,7 +1266,11 @@ def submit_search(store: Store, key: str, k: int, *, bloom: int = 0,
         store.bump(key)
 
         def check():
-            if store.labels(key) & P.LBL_SEARCH_REQ:
+            try:
+                labels = store.labels(key)
+            except KeyError:
+                return None               # caller deleted it mid-wait
+            if labels & P.LBL_SEARCH_REQ:
                 return PENDING
             try:
                 raw = store.get(P.search_result_key(idx))
@@ -1272,7 +1278,8 @@ def submit_search(store: Store, key: str, k: int, *, bloom: int = 0,
             except (KeyError, OSError, ValueError):
                 return None
 
-        return wait_with_repulse(store, key, left_ms, check)
+        return wait_with_repulse(store, key, left_ms, check,
+                                 mask=P.LBL_SEARCH_REQ, want=0)
 
     if not retry:
         return attempt(timeout_ms)

@@ -316,6 +316,22 @@ class Store:
         _ck(rc, key=key)
         return True
 
+    def poll_labels(self, key: str, mask: int, want: int,
+                    timeout_ms: int = -1) -> bool:
+        """Block until `labels(key) & mask == want` — at once when it
+        already holds — or until the slot's epoch moves (rewritten or
+        deleted: look again).  False on timeout.  The wait for a
+        request a daemon acknowledges by a label: a label flip moves
+        no epoch, so `poll` sleeps through it.  Wakes as `poll`
+        does: on the event bus where it is armed (a commit's `bump`
+        rings it), else once a millisecond."""
+        rc = self._lib.spt_poll_labels(self._h, key.encode(), mask, want,
+                                       timeout_ms)
+        if rc == -errno.ETIMEDOUT:
+            return False
+        _ck(rc, key=key)
+        return True
+
     # -- index accessors ---------------------------------------------------
 
     def find_index(self, key: str) -> int:

@@ -528,6 +528,33 @@ int spt_poll(spt_store *st, const char *key, int timeout_ms) {
   }
 }
 
+int spt_poll_labels(spt_store *st, const char *key, uint64_t mask,
+                    uint64_t want, int timeout_ms) {
+  if (!st || !key) return -EINVAL;
+  int idx = spt__probe_find(st, key, spt_hash_key(key));
+  if (idx < 0) return idx;
+  spt_slot *s = &st->slots[idx];
+  uint64_t e0 = atomic_load_explicit(&s->epoch, memory_order_acquire);
+  uint64_t deadline =
+      timeout_ms < 0 ? 0
+                     : spt_now() + (uint64_t)timeout_ms * 1000 *
+                                       spt_ticks_per_us();
+  struct timespec ts = {0, 1000000};  /* 1 ms */
+  for (;;) {
+    /* level-triggered: the condition is tested before the first sleep,
+     * so a flip between the caller's own read and this call is kept */
+    uint64_t l = atomic_load_explicit(&s->labels, memory_order_acquire);
+    if ((l & mask) == want) return 0;
+    if (atomic_load_explicit(&s->epoch, memory_order_acquire) != e0)
+      return 0;                 /* rewritten or deleted: the caller looks */
+    if (timeout_ms >= 0 && spt_now() >= deadline) return -ETIMEDOUT;
+    if (st->my_bus_fd >= 0)     /* spt_poll's cadence: the commit's bump */
+      spt_bus_wait(st, 1);      /* rings the bus */
+    else
+      nanosleep(&ts, NULL);
+  }
+}
+
 /* -------------------------------------------------------- index accessors */
 
 int spt_find_index(spt_store *st, const char *key) {

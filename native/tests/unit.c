@@ -24,10 +24,12 @@
 #include "sptpu.h"
 
 #include <errno.h>
+#include <pthread.h>
 #include <stdint.h>
 #include <stdio.h>
 #include <stdlib.h>
 #include <string.h>
+#include <time.h>
 #include <unistd.h>
 
 static int n_run = 0, n_fail = 0;
@@ -38,6 +40,19 @@ static int n_run = 0, n_fail = 0;
     else { n_fail++; printf("not ok %d - %s (%s:%d)\n", n_run, name,     \
                             __FILE__, __LINE__); }                       \
   } while (0)
+
+/* what a daemon's commit does to a request key, a little later: a label
+ * flip (and a bump), never a write to the slot */
+struct flip { spt_store *st; const char *key; uint64_t clear; };
+
+static void *flip_later(void *arg) {
+  struct flip *f = arg;
+  struct timespec ts = {0, 5000000};  /* 5 ms */
+  nanosleep(&ts, NULL);
+  spt_label_andnot(f->st, f->key, f->clear);
+  spt_bump(f->st, f->key);
+  return NULL;
+}
 
 static void suite(const char *name, uint32_t flags) {
   char buf[4096];
@@ -132,6 +147,34 @@ static void suite(const char *name, uint32_t flags) {
        "enumerate by label mask");
   TEST(spt_label_andnot(st, "lab", 0x4) == 0 &&
        spt_enumerate(st, 0x4, hits, 64) == 0, "label clear");
+
+  /* ---- the label wait ---- */
+  {
+    TEST(spt_poll_labels(st, "nope", 0x1, 0, 5) == -ENOENT,
+         "poll_labels: unknown key -ENOENT");
+    /* "lab" carries 0x1 here; level-triggered on either sense */
+    TEST(spt_poll_labels(st, "lab", 0x4, 0, -1) == 0,
+         "poll_labels: a cleared bit already clear returns at once");
+    TEST(spt_poll_labels(st, "lab", 0x1, 0x1, -1) == 0,
+         "poll_labels: a raised bit already raised returns at once");
+    TEST(spt_poll_labels(st, "lab", 0x5, 0x5, 5) == -ETIMEDOUT,
+         "poll_labels: every bit of want has to hold (-ETIMEDOUT)");
+    TEST(spt_poll_labels(st, "lab", 0x1, 0, 0) == -ETIMEDOUT,
+         "poll_labels: timeout 0 looks once");
+    int il = spt_find_index(st, "lab");
+    uint64_t el = spt_epoch_at(st, (uint32_t)il);
+    struct flip f = {st, "lab", 0x1};
+    pthread_t th;
+    int started = pthread_create(&th, NULL, flip_later, &f) == 0;
+    TEST(started && spt_poll_labels(st, "lab", 0x1, 0, 10000) == 0,
+         "poll_labels: woken by a label flip");
+    if (started) pthread_join(th, NULL);
+    TEST(spt_epoch_at(st, (uint32_t)il) == el,
+         "poll_labels: ... which moved no epoch");
+    TEST(spt_poll(st, "lab", 5) == -ETIMEDOUT,
+         "poll: the same flip is invisible to the epoch wait");
+    spt_label_or(st, "lab", 0x1);
+  }
 
   /* ---- prefix enumeration ---- */
   uint32_t live = 0, all = (uint32_t)spt_list(st, NULL, 0);

@@ -354,6 +354,16 @@ def _wait_heartbeat(store, timeout_s: float = 10.0) -> None:
         time.sleep(0.005)
 
 
+def _wait_served(sr, n: int, timeout_s: float = 10.0) -> None:
+    """Block until the daemon thread has COUNTED `n` answers: a client
+    wakes at its own row of the commit loop, before the drain that
+    served it has been added to the stats."""
+    deadline = time.monotonic() + timeout_s
+    while sr.stats.served < n:
+        assert time.monotonic() < deadline, sr.stats
+        time.sleep(0.005)
+
+
 def test_submit_search_round_trip(store):
     """Client helper against a live daemon thread: label, wait, read."""
     rng = np.random.default_rng(10)
@@ -1085,6 +1095,7 @@ def test_steady_drains_walk_no_slots():
 
         burst(1)                     # the lane's upload, the first mask
         assert not failed
+        _wait_served(sr, n_clients)
         assert sr.stats.gather_fallbacks == 1    # the first attach
         scanned0 = sr.stats.gather_slots_scanned
         assert scanned0 >= st.nslots
@@ -1093,6 +1104,7 @@ def test_steady_drains_walk_no_slots():
         burst(rounds)
         assert not failed
         asked = n_clients * rounds
+        _wait_served(sr, served0 + asked)
         assert sr.stats.served - served0 == asked
         assert calls == {"enumerate_indices": 0, "epochs": 0}
         assert 0 < sr.stats.gather_slots_scanned - scanned0 < 8 * asked

@@ -318,7 +318,8 @@ def check_doc_tables(ctx: Context) -> list[Finding]:
 # are legitimately recorded (whole-cycle aggregates)
 _EXTRA_SPANS = {"e2e", "drain_cycle"}
 _PREFIX_FAMILIES = {"embed": ("PIPELINE_STAGES",),
-                    "infer": ("INFER_STAGES", "CONT_INFER_STAGES"),
+                    "infer": ("INFER_STAGES", "CONT_INFER_STAGES",
+                              "CONT_LOOP_PHASES"),
                     "search": ("SEARCH_STAGES", "SEARCH_LOOP_PHASES"),
                     "script": ("SCRIPT_STAGES",)}
 

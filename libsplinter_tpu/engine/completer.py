@@ -1128,6 +1128,26 @@ class Completer:
             # (the PR 17 no-recompile gate covers tiered lanes too)
             self._model.warmup_handoff(cache, export=True, adopt=True)
 
+    def _beat(self) -> None:
+        """The continuous lane's 2 s beat, one leaf of its loop
+        (`infer.beat`).  It runs at the HEAD of a pass, so the
+        heartbeat it publishes holds whole passes only and `infer.loop`
+        equals its leaves' sum plus the loop's own bookkeeping in every
+        snapshot (the rule Searcher.run's docstring states)."""
+        with tracer.span("infer.beat", leaf=True):
+            # speculative degradation rides the heartbeat cadence on
+            # this lane (run_once's per-drain hook never fires here): a
+            # tripped floor swaps self._model to the target NOW, and
+            # the lane adopts it at its next idle point
+            self._maybe_demote_spec()
+            # same cadence: bound the join-backpressure memo (evict
+            # rewritten / no-longer-waiting slots)
+            self._sweep_bp_memo()
+            self.publish_stats()
+            # warm-layer checkpoint rides the same beat — dirty-gated,
+            # so a quiet tier costs one flag read
+            self._tier_checkpoint()
+
     def run_continuous(self, *, idle_timeout_ms: int = 100,
                        stop_after: float | None = None) -> None:
         """Continuous batched serving over the block-paged KV pool:
@@ -1158,7 +1178,22 @@ class Completer:
         heads like everything else, so spec-paged composes with
         --tp).  Models whose module cannot thread a mesh
         (paged_supported False) and window-only bucket geometries
-        fall back to run()."""
+        fall back to run().
+
+        With SPTPU_TRACE=1 every pass of the loop is one `infer.loop`
+        span and every second of a pass belongs to one LEAF
+        (protocol.CONT_LOOP_PHASES and the CONT_INFER_STAGES that are
+        disjoint in time: idle, beat, gather, prepare, prefix_hit,
+        state_restore, state_snapshot, join, sample, emit, decode,
+        collect, rebid); `infer.admit` encloses an admission round's
+        and `infer.chunk` a chunk round's.
+        A leaf also opens the profiler annotation, so a capture names
+        the device's idle gaps after it.  Where a request's event list
+        wants the same number, the leaf is `tracer.annotation` + the
+        local span(row, name, ms) from ONE clock pair; elsewhere it is
+        `tracer.span(name, leaf=True)`.  What is left of `infer.loop`
+        (deadline kills and the edge scan inside `infer.chunk`, the
+        loop's own lines) is its bookkeeping."""
         if not self._paged_ok():
             return self.run(idle_timeout_ms=idle_timeout_ms,
                             stop_after=stop_after)
@@ -1245,7 +1280,44 @@ class Completer:
                     "worst_len": worst_len, "span": span,
                     "finish": finish}
 
+        def plan(waiting: list, cap: int) -> list:
+            """The round's admission order (the head of `gather`).
+            Multi-tenant admission before any render: fair order
+            across tenants, expired deadlines rejected fast, backlog
+            past high water shed typed.  Pool-backpressured rows are
+            EXCLUDED from the fairness plan entirely — they are not
+            admissible this cycle, and letting the planner "admit"
+            them would charge their tenant's stride pass every chunk
+            for a row the pool can never seat, pushing that tenant
+            behind peers it was never actually served ahead of.
+            Their deadlines still matter: an expired blocked row is
+            rejected typed right here."""
+            plannable = []
+            now_wall = time.time()
+            for w_idx in waiting:
+                memo = bp_memo.get(w_idx)
+                if memo is not None \
+                        and memo[0] == st.epoch_at(w_idx) \
+                        and memo[1] > cache.available_pages:
+                    tenant, dl = self._qos_meta(w_idx)
+                    if dl is not None and dl <= now_wall:
+                        if self._terminal_reject(
+                                w_idx, P.DEADLINE_EXPIRED_DIAGNOSTIC,
+                                "deadline_expired", tenant):
+                            bp_memo.pop(w_idx, None)
+                    continue
+                plannable.append(w_idx)
+            return self._admit_waiting(plannable, cap)
+
         def admit() -> int:
+            """One admission round: the `infer.admit` span around
+            fill_rows, whose leaves (protocol.CONT_LOOP_PHASES: gather,
+            prepare, prefix_hit, state_restore, state_snapshot, join,
+            sample, emit; a decode lane's adopt) account for it."""
+            with tracer.span("infer.admit"):
+                return fill_rows()
+
+        def fill_rows() -> int:
             """Fill free rows from waiting keys.  EVERY admission is a
             join — the prompt prefills into freshly allocated pages
             right here, whether the batch is empty or mid-decode.
@@ -1262,36 +1334,14 @@ class Completer:
                 # prefill lanes, and a joiner's dense prefill never
                 # runs here (the whole point of the split)
                 return self._lane_admit(free, _lane_ctx())
-            self.stripes.refresh()    # admission IS this lane's drain
-            waiting = [i for i in st.enumerate_indices(P.LBL_INFER_REQ)
-                       if self.stripes.owns(int(i))]
-            if not waiting:
-                return 0
-            # multi-tenant admission before any render: fair order
-            # across tenants, expired deadlines rejected fast, backlog
-            # past high water shed typed.  Pool-backpressured rows are
-            # EXCLUDED from the fairness plan entirely — they are not
-            # admissible this cycle, and letting the planner "admit"
-            # them would charge their tenant's stride pass every chunk
-            # for a row the pool can never seat, pushing that tenant
-            # behind peers it was never actually served ahead of.
-            # Their deadlines still matter: an expired blocked row is
-            # rejected typed right here.
-            plannable = []
-            now_wall = time.time()
-            for w_idx in waiting:
-                memo = bp_memo.get(w_idx)
-                if memo is not None \
-                        and memo[0] == st.epoch_at(w_idx) \
-                        and memo[1] > cache.available_pages:
-                    tenant, dl = self._qos_meta(w_idx)
-                    if dl is not None and dl <= now_wall:
-                        if self._terminal_reject(
-                                w_idx, P.DEADLINE_EXPIRED_DIAGNOSTIC,
-                                "deadline_expired", tenant):
-                            bp_memo.pop(w_idx, None)
-                    continue
-                plannable.append(w_idx)
+            with tracer.span("infer.gather", leaf=True):
+                self.stripes.refresh()  # admission IS this lane's drain
+                waiting = [
+                    i for i in st.enumerate_indices(P.LBL_INFER_REQ)
+                    if self.stripes.owns(int(i))]
+                if not waiting:
+                    return 0
+                order = plan(waiting, len(free))
             n = 0
             traced = tracer.enabled
             pc = getattr(cache, "prefix_cache", None)
@@ -1299,22 +1349,24 @@ class Completer:
             # a hit resumes from a snapshot, a join leaves one
             stateful = bool(getattr(cache, "needs_state", False))
             wgroup = getattr(cache, "window", None)
-            for idx in self._admit_waiting(plannable, len(free)):
+            for idx in order:
                 if not free:
                     break
-                e = st.epoch_at(idx)
-                memo = bp_memo.get(idx)
-                if memo is not None and memo[0] == e:
-                    if memo[1] > cache.available_pages:
-                        continue      # still too big: skip the render
-                    del bp_memo[idx]  # pool may fit now: peek fresh
+                with tracer.span("infer.gather", leaf=True):
+                    e = st.epoch_at(idx)
+                    memo = bp_memo.get(idx)
+                    if memo is not None and memo[0] == e:
+                        if memo[1] > cache.available_pages:
+                            continue  # still too big: skip the render
+                        del bp_memo[idx]  # pool may fit: peek fresh
                 # peek BEFORE claiming: a backpressured request stays
                 # WAITING untouched (a claim would overwrite its slot
                 # with the rendered prompt)
-                peek = self._read_rendered(idx)
-                if peek is None:
-                    continue
-                ids = self._clip_paged(tok_izer.encode(peek[1]))
+                with tracer.span("infer.prepare", leaf=True):
+                    peek = self._read_rendered(idx)
+                    if peek is None:
+                        continue
+                    ids = self._clip_paged(tok_izer.encode(peek[1]))
                 # radix-tree walk BEFORE the page math: every hit
                 # page is a page the pool does not need free — the
                 # admission reservation (and the backpressure memo)
@@ -1324,6 +1376,7 @@ class Completer:
                 hit_bids: list[int] = []
                 match = 0
                 tier_nodes: list = []
+                walk_ms = 0.0
                 if pc is not None and len(ids):
                     # tier-aware walk: an HBM run, then (optionally) a
                     # run of demoted pages whose bytes live in host
@@ -1335,8 +1388,10 @@ class Completer:
                     # token and only where a snapshot sits: replaying
                     # the last token, as a fully cached prompt does
                     # below, would apply it to the state twice
-                    hit_bids, match, tier_nodes = pc.lookup_tiered(
-                        ids, len(ids) - 1 if stateful else None)
+                    tw = time.perf_counter()
+                    with tracer.annotation("infer.prefix_hit"):
+                        hit_bids, match, tier_nodes = pc.lookup_tiered(
+                            ids, len(ids) - 1 if stateful else None)
                     if (match + len(tier_nodes) * cache.page
                             == len(ids) and len(ids) < 2):
                         # a fully-covered 1-token prompt would enter
@@ -1344,66 +1399,73 @@ class Completer:
                         # it as a miss (page size 1 is a test-only
                         # geometry anyway)
                         hit_bids, match, tier_nodes = [], 0, []
-                cut = pc.last_cut if stateful and pc is not None else 0
-                # a pool with a window group (models/afmoe.py): the
-                # hit ends where the window's tail is still held, and
-                # maps that tail beside the global pages
-                windowed = wgroup is not None and pc is not None
-                wtail = list(pc.last_window) if windowed and hit_bids \
-                    else []
-                wcut = pc.last_window_cut if windowed else 0
-                # the snapshot this join will leave: the state after
-                # the prompt's last full page, if the hit ends short
-                # of it and the pool keeps snapshots at all
-                snap_at = (len(ids) // cache.page) * cache.page
-                wants_snap = (stateful and pc is not None
-                              and cache.state_snapshots > 0
-                              and snap_at > match)
-                match_all = match + len(tier_nodes) * cache.page
-                full_cover = ((bool(hit_bids) or bool(tier_nodes))
-                              and match_all == len(ids))
-                reserve = 0
-                if len(ids):
-                    reserve = min(worst_len(len(ids))
-                                  + (step if full_cover else 0),
-                                  cfg.max_len)
-                    need = (cache.pages_needed(reserve)
-                            - len(hit_bids)
-                            + (1 if full_cover else 0))
-                    # zero-ref hit pages count in available_pages as
-                    # reclaimable supply, but map_shared is about to
-                    # PIN them — they cannot also feed this row's new
-                    # allocations, so subtract them from the supply
-                    # side or a warm near-full pool would admit a row
-                    # whose ensure() then comes up short
-                    pinned = sum(1 for b in hit_bids
-                                 if cache.refcounts[b] == 0)
-                    # the window group's reservation: what the row
-                    # holds at the most while it joins and decodes,
-                    # less the tail it maps (pinned like the hit's
-                    # global pages)
-                    short_w = wgroup is not None and (
-                        wgroup.join_pages(match, reserve) - len(wtail)
-                        + (1 if full_cover else 0)
-                        > wgroup.available_pages - sum(
-                            1 for b in wtail
-                            if wgroup.refcounts[b] == 0))
-                    if need > cache.available_pages - pinned or (
-                            wants_snap
-                            and not cache.state_slot_available()) \
-                            or short_w:
-                        self.stats.join_backpressure += 1
-                        bp_memo[idx] = (e, need + pinned)
-                        self._bound_bp_memo()
-                        continue      # pool full: next cycle retries
-                tenant, _dl = self._qos_meta(idx)
-                prep = self._prepare(idx, peek=peek)
-                if prep is None:
-                    continue
-                key, rendered, t0, stamp = prep
-                if not len(ids):
-                    self._finalize(key, t0, 0, False)
-                    continue
+                    if traced:
+                        # the walk is prefix_hit's first part; the
+                        # row's event list takes it once it is seated
+                        walk_ms = (time.perf_counter() - tw) * 1e3
+                        span(None, "prefix_hit", walk_ms)
+                with tracer.span("infer.gather", leaf=True):
+                    cut = pc.last_cut if stateful and pc is not None else 0
+                    # a pool with a window group (models/afmoe.py): the
+                    # hit ends where the window's tail is still held, and
+                    # maps that tail beside the global pages
+                    windowed = wgroup is not None and pc is not None
+                    wtail = list(pc.last_window) if windowed and hit_bids \
+                        else []
+                    wcut = pc.last_window_cut if windowed else 0
+                    # the snapshot this join will leave: the state after
+                    # the prompt's last full page, if the hit ends short
+                    # of it and the pool keeps snapshots at all
+                    snap_at = (len(ids) // cache.page) * cache.page
+                    wants_snap = (stateful and pc is not None
+                                  and cache.state_snapshots > 0
+                                  and snap_at > match)
+                    match_all = match + len(tier_nodes) * cache.page
+                    full_cover = ((bool(hit_bids) or bool(tier_nodes))
+                                  and match_all == len(ids))
+                    reserve = 0
+                    if len(ids):
+                        reserve = min(worst_len(len(ids))
+                                      + (step if full_cover else 0),
+                                      cfg.max_len)
+                        need = (cache.pages_needed(reserve)
+                                - len(hit_bids)
+                                + (1 if full_cover else 0))
+                        # zero-ref hit pages count in available_pages as
+                        # reclaimable supply, but map_shared is about to
+                        # PIN them — they cannot also feed this row's new
+                        # allocations, so subtract them from the supply
+                        # side or a warm near-full pool would admit a row
+                        # whose ensure() then comes up short
+                        pinned = sum(1 for b in hit_bids
+                                     if cache.refcounts[b] == 0)
+                        # the window group's reservation: what the row
+                        # holds at the most while it joins and decodes,
+                        # less the tail it maps (pinned like the hit's
+                        # global pages)
+                        short_w = wgroup is not None and (
+                            wgroup.join_pages(match, reserve) - len(wtail)
+                            + (1 if full_cover else 0)
+                            > wgroup.available_pages - sum(
+                                1 for b in wtail
+                                if wgroup.refcounts[b] == 0))
+                        if need > cache.available_pages - pinned or (
+                                wants_snap
+                                and not cache.state_slot_available()) \
+                                or short_w:
+                            self.stats.join_backpressure += 1
+                            bp_memo[idx] = (e, need + pinned)
+                            self._bound_bp_memo()
+                            continue      # pool full: next cycle retries
+                    tenant, _dl = self._qos_meta(idx)
+                with tracer.span("infer.prepare", leaf=True):
+                    prep = self._prepare(idx, peek=peek)
+                    if prep is None:
+                        continue
+                    key, rendered, t0, stamp = prep
+                    if not len(ids):
+                        self._finalize(key, t0, 0, False)
+                        continue
                 r = free.pop(0)
                 rows[r] = {"key": key, "t0": t0, "n_tok": 0,
                            "pending": b"", "remaining": self.max_new,
@@ -1422,59 +1484,73 @@ class Completer:
                            "spans": ([] if traced and stamp is not None
                                      else None),
                            "wall0": time.perf_counter()}
+                if walk_ms and rows[r]["spans"] is not None:
+                    rows[r]["spans"].append(
+                        ["prefix_hit", round(walk_ms, 3)])
+                # prefix_hit, after the walk above: mapping the hit's
+                # pages and reserving the row's own — one clock pair,
+                # less the restore inside it (a leaf of its own)
                 ta = time.perf_counter()
+                restore_ms = 0.0
                 if hit_bids or tier_nodes:
-                    # the chaos matrix crashes HERE (mid table-
-                    # mapping, after the claim): the restarted lane
-                    # rebuilds pool + tree from scratch, so a death
-                    # between refcount bumps can strand nothing
-                    fault("completer.prefix_map")
+                    with tracer.annotation("infer.prefix_hit"):
+                        # the chaos matrix crashes HERE (mid table-
+                        # mapping, after the claim): the restarted lane
+                        # rebuilds pool + tree from scratch, so a death
+                        # between refcount bumps can strand nothing
+                        fault("completer.prefix_map")
+                        if hit_bids:
+                            # pin the HBM prefix FIRST: readmission
+                            # allocations below can trigger reclaim,
+                            # and an unpinned zero-ref hit page would
+                            # be fair game for the very eviction pass
+                            # serving it
+                            cache.map_shared(r, hit_bids)
+                            if wgroup is not None:
+                                wgroup.map_tail(
+                                    r, len(hit_bids) - len(wtail), wtail)
+                                self.stats.window_resumes += int(not wcut)
+                        if tier_nodes:
+                            # DRAM hit: readmit demoted pages.  They
+                            # come back holding refcount 1; drop each
+                            # to zero-ref (tree-retained, off the free
+                            # list) then map — map_shared's 0→1 bump
+                            # re-pins them for this row with the tree
+                            # reference accounted exactly once.  A
+                            # partial readmission (pool pressure,
+                            # injected fault) just shortens the hit —
+                            # the rest re-prefills
+                            tier_bids = pc.readmit(tier_nodes, cache)
+                            for b in tier_bids:
+                                cache._decref(b)
+                            if tier_bids:
+                                cache.map_shared(r, tier_bids)
+                            hit_bids = hit_bids + tier_bids
+                            match += len(tier_bids) * cache.page
+                            if len(tier_bids) < len(tier_nodes):
+                                full_cover = False
+                        if not hit_bids:
+                            pc.note_miss()   # every readmit failed
+                        else:
+                            cache.lengths[r] = (
+                                len(ids) - 1 if full_cover else match)
+                    if hit_bids and stateful:
+                        src = pc.state_slot(ids, match)
+                        if src < 0:
+                            raise RuntimeError(
+                                "a hit ends at a node without a "
+                                "state snapshot")
+                        t_s = time.perf_counter()
+                        with tracer.annotation("infer.state_restore"):
+                            m.state_restore(cache, src, r)
+                        self.stats.state_restores += 1
+                        if traced:
+                            restore_ms = (time.perf_counter() - t_s) * 1e3
+                            span(rows[r], "state_restore", restore_ms)
+                elif pc is not None and len(ids):
+                    pc.note_miss()
+                with tracer.annotation("infer.prefix_hit"):
                     if hit_bids:
-                        # pin the HBM prefix FIRST: readmission
-                        # allocations below can trigger reclaim, and
-                        # an unpinned zero-ref hit page would be fair
-                        # game for the very eviction pass serving it
-                        cache.map_shared(r, hit_bids)
-                        if wgroup is not None:
-                            wgroup.map_tail(r, len(hit_bids) - len(wtail),
-                                            wtail)
-                            self.stats.window_resumes += int(not wcut)
-                    if tier_nodes:
-                        # DRAM hit: readmit demoted pages.  They come
-                        # back holding refcount 1; drop each to
-                        # zero-ref (tree-retained, off the free list)
-                        # then map — map_shared's 0→1 bump re-pins
-                        # them for this row with the tree reference
-                        # accounted exactly once.  A partial
-                        # readmission (pool pressure, injected fault)
-                        # just shortens the hit — the rest re-prefills
-                        tier_bids = pc.readmit(tier_nodes, cache)
-                        for b in tier_bids:
-                            cache._decref(b)
-                        if tier_bids:
-                            cache.map_shared(r, tier_bids)
-                        hit_bids = hit_bids + tier_bids
-                        match += len(tier_bids) * cache.page
-                        if len(tier_bids) < len(tier_nodes):
-                            full_cover = False
-                    if not hit_bids:
-                        pc.note_miss()   # every readmit failed
-                    else:
-                        cache.lengths[r] = (len(ids) - 1 if full_cover
-                                            else match)
-                        if stateful:
-                            src = pc.state_slot(ids, match)
-                            if src < 0:
-                                raise RuntimeError(
-                                    "a hit ends at a node without a "
-                                    "state snapshot")
-                            t_s = time.perf_counter()
-                            with tracer.annotation("infer.state_restore"):
-                                m.state_restore(cache, src, r)
-                            self.stats.state_restores += 1
-                            if traced:
-                                span(rows[r], "state_restore",
-                                     (time.perf_counter() - t_s) * 1e3)
                         # hit/LRU recorded only now — a denied or
                         # raced admission must not inflate the hit
                         # rate the runbook triages on
@@ -1485,17 +1561,16 @@ class Completer:
                             self.tenants.bump(tenant,
                                               "prefix_hit_pages",
                                               len(hit_bids))
-                elif pc is not None and len(ids):
-                    pc.note_miss()
-                # the uncached tail AFTER tier readmission: a partial
-                # readmit lengthens the suffix the prefill must cover
-                suffix = ids[match:]
-                self.stats.prompt_tokens += len(ids)
-                self.stats.prefix_tokens += match
-                self.stats.state_cut_tokens += cut
-                self.stats.window_cut_tokens += wcut
-                w_s0 = wgroup.release_s if wgroup is not None else 0.0
-                if not cache.ensure(r, reserve):
+                    # the uncached tail AFTER tier readmission: a partial
+                    # readmit lengthens the suffix the prefill must cover
+                    suffix = ids[match:]
+                    self.stats.prompt_tokens += len(ids)
+                    self.stats.prefix_tokens += match
+                    self.stats.state_cut_tokens += cut
+                    self.stats.window_cut_tokens += wcut
+                    w_s0 = wgroup.release_s if wgroup is not None else 0.0
+                    seated = cache.ensure(r, reserve)
+                if not seated:
                     # defensive: the pinned-aware gate above makes
                     # this unreachable, but a seated row WITHOUT its
                     # reservation would strand mid-decode and abort
@@ -1507,9 +1582,9 @@ class Completer:
                     self.stats.join_backpressure += 1
                     self._requeue_failed([idx])
                     continue
-                if traced and hit_bids:
+                if traced:
                     span(rows[r], "prefix_hit",
-                         (time.perf_counter() - ta) * 1e3)
+                         (time.perf_counter() - ta) * 1e3 - restore_ms)
                 if getattr(cache, "quantized", False) and suffix:
                     # the quantized append/commit path: the commit
                     # scatter about to run quantizes the prompt's K/V
@@ -1536,53 +1611,60 @@ class Completer:
                     skw = ({"snap_at": snap[1], "snap_slot": snap[0]}
                            if snap else {})
                     ta = time.perf_counter()
-                    if hit_bids:
-                        # uncached tail only, attending the mapped
-                        # prefix through the ragged paged kernel
-                        logits = m.paged_append_prefill(
-                            cache, np.asarray(suffix, np.int32), r,
-                            **skw)
-                    else:
-                        logits = m.paged_prefill_row(
-                            cache, np.asarray(ids, np.int32), r, **skw)
+                    with tracer.annotation("infer.join"):
+                        if hit_bids:
+                            # uncached tail only, attending the mapped
+                            # prefix through the ragged paged kernel
+                            logits = m.paged_append_prefill(
+                                cache, np.asarray(suffix, np.int32), r,
+                                **skw)
+                        else:
+                            logits = m.paged_prefill_row(
+                                cache, np.asarray(ids, np.int32), r,
+                                **skw)
                     tb = time.perf_counter()
-                    if wgroup is not None:
-                        # the prefill gave the window pages it slid
-                        # past back a piece at a time; what the decode
-                        # still needs of the reservation comes now
-                        cache.ensure(r, reserve)
-                        if traced:
-                            span(rows[r], "window_release",
-                                 (wgroup.release_s - w_s0) * 1e3)
-                    if pc is not None:
-                        # freshly committed full prompt pages join
-                        # the tree NOW, donor still live — the next
-                        # identical admission maps them even while
-                        # this row decodes; the node the snapshot
-                        # belongs to takes its slot over
-                        ins = pc.insert(ids, cache, r, tenant,
-                                        **({"state": snap} if snap
-                                           else {}))
-                        if snap and pc.holds_snapshot(snap[0]):
-                            self.stats.state_snapshots += 1
-                        if ins and tenant:
-                            self.tenants.bump(
-                                tenant, "prefix_cached_pages", ins)
-                    # a model with two audit lanes (engine/audit.py)
-                    # keeps one for each way a prompt is served
-                    lane = int(not match) if self.audit is not None \
-                        and self.audit.lanes > 1 else 0
-                    if self.audit is not None and self.audit.wants(lane):
-                        rows[r]["audit"] = self.audit.open(
-                            key, ids, match, logits, lane)
-                        m.audit_seat(lane, r)
-                    # splint: ignore[SPL201] reason=the documented host "sample" stage (CONT_INFER_STAGES): one scalar draw per JOIN so the row's first token emits before the next chunk, not per decode step
-                    t = int(m.sample(logits))
+                    # sample: what stands between the logits and the
+                    # row's first token (the window group's reserve,
+                    # the tree's insert, the audit's copy, the draw)
+                    with tracer.annotation("infer.sample"):
+                        if wgroup is not None:
+                            # the prefill gave the window pages it slid
+                            # past back a piece at a time; what the decode
+                            # still needs of the reservation comes now
+                            cache.ensure(r, reserve)
+                            if traced:
+                                span(rows[r], "window_release",
+                                     (wgroup.release_s - w_s0) * 1e3)
+                        if pc is not None:
+                            # freshly committed full prompt pages join
+                            # the tree NOW, donor still live — the next
+                            # identical admission maps them even while
+                            # this row decodes; the node the snapshot
+                            # belongs to takes its slot over
+                            ins = pc.insert(ids, cache, r, tenant,
+                                            **({"state": snap} if snap
+                                               else {}))
+                            if snap and pc.holds_snapshot(snap[0]):
+                                self.stats.state_snapshots += 1
+                            if ins and tenant:
+                                self.tenants.bump(
+                                    tenant, "prefix_cached_pages", ins)
+                        # a model with two audit lanes (engine/audit.py)
+                        # keeps one for each way a prompt is served
+                        lane = int(not match) if self.audit is not None \
+                            and self.audit.lanes > 1 else 0
+                        if self.audit is not None and self.audit.wants(lane):
+                            rows[r]["audit"] = self.audit.open(
+                                key, ids, match, logits, lane)
+                            m.audit_seat(lane, r)
+                        # splint: ignore[SPL201] reason=the documented host "sample" stage (CONT_INFER_STAGES): one scalar draw per JOIN so the row's first token emits before the next chunk, not per decode step
+                        t = int(m.sample(logits))
                     if traced:
                         tc = time.perf_counter()
                         span(rows[r], "join", (tb - ta) * 1e3)
                         span(rows[r], "sample", (tc - tb) * 1e3)
-                    emit(r, t)
+                    with tracer.span("infer.emit", leaf=True):
+                        emit(r, t)
                     if rows[r] is not None:
                         fresh[r] = t  # host-side token: next dispatch
                 else:                 # reads it over the device carry
@@ -1595,8 +1677,14 @@ class Completer:
                     # The COW runs EAGERLY here — the admission need
                     # counted that page, and deferring the copy to
                     # dispatch would let a later admission consume it
-                    # and strand this row mid-decode.
-                    m._cow_fixups(cache)
+                    # and strand this row mid-decode.  That copy is
+                    # this row's whole join.
+                    ta = time.perf_counter()
+                    with tracer.annotation("infer.join"):
+                        m._cow_fixups(cache)
+                    if traced:
+                        span(rows[r], "join",
+                             (time.perf_counter() - ta) * 1e3)
                     rows[r]["disp_left"] = self.max_new
                     fresh[r] = int(ids[-1])
                 n += 1
@@ -1707,7 +1795,8 @@ class Completer:
             never delivered to the newcomer."""
             pend, live = entry
             tc0 = time.perf_counter()
-            blk = pend.block()
+            with tracer.annotation("infer.collect"):
+                blk = pend.block()
             # pool-occupancy high-water: chunk edges see the peak
             # (prefills landed, nothing freed yet) — heartbeats alone
             # would miss short bursts
@@ -1724,26 +1813,117 @@ class Completer:
                     if row is not None and row["serial"] == ser \
                             and row.get("spans") is not None:
                         row["spans"].append(["collect", round(ms, 3)])
-            slots = getattr(pend, "slots", None)
-            if slots is not None:
-                # fetched with the step's tokens: block() above was the
-                # wait, this copies (count,) integers
-                slots = np.asarray(slots)
-                # splint: ignore[SPL201] reason=a NumPy sum of the counts copied above, after the chunk's own block(); no device scalar is fetched
-                self.stats.expert_slots += int(slots.sum())
-                self._expert_totals = slots.astype(np.int64) + (
-                    0 if self._expert_totals is None
-                    else self._expert_totals)
-            for c in range(pend.n):
-                for r, ser in live:
-                    row = rows[r]
-                    if row is not None and row["serial"] == ser:
-                        if row.get("audit") is not None:
-                            # the logits this step sampled from stay
-                            # on the device; the record fetches them
-                            # when the row finishes
-                            row["audit"].steps.append((pend.audit, c))
-                        emit(r, int(blk[r, c]))
+            # emit: one span a CHUNK for the host work behind its
+            # tokens (pieces, streaming appends, finalize, pages freed)
+            with tracer.span("infer.emit", leaf=True):
+                slots = getattr(pend, "slots", None)
+                if slots is not None:
+                    # fetched with the step's tokens: block() above was
+                    # the wait, this copies (count,) integers
+                    slots = np.asarray(slots)
+                    # splint: ignore[SPL201] reason=a NumPy sum of the counts copied above, after the chunk's own block(); no device scalar is fetched
+                    self.stats.expert_slots += int(slots.sum())
+                    self._expert_totals = slots.astype(np.int64) + (
+                        0 if self._expert_totals is None
+                        else self._expert_totals)
+                for c in range(pend.n):
+                    for r, ser in live:
+                        row = rows[r]
+                        if row is not None and row["serial"] == ser:
+                            if row.get("audit") is not None:
+                                # the logits this step sampled from
+                                # stay on the device; the record
+                                # fetches them when the row finishes
+                                row["audit"].steps.append(
+                                    (pend.audit, c))
+                            emit(r, int(blk[r, c]))
+
+        def chunk_round() -> None:
+            """One chunk round, the `infer.chunk` span of a pass with
+            rows live: deadline kills and the edge scan (its own
+            bookkeeping), then the leaves decode (the next chunk's
+            dispatch), rebid, and collect + emit of the oldest chunk
+            once inflight_depth are un-awaited."""
+            nonlocal carry, rebid_due
+            kill_expired()    # chunk-edge deadline aborts
+
+            # per-row edges: a row without window room for the
+            # next chunk, or whose whole token budget is
+            # already in flight, must not be dispatched again.
+            # Its final tokens are still in the window —
+            # collect oldest-first until the edge rows have
+            # finished (budget-exhausted rows self-finish the
+            # moment their last tokens emit, so the common
+            # end-of-request edge drains only the entries that
+            # carry those tokens, preserving the overlap for
+            # the rest of the batch), then force any survivor
+            # (a true window-edge row) closed
+            edge = [r for r in range(B) if rows[r] is not None
+                    and (int(cache.lengths[r]) + step
+                         > cfg.max_len
+                         or rows[r]["disp_left"] <= 0)]
+            if edge:
+                while window and any(rows[r] is not None
+                                     for r in edge):
+                    collect(window.popleft())
+                for r in edge:
+                    if rows[r] is not None:
+                        finish(r)
+            if all(r is None for r in rows):
+                return
+
+            td = time.perf_counter()
+            with tracer.annotation("infer.decode"):
+                if sharded:
+                    fault("completer.sharded_dispatch")
+                wgroup = getattr(cache, "window", None)
+                w_s0 = wgroup.release_s if wgroup is not None \
+                    else 0.0
+                pend = m.paged_decode_chunk_async(
+                    cache, fresh, step, carry=carry)
+            if wgroup is not None and tracer.enabled:
+                # the chunk's rows slid: what they gave back
+                tracer.record("infer.window_release",
+                              (wgroup.release_s - w_s0) * 1e3)
+            live = [(r, rows[r]["serial"]) for r in range(B)
+                    if rows[r] is not None]
+            if tracer.enabled:
+                # decode = the async dispatch (host-side);
+                # the blocked wait surfaces as the collect
+                # span when the window forces the chunk.  One
+                # chunk = one histogram sample, whatever the
+                # occupancy — per-row recording would make
+                # decode quantiles occupancy-weighted, unlike
+                # every other stage; traced rows still each
+                # get the shared span in their event list
+                ms = (time.perf_counter() - td) * 1e3
+                tracer.record("infer.decode", ms)
+                for r, _ in live:
+                    if rows[r].get("spans") is not None:
+                        rows[r]["spans"].append(
+                            ["decode", round(ms, 3)])
+            carry = pend.last
+            fresh[:] = -1
+            self.stats.decode_steps += step
+            self.stats.decode_rows += step * len(live)
+            for r, _ in live:
+                rows[r]["disp_left"] -= step
+            window.append((pend, live))
+            self.stats.inflight_peak = max(
+                self.stats.inflight_peak, len(window))
+            rebid_due += step
+            if self.rebid_tokens \
+                    and rebid_due >= self.rebid_tokens:
+                rebid_due = 0
+                with tracer.span("infer.rebid", leaf=True):
+                    self._rebid()
+            # K-deep window: collect the oldest chunk only
+            # once inflight_depth are un-awaited — its emit/
+            # flush host work overlaps the newest chunk's
+            # device compute, so the per-chunk dispatch floor
+            # amortizes instead of serializing
+            while len(window) >= self.inflight_depth:
+                collect(window.popleft())
 
         def abort_all(reason: str) -> None:
             """Model failure must not wedge WAITING/SERVICING (the
@@ -1775,144 +1955,62 @@ class Completer:
                 now = time.monotonic()
                 if deadline and now > deadline:
                     break
-                if now >= next_beat:
-                    next_beat = now + 2.0
-                    # speculative degradation rides the heartbeat
-                    # cadence on this lane (run_once's per-drain hook
-                    # never fires here): a tripped floor swaps
-                    # self._model to the target NOW, and the lane
-                    # adopts it at the next idle point below
-                    self._maybe_demote_spec()
-                    # same cadence: bound the join-backpressure memo
-                    # (evict rewritten / no-longer-waiting slots)
-                    self._sweep_bp_memo()
-                    self.publish_stats()
-                    # warm-layer checkpoint rides the same beat —
-                    # dirty-gated, so a quiet tier costs one flag read
-                    self._tier_checkpoint()
+                with tracer.span("infer.loop"):
+                    if now >= next_beat:
+                        next_beat = now + 2.0
+                        self._beat()
 
-                try:
-                    if all(r is None for r in rows):
-                        # nothing live: retire any in-flight chunks
-                        # (their rows finished — serial guards drop
-                        # every column) and reset the device carry
-                        while window:
-                            collect(window.popleft())
-                        carry = None
-                        if self._model is not m:
-                            # demotion decided mid-run: adopt the
-                            # target model at this idle point (no live
-                            # rows, no in-flight chunks — the paired
-                            # spec pools retire with their wrapper and
-                            # a fresh pool serves the plain model)
-                            m = self._model
-                            sharded = getattr(m, "mesh",
-                                              None) is not None
-                            self._paged_cache = None
-                            cache = self._ensure_paged_cache()
-                            bp_memo.clear()
-                            self._debug(
-                                "continuous lane adopted the demoted "
-                                "(plain) model")
-                        if admit() == 0:
-                            if self.replica \
-                                    and self.stripes.poll_retired():
-                                # scale-down drain: stripes closed,
-                                # nothing live, window drained — exit
-                                # cleanly and let the supervisor reap
+                    try:
+                        if all(r is None for r in rows):
+                            # nothing live: retire any in-flight chunks
+                            # (their rows finished — serial guards drop
+                            # every column) and reset the device carry
+                            while window:
+                                collect(window.popleft())
+                            carry = None
+                            if self._model is not m:
+                                # demotion decided mid-run: adopt the
+                                # target model at this idle point (no live
+                                # rows, no in-flight chunks — the paired
+                                # spec pools retire with their wrapper and
+                                # a fresh pool serves the plain model)
+                                m = self._model
+                                sharded = getattr(m, "mesh",
+                                                  None) is not None
+                                self._paged_cache = None
+                                cache = self._ensure_paged_cache()
+                                bp_memo.clear()
                                 self._debug(
-                                    "replica destriped — retiring")
-                                break
-                            got = st.signal_wait(
-                                self.group, last,
-                                timeout_ms=idle_timeout_ms)
-                            if got is not None:
-                                last = got
-                                self.stats.wakes += 1
-                        continue
+                                    "continuous lane adopted the demoted "
+                                    "(plain) model")
+                            if admit() == 0:
+                                if self.replica \
+                                        and self.stripes.poll_retired():
+                                    # scale-down drain: stripes closed,
+                                    # nothing live, window drained — exit
+                                    # cleanly and let the supervisor reap
+                                    self._debug(
+                                        "replica destriped — retiring")
+                                    break
+                                with tracer.span("infer.idle",
+                                                 leaf=True):
+                                    got = st.signal_wait(
+                                        self.group, last,
+                                        timeout_ms=idle_timeout_ms)
+                                if got is not None:
+                                    last = got
+                                    self.stats.wakes += 1
+                            continue
 
-                    if any(r is None for r in rows):
-                        admit()       # joiners enter at ANY time —
-                        # even with chunks in flight: the serial guard
-                        # keeps lagged collects out of re-seated rows
+                        if any(r is None for r in rows):
+                            admit()       # joiners enter at ANY time —
+                            # even with chunks in flight: the serial guard
+                            # keeps lagged collects out of re-seated rows
 
-                    kill_expired()    # chunk-edge deadline aborts
-
-                    # per-row edges: a row without window room for the
-                    # next chunk, or whose whole token budget is
-                    # already in flight, must not be dispatched again.
-                    # Its final tokens are still in the window —
-                    # collect oldest-first until the edge rows have
-                    # finished (budget-exhausted rows self-finish the
-                    # moment their last tokens emit, so the common
-                    # end-of-request edge drains only the entries that
-                    # carry those tokens, preserving the overlap for
-                    # the rest of the batch), then force any survivor
-                    # (a true window-edge row) closed
-                    edge = [r for r in range(B) if rows[r] is not None
-                            and (int(cache.lengths[r]) + step
-                                 > cfg.max_len
-                                 or rows[r]["disp_left"] <= 0)]
-                    if edge:
-                        while window and any(rows[r] is not None
-                                             for r in edge):
-                            collect(window.popleft())
-                        for r in edge:
-                            if rows[r] is not None:
-                                finish(r)
-                    if all(r is None for r in rows):
-                        continue
-
-                    td = time.perf_counter()
-                    if sharded:
-                        fault("completer.sharded_dispatch")
-                    wgroup = getattr(cache, "window", None)
-                    w_s0 = wgroup.release_s if wgroup is not None else 0.0
-                    pend = m.paged_decode_chunk_async(
-                        cache, fresh, step, carry=carry)
-                    if wgroup is not None and tracer.enabled:
-                        # the chunk's rows slid: what they gave back
-                        tracer.record("infer.window_release",
-                                      (wgroup.release_s - w_s0) * 1e3)
-                    live = [(r, rows[r]["serial"]) for r in range(B)
-                            if rows[r] is not None]
-                    if tracer.enabled:
-                        # decode = the async dispatch (host-side);
-                        # the blocked wait surfaces as the collect
-                        # span when the window forces the chunk.  One
-                        # chunk = one histogram sample, whatever the
-                        # occupancy — per-row recording would make
-                        # decode quantiles occupancy-weighted, unlike
-                        # every other stage; traced rows still each
-                        # get the shared span in their event list
-                        ms = (time.perf_counter() - td) * 1e3
-                        tracer.record("infer.decode", ms)
-                        for r, _ in live:
-                            if rows[r].get("spans") is not None:
-                                rows[r]["spans"].append(
-                                    ["decode", round(ms, 3)])
-                    carry = pend.last
-                    fresh[:] = -1
-                    self.stats.decode_steps += step
-                    self.stats.decode_rows += step * len(live)
-                    for r, _ in live:
-                        rows[r]["disp_left"] -= step
-                    window.append((pend, live))
-                    self.stats.inflight_peak = max(
-                        self.stats.inflight_peak, len(window))
-                    rebid_due += step
-                    if self.rebid_tokens and rebid_due >= self.rebid_tokens:
-                        rebid_due = 0
-                        self._rebid()
-                    # K-deep window: collect the oldest chunk only
-                    # once inflight_depth are un-awaited — its emit/
-                    # flush host work overlaps the newest chunk's
-                    # device compute, so the per-chunk dispatch floor
-                    # amortizes instead of serializing
-                    while len(window) >= self.inflight_depth:
-                        collect(window.popleft())
-                except Exception as ex:
-                    abort_all(str(ex))
+                        with tracer.span("infer.chunk"):
+                            chunk_round()
+                    except Exception as ex:
+                        abort_all(str(ex))
         finally:
             # stop()/stop_after mid-batch: never strand keys in
             # SERVICING; the pool is reusable for the next run.

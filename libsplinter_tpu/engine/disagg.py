@@ -151,201 +151,217 @@ class PrefillLane(Completer):
         st = self.store
         m, tok = self._model, self._tok
         cache = self._ensure_paged_cache()
-        peek = self._read_rendered(idx)
-        if peek is None:
-            return False
-        ids = self._clip_paged(tok.encode(peek[1]))
+        with tracer.span("infer.prepare", leaf=True):
+            peek = self._read_rendered(idx)
+            if peek is None:
+                return False
+            ids = self._clip_paged(tok.encode(peek[1]))
         pc = getattr(cache, "prefix_cache", None)
         hit_bids: list[int] = []
         match = 0
         tier_nodes: list = []
-        if pc is not None and len(ids):
-            hit_bids, match, tier_nodes = pc.lookup_tiered(ids)
-            # keep >= 1 suffix token to prefill: the handoff needs
-            # the last-position logits for the first sample (the
-            # unified lane's fully-covered replay trick needs a
-            # decode chunk this lane never runs).  Trim the DRAM run
-            # first — dropping a tier node costs nothing readmitted
-            # yet, dropping an HBM page forfeits committed work
-            while tier_nodes \
-                    and match + len(tier_nodes) * cache.page \
-                    >= len(ids):
-                tier_nodes = tier_nodes[:-1]
-            while hit_bids and not tier_nodes and match >= len(ids):
-                hit_bids = hit_bids[:-1]
-                match -= cache.page
-            if not hit_bids and not tier_nodes:
-                match = 0
-        if len(ids):
-            # peek-before-claim backpressure, prompt-only: the DECODE
-            # reservation is the adopting lane's pool's problem
-            need = cache.pages_needed(len(ids)) - len(hit_bids)
-            pinned = sum(1 for b in hit_bids
-                         if cache.refcounts[b] == 0)
-            if need > cache.available_pages - pinned:
-                self.stats.join_backpressure += 1
+        # gather, this lane's: the prefix walk and the page math that
+        # decide whether the slot is served now
+        with tracer.span("infer.gather", leaf=True):
+            if pc is not None and len(ids):
+                hit_bids, match, tier_nodes = pc.lookup_tiered(ids)
+                # keep >= 1 suffix token to prefill: the handoff needs
+                # the last-position logits for the first sample (the
+                # unified lane's fully-covered replay trick needs a
+                # decode chunk this lane never runs).  Trim the DRAM
+                # run first — dropping a tier node costs nothing
+                # readmitted yet, dropping an HBM page forfeits
+                # committed work
+                while tier_nodes \
+                        and match + len(tier_nodes) * cache.page \
+                        >= len(ids):
+                    tier_nodes = tier_nodes[:-1]
+                while hit_bids and not tier_nodes \
+                        and match >= len(ids):
+                    hit_bids = hit_bids[:-1]
+                    match -= cache.page
+                if not hit_bids and not tier_nodes:
+                    match = 0
+            if len(ids):
+                # peek-before-claim backpressure, prompt-only: the
+                # DECODE reservation is the adopting lane's pool's
+                # problem
+                need = cache.pages_needed(len(ids)) - len(hit_bids)
+                pinned = sum(1 for b in hit_bids
+                             if cache.refcounts[b] == 0)
+                if need > cache.available_pages - pinned:
+                    self.stats.join_backpressure += 1
+                    return False
+            tenant, dl = self._qos_meta(idx)
+        with tracer.span("infer.prepare", leaf=True):
+            prep = self._prepare(idx, peek=peek)
+            if prep is None:
                 return False
-        tenant, dl = self._qos_meta(idx)
-        prep = self._prepare(idx, peek=peek)
-        if prep is None:
-            return False
-        key, _rendered, t0, _stamp = prep
-        if not len(ids):
-            self._finalize(key, t0, 0, False)
-            return True
+            key, _rendered, t0, _stamp = prep
+            if not len(ids):
+                self._finalize(key, t0, 0, False)
+                return True
+        # the claimed slot's two leaves: `join` (map the hit, prefill
+        # the rest into the scratch row, draw the first token) and
+        # `handoff` (stream that token, export the pages, land the
+        # record, flip DECODE_READY)
         tp0 = time.perf_counter()
         row = 0                       # serial scratch row
-        if hit_bids or tier_nodes:
-            fault("completer.prefix_map")
-            if hit_bids:
-                # pin the HBM prefix FIRST: readmission allocations
-                # below can trigger reclaim, and an unpinned zero-ref
-                # hit page would be fair game for that eviction pass
-                cache.map_shared(row, hit_bids)
-            if tier_nodes:
-                # DRAM hit: readmitted pages arrive holding refcount
-                # 1 — drop each to zero-ref (tree-retained), then let
-                # map_shared's 0→1 bump pin them for the scratch row.
-                # Partial readmission just lengthens the suffix
-                tier_bids = pc.readmit(tier_nodes, cache)
-                for b in tier_bids:
-                    cache._decref(b)
-                if tier_bids:
-                    cache.map_shared(row, tier_bids)
-                hit_bids = hit_bids + tier_bids
-                match += len(tier_bids) * cache.page
-            if not hit_bids:
-                pc.note_miss()       # every readmit failed
-            else:
-                cache.lengths[row] = match
-                pc.commit_hit(ids, match)
-                pc.stats.bytes_saved += \
-                    match * cache.kv_bytes_per_token()
-                if tenant:
-                    self.tenants.bump(tenant, "prefix_hit_pages",
-                                      len(hit_bids))
-        elif pc is not None:
-            pc.note_miss()
-        suffix = ids[match:]
-        if not cache.ensure(row, len(ids)):
-            # defensive (pinned-aware gate above): re-queue, same as
-            # the unified admit()'s unreachable branch
-            cache.free_row(row)
-            self.stats.join_backpressure += 1
-            self._requeue_failed([idx])
-            return True
+        with tracer.annotation("infer.join"):
+            if hit_bids or tier_nodes:
+                fault("completer.prefix_map")
+                if hit_bids:
+                    # pin the HBM prefix FIRST: readmission allocations
+                    # below can trigger reclaim, and an unpinned zero-ref
+                    # hit page would be fair game for that eviction pass
+                    cache.map_shared(row, hit_bids)
+                if tier_nodes:
+                    # DRAM hit: readmitted pages arrive holding refcount
+                    # 1 — drop each to zero-ref (tree-retained), then let
+                    # map_shared's 0→1 bump pin them for the scratch row.
+                    # Partial readmission just lengthens the suffix
+                    tier_bids = pc.readmit(tier_nodes, cache)
+                    for b in tier_bids:
+                        cache._decref(b)
+                    if tier_bids:
+                        cache.map_shared(row, tier_bids)
+                    hit_bids = hit_bids + tier_bids
+                    match += len(tier_bids) * cache.page
+                if not hit_bids:
+                    pc.note_miss()       # every readmit failed
+                else:
+                    cache.lengths[row] = match
+                    pc.commit_hit(ids, match)
+                    pc.stats.bytes_saved += \
+                        match * cache.kv_bytes_per_token()
+                    if tenant:
+                        self.tenants.bump(tenant, "prefix_hit_pages",
+                                          len(hit_bids))
+            elif pc is not None:
+                pc.note_miss()
+            suffix = ids[match:]
+            if not cache.ensure(row, len(ids)):
+                # defensive (pinned-aware gate above): re-queue, same as
+                # the unified admit()'s unreachable branch
+                cache.free_row(row)
+                self.stats.join_backpressure += 1
+                self._requeue_failed([idx])
+                return True
         try:
-            if getattr(cache, "quantized", False) and suffix:
-                fault("completer.kv_quant_commit")
-            if hit_bids:
-                logits = m.paged_append_prefill(
-                    cache, np.asarray(suffix, np.int32), row)
-            else:
-                logits = m.paged_prefill_row(
-                    cache, np.asarray(ids, np.int32), row)
-            if pc is not None:
-                ins = pc.insert(ids, cache, row, tenant)
-                if ins and tenant:
-                    self.tenants.bump(tenant, "prefix_cached_pages",
-                                      ins)
-            # splint: ignore[SPL201] reason=the documented host "sample" stage (CONT_INFER_STAGES): one scalar draw per request so the first token streams before the handoff
-            t = int(m.sample(logits))
+            with tracer.annotation("infer.join"):
+                if getattr(cache, "quantized", False) and suffix:
+                    fault("completer.kv_quant_commit")
+                if hit_bids:
+                    logits = m.paged_append_prefill(
+                        cache, np.asarray(suffix, np.int32), row)
+                else:
+                    logits = m.paged_prefill_row(
+                        cache, np.asarray(ids, np.int32), row)
+                if pc is not None:
+                    ins = pc.insert(ids, cache, row, tenant)
+                    if ins and tenant:
+                        self.tenants.bump(tenant, "prefix_cached_pages",
+                                          ins)
+                # splint: ignore[SPL201] reason=the documented host "sample" stage (CONT_INFER_STAGES): one scalar draw per request so the first token streams before the handoff
+                t = int(m.sample(logits))
             tp1 = time.perf_counter()
-            tracer.record("infer.join", (tp1 - tp0) * 1e3)
+            if tracer.enabled:
+                tracer.record("infer.join", (tp1 - tp0) * 1e3)
 
-            n_tok = truncated = vanished = 0
-            if t != tok.eos_id:
-                res = self._flush(key, tok.token_to_piece(t))
-                truncated, vanished = res == "full", res == "gone"
-                n_tok = 1
-            if t == tok.eos_id or self.max_new <= 1 \
-                    or truncated or vanished:
-                # nothing left to decode (or the slot is full/gone):
-                # this row finishes IN the prefill lane — no handoff
-                self._finalize(key, t0, n_tok, bool(truncated),
-                               bool(vanished))
-                return True
+            with tracer.annotation("infer.handoff"):
+                n_tok = truncated = vanished = 0
+                if t != tok.eos_id:
+                    res = self._flush(key, tok.token_to_piece(t))
+                    truncated, vanished = res == "full", res == "gone"
+                    n_tok = 1
+                if t == tok.eos_id or self.max_new <= 1 \
+                        or truncated or vanished:
+                    # nothing left to decode (or the slot is full/gone):
+                    # this row finishes IN the prefill lane — no handoff
+                    self._finalize(key, t0, n_tok, bool(truncated),
+                                   bool(vanished))
+                    return True
 
-            # -- the handoff: wire pages, record, DECODE_READY flip --
-            wire_pages = 0
-            if m.page_wire_bytes(cache) < st.max_val - 1:
+                # -- the handoff: wire pages, record, DECODE_READY flip --
+                wire_pages = 0
+                if m.page_wire_bytes(cache) < st.max_val - 1:
+                    try:
+                        pages_b, scales_b = m.export_row_pages(cache, row)
+                        for j, buf in enumerate(pages_b):
+                            pk = P.handoff_page_key(idx, j)
+                            st.set(pk, buf)
+                            st.label_or(pk, P.LBL_DEBUG)
+                            if scales_b[j] is not None:
+                                sk = P.handoff_scale_key(idx, j)
+                                st.set(sk, scales_b[j])
+                                st.label_or(sk, P.LBL_DEBUG)
+                        wire_pages = len(pages_b)
+                        self._lane_stats["handoff_wire_mb"] = round(
+                            self._lane_stats["handoff_wire_mb"]
+                            + wire_pages * m.page_wire_bytes(cache) / 1e6,
+                            3)
+                    except (KeyError, OSError):
+                        # store too full for the wire: the record's token
+                        # ids still let the decode lane re-prefill
+                        P.clear_handoff(st, idx,
+                                        pages=self._max_wire_pages())
+                        wire_pages = 0
+                # the chaos matrix crashes HERE — wire keys written, no
+                # record, row still SERVICING: _reclaim_stranded must
+                # sweep the orphans and re-queue (tests/test_disagg.py)
+                fault("prefill.handoff")
+                rec = {"len": int(len(ids)),
+                       "ids": [int(i) for i in ids],
+                       "carry": t, "n_tok": 1,
+                       "remaining": self.max_new - 1,
+                       "disp_left": self.max_new - 1,
+                       "plen": st.value_len(key), "t0": int(t0),
+                       "tenant": int(tenant),
+                       "deadline": dl, "wire_pages": wire_pages,
+                       "quant": bool(getattr(cache, "quantized", False))}
+                if not P.write_handoff_record(st, idx, rec):
+                    # no record -> no adoption, ever: finish with the
+                    # token already streamed instead of stranding the
+                    # client (runbook triage: handoff_failed)
+                    P.clear_handoff(st, idx, pages=max(wire_pages, 1))
+                    self._lane_stats["handoff_failed"] += 1
+                    self._finalize(key, t0, 1, False)
+                    return True
+                span = self._live_spans.pop(key, None)
+                device_ms = DEVTIME.take_lane_ms(self.LANE) \
+                    + DEVTIME.take_lane_ms("completer")
+                st.label_clear(key, P.LBL_SERVICING)
+                st.label_or(key, P.LBL_DECODE_READY)
+                # the handoff has LANDED (record + DECODE_READY): from
+                # here on nothing may escape — run_continuous's failure
+                # handler would re-queue a row the decode lane already
+                # owns (WAITING|DECODE_READY with no record = the first
+                # token streams twice).  Bookkeeping errors are swallowed.
                 try:
-                    pages_b, scales_b = m.export_row_pages(cache, row)
-                    for j, buf in enumerate(pages_b):
-                        pk = P.handoff_page_key(idx, j)
-                        st.set(pk, buf)
-                        st.label_or(pk, P.LBL_DEBUG)
-                        if scales_b[j] is not None:
-                            sk = P.handoff_scale_key(idx, j)
-                            st.set(sk, scales_b[j])
-                            st.label_or(sk, P.LBL_DEBUG)
-                    wire_pages = len(pages_b)
-                    self._lane_stats["handoff_wire_mb"] = round(
-                        self._lane_stats["handoff_wire_mb"]
-                        + wire_pages * m.page_wire_bytes(cache) / 1e6,
-                        3)
+                    st.bump(key)
                 except (KeyError, OSError):
-                    # store too full for the wire: the record's token
-                    # ids still let the decode lane re-prefill
-                    P.clear_handoff(st, idx,
-                                    pages=self._max_wire_pages())
-                    wire_pages = 0
-            # the chaos matrix crashes HERE — wire keys written, no
-            # record, row still SERVICING: _reclaim_stranded must
-            # sweep the orphans and re-queue (tests/test_disagg.py)
-            fault("prefill.handoff")
-            rec = {"len": int(len(ids)),
-                   "ids": [int(i) for i in ids],
-                   "carry": t, "n_tok": 1,
-                   "remaining": self.max_new - 1,
-                   "disp_left": self.max_new - 1,
-                   "plen": st.value_len(key), "t0": int(t0),
-                   "tenant": int(tenant),
-                   "deadline": dl, "wire_pages": wire_pages,
-                   "quant": bool(getattr(cache, "quantized", False))}
-            if not P.write_handoff_record(st, idx, rec):
-                # no record -> no adoption, ever: finish with the
-                # token already streamed instead of stranding the
-                # client (runbook triage: handoff_failed)
-                P.clear_handoff(st, idx, pages=max(wire_pages, 1))
-                self._lane_stats["handoff_failed"] += 1
-                self._finalize(key, t0, 1, False)
+                    pass
+                wall = time.perf_counter() - tp0
+                try:
+                    ho_ms = (time.perf_counter() - tp1) * 1e3
+                    if tracer.enabled:
+                        tracer.record("infer.handoff", ho_ms)
+                    self.spans.commit(
+                        span,
+                        stages={"join": round((tp1 - tp0) * 1e3, 3),
+                                "handoff": round(ho_ms, 3)},
+                        extra={"tokens": 1},
+                        device_ms=device_ms if device_ms > 0 else None)
+                except Exception:
+                    pass
+                self._lane_stats["handoffs"] += 1
+                self.stats.tokens += 1
+                # the phase-aware slack: admission rejects deadlines that
+                # land inside the NEXT request's expected prefill wall
+                self._pf_ema_s = (0.8 * self._pf_ema_s + 0.2 * wall
+                                  if self._pf_ema_s else wall)
+                self.qos_slack_s = self._pf_ema_s
                 return True
-            span = self._live_spans.pop(key, None)
-            device_ms = DEVTIME.take_lane_ms(self.LANE) \
-                + DEVTIME.take_lane_ms("completer")
-            st.label_clear(key, P.LBL_SERVICING)
-            st.label_or(key, P.LBL_DECODE_READY)
-            # the handoff has LANDED (record + DECODE_READY): from
-            # here on nothing may escape — run_continuous's failure
-            # handler would re-queue a row the decode lane already
-            # owns (WAITING|DECODE_READY with no record = the first
-            # token streams twice).  Bookkeeping errors are swallowed.
-            try:
-                st.bump(key)
-            except (KeyError, OSError):
-                pass
-            wall = time.perf_counter() - tp0
-            try:
-                tracer.record("infer.handoff",
-                              (time.perf_counter() - tp1) * 1e3)
-                self.spans.commit(
-                    span,
-                    stages={"join": round((tp1 - tp0) * 1e3, 3),
-                            "handoff": round(
-                                (time.perf_counter() - tp1) * 1e3, 3)},
-                    extra={"tokens": 1},
-                    device_ms=device_ms if device_ms > 0 else None)
-            except Exception:
-                pass
-            self._lane_stats["handoffs"] += 1
-            self.stats.tokens += 1
-            # the phase-aware slack: admission rejects deadlines that
-            # land inside the NEXT request's expected prefill wall
-            self._pf_ema_s = (0.8 * self._pf_ema_s + 0.2 * wall
-                              if self._pf_ema_s else wall)
-            self.qos_slack_s = self._pf_ema_s
-            return True
         finally:
             cache.free_row(row)
 
@@ -354,7 +370,12 @@ class PrefillLane(Completer):
         """The prefill lane's serve loop: drain WAITING keys through
         _handoff_one, phase-aware admission order, heartbeat cadence
         and scale-down retire identical to the sibling lanes.  Models
-        without the paged surface fall back to the unified lane."""
+        without the paged surface fall back to the unified lane.
+
+        The same loop accounting as the unified lane's
+        (protocol.CONT_LOOP_PHASES): a pass is one `infer.loop`, its
+        leaves idle, beat and — inside `infer.admit` — gather, prepare,
+        join, handoff."""
         if not self._paged_ok():
             return super().run_continuous(
                 idle_timeout_ms=idle_timeout_ms, stop_after=stop_after)
@@ -363,67 +384,77 @@ class PrefillLane(Completer):
         deadline = (time.monotonic() + stop_after) if stop_after else None
         last = st.signal_count(self.group)
         next_beat = time.monotonic() + 2.0
-        cache = self._ensure_paged_cache()
+        self._ensure_paged_cache()
         self.publish_stats()          # the attach-complete signal
         while self._running:
             now = time.monotonic()
             if deadline and now > deadline:
                 break
-            if now >= next_beat:
-                next_beat = now + 2.0
-                self.publish_stats()
-                if self.replica and self.stripes.poll_retired():
-                    self._debug("replica destriped — retiring")
-                    break
+            with tracer.span("infer.loop"):
+                if now >= next_beat:
+                    next_beat = now + 2.0
+                    with tracer.span("infer.beat", leaf=True):
+                        self.publish_stats()
+                    if self.replica and self.stripes.poll_retired():
+                        self._debug("replica destriped — retiring")
+                        break
+                try:
+                    with tracer.span("infer.admit"):
+                        n = self._handoff_round()
+                    if n == 0:
+                        with tracer.span("infer.idle", leaf=True):
+                            got = st.signal_wait(
+                                self.group, last,
+                                timeout_ms=idle_timeout_ms)
+                        if got is not None:
+                            last = got
+                            self.stats.wakes += 1
+                except Exception as ex:
+                    self.stats.faults += 1
+                    self._debug(f"prefill cycle failed: {ex}")
+
+    def _handoff_round(self) -> int:
+        """One admission round of the prefill lane (`infer.admit`):
+        the waiting slots in phase-aware order, each through
+        _handoff_one; the slots consumed."""
+        st = self.store
+        with tracer.span("infer.gather", leaf=True):
+            self.stripes.refresh()
+            waiting = [i for i in st.enumerate_indices(P.LBL_INFER_REQ)
+                       if self.stripes.owns(int(i))]
+            if not waiting:
+                return 0
+            cap = (len(waiting) if self.qos.high_water is None
+                   else min(len(waiting), max(1, self.qos.high_water)))
+            order = self._admit_waiting(waiting, cap)
+        n = 0
+        for idx in order:
+            if not self._running:
+                break
             try:
-                self.stripes.refresh()
-                waiting = [i for i in
-                           st.enumerate_indices(P.LBL_INFER_REQ)
-                           if self.stripes.owns(int(i))]
-                n = 0
-                if waiting:
-                    cap = (len(waiting) if self.qos.high_water is None
-                           else min(len(waiting),
-                                    max(1, self.qos.high_water)))
-                    for idx in self._admit_waiting(waiting, cap):
-                        if not self._running:
-                            break
-                        try:
-                            if self._handoff_one(idx):
-                                n += 1
-                        except Exception as ex:
-                            self.stats.faults += 1
-                            self._debug(
-                                f"prefill of slot {idx} failed: {ex}")
-                            try:
-                                handed = bool(
-                                    st.labels_at(idx)
-                                    & P.LBL_DECODE_READY)
-                            except (KeyError, OSError):
-                                handed = False
-                            if not handed:
-                                # only rows still on OUR side of the
-                                # flip are re-queued; a DECODE_READY
-                                # row belongs to the decode lane and
-                                # keeps its record + wire pages
-                                self._requeue_failed([idx])
-                                P.clear_handoff(
-                                    st, idx,
-                                    pages=self._max_wire_pages())
-                            # the failure may have escaped a donating
-                            # program: rebuild the pool outright (the
-                            # unified abort_all recovery)
-                            self._paged_cache = None
-                            cache = self._ensure_paged_cache()
-                if n == 0:
-                    got = st.signal_wait(self.group, last,
-                                         timeout_ms=idle_timeout_ms)
-                    if got is not None:
-                        last = got
-                        self.stats.wakes += 1
+                if self._handoff_one(idx):
+                    n += 1
             except Exception as ex:
                 self.stats.faults += 1
-                self._debug(f"prefill cycle failed: {ex}")
+                self._debug(f"prefill of slot {idx} failed: {ex}")
+                try:
+                    handed = bool(st.labels_at(idx)
+                                  & P.LBL_DECODE_READY)
+                except (KeyError, OSError):
+                    handed = False
+                if not handed:
+                    # only rows still on OUR side of the flip are
+                    # re-queued; a DECODE_READY row belongs to the
+                    # decode lane and keeps its record + wire pages
+                    self._requeue_failed([idx])
+                    P.clear_handoff(st, idx,
+                                    pages=self._max_wire_pages())
+                # the failure may have escaped a donating program:
+                # rebuild the pool outright (the unified abort_all
+                # recovery)
+                self._paged_cache = None
+                self._ensure_paged_cache()
+        return n
 
 
 class DecodeLane(Completer):
@@ -544,51 +575,63 @@ class DecodeLane(Completer):
         the fresh column, full worst-case page reservation, serial
         guard.  A row the pool cannot cover stays DECODE_READY
         (adopt_backpressure — never a mid-decode strand)."""
-        import numpy as np
         st = self.store
-        m = self._model
         cache = ctx["cache"]
-        rows, fresh = ctx["rows"], ctx["fresh"]
-        self.stripes.refresh()
-        ready = [i for i in st.enumerate_indices(P.LBL_DECODE_READY)
-                 if self.stripes.owns(int(i))]
-        if not ready:
-            return 0
+        with tracer.span("infer.gather", leaf=True):
+            self.stripes.refresh()
+            ready = [i for i in st.enumerate_indices(P.LBL_DECODE_READY)
+                     if self.stripes.owns(int(i))]
         n = 0
         now_wall = time.time()
         for idx in ready:
             if not free:
                 break
-            try:
-                labels = st.labels_at(idx)
-            except (KeyError, OSError):
-                continue
-            if labels & P.LBL_SERVICING \
-                    or not labels & P.LBL_DECODE_READY:
-                continue              # adopted already / raced away
-            rec = P.read_handoff_record(st, idx)
-            if rec is None:
-                continue              # record not landed yet
-            key = st.key_at(idx)
-            if key is None:
-                continue
-            dl = rec.get("deadline")
-            if dl is not None and dl <= now_wall:
-                # phase-aware QoS, decode side: an expired handoff
-                # dies before consuming pool or a batch slot
-                self._reject_ready(idx, key, rec)
-                continue
-            plen = int(rec.get("plen", 0))
-            reserve = ctx["worst_len"](int(rec["len"]))
-            if cache.pages_needed(reserve) > cache.available_pages:
-                self._lane_stats["adopt_backpressure"] += 1
-                continue              # stays DECODE_READY
-            ta = time.perf_counter()
+            with tracer.span("infer.gather", leaf=True):
+                try:
+                    labels = st.labels_at(idx)
+                except (KeyError, OSError):
+                    continue
+                if labels & P.LBL_SERVICING \
+                        or not labels & P.LBL_DECODE_READY:
+                    continue          # adopted already / raced away
+                rec = P.read_handoff_record(st, idx)
+                if rec is None:
+                    continue          # record not landed yet
+                key = st.key_at(idx)
+                if key is None:
+                    continue
+                dl = rec.get("deadline")
+                if dl is not None and dl <= now_wall:
+                    # phase-aware QoS, decode side: an expired handoff
+                    # dies before consuming pool or a batch slot
+                    self._reject_ready(idx, key, rec)
+                    continue
+                reserve = ctx["worst_len"](int(rec["len"]))
+                if cache.pages_needed(reserve) > cache.available_pages:
+                    self._lane_stats["adopt_backpressure"] += 1
+                    continue          # stays DECODE_READY
+            if self._adopt_one(idx, key, rec, reserve, free, ctx):
+                n += 1
+        return n
+
+    def _adopt_one(self, idx: int, key: str, rec: dict, reserve: int,
+                   free: list[int], ctx: dict) -> bool:
+        """Claim one vetted handoff and seat it: the `adopt` leaf of
+        the lane's admission round.  False leaves the row
+        DECODE_READY (a lost claim, or a pool that came up short)."""
+        import numpy as np
+        st = self.store
+        m = self._model
+        cache = ctx["cache"]
+        rows, fresh = ctx["rows"], ctx["fresh"]
+        plen = int(rec.get("plen", 0))
+        ta = time.perf_counter()
+        with tracer.annotation("infer.adopt"):
             try:
                 st.label_or(key, P.LBL_SERVICING)
                 st.bump(key)
             except (KeyError, OSError):
-                continue
+                return False
             # the chaos matrix crashes HERE — row claimed, nothing
             # imported: recovery re-opens it for re-adoption
             fault("decode.adopt")
@@ -627,7 +670,7 @@ class DecodeLane(Completer):
                 # recorded carry still supplies the first token
                 if not cache.ensure(r, int(rec["len"])):
                     self._unadopt(key)
-                    continue
+                    return False
                 self._lane_stats["handoff_refill"] += 1
                 m.paged_prefill_row(
                     cache,
@@ -638,12 +681,12 @@ class DecodeLane(Completer):
                 cache.free_row(r)
                 self._unadopt(key)
                 self._lane_stats["adopt_backpressure"] += 1
-                continue
+                return False
             free.pop(0)
             rows[r] = {"key": key, "t0": int(rec["t0"]),
                        "n_tok": int(rec["n_tok"]), "pending": b"",
                        "remaining": int(rec["remaining"]),
-                       "stamp": None, "deadline": dl,
+                       "stamp": None, "deadline": rec.get("deadline"),
                        "tenant": int(rec.get("tenant") or 0),
                        "serial": next(ctx["serial"]),
                        "disp_left": int(rec["disp_left"]),
@@ -651,11 +694,11 @@ class DecodeLane(Completer):
                        "wall0": time.perf_counter(),
                        "ho_idx": int(idx)}
             fresh[r] = int(rec["carry"])
+        if tracer.enabled:
             ctx["span"](rows[r], "adopt",
                         (time.perf_counter() - ta) * 1e3)
-            self._lane_stats["adopted"] += 1
-            n += 1
-        return n
+        self._lane_stats["adopted"] += 1
+        return True
 
     def _unadopt(self, key: str) -> None:
         """Back out a claimed-but-unseatable adoption: drop SERVICING,

@@ -205,6 +205,32 @@ CONT_INFER_STAGES = ("join", "sample", "decode", "collect", "flush",
                      # prefill pieces and after a chunk (leaf span)
                      "window_release")
 
+# the continuous lane's RUN LOOP, fully accounted (spans only: not in
+# CONT_INFER_STAGES, which sizes the per-request flight record).  Every
+# pass of Completer.run_continuous (and of the disaggregated lanes'
+# loops) is one `loop` span, and inside it every second belongs to one
+# LEAF: idle = blocked in signal_wait with nothing live; beat = the
+# 2 s beat at the head of a pass (spec demotion check, backpressure
+# memo sweep, publish_stats, tier checkpoint); inside one `admit` span
+# (a whole admission round, enclosing) gather = finding the waiting
+# rows, QoS order, the backpressure memo and the reservation check;
+# prepare = render + tokenize + the WAITING->SERVICING claim; then the
+# stages prefix_hit, state_restore, state_snapshot, join, sample (a
+# prefill lane: handoff; a decode lane: adopt); emit = the per-token
+# host work behind a join's sample or a collected chunk
+# (token_to_piece, streaming appends, finalize, pages freed); inside
+# one `chunk` span (a chunk round of a pass with rows live, enclosing:
+# deadline kills and the edge scan are its own bookkeeping) the stages
+# decode and collect, emit again, and rebid = the shard re-bid.  Those
+# stages are leaves too: each opens the profiler annotation
+# (utils/trace.py), so a capture names the device's idle gaps after
+# them.  flush (inside emit) and window_release (inside join and
+# decode) stay sums in their histograms.  A pass is idle, an admission
+# round, a chunk round or both, under the beat: admit + chunk + beat
+# is the loop's BUSY time, and loop - sum(leaves) its bookkeeping.
+CONT_LOOP_PHASES = ("loop", "idle", "beat", "admit", "chunk", "gather",
+                    "prepare", "emit", "rebid")
+
 # the search daemon's per-drain decomposition: wake = signal to drain
 # entry (the coalescing window's scheduling cost); drain = request
 # discovery + param parse + torn-safe query-vector gather; score =
@@ -1032,7 +1058,10 @@ def publish_heartbeat(store, key: str, payload: dict) -> None:
     optional dict/list sections, HEARTBEAT_DROP_LAST at the very end;
     marked truncated) so whatever telemetry fits still lands, and a
     section something reads never goes because it grew past a bulkier
-    one nothing reads.
+    one nothing reads.  The mark never takes the place of a counter: a
+    completer's payload has a `truncated` of its own (completions cut
+    at the slot's size, which the benchmark reads as a fault counter),
+    and keeps it — there the missing `quantiles` is the sign.
 
     Every heartbeat carries the publisher's pid: liveness probes
     (heartbeat_live) kill-0 it, so a crashed daemon reads as dead the
@@ -1052,7 +1081,8 @@ def publish_heartbeat(store, key: str, payload: dict) -> None:
             if not sections:
                 return
             rec.pop(min(sections, key=lambda k: _drop_rank(k, rec[k])))
-            rec["truncated"] = True
+            if "truncated" not in payload:
+                rec["truncated"] = True
 
 
 def _drop_rank(name: str, section) -> tuple:

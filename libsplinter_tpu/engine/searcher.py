@@ -123,11 +123,12 @@ class SearcherStats:
     drains: int = 0
     requests: int = 0            # requests gathered (incl. retried)
     served: int = 0              # results committed
+    returned_next_drain: int = 0  # gathered requests whose slot the
+                                  # previous serviced drain answered
     dispatches: int = 0          # device top-k program calls
     coalesced_max: int = 0       # most requests in one dispatch
     parse_errors: int = 0        # malformed / vectorless requests
     raced: int = 0               # slot changed mid-service; retried
-    full_refreshes: int = 0      # lane full uploads
     # -- how the lane and the mask learn what moved (StagedLane) -----
     lane_slots_scanned: int = 0  # epochs the drains' refreshes and
                                  # mask patches looked at (not audits)
@@ -142,8 +143,9 @@ class SearcherStats:
     # -- K-deep dispatch overlap (engine/resident.py): batch k's
     # select+commit resolve while batches k+1..k+K compute ---------
     inflight_peak: int = 0       # max un-awaited batch dispatches held
-    ready_selects: int = 0       # batch already complete at select
     blocking_selects: int = 0    # host blocked on the device fetch
+                                 # (the rest of `dispatches` were
+                                 # complete at select)
     # -- failure-domain accounting (the per-batch firewall) ----------
     batch_faults: int = 0        # batches that failed and degraded
     retried_unfused: int = 0     # recovered by the unfused retry
@@ -283,6 +285,10 @@ class Searcher:
         self._all_req_rows: list[int] = []
         self._audit_adopted = False  # the beat's audit found rows no
                                      # record named: drain, no wake comes
+        # who came back: the request slots the last serviced drain
+        # committed (a client keeps its key), which the NEXT gather
+        # counts its requests against (returned_next_drain)
+        self._answered_last: set[int] = set()
         self.stats = SearcherStats()
         self.generation = 0          # bumped at attach (restart marker)
         self.recorder = FlightRecorder()
@@ -415,6 +421,10 @@ class Searcher:
                                 tenant=P.read_tenant(labels),
                                 deadline=deadline,
                                 traced=bool(labels & P.LBL_TRACED)))
+        # how many of the clients the last serviced drain answered are
+        # asking again already (the closed loop's cycle in drains)
+        self.stats.returned_next_drain += sum(
+            1 for r in out if r.idx in self._answered_last)
         return out
 
     # -- admission (multi-tenant QoS) --------------------------------------
@@ -566,6 +576,7 @@ class Searcher:
                     st.shard_rebid(self._bid)
                 except OSError:
                     pass
+            self._answered_last = set()   # this drain's, from here
             try:
                 served = self._service(reqs)
             except Exception as ex:
@@ -615,9 +626,8 @@ class Searcher:
         full0 = self.lane.full_uploads
         with tracer.span("search.refresh", leaf=True):
             arr = self.lane.refresh()
-        fulls = self.lane.full_uploads - full0
-        self.stats.full_refreshes += fulls
-        if fulls and "first_refresh" not in self.startup_ms:
+        if self.lane.full_uploads > full0 \
+                and "first_refresh" not in self.startup_ms:
             # the staging of the lane: the daemon's largest start-up
             # cost, paid by the first request
             self.startup_ms["first_refresh"] = \
@@ -659,7 +669,6 @@ class Searcher:
             self._note_lane()
         self.stats.inflight_peak = max(self.stats.inflight_peak,
                                        win.inflight_peak)
-        self.stats.ready_selects += win.ready_resolves
         self.stats.blocking_selects += win.blocking_resolves
         t3 = time.perf_counter()
         if acc is not None:
@@ -847,7 +856,10 @@ class Searcher:
             out_k.append(key)
         rec = {"s": out_s, "i": out_i, "keys": out_k,
                "fetched": int(min(k_fetch, st.nslots)), "n": n_valid}
-        return self._commit_result(r.idx, r.epoch, rec)
+        done = self._commit_result(r.idx, r.epoch, rec)
+        if done:
+            self._answered_last.add(r.idx)
+        return done
 
     def _commit_result(self, idx: int, epoch: int, rec: dict) -> int:
         """Epoch-gated result commit: write __sr_<idx>, clear the

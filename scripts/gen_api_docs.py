@@ -288,7 +288,7 @@ this is the structured counterpart the TPU port adds.
 
 | var | effect |
 |---|---|
-| `SPTPU_TRACE=1` | enable span histograms + flight recording in the daemons, and put the search daemon's leaf phases on the profiler's clock as `jax.profiler.TraceAnnotation`s (off: the hot path pays one dict lookup, and `utils/trace.py` imports no jax) |
+| `SPTPU_TRACE=1` | enable span histograms + flight recording in the daemons, and put the leaf phases of the search daemon's and the continuous completion lane's run loops on the profiler's clock as `jax.profiler.TraceAnnotation`s (off: the hot path pays one dict lookup, and `utils/trace.py` imports no jax) |
 | `SPTPU_TRACE_SLOW_MS=<ms>` | explicit slow-log promotion threshold; unset → 5× the recorder's live e2e p50 (arms after 20 samples) |
 
 ### The search daemon's run loop, span by span
@@ -334,6 +334,50 @@ whatever a hosting process did first), `jax` (import + device open
 inside `main()`), `store_open`, `attach`, `warmup` (with `--warmup`),
 `first_refresh` (the first full lane upload, paid by the first
 request without `--warmup`), and their sum `total`.
+
+### The continuous completion lane's run loop, span by span
+
+`Completer.run_continuous` (and the disaggregated lanes' loops,
+`engine/disagg.py`) is accounted the same way: with `SPTPU_TRACE=1`
+every pass of `while self._running` is one `infer.loop` span and every
+second of a pass belongs to exactly one leaf
+(`protocol.CONT_LOOP_PHASES` beside `CONT_INFER_STAGES`; splint SPL107
+reads both under the `infer.` prefix; the phases are spans only and do
+not size the per-request flight record):
+
+| span | what it brackets | leaf |
+|---|---|---|
+| `infer.loop` | one pass of the run loop: an admission round, or one dispatched chunk and the collect of the oldest in flight | no |
+| `infer.idle` | blocked in `signal_wait` with no row live and no request waiting | yes |
+| `infer.beat` | the 2 s beat at the head of a pass: the speculative-demotion check, the backpressure memo's sweep, `publish_stats`, the tier checkpoint | yes |
+| `infer.admit` | one whole admission round (`admit()`); its leaves are the next five rows, `infer.state_restore` / `infer.state_snapshot`, the first token's `infer.emit`, and a decode lane's `infer.adopt` | no |
+| `infer.gather` | finding the waiting rows (`enumerate_indices` over every slot), the QoS order, the backpressure memo, the reservation check: everything of a round that is no other leaf's | yes |
+| `infer.prepare` | render + tokenize (`_read_rendered`, `encode`) and the WAITING → SERVICING claim (`_prepare`): two spans a request | yes |
+| `infer.prefix_hit` | the prefix cache's and the page allocator's part of a join: the radix walk (one span), then mapping the hit's pages and reserving the row's own (`ensure`; a second span, less the state restore inside it) | yes |
+| `infer.join` / `infer.sample` | the one-row prefill from its dispatch to its logits on the host (a fully cached prompt: its copy-on-write pass); from there to the row's first token — the window group's reserve, the tree's insert, the audit's copy, the scalar draw | yes |
+| `infer.state_restore` / `infer.state_snapshot` | a model with recurrent state: copying a snapshot into the joining row; finding the slot its own snapshot goes to | yes |
+| `infer.chunk` | one chunk round of a pass with rows live: deadline kills and the edge scan (its own bookkeeping), then `infer.decode`, `infer.rebid`, and `infer.collect` + `infer.emit` of the oldest chunk in flight | no |
+| `infer.emit` | the host work behind sampled tokens — pieces, streaming appends (`infer.flush`, a sum inside it), finalize, pages freed: one span a collected CHUNK and one a join's first token, never one a token | yes |
+| `infer.decode` / `infer.collect` | the async dispatch of a chunk; the blocked wait for the oldest chunk in flight (`pend.block()`) | yes |
+| `infer.rebid` | the shard re-bid | yes |
+| `infer.handoff` / `infer.adopt` | a prefill lane: streaming the first token, exporting the pages, landing the record, the DECODE_READY flip (its `infer.join` spans map + prefill + draw); a decode lane: the claim, the page import, the row's seating | yes |
+| `infer.window_release` | a sum read from `WindowPages.release_s`, inside `infer.join` and `infer.decode` | no |
+
+The beat runs at the head of a pass, so a heartbeat holds whole passes
+only and the difference of two heartbeats accounts for the time between
+them: `infer.loop` less its leaves is the loop's own bookkeeping
+(deadline kills, the edge scan), well under a percent.  `infer.admit`
++ `infer.chunk` + `infer.beat` is the loop's busy time; `decode_rows`
+over it gives decoded row-steps a second, the continuous reading of a
+rate that the benchmark's closed loops quantise, and the benchmark's
+shares stand on it too, because its two heartbeats are further apart
+than its window and what lies between is idle.  Where a
+request's event list wants a phase's number too, the phase is
+`tracer.annotation(name)` around the work and the lane's
+`span(row, name, ms)` after it, both from one `perf_counter` pair;
+elsewhere it is `tracer.span(name, leaf=True)`.  Every `infer.*`
+histogram is in `spt metrics` as `sptpu_stage_ms{stage=...}` and in the
+heartbeat's `quantiles`.
 
 ### Trace-id convention (`engine/protocol.py`)
 

@@ -264,6 +264,75 @@ class TestByteExactness:
         assert pf["handoff_wire_mb"] == 0
 
 
+class TestLoopAccounting:
+    def test_lanes_open_their_phases_and_handoff_adopt_land(
+            self, model, monkeypatch):
+        """Both lanes run the loop accounting of the unified lane
+        (protocol.CONT_LOOP_PHASES): on each lane's thread the leaves
+        never nest and `loop` / `admit` open no annotation; the
+        prefill lane's round is gather, prepare, join, handoff, the
+        decode lane's gather and adopt; one `infer.handoff` and one
+        `infer.adopt` a handed-off request."""
+        from libsplinter_tpu.engine import completer as cmod
+        from libsplinter_tpu.utils import trace as tmod
+
+        events: list[tuple[int, str, str]] = []
+
+        class _Ann:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                events.append((threading.get_ident(), self.name, "in"))
+
+            def __exit__(self, *exc):
+                events.append((threading.get_ident(), self.name, "out"))
+                return False
+
+        monkeypatch.setattr(cmod.tracer, "enabled", True)
+        monkeypatch.setattr(tmod, "_annotation", _Ann)
+        cmod.tracer.reset()
+        try:
+            _, stats = _serve("acct", _split, model, PROMPTS,
+                              joiner=JOINER)
+            snap = cmod.tracer.snapshot()
+        finally:
+            cmod.tracer.reset()
+        pf, dl = stats
+        assert pf["handoffs"] >= 4 and dl["adopted"] == pf["handoffs"]
+        assert snap["infer.handoff"]["n"] == pf["handoffs"]
+        assert snap["infer.adopt"]["n"] == dl["adopted"]
+        assert snap["infer.join"]["n"] >= pf["handoffs"]
+        for p in ("loop", "admit", "chunk", "idle", "gather", "prepare",
+                  "emit", "decode", "collect"):
+            assert snap[f"infer.{p}"]["n"] > 0, p
+        by_thread: dict[int, list] = {}
+        for tid, name, what in events:
+            by_thread.setdefault(tid, []).append((name, what))
+        lanes = {}
+        for tid, evs in by_thread.items():
+            open_now = None
+            for name, what in evs:
+                if what == "in":
+                    assert open_now is None, (open_now, name)
+                    open_now = name
+                else:
+                    assert open_now == name, (open_now, name)
+                    open_now = None
+            names = {n for n, _ in evs}
+            assert not names & {"infer.loop", "infer.admit",
+                                "infer.chunk"}
+            lanes["prefill" if "infer.handoff" in names
+                  else "decode"] = names
+        assert {"infer.gather", "infer.prepare", "infer.join",
+                "infer.handoff", "infer.idle"} <= lanes["prefill"]
+        assert not lanes["prefill"] & {"infer.adopt", "infer.decode"}
+        assert {"infer.gather", "infer.adopt", "infer.decode",
+                "infer.collect", "infer.emit",
+                "infer.idle"} <= lanes["decode"]
+        assert not lanes["decode"] & {"infer.handoff", "infer.prepare"}
+
+
 class TestPhaseAwareQoS:
     def test_prefill_fast_fails_deadline_inside_prefill_wall(
             self, model):

@@ -805,6 +805,39 @@ def test_run_loop_is_fully_accounted(traced):
         Store.unlink(name)
 
 
+def test_returned_next_drain_counts_who_came_back(store):
+    """`returned_next_drain`: at each gather, the requests whose slot
+    the PREVIOUS serviced drain answered (a client keeps its key).  An
+    idle gather in between changes nothing; a client that skips a
+    serviced drain is not counted; the heartbeat carries the count
+    beside `served`."""
+    rng = np.random.default_rng(23)
+    vecs = _fill_docs(store, 8, rng)
+    sr = Searcher(store)
+    sr.attach()
+    a, b, c = "__sqtmp_a", "__sqtmp_b", "__sqtmp_c"
+    _request(store, a, vecs[0], k=2)
+    _request(store, b, vecs[1], k=2)
+    assert sr.run_once() == 2
+    assert sr.stats.returned_next_drain == 0     # nobody before them
+    _request(store, a, vecs[2], k=2)             # a is back at once,
+    _request(store, c, vecs[3], k=2)             # c is new
+    assert sr.run_once() == 2
+    assert sr.stats.returned_next_drain == 1
+    assert sr.run_once() == 0                    # an idle gather
+    _request(store, b, vecs[4], k=2)             # b sat a drain out
+    assert sr.run_once() == 1
+    assert sr.stats.returned_next_drain == 1
+    _request(store, b, vecs[5], k=2)             # and is back at once
+    _request(store, a, vecs[6], k=2)             # a sat b's drain out
+    assert sr.run_once() == 2
+    assert sr.stats.returned_next_drain == 2
+    assert sr.stats.served == 7
+    sr.publish_stats()
+    snap = json.loads(store.get(P.KEY_SEARCH_STATS).rstrip(b"\0"))
+    assert snap["returned_next_drain"] == 2 and snap["served"] == 7
+
+
 # ---------------------------------- the journal-fed lane and its mask
 
 def _mask_spy(sr):

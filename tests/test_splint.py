@@ -355,6 +355,72 @@ def test_live_registry_has_the_loop_phases(splint):
     assert "idle" not in reg.stage_names()
 
 
+CONT_PHASES_SRC = (
+    'CONT_INFER_STAGES = ("join", "sample", "decode", "collect")\n'
+    'CONT_LOOP_PHASES = ("loop", "idle", "beat", "admit", "chunk",\n'
+    '                    "gather", "prepare", "emit", "rebid")\n')
+PROTO_CONT_PHASES = PROTO_OK + CONT_PHASES_SRC
+
+
+@pytest.mark.parametrize("call", [
+    "tracer.span('infer.loop')",
+    "tracer.span('infer.admit')",
+    "tracer.span('infer.chunk')",
+    "tracer.span('infer.gather', leaf=True)",
+    "tracer.span('infer.emit', leaf=True)",
+    "tracer.annotation('infer.join')",
+    "tracer.record('infer.collect', 1.0)"])
+def test_cont_loop_phase_from_the_tuple_passes(splint, R, core, runner,
+                                               call):
+    """The continuous lane's run-loop phases are pinned in
+    CONT_LOOP_PHASES, beside CONT_INFER_STAGES: SPL107 reads both
+    under the infer. prefix."""
+    fs = run_rule(splint, R, core, runner, "SPL107",
+                  proto=PROTO_CONT_PHASES,
+                  files={"libsplinter_tpu/engine/foo.py":
+                         f"def f(tracer):\n    {call}\n"})
+    assert fs == []
+
+
+@pytest.mark.parametrize("call,typo", [
+    ("tracer.span('infer.gathr', leaf=True)", "gathr"),
+    ("tracer.span('search.gather', leaf=True)", "gather"),
+    ("tracer.annotation('infer.sweep_stages')", "sweep_stages")])
+def test_misspelt_cont_loop_phase_flagged(splint, R, core, runner, call,
+                                          typo):
+    """A typo, a completer phase under the searcher's prefix, or a
+    searcher phase under the completer's still fails the lint."""
+    fs = run_rule(splint, R, core, runner, "SPL107",
+                  proto=PROTO_PHASES + CONT_PHASES_SRC,
+                  files={"libsplinter_tpu/engine/foo.py":
+                         f"def f(tracer):\n    {call}\n"})
+    assert len(fs) == 1 and typo in fs[0].message
+
+
+def test_span_helper_rejects_a_loop_phase(splint, R, core, runner):
+    """Phases are spans only: the per-request span(row, name, ms)
+    helper takes stage names, so a phase there is flagged."""
+    src = ("def f(span, r):\n"
+           "    span(r, 'join', 1.0)\n"
+           "    span(r, 'gather', 1.0)\n")
+    fs = run_rule(splint, R, core, runner, "SPL107",
+                  proto=PROTO_CONT_PHASES,
+                  files={"libsplinter_tpu/engine/foo.py": src})
+    assert len(fs) == 1 and "gather" in fs[0].message
+
+
+def test_live_registry_has_the_cont_loop_phases(splint):
+    """(d) CONT_LOOP_PHASES is a phases tuple, not a stages tuple: it
+    does not size the per-request flight record."""
+    reg = splint.extract_registry()
+    assert reg.phases["CONT_LOOP_PHASES"] == (
+        "loop", "idle", "beat", "admit", "chunk", "gather", "prepare",
+        "emit", "rebid")
+    assert "CONT_LOOP_PHASES" not in reg.stages
+    for phase in ("gather", "prepare", "emit", "beat", "admit"):
+        assert phase not in reg.stage_names()
+
+
 def test_span_helper_stage_checked(splint, R, core, runner):
     src = ("def f(span, r):\n"
            "    span(r, 'wake', 1.0)\n"

@@ -252,6 +252,131 @@ def test_searcher_heartbeat_keeps_what_is_read_at_2k(tmp_path):
         Store.unlink(name)
 
 
+def _completer_payload():
+    """The continuous lane's traced heartbeat with every section filled
+    at the magnitudes of a long-running pangu deployment (the shape of
+    completer.publish_stats + attach_trace_sections): counts to 1e5,
+    totals to 1e7 ms, every loop phase and every stage in `spans`."""
+    import dataclasses
+
+    from libsplinter_tpu.engine.completer import CompleterStats
+
+    stats = {f.name: 98765 for f in dataclasses.fields(CompleterStats)}
+    names = [f"infer.{p}" for p in (*P.CONT_LOOP_PHASES,
+                                    *P.CONT_INFER_STAGES)]
+    spans = {n: {"n": 9876543, "total_ms": 98765432.1, "max_ms": 17232.9}
+             for n in names}
+    quant = {n[len("infer."):]: {
+        "n": 9876543, "total_ms": 98765432.123, "max_ms": 17232.912,
+        "p50_ms": 21.2471, "p90_ms": 33.5127, "p95_ms": 40.1234,
+        "p99_ms": 71.4682} for n in (*names, "infer.e2e")}
+    prog = {"n": 9876543, "compiles": 2, "runtime_compiles": 0,
+            "total_ms": 98765432.1, "p50_ms": 21.247, "p99_ms": 71.468}
+    gauges = ("inflight_depth", "bp_memo", "pages_free", "pages_used",
+              "live_tokens", "pages_used_peak", "prefix_hits",
+              "prefix_misses", "prefix_hit_tokens", "prefix_evictions",
+              "prefix_shared_pages", "prefix_evictable",
+              "prefix_cow_copies", "prefix_bytes_saved",
+              "audit_records", "compile_events", "generation")
+    return {
+        **stats, **{g: 9876543210 for g in gauges},
+        "spans_obs": {"committed": 98765, "recovered": 0, "dropped": 0,
+                      "pending": 0},
+        "kv_dtype": "bfloat16", "pool_mb": 1887.437,
+        "pool_mb_peak": 1887.437,
+        "expert_totals": [9876543210] * 16,
+        "startup_ms": {"process": 13185.3, "jax": 9876.5,
+                       "weights": 98765.4, "warmup": 98765.4,
+                       "total": 220592.6},
+        "devtime": {k: prog for k in (
+            "paged_chunk", "suffix_prefill", "bucket_prefill",
+            "cow_copy", "sample", "state_copy", "state_zero")},
+        "spans": spans, "quantiles": quant,
+        "recorder": {"recorded": 98765, "dropped": 98765,
+                     "slow_promoted": 98765,
+                     "slow_threshold_ms": 98765.432},
+        "slow_log": [{"id": (1 << 24) | i, "key": f"__cq_{i:06d}",
+                      "wall_ms": 27934.429, "ts": 1790550501.957,
+                      "slow_threshold_ms": 98765.432,
+                      "events": [[s, 98765.432]
+                                 for s in P.CONT_INFER_STAGES]}
+                     for i in range(4)]}
+
+
+@pytest.mark.obs
+def test_a_dropped_section_never_overwrites_a_counter(tmp_path):
+    """The rehearsals' stores hold 4,096 B: the traced completer
+    heartbeat sheds sections there, and its `truncated` counter (which
+    the benchmark reads as a fault counter) must stay the count."""
+    name = f"/spt-stats-cp4-{tmp_path.name}"
+    Store.unlink(name)
+    st = Store.create(name, nslots=64, max_val=4096, vec_dim=8)
+    try:
+        payload = _completer_payload()
+        payload["truncated"] = 0
+        P.publish_heartbeat(st, "__hb", payload)
+        snap = json.loads(st.get("__hb").rstrip(b"\0"))
+        assert "quantiles" not in snap and "slow_log" not in snap
+        assert snap["truncated"] == 0 and snap["truncated"] is not True
+        assert snap["spans"] == payload["spans"]
+        # a payload with no such counter still gets the mark
+        del payload["truncated"]
+        P.publish_heartbeat(st, "__hb", payload)
+        snap = json.loads(st.get("__hb").rstrip(b"\0"))
+        assert snap["truncated"] is True
+    finally:
+        st.close()
+        Store.unlink(name)
+
+
+@pytest.mark.obs
+def test_completer_heartbeat_keeps_what_is_read_at_16k(tmp_path):
+    """At pangu's cell's max_val (16,384) the traced continuous-lane
+    heartbeat, with the loop's phases beside the stages, still lands
+    with `spans`, `devtime`, `startup_ms` and every scalar intact;
+    whatever has to go goes from HEARTBEAT_DROP_FIRST, in its order."""
+    import dataclasses
+
+    from libsplinter_tpu.engine.completer import CompleterStats
+
+    name = f"/spt-stats-cp-{tmp_path.name}"
+    Store.unlink(name)
+    st = Store.create(name, nslots=64, max_val=16384, vec_dim=8)
+    try:
+        spy = _SetSpy(st)
+        payload = _completer_payload()
+        P.publish_heartbeat(spy, "__hb", payload)
+        raw = st.get("__hb").rstrip(b"\0")
+        assert len(raw) <= 16384
+        snap = json.loads(raw)
+        dropped = set(payload) - set(snap)
+        assert dropped <= set(P.HEARTBEAT_DROP_FIRST), dropped
+        assert sorted(dropped) == sorted(
+            P.HEARTBEAT_DROP_FIRST[:len(dropped)])
+        for sec in ("spans", "devtime", "startup_ms", "expert_totals",
+                    "spans_obs"):
+            assert snap[sec] == payload[sec], sec
+        # every scalar, the completer's own `truncated` (completions
+        # cut at the slot's size) with them: the heartbeat's mark
+        # never takes a counter's place
+        for f in dataclasses.fields(CompleterStats):
+            assert snap[f.name] == payload[f.name], f.name
+        for k, v in payload.items():
+            if not isinstance(v, (dict, list)):
+                assert snap[k] == v, k
+        # what the benchmark's metrics read
+        want = {f"infer.{p}" for p in (*P.CONT_LOOP_PHASES,
+                                       *P.CONT_INFER_STAGES)}
+        assert set(snap["spans"]) == want
+        assert snap["spans"]["infer.loop"]["total_ms"] == 98765432.1
+        assert snap["spans"]["infer.join"]["n"] == 9876543
+        assert snap["devtime"]["paged_chunk"]["total_ms"] == 98765432.1
+        assert snap["startup_ms"]["total"] == 220592.6
+    finally:
+        st.close()
+        Store.unlink(name)
+
+
 def test_completer_stats_heartbeat(tmp_path):
     name, st = _mkstore(tmp_path.name)
     try:

@@ -28,6 +28,29 @@
  *   A writer that dies mid-write leaves an odd epoch; spt_retrain() is the
  *   sanctioned recovery (drives the epoch backward — "revalidate me").
  *
+ * Threads of one process, and a binding with an interpreter lock: no call
+ *   here takes a process-wide lock, and the calls fall into two kinds.
+ *   BOUNDED ones — a probe, at most one value (max_val bytes) or vector
+ *   (vec_dim floats) copied, a few atomics, a write to the non-blocking
+ *   event bus; the slot seqlock is a TRY-lock that returns -EAGAIN — and
+ *   ones that WAIT (spt_poll, spt_poll_labels, spt_signal_wait,
+ *   spt_bus_wait) or are LINEAR in the slots, a batch or a text
+ *   (spt_enumerate*, spt_list, spt_changed_since, spt_epochs*,
+ *   spt_vec_gather, spt_vec_commit_batch, spt_purge, spt_header_snapshot,
+ *   spt_wptok_*).  The Python binding (libsplinter_tpu/_native.py,
+ *   KEEPS_LOCK) KEEPS the interpreter lock across the seven bounded calls
+ *   of the request protocol — spt_vec_set, spt_find_index, spt_set,
+ *   spt_get, spt_label_or, spt_get_labels, spt_bump — and drops it across
+ *   every other: dropping and re-taking it costs a round of hand-overs
+ *   among the awake threads, ~30 us a waiter, where the call is ~1 us.
+ *   The rule a new symbol is classified by: bounded work, no sleep, no
+ *   syscall that can block, no callback into the interpreter; the retry on
+ *   -EAGAIN stays the caller's, and yields.  The worst case of a bounded
+ *   call is its probe: a miss walks to the first NEVER-USED slot (~73
+ *   slots at 92% load), and in a table whose free slots are all tombstones
+ *   that is every slot, once, with the lock held.  The Rust and TypeScript
+ *   bindings have no such lock.
+ *
  * Change journal (format version 2): no slot's epoch moves, and no label
  *   is raised, without a record.  A ring of SPT_JOURNAL_CAP slot indices in
  *   the shared mapping with a 64-bit head that only grows; taking a slot's

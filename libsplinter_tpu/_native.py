@@ -3,6 +3,21 @@
 Builds on demand with make if the shared object is missing or older than its
 sources, then binds the full C ABI via ctypes.  The C prototypes mirror
 native/include/sptpu.h exactly.
+
+**Threads and the interpreter lock.**  `ctypes.CDLL` drops the
+interpreter lock on the way into every call and has to win it back on
+the way out; with many client threads awake at once each store call
+then costs a round of hand-overs (~30 us a waiter) where the call
+itself is a microsecond.  So the shared object is opened twice — the
+second handle through `ctypes.PyDLL`, which KEEPS the lock; `dlopen`
+gives the same mapping, so the library's state exists once — and the
+symbols in `KEEPS_LOCK` are taken from that handle: `spt_vec_set`,
+`spt_find_index`, `spt_set`, `spt_get`, `spt_label_or`,
+`spt_get_labels`, `spt_bump`.  Every other symbol drops the lock as
+before, and every call that waits, sleeps, or is linear in the slots or
+in a batch must.  `get_lib().spt_set` IS the lock-keeping function:
+there is one path, no option.  A new symbol is classified by the rule
+above `KEEPS_LOCK`; `tests/test_native_binding.py` fails until it is.
 """
 from __future__ import annotations
 
@@ -92,20 +107,64 @@ def _needs_build() -> bool:
     return False
 
 
+# Symbols bound through `ctypes.PyDLL`: the call KEEPS the interpreter
+# lock.  The request protocol's calls (what a client thread makes
+# between an answer and its next request; PR 35), and no more.
+#
+# The rule for membership, checked against the C — all four hold:
+#   * bounded work: a probe, at most one value or vector copied, a few
+#     atomics — nothing linear in the slots or in a batch;
+#   * no sleep and no wait: `spt__lock` is a TRY-lock that returns
+#     -EAGAIN (store.c); the retry and its `time.sleep(0)` are Python's
+#     (`store._retry`), so a contended slot still yields the lock;
+#   * no syscall that can block: `spt_bump`'s and every write's fan-out
+#     is counter increments and one write to a NON-BLOCKING eventfd
+#     (coord.c `spt__fanout` / `bus_notify`; a process's first write
+#     after an arming also attaches to the bus: two pidfd syscalls);
+#   * no callback into Python.
+# Whatever waits (`spt_poll`, `spt_poll_labels`, `spt_signal_wait`,
+# `spt_bus_wait`) or walks the slots or a batch (`spt_enumerate*`,
+# `spt_list`, `spt_changed_since`, `spt_epochs*`, `spt_vec_gather`,
+# `spt_vec_commit_batch`, `spt_purge`, the tokenizer) stays off it: held
+# across such a call the lock would stop every other thread.
+#
+# The worst case, with the lock held.  `spt__probe_find` walks from the
+# key's home slot to the first NEVER-USED slot.  At the search cell's
+# 91.7% load (1,441,792 keys in 1,572,864 slots, linear probing) a hit
+# costs ~6.5 probes and a miss ~73 on average: under a microsecond.  A
+# table whose free slots are all TOMBSTONES has no never-used slot and a
+# miss walks all `nslots`: 1,572,864 slot headers there, milliseconds,
+# once a call — today that stalls one thread, bound here it stalls the
+# process.  (`spt_purge` does not reset tombstones; a store that churns
+# keys without end wants a rebuild either way.)  A value copy is at most
+# `max_val` bytes and a vector `vec_dim` floats: 2,048 B and 3,072 B in
+# the search cell; the largest `max_val` a benchmark cell creates is
+# 32,768 B (the kimi and trinity stores: prompts of tens of KB), a
+# `memcpy` of 1-3 us, and `spt_set` under `MOP_FULL` zeroes `max_val`
+# bytes more.
+KEEPS_LOCK = frozenset({
+    "spt_vec_set", "spt_find_index", "spt_set", "spt_get",
+    "spt_label_or", "spt_get_labels", "spt_bump",
+})
+
+
 def load() -> C.CDLL:
     if _needs_build():
         _build()
     lib = C.CDLL(str(_LIB_PATH), use_errno=True)
-    _declare(lib)
+    # the same mapping a second time (dlopen counts references): one
+    # copy of the library's state, two calling conventions
+    _declare(lib, C.PyDLL(str(_LIB_PATH), use_errno=True))
     return lib
 
 
-def _declare(lib: C.CDLL) -> None:
+def _sigs() -> dict[str, tuple]:
+    """Every bound symbol: name -> (restype, argtypes)."""
     P = C.c_void_p
     u32, u64, i32, i64 = C.c_uint32, C.c_uint64, C.c_int32, C.c_int64
     cs = C.c_char_p
 
-    sigs = {
+    return {
         "spt_create": (P, [cs, u32, u32, u32, u32]),
         "spt_open": (P, [cs, u32]),
         "spt_open_numa": (P, [cs, u32, i32, C.POINTER(i32)]),
@@ -209,8 +268,12 @@ def _declare(lib: C.CDLL) -> None:
                                          u32, C.POINTER(u32),
                                          C.POINTER(u32)]),
     }
-    for name, (res, args) in sigs.items():
-        fn = getattr(lib, name)
+
+
+def _declare(lib: C.CDLL, keeps: C.PyDLL) -> None:
+    for name, (res, args) in _sigs().items():
+        fn = getattr(keeps if name in KEEPS_LOCK else lib, name)
+        setattr(lib, name, fn)       # get_lib().<name> IS this function
         fn.restype = res
         fn.argtypes = args
 

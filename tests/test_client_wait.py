@@ -321,17 +321,3 @@ def test_counters_lose_no_update_under_threads(store):
         sys.setswitchinterval(old)
     assert _delta(before) == {"waits": n_threads * n_each, "woken": 0,
                               "slice_timeouts": 0, "repulses": 0}
-
-
-# -- the waits drop the interpreter lock ------------------------------------
-
-def test_waiting_store_calls_drop_the_lock():
-    """A client thread inside its wait must not hold the interpreter:
-    every call that waits is bound so that ctypes drops the lock."""
-    import ctypes
-
-    from libsplinter_tpu import _native as N
-    lib = N.get_lib()
-    for name in ("spt_poll", "spt_poll_labels", "spt_signal_wait",
-                 "spt_bus_wait"):
-        assert not getattr(lib, name)._flags_ & ctypes._FUNCFLAG_PYTHONAPI, name

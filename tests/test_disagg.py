@@ -77,6 +77,16 @@ def _await(st, keys, bit=P.LBL_READY, timeout=120.0):
     return False
 
 
+def _settles(read, want, timeout=5.0):
+    """A lane counts AFTER its record is readable (READY is raised,
+    then the wire keys and stamps go, then the counter moves): a
+    client that has just seen the record waits for the count."""
+    deadline = time.monotonic() + timeout
+    while read() != want and time.monotonic() < deadline:
+        time.sleep(0.005)
+    return read() == want
+
+
 def _run_bg(daemon, stop_after=180.0):
     th = threading.Thread(
         target=daemon.run_continuous,
@@ -354,11 +364,11 @@ class TestPhaseAwareQoS:
             assert _await(st, ["doomed"], timeout=60)
             rec = P.parse_error_payload(st.get("doomed"))
             assert rec["err"] == "deadline_expired"
-            assert pf.stats.deadline_expired == 1
+            assert _settles(lambda: pf.stats.deadline_expired, 1)
             # the live request got the full prefill + handoff
             assert _await(st, ["live"], bit=P.LBL_DECODE_READY,
                           timeout=60)
-            assert pf._lane_stats["handoffs"] == 1
+            assert _settles(lambda: pf._lane_stats["handoffs"], 1)
         finally:
             pf.stop()
             if th:
@@ -390,7 +400,7 @@ class TestPhaseAwareQoS:
             assert _await(st, ["q"], timeout=60)
             rec = P.parse_error_payload(st.get("q"))
             assert rec["err"] == "deadline_expired"
-            assert dl.stats.deadline_expired == 1
+            assert _settles(lambda: dl.stats.deadline_expired, 1)
             assert dl._lane_stats["adopted"] == 0
             assert _no_handoff_keys(st)
         finally:

@@ -158,6 +158,11 @@ class CompleterStats:
     # page
     window_resumes: int = 0
     window_cut_tokens: int = 0
+    # joins whose mapped tail page some other live row held already (a
+    # document's tail under several questions), and window pages rows
+    # gave back from inside a decode chunk round (as against at a join)
+    window_tail_shares: int = 0
+    window_decode_slides: int = 0
 
 
 class Completer:
@@ -1507,6 +1512,9 @@ class Completer:
                             # serving it
                             cache.map_shared(r, hit_bids)
                             if wgroup is not None:
+                                self.stats.window_tail_shares += any(
+                                    wgroup.refcounts[b] > 0
+                                    for b in wtail)
                                 wgroup.map_tail(
                                     r, len(hit_bids) - len(wtail), wtail)
                                 self.stats.window_resumes += int(not wcut)
@@ -1877,14 +1885,17 @@ class Completer:
                 if sharded:
                     fault("completer.sharded_dispatch")
                 wgroup = getattr(cache, "window", None)
-                w_s0 = wgroup.release_s if wgroup is not None \
-                    else 0.0
+                w_s0, w_n0 = (wgroup.release_s, wgroup.released) \
+                    if wgroup is not None else (0.0, 0)
                 pend = m.paged_decode_chunk_async(
                     cache, fresh, step, carry=carry)
-            if wgroup is not None and tracer.enabled:
+            if wgroup is not None:
                 # the chunk's rows slid: what they gave back
-                tracer.record("infer.window_release",
-                              (wgroup.release_s - w_s0) * 1e3)
+                self.stats.window_decode_slides += \
+                    wgroup.released - w_n0
+                if tracer.enabled:
+                    tracer.record("infer.window_release",
+                                  (wgroup.release_s - w_s0) * 1e3)
             live = [(r, rows[r]["serial"]) for r in range(B)
                     if rows[r] is not None]
             if tracer.enabled:
@@ -2326,7 +2337,8 @@ class Completer:
             # live keys the attention kernels were asked for
             payload.update(getattr(m_now, "attn_work", {}))
         else:
-            for k in ("window_resumes", "window_cut_tokens"):
+            for k in ("window_resumes", "window_cut_tokens",
+                      "window_tail_shares", "window_decode_slides"):
                 payload.pop(k, None)  # one page group: dead gauges
         pc = self.prefix_cache
         if pc is not None:

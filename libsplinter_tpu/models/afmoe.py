@@ -1,12 +1,18 @@
 """A decoder stack whose layers MIX sliding-window and global
-attention over grouped key/value heads, with a sparse shared-expert
-MoE, as the AFMoE family of public configs describes it (Trinity-Mini
-among them), served through the completion daemon's paged lane as one
+attention over grouped key/value heads, with a sparse (shared-expert
+or not) MoE, served through the completion daemon's paged lane as one
 chip's share of an expert-parallel deployment (models/mla.py holds the
 share's conventions and the weight recipe; this module reuses its
-feed-forward and its chunk hand-off).
+feed-forward and its chunk hand-off).  The block is a DESCRIPTION A
+KIND of layer (`AttnKind`: key/value heads, key and value widths,
+rotated dims and their base, window, sink) plus the block's own
+switches (`out_gate`, `qk_norm`, `sandwich_norm`, `mup`,
+`value_scale`, `n_shared_experts`), and two public families are
+settings of it: AFMoE (Trinity-Mini; the equations just below) and
+MiMo-V2-Flash (further down).  Every switch is static: a setting
+compiles the operations it names and no others.
 
-The layer (x: hidden; matrices without bias; RMSNorm eps
+AFMoE's layer (x: hidden; matrices without bias; RMSNorm eps
 `rms_norm_eps`; four norms a layer):
 
     x0 = E[token] * sqrt(hidden_size)                  (mup_enabled)
@@ -25,12 +31,29 @@ The layer (x: hidden; matrices without bias; RMSNorm eps
              ones (sigmoid scores, normalised over the selection,
              times route_scale).
 
-PAGES IN TWO GROUPS.  The cache holds K (after its norm and, on a
-sliding layer, its rotation) and V a token a layer.  A sliding layer
+MiMo-V2-Flash's layer (plain pre-norm: two norms, no output gate, no
+q/k norm, no muP, no shared expert; eps `layernorm_epsilon`):
+
+    x0 = E[token];   h = x + Attn(N1(x));   y = h + FFN(N2(h))
+
+    Attn(u): q = u W_Q -> heads x dk;  k = u W_K -> KH x dk;
+             v = (u W_V -> KH x dv) * attention_value_scale
+             KH, and RoPE's base, by the layer's kind; RoPE on the
+             LEADING rotary dims of q and k only (partial_rotary_factor)
+             s_ij = q_i . k_j / sqrt(dk); the window and the causal
+             mask as above
+             sink layer (the window kind): p_ij = exp(s_ij) /
+                 (exp(b_h) + sum_j exp(s_ij)), b_h learned, one a head
+                 — a key with no value (ops/paged_attention)
+             Attn = concat_h(sum_j p_ij v_j) W_O        (heads x dv -> H)
+
+PAGES IN TWO GROUPS.  The cache holds K (after its norm and its
+rotation, where it has them) and V a token a layer, each in a pool of
+its own width.  A sliding layer
 needs the last `sliding_window` tokens' only, so the model describes
 its pages as two groups (`page_layout`, decoder.PageLayout.window):
-the GLOBAL layers' pool (n_blocks, L_global, kv_heads, page, d) and
-the WINDOW layers' pool (n_blocks_w, L_window, kv_heads, page, d),
+the GLOBAL layers' pools (n_blocks, L_global, kv_heads, page, dk | dv)
+and the WINDOW layers' (n_blocks_w, L_window, kv_heads_w, page, dk | dv),
 each with its own table, page count and free list
 (decoder.PagedKVCache / WindowPages).  The cache gives a window
 group's page back when its row has slid past it; the attention
@@ -44,8 +67,9 @@ prompt the prefix cache does not know is the same program from an
 empty row, looped in its widest bucket, giving window pages back as it
 passes the window — no separate bucket prefill), so their keys and
 values are written a page at a time.  The decode chunk is mla's: n
-steps with the sampler in graph.  The layers after the dense ones
-repeat with the period of the layer pattern, and the periods run as
+steps with the sampler in graph.  Past a head that may be irregular
+the layers repeat with the period of the layer pattern
+(`WindowMoeConfig.plan`), and the periods run as
 ONE compiled body under lax.scan (the stack's weights a period's
 stacked; a layer is told its index in its group's pool by the loop's
 counter): 32 layers compile as 8.
@@ -65,6 +89,24 @@ this family's own, restated by the plain reference:
     token's top-k moved the logits as far as a window layer that lost
     a page of its keys (measured on the chip, PERF.md section 6), and
     the attention over paged keys is what this family's pages are for.
+A block WITHOUT the sandwich (MiMo) has no norm behind a branch, so
+the matrices that write into the stream carry the scale (models/kda.py's
+recipe) and the embedding has std 1:
+    w_down, experts.<e>.down: std s / sqrt(fan_in);
+    w_q: std PRE_Q_GAIN[kind] / sqrt(hidden) — a global layer's scores
+    at std 3, so that of 32.9k keys a few dozen carry a query's mass
+    (at std 1 every key carries 1/12,000th and the layer's output is
+    the same mean for every query, which no fault in a page could
+    move); a window layer's at std 1;
+    sink (heads,) float32, uniform on [2, 5]: at unit-scale scores
+    over 128 keys the sink takes 3% (b = 2) to 41% (b = 5) of a row's
+    softmax mass, 14% at the mean;
+    w_o: std ATTN_OUT x s x PRE_O_UNIT[kind] / sqrt(fan_in), PRE_O_UNIT
+    the inverse of the std of a head's output under the lines above
+    at the benchmark cell's context (an average over keys is narrower
+    than a value: 0.082 a window layer, 0.117 a global one at 32.9k
+    keys; CPU arithmetic, PERF.md section 6), so that the attention
+    branch writes at 2 s as AFMoE's does.
 """
 from __future__ import annotations
 
@@ -87,9 +129,34 @@ KINDS = ("window", "full")
 # the seeded post-branch norms' means, in units of 1/sqrt(2 x layers)
 # (module docstring, WEIGHTS)
 ATTN_OUT, MLP_OUT = 2.0, 0.5
+# a block without the sandwich: the gain of w_q and the inverse of a
+# head's output std, by kind (module docstring, WEIGHTS)
+PRE_Q_GAIN = {"window": 1.0, "full": 3.0}
+PRE_O_UNIT = {"window": 12.0, "full": 8.5}
+SINK_RANGE = (2.0, 5.0)
+# identical layers in a row that `plan` puts under a scan as periods
+# of one: from four on (a cut stack whose pattern repeats nowhere still
+# compiles its run of window layers once)
+RUN_SCAN = 4
 # pages of the widest suffix program: a follow-up turn of a few hundred
 # tokens fits one call, a cold prompt loops in it
 SUFFIX_PAGES = 5
+
+
+@dataclasses.dataclass(frozen=True)
+class AttnKind:
+    """What the layers of ONE kind ("window" | "full") attend with."""
+    kv_heads: int
+    qk_dim: int                   # a head's query and key width
+    v_dim: int                    # a head's value width
+    rotary_dim: int               # leading dims of q, k rotated (0: none)
+    rope_base: float
+    window: int                   # tokens attended (0: every one)
+    sink: bool = False            # a learned logit a head in the softmax
+    # the keys' pages hold a token a COLUMN, (kv_heads, qk_dim, page):
+    # for a key width that is no multiple of the 128-lane tile
+    # (ops/paged_attention, "KEYS A TOKEN A COLUMN")
+    k_cols: bool = False
 
 
 @dataclasses.dataclass(frozen=True)
@@ -98,8 +165,8 @@ class WindowMoeConfig:
     hidden: int
     kinds: tuple[str, ...]        # a kind ("window" | "full") a kept layer
     heads: int
-    kv_heads: int
-    head_dim: int
+    kv_heads: int                 # AFMoE's one setting of both kinds;
+    head_dim: int                 # `attn_kinds`, where given, overrides
     window: int                   # tokens a window layer attends
     dense_layers: int             # leading dense layers among `layers`
     dense_mlp_dim: int
@@ -121,9 +188,23 @@ class WindowMoeConfig:
     # layers of the WHOLE model (the share may keep fewer): what the
     # norms that write into the residual stream are scaled by
     model_layers: int | None = None
+    # the block's switches (module docstring); the defaults are AFMoE's
+    out_gate: bool = True         # Attn = (o * sigmoid(u W_G)) W_O
+    qk_norm: bool = True          # RMSNorm over a head's q and k
+    sandwich_norm: bool = True    # a norm behind each branch as well
+    value_scale: float = 1.0      # v <- v * value_scale
+    # ((kind, AttnKind), ...): None is AFMoE's — one kv_heads, one
+    # head_dim, RoPE(rope_base) on the window layers' whole heads only
+    attn_kinds: tuple | None = None
 
     def __post_init__(self):
         object.__setattr__(self, "kinds", tuple(self.kinds))
+        kh, d = self.kv_heads, self.head_dim
+        object.__setattr__(self, "attn_kinds", tuple(
+            self.attn_kinds or (
+                ("window", AttnKind(kh, d, d, d, self.rope_base,
+                                    self.window)),
+                ("full", AttnKind(kh, d, d, 0, self.rope_base, 0)))))
         if self.model_layers is None:
             object.__setattr__(self, "model_layers", len(self.kinds))
         if self.experts_held is None:
@@ -140,11 +221,18 @@ class WindowMoeConfig:
         if set(self.kinds) - set(KINDS) or "full" not in self.kinds:
             raise ValueError(f"layer kinds must be among {KINDS}, with "
                              "at least one full layer")
-        if self.heads % self.kv_heads or self.head_dim % 2:
-            raise ValueError("kv_heads must divide heads and head_dim "
-                             "be even")
         if "window" in self.kinds and self.window < 1:
             raise ValueError("a window layer needs sliding_window >= 1")
+        for kind in set(self.kinds):
+            a = self.attn(kind)
+            if self.heads % a.kv_heads or a.rotary_dim % 2 \
+                    or a.rotary_dim > a.qk_dim:
+                raise ValueError("kv_heads must divide heads and the "
+                                 "rotated dims be even and within "
+                                 "head_dim")
+            if a.window != (self.window if kind == "window" else 0):
+                raise ValueError(f"a {kind} layer's window is "
+                                 f"{a.window}")
 
     @classmethod
     def tiny(cls, **kw) -> "WindowMoeConfig":
@@ -162,19 +250,49 @@ class WindowMoeConfig:
     def layers(self) -> int:
         return len(self.kinds)
 
+    def attn(self, kind: str) -> AttnKind:
+        return dict(self.attn_kinds)[kind]
+
     @property
     def plan(self) -> tuple[int, int, int]:
         """(head, period, periods): the first `head` layers run
-        unrolled (whole periods of the kind pattern that cover the
-        dense layers), then `periods` identical periods of `period`
-        layers under one scanned body, then whatever is left,
-        unrolled."""
+        unrolled, then `periods` identical periods of `period` layers
+        under one scanned body, then whatever is left, unrolled.  The
+        pattern may start IRREGULARLY (MiMo: global, window x 4, then
+        periods of six): the kinds repeat with `period` from some
+        layer `first` on, the head is `first` plus the whole periods
+        that cover the dense layers, and of every (first, period) the
+        one that puts most layers under a scan of two periods or more,
+        each holding every kind of layer, is taken — the earliest
+        among equals, which is the pattern's own start where nothing
+        repeats twice.  Where no pattern repeats, RUN_SCAN or more
+        identical layers in a row are periods of one (MiMo's cut,
+        layers 0-6: the dense layer, window x 4 scanned, a tail of
+        two)."""
         n, kinds = self.layers, self.kinds
-        period = next(p for p in range(1, n + 1)
-                      if all(kinds[i] == kinds[i - p]
-                             for i in range(p, n)))
-        head = min(n, -(-self.dense_layers // period) * period)
-        return head, period, (n - head) // period
+        best = None
+        for first in range(n):
+            period = next(p for p in range(1, n - first + 1)
+                          if all(kinds[i] == kinds[i - p]
+                                 for i in range(first + p, n)))
+            head = min(n, first + -(-max(self.dense_layers - first, 0)
+                                    // period) * period)
+            periods = (n - head) // period
+            whole = set(kinds[head: head + period]) == set(kinds)
+            gain = periods * period if periods > 1 and whole else 0
+            if best is None or gain > best[0]:
+                best = (gain, head, period, periods)
+        # a run of identical layers past the dense ones is a period of
+        # one, taken where it beats every repeating pattern
+        start = self.dense_layers
+        while start < n:
+            end = start
+            while end < n and kinds[end] == kinds[start]:
+                end += 1
+            if end - start >= max(RUN_SCAN, best[0] + 1):
+                best = (end - start, start, 1, end - start)
+            start = end
+        return best[1:]
 
     def group_index(self, i: int) -> int:
         """Layer i's index within its group's pool."""
@@ -184,48 +302,67 @@ class WindowMoeConfig:
         """A layout a GROUP: the global layers' K and V side by side
         in one page, and the window layers'."""
         out = []
-        for kind, window in (("full", 0), ("window", self.window)):
-            n = self.kinds.count(kind)
+        for kind in ("full", "window"):
+            n, a = self.kinds.count(kind), self.attn(kind)
             if n:
-                shape = (n, self.kv_heads, page, self.head_dim)
                 out.append(PageLayout(
-                    (("k", shape), ("v", shape)),
-                    token_values=2 * n * self.kv_heads * self.head_dim,
-                    window=window, layers=n))
+                    (("k", (n, a.kv_heads, a.qk_dim, page) if a.k_cols
+                      else (n, a.kv_heads, page, a.qk_dim)),
+                     ("v", (n, a.kv_heads, page, a.v_dim))),
+                    token_values=n * a.kv_heads * (a.qk_dim + a.v_dim),
+                    window=a.window, layers=n))
         return tuple(out)
 
 
 # ------------------------------------------------------------- weights
 
 def _layer_params(cfg: WindowMoeConfig, seed: int, i: int) -> dict:
-    H, dt, D = cfg.hidden, cfg.dtype, cfg.head_dim
+    H, dt, kind = cfg.hidden, cfg.dtype, cfg.kinds[i]
+    a = cfg.attn(kind)
     p = f"layers.{i}."
     out_mean = 1.0 / math.sqrt(2.0 * cfg.model_layers)
+    # without a norm behind the branches the matrices that write into
+    # the stream carry the scale (module docstring, WEIGHTS)
+    pre = not cfg.sandwich_norm
 
-    def mat(name, shape):
-        return seed_tensor(seed, name, shape, 1.0 / math.sqrt(shape[0]),
-                           dt)
+    def mat(name, shape, gain=1.0):
+        return seed_tensor(seed, name, shape,
+                           gain / math.sqrt(shape[0]), dt)
 
     def norm(name, n, mean=1.0):
         return seed_tensor(seed, name, (n,), 0.1 * mean, jnp.float32,
                            mean)
 
     lp = {"ln_attn_in": norm(p + "ln_attn_in", H),
-          "ln_attn_out": norm(p + "ln_attn_out", H, ATTN_OUT * out_mean),
           "ln_mlp_in": norm(p + "ln_mlp_in", H),
-          "ln_mlp_out": norm(p + "ln_mlp_out", H, MLP_OUT * out_mean),
           # kept TRANSPOSED, (out, hidden): the layout the chip's
           # compiler asks for under both programs — as (hidden, out)
           # it copied all three at every dispatch
           # (tests/test_chip_compile.py)
-          "w_q": mat(p + "w_q", (H, cfg.heads * D)).T,
-          "w_k": mat(p + "w_k", (H, cfg.kv_heads * D)).T,
-          "w_v": mat(p + "w_v", (H, cfg.kv_heads * D)).T,
-          "w_g": mat(p + "w_g", (H, cfg.heads * D)),
-          "q_norm": norm(p + "q_norm", D),
-          "k_norm": norm(p + "k_norm", D),
-          "w_o": mat(p + "w_o", (cfg.heads * D, H))}
-    lp.update(ffn_params(cfg, seed, p, i < cfg.dense_layers, mat))
+          "w_q": mat(p + "w_q", (H, cfg.heads * a.qk_dim),
+                     PRE_Q_GAIN[kind] if pre else 1.0).T,
+          "w_k": mat(p + "w_k", (H, a.kv_heads * a.qk_dim)).T,
+          "w_v": mat(p + "w_v", (H, a.kv_heads * a.v_dim)).T,
+          "w_o": mat(p + "w_o", (cfg.heads * a.v_dim, H),
+                     ATTN_OUT * out_mean * PRE_O_UNIT[kind] if pre
+                     else 1.0)}
+    if cfg.sandwich_norm:
+        lp["ln_attn_out"] = norm(p + "ln_attn_out", H, ATTN_OUT * out_mean)
+        lp["ln_mlp_out"] = norm(p + "ln_mlp_out", H, MLP_OUT * out_mean)
+    if cfg.out_gate:
+        lp["w_g"] = mat(p + "w_g", (H, cfg.heads * a.v_dim))
+    if cfg.qk_norm:
+        lp["q_norm"] = norm(p + "q_norm", a.qk_dim)
+        lp["k_norm"] = norm(p + "k_norm", a.qk_dim)
+    if a.sink:
+        lo, hi = SINK_RANGE
+        lp["sink"] = seed_tensor(seed, p + "sink", (cfg.heads,),
+                                 (hi - lo) / math.sqrt(12.0), jnp.float32,
+                                 (lo + hi) / 2.0)
+    lp.update(ffn_params(
+        cfg, seed, p, i < cfg.dense_layers, mat,
+        (lambda name, shape: mat(name, shape, out_mean)) if pre
+        else None))
     return lp
 
 
@@ -244,8 +381,11 @@ def init_params(cfg: WindowMoeConfig, seed: int) -> dict:
             lambda *a: jnp.stack(a), *made))
         del made
     return {
+        # at unit scale once the muP multiplier, where there is one,
+        # has been applied
         "tok_emb": seed_tensor(seed, f"tok_emb.{cfg.vocab_first}",
-                               (cfg.vocab_size, H), 1.0 / math.sqrt(H),
+                               (cfg.vocab_size, H),
+                               1.0 / math.sqrt(H) if cfg.mup else 1.0,
                                dt),
         "head": [_layer_params(cfg, seed, i) for i in range(head)],
         "periods": periods,
@@ -268,12 +408,15 @@ def _normed(cfg, x, scale):
 
 
 def _rotate(x, cos, sin):
-    """Split-half rotation pairs, in float32.  x: (B, S, heads, d);
-    cos/sin: (B, S, d/2)."""
-    half = x.shape[-1] // 2
-    x1, x2 = x[..., :half], x[..., half:]
+    """Split-half rotation pairs over the LEADING 2 x cos.shape[-1]
+    dims of x, in float32; what lies behind them passes through.
+    x: (B, S, heads, d); cos/sin: (B, S, r/2)."""
+    half = cos.shape[-1]
+    x1, x2 = x[..., :half], x[..., half: 2 * half]
     cos, sin = cos[:, :, None, :], sin[:, :, None, :]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin,
+                            *([x[..., 2 * half:]]
+                              if 2 * half < x.shape[-1] else [])], -1)
 
 
 BANKED = ("exp_gate", "exp_up", "exp_down")
@@ -283,44 +426,57 @@ def _layer(cfg: WindowMoeConfig, lp, kind: str, gl, x, pos, live, pools,
            write, tables, att_len, interpret: bool, bank=None):
     """One block over its group's pool.  x: (B, S, H) float32; pos:
     (B, S); pools: {"full": (k, v), "window": (k, v)}; write[kind](pool,
-    new (B, S, kv_heads, d), layer) puts the new tokens' rows into
-    their pages; gl: the layer's index in its group (traced under the
+    new (B, S, kv_heads, width), layer, cols) puts the new tokens'
+    rows (columns, where the pool keeps a token a column) into their
+    pages; gl: the layer's index in its group (traced under the
     scan); bank: under the scan, the period's index in the expert
-    tensors, which stay stacked (moe.sparse_moe).  Returns (x, pools,
-    expert slots | None)."""
+    tensors, which stay stacked (moe.sparse_moe).  Every `if` below
+    reads the configuration, not the data: a setting compiles its own
+    operations only.  Returns (x, pools, expert slots | None)."""
     B, S, _ = x.shape
-    D, f32 = cfg.head_dim, jnp.float32
+    ak, f32 = cfg.attn(kind), jnp.float32
     xn = _normed(cfg, x, lp["ln_attn_in"])
-    def proj(w, heads):                 # w: (heads x d, hidden)
-        return jnp.einsum("bsh,xh->bsx", xn, w).reshape(B, S, heads, D)
-    q = proj(lp["w_q"], cfg.heads)
-    k = proj(lp["w_k"], cfg.kv_heads)
-    v = proj(lp["w_v"], cfg.kv_heads)
-    gate = jax.nn.sigmoid(jnp.dot(xn, lp["w_g"]).astype(f32))
-    q = _rms(q.astype(f32), lp["q_norm"], cfg.rms_eps)
-    k = _rms(k.astype(f32), lp["k_norm"], cfg.rms_eps)
-    if kind == "window":
-        cos, sin = _rotary_angles_at(pos.reshape(-1), D, cfg.rope_base)
+    def proj(w, heads, width):          # w: (heads x width, hidden)
+        return jnp.einsum("bsh,xh->bsx", xn, w).reshape(B, S, heads,
+                                                        width)
+    q = proj(lp["w_q"], cfg.heads, ak.qk_dim)
+    k = proj(lp["w_k"], ak.kv_heads, ak.qk_dim)
+    v = proj(lp["w_v"], ak.kv_heads, ak.v_dim)
+    if cfg.qk_norm:
+        q = _rms(q.astype(f32), lp["q_norm"], cfg.rms_eps)
+        k = _rms(k.astype(f32), lp["k_norm"], cfg.rms_eps)
+    if ak.rotary_dim:
+        cos, sin = _rotary_angles_at(pos.reshape(-1), ak.rotary_dim,
+                                     ak.rope_base)
         cos, sin = cos.reshape(B, S, -1), sin.reshape(B, S, -1)
-        q, k = _rotate(q, cos, sin), _rotate(k, cos, sin)
+        q, k = _rotate(q.astype(f32), cos, sin), \
+            _rotate(k.astype(f32), cos, sin)
+    if cfg.value_scale != 1.0:
+        v = v.astype(f32) * cfg.value_scale
     kp, vp = pools[kind]
-    kp = write[kind](kp, k.astype(kp.dtype), gl)
-    vp = write[kind](vp, v.astype(vp.dtype), gl)
+    kp = write[kind](kp, k.astype(kp.dtype), gl, ak.k_cols)
+    vp = write[kind](vp, v.astype(vp.dtype), gl, False)
     pools = {**pools, kind: (kp, vp)}
     o = window_paged_attention(
         q.astype(cfg.dtype), kp, vp, tables[kind], att_len, layer=gl,
-        window=cfg.window if kind == "window" else 0,
-        interpret=interpret)
-    a = jnp.dot((o.reshape(B, S, cfg.heads * D).astype(f32) * gate)
-                .astype(cfg.dtype), lp["w_o"],
-                preferred_element_type=f32)
-    h = x + _rms(a, lp["ln_attn_out"], cfg.rms_eps)
+        window=ak.window, sinks=lp["sink"] if ak.sink else None,
+        k_cols=ak.k_cols, interpret=interpret)
+    o = o.reshape(B, S, cfg.heads * ak.v_dim)
+    if cfg.out_gate:
+        gate = jax.nn.sigmoid(jnp.dot(xn, lp["w_g"]).astype(f32))
+        o = (o.astype(f32) * gate).astype(cfg.dtype)
+    a = jnp.dot(o, lp["w_o"], preferred_element_type=f32)
+    if cfg.sandwich_norm:
+        a = _rms(a, lp["ln_attn_out"], cfg.rms_eps)
+    h = x + a
     # the router reads the normed stream unrounded (moe.sparse_moe)
     hn = _rms(h, lp["ln_mlp_in"], cfg.rms_eps)
     f, slots = _ffn(cfg, lp, hn.astype(cfg.dtype), live, interpret, bank,
                     route_x=hn)
-    return h + _rms(f.astype(f32), lp["ln_mlp_out"], cfg.rms_eps), \
-        pools, slots
+    f = f.astype(f32)
+    if cfg.sandwich_norm:
+        f = _rms(f, lp["ln_mlp_out"], cfg.rms_eps)
+    return h + f, pools, slots
 
 
 def _stack(cfg: WindowMoeConfig, params, x, pos, live, pools, write,
@@ -388,18 +544,19 @@ def forward_decode(cfg: WindowMoeConfig, params, toks, pools, tables,
                    lengths, *, interpret: bool = False):
     """One new token a row over the pages its tables map.  toks: (B,);
     pools: {"full": (k, v), "window": (k, v)}, each (n_blocks, L,
-    kv_heads, page, d); tables: the same keys, (B, P); lengths: (B,).
+    kv_heads, page, width), the kind's own kv_heads and the pool's own
+    width; tables: the same keys, (B, P); lengths: (B,).
     Returns (hidden (B, H), pools, slots each held expert received)."""
-    page = pools["full"][0].shape[3]
+    page = pools["full"][1].shape[3]
     pos = jnp.minimum(lengths, cfg.max_len - 1).astype(jnp.int32)
     offs = pos % page
     write = {}
     for kind, tab in tables.items():
         bids = jnp.take_along_axis(tab, (pos // page)[:, None], axis=1)
 
-        def put(pool, new, gl, bids=bids[:, 0]):
+        def put(pool, new, gl, cols, bids=bids[:, 0]):
             return kv_append(pool, new[:, 0], bids, offs, layer=gl,
-                             interpret=interpret)
+                             cols=cols, interpret=interpret)
         write[kind] = put
     x, pools, slots = _stack(
         cfg, params, _embed(cfg, params, toks)[:, None], pos[:, None],
@@ -414,7 +571,7 @@ def forward_suffix(cfg: WindowMoeConfig, params, ids, pools, tables,
     (1, S) padded to whole pages, n_valid real.  Returns (hidden (1, S,
     H), pools)."""
     S = ids.shape[1]
-    page = pools["full"][0].shape[3]
+    page = pools["full"][1].shape[3]
     n_p = S // page
     pos = jnp.minimum(length[:, None] + jnp.arange(S)[None, :],
                       cfg.max_len - 1).astype(jnp.int32)
@@ -424,9 +581,11 @@ def forward_suffix(cfg: WindowMoeConfig, params, ids, pools, tables,
         bids = jax.lax.dynamic_slice_in_dim(tab[0], length[0] // page,
                                             n_p)
 
-        def put(pool, new, gl, bids=bids):
+        def put(pool, new, gl, cols, bids=bids):
             rows = new[0].reshape(n_p, page, *new.shape[2:])
-            return pool.at[bids, gl].set(rows.transpose(0, 2, 1, 3))
+            return pool.at[bids, gl].set(
+                rows.transpose(0, 2, 3, 1) if cols
+                else rows.transpose(0, 2, 1, 3))
         write[kind] = put
     x, pools, _ = _stack(cfg, params, _embed(cfg, params, ids), pos, ok,
                          pools, write, tables, pos[:, 0] + 1, interpret)

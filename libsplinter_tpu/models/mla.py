@@ -301,6 +301,54 @@ FAMILIES: dict[str, dict] = {
             "load_balance_coeff": Key(None, None, float),
             "use_grouped_mm": Key(None, None, bool),
         }},
+    # the MiMo-V2-Flash key set: models/afmoe.py with another setting
+    # a kind (window layers with a sink; heads and widths by kind)
+    "mimo_v2_flash": {
+        "window": "max_position_embeddings", "experts": "n_routed_experts",
+        # the leading zeros of moe_layer_freq
+        "dense": lambda arch: next(
+            (i for i, f in enumerate(arch["moe_layer_freq"]) if f),
+            len(arch["moe_layer_freq"])),
+        "keys": {
+            **{k: v for k, v in _COMMON.items() if k != "rms_norm_eps"},
+            "layernorm_epsilon": Key("rms_eps", 1e-5, float),
+            "max_position_embeddings": Key(None, None),
+            "head_dim": Key("head_dim"),
+            "v_head_dim": Key(None),
+            "swa_num_attention_heads": Key(None),
+            "swa_num_key_value_heads": Key(None),
+            "swa_head_dim": Key(None),
+            "swa_v_head_dim": Key(None),
+            "hybrid_layer_pattern": Key(None, cast=list),
+            "sliding_window": Key("window"),
+            "sliding_window_size": Key(None),
+            "attention_chunk_size": Key(None, None),
+            "add_swa_attention_sink_bias": Key(None, False, bool),
+            "add_full_attention_sink_bias": Key(None, False, bool),
+            "attention_value_scale": Key("value_scale", 1.0, float),
+            "partial_rotary_factor": Key(None, 1.0, float),
+            "rope_theta": Key("rope_base", 10000.0, float),
+            "swa_rope_theta": Key(None, 10000.0, float),
+            "moe_layer_freq": Key(None, cast=list),
+            "n_routed_experts": Key("n_routed_experts"),
+            "num_experts_per_tok": Key("top_k"),
+            # null in the published config: none
+            "n_shared_experts": Key("n_shared_experts", 0,
+                                    only=(None, 0, 1),
+                                    why=_SHARED_EXPERT["why"]),
+            "norm_topk_prob": Key("norm_topk_prob", True, bool),
+            "scoring_func": Key("score_fn", "sigmoid", str),
+            "routed_scaling_factor": Key("routed_scaling_factor", 1.0,
+                                         float),
+            "n_group": Key(None, **_ONE_GROUP),
+            "topk_group": Key(None, **_ONE_GROUP),
+            # the selection bias of noaux_tc is zero at seeded weights
+            "topk_method": Key(None, "noaux_tc", str,
+                               only=("noaux_tc", "greedy"),
+                               why="topk_method noaux_tc (a selection "
+                                   "bias, zero at seeded weights) or "
+                                   "greedy is served"),
+        }},
 }
 LAYER_TYPES = {"sliding_attention": "window", "full_attention": "full"}
 LINEAR_ATTN_KEYS = frozenset(("full_attn_layers", "kda_layers", "head_dim",
@@ -361,15 +409,69 @@ def _finish_window(arch, fields, path):
     return WindowMoeConfig(kinds=kinds, model_layers=n_layers, **fields)
 
 
+def _finish_sink_window(arch, fields, path):
+    from .afmoe import AttnKind, WindowMoeConfig
+    n_layers = int(arch["num_hidden_layers"])
+    pattern, freq = arch["hybrid_layer_pattern"], arch["moe_layer_freq"]
+    dense = FAMILIES["mimo_v2_flash"]["dense"](arch)
+    if len(pattern) != n_layers or set(pattern) - {0, 1} \
+            or len(freq) != n_layers or any(
+                f != int(i >= dense) for i, f in enumerate(freq)):
+        raise ValueError(
+            f"{path}: hybrid_layer_pattern (0 = global, 1 = sliding "
+            f"window) and moe_layer_freq (leading dense layers 0, then "
+            f"1) must each name {n_layers} layers")
+    if int(arch["sliding_window_size"]) != fields["window"] or int(
+            arch.get("attention_chunk_size") or fields["window"]) \
+            != fields["window"]:
+        raise ValueError(
+            f"{path}: sliding_window_size and attention_chunk_size "
+            "must equal sliding_window")
+    if int(arch["swa_num_attention_heads"]) != fields["heads"]:
+        raise ValueError(
+            f"{path}: swa_num_attention_heads must equal "
+            "num_attention_heads (one query head count is served)")
+    # null in the published config: no shared expert, no scaling
+    fields["n_shared_experts"] = int(fields["n_shared_experts"] or 0)
+    fields["routed_scaling_factor"] = float(
+        fields["routed_scaling_factor"] or 1.0)
+    factor = float(arch.get("partial_rotary_factor", 1.0))
+
+    def kind(kv, d, dv, base, window, sink):
+        # keys whose width is no whole number of 128-lane tiles are
+        # kept a token a column (ops/paged_attention)
+        return AttnKind(int(kv), int(d), int(dv),
+                        int(int(d) * factor) // 2 * 2, float(base),
+                        int(window), bool(sink), int(d) % 128 != 0)
+    kinds = (
+        ("window", kind(arch["swa_num_key_value_heads"],
+                        arch["swa_head_dim"], arch["swa_v_head_dim"],
+                        arch.get("swa_rope_theta", 10000.0),
+                        fields["window"],
+                        arch.get("add_swa_attention_sink_bias", False))),
+        ("full", kind(arch["num_key_value_heads"], arch["head_dim"],
+                      arch["v_head_dim"], fields["rope_base"], 0,
+                      arch.get("add_full_attention_sink_bias", False))))
+    return WindowMoeConfig(
+        kinds=tuple("window" if p else "full"
+                    for p in pattern[:fields.pop("layers")]),
+        model_layers=n_layers,
+        kv_heads=int(arch["num_key_value_heads"]), attn_kinds=kinds,
+        out_gate=False, qk_norm=False, sandwich_norm=False, mup=False,
+        **fields)
+
+
 FAMILIES["pangu_ultra_moe"]["finish"] = _finish_latent
 FAMILIES["kimi_linear"]["finish"] = _finish_hybrid
 FAMILIES["afmoe"]["finish"] = _finish_window
+FAMILIES["mimo_v2_flash"]["finish"] = _finish_sink_window
 
 
 def load_model_description(path: str, *, max_len: int | None = None):
     """A model description file -> (config, seed): a LatentMoeConfig,
-    a models/kda.HybridMoeConfig or a models/afmoe.WindowMoeConfig, by
-    the architecture's `model_type` (FAMILIES).
+    a models/kda.HybridMoeConfig or a models/afmoe.WindowMoeConfig
+    (AFMoE's setting or MiMo-V2-Flash's), by the architecture's
+    `model_type` (FAMILIES).
 
     {"architecture": {published keys verbatim, at their published
                       values},
@@ -417,8 +519,9 @@ def load_model_description(path: str, *, max_len: int | None = None):
         if key.field is not None:
             fields[key.field] = value
     n_layers = int(arch["num_hidden_layers"])
-    first_dense = int(arch.get(
-        family.get("dense", "first_k_dense_replace"), 0))
+    dense_key = family.get("dense", "first_k_dense_replace")
+    first_dense = dense_key(arch) if callable(dense_key) \
+        else int(arch.get(dense_key, 0))
     layers = int(share.get("layers", n_layers))
     dense = int(share.get("dense_layers", min(first_dense, layers)))
     e_first, e_held = share.get("experts", [0, fields["n_routed_experts"]])

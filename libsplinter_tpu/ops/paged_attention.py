@@ -325,7 +325,8 @@ def dequantize_pool(pool, scales):
     return pool.astype(jnp.float32) * scales[:, :, None, None]
 
 
-def _paged_ref(q, k_pool, v_pool, tables, lengths, starts=None):
+def _paged_ref(q, k_pool, v_pool, tables, lengths, starts=None,
+               sinks=None):
     """Reference math: gather every table page into a dense
     (B, KH, P*page, D) view and run the masked softmax — the
     correctness mirror the kernels are pinned against (and the non-TPU
@@ -333,7 +334,10 @@ def _paged_ref(q, k_pool, v_pool, tables, lengths, starts=None):
     (B, H, D) (single decode token) or (B, S, H, D) (multi-query
     verify: token t attends j < lengths + t).  `starts` (B,), where
     given, is each row's FIRST LIVE KEY (window_paged_attention):
-    token t attends starts + t <= j only."""
+    token t attends starts + t <= j only.  `sinks` (H,), where given,
+    is a learned logit a head that joins the softmax's denominator
+    and carries no value.  The values may be narrower than the keys
+    (v_pool's last axis): the output takes their width."""
     squeeze = q.ndim == 3
     if squeeze:
         q = q[:, None]
@@ -344,7 +348,7 @@ def _paged_ref(q, k_pool, v_pool, tables, lengths, starts=None):
     vg = v_pool[tables].transpose(0, 2, 1, 3, 4)
     T = kg.shape[2] * page
     kseq = kg.reshape(B, KH, T, D)
-    vseq = vg.reshape(B, KH, T, D)
+    vseq = vg.reshape(B, KH, T, vg.shape[-1])
     qr = q.reshape(B, S, KH, rep, D)
     logits = jnp.einsum(
         "bskrd,bktd->bskrt", qr.astype(jnp.float32),
@@ -355,10 +359,17 @@ def _paged_ref(q, k_pool, v_pool, tables, lengths, starts=None):
         valid &= jnp.arange(T)[None, None, :] \
             >= (starts[:, None, None] + jnp.arange(S)[None, :, None])
     logits = jnp.where(valid[:, :, None, None, :], logits, NEG_INF)
-    probs = jax.nn.softmax(logits, axis=-1)
+    if sinks is not None:
+        sink = jnp.broadcast_to(
+            jnp.asarray(sinks, jnp.float32).reshape(1, 1, KH, rep, 1),
+            logits.shape[:-1] + (1,))
+        probs = jax.nn.softmax(
+            jnp.concatenate([logits, sink], -1), axis=-1)[..., :-1]
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bskrt,bktd->bskrd", probs.astype(vseq.dtype),
                      vseq)
-    out = out.reshape(B, S, H, D).astype(q.dtype)
+    out = out.reshape(B, S, H, vseq.shape[-1]).astype(q.dtype)
     return out[:, 0] if squeeze else out
 
 
@@ -515,6 +526,19 @@ def paged_attention(q, k_pool, v_pool, tables, lengths, *,
 # whose table entries name the trash block, are never gathered — and
 # one program carries `hb` kv heads (all of them for a decode step:
 # the grid step, not the page's bytes, is what a 32 KiB page costs).
+# The keys and the values may differ in width (k_pool (..., Dk), v_pool
+# (..., Dv): the scores scale by 1/sqrt(Dk), the accumulator and the
+# output take Dv), and a layer may carry a learned SINK a head — a
+# logit in the softmax's denominator with no value — which is the
+# online softmax's initial state (m = sink, l = 1, acc = 0) and
+# nothing else.  KEYS A TOKEN A COLUMN (`k_cols`): a key width that is
+# no multiple of the 128-lane tile (192) would be padded to the next
+# one in HBM if a page's tokens were its rows, and the compiler then
+# keeps such a pool the other way round and copies it for every call
+# (tests/test_chip_compile.py found 5 pool copies a dispatch); the
+# model keeps those keys as k_pool (n_blocks, L, KH, Dk, page) —
+# whole tiles, the layout of ops/latent_attention's pages — and the
+# score is q (R, Dk) @ k (Dk, page) with no transpose at all.
 
 # query rows (tokens x heads of a kv group) one program holds
 WINDOW_Q_ROWS = 1024
@@ -539,23 +563,28 @@ def window_walk_pages(window: int, page: int, block_tokens: int) -> int:
 
 
 def _window_kernel(tab_ref, len_ref, start_ref, layer_ref, q_ref, k_ref,
-                   v_ref, out_ref, m_s, l_s, acc_s, *, page: int,
-                   scale: float, rep: int, block_tokens: int,
-                   n_table: int):
+                   v_ref, *rest, page: int, scale: float, rep: int,
+                   block_tokens: int, n_table: int, sink: bool,
+                   k_cols: bool):
     """One (batch row, kv-head block, query block, walked page)
     program.
 
       tab_ref: (B, P) SMEM block table;  len_ref / start_ref: (B,)
       SMEM — the row's first query attends start <= j < length;
       layer_ref: (1,) SMEM, the layer of the group's pool
-      q_ref:   (1, hb, R, D), R = block_tokens * rep, token-major
-      k_ref/v_ref: (1, 1, hb, page, D) the page the walk routed here
-      out_ref: (1, hb, R, D)
-      m_s/l_s: (hb, R, 1) f32;  acc_s: (hb, R, D) f32
+      q_ref:   (1, hb, R, Dk), R = block_tokens * rep, token-major
+      k_ref: (1, 1, hb, page, Dk) — (1, 1, hb, Dk, page) where
+      `k_cols` — v_ref: (1, 1, hb, page, Dv): the page the walk
+      routed here
+      rest:  [sink_ref (hb, R, 1) f32 — each query row's sink logit,
+      where `sink`], out_ref (1, hb, R, Dv), then the scratch:
+      m_s/l_s: (hb, R, 1) f32;  acc_s: (hb, R, Dv) f32
 
     Query token t of the whole stack attends start + t <= j <
     length + t.  The walk's page w of query block qb is page
     max(0, start + qb * block_tokens) // page + w of the row."""
+    sink_ref = rest[0] if sink else None
+    out_ref, m_s, l_s, acc_s = rest[-4:]
     b = pl.program_id(0)
     qb = pl.program_id(2)
     w = pl.program_id(3)
@@ -569,8 +598,13 @@ def _window_kernel(tab_ref, len_ref, start_ref, layer_ref, q_ref, k_ref,
 
     @pl.when(w == 0)
     def _init():
-        m_s[...] = jnp.full_like(m_s, NEG_INF)
-        l_s[...] = jnp.zeros_like(l_s)
+        if sink:
+            # the sink is a key every query sees, with no value
+            m_s[...] = sink_ref[...]
+            l_s[...] = jnp.ones_like(l_s)
+        else:
+            m_s[...] = jnp.full_like(m_s, NEG_INF)
+            l_s[...] = jnp.zeros_like(l_s)
         acc_s[...] = jnp.zeros_like(acc_s)
 
     # the block's last token attends j < length + t0 + block_tokens - 1
@@ -581,11 +615,11 @@ def _window_kernel(tab_ref, len_ref, start_ref, layer_ref, q_ref, k_ref,
         t = t0 + jax.lax.broadcasted_iota(jnp.int32, (R, page), 0) // rep
         valid = jnp.logical_and(j < length + t, j >= start + t)
         for h in range(hb):
-            q = q_ref[0, h]                             # (R, D)
-            k = k_ref[0, 0, h]                          # (page, D)
-            v = v_ref[0, 0, h]
+            q = q_ref[0, h]                             # (R, Dk)
+            k = k_ref[0, 0, h]                  # (page, Dk) | (Dk, page)
+            v = v_ref[0, 0, h]                          # (page, Dv)
             logits = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
+                q, k, (((1,), (0 if k_cols else 1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale
             logits = jnp.where(valid, logits, NEG_INF)
             m_prev, l_prev = m_s[h], l_s[h]
@@ -608,15 +642,19 @@ def _window_kernel(tab_ref, len_ref, start_ref, layer_ref, q_ref, k_ref,
 
 # splint: ignore[SPL205] reason=runs inside the registered paged programs (completer.paged_chunk / completer.suffix_prefill); the outer program is the attribution point
 @functools.partial(jax.jit, static_argnames=(
-    "n_walk", "block_tokens", "q_tokens", "interpret"))
+    "n_walk", "block_tokens", "q_tokens", "interpret", "k_cols"))
 def _window_pallas(q4, k_pool, v_pool, tables, lengths, starts, layer, *,
                    n_walk: int, block_tokens: int, q_tokens: int,
-                   interpret: bool):
-    """q4: (B, KH, q_tokens*rep, D) token-major; pools: (n_blocks, L,
-    KH, page, D); tables (B, P); lengths, starts (B,); layer (1,)."""
+                   interpret: bool, sinks=None, k_cols: bool = False):
+    """q4: (B, KH, q_tokens*rep, Dk) token-major; k_pool: (n_blocks, L,
+    KH, page, Dk) — (n_blocks, L, KH, Dk, page) where `k_cols` —
+    v_pool: (n_blocks, L, KH, page, Dv); tables (B, P); lengths,
+    starts (B,); layer (1,); sinks: None or (KH, rep) f32, a logit a
+    head.  Returns (B, KH, q_tokens*rep, Dv)."""
     B, KH, RT, D = q4.shape
+    Dv = v_pool.shape[4]
     rep = RT // q_tokens
-    page = k_pool.shape[3]
+    page = v_pool.shape[3]
     P = tables.shape[1]
     R = block_tokens * rep
     # a decode step carries every kv head in one program; a stack of
@@ -630,36 +668,52 @@ def _window_pallas(q4, k_pool, v_pool, tables, lengths, starts, layer, *,
         first = jnp.maximum(sts[b] + qb * block_tokens, 0) // page
         return (tab[b, jnp.minimum(first + w, P - 1)], lay[0], g, 0, 0)
 
-    kv_spec = pl.BlockSpec((1, 1, hb, page, D), _kv_map,
-                           memory_space=pltpu.VMEM)
+    def kv_spec(*block):
+        return pl.BlockSpec((1, 1, hb, *block), _kv_map,
+                            memory_space=pltpu.VMEM)
+
+    in_specs = [pl.BlockSpec((1, hb, R, D), _q_map,
+                             memory_space=pltpu.VMEM),
+                kv_spec(D, page) if k_cols else kv_spec(page, D),
+                kv_spec(page, Dv)]
+    operands = [q4, k_pool, v_pool]
+    if sinks is not None:
+        # a block's rows are token-major: row r is head r % rep
+        in_specs.append(pl.BlockSpec(
+            (hb, R, 1), lambda b, g, qb, w, *pre: (g, 0, 0),
+            memory_space=pltpu.VMEM))
+        operands.append(jnp.tile(
+            jnp.asarray(sinks, jnp.float32).reshape(KH, rep),
+            (1, block_tokens))[..., None])
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(B, KH // hb, q_tokens // block_tokens, n_walk),
-        in_specs=[pl.BlockSpec((1, hb, R, D), _q_map,
-                               memory_space=pltpu.VMEM),
-                  kv_spec, kv_spec],
-        out_specs=pl.BlockSpec((1, hb, R, D), _q_map,
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, hb, R, Dv), _q_map,
                                memory_space=pltpu.VMEM),
         scratch_shapes=[pltpu.VMEM((hb, R, 1), jnp.float32),
                         pltpu.VMEM((hb, R, 1), jnp.float32),
-                        pltpu.VMEM((hb, R, D), jnp.float32)],
+                        pltpu.VMEM((hb, R, Dv), jnp.float32)],
     )
     return pl.pallas_call(
         functools.partial(_window_kernel, page=page,
                           scale=1.0 / float(np.sqrt(D)), rep=rep,
-                          block_tokens=block_tokens, n_table=P),
+                          block_tokens=block_tokens, n_table=P,
+                          sink=sinks is not None, k_cols=k_cols),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q4.shape, q4.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, KH, RT, Dv), q4.dtype),
         interpret=interpret,
         # the decode step's kernel and the suffix stack's are told
         # apart by name in a device trace (benchmark/readers)
         name=("gqa_window_decode" if q_tokens == 1
               else "gqa_window_stack"),
-    )(tables, lengths, starts, layer, q4, k_pool, v_pool)
+    )(tables, lengths, starts, layer, *operands)
 
 
 def window_paged_attention(q, k_pool, v_pool, tables, lengths, *, layer,
-                           window: int = 0, interpret: bool = False,
+                           window: int = 0, sinks=None,
+                           k_cols: bool = False,
+                           interpret: bool = False,
                            force_pallas: bool = False):
     """Ragged paged attention over ONE LAYER of a page group's pool,
     global or sliding-window (FORWARD only; float pools).
@@ -668,13 +722,18 @@ def window_paged_attention(q, k_pool, v_pool, tables, lengths, *, layer,
     t sits at position lengths[b] - 1 + t and attends keys
     j < lengths[b] + t — and, where window > 0, only the last `window`
     of them (0 <= position - j < window);
-    k_pool/v_pool: (n_blocks, L, KH, page, D), kv heads unrepeated;
+    k_pool: (n_blocks, L, KH, page, D) — (n_blocks, L, KH, D, page),
+    a token a column, where `k_cols` — v_pool: (n_blocks, L, KH, page,
+    Dv), kv heads unrepeated, Dv the values' own width;
     layer: int32 scalar (traced or not), the layer within the group;
     tables: (B, P) the GROUP's block table; a window group's entries
-    behind the window may name the trash block: they are not read.
-    Returns (B, S, H, D) in q's dtype."""
+    behind the window may name the trash block: they are not read;
+    sinks: None, or (H,) float32 — a learned logit a head that joins
+    every query's softmax denominator and carries no value.
+    Returns (B, S, H, Dv) in q's dtype."""
     B, S, H, D = q.shape
-    KH, page = k_pool.shape[2], k_pool.shape[3]
+    KH, page = v_pool.shape[2], v_pool.shape[3]
+    Dv = v_pool.shape[4]
     rep = H // KH
     tables = jnp.asarray(tables, jnp.int32)
     lengths = jnp.asarray(lengths, jnp.int32)
@@ -682,8 +741,10 @@ def window_paged_attention(q, k_pool, v_pool, tables, lengths, *, layer,
               else jnp.full_like(lengths, NO_START))
     layer = jnp.asarray(layer, jnp.int32)
     if not (force_pallas or interpret or jax.default_backend() == "tpu"):
-        return _paged_ref(q, k_pool[:, layer], v_pool[:, layer], tables,
-                          lengths, starts)
+        k_layer = k_pool[:, layer]
+        return _paged_ref(q, k_layer.swapaxes(-1, -2) if k_cols
+                          else k_layer, v_pool[:, layer], tables,
+                          lengths, starts, sinks)
     tq = stack_block(S, rep)
     n_walk = tables.shape[1]
     if window > 0:
@@ -693,9 +754,11 @@ def window_paged_attention(q, k_pool, v_pool, tables, lengths, *, layer,
     out = _window_pallas(q4, k_pool, v_pool, tables, lengths, starts,
                          layer.reshape(1), n_walk=n_walk,
                          block_tokens=tq, q_tokens=S,
-                         interpret=interpret)
-    return out.reshape(B, KH, S, rep, D).transpose(0, 2, 1, 3, 4) \
-              .reshape(B, S, H, D)
+                         interpret=interpret, k_cols=k_cols,
+                         sinks=None if sinks is None
+                         else jnp.asarray(sinks).reshape(KH, rep))
+    return out.reshape(B, KH, S, rep, Dv).transpose(0, 2, 1, 3, 4) \
+              .reshape(B, S, H, Dv)
 
 
 def _kv_append_kernel(bid_ref, off_ref, lay_ref, new_ref, pool_ref,
@@ -756,19 +819,81 @@ def _kv_append_pallas(pool, new, bids, offs, layer, *, interpret: bool):
     )(bids, offs, layer, new, pool)
 
 
-def kv_append(pool, new, bids, offs, *, layer, interpret: bool = False,
-              force_pallas: bool = False):
+def _kv_append_cols_kernel(bid_ref, off_ref, lay_ref, new_ref, pool_ref,
+                           out_ref):
+    """Row i of the batch, keys a token a COLUMN: its page of the
+    layer comes in whole, column off[i] takes the new key in every kv
+    head, the page goes back (ops/latent_attention._append_kernel's
+    discipline).  Consecutive rows of one page keep writing the block
+    that is already resident."""
+    i = pl.program_id(0)
+    fresh = jnp.logical_or(
+        i == 0, bid_ref[i] != bid_ref[jnp.maximum(i - 1, 0)])
+
+    @pl.when(fresh)
+    def _load():
+        out_ref[...] = pool_ref[...]
+
+    col = jax.lax.broadcasted_iota(jnp.int32, out_ref.shape[2:], 2)
+    out_ref[0, 0] = jnp.where(
+        col == off_ref[i],
+        jnp.broadcast_to(new_ref[0], out_ref.shape[2:]), out_ref[0, 0])
+
+
+# splint: ignore[SPL205] reason=runs inside the registered paged programs; the outer program is the attribution point
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _kv_append_cols_pallas(pool, new, bids, offs, layer, *,
+                           interpret: bool):
+    """pool: (n_blocks, L, KH, D, page), updated in place (aliased);
+    new: (N, KH, D, 1); bids/offs: (N,) int32; layer: (1,) int32."""
+    N = new.shape[0]
+    _, _, KH, D, page = pool.shape
+
+    def _page(i, bid, off, lay):
+        return (bid[i], lay[0], 0, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(N,),
+        in_specs=[
+            pl.BlockSpec((1, KH, D, 1), lambda i, *pre: (i, 0, 0, 0),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, 1, KH, D, page), _page,
+                         memory_space=pltpu.VMEM),
+        ],
+        out_specs=pl.BlockSpec((1, 1, KH, D, page), _page,
+                               memory_space=pltpu.VMEM),
+    )
+    return pl.pallas_call(
+        _kv_append_cols_kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+        input_output_aliases={4: 0},
+        interpret=interpret,
+        name="gqa_window_append_cols",
+    )(bids, offs, layer, new, pool)
+
+
+def kv_append(pool, new, bids, offs, *, layer, cols: bool = False,
+              interpret: bool = False, force_pallas: bool = False):
     """Write one new token a row into a page group's pool:
     pool[bids[i], layer, :, offs[i]] = new[i].  pool: (n_blocks, L, KH,
-    page, D); new: (N, KH, D); bids/offs: (N,) int32; layer: int32
+    page, D) — (n_blocks, L, KH, D, page), a token a column, where
+    `cols`; new: (N, KH, D); bids/offs: (N,) int32; layer: int32
     scalar.  Rows sent to the trash block 0 may collide freely.  In
     place on a TPU (an XLA scatter there asks for the pool in another
     layout and copies it both ways, tests/test_chip_compile.py)."""
     b = jnp.asarray(bids, jnp.int32).reshape(-1)
     o = jnp.asarray(offs, jnp.int32).reshape(-1)
     layer = jnp.asarray(layer, jnp.int32)
+    new = new.astype(pool.dtype)
     if force_pallas or interpret or jax.default_backend() == "tpu":
-        return _kv_append_pallas(pool, new.astype(pool.dtype)[:, :, None],
-                                 b, o, layer.reshape(1),
-                                 interpret=interpret)
-    return pool.at[b, layer, :, o].set(new.astype(pool.dtype))
+        if cols:
+            return _kv_append_cols_pallas(pool, new[..., None], b, o,
+                                          layer.reshape(1),
+                                          interpret=interpret)
+        return _kv_append_pallas(pool, new[:, :, None], b, o,
+                                 layer.reshape(1), interpret=interpret)
+    if cols:
+        return pool.at[b, layer, :, :, o].set(new)
+    return pool.at[b, layer, :, o].set(new)

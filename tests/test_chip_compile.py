@@ -518,3 +518,126 @@ def test_window_programs_at_published_widths(one_chip, monkeypatch,
     assert mem.argument_size_in_bytes > 14.5e9   # weights, both groups
     assert mem.temp_size_in_bytes < 0.6e9        # no pool copies
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.6e9
+
+
+# ---- the window / global stack in MiMo-V2-Flash's setting at published
+# widths (benchmark/configs/mimo-v2-flash-309b-ep16.json: 64 heads over
+# 8 window / 4 global key/value heads, keys of 192 beside values of 128,
+# a sink a head in the window layers, sliding_window 128 = one page;
+# 4,608 global pages of 2 layers, 256 window pages of 5, a 258-page
+# table, 16 held experts of 4,096 x 2,048, layers 0-6)
+
+SWA_H, SWA_DK, SWA_DV, SWA_W, SWA_P = 64, 192, 128, 128, 258
+SWA_KH = {"full": 4, "window": 8}
+SWA_POOLS = {"full": (4609, 2, 4, PAGE), "window": (257, 5, 8, PAGE)}
+
+
+def _swa_pools(one_chip):
+    """Keys a token a column (192 is no whole number of lane tiles),
+    values a token a row."""
+    return {k: (_spec(one_chip, (*s[:3], SWA_DK, PAGE), jnp.bfloat16),
+                _spec(one_chip, (*s, SWA_DV), jnp.bfloat16))
+            for k, s in SWA_POOLS.items()}
+
+
+@pytest.mark.parametrize("q_tokens, rows, kind", [
+    (1, 48, "window"), (1, 48, "full"), (128, 1, "window"),
+    (128, 1, "full"), (640, 1, "window"), (640, 1, "full")],
+    ids=["decode-window-sink", "decode-full", "stack-128-window-sink",
+         "stack-128-full", "stack-640-window-sink", "stack-640-full"])
+def test_sink_window_attention_kernel(one_chip, q_tokens, rows, kind):
+    """The same kernel with keys of 192 (not a multiple of the 128-lane
+    tile) beside values of 128, groups of 8 and of 16 query heads, and
+    a window layer's sink: a decode step walks a window layer's 2
+    pages or a global layer's table; neither pool is copied (the keys
+    a token a ROW were: the compiler keeps a 192-wide row-major pool
+    the other way round and copied it for every call)."""
+    from libsplinter_tpu.ops.paged_attention import (
+        _window_pallas, stack_block, window_walk_pages)
+    kh = SWA_KH[kind]
+    rep = SWA_H // kh
+    tq = stack_block(q_tokens, rep)
+    n_walk = (window_walk_pages(SWA_W, PAGE, tq) if kind == "window"
+              else SWA_P)
+    if kind == "window":
+        assert (tq, n_walk) == ((1, 2) if q_tokens == 1 else (128, 3))
+    else:
+        assert tq == (1 if q_tokens == 1 else 64)
+    kp, vp = _swa_pools(one_chip)[kind]
+    i32 = functools.partial(_spec, one_chip, dtype=jnp.int32)
+    sink = kind == "window"
+    compiled = _compile(
+        lambda q4, kp, vp, t, l, s, lay, *sk: _window_pallas(
+            q4, kp, vp, t, l, s, lay, n_walk=n_walk, block_tokens=tq,
+            q_tokens=q_tokens, interpret=False, k_cols=True,
+            sinks=sk[0] if sk else None),
+        _spec(one_chip, (rows, kh, q_tokens * rep, SWA_DK), jnp.bfloat16),
+        kp, vp, i32(shape=(rows, SWA_P)), i32(shape=(rows,)),
+        i32(shape=(rows,)), i32(shape=(1,)),
+        *([_spec(one_chip, (kh, rep), jnp.float32)] if sink else []))
+    txt = compiled.as_text()
+    assert "tpu_custom_call" in txt
+    for pool in (kp, vp):
+        shape = ",".join(str(d) for d in pool.shape)
+        assert not [ln for ln in txt.split("ENTRY")[1].splitlines()
+                    if shape in ln and " copy(" in ln]
+
+
+@pytest.mark.parametrize("program", ["chunk", "suffix-128", "suffix-640"])
+def test_sink_window_programs_at_published_widths(one_chip, monkeypatch,
+                                                  program):
+    """The 8-step decode chunk of 48 rows, the one-page suffix prefill
+    (a question over its document) and the widest (a cold document's
+    pieces) of the benchmark's MiMo configuration (7 layers: the dense
+    one, four window layers as ONE scanned body, a tail of two;
+    6.87 GB of weights; the pools as the compiler lays them out): each
+    compiles, fits the chip beside its arguments, and keeps both
+    groups' pools in place."""
+    from libsplinter_tpu.models import afmoe
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    kinds = tuple("window" if p else "full" for p in (0, 1, 1, 1, 1, 0, 1))
+    cfg = afmoe.WindowMoeConfig(
+        vocab_size=19072, hidden=4096, kinds=kinds, heads=SWA_H,
+        kv_heads=4, head_dim=SWA_DK, window=SWA_W, dense_layers=1,
+        dense_mlp_dim=16384, moe_mlp_dim=2048, n_routed_experts=256,
+        top_k=8, experts_first=0, experts_held=16, n_shared_experts=0,
+        max_len=33024, model_layers=48, mup=False, out_gate=False,
+        qk_norm=False, sandwich_norm=False, value_scale=0.707,
+        attn_kinds=(
+            ("window", afmoe.AttnKind(8, SWA_DK, SWA_DV, 64, 1e4, SWA_W,
+                                      True, True)),
+            ("full", afmoe.AttnKind(4, SWA_DK, SWA_DV, 64, 5e6, 0,
+                                    False, True))))
+    assert cfg.plan == (1, 1, 4)          # window x 4 under one scanned body
+    params = jax.tree_util.tree_map(
+        lambda a: _spec(one_chip, a.shape, a.dtype),
+        jax.eval_shape(lambda: afmoe.init_params(cfg, 0)))
+    weights = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                  for a in jax.tree_util.tree_leaves(params))
+    assert 6.86e9 < weights < 6.88e9        # 3,430M parameters
+    m = afmoe.WindowCompletionModel(cfg, params=params)
+    pools = _swa_pools(one_chip)
+    i32 = _spec(one_chip, (), jnp.int32)
+    if program == "chunk":
+        fn = m._chunk_program(8, 48)
+        tables = {k: _spec(one_chip, (48, SWA_P), jnp.int32)
+                  for k in SWA_POOLS}
+        args = (_spec(one_chip, (48,), jnp.int32),
+                _spec(one_chip, (2,), jnp.uint32),
+                _spec(one_chip, (48,), jnp.int32),
+                _spec(one_chip, (48,), jnp.bool_),
+                _spec(one_chip, (48,), jnp.int32),
+                _spec(one_chip, (2,), jnp.int32))
+    else:
+        width = int(program.split("-")[1])
+        fn = m._suffix_program(width)
+        tables = {k: _spec(one_chip, (1, SWA_P), jnp.int32)
+                  for k in SWA_POOLS}
+        args = (_spec(one_chip, (1,), jnp.int32),
+                _spec(one_chip, (1, width), jnp.int32), i32)
+    compiled = getattr(fn, "__wrapped__", fn).lower(
+        params, pools, tables, *args).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes > 10.5e9   # weights, both groups
+    assert mem.temp_size_in_bytes < 1.0e9        # no pool copies
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9

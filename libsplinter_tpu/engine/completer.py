@@ -113,6 +113,18 @@ def detect_template(chat_template: str | None) -> str:
     return "none"
 
 
+def _awaits(ids: list[int], match: int, page: int, join: dict) -> bool:
+    """A prompt whose prefix walk matched `match` tokens would have
+    matched a page more had `join` — a row seated earlier in the same
+    admission round, its prefill still to come — been inserted into
+    the tree already."""
+    end = match + page
+    other = join["ids"]
+    return (join["match"] < end <= min(len(ids), len(other))
+            and ids[match:end] == other[match:end]
+            and ids[:match] == other[:match])
+
+
 @dataclasses.dataclass
 class CompleterStats:
     wakes: int = 0
@@ -143,6 +155,11 @@ class CompleterStats:
     # (token, expert) slots decode steps routed to experts held here
     decode_steps: int = 0
     decode_rows: int = 0
+    # join prefills dispatched and the rows they carried: an admission
+    # round's hits ride one program where the model's suffix program
+    # has a row axis (rows a program = their ratio; 1 where it has not)
+    join_programs: int = 0
+    join_rows: int = 0
     prompt_tokens: int = 0
     prefix_tokens: int = 0
     expert_slots: int = 0
@@ -1339,22 +1356,52 @@ class Completer:
                 # prefill lanes, and a joiner's dense prefill never
                 # runs here (the whole point of the split)
                 return self._lane_admit(free, _lane_ctx())
-            with tracer.span("infer.gather", leaf=True):
-                self.stripes.refresh()  # admission IS this lane's drain
-                waiting = [
-                    i for i in st.enumerate_indices(P.LBL_INFER_REQ)
-                    if self.stripes.owns(int(i))]
-                if not waiting:
-                    return 0
-                order = plan(waiting, len(free))
+            looked: set[int] = set()
+
+            def gather() -> list:
+                """The waiting requests this round has not looked at,
+                in admission order."""
+                with tracer.span("infer.gather", leaf=True):
+                    self.stripes.refresh()  # admission IS this lane's
+                    waiting = [             # drain
+                        i for i in st.enumerate_indices(P.LBL_INFER_REQ)
+                        if self.stripes.owns(int(i))
+                        and int(i) not in looked]
+                    order = plan(waiting, len(free)) if waiting else []
+                    looked.update(int(i) for i in order)
+                    return order
+
+            def round_order(order: list):
+                """The round's requests: what waited when it opened
+                and, while seated hits await the round's program and
+                rows are still free, what has arrived since — clients
+                answered together come back over the milliseconds the
+                first of them take to seat, and a straggler's program
+                of its own would read the weights again."""
+                while order:
+                    yield from order
+                    if not (round_joins and free):
+                        return
+                    order = gather()
+
+            order = gather()
+            if not order:
+                return 0
             n = 0
             traced = tracer.enabled
             pc = getattr(cache, "prefix_cache", None)
+            # the round's hits whose suffix one program width holds
+            # are seated first and prefilled together, up to as many
+            # rows as the model's widest suffix program takes; a model
+            # whose programs are one row wide joins request by request
+            rungs = getattr(m, "join_rungs", None)
+            round_cap = rungs(cache)[-1] if rungs is not None else 1
+            round_joins: list[dict] = []
             # a model with per-row recurrent state (models/kda.py):
             # a hit resumes from a snapshot, a join leaves one
             stateful = bool(getattr(cache, "needs_state", False))
             wgroup = getattr(cache, "window", None)
-            for idx in order:
+            for idx in round_order(order):
                 if not free:
                     break
                 with tracer.span("infer.gather", leaf=True):
@@ -1409,6 +1456,13 @@ class Completer:
                         # row's event list takes it once it is seated
                         walk_ms = (time.perf_counter() - tw) * 1e3
                         span(None, "prefix_hit", walk_ms)
+                if any(_awaits(ids, match, cache.page, j)
+                       for j in round_joins):
+                    # the walk ends short of a page a seated row of
+                    # this round is about to prefill: the round closes
+                    # here, and the request (untouched) hits that page
+                    # in the next
+                    break
                 with tracer.span("infer.gather", leaf=True):
                     cut = pc.last_cut if stateful and pc is not None else 0
                     # a pool with a window group (models/afmoe.py): the
@@ -1616,65 +1670,24 @@ class Completer:
                         if traced:
                             span(rows[r], "state_snapshot",
                                  (time.perf_counter() - t_s) * 1e3)
-                    skw = ({"snap_at": snap[1], "snap_slot": snap[0]}
-                           if snap else {})
-                    ta = time.perf_counter()
-                    with tracer.annotation("infer.join"):
-                        if hit_bids:
-                            # uncached tail only, attending the mapped
-                            # prefix through the ragged paged kernel
-                            logits = m.paged_append_prefill(
-                                cache, np.asarray(suffix, np.int32), r,
-                                **skw)
-                        else:
-                            logits = m.paged_prefill_row(
-                                cache, np.asarray(ids, np.int32), r,
-                                **skw)
-                    tb = time.perf_counter()
-                    # sample: what stands between the logits and the
-                    # row's first token (the window group's reserve,
-                    # the tree's insert, the audit's copy, the draw)
-                    with tracer.annotation("infer.sample"):
-                        if wgroup is not None:
-                            # the prefill gave the window pages it slid
-                            # past back a piece at a time; what the decode
-                            # still needs of the reservation comes now
-                            cache.ensure(r, reserve)
-                            if traced:
-                                span(rows[r], "window_release",
-                                     (wgroup.release_s - w_s0) * 1e3)
-                        if pc is not None:
-                            # freshly committed full prompt pages join
-                            # the tree NOW, donor still live — the next
-                            # identical admission maps them even while
-                            # this row decodes; the node the snapshot
-                            # belongs to takes its slot over
-                            ins = pc.insert(ids, cache, r, tenant,
-                                            **({"state": snap} if snap
-                                               else {}))
-                            if snap and pc.holds_snapshot(snap[0]):
-                                self.stats.state_snapshots += 1
-                            if ins and tenant:
-                                self.tenants.bump(
-                                    tenant, "prefix_cached_pages", ins)
-                        # a model with two audit lanes (engine/audit.py)
-                        # keeps one for each way a prompt is served
-                        lane = int(not match) if self.audit is not None \
-                            and self.audit.lanes > 1 else 0
-                        if self.audit is not None and self.audit.wants(lane):
-                            rows[r]["audit"] = self.audit.open(
-                                key, ids, match, logits, lane)
-                            m.audit_seat(lane, r)
-                        # splint: ignore[SPL201] reason=the documented host "sample" stage (CONT_INFER_STAGES): one scalar draw per JOIN so the row's first token emits before the next chunk, not per decode step
-                        t = int(m.sample(logits))
-                    if traced:
-                        tc = time.perf_counter()
-                        span(rows[r], "join", (tb - ta) * 1e3)
-                        span(rows[r], "sample", (tc - tb) * 1e3)
-                    with tracer.span("infer.emit", leaf=True):
-                        emit(r, t)
-                    if rows[r] is not None:
-                        fresh[r] = t  # host-side token: next dispatch
+                    join = {"r": r, "key": key, "ids": ids,
+                            "match": match, "suffix": suffix,
+                            "hit": bool(hit_bids), "snap": snap,
+                            "reserve": reserve, "tenant": tenant,
+                            "w_s0": w_s0}
+                    if round_cap > 1 and hit_bids and snap is None \
+                            and len(suffix) <= m.suffix_buckets[-1]:
+                        # a hit inside one program width: its prefill
+                        # waits for the round's other hits
+                        round_joins.append(join)
+                        if len(round_joins) == round_cap:
+                            join_round(round_joins)
+                            round_joins = []
+                    else:
+                        # a miss, a suffix that loops the widest
+                        # width, a model joined a row at a time: a
+                        # round of one, served here and now
+                        join_round([join])
                 else:                 # reads it over the device carry
                     # FULLY cached prompt: no prefill at all.  The
                     # row enters at lengths = P-1 and the next decode
@@ -1696,7 +1709,105 @@ class Completer:
                     rows[r]["disp_left"] = self.max_new
                     fresh[r] = int(ids[-1])
                 n += 1
+            if round_joins:
+                join_round(round_joins)
             return n
+
+        def join_round(joins: list[dict]) -> None:
+            """The prefill of rows fill_rows seated, and what follows
+            their logits.  One join runs the model's one-row program
+            (a miss: its bucket prefill) and draws on the host;
+            several — hits, each suffix inside one program width — ride
+            ONE dispatch of the model's row-batched suffix program,
+            which draws their first tokens in graph.  `infer.join` is
+            one annotation from the dispatch to the logits and is
+            recorded once a ROW, the round's wall over its rows;
+            `infer.sample` is a row's share of what stands between the
+            logits and its first token (the window group's reserve,
+            the tree's insert, the audit's copy, the draw)."""
+            pc = getattr(cache, "prefix_cache", None)
+            wgroup = getattr(cache, "window", None)
+            traced = tracer.enabled
+            firsts = None
+            ta = time.perf_counter()
+            with tracer.annotation("infer.join"):
+                if len(joins) > 1:
+                    logits, firsts = m.paged_append_prefill_rows(
+                        cache, [(j["r"], np.asarray(j["suffix"], np.int32))
+                                for j in joins])
+                else:
+                    j = joins[0]
+                    skw = ({"snap_at": j["snap"][1],
+                            "snap_slot": j["snap"][0]}
+                           if j["snap"] else {})
+                    if j["hit"]:
+                        # uncached tail only, attending the mapped
+                        # prefix through the ragged paged kernel
+                        logits = m.paged_append_prefill(
+                            cache, np.asarray(j["suffix"], np.int32),
+                            j["r"], **skw)
+                    else:
+                        logits = m.paged_prefill_row(
+                            cache, np.asarray(j["ids"], np.int32),
+                            j["r"], **skw)
+            join_ms = (time.perf_counter() - ta) * 1e3 / len(joins)
+            self.stats.join_programs += 1
+            self.stats.join_rows += len(joins)
+            # the round's logits stay on the device; an audited row
+            # brings them to the host
+            on_host = None
+            for i, j in enumerate(joins):
+                r, ids, match, snap = j["r"], j["ids"], j["match"], j["snap"]
+                tb = time.perf_counter()
+                with tracer.annotation("infer.sample"):
+                    if wgroup is not None:
+                        # the prefill gave the window pages it slid
+                        # past back a piece at a time; what the decode
+                        # still needs of the reservation comes now
+                        cache.ensure(r, j["reserve"])
+                        if traced:
+                            span(rows[r], "window_release",
+                                 (wgroup.release_s - j["w_s0"]) * 1e3)
+                    if pc is not None:
+                        # freshly committed full prompt pages join
+                        # the tree NOW, donor still live — the next
+                        # identical admission maps them even while
+                        # this row decodes; the node the snapshot
+                        # belongs to takes its slot over
+                        ins = pc.insert(ids, cache, r, j["tenant"],
+                                        **({"state": snap} if snap
+                                           else {}))
+                        if snap and pc.holds_snapshot(snap[0]):
+                            self.stats.state_snapshots += 1
+                        if ins and j["tenant"]:
+                            self.tenants.bump(
+                                j["tenant"], "prefix_cached_pages", ins)
+                    # a model with two audit lanes (engine/audit.py)
+                    # keeps one for each way a prompt is served
+                    lane = int(not match) if self.audit is not None \
+                        and self.audit.lanes > 1 else 0
+                    if self.audit is not None and self.audit.wants(lane):
+                        if firsts is not None and on_host is None:
+                            on_host = np.asarray(logits)
+                        rows[r]["audit"] = self.audit.open(
+                            j["key"], ids, match,
+                            logits if firsts is None else on_host[i],
+                            lane)
+                        m.audit_seat(lane, r)
+                    if firsts is not None:
+                        t = int(firsts[i])
+                    else:
+                        # splint: ignore[SPL201] reason=the documented host "sample" stage (CONT_INFER_STAGES): one scalar draw per JOIN so the row's first token emits before the next chunk, not per decode step
+                        t = int(m.sample(logits))
+                if traced:
+                    span(rows[r], "join", join_ms)
+                    span(rows[r], "sample",
+                         (time.perf_counter() - tb) * 1e3)
+                with tracer.span("infer.emit", leaf=True):
+                    emit(r, t)
+                if rows[r] is not None:
+                    fresh[r] = t      # host-side token: next dispatch
+                                      # reads it over the device carry
 
         def emit(r: int, t: int) -> None:
             """One sampled token for row r: eos / flush / budget."""

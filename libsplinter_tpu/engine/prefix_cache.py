@@ -189,22 +189,29 @@ class PrefixCache:
         self._zero_ref_w = 0
         self.last_window: list[int] = []
         self.last_window_cut = 0
-        # the page keys of the prompt being admitted: an admission
+        # the page keys of the prompts being admitted: an admission
         # walks the tree up to four times over the same ids (lookup,
         # commit_hit, state_slot, insert), and at ~80 pages of 128
-        # tokens each walk's tuples cost more than the walk
-        self._key_memo: tuple | None = None
+        # tokens each walk's tuples cost more than the walk.  One memo
+        # a row of the pool: a round of hits seats all its rows
+        # before it inserts any (completer.join_round)
+        self._key_memo: dict[int, tuple] = {}
 
     def _keys(self, ids) -> list[tuple]:
         """The token-id tuple of every full page of `ids`, built once
         for as long as the caller keeps handing in the same object."""
-        memo = self._key_memo
+        memo = self._key_memo.get(id(ids))
         if memo is None or memo[0] is not ids or memo[1] != len(ids):
             page = self.page
             flat = [int(t) for t in ids]
-            memo = self._key_memo = (ids, len(ids), [
+            memo = (ids, len(ids), [
                 tuple(flat[j * page:(j + 1) * page])
                 for j in range(len(flat) // page)])
+            self._key_memo.pop(id(ids), None)
+            while len(self._key_memo) >= max(
+                    getattr(self._cache, "batch", 1), 1):
+                del self._key_memo[next(iter(self._key_memo))]
+            self._key_memo[id(ids)] = memo
         return memo[2]
 
     @property

@@ -663,6 +663,12 @@ class WindowCompletionModel(LatentCompletionModel):
                             window_pool_pages=window_pool_pages,
                             window_span=span)
 
+    def join_rungs(self, cache: PagedKVCache) -> tuple[int, ...]:
+        """One row a suffix program: it loops page-wide pieces of a
+        ragged width and gives window pages back between them, row by
+        row (ROADMAP.md A1)."""
+        return (1,)
+
     # -- the two groups' buffers -------------------------------------------
 
     @staticmethod

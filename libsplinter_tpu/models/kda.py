@@ -479,6 +479,12 @@ class HybridCompletionModel(LatentCompletionModel):
                             pool_pages=pool_pages, kv_dtype=kv_dtype,
                             state_snapshots=state_snapshots)
 
+    def join_rungs(self, cache: PagedKVCache) -> tuple[int, ...]:
+        """One row a suffix program: it restores the row's state, runs
+        the delta rule over it and leaves a snapshot, none of which
+        has a row axis yet (ROADMAP.md A1)."""
+        return (1,)
+
     # -- state slots -------------------------------------------------------
 
     def _state_program(self, short: str, body):

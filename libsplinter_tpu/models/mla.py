@@ -726,15 +726,16 @@ def _expanded_attention(cfg: LatentMoeConfig, lp, q_nope, q_rope,
 
 
 def _absorbed_attention(cfg: LatentMoeConfig, lp, q_nope, q_rope, pool,
-                        tables, att_len, interpret: bool):
+                        tables, att_len, interpret: bool, q_valid=None):
     """DECODE / SUFFIX: attention in the latent space over the paged
     pool, whose rows for these S tokens are appended already.
-    q_*: (B, S, heads, .).  Returns (B, S, heads, v)."""
+    q_*: (B, S, heads, .); q_valid: None or (B,), a row's real tokens
+    among the S.  Returns (B, S, heads, v)."""
     w_uk, w_uv = _up_kv(cfg, lp)
     q_lat = jnp.einsum("bshd,rhd->bshr", q_nope, w_uk)
     o_lat = latent_paged_attention(
         jnp.concatenate([q_lat, q_rope], -1), pool, tables, att_len,
-        kv_rank=cfg.kv_lora_rank,
+        kv_rank=cfg.kv_lora_rank, q_valid=q_valid,
         scale=1.0 / math.sqrt(cfg.qk_head_dim), interpret=interpret)
     return jnp.einsum("bshr,rhd->bshd", o_lat, w_uv)
 
@@ -818,20 +819,28 @@ def forward_paged(cfg: LatentMoeConfig, params, ids, pools, tables,
                   lengths, n_valid=None, *, interpret: bool = False):
     """S new tokens a row atop what its table maps, absorbed
     attention over the latent pages (S == 1: a decode step; S > 1: the
-    suffix prefill of a prefix-cache hit, pad appends past n_valid
+    suffix prefill of prefix-cache hits, pad appends past n_valid
     routed to the trash block).  ids: (B, S); pools: a latent pool a
-    layer; tables: (B, P); lengths: (B,).  Returns (hidden (B, S, H),
-    new pools, slots each held expert received)."""
+    layer; tables: (B, P); lengths: (B,); n_valid: None, one count for
+    every row, or (B,) a row's own (0: a pad row, which writes the
+    trash block alone).  Returns (hidden (B, S, H), new pools, slots
+    each held expert received)."""
     B, S = ids.shape
     page = pools[0].shape[2]
     pos = jnp.minimum(lengths[:, None] + jnp.arange(S)[None, :],
                       cfg.max_len - 1).astype(jnp.int32)
     bids = jnp.take_along_axis(tables, pos // page, axis=1)
     live = (lengths > 0)[:, None] & jnp.ones((1, S), bool)
+    q_valid = None
     if n_valid is not None:
-        ok = jnp.arange(S)[None, :] < n_valid
+        ok = jnp.arange(S)[None, :] < jnp.reshape(n_valid, (-1, 1))
         bids = jnp.where(ok, bids, 0)
         live = live & ok
+        if jnp.ndim(n_valid):
+            # rows of their own lengths in one width: the attention
+            # skips the pad tokens' blocks (the one-row program pads
+            # to the next width at most, and runs as it always has)
+            q_valid = n_valid
     offs = pos % page
     att_len = pos[:, 0] + 1
     x = params["tok_emb"][ids]
@@ -841,7 +850,7 @@ def forward_paged(cfg: LatentMoeConfig, params, ids, pools, tables,
             pool = latent_append(pool, lat, bids, offs,
                                  interpret=interpret)
             return _absorbed_attention(cfg, lp, qn, qr, pool, tables,
-                                       att_len, interpret), pool
+                                       att_len, interpret, q_valid), pool
         x, pool, s = _layer(cfg, lp, x, pos, attend, live, interpret)
         new_pools.append(pool)
         slots.append(s)
@@ -1096,6 +1105,90 @@ class LatentCompletionModel:
         close_mark(mark)
         return out
 
+    def join_rungs(self, cache: PagedKVCache) -> tuple[int, ...]:
+        """The row counts this model's suffix programs come in,
+        ascending: 1 (paged_append_prefill), the lane's batch, and 8
+        between them for the few rows that come back out of step with
+        a batch (at the batch's rung their round would push a whole
+        batch's pad tokens through the dense layers).  An admission
+        round's hits ride the smallest rung that holds them
+        (paged_append_prefill_rows); a model whose suffix program has
+        no row axis answers (1,) and is joined a request at a time."""
+        return tuple(sorted({1, min(8, cache.batch), cache.batch}))
+
+    def _suffix_rows_program(self, rows: int, sb: int):
+        cfg, interp = self.cfg, self.interpret
+        top_p, temp = self.top_p, self.temp
+
+        def build():
+            def run(params, pools, tables, lengths, ids, n_valid, rng):
+                x, pools, _ = forward_paged(
+                    cfg, params, ids, pools, tables, lengths, n_valid,
+                    interpret=interp)
+                last = jnp.take_along_axis(
+                    x, jnp.maximum(n_valid - 1, 0)[:, None, None],
+                    axis=1)[:, 0]
+                logits = _logits(cfg, params, last)
+                return pools, logits, _sample_rows(rng, logits, top_p,
+                                                   temp)
+            return run
+        return self._program(("suffix", rows, sb, top_p, temp),
+                             "suffix_prefill", build)
+
+    def paged_append_prefill_rows(self, cache: PagedKVCache, joins):
+        """paged_append_prefill for the hits of ONE admission round in
+        one dispatch: joins is [(row, suffix_ids), ...], every suffix
+        at most the widest suffix bucket, every row seated with its
+        prefix mapped (cache.lengths[row] tokens).  The program is the
+        smallest rung of join_rungs that holds them at the widest
+        suffix width (a rung has one program), its other rows pads
+        (length 0, no token valid: they attend nothing, reach no
+        expert and write the trash block), and draws each row's first
+        token in graph with the decode chunk's sampler.  Returns
+        (logits — a device array whose row i is joins[i]'s last real
+        token's (V,) float32 —, first tokens (len(joins),) on the
+        host).  One join runs the one-row program and the host's
+        draw."""
+        if len(joins) == 1:
+            row, suffix = joins[0]
+            logits = self.paged_append_prefill(cache, suffix, row)
+            return logits[None], np.array([self.sample(logits)], np.int32)
+        rows = next(r for r in self.join_rungs(cache)
+                    if r >= len(joins))
+        sb = self.suffix_buckets[-1]
+        ids = np.zeros((rows, sb), np.int32)
+        n_valid = np.zeros((rows,), np.int32)
+        tables = np.zeros((rows, cache.tables.shape[1]), np.int32)
+        lengths = np.zeros((rows,), np.int32)
+        for i, (row, suffix) in enumerate(joins):
+            n = len(suffix)
+            pos = int(cache.lengths[row])
+            if not 0 < n <= sb:
+                raise ValueError(f"a suffix of {n} tokens in a "
+                                 f"{sb}-token program")
+            if pos + n >= self.cfg.max_len:
+                raise ValueError("suffix exceeds context window")
+            if not cache.ensure(row, pos + n):
+                raise RuntimeError(
+                    f"paged pool exhausted: row {row} suffix needs "
+                    f"{cache.pages_needed(pos + n)} pages")
+            ids[i, :n] = suffix
+            n_valid[i] = n
+            tables[i] = cache.tables[row]
+            lengths[i] = pos
+        self._rng, sub = jax.random.split(self._rng)
+        pools, logits, toks = self._suffix_rows_program(rows, sb)(
+            self.params, cache.pools[0], jnp.asarray(tables),
+            jnp.asarray(lengths), jnp.asarray(ids), jnp.asarray(n_valid),
+            sub)
+        mark = DEVTIME.take_mark(self._devname("suffix_prefill"))
+        cache.pools[0] = list(pools)
+        for i, (row, _) in enumerate(joins):
+            cache.lengths[row] += int(n_valid[i])
+        toks = np.asarray(toks)[:len(joins)]
+        close_mark(mark)
+        return logits, toks
+
     def _cow_fixups(self, cache) -> int:
         """Copy-on-write pass before a decode dispatch (see
         CompletionModel._cow_fixups): one page copy a layer."""
@@ -1234,6 +1327,16 @@ class LatentCompletionModel:
                 self.paged_append_prefill(
                     cache, np.ones((sb,), np.int32), 0)
                 cache.free_row(0)
+            else:
+                # the row-batched rungs, at the widest width
+                for below in self.join_rungs(cache)[:-1]:
+                    # a rung's program is its shape: one row more than
+                    # the rung below holds compiles and runs it
+                    self.paged_append_prefill_rows(
+                        cache, [(r, np.ones((sb,), np.int32))
+                                for r in range(below + 1)])
+                    for r in range(below + 1):
+                        cache.free_row(r)
             self._warm_cow(cache)
 
     def _warm_cow(self, cache: PagedKVCache) -> None:

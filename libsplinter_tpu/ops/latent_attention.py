@@ -40,6 +40,11 @@ Grid (B, H // G, P): G heads x S tokens share one program's
 (S*G, width) query block, token-major (row // G == token), with G
 chosen so that the block stays near 1,024 rows — the whole 128 heads
 for a decode step (S == 1), 16 heads for a 64-token suffix stack.
+Where the rows of one call bring suffixes of their own lengths in one
+width (an admission round's hits, models/mla.py), `q_valid` says how
+many of a row's S tokens are real and a program skips its query rows'
+token blocks (Q_BLOCK_ROWS rows each) past that count: a row of 20
+tokens in a 64-token width runs 2 of its 4 blocks, a pad row none.
 
 On non-TPU backends the same math runs as plain jnp over a gathered
 page view; tests run the kernel itself with interpret=True, and
@@ -49,6 +54,7 @@ described v5e.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -70,24 +76,42 @@ def head_group(heads: int, q_tokens: int) -> int:
     return g
 
 
-def _latent_kernel(tab_ref, len_ref, q_ref, kv_ref, out_ref,
-                   m_s, l_s, acc_s, *, page: int, scale: float,
-                   group: int, kv_rank: int):
+# query rows one block of a program's queries holds where a row says
+# how many of its stacked tokens are real (q_valid): blocks wholly
+# past that count are skipped, each still a full MXU tile
+Q_BLOCK_ROWS = 256
+
+
+def q_blocks(q_tokens: int, group: int) -> int:
+    """Token blocks a program's (q_tokens x group) query rows split
+    into for the skip of pad tokens: as many as keep a block at
+    Q_BLOCK_ROWS rows or more and divide the tokens."""
+    return math.gcd(q_tokens, max(1, q_tokens * group // Q_BLOCK_ROWS))
+
+
+def _latent_kernel(tab_ref, len_ref, *refs, page: int, scale: float,
+                   group: int, kv_rank: int, blocks: int):
     """One (batch row, head group, page) program.
 
-    tab_ref: (B, P) SMEM block table;  len_ref: (B,) SMEM lengths
+    tab_ref: (B, P) SMEM block table;  len_ref: (B,) SMEM lengths;
+    with blocks > 1 a third prefetched operand, (B,) SMEM: how many of
+    the row's stacked tokens are real
     q_ref:   (1, 1, R, W) this row's folded queries, R = S*group,
              token-major;  kv_ref: (1, W, page) the page the table
              routed here (a token a column), W = kv_rank + rope_dim
     out_ref: (1, 1, R, kv_rank)
     m_s/l_s: (R, 1) f32 running max / sum;  acc_s: (R, kv_rank) f32
     """
+    nv_ref = refs[0] if blocks > 1 else None
+    q_ref, kv_ref, out_ref, m_s, l_s, acc_s = refs[-6:]
     b = pl.program_id(0)
     p = pl.program_id(2)
     n_pages = pl.num_programs(2)
     length = len_ref[b]
     R = q_ref.shape[2]
     q_tokens = R // group
+    # the row's real tokens: the last of them attends furthest
+    n_real = q_tokens if nv_ref is None else nv_ref[b]
 
     @pl.when(p == 0)
     def _init():
@@ -95,26 +119,40 @@ def _latent_kernel(tab_ref, len_ref, q_ref, kv_ref, out_ref,
         l_s[...] = jnp.zeros_like(l_s)
         acc_s[...] = jnp.zeros_like(acc_s)
 
-    @pl.when(p * page < length + (q_tokens - 1))
-    def _accumulate():
-        q = q_ref[0, 0]                                 # (R, W)
+    def accumulate(rows, t0: int):
+        """The page into the running softmax of query rows `rows`,
+        whose first token is the row's t0-th."""
+        n = rows.stop - rows.start
+        q = q_ref[0, 0, rows]                           # (n, W)
         kv = kv_ref[0]                                  # (W, page)
         logits = jnp.dot(q, kv, preferred_element_type=jnp.float32) \
-            * scale                                     # (R, page)
-        j = jax.lax.broadcasted_iota(jnp.int32, (R, page), 1)
-        t = jax.lax.broadcasted_iota(jnp.int32, (R, page), 0) // group
+            * scale                                     # (n, page)
+        j = jax.lax.broadcasted_iota(jnp.int32, (n, page), 1)
+        t = t0 + jax.lax.broadcasted_iota(jnp.int32, (n, page), 0) \
+            // group
         valid = (p * page + j) < (length + t)
         logits = jnp.where(valid, logits, NEG_INF)
-        m_prev, l_prev = m_s[...], l_s[...]
+        m_prev, l_prev = m_s[rows], l_s[rows]
         m_new = jnp.maximum(m_prev, jnp.max(logits, -1, keepdims=True))
         corr = jnp.exp(m_prev - m_new)
         pexp = jnp.where(valid, jnp.exp(logits - m_new), 0.0)
-        m_s[...] = m_new
-        l_s[...] = l_prev * corr + jnp.sum(pexp, -1, keepdims=True)
-        acc_s[...] = acc_s[...] * corr + jax.lax.dot_general(
+        m_s[rows] = m_new
+        l_s[rows] = l_prev * corr + jnp.sum(pexp, -1, keepdims=True)
+        acc_s[rows] = acc_s[rows] * corr + jax.lax.dot_general(
             pexp.astype(kv.dtype), kv[:kv_rank],
             (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
+
+    @pl.when(p * page < length + (n_real - 1))
+    def _accumulate():
+        if blocks == 1:
+            accumulate(slice(0, R), 0)
+        else:
+            tb = q_tokens // blocks
+            for i in range(blocks):
+                pl.when(i * tb < n_real)(functools.partial(
+                    accumulate, slice(i * tb * group,
+                                      (i + 1) * tb * group), i * tb))
 
     @pl.when(p == n_pages - 1)
     def _write():
@@ -127,13 +165,18 @@ def _latent_kernel(tab_ref, len_ref, q_ref, kv_ref, out_ref,
 # splint: ignore[SPL205] reason=runs inside the registered paged programs (completer.paged_chunk / completer.suffix_prefill); the outer program is the attribution point
 @functools.partial(jax.jit, static_argnames=("kv_rank", "scale", "group",
                                              "interpret"))
-def _latent_pallas(q4, pool, tables, lengths, *, kv_rank: int,
-                   scale: float, group: int, interpret: bool):
+def _latent_pallas(q4, pool, tables, lengths, q_valid=None, *,
+                   kv_rank: int, scale: float, group: int,
+                   interpret: bool):
     """q4: (B, H//group, S*group, W); pool: (n_blocks, W, page);
-    tables: (B, P) int32; lengths: (B,) int32.
+    tables: (B, P) int32; lengths: (B,) int32; q_valid: None or (B,)
+    int32, a row's real tokens.
     Returns (B, H//group, S*group, kv_rank)."""
     B, NG, R, W = q4.shape
     page = pool.shape[2]
+    blocks = 1 if q_valid is None else q_blocks(R // group, group)
+    prefetch = (tables, lengths) if blocks == 1 \
+        else (tables, lengths, q_valid)
 
     def _q_map(b, g, p, *pre):
         return (b, g, 0, 0)
@@ -142,7 +185,7 @@ def _latent_pallas(q4, pool, tables, lengths, *, kv_rank: int,
         return (pre[0][b, p], 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=len(prefetch),
         grid=(B, NG, tables.shape[1]),
         in_specs=[
             pl.BlockSpec((1, 1, R, W), _q_map, memory_space=pltpu.VMEM),
@@ -159,7 +202,7 @@ def _latent_pallas(q4, pool, tables, lengths, *, kv_rank: int,
     )
     return pl.pallas_call(
         functools.partial(_latent_kernel, page=page, scale=scale,
-                          group=group, kv_rank=kv_rank),
+                          group=group, kv_rank=kv_rank, blocks=blocks),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, NG, R, kv_rank), q4.dtype),
         interpret=interpret,
@@ -167,7 +210,7 @@ def _latent_pallas(q4, pool, tables, lengths, *, kv_rank: int,
         # apart by name in a device trace (benchmark/readers)
         name=("latent_decode_attention" if R == group
               else "latent_stack_attention"),
-    )(tables, lengths, q4, pool)
+    )(*prefetch, q4, pool)
 
 
 def _latent_ref(q, pool, tables, lengths, *, kv_rank: int, scale: float):
@@ -189,7 +232,8 @@ def _latent_ref(q, pool, tables, lengths, *, kv_rank: int, scale: float):
 
 
 def latent_paged_attention(q, pool, tables, lengths, *, kv_rank: int,
-                           scale: float, interpret: bool = False,
+                           scale: float, q_valid=None,
+                           interpret: bool = False,
                            force_pallas: bool = False):
     """Ragged paged attention in the latent space (FORWARD only).
 
@@ -200,7 +244,11 @@ def latent_paged_attention(q, pool, tables, lengths, *, kv_rank: int,
     pool: (n_blocks, W, page) latent pages, a token a column,
     W = kv_rank + rope_dim;
     tables: (B, P) int32; lengths: (B,) int32 (ops/paged_attention's
-    contract, trash block 0 included).
+    contract, trash block 0 included); q_valid: None or (B,) int32 —
+    how many of a row's S stacked tokens are real, where rows bring
+    suffixes of their own lengths in one width: the kernel skips the
+    token blocks past it (what it returns for a pad token is not
+    defined, and finite).
     Returns q's leading shape with kv_rank last: the probability-
     weighted latent, to be taken through each head's value
     up-projection by the caller."""
@@ -216,9 +264,11 @@ def latent_paged_attention(q, pool, tables, lengths, *, kv_rank: int,
         # head (group index)*g + i
         q4 = q.reshape(B, S, H // g, g, W).transpose(0, 2, 1, 3, 4) \
               .reshape(B, H // g, S * g, W)
-        out = _latent_pallas(q4, pool, tables, lengths, kv_rank=kv_rank,
-                             scale=float(scale), group=g,
-                             interpret=interpret)
+        out = _latent_pallas(
+            q4, pool, tables, lengths,
+            None if q_valid is None else jnp.asarray(q_valid, jnp.int32),
+            kv_rank=kv_rank, scale=float(scale), group=g,
+            interpret=interpret)
         out = out.reshape(B, H // g, S, g, kv_rank) \
                  .transpose(0, 2, 1, 3, 4).reshape(B, S, H, kv_rank)
     else:

@@ -231,8 +231,11 @@ def test_paged_attention_tp4(topo, kind):
 LAT_W, LAT_RANK, LAT_HEADS, LAT_POOL = 576, 512, 128, (2561, 576, 128)
 
 
-@pytest.mark.parametrize("q_tokens, rows", [(1, 64), (16, 1), (64, 1)],
-                         ids=["decode-64-rows", "suffix-16", "suffix-64"])
+@pytest.mark.parametrize("q_tokens, rows",
+                         [(1, 64), (16, 1), (64, 1), (64, 8), (64, 64)],
+                         ids=["decode-64-rows", "suffix-16", "suffix-64",
+                              "suffix-64-of-8-rows",
+                              "suffix-64-of-64-rows"])
 def test_latent_attention_kernel(one_chip, q_tokens, rows):
     """The latent decode kernel (S == 1, all 128 heads a program) and
     the suffix stacks (S x 64 / 16 heads), over transposed pages: the
@@ -241,15 +244,18 @@ def test_latent_attention_kernel(one_chip, q_tokens, rows):
     from libsplinter_tpu.ops.latent_attention import (_latent_pallas,
                                                       head_group)
     g = head_group(LAT_HEADS, q_tokens)
+    # the rows of a round bring their own lengths (q_valid): the kernel
+    # that skips the pad tokens' blocks
+    ragged = rows > 1 and q_tokens > 1
     compiled = _compile(
-        lambda q4, pool, t, l: _latent_pallas(
-            q4, pool, t, l, kv_rank=LAT_RANK, scale=192 ** -0.5, group=g,
-            interpret=False),
+        lambda q4, pool, t, l, *nv: _latent_pallas(
+            q4, pool, t, l, *nv, kv_rank=LAT_RANK, scale=192 ** -0.5,
+            group=g, interpret=False),
         _spec(one_chip, (rows, LAT_HEADS // g, q_tokens * g, LAT_W),
               jnp.bfloat16),
         _spec(one_chip, LAT_POOL, jnp.bfloat16),
         _spec(one_chip, (rows, 66), jnp.int32),
-        _spec(one_chip, (rows,), jnp.int32))
+        *[_spec(one_chip, (rows,), jnp.int32)] * (2 if ragged else 1))
     txt = compiled.as_text()
     assert "tpu_custom_call" in txt
     # the pool reaches the kernel in the layout it is kept in: no copy
@@ -287,13 +293,10 @@ def test_expert_grouped_matmul(one_chip, monkeypatch, rows):
         assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_latent_decode_chunk_program(one_chip, monkeypatch):
-    """The whole 8-step decode chunk of the benchmark's configuration
-    (5 layers, 64 rows, 9.85 GB of weights + 1.89 GB of pages): it
-    compiles, fits the chip beside its arguments, and keeps the pools
-    in place."""
+def _pangu_model(one_chip):
+    """The benchmark's configuration (5 layers, 9.85 GB of weights) as
+    shapes on the described chip: (model, params)."""
     from libsplinter_tpu.models import mla
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg = mla.LatentMoeConfig(
         vocab_size=19200, hidden=7680, layers=5, heads=LAT_HEADS,
         q_lora_rank=1536, kv_lora_rank=LAT_RANK, qk_nope_head_dim=128,
@@ -304,7 +307,41 @@ def test_latent_decode_chunk_program(one_chip, monkeypatch):
     params = jax.tree_util.tree_map(
         lambda a: _spec(one_chip, a.shape, a.dtype),
         jax.eval_shape(lambda: mla.init_params(cfg, 0)))
-    m = mla.LatentCompletionModel(cfg, params=params)
+    return mla.LatentCompletionModel(cfg, params=params), params
+
+
+def test_latent_suffix_rows_program(one_chip, monkeypatch):
+    """The full rung of the row-batched suffix prefill (64 rows x 64
+    tokens: an admission round of the benchmark's cell in ONE program):
+    it compiles, its 4,096 tokens' temporaries fit the chip beside the
+    weights and the pages, and no pool is copied."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    m, params = _pangu_model(one_chip)
+    fn = m._suffix_rows_program(64, 64)
+    compiled = getattr(fn, "__wrapped__", fn).lower(
+        params, [_spec(one_chip, LAT_POOL, jnp.bfloat16)] * 5,
+        _spec(one_chip, (64, 66), jnp.int32),
+        _spec(one_chip, (64,), jnp.int32),
+        _spec(one_chip, (64, 64), jnp.int32),
+        _spec(one_chip, (64,), jnp.int32),
+        _spec(one_chip, (2,), jnp.uint32)).compile()
+    mem = compiled.memory_analysis()
+    print("suffix rows 64x64: arguments",
+          mem.argument_size_in_bytes, "temporaries",
+          mem.temp_size_in_bytes)
+    assert mem.argument_size_in_bytes > 11.5e9          # weights + pool
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+    assert not [ln for ln in compiled.as_text().split("ENTRY")[1]
+                .splitlines() if "2561,576,128" in ln and " copy(" in ln]
+
+
+def test_latent_decode_chunk_program(one_chip, monkeypatch):
+    """The whole 8-step decode chunk of the benchmark's configuration
+    (5 layers, 64 rows, 9.85 GB of weights + 1.89 GB of pages): it
+    compiles, fits the chip beside its arguments, and keeps the pools
+    in place."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    m, params = _pangu_model(one_chip)
     fn = m._chunk_program(8, 64)
     compiled = getattr(fn, "__wrapped__", fn).lower(
         params, [_spec(one_chip, LAT_POOL, jnp.bfloat16)] * 5,

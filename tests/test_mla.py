@@ -116,6 +116,97 @@ def test_latent_pages_with_shared_prefix_and_suffix_prefill(
     assert cache.used_pages == 0
 
 
+def _seat_hits(model, spans):
+    """A cache of 8 rows whose rows 0..len(spans)-1 each map the first
+    a // 16 pages of IDS[:48] (prefilled by a donor row that then
+    leaves) and own the pages their suffix IDS[a:b] needs.  Returns
+    (cache, [(row, suffix), ...])."""
+    cache = model.init_paged(8, page=16, pool_pages=40)
+    model.paged_prefill_row(cache, IDS[:48], 7)
+    donor = [int(x) for x in cache.tables[7, :3]]
+    joins = []
+    for r, (a, b) in enumerate(spans):
+        if r == 7:
+            cache.free_row(7)       # rows 0..6 keep its pages alive
+        cache.map_shared(r, donor[:a // 16])
+        cache.lengths[r] = a
+        assert cache.ensure(r, b)
+        joins.append((r, IDS[a:b]))
+    if len(spans) < 8:
+        cache.free_row(7)
+    return cache, joins
+
+
+@pytest.mark.parametrize("spans, interpret", [
+    ([(32, 45)], False),
+    ([(32, 45), (16, 30), (32, 33)], False),
+    ([(32, 45), (16, 30), (32, 33)], True),
+    ([(32, 45), (16, 30), (32, 33), (16, 17), (32, 48), (16, 32),
+      (32, 40), (16, 41)], False),
+], ids=["round-of-1", "round-of-3", "round-of-3-pallas-interpret",
+        "full-rung-of-8"])
+def test_row_batched_suffix_prefill_is_the_one_row_programs(
+        model, ref_logits, spans, interpret):
+    """An admission round's hits in ONE dispatch of the suffix program
+    (ragged suffix lengths, the rung's other rows dead) against the
+    one-row program a request at a time: the same logits, the same
+    latents on every live page, and no other write but the trash
+    block's; the first tokens are drawn in graph (greedy here)."""
+    m = model if not interpret else mla.LatentCompletionModel(
+        CFG, seed=3, temp=0.0, interpret=True)
+    one, joins = _seat_hits(m, spans)
+    want = [m.paged_append_prefill(one, suffix, r) for r, suffix in joins]
+    rows, _ = _seat_hits(m, spans)
+    np.testing.assert_array_equal(one.tables, rows.tables)
+    before = [np.asarray(p) for p in rows.pools[0]]
+    logits, toks = m.paged_append_prefill_rows(rows, joins)
+    logits = np.asarray(logits)
+    assert toks.shape == (len(spans),)
+    assert logits.shape[0] == (1 if len(spans) == 1 else 8)
+    for i, (a, b) in enumerate(spans):
+        np.testing.assert_allclose(logits[i], want[i], atol=2e-5)
+        np.testing.assert_allclose(logits[i], ref_logits[b - 1],
+                                   atol=2e-5)
+        assert toks[i] == int(np.argmax(want[i]))
+    np.testing.assert_array_equal(one.lengths, rows.lengths)
+    # the pages a suffix may write: from the one its first token lands
+    # in to its last token's
+    own = {int(rows.tables[r, p]) for r, (a, b) in enumerate(spans)
+           for p in range(a // 16, (b - 1) // 16 + 1)}
+    for was, got, ref in zip(before, rows.pools[0], one.pools[0]):
+        got, ref = np.asarray(got), np.asarray(ref)
+        live = sorted(own | {int(b) for r in range(len(spans))
+                             for b in rows.tables[r, :spans[r][0] // 16]})
+        np.testing.assert_allclose(got[live], ref[live], atol=2e-5)
+        wrote = {int(b) for b in
+                 np.nonzero((got != was).any(axis=(1, 2)))[0]}
+        assert wrote <= own | {0}
+
+
+def test_in_graph_first_token_draw_has_the_host_draws_support():
+    """The round's in-graph draw (decoder._sample_rows, the decode
+    chunk's sampler) and the host's one-row draw (m.sample) cut the
+    same nucleus: over fixed logits both reach every token inside
+    top-p 0.9 at temperature 0.7 and none outside it."""
+    from libsplinter_tpu.models.decoder import _sample_rows
+    logits = np.full((CFG.vocab_size,), -30.0, np.float32)
+    # at temperature 0.7: p = .334 .290 .217 .123 | .029 .007 — the
+    # fifth starts at a cumulative .964 >= 0.9 and is cut
+    logits[[7, 3, 400, 90, 11, 250]] = [2.0, 1.9, 1.7, 1.3, 0.3, -0.7]
+    z = np.exp((logits - logits.max()) / 0.7)
+    order = np.argsort(-z)
+    cum = np.cumsum(z[order] / z.sum())
+    nucleus = {int(t) for t in order[(cum - z[order] / z.sum()) < 0.9]}
+    assert nucleus == {7, 3, 400, 90}
+    m = mla.LatentCompletionModel(CFG, seed=3, params={}, top_p=0.9,
+                                  temp=0.7)
+    host = {m.sample(logits) for _ in range(160)}
+    graph = {int(t) for t in np.asarray(jax.jit(
+        lambda k, l: _sample_rows(k, l, 0.9, 0.7))(
+            jax.random.PRNGKey(4), jnp.tile(logits[None], (512, 1))))}
+    assert host == graph == nucleus
+
+
 def _aligned(a: np.ndarray) -> np.ndarray:
     """A copy of `a` whose first byte sits on a 64-byte boundary: the
     CPU backend hands such a buffer to a program WITHOUT copying."""
@@ -194,6 +285,33 @@ def test_latent_kernel_and_append_match_the_gathered_reference():
     np.testing.assert_array_equal(np.asarray(got)[1:],
                                   np.asarray(want)[1:])
     np.testing.assert_array_equal(np.asarray(got)[4, :, 4], lat[1])
+
+
+def test_latent_kernel_skips_the_blocks_of_pad_tokens():
+    """Rows that bring suffixes of their own lengths in one width say
+    how many of their stacked tokens are real (q_valid): the kernel
+    splits a program's query rows into token blocks and skips those
+    wholly past the count — a real token's output is what the unsplit
+    kernel gives, a dead row (no real token) computes nothing."""
+    from libsplinter_tpu.ops.latent_attention import head_group, q_blocks
+    rng = np.random.default_rng(7)
+    B, S, H, W, rank, page, P, nb = 4, 32, 32, 48, 32, 16, 6, 24
+    assert q_blocks(S, head_group(H, S)) == 4          # 8 tokens a block
+    pool = jnp.asarray(rng.normal(size=(nb, W, page)), jnp.float32)
+    tables = jnp.asarray(rng.integers(1, nb, (B, P)), jnp.int32)
+    lengths = jnp.asarray([5, 33, 60, 1], jnp.int32)
+    valid = np.asarray([32, 9, 16, 0], np.int32)
+    q = jnp.asarray(rng.normal(size=(B, S, H, W)), jnp.float32)
+    want = np.asarray(latent_paged_attention(
+        q, pool, tables, lengths, kv_rank=rank, scale=0.2))
+    got = np.asarray(latent_paged_attention(
+        q, pool, tables, lengths, kv_rank=rank, scale=0.2,
+        q_valid=valid, interpret=True))
+    for b, n in enumerate(valid):
+        np.testing.assert_allclose(got[b, :n], want[b, :n], atol=2e-6)
+        blocks_run = -(-int(n) // 8) * 8
+        assert np.isfinite(got[b]).all()
+        assert not got[b, blocks_run:].any()           # skipped: zeros
 
 
 def test_grouped_matmul_kernel_matches_ragged_dot():

@@ -382,3 +382,282 @@ def test_paged_continuous_churn_no_leak(tmp_path):
     finally:
         st.close()
         Store.unlink(name)
+
+
+# ---- admission rounds (run_continuous.fill_rows / join_round) over a
+# model whose suffix program has a row axis (models/mla.py join_rungs)
+
+_DOC = "the quick brown fox jumps over the lazy "   # + BOS: 41 tokens
+
+
+@pytest.fixture(scope="module")
+def latent_models():
+    """The tiny latent model twice over the same weights: as it is
+    (`rows`) and joined a request at a time (`one-row`)."""
+    from libsplinter_tpu.models import mla
+
+    class OneRow(mla.LatentCompletionModel):
+        """The same weights, joined a request at a time."""
+
+        def join_rungs(self, cache):
+            return (1,)
+
+    cfg = mla.LatentMoeConfig.tiny(dtype=jnp.float32, experts_first=2,
+                                   experts_held=4)
+    return {"rows": mla.LatentCompletionModel(cfg, seed=3, temp=0.0),
+            "one-row": OneRow(cfg, seed=3, temp=0.0)}
+
+
+class _RoundLane:
+    """A continuous lane of 6 rows over `model` whose admission waits
+    at a gate, so that a burst of requests is ONE round, and whose
+    model and prefix tree log every call the round makes."""
+
+    SPIED = (("paged_prefill_row", "miss", lambda c, ids, row, **k: row),
+             ("paged_append_prefill", "suffix",
+              lambda c, ids, row, **k: row),
+             ("paged_append_prefill_rows", "rows",
+              lambda c, joins: tuple(r for r, _ in joins)),
+             ("sample", "sample", lambda logits: None),
+             ("_cow_fixups", "cow", lambda c: None),
+             ("paged_decode_chunk_async", "chunk", lambda *a, **k: None))
+
+    def __init__(self, tmp_path, model, tag, pool_pages=40):
+        self.model, self.log, self.at_rows = model, [], None
+        self.name, self.st = _mkstore(tmp_path, tag)
+        self.comp = Completer(self.st, model=model, max_new_tokens=4,
+                              flush_tokens=4, template="none",
+                              batch_cap=6, page_size=16,
+                              pool_pages=pool_pages)
+        self.comp.attach()
+        self.comp._ensure_paged_cache()
+        self.gate, self.parked = threading.Event(), threading.Event()
+        self.gate.set()
+
+    def _spy(self, obj, name, tag, what):
+        orig = getattr(obj, name)
+
+        def wrapped(*a, **k):
+            self.log.append((tag, what(*a, **k)))
+            if tag == "rows" and self.at_rows is not None:
+                self.at_rows()
+            return orig(*a, **k)
+        setattr(obj, name, wrapped)
+
+    def __enter__(self):
+        for name, tag, what in self.SPIED:
+            self._spy(self.model, name, tag, what)
+        self._spy(self.comp.prefix_cache, "insert", "insert",
+                  lambda ids, c, row, *a, **k: row)
+        refresh = self.comp.stripes.refresh
+
+        def gated():
+            if not self.gate.is_set():
+                self.parked.set()
+                self.gate.wait()
+            self.log.append(("gather", None))
+            return refresh()
+        self.comp.stripes.refresh = gated
+        self.th = _run_bg(self.comp, stop_after=300.0)
+        return self
+
+    def __exit__(self, *exc):
+        self.gate.set()
+        self.comp.stop()
+        self.th.join(timeout=30)
+        for name, _, _ in self.SPIED:
+            delattr(self.model, name)      # the class's own again
+        self.st.close()
+        Store.unlink(self.name)
+
+    def burst(self, prompts: dict) -> tuple[dict, list]:
+        """Submit `prompts` (key -> text) while admission waits, let
+        ONE round see them all.  Returns (key -> answer, the log from
+        the round's first call to the first decode chunk after it)."""
+        self.parked.clear()
+        self.gate.clear()
+        assert self.parked.wait(30)
+        mark = len(self.log)
+        for k, text in prompts.items():
+            _submit(self.st, k, text)
+        self.gate.set()
+        assert _await_ready(self.st, list(prompts), timeout=240), \
+            self.comp.stats
+        log = self.log[mark:]
+        if ("chunk", None) in log:
+            log = log[:log.index(("chunk", None))]
+        self.gathers = log.count(("gather", None))
+        return ({k: self.st.get(k).rstrip(b"\0") for k in prompts},
+                [e for e in log if e[0] != "gather"])
+
+
+@pytest.fixture(scope="module")
+def round_answers():
+    """What each scenario's prompts are answered with, by model kind:
+    greedy answers must not depend on how the round was joined."""
+    return {}
+
+
+def _same_answers(round_answers, scenario, kind, got):
+    other = round_answers.setdefault(scenario, {})
+    other[kind] = got
+    if len(other) == 2:
+        assert other["rows"] == other["one-row"]
+
+
+@pytest.mark.parametrize("kind", ["rows", "one-row"])
+def test_a_round_of_hits_is_one_dispatch_or_todays_sequence(
+        tmp_path, latent_models, round_answers, kind):
+    """Three prefix hits in one admission round: a model whose suffix
+    program has a row axis prefills them in ONE dispatch (join_programs
+    1, join_rows 3) after seating all three; a model with rung 1 makes
+    today's calls in today's order — prefill, insert, sample, request
+    by request — and counts a program a row."""
+    with _RoundLane(tmp_path, latent_models[kind], f"hits-{kind}") as ln:
+        ln.burst({"d": _DOC})
+        s = ln.comp.stats
+        p0, r0 = s.join_programs, s.join_rows
+        out, log = ln.burst({f"q/{i}": _DOC + q for i, q in enumerate(
+            ("who?", "what now?", "where to, and why?"))})
+        if kind == "rows":
+            assert [t for t, _ in log] == ["rows"] + ["insert"] * 3
+            assert sorted(log[0][1]) == sorted(r for _, r in log[1:])
+            assert (s.join_programs - p0, s.join_rows - r0) == (1, 3)
+            # rows were left free: the round looked once more for
+            # arrivals before it dispatched (and the next pass, ahead
+            # of its chunk, a third time)
+            assert ln.gathers == 3
+        else:
+            assert ln.gathers == 2         # the round's, the next pass's
+            assert [t for t, _ in log] == ["suffix", "insert",
+                                           "sample"] * 3
+            for i in range(0, 9, 3):
+                assert log[i][1] == log[i + 1][1]
+            assert (s.join_programs - p0, s.join_rows - r0) == (3, 3)
+        assert s.prefix_tokens == 3 * 32
+        _same_answers(round_answers, "hits", kind, out)
+
+
+@pytest.mark.parametrize("kind", ["rows", "one-row"])
+def test_a_miss_a_cached_prompt_and_a_wide_suffix_are_rounds_of_one(
+        tmp_path, latent_models, round_answers, kind):
+    """One round holding two hits, a miss, a fully cached prompt and a
+    suffix wider than the widest suffix program: the last three are
+    served where they stand, each in the old order (prefill, insert,
+    sample; the cached prompt: its page copy alone), and the two hits
+    ride one program behind them."""
+    with _RoundLane(tmp_path, latent_models[kind], f"mix-{kind}") as ln:
+        ln.burst({"d": _DOC})
+        s = ln.comp.stats
+        p0, r0 = s.join_programs, s.join_rows
+        out, log = ln.burst({
+            "hit/0": _DOC + "and then?",
+            "hit/1": _DOC + "so?",
+            "miss": "an unrelated prompt of its own",
+            "cached": _DOC[:31],                 # + BOS: two whole pages
+            "wide": _DOC + "w" * 60})            # a suffix of 69 tokens
+        tags = [t for t, _ in log]
+        assert tags.count("miss") == 1 and tags.count("cow") == 1
+        i = tags.index("miss")
+        assert tags[i:i + 3] == ["miss", "insert", "sample"]
+        assert log[i][1] == log[i + 1][1]
+        if kind == "rows":
+            assert tags.count("suffix") == 1 and tags.count("rows") == 1
+            assert tags[-3:] == ["rows", "insert", "insert"]
+            assert sorted(log[-3][1]) == sorted(r for _, r in log[-2:])
+            assert (s.join_programs - p0, s.join_rows - r0) == (3, 4)
+        else:
+            assert tags.count("suffix") == 3 and "rows" not in tags
+            assert (s.join_programs - p0, s.join_rows - r0) == (4, 4)
+        i = [k for k, (t, r) in enumerate(log) if t == "suffix"
+             and tags[k:k + 3] == ["suffix", "insert", "sample"]]
+        assert len(i) == tags.count("suffix")
+        _same_answers(round_answers, "mix", kind, out)
+
+
+@pytest.mark.parametrize("kind", ["rows", "one-row"])
+def test_a_request_that_needs_a_page_of_the_round_waits_and_then_hits(
+        tmp_path, latent_models, round_answers, kind):
+    """Two prompts of one round share a page that neither finds in the
+    tree yet: whichever is seated first closes the round before the
+    other, which joins in the next and HITS that page — the tree ends
+    up with what row-by-row admission leaves, and no page is prefilled
+    twice."""
+    with _RoundLane(tmp_path, latent_models[kind], f"dep-{kind}") as ln:
+        ln.burst({"d": _DOC})
+        s, pc = ln.comp.stats, ln.comp.prefix_cache.stats
+        p0, r0, ins0 = s.join_programs, s.join_rows, pc.inserts
+        longer = _DOC + "a page more of shared "     # 63 tokens
+        out, _ = ln.burst({"first": longer[:-2], "second": longer,
+                           "aside": _DOC + "hm?"})
+        # 32 + 32 (the third page of whichever came first) + 48
+        assert s.prefix_tokens == 32 + 32 + 48
+        assert pc.inserts - ins0 == 1
+        assert s.join_rows - r0 == 3
+        assert s.join_programs - p0 == (2 if kind == "rows" else 3)
+        _same_answers(round_answers, "dep", kind, out)
+
+
+def test_a_round_takes_in_what_arrives_while_its_hits_are_seated(
+        tmp_path, latent_models):
+    """Clients answered together come back over the milliseconds the
+    first of them take to seat: requests that arrive while a round's
+    hits are being seated join THAT round's program, not one of their
+    own."""
+    with _RoundLane(tmp_path, latent_models["rows"], "late") as ln:
+        ln.burst({"d": _DOC})
+        late = {f"late/{i}": _DOC + f"{i}, the late one?" for i in (0, 1)}
+        claim, unsent = ln.comp._prepare, list(late)
+
+        def claim_then_arrive(idx, peek=None):
+            got = claim(idx, peek=peek)
+            while unsent:
+                k = unsent.pop()
+                _submit(ln.st, k, late[k])
+            return got
+        ln.comp._prepare = claim_then_arrive
+        s = ln.comp.stats
+        p0, r0 = s.join_programs, s.join_rows
+        out, log = ln.burst({"early/0": _DOC + "first?",
+                             "early/1": _DOC + "second, then?"})
+        assert _await_ready(ln.st, list(late), timeout=240)
+        rows = [what for t, what in ln.log if t == "rows"]
+        assert len(rows) == 1 and len(rows[0]) == 4
+        assert (s.join_programs - p0, s.join_rows - r0) == (1, 4)
+        assert all(ln.st.get(k).startswith(late[k].encode())
+                   for k in late)
+
+
+def test_backpressure_inside_a_round_leaves_the_denied_request_waiting(
+        tmp_path, latent_models):
+    """A pool that seats two of a round's three hits: the two are
+    prefilled in one dispatch while the third is still WAITING,
+    untouched (its prompt and its label as submitted); it joins when
+    pages come back."""
+    # 8 pages: the document's two in the tree, three a hit (68 tokens
+    # + the decode's 4 = five pages, two of them mapped), so the third
+    # hit finds none
+    with _RoundLane(tmp_path, latent_models["rows"], "bp",
+                    pool_pages=8) as ln:
+        ln.burst({"d": _DOC})
+        prompts = {f"q/{i}": _DOC + f"{i}: what of the other fox?"
+                   for i in range(3)}
+        seen = {}
+
+        def at_rows():
+            for k in prompts:
+                seen[k] = (ln.st.labels(k), ln.st.get(k).rstrip(b"\0"))
+        ln.at_rows = at_rows
+        out, _ = ln.burst(prompts)
+        s = ln.comp.stats
+        assert s.join_backpressure >= 1
+        rows = [what for t, what in ln.log if t == "rows"]
+        assert len(rows) == 1 and len(rows[0]) == 2
+        waiting = [k for k, (lab, val) in seen.items()
+                   if lab & P.LBL_INFER_REQ]
+        assert len(waiting) == 1
+        assert seen[waiting[0]][1] == prompts[waiting[0]].encode()
+        assert not seen[waiting[0]][0] & (P.LBL_SERVICING | P.LBL_READY)
+        assert all(out[k].startswith(prompts[k].encode())
+                   for k in prompts)
+        assert s.completions == 4 and s.faults == 0

@@ -362,6 +362,7 @@ class Completer:
         # slot totals of the decode steps (expert models only)
         self.startup_ms: dict[str, float] = {}
         self._expert_totals = None
+        self._moe_counts = None
         # audit records (engine/audit.py): for a sample of the
         # continuous lane's requests, the prompt ids, the ids generated
         # and the logits behind the first and the last of them — what
@@ -1784,7 +1785,8 @@ class Completer:
                                 j["tenant"], "prefix_cached_pages", ins)
                     # a model with two audit lanes (engine/audit.py)
                     # keeps one for each way a prompt is served
-                    lane = int(not match) if self.audit is not None \
+                    lane = m.audit_lane(match, len(j["suffix"])) \
+                        if self.audit is not None \
                         and self.audit.lanes > 1 else 0
                     if self.audit is not None and self.audit.wants(lane):
                         if firsts is not None and on_host is None:
@@ -1945,6 +1947,13 @@ class Completer:
                     self._expert_totals = slots.astype(np.int64) + (
                         0 if self._expert_totals is None
                         else self._expert_totals)
+                counts = getattr(pend, "counts", None)
+                if counts is not None:
+                    # live experts and selections the router's bias
+                    # changed, fetched like the slots
+                    self._moe_counts = np.asarray(counts, np.int64) + (
+                        0 if self._moe_counts is None
+                        else self._moe_counts)
                 for c in range(pend.n):
                     for r, ser in live:
                         row = rows[r]
@@ -2445,12 +2454,13 @@ class Completer:
             if self.prefix_cache is not None:
                 payload["window_evictions"] = \
                     self.prefix_cache.stats.window_evictions
-            # live keys the attention kernels were asked for
-            payload.update(getattr(m_now, "attn_work", {}))
         else:
             for k in ("window_resumes", "window_cut_tokens",
                       "window_tail_shares", "window_decode_slides"):
                 payload.pop(k, None)  # one page group: dead gauges
+        # live keys the grouped-query kernels were asked for, where the
+        # model counts them
+        payload.update(getattr(m_now, "attn_work", {}))
         pc = self.prefix_cache
         if pc is not None:
             # prefix-cache gauges (sptpu_completer_prefix_* in `spt
@@ -2527,6 +2537,9 @@ class Completer:
         if self._expert_totals is not None:
             payload["expert_totals"] = [int(x) for x in
                                         self._expert_totals]
+        if self._moe_counts is not None:
+            payload["experts_live"] = int(self._moe_counts[0])
+            payload["router_bias_swaps"] = int(self._moe_counts[1])
         if self.startup_ms:
             payload["startup_ms"] = {
                 **{k: round(v, 1) for k, v in self.startup_ms.items()},

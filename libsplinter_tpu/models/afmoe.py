@@ -594,9 +594,84 @@ def forward_suffix(cfg: WindowMoeConfig, params, ids, pools, tables,
 
 # ------------------------------------------------------------- front end
 
-class WindowCompletionModel(LatentCompletionModel):
+class GroupPagePrograms:
+    """The buffers and the copy-on-write of a cache whose pages come
+    in GROUPS — one K and one V pool a group, every layer of the group
+    side by side in a page (PageLayout.layers): the global group
+    alone (models/lfm2.py), or the global group and a window group
+    beside it (this module)."""
+
+    @staticmethod
+    def _pools(cache: PagedKVCache) -> dict:
+        out = {"full": (cache.pools[0][0], cache.pools[1][0])}
+        if cache.window is not None:
+            w = cache.window
+            out["window"] = (w.pools[0][0], w.pools[1][0])
+        return out
+
+    @staticmethod
+    def _keep(cache: PagedKVCache, pools: dict) -> None:
+        (cache.pools[0][0], cache.pools[1][0]) = pools["full"]
+        if cache.window is not None:
+            w = cache.window
+            (w.pools[0][0], w.pools[1][0]) = pools["window"]
+
+    @staticmethod
+    def _tables(cache: PagedKVCache, row: int | None = None) -> dict:
+        """Host-side copies (lengths and tables move right after a
+        dispatch: mla.paged_append_prefill)."""
+        rows = slice(None) if row is None else slice(row, row + 1)
+        out = {"full": jnp.asarray(np.array(cache.tables[rows]))}
+        if cache.window is not None:
+            out["window"] = jnp.asarray(np.array(
+                cache.window.tables[rows]))
+        return out
+
+    def _cow_program(self):
+        def build():
+            def run(pools, src, dst):
+                return [p.at[dst].set(p[src]) for p in pools]
+            return run
+        return self._program(("cow",), "cow_copy", build, donate=(0,))
+
+    def _copy_page(self, pools, src: int, dst: int) -> None:
+        """One page of a group, every layer of it, K and V."""
+        pools[0][0], pools[1][0] = self._cow_program()(
+            [pools[0][0], pools[1][0]], jnp.int32(src), jnp.int32(dst))
+
+    def _cow_fixups(self, cache) -> int:
+        """Copy-on-write pass before a decode dispatch, a group at a
+        time: one page copy holds every layer of the group."""
+        n, w = 0, cache.window
+        for row, p_idx in cache.cow_targets():
+            dst = cache._alloc_page()
+            self._copy_page(cache.pools, int(cache.tables[row, p_idx]), dst)
+            cache.commit_cow(row, p_idx, dst)
+            n += 1
+        for row, p_idx in cache.window_cow_targets():
+            dst = w._alloc()
+            self._copy_page(w.pools, int(w.tables[row, p_idx]), dst)
+            w.commit_cow(row, p_idx, dst)
+            n += 1
+        return n
+
+    def _warm_cow(self, cache: PagedKVCache) -> None:
+        w = cache.window
+        for pools, alloc, free in (
+                (cache.pools, cache._alloc_page, cache._decref),
+                *(((w.pools, w._alloc, w._decref),) if w is not None
+                  else ())):
+            src, dst = alloc(), alloc()
+            self._copy_page(pools, src, dst)
+            free(src)
+            free(dst)
+
+
+class WindowCompletionModel(GroupPagePrograms,
+                            LatentCompletionModel):
     """LatentCompletionModel's paged serving surface over the mixed
-    window / global stack and its two page groups."""
+    window / global stack and its two page groups
+    (GroupPagePrograms)."""
 
     needs_window = True
     # lane 0: a row that resumed from the prefix cache, lane 1: one
@@ -668,29 +743,6 @@ class WindowCompletionModel(LatentCompletionModel):
         ragged width and gives window pages back between them, row by
         row (ROADMAP.md A1)."""
         return (1,)
-
-    # -- the two groups' buffers -------------------------------------------
-
-    @staticmethod
-    def _pools(cache: PagedKVCache) -> dict:
-        w = cache.window
-        return {"full": (cache.pools[0][0], cache.pools[1][0]),
-                "window": (w.pools[0][0], w.pools[1][0])}
-
-    @staticmethod
-    def _keep(cache: PagedKVCache, pools: dict) -> None:
-        w = cache.window
-        (cache.pools[0][0], cache.pools[1][0]) = pools["full"]
-        (w.pools[0][0], w.pools[1][0]) = pools["window"]
-
-    @staticmethod
-    def _tables(cache: PagedKVCache, row: int | None = None) -> dict:
-        """Host-side copies (lengths and tables move right after a
-        dispatch: mla.paged_append_prefill)."""
-        rows = slice(None) if row is None else slice(row, row + 1)
-        return {"full": jnp.asarray(np.array(cache.tables[rows])),
-                "window": jnp.asarray(np.array(
-                    cache.window.tables[rows]))}
 
     # -- prefill -----------------------------------------------------------
 
@@ -767,46 +819,6 @@ class WindowCompletionModel(LatentCompletionModel):
         out = np.asarray(logits)
         close_mark(mark)
         return out
-
-    # -- copy-on-write -----------------------------------------------------
-
-    def _cow_program(self):
-        def build():
-            def run(pools, src, dst):
-                return [p.at[dst].set(p[src]) for p in pools]
-            return run
-        return self._program(("cow",), "cow_copy", build, donate=(0,))
-
-    def _copy_page(self, pools, src: int, dst: int) -> None:
-        """One page of a group, every layer of it, K and V."""
-        pools[0][0], pools[1][0] = self._cow_program()(
-            [pools[0][0], pools[1][0]], jnp.int32(src), jnp.int32(dst))
-
-    def _cow_fixups(self, cache) -> int:
-        """Copy-on-write pass before a decode dispatch, a group at a
-        time: one page copy holds every layer of the group."""
-        n, w = 0, cache.window
-        for row, p_idx in cache.cow_targets():
-            dst = cache._alloc_page()
-            self._copy_page(cache.pools, int(cache.tables[row, p_idx]), dst)
-            cache.commit_cow(row, p_idx, dst)
-            n += 1
-        for row, p_idx in cache.window_cow_targets():
-            dst = w._alloc()
-            self._copy_page(w.pools, int(w.tables[row, p_idx]), dst)
-            w.commit_cow(row, p_idx, dst)
-            n += 1
-        return n
-
-    def _warm_cow(self, cache: PagedKVCache) -> None:
-        w = cache.window
-        for pools, alloc, free in (
-                (cache.pools, cache._alloc_page, cache._decref),
-                (w.pools, w._alloc, w._decref)):
-            src, dst = alloc(), alloc()
-            self._copy_page(pools, src, dst)
-            free(src)
-            free(dst)
 
     # -- decode ------------------------------------------------------------
 

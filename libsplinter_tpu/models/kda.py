@@ -431,12 +431,114 @@ def forward_suffix(cfg: HybridMoeConfig, params, ids, pools, states,
 
 # ------------------------------------------------------------- front end
 
-class HybridCompletionModel(LatentCompletionModel):
-    """LatentCompletionModel's paged serving surface over the hybrid
-    stack, plus what a cache with state slots asks of its model:
-    state_restore / state_zero, and a prefill that leaves a snapshot."""
+class StateSlotPrograms:
+    """What a cache with STATE SLOTS asks of its model, whatever the
+    state is (this module's recurrent matrix and convolution tails,
+    models/lfm2.py's two-token register): state_restore / state_zero
+    over every layer's arrays, and the suffix prefill's loop — pieces
+    of the widest suffix width from (pages + state), the piece that
+    passes `snap_at` leaving the snapshot.  The family brings
+    `_suffix_piece`, the dispatch of ONE piece, and `snap_granule`,
+    the token count a snapshot's distance from the mapped length is a
+    multiple of."""
 
     needs_state = True
+    snap_granule = 1
+
+    def join_rungs(self, cache: PagedKVCache) -> tuple[int, ...]:
+        """One row a suffix program: it restores the row's state, runs
+        the recurrence over it and leaves a snapshot, none of which
+        has a row axis yet (ROADMAP.md A1)."""
+        return (1,)
+
+    def _state_program(self, short: str, body):
+        def build():
+            def run(states, *a):
+                return [[body(arr, *a) for arr in layer]
+                        for layer in states]
+            return run
+        return self._program((short,), short, build, donate=(0,))
+
+    def state_restore(self, cache: PagedKVCache, src: int, row: int):
+        """Copy snapshot slot `src` into `row`'s slot (a prefix hit)."""
+        fn = self._state_program(
+            "state_copy", lambda a, src, dst: a.at[dst].set(a[src]))
+        cache.states = fn(cache.states, jnp.int32(src), jnp.int32(row))
+
+    def state_zero(self, cache: PagedKVCache, row: int):
+        """A prompt from nothing starts from the zero state."""
+        fn = self._state_program(
+            "state_zero",
+            lambda a, dst: a.at[dst].set(jnp.zeros_like(a[0])))
+        cache.states = fn(cache.states, jnp.int32(row))
+
+    def paged_prefill_row(self, cache: PagedKVCache,
+                          prompt_ids: np.ndarray, row: int, *,
+                          snap_at: int | None = None,
+                          snap_slot: int | None = None) -> np.ndarray:
+        """A whole prompt from nothing: the zero state, an empty table,
+        and the suffix program over it."""
+        if len(prompt_ids) == 0:
+            raise ValueError("empty prompt")
+        cache.lengths[row] = 0
+        self.state_zero(cache, row)
+        return self.paged_append_prefill(cache, prompt_ids, row,
+                                         snap_at=snap_at,
+                                         snap_slot=snap_slot)
+
+    def paged_append_prefill(self, cache: PagedKVCache, suffix_ids,
+                             row: int, *, snap_at: int | None = None,
+                             snap_slot: int | None = None) -> np.ndarray:
+        """Prefill the suffix of row's prompt atop the
+        cache.lengths[row] tokens its table maps and the state in its
+        slot.  With `snap_at` (a token count of the whole prompt, whole
+        granules past the mapped length) the state after that many
+        tokens is left in state slot `snap_slot`.  Returns the last
+        real token's logits (V,)."""
+        ids = np.asarray(suffix_ids, np.int32)
+        if ids.size == 0:
+            raise ValueError("empty suffix")
+        pos = int(cache.lengths[row])
+        if pos + ids.size >= self.cfg.max_len:
+            raise ValueError("suffix exceeds context window")
+        if snap_at is not None and (
+                snap_slot is None or not pos < snap_at <= pos + ids.size
+                or (snap_at - pos) % self.snap_granule):
+            raise ValueError(
+                f"a snapshot at {snap_at} is not whole chunks of "
+                f"{self.snap_granule} inside {pos}..{pos + ids.size}")
+        if not cache.ensure(row, pos + ids.size):
+            raise RuntimeError(
+                f"paged pool exhausted: row {row} suffix needs "
+                f"{cache.pages_needed(pos + ids.size)} pages")
+        spare = cache.state_spare
+        logits, mark, off = None, None, 0
+        while off < ids.size:
+            rem = ids.size - off
+            sb = next((b for b in self.suffix_buckets if b >= rem),
+                      self.suffix_buckets[-1])
+            n = min(rem, sb)
+            piece = np.zeros((1, sb), np.int32)
+            piece[0, :n] = ids[off: off + n]
+            here = snap_at is not None and pos + off < snap_at <= pos + off + n
+            logits = self._suffix_piece(
+                cache, row, sb, piece, n,
+                snap_at - pos - off if here else 0,
+                snap_slot if here else spare)
+            close_mark(mark)
+            mark = DEVTIME.take_mark(self._devname("suffix_prefill"))
+            cache.lengths[row] += n
+            off += n
+        out = np.asarray(logits)
+        close_mark(mark)
+        return out
+
+
+class HybridCompletionModel(StateSlotPrograms, LatentCompletionModel):
+    """LatentCompletionModel's paged serving surface over the hybrid
+    stack, plus what a cache with state slots asks of its model
+    (StateSlotPrograms)."""
+
     program_prefix = "hybrid"
     refused_options = {
         **LatentCompletionModel.refused_options,
@@ -464,7 +566,7 @@ class HybridCompletionModel(LatentCompletionModel):
         pages: each width from one page to SUFFIX_PAGES, so a suffix
         pads by less than a page in every layer; a longer one (a cold
         prompt) loops in the widest."""
-        self.kda_chunk = math.gcd(CHUNK, page)
+        self.kda_chunk = self.snap_granule = math.gcd(CHUNK, page)
         self.suffix_buckets = tuple(
             n * page for n in range(1, SUFFIX_PAGES + 1)
             if n * page < self.cfg.max_len) or (self.kda_chunk,)
@@ -478,35 +580,6 @@ class HybridCompletionModel(LatentCompletionModel):
         return PagedKVCache(self.cfg, batch, page=page,
                             pool_pages=pool_pages, kv_dtype=kv_dtype,
                             state_snapshots=state_snapshots)
-
-    def join_rungs(self, cache: PagedKVCache) -> tuple[int, ...]:
-        """One row a suffix program: it restores the row's state, runs
-        the delta rule over it and leaves a snapshot, none of which
-        has a row axis yet (ROADMAP.md A1)."""
-        return (1,)
-
-    # -- state slots -------------------------------------------------------
-
-    def _state_program(self, short: str, body):
-        def build():
-            def run(states, *a):
-                return [[body(arr, *a) for arr in layer]
-                        for layer in states]
-            return run
-        return self._program((short,), short, build, donate=(0,))
-
-    def state_restore(self, cache: PagedKVCache, src: int, row: int):
-        """Copy snapshot slot `src` into `row`'s slot (a prefix hit)."""
-        fn = self._state_program(
-            "state_copy", lambda a, src, dst: a.at[dst].set(a[src]))
-        cache.states = fn(cache.states, jnp.int32(src), jnp.int32(row))
-
-    def state_zero(self, cache: PagedKVCache, row: int):
-        """A prompt from nothing starts from the zero state."""
-        fn = self._state_program(
-            "state_zero",
-            lambda a, dst: a.at[dst].set(jnp.zeros_like(a[0])))
-        cache.states = fn(cache.states, jnp.int32(row))
 
     # -- prefill -----------------------------------------------------------
 
@@ -527,74 +600,19 @@ class HybridCompletionModel(LatentCompletionModel):
         return self._program(("suffix", sb, chunk), "suffix_prefill",
                              build, donate=(1, 2))
 
-    def paged_prefill_row(self, cache: PagedKVCache,
-                          prompt_ids: np.ndarray, row: int, *,
-                          snap_at: int | None = None,
-                          snap_slot: int | None = None) -> np.ndarray:
-        """A whole prompt from nothing: the zero state, an empty table,
-        and the suffix program over it."""
-        if len(prompt_ids) == 0:
-            raise ValueError("empty prompt")
-        cache.lengths[row] = 0
-        self.state_zero(cache, row)
-        return self.paged_append_prefill(cache, prompt_ids, row,
-                                         snap_at=snap_at,
-                                         snap_slot=snap_slot)
-
-    def paged_append_prefill(self, cache: PagedKVCache, suffix_ids,
-                             row: int, *, snap_at: int | None = None,
-                             snap_slot: int | None = None) -> np.ndarray:
-        """Prefill the suffix of row's prompt atop the
-        cache.lengths[row] tokens its table maps and the state in its
-        slot.  With `snap_at` (a token count of the whole prompt, whole
-        chunks past the mapped length) the state after that many
-        tokens is left in state slot `snap_slot`.  Returns the last
-        real token's logits (V,)."""
-        ids = np.asarray(suffix_ids, np.int32)
-        if ids.size == 0:
-            raise ValueError("empty suffix")
-        pos = int(cache.lengths[row])
-        if pos + ids.size >= self.cfg.max_len:
-            raise ValueError("suffix exceeds context window")
-        if snap_at is not None and (
-                snap_slot is None or not pos < snap_at <= pos + ids.size
-                or (snap_at - pos) % self.kda_chunk):
-            raise ValueError(
-                f"a snapshot at {snap_at} is not whole chunks of "
-                f"{self.kda_chunk} inside {pos}..{pos + ids.size}")
-        if not cache.ensure(row, pos + ids.size):
-            raise RuntimeError(
-                f"paged pool exhausted: row {row} suffix needs "
-                f"{cache.pages_needed(pos + ids.size)} pages")
-        table = cache.tables[row: row + 1]
-        spare = cache.state_spare
-        logits, mark, off = None, None, 0
-        while off < ids.size:
-            rem = ids.size - off
-            sb = next((b for b in self.suffix_buckets if b >= rem),
-                      self.suffix_buckets[-1])
-            n = min(rem, sb)
-            piece = np.zeros((1, sb), np.int32)
-            piece[0, :n] = ids[off: off + n]
-            here = snap_at is not None and pos + off < snap_at <= pos + off + n
-            pools, states, logits = self._suffix_program(sb)(
-                self.params, cache.pools[0], cache.states,
-                # host-side copies: lengths is bumped right below
-                # (mla.paged_append_prefill)
-                jnp.asarray(np.array(table)),
-                jnp.asarray(np.array(cache.lengths[row: row + 1])),
-                jnp.asarray(piece), jnp.int32(n), jnp.int32(row),
-                jnp.int32(snap_at - pos - off if here else 0),
-                jnp.int32(snap_slot if here else spare))
-            close_mark(mark)
-            mark = DEVTIME.take_mark(self._devname("suffix_prefill"))
-            cache.pools[0] = list(pools)
-            cache.states = states
-            cache.lengths[row] += n
-            off += n
-        out = np.asarray(logits)
-        close_mark(mark)
-        return out
+    def _suffix_piece(self, cache: PagedKVCache, row: int, sb: int,
+                      piece, n: int, n_snap: int, snap_slot: int):
+        pools, states, logits = self._suffix_program(sb)(
+            self.params, cache.pools[0], cache.states,
+            # host-side copies: lengths is bumped right after
+            # (mla.paged_append_prefill)
+            jnp.asarray(np.array(cache.tables[row: row + 1])),
+            jnp.asarray(np.array(cache.lengths[row: row + 1])),
+            jnp.asarray(piece), jnp.int32(n), jnp.int32(row),
+            jnp.int32(n_snap), jnp.int32(snap_slot))
+        cache.pools[0] = list(pools)
+        cache.states = states
+        return logits
 
     # -- decode ------------------------------------------------------------
 

@@ -349,6 +349,28 @@ FAMILIES: dict[str, dict] = {
                                    "bias, zero at seeded weights) or "
                                    "greedy is served"),
         }},
+    # the LFM2-MoE key set: models/lfm2.py (gated short-convolution
+    # layers with state slots beside grouped-query key/value pages)
+    "lfm2_moe": {
+        "window": "max_position_embeddings", "experts": "num_experts",
+        "dense": "num_dense_layers",
+        "keys": {
+            **{k: v for k, v in _COMMON.items() if k != "rms_norm_eps"},
+            "norm_eps": Key("rms_eps", 1e-5, float),
+            "max_position_embeddings": Key(None, None),
+            "num_key_value_heads": Key("kv_heads"),
+            "layer_types": Key(None, cast=list),
+            "conv_L_cache": Key("conv_kernel"),
+            "conv_bias": _served("conv_bias is not served"),
+            "num_dense_layers": Key(None, 0),
+            "rope_parameters": Key(None, cast=dict),
+            "num_experts": Key("n_routed_experts"),
+            "num_experts_per_tok": Key("top_k"),
+            "norm_topk_prob": Key("norm_topk_prob", True, bool),
+            "routed_scaling_factor": Key("routed_scaling_factor", 1.0,
+                                         float),
+            "use_expert_bias": Key("expert_bias", False, bool),
+        }},
 }
 LAYER_TYPES = {"sliding_attention": "window", "full_attention": "full"}
 LINEAR_ATTN_KEYS = frozenset(("full_attn_layers", "kda_layers", "head_dim",
@@ -461,17 +483,45 @@ def _finish_sink_window(arch, fields, path):
         **fields)
 
 
+def _finish_conv(arch, fields, path):
+    from .lfm2 import ConvMoeConfig
+    n_layers = int(arch["num_hidden_layers"])
+    types, rope = arch["layer_types"], arch["rope_parameters"]
+    if len(types) != n_layers or set(types) - {"conv", "full_attention"}:
+        raise ValueError(
+            f"{path}: layer_types must name {n_layers} layers, each "
+            "'conv' or 'full_attention'")
+    if set(rope) - {"rope_theta", "rope_type"} \
+            or rope.get("rope_type", "default") != "default":
+        raise ValueError(f"{path}: rope_parameters must hold rope_theta "
+                         "and rope_type 'default' (rope scaling is not "
+                         "served)")
+    # the share keeps its `dense_layers` as the LAST of the model's
+    # leading dense layers, then the layers after them: leading dense
+    # layers beyond the share's count are passed over, not relabelled
+    layers = fields.pop("layers")
+    skip = int(arch.get("num_dense_layers", 0)) - fields["dense_layers"]
+    if skip + layers > n_layers:
+        raise ValueError("the share keeps more layers than the model has")
+    return ConvMoeConfig(
+        kinds=tuple("conv" if t == "conv" else "full"
+                    for t in types[skip: skip + layers]),
+        model_layers=n_layers, head_dim=fields["hidden"] // fields["heads"],
+        rope_base=float(rope.get("rope_theta", 10000.0)), **fields)
+
+
 FAMILIES["pangu_ultra_moe"]["finish"] = _finish_latent
 FAMILIES["kimi_linear"]["finish"] = _finish_hybrid
 FAMILIES["afmoe"]["finish"] = _finish_window
 FAMILIES["mimo_v2_flash"]["finish"] = _finish_sink_window
+FAMILIES["lfm2_moe"]["finish"] = _finish_conv
 
 
 def load_model_description(path: str, *, max_len: int | None = None):
     """A model description file -> (config, seed): a LatentMoeConfig,
-    a models/kda.HybridMoeConfig or a models/afmoe.WindowMoeConfig
-    (AFMoE's setting or MiMo-V2-Flash's), by the architecture's
-    `model_type` (FAMILIES).
+    a models/kda.HybridMoeConfig, a models/afmoe.WindowMoeConfig
+    (AFMoE's setting or MiMo-V2-Flash's) or a models/lfm2.ConvMoeConfig,
+    by the architecture's `model_type` (FAMILIES).
 
     {"architecture": {published keys verbatim, at their published
                       values},
@@ -549,6 +599,9 @@ def completion_model_class(cfg):
     from .afmoe import WindowCompletionModel, WindowMoeConfig
     if isinstance(cfg, WindowMoeConfig):
         return WindowCompletionModel
+    from .lfm2 import ConvCompletionModel, ConvMoeConfig
+    if isinstance(cfg, ConvMoeConfig):
+        return ConvCompletionModel
     from .kda import HybridCompletionModel
     return HybridCompletionModel
 
@@ -591,6 +644,13 @@ def ffn_params(cfg, seed: int, p: str, dense: bool, mat, down=None):
     lp = {"router": seed_tensor(seed, p + "router",
                                 (H, cfg.n_routed_experts),
                                 1.0 / math.sqrt(H), jnp.float32)}
+    if getattr(cfg, "expert_bias", False):
+        # the router's selection bias (moe.router_gates): learned by
+        # the balancing rule in a trained model, seeded NON-zero here
+        # so that the mechanism is served (models/lfm2.py, WEIGHTS)
+        lp["router_bias"] = seed_tensor(
+            seed, p + "router_bias", (cfg.n_routed_experts,),
+            cfg.expert_bias_std, jnp.float32)
     if cfg.n_shared_experts:
         lp["shared_gate"] = mat(p + "shared.gate", (H, M))
         lp["shared_up"] = mat(p + "shared.up", (H, M))
@@ -759,7 +819,7 @@ def _ffn(cfg: LatentMoeConfig, lp, x, live, interpret: bool, bank=None,
         top_k=cfg.top_k, first=cfg.experts_first, score=cfg.score_fn,
         norm_topk=cfg.norm_topk_prob, scale=cfg.routed_scaling_factor,
         shared=shared, live=live, interpret=interpret, bank=bank,
-        route_x=route_x)
+        route_x=route_x, bias=lp.get("router_bias"))
 
 
 def _layer(cfg: LatentMoeConfig, lp, x, pos, attend, live,
@@ -864,15 +924,19 @@ class LatentPendingChunk(PendingChunk):
     block, `slots` — (count,) int32 expert-slots the chunk's LIVE rows
     sent to each held expert, all steps and layers — and `audit`, the
     (n, V) float32 logits of the one row the dispatch was told to
-    keep.  Both stay on the device until asked for; after block()
+    keep; `counts`, where the model keeps them (models/lfm2.py): (2,)
+    int32 — experts that received a slot, summed over the chunk's
+    steps and expert layers, and selections the router's bias
+    changed.  All stay on the device until asked for; after block()
     reading them waits for nothing."""
 
-    __slots__ = ("slots", "audit")
+    __slots__ = ("slots", "audit", "counts")
 
-    def __init__(self, out, last, n, mark, slots, audit):
+    def __init__(self, out, last, n, mark, slots, audit, counts=None):
         super().__init__(out, last, n, mark)
         self.slots = slots
         self.audit = audit
+        self.counts = counts
 
 
 def prefill_buckets(max_len: int, page: int) -> tuple[int, ...]:
@@ -948,6 +1012,12 @@ class LatentCompletionModel:
         """The batch row `lane` audits from the next chunk on (-1:
         none)."""
         self.audit_row = row
+
+    def audit_lane(self, match: int, n_suffix: int) -> int:
+        """The audit lane of a join that mapped `match` tokens and
+        prefilled `n_suffix`, for a model that keeps several
+        (`audit_lanes`): a resumed row 0, one from nothing 1."""
+        return int(not match)
 
     def resident_bytes(self) -> int:
         return sum(a.nbytes for a in jax.tree_util.tree_leaves(

@@ -71,25 +71,48 @@ MOE_CHUNK_TOKENS = 2048
 GMM_TILES = (128, 1024, 1024)
 
 
+def _router_scores(x, router, score: str):
+    """(T, E) float32 scores over ALL of the model's experts."""
+    logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    if score == "softmax":
+        return jax.nn.softmax(logits, axis=-1)
+    if score == "sigmoid":
+        return jax.nn.sigmoid(logits)
+    raise ValueError(f"unknown router score function {score!r}")
+
+
 def router_gates(x, router, *, top_k: int, score: str = "softmax",
-                 norm_topk: bool = True, scale: float = 1.0):
+                 norm_topk: bool = True, scale: float = 1.0, bias=None):
     """The routing every share of a layer computes alike, in float32
     over ALL of the model's experts.  x: (T, H); router: (H, E).
     Returns (ids (T, k) int32, gates (T, k) f32): the k best experts
     a token and the weight of each — softmax or sigmoid scores,
-    normalised over the selection (norm_topk), times `scale`."""
-    logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
-                     precision=jax.lax.Precision.HIGHEST)
-    if score == "softmax":
-        scores = jax.nn.softmax(logits, axis=-1)
-    elif score == "sigmoid":
-        scores = jax.nn.sigmoid(logits)
+    normalised over the selection (norm_topk), times `scale`.
+    bias: None, or (E,) float32 — a learned SELECTION bias (the
+    auxiliary-loss-free balancing of the DeepSeek-V3 line): the k best
+    are those of scores + bias, their gates the scores alone."""
+    scores = _router_scores(x, router, score)
+    if bias is None:
+        topv, topi = jax.lax.top_k(scores, top_k)
     else:
-        raise ValueError(f"unknown router score function {score!r}")
-    topv, topi = jax.lax.top_k(scores, top_k)
+        _, topi = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+        topv = jnp.take_along_axis(scores, topi, axis=-1)
     if norm_topk:
         topv = topv / jnp.maximum(topv.sum(-1, keepdims=True), 1e-20)
     return topi.astype(jnp.int32), topv * scale
+
+
+def router_bias_swaps(x, router, bias, live, *, top_k: int, score: str):
+    """How many of the LIVE tokens' selections the bias changed: the
+    (token, expert) slots of the top-k of scores + bias that the top-k
+    of the scores alone does not hold.  x: (T, H); live: (T,) bool.
+    Returns an int32 scalar."""
+    scores = _router_scores(x, router, score)
+    _, with_b = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    _, without = jax.lax.top_k(scores, top_k)
+    kept = (with_b[:, :, None] == without[:, None, :]).any(-1)
+    return jnp.sum(~kept & live[:, None], dtype=jnp.int32)
 
 
 def _tile(dim: int, want: int) -> int:
@@ -172,7 +195,8 @@ def _dispatch(x, ids, gates, live, wg, wu, wd, first: int,
 def sparse_moe(x, router, wg, wu, wd, *, top_k: int, first: int = 0,
                score: str = "softmax", norm_topk: bool = True,
                scale: float = 1.0, shared=None, live=None,
-               interpret: bool = False, bank=None, route_x=None):
+               interpret: bool = False, bank=None, route_x=None,
+               bias=None):
     """The expert layer over the experts held here.
 
     x: (..., H) activations; router: (H, E) over ALL E experts of the
@@ -188,7 +212,8 @@ def sparse_moe(x, router, wg, wu, wd, *, top_k: int, first: int = 0,
     or x as the ROUTER sees it, where the caller has it unrounded
     (float32, x's shape): which experts a token takes is the layer's
     one discontinuous decision, and a rounding of its input flips it
-    where two experts score alike.
+    where two experts score alike; bias: None, or the router's (E,)
+    selection bias (router_gates).
     Returns (out (..., H) in x's dtype — the shared expert plus the
     gated sum over the HELD experts among each token's top-k, (count,)
     int32 — the slots each held expert received)."""
@@ -198,7 +223,8 @@ def sparse_moe(x, router, wg, wu, wd, *, top_k: int, first: int = 0,
     live2 = jnp.ones((T,), bool) if live is None else live.reshape(-1)
     ids, gates = router_gates(
         x2 if route_x is None else route_x.reshape(-1, H), router,
-        top_k=top_k, score=score, norm_topk=norm_topk, scale=scale)
+        top_k=top_k, score=score, norm_topk=norm_topk, scale=scale,
+        bias=bias)
     if T <= MOE_CHUNK_TOKENS:
         out, sizes = _dispatch(x2, ids, gates, live2, wg, wu, wd,
                                first, interpret, bank)

@@ -539,6 +539,12 @@ def paged_attention(q, k_pool, v_pool, tables, lengths, *,
 # model keeps those keys as k_pool (n_blocks, L, KH, Dk, page) —
 # whole tiles, the layout of ops/latent_attention's pages — and the
 # score is q (R, Dk) @ k (Dk, page) with no transpose at all.
+# VALUES A TOKEN A COLUMN (`v_cols`) for the same reason, where a head
+# is NARROWER than the tile (64): a (page, 64) block half-fills its
+# lanes, and the compiler copied a 1 GB pool of them for every call
+# (tests/test_chip_compile.py); kept as v_pool (n_blocks, L, KH, Dv,
+# page) the sum is p (R, page) . v (Dv, page) over the lanes of both,
+# the form the score of keys a token a row has.
 
 # query rows (tokens x heads of a kv group) one program holds
 WINDOW_Q_ROWS = 1024
@@ -565,7 +571,7 @@ def window_walk_pages(window: int, page: int, block_tokens: int) -> int:
 def _window_kernel(tab_ref, len_ref, start_ref, layer_ref, q_ref, k_ref,
                    v_ref, *rest, page: int, scale: float, rep: int,
                    block_tokens: int, n_table: int, sink: bool,
-                   k_cols: bool):
+                   k_cols: bool, v_cols: bool = False):
     """One (batch row, kv-head block, query block, walked page)
     program.
 
@@ -574,8 +580,8 @@ def _window_kernel(tab_ref, len_ref, start_ref, layer_ref, q_ref, k_ref,
       layer_ref: (1,) SMEM, the layer of the group's pool
       q_ref:   (1, hb, R, Dk), R = block_tokens * rep, token-major
       k_ref: (1, 1, hb, page, Dk) — (1, 1, hb, Dk, page) where
-      `k_cols` — v_ref: (1, 1, hb, page, Dv): the page the walk
-      routed here
+      `k_cols` — v_ref: (1, 1, hb, page, Dv) — (1, 1, hb, Dv, page)
+      where `v_cols`: the page the walk routed here
       rest:  [sink_ref (hb, R, 1) f32 — each query row's sink logit,
       where `sink`], out_ref (1, hb, R, Dv), then the scratch:
       m_s/l_s: (hb, R, 1) f32;  acc_s: (hb, R, Dv) f32
@@ -617,7 +623,7 @@ def _window_kernel(tab_ref, len_ref, start_ref, layer_ref, q_ref, k_ref,
         for h in range(hb):
             q = q_ref[0, h]                             # (R, Dk)
             k = k_ref[0, 0, h]                  # (page, Dk) | (Dk, page)
-            v = v_ref[0, 0, h]                          # (page, Dv)
+            v = v_ref[0, 0, h]                  # (page, Dv) | (Dv, page)
             logits = jax.lax.dot_general(
                 q, k, (((1,), (0 if k_cols else 1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale
@@ -629,9 +635,11 @@ def _window_kernel(tab_ref, len_ref, start_ref, layer_ref, q_ref, k_ref,
             pexp = jnp.where(valid, jnp.exp(logits - m_new), 0.0)
             m_s[h] = m_new
             l_s[h] = l_prev * corr + jnp.sum(pexp, -1, keepdims=True)
-            acc_s[h] = acc_s[h] * corr + jnp.dot(
+            acc_s[h] = acc_s[h] * corr + (jax.lax.dot_general(
+                pexp.astype(v.dtype), v, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) if v_cols else jnp.dot(
                 pexp.astype(v.dtype), v,
-                preferred_element_type=jnp.float32)
+                preferred_element_type=jnp.float32))
 
     @pl.when(w == n_walk - 1)
     def _write():
@@ -642,19 +650,20 @@ def _window_kernel(tab_ref, len_ref, start_ref, layer_ref, q_ref, k_ref,
 
 # splint: ignore[SPL205] reason=runs inside the registered paged programs (completer.paged_chunk / completer.suffix_prefill); the outer program is the attribution point
 @functools.partial(jax.jit, static_argnames=(
-    "n_walk", "block_tokens", "q_tokens", "interpret", "k_cols"))
+    "n_walk", "block_tokens", "q_tokens", "interpret", "k_cols", "v_cols"))
 def _window_pallas(q4, k_pool, v_pool, tables, lengths, starts, layer, *,
                    n_walk: int, block_tokens: int, q_tokens: int,
-                   interpret: bool, sinks=None, k_cols: bool = False):
+                   interpret: bool, sinks=None, k_cols: bool = False,
+                   v_cols: bool = False):
     """q4: (B, KH, q_tokens*rep, Dk) token-major; k_pool: (n_blocks, L,
     KH, page, Dk) — (n_blocks, L, KH, Dk, page) where `k_cols` —
-    v_pool: (n_blocks, L, KH, page, Dv); tables (B, P); lengths,
-    starts (B,); layer (1,); sinks: None or (KH, rep) f32, a logit a
-    head.  Returns (B, KH, q_tokens*rep, Dv)."""
+    v_pool: (n_blocks, L, KH, page, Dv) — (n_blocks, L, KH, Dv, page)
+    where `v_cols`; tables (B, P); lengths, starts (B,); layer (1,);
+    sinks: None or (KH, rep) f32, a logit a head.  Returns (B, KH,
+    q_tokens*rep, Dv)."""
     B, KH, RT, D = q4.shape
-    Dv = v_pool.shape[4]
+    page, Dv = v_pool.shape[3:][::-1] if v_cols else v_pool.shape[3:]
     rep = RT // q_tokens
-    page = v_pool.shape[3]
     P = tables.shape[1]
     R = block_tokens * rep
     # a decode step carries every kv head in one program; a stack of
@@ -675,7 +684,7 @@ def _window_pallas(q4, k_pool, v_pool, tables, lengths, starts, layer, *,
     in_specs = [pl.BlockSpec((1, hb, R, D), _q_map,
                              memory_space=pltpu.VMEM),
                 kv_spec(D, page) if k_cols else kv_spec(page, D),
-                kv_spec(page, Dv)]
+                kv_spec(Dv, page) if v_cols else kv_spec(page, Dv)]
     operands = [q4, k_pool, v_pool]
     if sinks is not None:
         # a block's rows are token-major: row r is head r % rep
@@ -699,7 +708,8 @@ def _window_pallas(q4, k_pool, v_pool, tables, lengths, starts, layer, *,
         functools.partial(_window_kernel, page=page,
                           scale=1.0 / float(np.sqrt(D)), rep=rep,
                           block_tokens=block_tokens, n_table=P,
-                          sink=sinks is not None, k_cols=k_cols),
+                          sink=sinks is not None, k_cols=k_cols,
+                          v_cols=v_cols),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KH, RT, Dv), q4.dtype),
         interpret=interpret,
@@ -712,7 +722,7 @@ def _window_pallas(q4, k_pool, v_pool, tables, lengths, starts, layer, *,
 
 def window_paged_attention(q, k_pool, v_pool, tables, lengths, *, layer,
                            window: int = 0, sinks=None,
-                           k_cols: bool = False,
+                           k_cols: bool = False, v_cols: bool = False,
                            interpret: bool = False,
                            force_pallas: bool = False):
     """Ragged paged attention over ONE LAYER of a page group's pool,
@@ -724,7 +734,8 @@ def window_paged_attention(q, k_pool, v_pool, tables, lengths, *, layer,
     of them (0 <= position - j < window);
     k_pool: (n_blocks, L, KH, page, D) — (n_blocks, L, KH, D, page),
     a token a column, where `k_cols` — v_pool: (n_blocks, L, KH, page,
-    Dv), kv heads unrepeated, Dv the values' own width;
+    Dv) — (n_blocks, L, KH, Dv, page) where `v_cols` — kv heads
+    unrepeated, Dv the values' own width;
     layer: int32 scalar (traced or not), the layer within the group;
     tables: (B, P) the GROUP's block table; a window group's entries
     behind the window may name the trash block: they are not read;
@@ -732,8 +743,8 @@ def window_paged_attention(q, k_pool, v_pool, tables, lengths, *, layer,
     every query's softmax denominator and carries no value.
     Returns (B, S, H, Dv) in q's dtype."""
     B, S, H, D = q.shape
-    KH, page = v_pool.shape[2], v_pool.shape[3]
-    Dv = v_pool.shape[4]
+    KH = v_pool.shape[2]
+    page, Dv = v_pool.shape[3:][::-1] if v_cols else v_pool.shape[3:]
     rep = H // KH
     tables = jnp.asarray(tables, jnp.int32)
     lengths = jnp.asarray(lengths, jnp.int32)
@@ -741,10 +752,10 @@ def window_paged_attention(q, k_pool, v_pool, tables, lengths, *, layer,
               else jnp.full_like(lengths, NO_START))
     layer = jnp.asarray(layer, jnp.int32)
     if not (force_pallas or interpret or jax.default_backend() == "tpu"):
-        k_layer = k_pool[:, layer]
+        k_layer, v_layer = k_pool[:, layer], v_pool[:, layer]
         return _paged_ref(q, k_layer.swapaxes(-1, -2) if k_cols
-                          else k_layer, v_pool[:, layer], tables,
-                          lengths, starts, sinks)
+                          else k_layer, v_layer.swapaxes(-1, -2) if v_cols
+                          else v_layer, tables, lengths, starts, sinks)
     tq = stack_block(S, rep)
     n_walk = tables.shape[1]
     if window > 0:
@@ -755,6 +766,7 @@ def window_paged_attention(q, k_pool, v_pool, tables, lengths, *, layer,
                          layer.reshape(1), n_walk=n_walk,
                          block_tokens=tq, q_tokens=S,
                          interpret=interpret, k_cols=k_cols,
+                         v_cols=v_cols,
                          sinks=None if sinks is None
                          else jnp.asarray(sinks).reshape(KH, rep))
     return out.reshape(B, KH, S, rep, Dv).transpose(0, 2, 1, 3, 4) \

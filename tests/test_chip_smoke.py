@@ -147,6 +147,7 @@ def test_single_guarded_cache_call_site():
     # the benchmark's plain references import nothing of the package
     # they check, so each keeps its own (equally guarded) site
     assert sorted(h.split(":")[0] for h in hits) == [
+        "benchmark/reference/conv_gqa_moe_block.py",
         "benchmark/reference/hybrid_kda_block.py",
         "benchmark/reference/latent_moe_block.py",
         "benchmark/reference/window_gqa_moe_block.py",

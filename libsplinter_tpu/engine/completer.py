@@ -1394,9 +1394,12 @@ class Completer:
             # the round's hits whose suffix one program width holds
             # are seated first and prefilled together, up to as many
             # rows as the model's widest suffix program takes; a model
-            # whose programs are one row wide joins request by request
+            # whose programs are one row wide joins request by request,
+            # and a join that leaves a state snapshot rides the round
+            # only where the model's rows program leaves it
             rungs = getattr(m, "join_rungs", None)
             round_cap = rungs(cache)[-1] if rungs is not None else 1
+            snaps_ride = getattr(m, "join_snapshots", False)
             round_joins: list[dict] = []
             # a model with per-row recurrent state (models/kda.py):
             # a hit resumes from a snapshot, a join leaves one
@@ -1676,7 +1679,8 @@ class Completer:
                             "hit": bool(hit_bids), "snap": snap,
                             "reserve": reserve, "tenant": tenant,
                             "w_s0": w_s0}
-                    if round_cap > 1 and hit_bids and snap is None \
+                    if round_cap > 1 and hit_bids \
+                            and (snap is None or snaps_ride) \
                             and len(suffix) <= m.suffix_buckets[-1]:
                         # a hit inside one program width: its prefill
                         # waits for the round's other hits
@@ -1720,7 +1724,13 @@ class Completer:
             (a miss: its bucket prefill) and draws on the host;
             several — hits, each suffix inside one program width — ride
             ONE dispatch of the model's row-batched suffix program,
-            which draws their first tokens in graph.  `infer.join` is
+            which draws their first tokens in graph and, for a model
+            with state slots that says `join_snapshots`, leaves each
+            row's snapshot in the slot fill_rows allocated for it (the
+            rows' states were restored at their seats, before the
+            program: it reads no snapshot a later seat may have
+            evicted); each snapshot's node takes its slot over below, a
+            row at a time, as after a one-row join.  `infer.join` is
             one annotation from the dispatch to the logits and is
             recorded once a ROW, the round's wall over its rows;
             `infer.sample` is a row's share of what stands between the
@@ -1733,9 +1743,13 @@ class Completer:
             ta = time.perf_counter()
             with tracer.annotation("infer.join"):
                 if len(joins) > 1:
+                    # only a model that says `join_snapshots` has a
+                    # round with a snapshot in it (fill_rows)
+                    snaps = [j["snap"] for j in joins]
                     logits, firsts = m.paged_append_prefill_rows(
                         cache, [(j["r"], np.asarray(j["suffix"], np.int32))
-                                for j in joins])
+                                for j in joins],
+                        *([snaps] if any(snaps) else ()))
                 else:
                     j = joins[0]
                     skw = ({"snap_at": j["snap"][1],

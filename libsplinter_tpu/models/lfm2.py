@@ -85,12 +85,27 @@ from .kda import StateSlotPrograms, _head, _normed
 from .mla import (LatentCompletionModel, LatentPendingChunk, _ffn, _rms,
                   _sum_slots, ffn_params, seed_tensor)
 from .moe import router_bias_swaps
-from ..obs.devtime import DEVTIME
+from ..obs.devtime import DEVTIME, close_mark
 
 KINDS = ("conv", "full")
 # pages of the widest suffix program: a tool result or a user turn of a
 # few hundred tokens fits one call, a cold prompt loops in it
 SUFFIX_PAGES = 4
+# rows of the row-batched suffix program (join_rungs): ONE rung beside
+# the one-row programs.  32 is where a round's expert products turn
+# compute-bound (~500 real tokens an expert: more rows a program save a
+# host round trip and no weight read) and leaves the chip room — its
+# temporaries at the benchmark's widths are 1.06 GB beside 12.79 GB of
+# arguments, 82% of a v5e (tests/test_chip_compile.py).  No rung
+# between: a program is 20-30 s of a cold start that has none to spare
+# (PERF.md section 6, PR 41), and in 96 sessions' traffic a round is
+# one row (the first back) or the rung's fill
+JOIN_ROWS = 32
+# LIVE tokens of a round's rows one pass over the experts takes
+# (moe.sparse_moe live_chunk): a round's rows are 512 token slots each
+# and about a third of them real; in chunks of 2,048 SLOTS the 32-row
+# program read every expert's weights eight times
+JOIN_MOE_CHUNK = 8192
 BIAS_STD = 0.015
 # joins whose suffix is at most this many tokens audit in lane 0: their
 # first answer token still lies inside the reach of the stacked
@@ -305,16 +320,17 @@ def _attn_mix(cfg: ConvMoeConfig, lp, xn, pos, pools, write, gl, tables,
                    preferred_element_type=f32), (kp, vp)
 
 
-def _finish_layer(cfg: ConvMoeConfig, lp, x, a, live, interpret):
+def _finish_layer(cfg: ConvMoeConfig, lp, x, a, live, interpret,
+                  live_chunk=None):
     """The residual stream stays float32 from the embedding to the
     head (models/kda.py); the router reads the normed stream unrounded
-    (moe.sparse_moe).  Returns (x, slots each held expert received |
-    None, (2,) int32 [experts that received any, selections the bias
-    changed] | None)."""
+    (moe.sparse_moe, and `live_chunk`).  Returns (x, slots each held
+    expert received | None, (2,) int32 [experts that received any,
+    selections the bias changed] | None)."""
     h = x + a
     hn = _rms(h, lp["ln_mlp_in"], cfg.rms_eps)
     f, slots = _ffn(cfg, lp, hn.astype(cfg.dtype), live, interpret,
-                    route_x=hn)
+                    route_x=hn, live_chunk=live_chunk)
     counts = None
     if slots is not None:
         swaps = router_bias_swaps(
@@ -405,6 +421,63 @@ def forward_suffix(cfg: ConvMoeConfig, params, ids, pools, states, table,
                                  cfg.group_index(i), table, pos[:, 0] + 1,
                                  interpret)
         x, _, c = _finish_layer(cfg, lp, x, a, ok, interpret)
+        if c is not None:
+            live_experts = live_experts + c[0]
+    return x, pools, new_states, live_experts
+
+
+def forward_suffix_rows(cfg: ConvMoeConfig, params, ids, pools, states,
+                        tables, lengths, n_valid, row_slot, n_snap,
+                        snap_slot, *, interpret: bool = False):
+    """forward_suffix with a ROW axis: the hits of one admission round
+    in one program, so a layer's weights are read once a round.  ids:
+    (R, S) padded to whole pages, n_valid (R,) real (0: a pad row, its
+    length 0, its table trash blocks, its slot the spare one); row i's
+    registers are read from slot row_slot[i] and written back there as
+    they stand after n_valid[i] tokens, and to slot snap_slot[i] as
+    they stood after n_snap[i] (the spare slot where the row leaves no
+    snapshot: no live slot is ever written twice); its keys go to
+    tables[i] from page lengths[i] // page on, a page none of its real
+    tokens reaches to the trash block.  Pad tokens reach no expert.
+    Returns what forward_suffix does, hidden (R, S, H)."""
+    R, S = ids.shape
+    page = pools[0].shape[4]
+    n_p = S // page
+    at = jnp.arange(S)[None, :]
+    pos = jnp.minimum(lengths[:, None] + at,
+                      cfg.max_len - 1).astype(jnp.int32)
+    ok = at < n_valid[:, None]                            # (R, S)
+    piece = jnp.arange(n_p)[None, :]
+    held = jnp.take_along_axis(
+        tables, jnp.minimum(lengths[:, None] // page + piece,
+                            tables.shape[1] - 1), axis=1)
+    bids = jnp.where(piece * page < n_valid[:, None], held, 0).reshape(-1)
+    tail = cfg.conv_kernel - 1
+
+    def write(pool, new, gl):         # whole pages, a token a column
+        pages = new.reshape(R * n_p, page, *new.shape[2:])
+        return pool.at[bids, gl].set(pages.transpose(0, 2, 3, 1))
+    x = params["tok_emb"][ids].astype(jnp.float32)
+    new_states, live_experts = [], jnp.int32(0)
+    for i, (lp, kind) in enumerate(zip(params["layers"], cfg.kinds)):
+        xn = _normed(cfg, x, lp["ln_mix_in"])
+        if kind == "conv":
+            (reg,) = states[len(new_states)]
+            a, full = jax.vmap(
+                lambda xr, rr, lp=lp: conv_suffix(cfg, lp, xr, rr))(
+                    xn, reg[row_slot])
+
+            def reg_at(n, full=full):
+                return jax.vmap(lambda f, k: jax.lax.dynamic_slice_in_dim(
+                    f, k, tail, 0))(full, n)
+            new_states.append([reg.at[snap_slot].set(reg_at(n_snap))
+                               .at[row_slot].set(reg_at(n_valid))])
+        else:
+            a, pools = _attn_mix(cfg, lp, xn, pos, pools, write,
+                                 cfg.group_index(i), tables, pos[:, 0] + 1,
+                                 interpret)
+        x, _, c = _finish_layer(cfg, lp, x, a, ok, interpret,
+                                live_chunk=JOIN_MOE_CHUNK)
         if c is not None:
             live_experts = live_experts + c[0]
     return x, pools, new_states, live_experts
@@ -530,6 +603,93 @@ class ConvCompletionModel(StateSlotPrograms, GroupPagePrograms,
         self._prefill_live.append(live)
         return logits
 
+    # -- an admission round's hits in one program -----------------------------
+
+    # the rows program leaves each row's snapshot itself: a round takes
+    # a join whether or not it leaves one (completer.fill_rows)
+    join_snapshots = True
+
+    def join_rungs(self, cache: PagedKVCache) -> tuple[int, ...]:
+        """The row counts the suffix programs come in, ascending
+        (mla.join_rungs): 1 and JOIN_ROWS, or the lane's batch where
+        that is less."""
+        return tuple(sorted({1, min(JOIN_ROWS, cache.batch)}))
+
+    def _suffix_rows_program(self, rows: int, sb: int):
+        cfg, interp = self.cfg, self.interpret
+        top_p, temp = self.top_p, self.temp
+
+        def build():
+            def run(params, pools, states, tables, lengths, ids, n_valid,
+                    row_slot, n_snap, snap_slot, rng):
+                x, pools, states, live = forward_suffix_rows(
+                    cfg, params, ids, pools, states, tables, lengths,
+                    n_valid, row_slot, n_snap, snap_slot, interpret=interp)
+                last = jnp.take_along_axis(
+                    x, jnp.maximum(n_valid - 1, 0)[:, None, None],
+                    axis=1)[:, 0]
+                logits = _head(cfg, params, last)
+                return (pools, states, logits,
+                        _sample_rows(rng, logits, top_p, temp), live)
+            return run
+        return self._program(("suffix", rows, sb, top_p, temp),
+                             "suffix_prefill", build, donate=(1, 2))
+
+    def paged_append_prefill_rows(self, cache: PagedKVCache, joins,
+                                  snaps=None):
+        """mla.paged_append_prefill_rows over (pages + state): joins is
+        [(row, suffix_ids), ...], every row seated with its prefix
+        mapped and its registers RESTORED into its slot already (the
+        program reads no snapshot: a later seat of the round may have
+        evicted the one a row resumed from); snaps[i] is None or
+        (slot, snap_at) as paged_append_prefill takes them.  Pad rows
+        and rows that leave no snapshot write the spare slot.  Returns
+        (logits on the device, first tokens on the host)."""
+        snaps = snaps or [None] * len(joins)
+        if len(joins) == 1:
+            (row, suffix), snap = joins[0], snaps[0]
+            logits = self.paged_append_prefill(
+                cache, suffix, row, **({"snap_at": snap[1],
+                                        "snap_slot": snap[0]}
+                                       if snap else {}))
+            return logits[None], np.array([self.sample(logits)], np.int32)
+        ids, n_valid, tables, lengths = self._round_inputs(cache, joins)
+        rows, sb = ids.shape
+        n_snap = np.zeros((rows,), np.int32)
+        row_slot, snap_slot = np.full((2, rows), cache.state_spare,
+                                      np.int32)
+        aw = self._attn_work
+        for i, ((row, _), snap) in enumerate(zip(joins, snaps)):
+            n, pos = int(n_valid[i]), int(lengths[i])
+            if pos % cache.page:
+                raise ValueError(
+                    f"a suffix starts at a page boundary; row {row} holds "
+                    f"{pos} tokens")
+            row_slot[i] = row
+            if snap is not None:
+                if not pos < snap[1] <= pos + n \
+                        or (snap[1] - pos) % self.snap_granule:
+                    raise ValueError(f"a snapshot at {snap[1]} outside "
+                                     f"{pos}..{pos + n}")
+                snap_slot[i], n_snap[i] = snap[0], snap[1] - pos
+            aw["prefill_keys"] += n * pos + n * (n + 1) // 2
+            aw["prefill_kv"] += pos + n
+        self._rng, sub = jax.random.split(self._rng)
+        pools, states, logits, toks, live = self._suffix_rows_program(
+            rows, sb)(
+            self.params, self._pools(cache)["full"], cache.states,
+            *(jnp.asarray(a) for a in (tables, lengths, ids, n_valid,
+                                       row_slot, n_snap, snap_slot)), sub)
+        mark = DEVTIME.take_mark(self._devname("suffix_prefill"))
+        self._keep(cache, {"full": pools})
+        cache.states = states
+        self._prefill_live.append(live)
+        for i, (row, _) in enumerate(joins):
+            cache.lengths[row] += int(n_valid[i])
+        toks = np.asarray(toks)[:len(joins)]
+        close_mark(mark)
+        return logits, toks
+
     # -- decode ------------------------------------------------------------
 
     def _chunk_program(self, n: int, bp: int):
@@ -607,4 +767,6 @@ class ConvCompletionModel(StateSlotPrograms, GroupPagePrograms,
                 chunk_done = True
             cache.free_row(0)
         self.state_restore(cache, cache.state_spare, 0)
+        if self.suffix_buckets[-1] + chunk < self.cfg.max_len:
+            self._warm_join_rungs(cache)
         self._warm_cow(cache)

@@ -801,13 +801,14 @@ def _absorbed_attention(cfg: LatentMoeConfig, lp, q_nope, q_rope, pool,
 
 
 def _ffn(cfg: LatentMoeConfig, lp, x, live, interpret: bool, bank=None,
-         route_x=None):
+         route_x=None, live_chunk=None):
     """The layer's feed-forward on normed x: dense SwiGLU, or the
     shared expert + this share of the routed ones (bank: the layer's
     index in expert tensors that stack several layers'; route_x: x
-    before it was rounded to the model's dtype, for the router:
-    moe.sparse_moe).  Returns (out, slots each held expert received |
-    None)."""
+    before it was rounded to the model's dtype, for the router;
+    live_chunk: the chunking of a caller most of whose tokens are
+    dead: moe.sparse_moe).  Returns (out, slots each held expert
+    received | None)."""
     if "router" not in lp:
         return jnp.dot(jax.nn.silu(jnp.dot(x, lp["w_gate"]))
                        * jnp.dot(x, lp["w_up"]), lp["w_down"]), None
@@ -819,7 +820,8 @@ def _ffn(cfg: LatentMoeConfig, lp, x, live, interpret: bool, bank=None,
         top_k=cfg.top_k, first=cfg.experts_first, score=cfg.score_fn,
         norm_topk=cfg.norm_topk_prob, scale=cfg.routed_scaling_factor,
         shared=shared, live=live, interpret=interpret, bank=bank,
-        route_x=route_x, bias=lp.get("router_bias"))
+        route_x=route_x, bias=lp.get("router_bias"),
+        live_chunk=live_chunk)
 
 
 def _layer(cfg: LatentMoeConfig, lp, x, pos, attend, live,
@@ -1205,24 +1207,11 @@ class LatentCompletionModel:
         return self._program(("suffix", rows, sb, top_p, temp),
                              "suffix_prefill", build)
 
-    def paged_append_prefill_rows(self, cache: PagedKVCache, joins):
-        """paged_append_prefill for the hits of ONE admission round in
-        one dispatch: joins is [(row, suffix_ids), ...], every suffix
-        at most the widest suffix bucket, every row seated with its
-        prefix mapped (cache.lengths[row] tokens).  The program is the
-        smallest rung of join_rungs that holds them at the widest
-        suffix width (a rung has one program), its other rows pads
-        (length 0, no token valid: they attend nothing, reach no
-        expert and write the trash block), and draws each row's first
-        token in graph with the decode chunk's sampler.  Returns
-        (logits — a device array whose row i is joins[i]'s last real
-        token's (V,) float32 —, first tokens (len(joins),) on the
-        host).  One join runs the one-row program and the host's
-        draw."""
-        if len(joins) == 1:
-            row, suffix = joins[0]
-            logits = self.paged_append_prefill(cache, suffix, row)
-            return logits[None], np.array([self.sample(logits)], np.int32)
+    def _round_inputs(self, cache: PagedKVCache, joins):
+        """The host half of a round's dispatch: every join's pages
+        reserved, and (ids (rows, sb), n_valid (rows,), tables (rows,
+        P), lengths (rows,)) of the smallest rung that holds the joins
+        at the widest suffix width, its other rows pads."""
         rows = next(r for r in self.join_rungs(cache)
                     if r >= len(joins))
         sb = self.suffix_buckets[-1]
@@ -1246,6 +1235,28 @@ class LatentCompletionModel:
             n_valid[i] = n
             tables[i] = cache.tables[row]
             lengths[i] = pos
+        return ids, n_valid, tables, lengths
+
+    def paged_append_prefill_rows(self, cache: PagedKVCache, joins):
+        """paged_append_prefill for the hits of ONE admission round in
+        one dispatch: joins is [(row, suffix_ids), ...], every suffix
+        at most the widest suffix bucket, every row seated with its
+        prefix mapped (cache.lengths[row] tokens).  The program is the
+        smallest rung of join_rungs that holds them at the widest
+        suffix width (a rung has one program), its other rows pads
+        (length 0, no token valid: they attend nothing, reach no
+        expert and write the trash block), and draws each row's first
+        token in graph with the decode chunk's sampler.  Returns
+        (logits — a device array whose row i is joins[i]'s last real
+        token's (V,) float32 —, first tokens (len(joins),) on the
+        host).  One join runs the one-row program and the host's
+        draw."""
+        if len(joins) == 1:
+            row, suffix = joins[0]
+            logits = self.paged_append_prefill(cache, suffix, row)
+            return logits[None], np.array([self.sample(logits)], np.int32)
+        ids, n_valid, tables, lengths = self._round_inputs(cache, joins)
+        rows, sb = ids.shape
         self._rng, sub = jax.random.split(self._rng)
         pools, logits, toks = self._suffix_rows_program(rows, sb)(
             self.params, cache.pools[0], jnp.asarray(tables),
@@ -1398,16 +1409,20 @@ class LatentCompletionModel:
                     cache, np.ones((sb,), np.int32), 0)
                 cache.free_row(0)
             else:
-                # the row-batched rungs, at the widest width
-                for below in self.join_rungs(cache)[:-1]:
-                    # a rung's program is its shape: one row more than
-                    # the rung below holds compiles and runs it
-                    self.paged_append_prefill_rows(
-                        cache, [(r, np.ones((sb,), np.int32))
-                                for r in range(below + 1)])
-                    for r in range(below + 1):
-                        cache.free_row(r)
+                self._warm_join_rungs(cache)
             self._warm_cow(cache)
+
+    def _warm_join_rungs(self, cache: PagedKVCache) -> None:
+        """The row-batched rungs, at the widest width.  A rung's
+        program is its shape: one row more than the rung below holds
+        compiles and runs it."""
+        sb = self.suffix_buckets[-1]
+        for below in self.join_rungs(cache)[:-1]:
+            self.paged_append_prefill_rows(
+                cache, [(r, np.ones((sb,), np.int32))
+                        for r in range(below + 1)])
+            for r in range(below + 1):
+                cache.free_row(r)
 
     def _warm_cow(self, cache: PagedKVCache) -> None:
         src, dst = cache._alloc_page(), cache._alloc_page()

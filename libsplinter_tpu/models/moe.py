@@ -196,7 +196,7 @@ def sparse_moe(x, router, wg, wu, wd, *, top_k: int, first: int = 0,
                score: str = "softmax", norm_topk: bool = True,
                scale: float = 1.0, shared=None, live=None,
                interpret: bool = False, bank=None, route_x=None,
-               bias=None):
+               bias=None, live_chunk: int | None = None):
     """The expert layer over the experts held here.
 
     x: (..., H) activations; router: (H, E) over ALL E experts of the
@@ -213,7 +213,13 @@ def sparse_moe(x, router, wg, wu, wd, *, top_k: int, first: int = 0,
     (float32, x's shape): which experts a token takes is the layer's
     one discontinuous decision, and a rounding of its input flips it
     where two experts score alike; bias: None, or the router's (E,)
-    selection bias (router_gates).
+    selection bias (router_gates); live_chunk: None, or the tokens a
+    chunk for a caller MOST of whose tokens are dead (the pad tokens
+    of an admission round's rows, models/lfm2.py): the live tokens
+    are brought to the front first and a chunk that holds none is
+    skipped, so the experts' weights are read once for every
+    live_chunk LIVE tokens and not once for every MOE_CHUNK_TOKENS
+    token slots.
     Returns (out (..., H) in x's dtype — the shared expert plus the
     gated sum over the HELD experts among each token's top-k, (count,)
     int32 — the slots each held expert received)."""
@@ -225,21 +231,35 @@ def sparse_moe(x, router, wg, wu, wd, *, top_k: int, first: int = 0,
         x2 if route_x is None else route_x.reshape(-1, H), router,
         top_k=top_k, score=score, norm_topk=norm_topk, scale=scale,
         bias=bias)
-    if T <= MOE_CHUNK_TOKENS:
+    chunk = live_chunk or MOE_CHUNK_TOKENS
+    if T <= chunk:
         out, sizes = _dispatch(x2, ids, gates, live2, wg, wu, wd,
                                first, interpret, bank)
     else:
-        n = -(-T // MOE_CHUNK_TOKENS)
-        pad = n * MOE_CHUNK_TOKENS - T
+        n = -(-T // chunk)
+        pad = n * chunk - T
 
         def chunks(a):
             a = jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))
-            return a.reshape(n, MOE_CHUNK_TOKENS, *a.shape[1:])
+            return a.reshape(n, chunk, *a.shape[1:])
 
-        out, sizes = jax.lax.map(
-            lambda c: _dispatch(*c, wg, wu, wd, first, interpret, bank),
-            (chunks(x2), chunks(ids), chunks(gates), chunks(live2)))
+        def one(c):
+            return _dispatch(*c, wg, wu, wd, first, interpret, bank)
+
+        def one_if_live(c):
+            return jax.lax.cond(
+                c[3].any(), one,
+                lambda c: (jnp.zeros((chunk, H), jnp.float32),
+                           jnp.zeros((wg.shape[-3],), jnp.int32)), c)
+        args = (x2, ids, gates, live2)
+        if live_chunk:
+            order = jnp.argsort(~live2, stable=True)
+            args = tuple(a[order] for a in args)
+        out, sizes = jax.lax.map(one_if_live if live_chunk else one,
+                                 tuple(chunks(a) for a in args))
         out = out.reshape(-1, H)[:T]
+        if live_chunk:
+            out = out[jnp.argsort(order)]
         sizes = sizes.sum(0)
     if shared is not None:
         sg, su, sd = shared

@@ -310,21 +310,34 @@ def _pangu_model(one_chip):
     return mla.LatentCompletionModel(cfg, params=params), params
 
 
+def _latent_case(one_chip, program):
+    """(program, its arguments as shapes on the described chip) of the
+    benchmark's latent configuration: "rows-64" (the full rung of the
+    row-batched suffix prefill, 64 rows x 64 tokens) or "chunk"."""
+    m, params = _pangu_model(one_chip)
+    pools = [_spec(one_chip, LAT_POOL, jnp.bfloat16)] * 5
+
+    def i32(*shape):
+        return _spec(one_chip, shape, jnp.int32)
+    key = _spec(one_chip, (2,), jnp.uint32)
+    if program == "chunk":
+        fn = m._chunk_program(8, 64)
+        args = (i32(64, 66), i32(64), key, i32(64),
+                _spec(one_chip, (64,), jnp.bool_), i32(64), i32())
+    else:
+        fn = m._suffix_rows_program(64, 64)
+        args = (i32(64, 66), i32(64), i32(64, 64), i32(64), key)
+    return getattr(fn, "__wrapped__", fn), (params, pools, *args), None
+
+
 def test_latent_suffix_rows_program(one_chip, monkeypatch):
     """The full rung of the row-batched suffix prefill (64 rows x 64
     tokens: an admission round of the benchmark's cell in ONE program):
     it compiles, its 4,096 tokens' temporaries fit the chip beside the
     weights and the pages, and no pool is copied."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    m, params = _pangu_model(one_chip)
-    fn = m._suffix_rows_program(64, 64)
-    compiled = getattr(fn, "__wrapped__", fn).lower(
-        params, [_spec(one_chip, LAT_POOL, jnp.bfloat16)] * 5,
-        _spec(one_chip, (64, 66), jnp.int32),
-        _spec(one_chip, (64,), jnp.int32),
-        _spec(one_chip, (64, 64), jnp.int32),
-        _spec(one_chip, (64,), jnp.int32),
-        _spec(one_chip, (2,), jnp.uint32)).compile()
+    fn, args, _ = _latent_case(one_chip, "rows-64")
+    compiled = fn.lower(*args).compile()
     mem = compiled.memory_analysis()
     print("suffix rows 64x64: arguments",
           mem.argument_size_in_bytes, "temporaries",
@@ -341,17 +354,8 @@ def test_latent_decode_chunk_program(one_chip, monkeypatch):
     compiles, fits the chip beside its arguments, and keeps the pools
     in place."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    m, params = _pangu_model(one_chip)
-    fn = m._chunk_program(8, 64)
-    compiled = getattr(fn, "__wrapped__", fn).lower(
-        params, [_spec(one_chip, LAT_POOL, jnp.bfloat16)] * 5,
-        _spec(one_chip, (64, 66), jnp.int32),
-        _spec(one_chip, (64,), jnp.int32),
-        _spec(one_chip, (2,), jnp.uint32),
-        _spec(one_chip, (64,), jnp.int32),
-        _spec(one_chip, (64,), jnp.bool_),
-        _spec(one_chip, (64,), jnp.int32),
-        _spec(one_chip, (), jnp.int32)).compile()
+    fn, args, _ = _latent_case(one_chip, "chunk")
+    compiled = fn.lower(*args).compile()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes > 11.5e9          # weights + pool
     assert mem.temp_size_in_bytes < 1.5e9               # no pool copies
@@ -735,17 +739,12 @@ def test_narrow_head_attention_kernel(one_chip, q_tokens, rows):
     assert compiled.memory_analysis().temp_size_in_bytes < 1e8
 
 
-@pytest.mark.parametrize("program", ["chunk", "suffix-128", "suffix-512"])
-def test_conv_programs_at_published_widths(one_chip, monkeypatch, program):
-    """The 8-step decode chunk of 96 rows, the one-page suffix prefill
-    (a short tool result behind its snapshot) and the widest (a cold
-    system prompt's pieces) of the benchmark's LFM2 configuration (9
-    layers unrolled; 10.62 GB of weights, 9.66 GB of them the 8 expert
-    layers' 64 experts): each compiles, fits the chip beside its
-    arguments, and keeps the page group's pools and the state slots in
-    place."""
+def _conv_case(one_chip, program):
+    """(program, its arguments as shapes on the described chip, bytes
+    of weights) of the benchmark's LFM2 configuration: "chunk",
+    "suffix-<width>" (one row) or "rows-<rung>" (an admission round's
+    rows at the widest suffix width)."""
     from libsplinter_tpu.models import lfm2
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg = lfm2.ConvMoeConfig(
         vocab_size=65536, hidden=2048,
         kinds=("conv",) + ("full", "conv", "conv", "conv") * 2,
@@ -757,38 +756,86 @@ def test_conv_programs_at_published_widths(one_chip, monkeypatch, program):
         jax.eval_shape(lambda: lfm2.init_params(cfg, 0)))
     weights = sum(int(np.prod(a.shape)) * a.dtype.itemsize
                   for a in jax.tree_util.tree_leaves(params))
-    assert 10.62e9 < weights < 10.64e9    # 5,312M parameters
     m = lfm2.ConvCompletionModel(cfg, params=params)
     pools = (_spec(one_chip, CONV_POOL, jnp.bfloat16),) * 2
     states = [[_spec(one_chip, (CONV_SLOTS, 2, 2048), jnp.bfloat16)]
               for _ in range(7)]
     i32 = _spec(one_chip, (), jnp.int32)
-    if program == "chunk":
+
+    def vec(n, dtype=jnp.int32):
+        return _spec(one_chip, (n,), dtype)
+    kind, _, n = program.partition("-")
+    if kind == "chunk":
         fn = m._chunk_program(8, 96)
-        args = (_spec(one_chip, (96, CONV_P), jnp.int32),
-                _spec(one_chip, (96,), jnp.int32),
-                _spec(one_chip, (2,), jnp.uint32),
-                _spec(one_chip, (96,), jnp.int32),
-                _spec(one_chip, (96,), jnp.bool_),
-                _spec(one_chip, (96,), jnp.int32),
-                _spec(one_chip, (2,), jnp.int32))
+        args = (_spec(one_chip, (96, CONV_P), jnp.int32), vec(96),
+                vec(2, jnp.uint32), vec(96), vec(96, jnp.bool_), vec(96),
+                vec(2))
+    elif kind == "suffix":
+        fn = m._suffix_program(int(n))
+        args = (_spec(one_chip, (1, CONV_P), jnp.int32), vec(1),
+                _spec(one_chip, (1, int(n)), jnp.int32), i32, i32, i32, i32)
     else:
-        width = int(program.split("-")[1])
-        fn = m._suffix_program(width)
-        args = (_spec(one_chip, (1, CONV_P), jnp.int32),
-                _spec(one_chip, (1,), jnp.int32),
-                _spec(one_chip, (1, width), jnp.int32), i32, i32, i32, i32)
-    compiled = getattr(fn, "__wrapped__", fn).lower(
-        params, pools, states, *args).compile()
+        r = int(n)
+        fn = m._suffix_rows_program(r, 512)
+        args = (_spec(one_chip, (r, CONV_P), jnp.int32), vec(r),
+                _spec(one_chip, (r, 512), jnp.int32), vec(r), vec(r),
+                vec(r), vec(r), vec(2, jnp.uint32))
+    return getattr(fn, "__wrapped__", fn), (params, pools, states,
+                                            *args), weights
+
+
+def _no_pool_copied(compiled, pool_shape) -> bool:
+    shape = ",".join(str(d) for d in pool_shape)
+    return not [ln for ln in compiled.as_text().split("ENTRY")[1]
+                .splitlines() if shape in ln and " copy(" in ln]
+
+
+@pytest.mark.parametrize("program", ["chunk", "suffix-128", "suffix-512"])
+def test_conv_programs_at_published_widths(one_chip, monkeypatch, program):
+    """The 8-step decode chunk of 96 rows, the one-page suffix prefill
+    (a short tool result behind its snapshot) and the widest (a cold
+    system prompt's pieces) of the benchmark's LFM2 configuration (9
+    layers unrolled; 10.62 GB of weights, 9.66 GB of them the 8 expert
+    layers' 64 experts): each compiles, fits the chip beside its
+    arguments, and keeps the page group's pools and the state slots in
+    place."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    fn, args, weights = _conv_case(one_chip, program)
+    assert 10.62e9 < weights < 10.64e9    # 5,312M parameters
+    compiled = fn.lower(*args).compile()
     mem = compiled.memory_analysis()
     print(program, "arguments", mem.argument_size_in_bytes, "temporaries",
           mem.temp_size_in_bytes)
     assert mem.argument_size_in_bytes > 12.7e9   # weights, pages, slots
     assert mem.temp_size_in_bytes < 1.0e9        # no pool copies
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
-    shape = ",".join(str(d) for d in CONV_POOL)
-    assert not [ln for ln in compiled.as_text().split("ENTRY")[1]
-                .splitlines() if shape in ln and " copy(" in ln]
+    assert _no_pool_copied(compiled, CONV_POOL)
+
+
+# the rung was chosen by these figures (models/lfm2.py JOIN_ROWS):
+# temporaries of 1.06 GB at 32 rows x 512 tokens with the experts taking
+# the round's live tokens 8,192 at a time (0.85 GB in chunks of 2,048
+# token slots, which read the experts' weights eight times a program) —
+# the program stands at 13.85 GB of arguments + temporaries, 82% of the
+# chip's 16.9 GB; ~30 MB a row, so 96 rows would pass 90%
+def test_conv_suffix_rows_program(one_chip, monkeypatch):
+    """The row-batched suffix prefill (an admission round's hits x 512
+    tokens in ONE program, snapshots and first tokens included): it
+    compiles, its temporaries stay under the figure the rung was chosen
+    by, arguments + temporaries under 90% of the chip, and neither the
+    pools nor the state slots are copied."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    fn, args, _ = _conv_case(one_chip, "rows-32")
+    compiled = fn.lower(*args).compile()
+    mem = compiled.memory_analysis()
+    print("suffix rows 32 x 512: arguments", mem.argument_size_in_bytes,
+          "temporaries", mem.temp_size_in_bytes)
+    assert mem.argument_size_in_bytes > 12.7e9   # weights, pages, slots
+    assert mem.temp_size_in_bytes < 1.2e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < 0.9 * 16.9e9
+    assert _no_pool_copied(compiled, CONV_POOL)
+    assert _no_pool_copied(compiled, (CONV_SLOTS, 2, 2048))
 
 
 # the lowered text (kernel payloads, which carry source lines, left
@@ -801,6 +848,15 @@ SIBLING_PROGRAMS = {
     ("hybrid", "suffix-640"): "defcd610c0408b44",
     ("window", "chunk"): "ffe29bfc3cbbf3a7",
     ("window", "suffix-640"): "5b6661e293abaabe",
+    # the latent family's round (its expert layer runs in chunks of
+    # 2,048 token slots: moe.sparse_moe without live_chunk) and chunk
+    ("latent", "rows-64"): "30435683c3a2a679",
+    ("latent", "chunk"): "478b7f8b2a180501",
+    # this family's own chunk and one-row suffix programs, as PR 40
+    # left them: the row axis (PR 41) is a program beside them
+    ("conv", "chunk"): "c3ecbe24226ac7b8",
+    ("conv", "suffix-128"): "0ea61ece1929056c",
+    ("conv", "suffix-512"): "5abeb208f2b7f3bd",
 }
 
 
@@ -810,7 +866,8 @@ def test_sibling_programs_are_the_parents(one_chip, monkeypatch, family,
     import hashlib
     import re
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    case = {"hybrid": _hybrid_case, "window": _window_case}[family]
+    case = {"hybrid": _hybrid_case, "window": _window_case,
+            "conv": _conv_case, "latent": _latent_case}[family]
     fn, args, _ = case(one_chip, program)
     text = re.sub(r'backend_config = "(?:[^"\\]|\\.)*"',
                   'backend_config = ""', fn.lower(*args).as_text())

@@ -289,6 +289,27 @@ def test_two_half_shares_add_up_to_the_whole_layer():
 
 # ------------------------------------------------------ 64-wide heads
 
+@pytest.mark.parametrize("chunk", [16, 100, 512])
+def test_live_tokens_first_in_chunks_is_the_same_sum(chunk):
+    """sparse_moe(live_chunk=): 300 token slots, a third of them live,
+    brought to the front and sent through in chunks of `chunk` (19, 3
+    and 1 of them; the chunks behind the live tokens skipped) — each
+    token's sum and each expert's count are what one dispatch over all
+    300 gives, to the bit, and a dead token's row is zero."""
+    x, router, wg, wu, wd, k = _layer(T=300)
+    rng = np.random.default_rng(2)
+    live = jnp.asarray(rng.random(300) < 0.33)
+    bias = jnp.asarray(rng.standard_normal(64) * 0.1, jnp.float32)
+    kw = dict(top_k=k, score="sigmoid", bias=bias, live=live)
+    want, slots = sparse_moe(x, router, wg, wu, wd, **kw)
+    got, got_slots = sparse_moe(x, router, wg, wu, wd, live_chunk=chunk,
+                                **kw)
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(got_slots, slots)
+    assert int(slots.sum()) == 4 * int(live.sum())
+    assert not np.asarray(got)[~np.asarray(live)].any()
+
+
 @pytest.mark.parametrize("q_tokens", [1, 128], ids=["decode", "stack"])
 def test_heads_of_64_through_the_window_kernel_in_interpret_mode(q_tokens):
     """32 query heads over 8 key/value heads of 64, K and V both a
@@ -526,3 +547,176 @@ def test_the_two_copies_of_the_reference_agree(tmp_path):
     low = bench.forward_logits(ARCH, SHARE, seed, seqs[:1], pos[:1],
                                f8=True, **kw)
     assert bench.rel_err(low[0], got[0]).min() > 0.02
+
+
+# ------------------------------------- an admission round in one program
+
+# (prefix tokens, suffix tokens, snapshot at | None) a join: one token,
+# one page + 1, the four pages of the program's width with the snapshot
+# at the last token, a page behind three; then rows without snapshots
+_JOINS = [(32, 1, None), (16, 17, 32), (48, 64, 112), (32, 20, None),
+          (16, 16, 32), (0, 33, 32), (64, 5, None), (16, 48, 48),
+          (32, 64, None)]
+ROUNDS = {
+    # name: (batch, the joins of the round): the rung and its pad rows
+    "three-of-six": (6, _JOINS[:3]),           # rung 6, three pads
+    "a-full-rung": (6, _JOINS[:6]),            # rung 6, no pad
+    "two-of-twelve": (12, _JOINS[1:3]),        # rung 12, ten pads
+    "nine-of-twelve": (12, _JOINS),            # rung 12, three pads
+    "no-snapshot": (6, [_JOINS[0], _JOINS[3], _JOINS[6]]),
+}
+
+
+def _seated(model, batch, joins, widest_only, **kw):
+    """A model over `model`'s weights and its cache, every join's
+    prefix prefilled into its row (a row a join), every state slot
+    that is no row's filled with a pattern of its own, so that a write
+    to it shows."""
+    m = lfm2.ConvCompletionModel(CFG, params=model.params, temp=0.0, **kw)
+    cache = m.init_paged(batch, page=PAGE, pool_pages=96,
+                         state_snapshots=len(joins))
+    if widest_only:
+        m.suffix_buckets = m.suffix_buckets[-1:]
+    rng = np.random.default_rng(7)
+    toks = [(rng.integers(3, CFG.vocab_size, p).astype(np.int32),
+             rng.integers(3, CFG.vocab_size, n).astype(np.int32))
+            for p, n, _ in joins]
+    for row, (prefix, _) in enumerate(toks):
+        if len(prefix):
+            m.paged_prefill_row(cache, prefix, row)
+        else:
+            m.state_zero(cache, row)
+    mark = jnp.arange(cache.state_slots, dtype=jnp.float32)[:, None, None]
+    cache.states = [[jnp.where(mark >= batch, mark + layer, reg)]
+                    for layer, (reg,) in enumerate(cache.states)]
+    snaps = [None if at is None else (cache.alloc_state_slot(), at)
+             for _, _, at in joins]
+    return m, cache, [(row, s) for row, (_, s) in enumerate(toks)], snaps
+
+
+def _one_by_one(m, cache, rows, snaps):
+    return np.stack([
+        m.paged_append_prefill(cache, suffix, row, **(
+            {"snap_slot": snap[0], "snap_at": snap[1]} if snap else {}))
+        for (row, suffix), snap in zip(rows, snaps)])
+
+
+@pytest.mark.parametrize("name", sorted(ROUNDS))
+def test_a_round_in_one_program_is_its_joins_one_by_one(model, name):
+    """paged_append_prefill_rows against the same joins through
+    paged_append_prefill.  Against the one-row program AT THE ROUND'S
+    WIDTH everything is equal to the bit — logits, every page but the
+    trash block, every state slot but the spare one: each row's
+    register, each snapshot, and the untouched pattern of every slot
+    the round was not given; against the one-row programs at their own
+    widths (another summation order) the logits hold the file's
+    tolerance.  The heartbeat's counts do not know how the round was
+    joined."""
+    batch, joins = ROUNDS[name]
+    want_m, want_c, rows, snaps = _seated(model, batch, joins, True)
+    want = _one_by_one(want_m, want_c, rows, snaps)
+    m, cache, rows, snaps = _seated(model, batch, joins, True)
+    m.suffix_buckets = want_m.suffix_buckets
+    before = [np.asarray(reg) for (reg,) in cache.states]
+    assert len(joins) > 1 and m.join_rungs(cache) == (1, batch)
+    logits, firsts = m.paged_append_prefill_rows(
+        cache, rows, snaps if any(snaps) else None)
+    np.testing.assert_array_equal(np.asarray(logits)[:len(joins)], want)
+    np.testing.assert_array_equal(firsts, want.argmax(-1))
+    np.testing.assert_array_equal(cache.lengths, want_c.lengths)
+    np.testing.assert_array_equal(cache.tables, want_c.tables)
+    for got, ref in zip(cache.pools, want_c.pools):
+        np.testing.assert_array_equal(got[0][1:], ref[0][1:])
+    spare = cache.state_spare
+    given = {r for r, _ in rows} | {s[0] for s in snaps if s}
+    assert spare not in given and len(given) == len(rows) + sum(
+        s is not None for s in snaps)
+    for (got,), (ref,), was in zip(cache.states, want_c.states, before):
+        np.testing.assert_array_equal(got[:spare], ref[:spare])
+        idle = [s for s in range(spare) if s not in given]
+        np.testing.assert_array_equal(np.asarray(got)[idle], was[idle])
+    for k in ("prefill_keys", "prefill_kv"):
+        assert m.attn_work[k] == want_m.attn_work[k]
+    # the program counts the experts its tokens reached ONCE a layer
+    assert 0 < m.attn_work["prefill_experts_live"] \
+        <= want_m.attn_work["prefill_experts_live"]
+    narrow_m, narrow_c, rows, snaps = _seated(model, batch, joins, False)
+    np.testing.assert_allclose(
+        np.asarray(logits)[:len(joins)],
+        _one_by_one(narrow_m, narrow_c, rows, snaps), atol=2e-4)
+
+
+def test_a_round_of_one_is_the_one_row_program_and_bad_rows_are_refused(
+        model):
+    m, cache, rows, snaps = _seated(model, 6, _JOINS[1:2], False)
+    want_m, want_c, *_ = _seated(model, 6, _JOINS[1:2], False)
+    logits, firsts = m.paged_append_prefill_rows(cache, rows, snaps)
+    np.testing.assert_array_equal(
+        logits, _one_by_one(want_m, want_c, rows, snaps))
+    assert firsts.tolist() == [int(np.argmax(logits[0]))]
+    for (got,), (ref,) in zip(cache.states, want_c.states):
+        np.testing.assert_array_equal(got[snaps[0][0]], ref[snaps[0][0]])
+    m, cache, rows, snaps = _seated(model, 6, _JOINS[:2], False)
+    with pytest.raises(ValueError, match="65 tokens"):
+        m.paged_append_prefill_rows(
+            cache, [rows[0], (1, np.ones((65,), np.int32))])
+    with pytest.raises(ValueError, match="snapshot at 48"):
+        m.paged_append_prefill_rows(cache, rows, [None, (snaps[1][0], 48)])
+    cache.lengths[0] = 33
+    with pytest.raises(ValueError, match="page boundary"):
+        m.paged_append_prefill_rows(cache, rows)
+
+
+def test_a_rounds_first_tokens_are_a_function_of_the_seed(model):
+    """Drawn in graph by the decode chunk's sampler from the model's
+    own key: the same seed draws the same first tokens, another seed
+    others, and a cold sampler (temp 0) the logits' argmax."""
+    def draw(seed):
+        m, cache, rows, snaps = _seated(model, 6, _JOINS[:6], False,
+                                        seed=seed)
+        m.temp = 0.9
+        logits, firsts = m.paged_append_prefill_rows(cache, rows, snaps)
+        assert firsts.shape == (6,) and firsts.dtype == np.int32
+        return np.asarray(logits), firsts
+    (la, a), (lb, b), (lc, c) = draw(11), draw(11), draw(12)
+    np.testing.assert_array_equal(la, lc)
+    np.testing.assert_array_equal(a, b)
+    assert (a != c).any() and (a != la.argmax(-1)).any()
+
+
+def test_a_round_through_the_kernels_in_interpret_mode(model):
+    """The round's rows through the Pallas kernels as the chip runs
+    them — pad rows of length 0 over a table of trash blocks, rows
+    narrower than the program — against the same round's jnp path."""
+    batch, joins = ROUNDS["three-of-six"]
+    want_m, want_c, rows, snaps = _seated(model, batch, joins, False)
+    want, _ = want_m.paged_append_prefill_rows(want_c, rows, snaps)
+    m, cache, rows, snaps = _seated(model, batch, joins, False,
+                                    interpret=True)
+    logits, _ = m.paged_append_prefill_rows(cache, rows, snaps)
+    assert np.isfinite(np.asarray(logits)).all()
+    np.testing.assert_allclose(np.asarray(logits)[:3],
+                               np.asarray(want)[:3], atol=6e-2)
+    for (got,), (ref,) in zip(cache.states, want_c.states):
+        np.testing.assert_allclose(got[:cache.state_spare],
+                                   ref[:cache.state_spare], atol=6e-2)
+
+
+def test_a_rounds_experts_take_the_live_tokens_in_chunks(model, monkeypatch):
+    """The round's expert layers with its 384 token slots in chunks of
+    100 LIVE tokens (the benchmark's 16,384 slots go in chunks of
+    8,192): the same logits, pages and registers to the bit."""
+    batch, joins = ROUNDS["a-full-rung"]
+    want_m, want_c, rows, snaps = _seated(model, batch, joins, False)
+    want, _ = want_m.paged_append_prefill_rows(want_c, rows, snaps)
+    monkeypatch.setattr(lfm2, "JOIN_MOE_CHUNK", 100)
+    m, cache, rows, snaps = _seated(model, batch, joins, False)
+    logits, _ = m.paged_append_prefill_rows(cache, rows, snaps)
+    np.testing.assert_array_equal(logits, want)
+    for (got,), (ref,) in zip(cache.states, want_c.states):
+        np.testing.assert_array_equal(got[:cache.state_spare],
+                                      ref[:cache.state_spare])
+    for got, ref in zip(cache.pools, want_c.pools):
+        np.testing.assert_array_equal(got[0][1:], ref[0][1:])
+    assert m.attn_work["prefill_experts_live"] \
+        == want_m.attn_work["prefill_experts_live"]

@@ -417,18 +417,20 @@ class _RoundLane:
              ("paged_append_prefill", "suffix",
               lambda c, ids, row, **k: row),
              ("paged_append_prefill_rows", "rows",
-              lambda c, joins: tuple(r for r, _ in joins)),
+              lambda c, joins, *snaps: tuple(r for r, _ in joins)),
+             # a model with state slots: (snapshot slot, row)
+             ("state_restore", "restore", lambda c, src, row: (src, row)),
              ("sample", "sample", lambda logits: None),
              ("_cow_fixups", "cow", lambda c: None),
              ("paged_decode_chunk_async", "chunk", lambda *a, **k: None))
 
-    def __init__(self, tmp_path, model, tag, pool_pages=40):
+    def __init__(self, tmp_path, model, tag, pool_pages=40, **kw):
         self.model, self.log, self.at_rows = model, [], None
         self.name, self.st = _mkstore(tmp_path, tag)
         self.comp = Completer(self.st, model=model, max_new_tokens=4,
                               flush_tokens=4, template="none",
                               batch_cap=6, page_size=16,
-                              pool_pages=pool_pages)
+                              pool_pages=pool_pages, **kw)
         self.comp.attach()
         self.comp._ensure_paged_cache()
         self.gate, self.parked = threading.Event(), threading.Event()
@@ -445,10 +447,20 @@ class _RoundLane:
         setattr(obj, name, wrapped)
 
     def __enter__(self):
-        for name, tag, what in self.SPIED:
+        self.spied = [s for s in self.SPIED if hasattr(self.model, s[0])]
+        for name, tag, what in self.spied:
             self._spy(self.model, name, tag, what)
         self._spy(self.comp.prefix_cache, "insert", "insert",
                   lambda ids, c, row, *a, **k: row)
+        cache = self.comp._paged_cache
+        if getattr(cache, "needs_state", False):
+            alloc = cache.alloc_state_slot
+
+            def logged():
+                slot = alloc()
+                self.log.append(("slot", slot))
+                return slot
+            cache.alloc_state_slot = logged
         refresh = self.comp.stripes.refresh
 
         def gated():
@@ -465,7 +477,7 @@ class _RoundLane:
         self.gate.set()
         self.comp.stop()
         self.th.join(timeout=30)
-        for name, _, _ in self.SPIED:
+        for name, _, _ in self.spied:
             delattr(self.model, name)      # the class's own again
         self.st.close()
         Store.unlink(self.name)
@@ -661,3 +673,110 @@ def test_backpressure_inside_a_round_leaves_the_denied_request_waiting(
         assert all(out[k].startswith(prompts[k].encode())
                    for k in prompts)
         assert s.completions == 4 and s.faults == 0
+
+
+# ---- the same rounds over a model WITH STATE SLOTS whose rows program
+# leaves the snapshots (models/lfm2.py join_snapshots)
+
+@pytest.fixture(scope="module")
+def conv_models():
+    """The tiny convolution / attention model twice over the same
+    weights: as it is (`rows`) and answering `(1,)` (`one-row`), which
+    is how every family with state was joined before it had a row
+    axis and how kimi's still is."""
+    from libsplinter_tpu.models import lfm2
+
+    class OneRow(lfm2.ConvCompletionModel):
+        join_snapshots = False
+
+        def join_rungs(self, cache):
+            return (1,)
+
+    cfg = lfm2.ConvMoeConfig.tiny(dtype=jnp.float32)
+    rows = lfm2.ConvCompletionModel(cfg, seed=3, temp=0.0)
+    return {"rows": rows,
+            "one-row": OneRow(cfg, params=rows.params, temp=0.0)}
+
+
+def _snapshot_slot(ln, prompt: str) -> tuple[int, int]:
+    """(the slot of the snapshot at `prompt`'s last page boundary,
+    that boundary)."""
+    ids = ln.comp._tok.encode(prompt)
+    at = len(ids) // 16 * 16
+    return ln.comp.prefix_cache.state_slot(ids, at), at
+
+
+@pytest.mark.parametrize("kind", ["rows", "one-row"])
+def test_joins_that_leave_snapshots_ride_one_round(
+        tmp_path, conv_models, round_answers, kind):
+    """Three hits on the document's snapshot in one admission round,
+    two of which leave a snapshot of their own (at 48 and at 64 tokens)
+    and one that does not: the rows model restores each at its seat,
+    then prefills all three in ONE dispatch (a program counted once,
+    its rows each), and each snapshot's node holds the slot its join
+    was given — no two the same; a model with state that answers (1,)
+    is served as it always was, restore, prefill, insert, draw, request
+    by request."""
+    with _RoundLane(tmp_path, conv_models[kind], f"snap-{kind}",
+                    state_snapshots=6) as ln:
+        ln.burst({"d": _DOC})
+        s = ln.comp.stats
+        p0, r0 = s.join_programs, s.join_rows
+        src, at = _snapshot_slot(ln, _DOC)
+        assert (s.state_snapshots, at) == (1, 32) and src >= 6
+        prompts = {"q/0": _DOC + "who goes there?",              # 56
+                   "q/1": _DOC + "hm?",                          # 44
+                   "q/2": _DOC + "what now, fox, and why not sooner?"}
+        out, log = ln.burst(prompts)
+        tags = [t for t, _ in log]
+        slots = [what for t, what in log if t == "slot"]
+        assert [what[0] for t, what in log if t == "restore"] == [src] * 3
+        assert len(set(slots)) == 2 and src not in slots
+        if kind == "rows":
+            assert tags[-4:] == ["rows"] + ["insert"] * 3
+            assert "suffix" not in tags and "sample" not in tags
+            assert sorted(log[-4][1]) == sorted(r for _, r in log[-3:])
+            assert (s.join_programs - p0, s.join_rows - r0) == (1, 3)
+        else:
+            per = [t for t in tags if t != "slot"]
+            assert per == ["restore", "suffix", "insert", "sample"] * 3
+            assert (s.join_programs - p0, s.join_rows - r0) == (3, 3)
+        assert (s.state_restores, s.state_snapshots) == (3, 3)
+        held = [_snapshot_slot(ln, prompts[k]) for k in ("q/0", "q/2")]
+        assert [at for _, at in held] == [48, 64]
+        assert sorted(slot for slot, _ in held) == sorted(slots)
+        assert _snapshot_slot(ln, prompts["q/1"]) == (src, 32)
+        assert s.prefix_tokens == 3 * 32 and s.faults == 0
+        _same_answers(round_answers, "snap", kind, out)
+
+
+@pytest.mark.parametrize("kind", ["rows", "one-row"])
+def test_a_seat_may_evict_the_snapshot_an_earlier_row_of_the_round_resumed(
+        tmp_path, conv_models, round_answers, kind):
+    """A budget of two snapshots: the round's first row resumes from
+    the document's and takes the free slot, the second resumes from it
+    too and its snapshot's slot is THE DOCUMENT'S, evicted for it.  The
+    rows were restored at their seats, so the round's program — which
+    writes that slot — answers as the one-row model does."""
+    with _RoundLane(tmp_path, conv_models[kind], f"evict-{kind}",
+                    state_snapshots=2) as ln:
+        ln.burst({"d": _DOC})
+        src, _ = _snapshot_slot(ln, _DOC)
+        prompts = {"q/0": _DOC + "who goes there?",
+                   "q/1": _DOC + "and who went before?"}
+        out, log = ln.burst(prompts)
+        s = ln.comp.stats
+        assert s.state_restores == 2 and s.faults == 0
+        if kind == "rows":
+            tags = [t for t, _ in log]
+            assert tags == ["restore", "slot", "restore", "slot", "rows",
+                            "insert", "insert"]
+            assert [log[0][1][0], log[2][1][0], log[3][1]] == [src] * 3
+            assert log[1][1] != src
+            assert ln.comp.prefix_cache.stats.state_evictions == 1
+            # both snapshots are the tree's now, the document's is not
+            assert sorted(_snapshot_slot(ln, p)[0]
+                          for p in prompts.values()) \
+                == sorted([log[1][1], src])
+            assert _snapshot_slot(ln, _DOC)[0] == -1
+        _same_answers(round_answers, "evict", kind, out)

@@ -1391,10 +1391,10 @@ class Completer:
             n = 0
             traced = tracer.enabled
             pc = getattr(cache, "prefix_cache", None)
-            # the round's hits whose suffix one program width holds
-            # are seated first and prefilled together, up to as many
-            # rows as the model's widest suffix program takes; a model
-            # whose programs are one row wide joins request by request,
+            # the round's hits whose suffix the model's rows program
+            # holds (`join_width`) are seated first and prefilled
+            # together, up to as many rows as its widest rung takes; a
+            # model whose programs are one row wide joins one by one,
             # and a join that leaves a state snapshot rides the round
             # only where the model's rows program leaves it
             rungs = getattr(m, "join_rungs", None)
@@ -1681,7 +1681,7 @@ class Completer:
                             "w_s0": w_s0}
                     if round_cap > 1 and hit_bids \
                             and (snap is None or snaps_ride) \
-                            and len(suffix) <= m.suffix_buckets[-1]:
+                            and len(suffix) <= m.join_width:
                         # a hit inside one program width: its prefill
                         # waits for the round's other hits
                         round_joins.append(join)
@@ -1689,8 +1689,8 @@ class Completer:
                             join_round(round_joins)
                             round_joins = []
                     else:
-                        # a miss, a suffix that loops the widest
-                        # width, a model joined a row at a time: a
+                        # a miss, a suffix wider than the rows
+                        # program, a model joined a row at a time: a
                         # round of one, served here and now
                         join_round([join])
                 else:                 # reads it over the device carry

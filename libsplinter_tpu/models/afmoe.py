@@ -66,9 +66,13 @@ tokens start at a page boundary (a prefix hit maps whole pages; a
 prompt the prefix cache does not know is the same program from an
 empty row, looped in its widest bucket, giving window pages back as it
 passes the window — no separate bucket prefill), so their keys and
-values are written a page at a time.  The decode chunk is mla's: n
-steps with the sampler in graph.  Past a head that may be irregular
-the layers repeat with the period of the layer pattern
+values are written a page at a time.  The same program with a ROW axis
+(`forward_suffix_rows`, ONE page wide) takes the hits of an admission
+round whose suffix is a page or less in one dispatch, each row over
+its own two tables, first tokens drawn in graph; a wider hit stays a
+round of one.  The decode chunk is mla's: n steps with the sampler in
+graph.  Past a head that may be irregular the layers repeat with the
+period of the layer pattern
 (`WindowMoeConfig.plan`), and the periods run as
 ONE compiled body under lax.scan (the stack's weights a period's
 stacked; a layer is told its index in its group's pool by the loop's
@@ -112,6 +116,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import threading
 from typing import Any
 
 import jax
@@ -141,6 +146,25 @@ RUN_SCAN = 4
 # pages of the widest suffix program: a follow-up turn of a few hundred
 # tokens fits one call, a cold prompt loops in it
 SUFFIX_PAGES = 5
+# rows of the row-batched suffix program (join_rungs): ONE rung beside
+# the one-row programs (a rung is a program, 25-30 s of a start from an
+# empty compile cache), ONE page wide.  16, not a lane's whole batch:
+# three programs of 16 cost what one of 48 does (0.765 against 0.783 s
+# on a v5e at MiMo's widths: PERF.md section 6), and a round is
+# dispatched the moment it is full, so the device starts on a
+# generation's first 16 hits while the others are still arriving.  The
+# rung of 48 read 12.0 answers/s on the one machine whose clients came
+# back slowly (a round of the whole batch waits for the slowest with
+# the device idle); whether 16 does better THERE is not measured.
+# Compiled for a described v5e (tests/test_chip_compile.py
+# test_window_suffix_rows_program): 16 rows x 128 tokens at MiMo's
+# widths 10.74 GB of arguments + 0.45 GB of temporaries, 66% of the
+# chip's 16.9 GB; at Trinity's 14.60 + 0.18 GB, 87.5%
+JOIN_ROWS = 16
+# tokens a chunk of the rows program's expert layers: its LIVE tokens
+# first (moe.sparse_moe live_chunk), so that a round's rows x page
+# token slots read the experts' weights once
+JOIN_MOE_CHUNK = 8192
 
 
 @dataclasses.dataclass(frozen=True)
@@ -423,16 +447,19 @@ BANKED = ("exp_gate", "exp_up", "exp_down")
 
 
 def _layer(cfg: WindowMoeConfig, lp, kind: str, gl, x, pos, live, pools,
-           write, tables, att_len, interpret: bool, bank=None):
+           write, tables, att_len, interpret: bool, bank=None,
+           live_chunk=None):
     """One block over its group's pool.  x: (B, S, H) float32; pos:
     (B, S); pools: {"full": (k, v), "window": (k, v)}; write[kind](pool,
     new (B, S, kv_heads, width), layer, cols) puts the new tokens'
     rows (columns, where the pool keeps a token a column) into their
     pages; gl: the layer's index in its group (traced under the
     scan); bank: under the scan, the period's index in the expert
-    tensors, which stay stacked (moe.sparse_moe).  Every `if` below
-    reads the configuration, not the data: a setting compiles its own
-    operations only.  Returns (x, pools, expert slots | None)."""
+    tensors, which stay stacked; live_chunk: the expert layer's
+    chunking where most tokens are dead (both: moe.sparse_moe).  Every
+    `if` below reads the configuration, not the data: a setting
+    compiles its own operations only.  Returns (x, pools, expert slots
+    | None)."""
     B, S, _ = x.shape
     ak, f32 = cfg.attn(kind), jnp.float32
     xn = _normed(cfg, x, lp["ln_attn_in"])
@@ -472,7 +499,7 @@ def _layer(cfg: WindowMoeConfig, lp, kind: str, gl, x, pos, live, pools,
     # the router reads the normed stream unrounded (moe.sparse_moe)
     hn = _rms(h, lp["ln_mlp_in"], cfg.rms_eps)
     f, slots = _ffn(cfg, lp, hn.astype(cfg.dtype), live, interpret, bank,
-                    route_x=hn)
+                    route_x=hn, live_chunk=live_chunk)
     f = f.astype(f32)
     if cfg.sandwich_norm:
         f = _rms(f, lp["ln_mlp_out"], cfg.rms_eps)
@@ -480,7 +507,7 @@ def _layer(cfg: WindowMoeConfig, lp, kind: str, gl, x, pos, live, pools,
 
 
 def _stack(cfg: WindowMoeConfig, params, x, pos, live, pools, write,
-           tables, att_len, interpret: bool):
+           tables, att_len, interpret: bool, live_chunk=None):
     """Every kept layer: the head unrolled, the periods under one
     scanned body, the tail unrolled.  Returns (x, pools, slots)."""
     head, period, n = cfg.plan
@@ -488,7 +515,7 @@ def _stack(cfg: WindowMoeConfig, params, x, pos, live, pools, write,
 
     def one(lp, i, gl, x, pools, bank=None):
         return _layer(cfg, lp, cfg.kinds[i], gl, x, pos, live, pools,
-                      write, tables, att_len, interpret, bank)
+                      write, tables, att_len, interpret, bank, live_chunk)
 
     for i, lp in enumerate(params["head"]):
         x, pools, s = one(lp, i, cfg.group_index(i), x, pools)
@@ -589,6 +616,44 @@ def forward_suffix(cfg: WindowMoeConfig, params, ids, pools, tables,
         write[kind] = put
     x, pools, _ = _stack(cfg, params, _embed(cfg, params, ids), pos, ok,
                          pools, write, tables, pos[:, 0] + 1, interpret)
+    return x, pools
+
+
+def forward_suffix_rows(cfg: WindowMoeConfig, params, ids, pools, tables,
+                        lengths, n_valid, *, interpret: bool = False):
+    """forward_suffix with a ROW axis: the hits of one admission round
+    in one program, so a layer's weights are read once a round.  ids:
+    (R, S) padded to whole pages, n_valid (R,) real (0: a pad row, its
+    length 0, its tables trash blocks); tables: a (R, P) table a
+    group; row i's keys and values go to its own tables from page
+    lengths[i] // page on, a page none of its real tokens reaches to
+    the trash block.  Pad tokens reach no expert.  Returns (hidden (R,
+    S, H), pools)."""
+    R, S = ids.shape
+    page = pools["full"][1].shape[3]
+    n_p = S // page
+    at = jnp.arange(S)[None, :]
+    pos = jnp.minimum(lengths[:, None] + at,
+                      cfg.max_len - 1).astype(jnp.int32)
+    ok = at < n_valid[:, None]                            # (R, S)
+    piece = jnp.arange(n_p)[None, :]
+    write = {}
+    for kind, tab in tables.items():
+        held = jnp.take_along_axis(
+            tab, jnp.minimum(lengths[:, None] // page + piece,
+                             tab.shape[1] - 1), axis=1)
+        bids = jnp.where(piece * page < n_valid[:, None], held,
+                         0).reshape(-1)
+
+        def put(pool, new, gl, cols, bids=bids):
+            pages = new.reshape(R * n_p, page, *new.shape[2:])
+            return pool.at[bids, gl].set(
+                pages.transpose(0, 2, 3, 1) if cols
+                else pages.transpose(0, 2, 1, 3))
+        write[kind] = put
+    x, pools, _ = _stack(cfg, params, _embed(cfg, params, ids), pos, ok,
+                         pools, write, tables, pos[:, 0] + 1, interpret,
+                         live_chunk=JOIN_MOE_CHUNK)
     return x, pools
 
 
@@ -739,10 +804,19 @@ class WindowCompletionModel(GroupPagePrograms,
                             window_span=span)
 
     def join_rungs(self, cache: PagedKVCache) -> tuple[int, ...]:
-        """One row a suffix program: it loops page-wide pieces of a
-        ragged width and gives window pages back between them, row by
-        row (ROADMAP.md A1)."""
-        return (1,)
+        """The row counts the suffix programs come in, ascending
+        (mla.join_rungs): 1 and JOIN_ROWS, or the lane's batch where
+        that is less."""
+        return tuple(sorted({1, min(JOIN_ROWS, cache.batch)}))
+
+    @property
+    def join_width(self) -> int:
+        """ONE page: a pad token costs the dense layers what a live
+        one does, and the stack kernel walks a pad query block's whole
+        table (PERF.md section 7), so a round takes the hits of a page
+        or less and a wider one is a round of one, a piece at a time
+        with its window pages given back between pieces."""
+        return self.suffix_buckets[0]
 
     # -- prefill -----------------------------------------------------------
 
@@ -807,18 +881,79 @@ class WindowCompletionModel(GroupPagePrograms,
             close_mark(mark)
             mark = DEVTIME.take_mark(self._devname("suffix_prefill"))
             self._keep(cache, pools)
-            ctx = pos + off + 1 + np.arange(n)    # keys a token attends
-            W, aw = self.cfg.window, self.attn_work
-            aw["prefill_keys"] += int(ctx.sum())
-            aw["prefill_window_keys"] += int(np.minimum(ctx, W).sum())
-            aw["prefill_kv"] += pos + off + n
-            aw["prefill_window_kv"] += min(pos + off + n, W - 1 + n)
+            self._count_prefill(pos + off, n)
             cache.lengths[row] += n
             off += n
             cache.release_window(row)
         out = np.asarray(logits)
         close_mark(mark)
         return out
+
+    def _count_prefill(self, pos: int, n: int) -> None:
+        """attn_work of one suffix piece: n real tokens atop pos."""
+        ctx = pos + 1 + np.arange(n)              # keys a token attends
+        W, aw = self.cfg.window, self.attn_work
+        aw["prefill_keys"] += int(ctx.sum())
+        aw["prefill_window_keys"] += int(np.minimum(ctx, W).sum())
+        aw["prefill_kv"] += pos + n
+        aw["prefill_window_kv"] += min(pos + n, W - 1 + n)
+
+    # -- an admission round's hits in one program -----------------------------
+
+    def _suffix_rows_program(self, rows: int, sb: int):
+        cfg, interp = self.cfg, self.interpret
+        top_p, temp = self.top_p, self.temp
+
+        def build():
+            def run(params, pools, tables, lengths, ids, n_valid, rng):
+                x, pools = forward_suffix_rows(
+                    cfg, params, ids, pools, tables, lengths, n_valid,
+                    interpret=interp)
+                last = jnp.take_along_axis(
+                    x, jnp.maximum(n_valid - 1, 0)[:, None, None],
+                    axis=1)[:, 0]
+                logits = _head(cfg, params, last)
+                return pools, logits, _sample_rows(rng, logits, top_p,
+                                                   temp)
+            return run
+        return self._program(("suffix", rows, sb, top_p, temp),
+                             "suffix_prefill", build)
+
+    def paged_append_prefill_rows(self, cache: PagedKVCache, joins):
+        """mla.paged_append_prefill_rows over the two page groups:
+        joins is [(row, suffix_ids), ...], every suffix at most
+        `join_width` tokens, every row seated with its prefix mapped in
+        both groups.  The window group's pages of a row's whole suffix
+        are held before the dispatch (the span a seat reserves covers
+        the widest program's) and what each row slid past goes back
+        after it.  Returns (logits on the device, first tokens on the
+        host)."""
+        if len(joins) == 1:
+            return super().paged_append_prefill_rows(cache, joins)
+        for row, _ in joins:
+            if cache.lengths[row] % cache.page:
+                raise ValueError(
+                    f"a suffix starts at a page boundary; row {row} holds "
+                    f"{cache.lengths[row]} tokens")
+        ids, n_valid, full, lengths = self._round_inputs(cache, joins)
+        window = np.zeros_like(full)
+        for i, (row, _) in enumerate(joins):
+            window[i] = cache.window.tables[row]
+            self._count_prefill(int(lengths[i]), int(n_valid[i]))
+        self._rng, sub = jax.random.split(self._rng)
+        pools, logits, toks = self._suffix_rows_program(*ids.shape)(
+            self.params, self._pools(cache),
+            {"full": jnp.asarray(full), "window": jnp.asarray(window)},
+            jnp.asarray(lengths), jnp.asarray(ids), jnp.asarray(n_valid),
+            sub)
+        mark = DEVTIME.take_mark(self._devname("suffix_prefill"))
+        self._keep(cache, pools)
+        for i, (row, _) in enumerate(joins):
+            cache.lengths[row] += int(n_valid[i])
+            cache.release_window(row)
+        toks = np.asarray(toks)[:len(joins)]
+        close_mark(mark)
+        return logits, toks
 
     # -- decode ------------------------------------------------------------
 
@@ -884,7 +1019,13 @@ class WindowCompletionModel(GroupPagePrograms,
     def _warmup_paged_impl(self, cache: PagedKVCache, chunk: int,
                            max_prompt: int | None) -> None:
         """Every program the lane can dispatch: the suffix widths, the
-        decode chunk, the page copy of each group."""
+        decode chunk, the round's rung, the page copy of each group.
+        The rung compiles in a thread beside the others
+        (_compile_beside): a start from an empty compile cache is
+        ~3 s longer for it, not its 25-30 (PERF.md section 5)."""
+        rung = (self.join_rungs(cache)[-1] > 1
+                and self.join_width + chunk < self.cfg.max_len)
+        compiled = self._compile_beside(cache) if rung else None
         chunk_done = False
         for sb in self.suffix_buckets:
             n = max(1, min(sb, self.cfg.max_len - 1 - chunk))
@@ -895,4 +1036,43 @@ class WindowCompletionModel(GroupPagePrograms,
                     cache, np.ones((cache.batch,), np.int32), chunk)
                 chunk_done = True
             cache.free_row(0)
+        if rung:
+            compiled()
+            self._warm_join_rungs(cache)
         self._warm_cow(cache)
+
+    def _compile_beside(self, cache: PagedKVCache):
+        """Start compiling the round's rung in a thread (XLA's compile
+        drops the interpreter lock): `jit(...).lower(shapes).compile()`,
+        and the call that follows finds the executable on the lowering
+        the two share (the persistent compilation cache, where that
+        misses).  Returns the wait for it, which raises what the
+        compile raised."""
+        def spec(a):
+            return jax.ShapeDtypeStruct(a.shape, a.dtype)
+
+        def i32(*shape):
+            return jax.ShapeDtypeStruct(shape, jnp.int32)
+        rows, sb = self.join_rungs(cache)[-1], self.join_width
+        fn = self._suffix_rows_program(rows, sb)
+        args = jax.tree_util.tree_map(
+            spec, (self.params, self._pools(cache))) + (
+            {k: i32(rows, cache.tables.shape[1])
+             for k in ("full", "window")},
+            i32(rows), i32(rows, sb), i32(rows), spec(self._rng))
+        failed = []
+
+        def compile_it():
+            try:
+                getattr(fn, "__wrapped__", fn).lower(*args).compile()
+            except Exception as e:
+                failed.append(e)
+        thread = threading.Thread(target=compile_it, daemon=True,
+                                  name="compile-beside-warmup")
+        thread.start()
+
+        def wait():
+            thread.join()
+            if failed:
+                raise failed[0]
+        return wait

@@ -1188,6 +1188,12 @@ class LatentCompletionModel:
         no row axis answers (1,) and is joined a request at a time."""
         return tuple(sorted({1, min(8, cache.batch), cache.batch}))
 
+    @property
+    def join_width(self) -> int:
+        """Tokens a row of the row-batched suffix programs holds: a
+        hit whose suffix is longer is a round of one."""
+        return self.suffix_buckets[-1]
+
     def _suffix_rows_program(self, rows: int, sb: int):
         cfg, interp = self.cfg, self.interpret
         top_p, temp = self.top_p, self.temp
@@ -1211,10 +1217,10 @@ class LatentCompletionModel:
         """The host half of a round's dispatch: every join's pages
         reserved, and (ids (rows, sb), n_valid (rows,), tables (rows,
         P), lengths (rows,)) of the smallest rung that holds the joins
-        at the widest suffix width, its other rows pads."""
+        at the rows programs' width, its other rows pads."""
         rows = next(r for r in self.join_rungs(cache)
                     if r >= len(joins))
-        sb = self.suffix_buckets[-1]
+        sb = self.join_width
         ids = np.zeros((rows, sb), np.int32)
         n_valid = np.zeros((rows,), np.int32)
         tables = np.zeros((rows, cache.tables.shape[1]), np.int32)
@@ -1413,10 +1419,10 @@ class LatentCompletionModel:
             self._warm_cow(cache)
 
     def _warm_join_rungs(self, cache: PagedKVCache) -> None:
-        """The row-batched rungs, at the widest width.  A rung's
-        program is its shape: one row more than the rung below holds
-        compiles and runs it."""
-        sb = self.suffix_buckets[-1]
+        """The row-batched rungs, at their width.  A rung's program
+        is its shape: one row more than the rung below holds compiles
+        and runs it."""
+        sb = self.join_width
         for below in self.join_rungs(cache)[:-1]:
             self.paged_append_prefill_rows(
                 cache, [(r, np.ones((sb,), np.int32))

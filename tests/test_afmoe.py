@@ -457,6 +457,10 @@ def test_a_session_resumes_on_its_tail_through_run_continuous(tmp_path,
         _against_reference(model, *ask(3, turns[2]))
         assert s.window_resumes == 1 and s.window_cut_tokens == 96
         assert s.prefix_tokens == 64          # served from nothing
+        # a turn's suffix of three pages is wider than the rows program
+        # (one page): every join here was a round of one
+        assert model.join_rungs(comp._paged_cache) == (1, 2)
+        assert (s.join_programs, s.join_rows) == (4, 4)
         comp.publish_stats()
         hb = json.loads(comp.store.get(C.P.KEY_COMPLETE_STATS)
                         .rstrip(b"\0"))

@@ -547,6 +547,9 @@ def _window_case(one_chip, program):
                 _spec(one_chip, (32,), jnp.bool_),
                 _spec(one_chip, (32,), jnp.int32),
                 _spec(one_chip, (2,), jnp.int32))
+    elif program.startswith("rows-"):
+        fn, tables, args = _window_rows(one_chip, m, int(program[5:]),
+                                        GQA_P, GQA_POOLS)
     else:
         fn = m._suffix_program(640)
         tables = {k: _spec(one_chip, (1, GQA_P), jnp.int32)
@@ -555,6 +558,17 @@ def _window_case(one_chip, program):
                 _spec(one_chip, (1, 640), jnp.int32), i32)
     return getattr(fn, "__wrapped__", fn), (params, pools, tables,
                                             *args), weights
+
+
+def _window_rows(one_chip, m, rows, pages, groups):
+    """(program, tables, the other arguments) of the window / global
+    family's row-batched suffix prefill: `rows` rows, one page wide."""
+    def vec(n, dtype=jnp.int32):
+        return _spec(one_chip, (n,), dtype)
+    return (m._suffix_rows_program(rows, PAGE),
+            {k: _spec(one_chip, (rows, pages), jnp.int32) for k in groups},
+            (vec(rows), _spec(one_chip, (rows, PAGE), jnp.int32),
+             vec(rows), vec(2, jnp.uint32)))
 
 
 @pytest.mark.parametrize("program", ["chunk", "suffix-640"])
@@ -638,18 +652,12 @@ def test_sink_window_attention_kernel(one_chip, q_tokens, rows, kind):
                     if shape in ln and " copy(" in ln]
 
 
-@pytest.mark.parametrize("program", ["chunk", "suffix-128", "suffix-640"])
-def test_sink_window_programs_at_published_widths(one_chip, monkeypatch,
-                                                  program):
-    """The 8-step decode chunk of 48 rows, the one-page suffix prefill
-    (a question over its document) and the widest (a cold document's
-    pieces) of the benchmark's MiMo configuration (7 layers: the dense
-    one, four window layers as ONE scanned body, a tail of two;
-    6.87 GB of weights; the pools as the compiler lays them out): each
-    compiles, fits the chip beside its arguments, and keeps both
-    groups' pools in place."""
+def _sink_case(one_chip, program):
+    """(program, its arguments as shapes on the described chip, bytes
+    of weights) of the benchmark's MiMo configuration: "chunk",
+    "suffix-<width>" (one row) or "rows-<rung>" (an admission round's
+    rows, one page wide)."""
     from libsplinter_tpu.models import afmoe
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     kinds = tuple("window" if p else "full" for p in (0, 1, 1, 1, 1, 0, 1))
     cfg = afmoe.WindowMoeConfig(
         vocab_size=19072, hidden=4096, kinds=kinds, heads=SWA_H,
@@ -669,7 +677,6 @@ def test_sink_window_programs_at_published_widths(one_chip, monkeypatch,
         jax.eval_shape(lambda: afmoe.init_params(cfg, 0)))
     weights = sum(int(np.prod(a.shape)) * a.dtype.itemsize
                   for a in jax.tree_util.tree_leaves(params))
-    assert 6.86e9 < weights < 6.88e9        # 3,430M parameters
     m = afmoe.WindowCompletionModel(cfg, params=params)
     pools = _swa_pools(one_chip)
     i32 = _spec(one_chip, (), jnp.int32)
@@ -683,6 +690,9 @@ def test_sink_window_programs_at_published_widths(one_chip, monkeypatch,
                 _spec(one_chip, (48,), jnp.bool_),
                 _spec(one_chip, (48,), jnp.int32),
                 _spec(one_chip, (2,), jnp.int32))
+    elif program.startswith("rows-"):
+        fn, tables, args = _window_rows(one_chip, m, int(program[5:]),
+                                        SWA_P, SWA_POOLS)
     else:
         width = int(program.split("-")[1])
         fn = m._suffix_program(width)
@@ -690,9 +700,24 @@ def test_sink_window_programs_at_published_widths(one_chip, monkeypatch,
                   for k in SWA_POOLS}
         args = (_spec(one_chip, (1,), jnp.int32),
                 _spec(one_chip, (1, width), jnp.int32), i32)
-    compiled = getattr(fn, "__wrapped__", fn).lower(
-        params, pools, tables, *args).compile()
-    mem = compiled.memory_analysis()
+    return getattr(fn, "__wrapped__", fn), (params, pools, tables,
+                                            *args), weights
+
+
+@pytest.mark.parametrize("program", ["chunk", "suffix-128", "suffix-640"])
+def test_sink_window_programs_at_published_widths(one_chip, monkeypatch,
+                                                  program):
+    """The 8-step decode chunk of 48 rows, the one-page suffix prefill
+    (a question over its document) and the widest (a cold document's
+    pieces) of the benchmark's MiMo configuration (7 layers: the dense
+    one, four window layers as ONE scanned body, a tail of two;
+    6.87 GB of weights; the pools as the compiler lays them out): each
+    compiles, fits the chip beside its arguments, and keeps both
+    groups' pools in place."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    fn, args, weights = _sink_case(one_chip, program)
+    assert 6.86e9 < weights < 6.88e9        # 3,430M parameters
+    mem = fn.lower(*args).compile().memory_analysis()
     assert mem.argument_size_in_bytes > 10.5e9   # weights, both groups
     assert mem.temp_size_in_bytes < 1.0e9        # no pool copies
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
@@ -836,6 +861,34 @@ def test_conv_suffix_rows_program(one_chip, monkeypatch):
         < 0.9 * 16.9e9
     assert _no_pool_copied(compiled, CONV_POOL)
     assert _no_pool_copied(compiled, (CONV_SLOTS, 2, 2048))
+
+
+# the rung's figures (models/afmoe.py JOIN_ROWS quotes them): MiMo 16
+# rows x 128 tokens 10,735,366,144 B of arguments + 450,188,800 B of
+# temporaries = 66.2% of the chip's 16.9 GB; Trinity 16 x 128
+# 14,599,017,984 + 181,220,352 B = 87.5% (the cell's two pools beside
+# 8.55 GB of weights).  At 48 and 32 rows — a round of the cells'
+# whole batch in one program — they read 10.74 + 1.73 GB (73.8%) and
+# 14.60 + 0.48 GB (89.2%, 0.13 GB under the line)
+@pytest.mark.parametrize("family, rows", [("mimo", 16), ("trinity", 16)])
+def test_window_suffix_rows_program(one_chip, monkeypatch, family, rows):
+    """The window / global family's row-batched suffix prefill (an
+    admission round's hits x ONE page in one program, first tokens
+    included) at MiMo's and at Trinity's published widths: it
+    compiles, arguments + temporaries stay under 90% of the chip beside
+    that cell's pools, and neither group's pools are copied."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    case = {"mimo": _sink_case, "trinity": _window_case}[family]
+    fn, args, _ = case(one_chip, f"rows-{rows}")
+    compiled = fn.lower(*args).compile()
+    mem = compiled.memory_analysis()
+    print(family, f"suffix rows {rows} x {PAGE}: arguments",
+          mem.argument_size_in_bytes, "temporaries",
+          mem.temp_size_in_bytes)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < 0.9 * 16.9e9
+    for pool in jax.tree_util.tree_leaves(args[1]):
+        assert _no_pool_copied(compiled, pool.shape)
 
 
 # the lowered text (kernel payloads, which carry source lines, left

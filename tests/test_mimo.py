@@ -707,3 +707,217 @@ def test_a_one_group_model_has_neither_counter(tmp_path):
     finally:
         st.close()
         Store.unlink(name)
+
+
+# ------------------------------------- an admission round in one program
+#
+# Both small configurations of models/afmoe.py: this file's (bfloat16,
+# a window of ONE page, keys a token a column, a sink) and
+# tests/test_afmoe.py's (float32, a window of two pages, the output
+# gate and the sandwich norms).
+
+# (document, its whole pages, suffix tokens) a join: three questions on
+# one document — they share its window tail —, one of them ONE token
+# and one a whole page; a fourth on a document of its own
+ROUND = [("a", 4, 1), ("a", 4, PAGE), ("a", 4, 7), ("b", 3, 11)]
+
+
+@pytest.fixture(scope="module", params=["afmoe", "mimo"])
+def round_model(request, cfg):
+    if request.param == "afmoe":
+        cfg = afmoe.WindowMoeConfig.tiny(dtype=jnp.float32,
+                                         experts_first=2, experts_held=4)
+    return afmoe.WindowCompletionModel(cfg, seed=SEED)
+
+
+def _seated_round(base, joins, batch, warm_chunk=0, **kw):
+    """A model over `base`'s weights, its cache (warmed up for chunks
+    of `warm_chunk` steps, if any) and its tree: every
+    document prefilled once and filed, every join seated as
+    completer.fill_rows seats a hit — the document's pages mapped in
+    both groups, the row's reservation made, its suffix not yet
+    prefilled.  Returns (model, cache, [(row, suffix)])."""
+    cfg = base.cfg
+    m = afmoe.WindowCompletionModel(cfg, params=base.params, temp=0.0,
+                                    **kw)
+    cache = m.init_paged(batch, page=PAGE, pool_pages=40,
+                         window_pool_pages=24)
+    pc = PrefixCache(PAGE)
+    pc.attach(cache)
+    cache.prefix_cache = pc
+    if warm_chunk:
+        m.warmup_paged(cache, chunk=warm_chunk)
+    rng = np.random.default_rng(11)
+    docs, rows = {}, []
+    for name, pages, _ in joins:
+        if name not in docs:
+            docs[name] = rng.integers(3, cfg.vocab_size,
+                                      pages * PAGE).astype(np.int32)
+            m.paged_prefill_row(cache, docs[name], 0)
+            pc.insert(docs[name], cache, 0)
+            cache.free_row(0)
+    for row, (name, pages, n) in enumerate(joins):
+        suffix = rng.integers(3, cfg.vocab_size, n).astype(np.int32)
+        ids = np.concatenate([docs[name], suffix])
+        bids, match, _ = pc.lookup_tiered(ids)
+        tail = list(pc.last_window)
+        assert match == pages * PAGE and tail and not pc.last_window_cut
+        cache.map_shared(row, bids)
+        cache.window.map_tail(row, len(bids) - len(tail), tail)
+        pc.commit_hit(ids, match)
+        cache.lengths[row] = match
+        assert cache.ensure(row, len(ids) + 12)
+        rows.append((row, suffix))
+    for k in m.attn_work:
+        m.attn_work[k] = 0
+    return m, cache, rows
+
+
+def _row_pages(cache, row):
+    """The pages row holds, by group: [K, V] x (pages, ...)."""
+    w = cache.window
+    lo, hi = int(w._lo[row]), int(w._hi[row])
+    held = len(cache._owned[row])
+    return ([np.asarray(p[0][cache.tables[row, :held]], np.float32)
+             for p in cache.pools]
+            + [np.asarray(p[0][w.tables[row, lo:hi]], np.float32)
+               for p in w.pools])
+
+
+def _assert_same_round(cfg, got, want, n):
+    """Another row count is another summation order: float32 holds
+    tests/test_afmoe.py's tolerance; in bfloat16 a rounding may fall
+    the other way (measured here: 5e-7 of the logits' spread, a
+    hundredth of what the reference is held to is the limit)."""
+    (m, cache, logits), (want_m, want_c, want_logits) = got, want
+    logits = np.asarray(logits)[:n]
+    if cfg.dtype == jnp.float32:
+        np.testing.assert_allclose(logits, want_logits, atol=2e-4)
+        atol = 2e-4
+    else:
+        assert BENCH.rel_err(logits, want_logits).max() < 0.01 * TOL
+        atol = 2e-2
+    np.testing.assert_array_equal(cache.lengths, want_c.lengths)
+    np.testing.assert_array_equal(cache.window._lo, want_c.window._lo)
+    np.testing.assert_array_equal(cache.window._hi, want_c.window._hi)
+    assert cache.window.released == want_c.window.released
+    assert cache.window.free_pages == want_c.window.free_pages
+    assert cache.free_pages == want_c.free_pages
+    for row in range(n):
+        for a, b in zip(_row_pages(cache, row), _row_pages(want_c, row)):
+            np.testing.assert_allclose(a, b, atol=atol)
+    assert m.attn_work == want_m.attn_work
+
+
+@pytest.mark.parametrize("batch", [4, 6], ids=["a-full-rung", "two-pads"])
+def test_a_round_in_one_program_is_its_joins_one_by_one(round_model,
+                                                        batch):
+    """paged_append_prefill_rows against the same joins through
+    paged_append_prefill: each row's logits, its pages of BOTH groups
+    (read through its tables: the two orders of ensure and release
+    hand out other page ids), its window span, what went back to the
+    window group's free list, and the kernels' live-key counts; the
+    rows that share a document's tail read it and write beside it; a
+    pad row writes the trash block alone; the first tokens are the
+    logits' argmax under a cold sampler."""
+    cfg = round_model.cfg
+    want_m, want_c, rows = _seated_round(round_model, ROUND, batch)
+    shared = want_c.window.tables[:3, 3]
+    assert shared[0] > 0 and (shared == shared[0]).all()
+    assert want_c.window.refcounts[shared[0]] == 3
+    want = np.stack([want_m.paged_append_prefill(want_c, s, r)
+                     for r, s in rows])
+    m, cache, rows = _seated_round(round_model, ROUND, batch)
+    assert m.join_rungs(cache) == (1, batch) and m.join_width == PAGE
+    idle = [np.asarray(p[0]) for p in cache.pools + cache.window.pools]
+    logits, firsts = m.paged_append_prefill_rows(cache, rows)
+    assert np.asarray(logits).shape == (batch, cfg.vocab_size)
+    _assert_same_round(cfg, (m, cache, logits), (want_m, want_c, want),
+                       len(rows))
+    np.testing.assert_array_equal(firsts,
+                                  np.asarray(logits)[:4].argmax(-1))
+    # every page no row of the round was writing is as it was: the
+    # documents', the tree's, the free ones (block 0 takes the pads')
+    wrote = {0} | {int(cache.tables[r, p]) for r, s in rows
+                   for p in range(len(cache._owned[r]))
+                   if p * PAGE >= cache.lengths[r] - len(s)}
+    wwrote = {0} | {int(cache.window.tables[r, p]) for r, s in rows
+                    for p in range(cache.pages_per_row)
+                    if (cache.lengths[r] - len(s)) // PAGE <= p
+                    < -(-cache.lengths[r] // PAGE)}
+    for pools, was, skip in ((cache.pools, idle[:2], wrote),
+                             (cache.window.pools, idle[2:], wwrote)):
+        keep = [b for b in range(was[0].shape[0]) if b not in skip]
+        for pool, before in zip(pools, was):
+            np.testing.assert_array_equal(np.asarray(pool[0])[keep],
+                                          before[keep])
+
+
+def test_a_round_of_one_is_the_one_row_program_and_bad_rows_are_refused(
+        round_model):
+    m, cache, rows = _seated_round(round_model, ROUND, 4)
+    want_m, want_c, _ = _seated_round(round_model, ROUND, 4)
+    logits, firsts = m.paged_append_prefill_rows(cache, rows[1:2])
+    np.testing.assert_array_equal(
+        logits[0], want_m.paged_append_prefill(want_c, *rows[1][::-1]))
+    assert firsts.tolist() == [int(np.argmax(logits[0]))]
+    assert not any(k[0] == "suffix" and len(k) > 2
+                   for k in m._paged_progs)
+    # a suffix wider than the rows program: the completer serves it as
+    # a round of one, a piece at a time
+    wide = np.ones((PAGE + 1,), np.int32)
+    with pytest.raises(ValueError, match=f"{PAGE + 1} tokens in a "
+                                         f"{PAGE}-token program"):
+        m.paged_append_prefill_rows(cache, [rows[0], (2, wide)])
+    cache.lengths[0] += 3
+    with pytest.raises(ValueError, match="page boundary"):
+        m.paged_append_prefill_rows(cache, [rows[0], rows[2]])
+
+
+def test_a_round_through_the_kernels_in_interpret_mode(round_model):
+    """The round's rows through the Pallas kernels as the chip runs
+    them — a pad row of length 0 over tables of trash blocks, rows
+    narrower than the program, a shared tail — against the same
+    round's jnp path."""
+    cfg = round_model.cfg
+    want_m, want_c, rows = _seated_round(round_model, ROUND[:3], 4)
+    want, _ = want_m.paged_append_prefill_rows(want_c, rows)
+    m, cache, rows = _seated_round(round_model, ROUND[:3], 4,
+                                   interpret=True)
+    logits, _ = m.paged_append_prefill_rows(cache, rows)
+    assert np.isfinite(np.asarray(logits)[:3]).all()
+    if cfg.dtype == jnp.float32:
+        np.testing.assert_allclose(np.asarray(logits)[:3],
+                                   np.asarray(want)[:3], atol=2e-2)
+    else:
+        assert BENCH.rel_err(np.asarray(logits)[:3],
+                             np.asarray(want)[:3]).max() < TOL
+    for row in range(3):
+        for a, b in zip(_row_pages(cache, row), _row_pages(want_c, row)):
+            np.testing.assert_allclose(a, b, atol=0.1)
+
+
+def test_warm_up_leaves_nothing_for_a_round_to_compile(round_model):
+    """warmup_paged compiles the one-row widths and, in a thread
+    beside them, the rung: a round, a wide hit's pieces and a chunk
+    afterwards compile no program, and the thread is gone; a compile
+    that fails in the thread fails the warm-up."""
+    m, cache, rows = _seated_round(round_model, ROUND, 4, warm_chunk=4)
+    assert not [t for t in threading.enumerate()
+                if t.name == "compile-beside-warmup"]
+    assert ("suffix", 4, PAGE, m.top_p, m.temp) in m._paged_progs
+    before = m.compile_count()
+    assert before >= len(m.suffix_buckets) + 2
+    m.paged_append_prefill_rows(cache, rows[1:])
+    m.paged_append_prefill(cache, np.ones((3 * PAGE + 2,), np.int32), 0)
+    m.paged_decode_chunk(cache, np.ones((4,), np.int32), 4)
+    assert m.compile_count() == before and cache.lengths.min() > 48
+
+    def refused(*a):
+        raise RuntimeError("the compiler said no")
+    cold = afmoe.WindowCompletionModel(m.cfg, params=m.params)
+    cold._paged_progs[("suffix", 4, PAGE, cold.top_p, cold.temp)] = \
+        type("Refusing", (), {"lower": refused})()
+    with pytest.raises(RuntimeError, match="said no"):
+        cold.warmup_paged(cold.init_paged(4, page=PAGE, pool_pages=40,
+                                          window_pool_pages=24), chunk=4)

@@ -780,3 +780,98 @@ def test_a_seat_may_evict_the_snapshot_an_earlier_row_of_the_round_resumed(
                 == sorted([log[1][1], src])
             assert _snapshot_slot(ln, _DOC)[0] == -1
         _same_answers(round_answers, "evict", kind, out)
+
+
+# ---- the same rounds over a model with a WINDOW GROUP beside the
+# global pages (models/afmoe.py: rungs 1 and the batch, ONE page wide)
+
+@pytest.fixture(scope="module")
+def window_models():
+    """The tiny window / global model twice over the same weights: as
+    it is (`rows`) and answering `(1,)` (`one-row`), which is how the
+    family was joined before it had a row axis."""
+    from libsplinter_tpu.models import afmoe
+
+    class OneRow(afmoe.WindowCompletionModel):
+        def join_rungs(self, cache):
+            return (1,)
+
+    cfg = afmoe.WindowMoeConfig.tiny(dtype=jnp.float32, experts_first=2,
+                                     experts_held=4)
+    rows = afmoe.WindowCompletionModel(cfg, seed=3, temp=0.0)
+    return {"rows": rows,
+            "one-row": OneRow(cfg, params=rows.params, temp=0.0)}
+
+
+@pytest.mark.parametrize("kind", ["rows", "one-row"])
+def test_window_group_hits_ride_one_round_and_a_wide_one_goes_alone(
+        tmp_path, monkeypatch, window_models, round_answers, kind):
+    """Three hits of a page or less and one of three pages, waiting
+    together on a document whose window tail they share: the three ride
+    ONE dispatch (a program counted once, its rows each) and the wide
+    one is a round of one, a piece at a time as before; every row
+    still records its `window_release`, and the audit's lane-0 record
+    (a resumed row) holds the batched row's first logits, the
+    reference's for the whole prompt."""
+    import reference_afmoe as R
+    from libsplinter_tpu.engine import completer as cmod
+
+    monkeypatch.setattr(cmod.tracer, "enabled", True)
+    cmod.tracer.reset()
+    model = window_models[kind]
+    audit_dir = str(tmp_path / "audit")
+    with _RoundLane(tmp_path, model, f"win-{kind}", window_pool_pages=24,
+                    audit={"dir": audit_dir, "every": 1}) as ln:
+        # the document, a first wide question (which files a page below
+        # it: the tree then gives the document's tail up, ROADMAP
+        # B1.7) and a short one that files the tail again, under a
+        # node with a child now: from here on it stays
+        for warm in ({"d": _DOC}, {"w": _DOC + "v" * 30},
+                     {"d/2": _DOC + "eh?"}):
+            ln.burst(warm)
+        s, w = ln.comp.stats, ln.comp._paged_cache.window
+        p0, r0 = s.join_programs, s.join_rows
+        res0, cut0, share0, hit0 = (s.window_resumes, s.window_cut_tokens,
+                                    s.window_tail_shares, s.prefix_tokens)
+        rel0 = cmod.tracer.snapshot()["infer.window_release"]["n"]
+        prompts = {"q/0": _DOC + "who?", "q/1": _DOC + "so?",
+                   "q/2": _DOC + "why so?",              # 16: a whole page
+                   "wide": _DOC + "w" * 30}              # 39: three pages
+        out, log = ln.burst(prompts)
+        tags = [t for t, _ in log]
+        assert (s.window_resumes - res0, s.window_cut_tokens - cut0) \
+            == (4, 0)
+        assert s.window_tail_shares - share0 == 3   # all but the first
+        assert s.prefix_tokens - hit0 == 4 * 32 and s.faults == 0
+        assert cmod.tracer.snapshot()["infer.window_release"]["n"] \
+            - rel0 >= 4
+        if kind == "rows":
+            assert tags.count("rows") == 1 and tags.count("suffix") == 1
+            assert tags[-4:] == ["rows"] + ["insert"] * 3
+            assert sorted(log[-4][1]) == sorted(r for _, r in log[-3:])
+            i = tags.index("suffix")
+            assert tags[i:i + 3] == ["suffix", "insert", "sample"]
+            assert (s.join_programs - p0, s.join_rows - r0) == (2, 4)
+        else:
+            assert tags == ["suffix", "insert", "sample"] * 4
+            assert (s.join_programs - p0, s.join_rows - r0) == (4, 4)
+        # one row a lane is audited at a time, and the wide hit, joined
+        # at its seat, took lane 0 above: two more hits, alone in their
+        # round, and the first of them is the lane's
+        def settled():
+            for _ in range(250):
+                if w.live_pages == 0:
+                    return ln.comp.audit.written
+                time.sleep(0.02)
+        written = settled()
+        ln.burst({"a/0": _DOC + "and?", "a/1": _DOC + "or?"})
+        assert settled() > written
+        rec = np.load(f"{audit_dir}/{written}.npz")
+        assert int(rec["n_prefix"]) == 32 and str(rec["key"])[:2] == "a/"
+        full = R.forward(model.cfg, model.params, np.concatenate(
+            [rec["prompt"], rec["tokens"][:-1]]))
+        np.testing.assert_allclose(
+            rec["logits"], full[len(rec["prompt"]) - 1:], atol=2e-4)
+        pc = ln.comp.prefix_cache
+        assert w.free_pages + pc.window_evictable_count() == 24
+        _same_answers(round_answers, "window", kind, out)

@@ -47,6 +47,7 @@ from ..utils import faults
 from ..utils.faults import fault
 from ..utils.trace import tracer
 from . import protocol as P
+from .prefix_cache import Join, PrefixCache, Seat
 from .qos import (AdmissionController, TenantLedger, WaitingRow,
                   parse_tenant_quotas, parse_tenant_weights,
                   prune_idle_counters)
@@ -113,14 +114,14 @@ def detect_template(chat_template: str | None) -> str:
     return "none"
 
 
-def _awaits(ids: list[int], match: int, page: int, join: dict) -> bool:
+def _awaits(ids: list[int], match: int, page: int, join: Join) -> bool:
     """A prompt whose prefix walk matched `match` tokens would have
     matched a page more had `join` — a row seated earlier in the same
     admission round, its prefill still to come — been inserted into
     the tree already."""
     end = match + page
-    other = join["ids"]
-    return (join["match"] < end <= min(len(ids), len(other))
+    other = join.ids
+    return (join.match < end <= min(len(ids), len(other))
             and ids[match:end] == other[match:end]
             and ids[:match] == other[:match])
 
@@ -1087,7 +1088,6 @@ class Completer:
                 # pool (abort recovery, spec demotion) invalidates
                 # every cached page id, so attach() empties the tree
                 if self.prefix_cache is None:
-                    from .prefix_cache import PrefixCache
                     self.prefix_cache = PrefixCache(
                         self.page_size,
                         max_pages=self._prefix_cache_pages,
@@ -1230,10 +1230,6 @@ class Completer:
         B = self.paged_batch_cap
         cfg = m.cfg
         cache = self._ensure_paged_cache()
-        # pod-sharded lane (ShardedCompletionModel): the dispatch gets
-        # its own fault site so the chaos matrix can crash/raise inside
-        # a sharded decode specifically (operations.md catalog)
-        sharded = getattr(m, "mesh", None) is not None
         self._running = True
         deadline = (time.monotonic() + stop_after) if stop_after else None
         last = st.signal_count(self.group)
@@ -1270,7 +1266,7 @@ class Completer:
         bp_memo = self._bp_memo
         bp_memo.clear()
 
-        def worst_len(n_ids: int) -> int:
+        def worst_len(n_ids: int, replay: int = 0) -> int:
             """Worst-case cache length for an admitted prompt.  Decode
             appends whole `step`-token chunks (paged_decode_chunk),
             so the final chunk can grow the cache up to step-1 tokens
@@ -1279,10 +1275,11 @@ class Completer:
             fully reserved pool could still raise mid-decode and
             abort every live row.  The first output token comes from
             the prefill sample; the remaining max_new - 1 arrive in
-            whole chunks."""
+            whole chunks; a fully cached prompt adds the `replay` of
+            its last token (prefix_cache.Seat.plan)."""
             chunks = (-(-(self.max_new - 1) // step)
                       if self.max_new > 1 else 0)
-            return min(n_ids + chunks * step, cfg.max_len)
+            return min(n_ids + chunks * step + replay, cfg.max_len)
 
         def span(row: dict | None, name: str, ms: float) -> None:
             """Accumulate a stage span: the lane histogram always, the
@@ -1390,21 +1387,13 @@ class Completer:
                 return 0
             n = 0
             traced = tracer.enabled
-            pc = getattr(cache, "prefix_cache", None)
-            # the round's hits whose suffix the model's rows program
-            # holds (`join_width`) are seated first and prefilled
-            # together, up to as many rows as its widest rung takes; a
-            # model whose programs are one row wide joins one by one,
-            # and a join that leaves a state snapshot rides the round
-            # only where the model's rows program leaves it
-            rungs = getattr(m, "join_rungs", None)
-            round_cap = rungs(cache)[-1] if rungs is not None else 1
-            snaps_ride = getattr(m, "join_snapshots", False)
+            pc, wgroup = cache.prefix_cache, cache.window
+            # the joins that ride the round (the model says which:
+            # `rides_round`) are seated first and prefilled together,
+            # `round_cap` to a program; the others are rounds of one,
+            # served where they are seated
+            round_cap = m.round_cap(cache)
             round_joins: list[dict] = []
-            # a model with per-row recurrent state (models/kda.py):
-            # a hit resumes from a snapshot, a join leaves one
-            stateful = bool(getattr(cache, "needs_state", False))
-            wgroup = getattr(cache, "window", None)
             for idx in round_order(order):
                 if not free:
                     break
@@ -1423,44 +1412,22 @@ class Completer:
                     if peek is None:
                         continue
                     ids = self._clip_paged(tok_izer.encode(peek[1]))
-                # radix-tree walk BEFORE the page math: every hit
-                # page is a page the pool does not need free — the
-                # admission reservation (and the backpressure memo)
-                # counts only the UNCACHED suffix, plus one page for
-                # the copy-on-write a fully cached prompt's replay
-                # append will take
-                hit_bids: list[int] = []
-                match = 0
-                tier_nodes: list = []
+                # the pages side of the admission (prefix_cache.Seat):
+                # the radix-tree walk BEFORE the page math, so that the
+                # reservation (and the backpressure memo) counts only
+                # what the tree does not hold
+                seat = Seat(cache, ids)
                 walk_ms = 0.0
                 if pc is not None and len(ids):
-                    # tier-aware walk: an HBM run, then (optionally) a
-                    # run of demoted pages whose bytes live in host
-                    # RAM — those cost a readmit (device_put + table
-                    # write) instead of a re-prefill, and the pool
-                    # pages they land in come out of the same `need`
-                    # budget the uncached suffix would have used
-                    # a model with state resumes STRICTLY below its last
-                    # token and only where a snapshot sits: replaying
-                    # the last token, as a fully cached prompt does
-                    # below, would apply it to the state twice
                     tw = time.perf_counter()
                     with tracer.annotation("infer.prefix_hit"):
-                        hit_bids, match, tier_nodes = pc.lookup_tiered(
-                            ids, len(ids) - 1 if stateful else None)
-                    if (match + len(tier_nodes) * cache.page
-                            == len(ids) and len(ids) < 2):
-                        # a fully-covered 1-token prompt would enter
-                        # at lengths 0 — the DEAD-row sentinel; serve
-                        # it as a miss (page size 1 is a test-only
-                        # geometry anyway)
-                        hit_bids, match, tier_nodes = [], 0, []
+                        seat.walk()
                     if traced:
                         # the walk is prefix_hit's first part; the
                         # row's event list takes it once it is seated
                         walk_ms = (time.perf_counter() - tw) * 1e3
                         span(None, "prefix_hit", walk_ms)
-                if any(_awaits(ids, match, cache.page, j)
+                if any(_awaits(ids, seat.match, cache.page, j["join"])
                        for j in round_joins):
                     # the walk ends short of a page a seated row of
                     # this round is about to prefill: the round closes
@@ -1468,56 +1435,12 @@ class Completer:
                     # in the next
                     break
                 with tracer.span("infer.gather", leaf=True):
-                    cut = pc.last_cut if stateful and pc is not None else 0
-                    # a pool with a window group (models/afmoe.py): the
-                    # hit ends where the window's tail is still held, and
-                    # maps that tail beside the global pages
-                    windowed = wgroup is not None and pc is not None
-                    wtail = list(pc.last_window) if windowed and hit_bids \
-                        else []
-                    wcut = pc.last_window_cut if windowed else 0
-                    # the snapshot this join will leave: the state after
-                    # the prompt's last full page, if the hit ends short
-                    # of it and the pool keeps snapshots at all
-                    snap_at = (len(ids) // cache.page) * cache.page
-                    wants_snap = (stateful and pc is not None
-                                  and cache.state_snapshots > 0
-                                  and snap_at > match)
-                    match_all = match + len(tier_nodes) * cache.page
-                    full_cover = ((bool(hit_bids) or bool(tier_nodes))
-                                  and match_all == len(ids))
-                    reserve = 0
                     if len(ids):
-                        reserve = min(worst_len(len(ids))
-                                      + (step if full_cover else 0),
-                                      cfg.max_len)
-                        need = (cache.pages_needed(reserve)
-                                - len(hit_bids)
-                                + (1 if full_cover else 0))
-                        # zero-ref hit pages count in available_pages as
-                        # reclaimable supply, but map_shared is about to
-                        # PIN them — they cannot also feed this row's new
-                        # allocations, so subtract them from the supply
-                        # side or a warm near-full pool would admit a row
-                        # whose ensure() then comes up short
-                        pinned = sum(1 for b in hit_bids
-                                     if cache.refcounts[b] == 0)
-                        # the window group's reservation: what the row
-                        # holds at the most while it joins and decodes,
-                        # less the tail it maps (pinned like the hit's
-                        # global pages)
-                        short_w = wgroup is not None and (
-                            wgroup.join_pages(match, reserve) - len(wtail)
-                            + (1 if full_cover else 0)
-                            > wgroup.available_pages - sum(
-                                1 for b in wtail
-                                if wgroup.refcounts[b] == 0))
-                        if need > cache.available_pages - pinned or (
-                                wants_snap
-                                and not cache.state_slot_available()) \
-                                or short_w:
+                        short = seat.plan(worst_len(len(ids)),
+                                          worst_len(len(ids), step))
+                        if short is not None:
                             self.stats.join_backpressure += 1
-                            bp_memo[idx] = (e, need + pinned)
+                            bp_memo[idx] = (e, short)
                             self._bound_bp_memo()
                             continue      # pool full: next cycle retries
                     tenant, _dl = self._qos_meta(idx)
@@ -1551,97 +1474,22 @@ class Completer:
                     rows[r]["spans"].append(
                         ["prefix_hit", round(walk_ms, 3)])
                 # prefix_hit, after the walk above: mapping the hit's
-                # pages and reserving the row's own — one clock pair,
-                # less the restore inside it (a leaf of its own)
+                # pages and reserving the row's own — one clock pair
                 ta = time.perf_counter()
-                restore_ms = 0.0
-                if hit_bids or tier_nodes:
-                    with tracer.annotation("infer.prefix_hit"):
-                        # the chaos matrix crashes HERE (mid table-
-                        # mapping, after the claim): the restarted lane
-                        # rebuilds pool + tree from scratch, so a death
-                        # between refcount bumps can strand nothing
-                        fault("completer.prefix_map")
-                        if hit_bids:
-                            # pin the HBM prefix FIRST: readmission
-                            # allocations below can trigger reclaim,
-                            # and an unpinned zero-ref hit page would
-                            # be fair game for the very eviction pass
-                            # serving it
-                            cache.map_shared(r, hit_bids)
-                            if wgroup is not None:
-                                self.stats.window_tail_shares += any(
-                                    wgroup.refcounts[b] > 0
-                                    for b in wtail)
-                                wgroup.map_tail(
-                                    r, len(hit_bids) - len(wtail), wtail)
-                                self.stats.window_resumes += int(not wcut)
-                        if tier_nodes:
-                            # DRAM hit: readmit demoted pages.  They
-                            # come back holding refcount 1; drop each
-                            # to zero-ref (tree-retained, off the free
-                            # list) then map — map_shared's 0→1 bump
-                            # re-pins them for this row with the tree
-                            # reference accounted exactly once.  A
-                            # partial readmission (pool pressure,
-                            # injected fault) just shortens the hit —
-                            # the rest re-prefills
-                            tier_bids = pc.readmit(tier_nodes, cache)
-                            for b in tier_bids:
-                                cache._decref(b)
-                            if tier_bids:
-                                cache.map_shared(r, tier_bids)
-                            hit_bids = hit_bids + tier_bids
-                            match += len(tier_bids) * cache.page
-                            if len(tier_bids) < len(tier_nodes):
-                                full_cover = False
-                        if not hit_bids:
-                            pc.note_miss()   # every readmit failed
-                        else:
-                            cache.lengths[r] = (
-                                len(ids) - 1 if full_cover else match)
-                    if hit_bids and stateful:
-                        src = pc.state_slot(ids, match)
-                        if src < 0:
-                            raise RuntimeError(
-                                "a hit ends at a node without a "
-                                "state snapshot")
-                        t_s = time.perf_counter()
-                        with tracer.annotation("infer.state_restore"):
-                            m.state_restore(cache, src, r)
-                        self.stats.state_restores += 1
-                        if traced:
-                            restore_ms = (time.perf_counter() - t_s) * 1e3
-                            span(rows[r], "state_restore", restore_ms)
-                elif pc is not None and len(ids):
-                    pc.note_miss()
+                w_s0 = wgroup.release_s if wgroup is not None else 0.0
                 with tracer.annotation("infer.prefix_hit"):
-                    if hit_bids:
-                        # hit/LRU recorded only now — a denied or
-                        # raced admission must not inflate the hit
-                        # rate the runbook triages on
-                        pc.commit_hit(ids, match)
-                        pc.stats.bytes_saved += \
-                            match * cache.kv_bytes_per_token()
-                        if tenant:
-                            self.tenants.bump(tenant,
-                                              "prefix_hit_pages",
-                                              len(hit_bids))
-                    # the uncached tail AFTER tier readmission: a partial
-                    # readmit lengthens the suffix the prefill must cover
-                    suffix = ids[match:]
+                    seated = seat.map(r)
+                    if seat.hit_bids and tenant:
+                        self.tenants.bump(tenant, "prefix_hit_pages",
+                                          len(seat.hit_bids))
+                    if seat.hit_bids and wgroup is not None:
+                        self.stats.window_tail_shares += seat.tail_shared
+                        self.stats.window_resumes += int(not seat.wcut)
                     self.stats.prompt_tokens += len(ids)
-                    self.stats.prefix_tokens += match
-                    self.stats.state_cut_tokens += cut
-                    self.stats.window_cut_tokens += wcut
-                    w_s0 = wgroup.release_s if wgroup is not None else 0.0
-                    seated = cache.ensure(r, reserve)
+                    self.stats.prefix_tokens += seat.match
+                    self.stats.state_cut_tokens += seat.cut
+                    self.stats.window_cut_tokens += seat.wcut
                 if not seated:
-                    # defensive: the pinned-aware gate above makes
-                    # this unreachable, but a seated row WITHOUT its
-                    # reservation would strand mid-decode and abort
-                    # the whole batch — re-queue it instead
-                    cache.free_row(r)
                     rows[r] = None
                     free.insert(0, r)
                     self._live_spans.pop(key, None)
@@ -1650,8 +1498,20 @@ class Completer:
                     continue
                 if traced:
                     span(rows[r], "prefix_hit",
-                         (time.perf_counter() - ta) * 1e3 - restore_ms)
-                if getattr(cache, "quantized", False) and suffix:
+                         (time.perf_counter() - ta) * 1e3)
+                if seat.state_src is not None:
+                    # a hit of a model with per-row recurrent state
+                    # (models/kda.py) resumes from the snapshot its
+                    # last node owns
+                    t_s = time.perf_counter()
+                    with tracer.annotation("infer.state_restore"):
+                        m.state_restore(cache, seat.state_src, r)
+                    self.stats.state_restores += 1
+                    if traced:
+                        span(rows[r], "state_restore",
+                             (time.perf_counter() - t_s) * 1e3)
+                suffix = seat.suffix
+                if cache.quantized and suffix:
                     # the quantized append/commit path: the commit
                     # scatter about to run quantizes the prompt's K/V
                     # into int8 pages (per-page scales) — the chaos
@@ -1661,7 +1521,7 @@ class Completer:
                     fault("completer.kv_quant_commit")
                 if suffix:
                     snap = None
-                    if wants_snap:
+                    if seat.snap_at is not None:
                         # a slot for the snapshot BEFORE the prefill
                         # that fills it (the tree may give up another
                         # snapshot for it; the restore above is
@@ -1670,28 +1530,20 @@ class Completer:
                         with tracer.annotation("infer.state_snapshot"):
                             slot = cache.alloc_state_slot()
                         if slot is not None:
-                            snap = (slot, snap_at)
+                            snap = (slot, seat.snap_at)
                         if traced:
                             span(rows[r], "state_snapshot",
                                  (time.perf_counter() - t_s) * 1e3)
-                    join = {"r": r, "key": key, "ids": ids,
-                            "match": match, "suffix": suffix,
-                            "hit": bool(hit_bids), "snap": snap,
-                            "reserve": reserve, "tenant": tenant,
-                            "w_s0": w_s0}
-                    if round_cap > 1 and hit_bids \
-                            and (snap is None or snaps_ride) \
-                            and len(suffix) <= m.join_width:
-                        # a hit inside one program width: its prefill
-                        # waits for the round's other hits
+                    join = {"join": Join(r, ids, seat.match,
+                                         bool(seat.hit_bids), snap),
+                            "key": key, "reserve": seat.reserve,
+                            "tenant": tenant, "w_s0": w_s0}
+                    if m.rides_round(join["join"]):
                         round_joins.append(join)
                         if len(round_joins) == round_cap:
                             join_round(round_joins)
                             round_joins = []
                     else:
-                        # a miss, a suffix wider than the rows
-                        # program, a model joined a row at a time: a
-                        # round of one, served here and now
                         join_round([join])
                 else:                 # reads it over the device carry
                     # FULLY cached prompt: no prefill at all.  The
@@ -1719,52 +1571,24 @@ class Completer:
             return n
 
         def join_round(joins: list[dict]) -> None:
-            """The prefill of rows fill_rows seated, and what follows
-            their logits.  One join runs the model's one-row program
-            (a miss: its bucket prefill) and draws on the host;
-            several — hits, each suffix inside one program width — ride
-            ONE dispatch of the model's row-batched suffix program,
-            which draws their first tokens in graph and, for a model
-            with state slots that says `join_snapshots`, leaves each
-            row's snapshot in the slot fill_rows allocated for it (the
-            rows' states were restored at their seats, before the
-            program: it reads no snapshot a later seat may have
-            evicted); each snapshot's node takes its slot over below, a
-            row at a time, as after a one-row join.  `infer.join` is
+            """The prefill of rows fill_rows seated — ONE call of the
+            model's `join`, which picks the program — and what follows
+            their logits.  A round of one comes back with its logits
+            on the host and is drawn here; a round of several with its
+            first tokens drawn in graph (the rows' states were restored
+            at their seats, before the program: it reads no snapshot a
+            later seat may have evicted); each snapshot's node takes
+            its slot over below, a row at a time.  `infer.join` is
             one annotation from the dispatch to the logits and is
             recorded once a ROW, the round's wall over its rows;
             `infer.sample` is a row's share of what stands between the
             logits and its first token (the window group's reserve,
             the tree's insert, the audit's copy, the draw)."""
-            pc = getattr(cache, "prefix_cache", None)
-            wgroup = getattr(cache, "window", None)
+            pc, wgroup = cache.prefix_cache, cache.window
             traced = tracer.enabled
-            firsts = None
             ta = time.perf_counter()
             with tracer.annotation("infer.join"):
-                if len(joins) > 1:
-                    # only a model that says `join_snapshots` has a
-                    # round with a snapshot in it (fill_rows)
-                    snaps = [j["snap"] for j in joins]
-                    logits, firsts = m.paged_append_prefill_rows(
-                        cache, [(j["r"], np.asarray(j["suffix"], np.int32))
-                                for j in joins],
-                        *([snaps] if any(snaps) else ()))
-                else:
-                    j = joins[0]
-                    skw = ({"snap_at": j["snap"][1],
-                            "snap_slot": j["snap"][0]}
-                           if j["snap"] else {})
-                    if j["hit"]:
-                        # uncached tail only, attending the mapped
-                        # prefix through the ragged paged kernel
-                        logits = m.paged_append_prefill(
-                            cache, np.asarray(j["suffix"], np.int32),
-                            j["r"], **skw)
-                    else:
-                        logits = m.paged_prefill_row(
-                            cache, np.asarray(j["ids"], np.int32),
-                            j["r"], **skw)
+                logits, firsts = m.join(cache, [j["join"] for j in joins])
             join_ms = (time.perf_counter() - ta) * 1e3 / len(joins)
             self.stats.join_programs += 1
             self.stats.join_rows += len(joins)
@@ -1772,7 +1596,8 @@ class Completer:
             # brings them to the host
             on_host = None
             for i, j in enumerate(joins):
-                r, ids, match, snap = j["r"], j["ids"], j["match"], j["snap"]
+                jn = j["join"]
+                r, ids, match, snap = jn.row, jn.ids, jn.match, jn.snap
                 tb = time.perf_counter()
                 with tracer.annotation("infer.sample"):
                     if wgroup is not None:
@@ -1799,7 +1624,7 @@ class Completer:
                                 j["tenant"], "prefix_cached_pages", ins)
                     # a model with two audit lanes (engine/audit.py)
                     # keeps one for each way a prompt is served
-                    lane = m.audit_lane(match, len(j["suffix"])) \
+                    lane = m.audit_lane(match, len(ids) - match) \
                         if self.audit is not None \
                         and self.audit.lanes > 1 else 0
                     if self.audit is not None and self.audit.wants(lane):
@@ -2016,9 +1841,13 @@ class Completer:
 
             td = time.perf_counter()
             with tracer.annotation("infer.decode"):
-                if sharded:
+                if getattr(m, "mesh", None) is not None:
+                    # pod-sharded lane (ShardedCompletionModel): the
+                    # dispatch gets its own fault site so the chaos
+                    # matrix can crash/raise inside a sharded decode
+                    # specifically (operations.md catalog)
                     fault("completer.sharded_dispatch")
-                wgroup = getattr(cache, "window", None)
+                wgroup = cache.window
                 w_s0, w_n0 = (wgroup.release_s, wgroup.released) \
                     if wgroup is not None else (0.0, 0)
                 pend = m.paged_decode_chunk_async(
@@ -2120,8 +1949,6 @@ class Completer:
                                 # spec pools retire with their wrapper and
                                 # a fresh pool serves the plain model)
                                 m = self._model
-                                sharded = getattr(m, "mesh",
-                                                  None) is not None
                                 self._paged_cache = None
                                 cache = self._ensure_paged_cache()
                                 bp_memo.clear()

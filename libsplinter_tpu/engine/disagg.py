@@ -52,6 +52,7 @@ from ..utils.faults import fault
 from ..utils.trace import tracer
 from . import protocol as P
 from .completer import Completer
+from .prefix_cache import Join, Seat
 
 __all__ = ["PrefillLane", "DecodeLane"]
 
@@ -147,7 +148,6 @@ class PrefillLane(Completer):
         True when the slot was consumed (handed off, finished, or
         typed-rejected); False leaves it WAITING for the next cycle
         (backpressure / race)."""
-        import numpy as np
         st = self.store
         m, tok = self._model, self._tok
         cache = self._ensure_paged_cache()
@@ -156,42 +156,19 @@ class PrefillLane(Completer):
             if peek is None:
                 return False
             ids = self._clip_paged(tok.encode(peek[1]))
-        pc = getattr(cache, "prefix_cache", None)
-        hit_bids: list[int] = []
-        match = 0
-        tier_nodes: list = []
+        pc = cache.prefix_cache
         # gather, this lane's: the prefix walk and the page math that
-        # decide whether the slot is served now
+        # decide whether the slot is served now (prefix_cache.Seat,
+        # the variant that keeps a token to prefill).  Peek-before-
+        # claim backpressure, prompt-only: the DECODE reservation is
+        # the adopting lane's pool's problem
+        seat = Seat(cache, ids, keep_suffix=True)
         with tracer.span("infer.gather", leaf=True):
             if pc is not None and len(ids):
-                hit_bids, match, tier_nodes = pc.lookup_tiered(ids)
-                # keep >= 1 suffix token to prefill: the handoff needs
-                # the last-position logits for the first sample (the
-                # unified lane's fully-covered replay trick needs a
-                # decode chunk this lane never runs).  Trim the DRAM
-                # run first — dropping a tier node costs nothing
-                # readmitted yet, dropping an HBM page forfeits
-                # committed work
-                while tier_nodes \
-                        and match + len(tier_nodes) * cache.page \
-                        >= len(ids):
-                    tier_nodes = tier_nodes[:-1]
-                while hit_bids and not tier_nodes \
-                        and match >= len(ids):
-                    hit_bids = hit_bids[:-1]
-                    match -= cache.page
-                if not hit_bids and not tier_nodes:
-                    match = 0
-            if len(ids):
-                # peek-before-claim backpressure, prompt-only: the
-                # DECODE reservation is the adopting lane's pool's
-                # problem
-                need = cache.pages_needed(len(ids)) - len(hit_bids)
-                pinned = sum(1 for b in hit_bids
-                             if cache.refcounts[b] == 0)
-                if need > cache.available_pages - pinned:
-                    self.stats.join_backpressure += 1
-                    return False
+                seat.walk()
+            if len(ids) and seat.plan(len(ids)) is not None:
+                self.stats.join_backpressure += 1
+                return False
             tenant, dl = self._qos_meta(idx)
         with tracer.span("infer.prepare", leaf=True):
             prep = self._prepare(idx, peek=peek)
@@ -208,55 +185,20 @@ class PrefillLane(Completer):
         tp0 = time.perf_counter()
         row = 0                       # serial scratch row
         with tracer.annotation("infer.join"):
-            if hit_bids or tier_nodes:
-                fault("completer.prefix_map")
-                if hit_bids:
-                    # pin the HBM prefix FIRST: readmission allocations
-                    # below can trigger reclaim, and an unpinned zero-ref
-                    # hit page would be fair game for that eviction pass
-                    cache.map_shared(row, hit_bids)
-                if tier_nodes:
-                    # DRAM hit: readmitted pages arrive holding refcount
-                    # 1 — drop each to zero-ref (tree-retained), then let
-                    # map_shared's 0→1 bump pin them for the scratch row.
-                    # Partial readmission just lengthens the suffix
-                    tier_bids = pc.readmit(tier_nodes, cache)
-                    for b in tier_bids:
-                        cache._decref(b)
-                    if tier_bids:
-                        cache.map_shared(row, tier_bids)
-                    hit_bids = hit_bids + tier_bids
-                    match += len(tier_bids) * cache.page
-                if not hit_bids:
-                    pc.note_miss()       # every readmit failed
-                else:
-                    cache.lengths[row] = match
-                    pc.commit_hit(ids, match)
-                    pc.stats.bytes_saved += \
-                        match * cache.kv_bytes_per_token()
-                    if tenant:
-                        self.tenants.bump(tenant, "prefix_hit_pages",
-                                          len(hit_bids))
-            elif pc is not None:
-                pc.note_miss()
-            suffix = ids[match:]
-            if not cache.ensure(row, len(ids)):
-                # defensive (pinned-aware gate above): re-queue, same as
-                # the unified admit()'s unreachable branch
-                cache.free_row(row)
+            seated = seat.map(row)
+            if seat.hit_bids and tenant:
+                self.tenants.bump(tenant, "prefix_hit_pages",
+                                  len(seat.hit_bids))
+            if not seated:
                 self.stats.join_backpressure += 1
                 self._requeue_failed([idx])
                 return True
         try:
             with tracer.annotation("infer.join"):
-                if getattr(cache, "quantized", False) and suffix:
+                if cache.quantized and seat.suffix:
                     fault("completer.kv_quant_commit")
-                if hit_bids:
-                    logits = m.paged_append_prefill(
-                        cache, np.asarray(suffix, np.int32), row)
-                else:
-                    logits = m.paged_prefill_row(
-                        cache, np.asarray(ids, np.int32), row)
+                logits, _ = m.join(cache, [Join(
+                    row, ids, seat.match, bool(seat.hit_bids))])
                 if pc is not None:
                     ins = pc.insert(ids, cache, row, tenant)
                     if ins and tenant:
@@ -318,7 +260,7 @@ class PrefillLane(Completer):
                        "plen": st.value_len(key), "t0": int(t0),
                        "tenant": int(tenant),
                        "deadline": dl, "wire_pages": wire_pages,
-                       "quant": bool(getattr(cache, "quantized", False))}
+                       "quant": bool(cache.quantized)}
                 if not P.write_handoff_record(st, idx, rec):
                     # no record -> no adoption, ever: finish with the
                     # token already streamed instead of stranding the

@@ -839,3 +839,192 @@ class PrefixCache:
 
     def tenant_pages(self) -> dict[int, int]:
         return {t: n for t, n in self._tenant_pages.items() if n}
+
+
+# ---------------------------------------------------------------- the seat
+
+@dataclasses.dataclass(frozen=True)
+class Join:
+    """One seated request as its model's `join` sees it: the batch
+    row, the prompt's ids, how many of them the row's table maps
+    already, whether that is a prefix hit (the suffix prefills atop
+    it) or a miss (the whole prompt prefills), and the snapshot the
+    prefill leaves — (state slot, token count) or None."""
+
+    row: int
+    ids: list
+    match: int
+    hit: bool
+    snap: tuple[int, int] | None = None
+
+
+class Seat:
+    """The PAGES side of one admission, for the unified lane
+    (completer.fill_rows) and the prefill lane (disagg._handoff_one)
+    alike, in the three steps a lane sequences inside its own spans:
+
+      walk()  PURE: the tiered prefix walk and what it may not use.
+      plan()  PURE: the reservation and whether the pool holds it —
+              a denied request stays WAITING, untouched.
+      map()   after the claim: the table writes, the tree's counters,
+              the row's reservation.
+
+    A pool without a prefix tree (prefix sharing off, the paired
+    speculative pools) skips walk(): every prompt is a miss.
+
+    `keep_suffix` is the prefill lane's variant: the hand-off needs
+    the last position's logits for the first token (the unified lane's
+    replay of a fully cached prompt needs a decode chunk that lane
+    never runs), so the walk ends a token short of the prompt and a
+    fully covered prompt gives up the LAST page of its match — a
+    host-tier node where the match ends in one (it costs nothing
+    readmitted yet; an HBM page forfeits committed work)."""
+
+    def __init__(self, cache, ids, *, keep_suffix: bool = False):
+        self.cache, self.pc, self.ids = cache, cache.prefix_cache, ids
+        self.keep_suffix = keep_suffix
+        self.hit_bids: list[int] = []   # HBM pages the hit maps
+        self.tier_nodes: list = []      # demoted pages trailing them
+        self.match = 0                  # tokens the row's table maps
+        # tokens the walk gave up for want of a snapshot / of a window
+        # tail, and the window group's pages the hit resumes on
+        self.cut = self.wcut = 0
+        self.wtail: list[int] = []
+        self.tail_shared = False        # some row still reads that tail
+        self.full_cover = False
+        self.reserve = self.need = self.pinned = 0
+        # the snapshot the join should leave (a token count; None: none)
+        # and the slot a hit's state is restored from
+        self.snap_at = self.state_src = None
+
+    @property
+    def suffix(self):
+        """The tokens left to prefill."""
+        return self.ids[self.match:]
+
+    def walk(self) -> None:
+        cache, pc, ids = self.cache, self.pc, self.ids
+        page = cache.page
+        # a model with state resumes STRICTLY below its last token and
+        # only where a snapshot sits: replaying the last token, as a
+        # fully cached prompt does, would apply it to the state twice
+        bids, match, nodes = pc.lookup_tiered(
+            ids, len(ids) - 1 if self.keep_suffix or cache.needs_state
+            else None)
+        if match + len(nodes) * page == len(ids) and len(ids) < 2:
+            # a fully-covered 1-token prompt would enter at lengths 0 —
+            # the DEAD-row sentinel; serve it as a miss (page size 1 is
+            # a test-only geometry anyway)
+            bids, match, nodes = [], 0, []
+        self.hit_bids, self.match, self.tier_nodes = bids, match, nodes
+        self.cut, self.wcut = pc.last_cut, pc.last_window_cut
+        self.wtail = list(pc.last_window) if bids else []
+
+    def plan(self, reserve: int, replay_reserve: int | None = None
+             ) -> int | None:
+        """Price the seat: `reserve` is the token count the row's
+        table must cover at the most, `replay_reserve` the same for a
+        FULLY cached prompt (it enters one token short and the next
+        decode chunk replays that token into a private copy of the
+        shared tail page).  Every hit page is a page the pool does not
+        need free: `need` counts the uncached rest only, plus that
+        copy.  Returns None when the pool holds the seat, else the
+        pages that must be available before it is worth asking again
+        (the backpressure memo's value)."""
+        cache, ids, bids = self.cache, self.ids, self.hit_bids
+        self.full_cover = bool(bids or self.tier_nodes) and (
+            self.match + len(self.tier_nodes) * cache.page == len(ids))
+        cow = int(self.full_cover)
+        self.reserve = replay_reserve if self.full_cover else reserve
+        self.need = cache.pages_needed(self.reserve) - len(bids) + cow
+        # zero-ref hit pages count in available_pages as reclaimable
+        # supply, but map() is about to PIN them — they cannot also
+        # feed this row's new allocations, so they come off the supply
+        # side, or a warm near-full pool would admit a row whose
+        # ensure() then comes up short
+        self.pinned = sum(1 for b in bids if cache.refcounts[b] == 0)
+        # the snapshot the join will leave: the state after the
+        # prompt's last full page, if the hit ends short of it and the
+        # pool keeps snapshots at all
+        at = len(ids) // cache.page * cache.page
+        self.snap_at = at if (
+            cache.needs_state and self.pc is not None
+            and cache.state_snapshots > 0 and at > self.match) else None
+        # the window group's reservation: what the row holds at the
+        # most while it joins and decodes, less the tail it maps
+        # (pinned like the hit's global pages)
+        w = cache.window
+        short_w = w is not None and (
+            w.join_pages(self.match, self.reserve) - len(self.wtail) + cow
+            > w.available_pages - sum(
+                1 for b in self.wtail if w.refcounts[b] == 0))
+        if self.need > cache.available_pages - self.pinned or short_w \
+                or (self.snap_at is not None
+                    and not cache.state_slot_available()):
+            return self.need + self.pinned
+        return None
+
+    def map(self, row: int) -> bool:
+        """Seat the planned request in `row`: map the hit (readmitting
+        its demoted tail), count it, reserve the row's pages.  False —
+        the row freed again — when the reservation fails all the same:
+        the pinned-aware gate of plan() makes that unreachable, but a
+        row seated WITHOUT its reservation would strand mid-decode and
+        abort the whole batch, so the lane re-queues it."""
+        cache, pc, ids = self.cache, self.pc, self.ids
+        if self.hit_bids or self.tier_nodes:
+            # the chaos matrix crashes HERE (mid table-mapping, after
+            # the claim): the restarted lane rebuilds pool + tree from
+            # scratch, so a death between refcount bumps strands nothing
+            fault("completer.prefix_map")
+            if self.hit_bids:
+                # pin the HBM prefix FIRST: readmission allocations
+                # below can trigger reclaim, and an unpinned zero-ref
+                # hit page would be fair game for the very eviction
+                # pass serving it
+                cache.map_shared(row, self.hit_bids)
+                w = cache.window
+                if w is not None:
+                    self.tail_shared = any(w.refcounts[b] > 0
+                                           for b in self.wtail)
+                    w.map_tail(row, len(self.hit_bids) - len(self.wtail),
+                               self.wtail)
+            if self.tier_nodes:
+                # DRAM hit: readmitted pages come back holding refcount
+                # 1; drop each to zero-ref (tree-retained, off the free
+                # list) then map — map_shared's 0→1 bump re-pins them
+                # for this row with the tree reference accounted
+                # exactly once.  A partial readmission (pool pressure,
+                # injected fault) just shortens the hit — the rest
+                # re-prefills
+                tier_bids = pc.readmit(self.tier_nodes, cache)
+                for b in tier_bids:
+                    cache._decref(b)
+                if tier_bids:
+                    cache.map_shared(row, tier_bids)
+                self.hit_bids = self.hit_bids + tier_bids
+                self.match += len(tier_bids) * cache.page
+                if len(tier_bids) < len(self.tier_nodes):
+                    self.full_cover = False
+            if self.hit_bids:
+                cache.lengths[row] = (len(ids) - 1 if self.full_cover
+                                      else self.match)
+                if cache.needs_state:
+                    self.state_src = pc.state_slot(ids, self.match)
+                    if self.state_src < 0:
+                        raise RuntimeError("a hit ends at a node "
+                                           "without a state snapshot")
+        if pc is not None:
+            if self.hit_bids:
+                # hit/LRU recorded only now — a denied or raced
+                # admission must not inflate the hit rate the runbook
+                # triages on
+                pc.commit_hit(ids, self.match)
+                pc.stats.bytes_saved += \
+                    self.match * cache.kv_bytes_per_token()
+            else:
+                pc.note_miss()       # nothing matched, or no readmit held
+        if cache.ensure(row, self.reserve):
+            return True
+        cache.free_row(row)
+        return False

@@ -146,21 +146,6 @@ RUN_SCAN = 4
 # pages of the widest suffix program: a follow-up turn of a few hundred
 # tokens fits one call, a cold prompt loops in it
 SUFFIX_PAGES = 5
-# rows of the row-batched suffix program (join_rungs): ONE rung beside
-# the one-row programs (a rung is a program, 25-30 s of a start from an
-# empty compile cache), ONE page wide.  16, not a lane's whole batch:
-# three programs of 16 cost what one of 48 does (0.765 against 0.783 s
-# on a v5e at MiMo's widths: PERF.md section 6), and a round is
-# dispatched the moment it is full, so the device starts on a
-# generation's first 16 hits while the others are still arriving.  The
-# rung of 48 read 12.0 answers/s on the one machine whose clients came
-# back slowly (a round of the whole batch waits for the slowest with
-# the device idle); whether 16 does better THERE is not measured.
-# Compiled for a described v5e (tests/test_chip_compile.py
-# test_window_suffix_rows_program): 16 rows x 128 tokens at MiMo's
-# widths 10.74 GB of arguments + 0.45 GB of temporaries, 66% of the
-# chip's 16.9 GB; at Trinity's 14.60 + 0.18 GB, 87.5%
-JOIN_ROWS = 16
 # tokens a chunk of the rows program's expert layers: its LIVE tokens
 # first (moe.sparse_moe live_chunk), so that a round's rows x page
 # token slots read the experts' weights once
@@ -803,12 +788,22 @@ class WindowCompletionModel(GroupPagePrograms,
                             window_pool_pages=window_pool_pages,
                             window_span=span)
 
-    def join_rungs(self, cache: PagedKVCache) -> tuple[int, ...]:
-        """The row counts the suffix programs come in, ascending
-        (mla.join_rungs): 1 and JOIN_ROWS, or the lane's batch where
-        that is less."""
-        return tuple(sorted({1, min(JOIN_ROWS, cache.batch)}))
-
+    # rows of the row-batched suffix program (join_rungs): ONE rung
+    # beside the one-row programs (a rung is a program, 25-30 s of a
+    # start from an empty compile cache), ONE page wide.  16, not a
+    # lane's whole batch: three programs of 16 cost what one of 48 does
+    # (0.765 against 0.783 s on a v5e at MiMo's widths: PERF.md section
+    # 6), and a round is dispatched the moment it is full, so the
+    # device starts on a generation's first 16 hits while the others
+    # are still arriving.  The rung of 48 read 12.0 answers/s on the
+    # one machine whose clients came back slowly (a round of the whole
+    # batch waits for the slowest with the device idle); whether 16
+    # does better THERE is not measured.  Compiled for a described v5e
+    # (tests/test_chip_compile.py test_window_suffix_rows_program): 16
+    # rows x 128 tokens at MiMo's widths 10.74 GB of arguments + 0.45
+    # GB of temporaries, 66% of the chip's 16.9 GB; at Trinity's 14.60
+    # + 0.18 GB, 87.5%
+    JOIN_ROWS = (16,)
     @property
     def join_width(self) -> int:
         """ONE page: a pad token costs the dense layers what a live
@@ -928,8 +923,6 @@ class WindowCompletionModel(GroupPagePrograms,
         the widest program's) and what each row slid past goes back
         after it.  Returns (logits on the device, first tokens on the
         host)."""
-        if len(joins) == 1:
-            return super().paged_append_prefill_rows(cache, joins)
         for row, _ in joins:
             if cache.lengths[row] % cache.page:
                 raise ValueError(

@@ -1258,7 +1258,48 @@ sample_top_p_batch = DEVTIME.register(
 
 # ------------------------------------------------------------- front end
 
-class CompletionModel:
+def join_one(model, cache, join) -> np.ndarray:
+    """An admission round of ONE seated request (engine/prefix_cache.py
+    `Join`: row, the prompt's ids, the tokens its table maps, hit or
+    miss, the snapshot it leaves) through `model`'s one-row programs: a
+    miss prefills the whole prompt, a hit the suffix atop the mapped
+    prefix.  Returns the last token's logits (V,) on the host — the
+    lane draws the first token from them.  This is the one place that
+    decides what a round of one runs."""
+    skw = ({"snap_at": join.snap[1], "snap_slot": join.snap[0]}
+           if join.snap else {})
+    if join.hit:
+        return model.paged_append_prefill(
+            cache, np.asarray(join.ids[join.match:], np.int32), join.row,
+            **skw)
+    return model.paged_prefill_row(
+        cache, np.asarray(join.ids, np.int32), join.row, **skw)
+
+
+class RowJoins:
+    """The admission surface of a model whose prefill programs are one
+    row wide (the key/value decoder, its sharded and its speculative
+    wrappers): no join rides a round, every request is a round of its
+    own.  models/mla.py has the surface of the families with a
+    row-batched suffix program."""
+
+    def round_cap(self, cache) -> int:
+        """Joins one round's program holds."""
+        return 1
+
+    def rides_round(self, join) -> bool:
+        """Whether `join` waits for its round's other joins."""
+        return False
+
+    def join(self, cache, joins):
+        """Prefill a round's seated rows: (logits, first tokens drawn
+        in graph — None for a round of one, whose logits (V,) are on
+        the host for the lane's draw)."""
+        (one,) = joins
+        return join_one(self, cache, one), None
+
+
+class CompletionModel(RowJoins):
     """Bucketed prefill + token-at-a-time decode with persistent cache.
 
     paged_supported marks the block-paged continuous-batching surface
@@ -1338,7 +1379,7 @@ class CompletionModel:
                 jnp.int32(0))
         self.params = params
         # devtime attribution lane for the LAZY program caches below
-        # (chunk/join/paged): a disaggregated lane overwrites this
+        # (chunk/paged): a disaggregated lane overwrites this
         # ("prefill"/"decode") before warmup so its programs ledger
         # under their phase — prefill.bucket_commit, decode.paged_chunk
         # — while the trunk and samplers (registered eagerly, shared
@@ -1352,7 +1393,6 @@ class CompletionModel:
         self._start = None            # (B,) left-pad offsets when batched
         self._batch = 0
         self._chunk_progs: dict[tuple, Any] = {}
-        self._join_progs: dict[int, Any] = {}     # continuous-batch joins
         self._paged_progs: dict[tuple, Any] = {}  # paged decode/commit
 
     def _devname(self, short: str) -> str:
@@ -1590,87 +1630,11 @@ class CompletionModel:
         self._pos += n
         return np.asarray(out).T[: self._batch]    # (B, n)
 
-    def _join_program(self, b: int):
-        """One program prefilling a SINGLE row's prompt into the live
-        batch cache.  LEGACY dense-join surface: the continuous lane
-        now joins through paged_prefill_row (no shared window); this
-        model-level API remains for the dense batched cache and its
-        tests (tests/test_continuous.py).
-        The row's prompt is left-padded so its last token lands at slot
-        pos-1 — the batch's next decode step then serves it like any
-        other row.  Returns (new_batch_cache, last_logits (V,))."""
-        fn = self._join_progs.get(b)
-        if fn is None:
-            module = self.module
-
-            def run(params, batch_cache, ids, row, pos, start_row):
-                # ids: (1, b) left-padded; writes cache slots
-                # [pos-b, pos) of row `row` only
-                row_cache = [
-                    (jax.lax.dynamic_slice_in_dim(k, row, 1, 0),
-                     jax.lax.dynamic_slice_in_dim(v, row, 1, 0))
-                    for k, v in batch_cache]
-                logits, row_cache = module.apply(
-                    params, ids, row_cache, pos - b,
-                    start_row.reshape(1))
-                new_cache = [
-                    (jax.lax.dynamic_update_slice_in_dim(bk, rk, row, 0),
-                     jax.lax.dynamic_update_slice_in_dim(bv, rv, row, 0))
-                    for (bk, bv), (rk, rv) in zip(batch_cache, row_cache)]
-                return new_cache, logits[0, b - 1]
-
-            fn = DEVTIME.register(self._devname("join"),
-                                  jax.jit(run, donate_argnums=(1,)))
-            self._join_progs[b] = fn
-        return fn
-
-    def join_row(self, prompt_ids: np.ndarray, row: int) -> np.ndarray:
-        """Prefill `prompt_ids` into row `row` of the live batched
-        cache, ending at the current decode position.  The prompt is
-        clipped to the most recent `pos` tokens when longer (a joiner
-        cannot reach behind the batch's shared position).  Updates
-        self._start for the row; returns the row's last-token logits
-        (V,) for sampling its first output token."""
-        if self._cache is None or getattr(self, "_start", None) is None:
-            raise RuntimeError("prefill_batch first")
-        P = len(prompt_ids)
-        if P == 0:
-            raise ValueError("empty prompt")
-        # the pad width must come from the FIXED bucket set (one join
-        # program per bucket, like every other program here) and fit
-        # below the current position; pos starts at a bucket, so at
-        # least the smallest bucket always fits
-        fit = [bb for bb in self.buckets if bb <= self._pos]
-        b = next((bb for bb in fit if bb >= P), fit[-1])
-        if P > b:
-            prompt_ids = prompt_ids[-b:]      # keep recent context
-            P = b
-        ids = np.zeros((1, b), np.int32)
-        ids[0, b - P:] = prompt_ids[-P:]
-        start_row = np.int32(self._pos - P)
-        self._cache, logits = self._join_program(b)(
-            self.params, self._cache, jnp.asarray(ids),
-            jnp.int32(row), jnp.int32(self._pos), jnp.asarray(start_row))
-        start = np.array(self._start)             # writable copy
-        start[row] = self._pos - P
-        self._start = jnp.asarray(start)
-        return np.asarray(logits)
-
-    def join_budget(self) -> int:
-        """Largest prompt length a joiner can bring into the live
-        batch without losing context: the widest bucket at or below
-        the current decode position."""
-        if self._cache is None:
-            return 0
-        return max((b for b in self.buckets if b <= self._pos),
-                   default=0)
-
     # -- paged serving (the continuous-batching path) ---------------------
     #
     # The dense batched path above shares ONE window across the batch:
-    # prefill parks every row at the same bucket position, joiners can
-    # only reach back join_budget() tokens, and the cache resets when
-    # every slot frees.  The paged path drops all of that: each row
+    # prefill parks every row at the same bucket position and the cache
+    # resets when every slot frees.  The paged path drops that: each row
     # has its own logical positions 0..len-1 in pages of a global pool
     # (PagedKVCache), a joiner prefills into freshly allocated pages
     # at ANY time with its full context, and a finished row's pages
@@ -1831,9 +1795,8 @@ class CompletionModel:
                           prompt_ids: np.ndarray, row: int) -> np.ndarray:
         """Prefill one row's prompt into its pages: bucketed dense
         prefill over a (1, bucket) scratch cache, then the commit
-        scatter.  Unlike join_row there is no clipping to a shared
-        position — the row keeps its FULL prompt (callers clip only
-        to the window budget).  Returns the last real token's logits
+        scatter.  The row keeps its FULL prompt (callers clip only to
+        the window budget).  Returns the last real token's logits
         (V,) for sampling the first output token."""
         cfg = self.cfg
         P = len(prompt_ids)
@@ -2596,13 +2559,12 @@ class CompletionModel:
 
     def compile_count(self) -> int:
         """Distinct XLA programs compiled across every program cache
-        (trunk, chunk/join/paged dispatch tables) — the obs surface
+        (trunk, chunk/paged dispatch tables) — the obs surface
         the encoder already publishes: a count still growing after
         warmup means some serving geometry escapes the bucket set and
         pays jit compiles on the wake path.  -1 when the private jax
         cache API is unavailable."""
         fns = ([self._fn] + list(self._chunk_progs.values())
-               + list(self._join_progs.values())
                + list(self._paged_progs.values()))
         total = 0
         for f in fns:

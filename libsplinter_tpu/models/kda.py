@@ -444,21 +444,15 @@ class StateSlotPrograms:
 
     needs_state = True
     snap_granule = 1
-    # a family whose rows program (below) leaves each row's snapshot
-    # says True, and a join that leaves one then rides its round
-    join_snapshots = False
-
-    def join_rungs(self, cache: PagedKVCache) -> tuple[int, ...]:
-        """One row a suffix program, until the family's recurrence has
-        a row axis (ROADMAP.md A1): an admission round then joins a
-        request at a time.  A family that has one (models/lfm2.py)
-        answers with its row counts and brings
-        `paged_append_prefill_rows(cache, joins, snaps)` — every row's
-        state restored into its slot BEFORE the call (state_restore, a
-        dispatch a row at its seat), each row's end state and snapshot
-        written by the program, the spare slot for a row that leaves
-        none — and `join_snapshots = True`."""
-        return (1,)
+    # one row a suffix program, until the family's recurrence has a row
+    # axis (ROADMAP.md A1): an admission round then joins a request at
+    # a time.  A family that has one (models/lfm2.py) names its rows
+    # and brings `paged_append_prefill_rows(cache, joins, snaps)` —
+    # every row's state restored into its slot BEFORE the call
+    # (state_restore, a dispatch a row at its seat), each row's end
+    # state and snapshot written by the program, the spare slot for a
+    # row that leaves none
+    JOIN_ROWS = ()
 
     def _state_program(self, short: str, body):
         def build():

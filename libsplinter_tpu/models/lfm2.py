@@ -91,16 +91,6 @@ KINDS = ("conv", "full")
 # pages of the widest suffix program: a tool result or a user turn of a
 # few hundred tokens fits one call, a cold prompt loops in it
 SUFFIX_PAGES = 4
-# rows of the row-batched suffix program (join_rungs): ONE rung beside
-# the one-row programs.  32 is where a round's expert products turn
-# compute-bound (~500 real tokens an expert: more rows a program save a
-# host round trip and no weight read) and leaves the chip room — its
-# temporaries at the benchmark's widths are 1.06 GB beside 12.79 GB of
-# arguments, 82% of a v5e (tests/test_chip_compile.py).  No rung
-# between: a program is 20-30 s of a cold start that has none to spare
-# (PERF.md section 6, PR 41), and in 96 sessions' traffic a round is
-# one row (the first back) or the rung's fill
-JOIN_ROWS = 32
 # LIVE tokens of a round's rows one pass over the experts takes
 # (moe.sparse_moe live_chunk): a round's rows are 512 token slots each
 # and about a third of them real; in chunks of 2,048 SLOTS the 32-row
@@ -605,15 +595,18 @@ class ConvCompletionModel(StateSlotPrograms, GroupPagePrograms,
 
     # -- an admission round's hits in one program -----------------------------
 
-    # the rows program leaves each row's snapshot itself: a round takes
-    # a join whether or not it leaves one (completer.fill_rows)
-    join_snapshots = True
-
-    def join_rungs(self, cache: PagedKVCache) -> tuple[int, ...]:
-        """The row counts the suffix programs come in, ascending
-        (mla.join_rungs): 1 and JOIN_ROWS, or the lane's batch where
-        that is less."""
-        return tuple(sorted({1, min(JOIN_ROWS, cache.batch)}))
+    # rows of the row-batched suffix program (join_rungs): ONE rung
+    # beside the one-row programs, and it leaves each row's snapshot
+    # itself, so a round takes a join whether or not it leaves one.  32
+    # is where a round's expert products turn compute-bound (~500 real
+    # tokens an expert: more rows a program save a host round trip and
+    # no weight read) and leaves the chip room — its temporaries at the
+    # benchmark's widths are 1.06 GB beside 12.79 GB of arguments, 82%
+    # of a v5e (tests/test_chip_compile.py).  No rung between: a
+    # program is 20-30 s of a cold start that has none to spare
+    # (PERF.md section 6, PR 41), and in 96 sessions' traffic a round
+    # is one row (the first back) or the rung's fill
+    JOIN_ROWS = (32,)
 
     def _suffix_rows_program(self, rows: int, sb: int):
         cfg, interp = self.cfg, self.interpret
@@ -646,13 +639,6 @@ class ConvCompletionModel(StateSlotPrograms, GroupPagePrograms,
         and rows that leave no snapshot write the spare slot.  Returns
         (logits on the device, first tokens on the host)."""
         snaps = snaps or [None] * len(joins)
-        if len(joins) == 1:
-            (row, suffix), snap = joins[0], snaps[0]
-            logits = self.paged_append_prefill(
-                cache, suffix, row, **({"snap_at": snap[1],
-                                        "snap_slot": snap[0]}
-                                       if snap else {}))
-            return logits[None], np.array([self.sample(logits)], np.int32)
         ids, n_valid, tables, lengths = self._round_inputs(cache, joins)
         rows, sb = ids.shape
         n_snap = np.zeros((rows,), np.int32)

@@ -81,7 +81,7 @@ import numpy as np
 
 from ..obs.devtime import DEVTIME, close_mark
 from .decoder import (PageLayout, PagedKVCache, PendingChunk,
-                      _sample_rows, sample_top_p)
+                      _sample_rows, join_one, sample_top_p)
 from .encoder import _apply_rotary, _rotary_angles_at
 from ..ops.latent_attention import latent_append, latent_paged_attention
 from .moe import sparse_moe
@@ -1177,22 +1177,58 @@ class LatentCompletionModel:
         close_mark(mark)
         return out
 
+    # -- an admission round (the lane's ONE call: `join`) ------------------
+
+    # row counts of the family's row-batched suffix programs beside the
+    # one-row program, each at most the lane's batch (None: the batch):
+    # the batch, and 8 between for the few rows that come back out of
+    # step with a batch (at the batch's rung their round would push a
+    # whole batch's pad tokens through the dense layers)
+    JOIN_ROWS: tuple = (8, None)
+
     def join_rungs(self, cache: PagedKVCache) -> tuple[int, ...]:
         """The row counts this model's suffix programs come in,
-        ascending: 1 (paged_append_prefill), the lane's batch, and 8
-        between them for the few rows that come back out of step with
-        a batch (at the batch's rung their round would push a whole
-        batch's pad tokens through the dense layers).  An admission
-        round's hits ride the smallest rung that holds them
-        (paged_append_prefill_rows); a model whose suffix program has
-        no row axis answers (1,) and is joined a request at a time."""
-        return tuple(sorted({1, min(8, cache.batch), cache.batch}))
+        ascending: 1 (paged_append_prefill) and the family's JOIN_ROWS.
+        A round's joins ride the smallest rung that holds them
+        (paged_append_prefill_rows); a family whose suffix program has
+        no row axis has none, answers (1,) and is joined a request at
+        a time."""
+        return tuple(sorted({1, *(min(r or cache.batch, cache.batch)
+                                  for r in self.JOIN_ROWS)}))
 
     @property
     def join_width(self) -> int:
         """Tokens a row of the row-batched suffix programs holds: a
         hit whose suffix is longer is a round of one."""
         return self.suffix_buckets[-1]
+
+    def round_cap(self, cache: PagedKVCache) -> int:
+        """Joins one round's program holds: the widest rung."""
+        return self.join_rungs(cache)[-1]
+
+    def rides_round(self, join) -> bool:
+        """Whether `join` waits for its round's other joins: a prefix
+        hit whose suffix the rows program holds.  A miss, and a hit
+        wider than that, is a round of one, served where it is seated.
+        (A family with state slots whose rows program could not leave
+        a join's snapshot would refuse `join.snap` here.)"""
+        return join.hit and len(join.ids) - join.match <= self.join_width
+
+    def join(self, cache: PagedKVCache, joins):
+        """Prefill the seated rows of ONE admission round
+        (engine/prefix_cache.py `Join`s, at most round_cap of them, all
+        of which ride unless there is one): one join runs the one-row
+        program (decoder.join_one) and hands back (logits (V,) on the
+        host, None) — the lane draws; several run the rows program in
+        one dispatch and hand back (logits on the device, a row a
+        join, the first tokens drawn in graph)."""
+        if len(joins) == 1:
+            return join_one(self, cache, joins[0]), None
+        snaps = [j.snap for j in joins]
+        return self.paged_append_prefill_rows(
+            cache, [(j.row, np.asarray(j.ids[j.match:], np.int32))
+                    for j in joins],
+            *([snaps] if any(snaps) else ()))
 
     def _suffix_rows_program(self, rows: int, sb: int):
         cfg, interp = self.cfg, self.interpret
@@ -1255,12 +1291,7 @@ class LatentCompletionModel:
         token in graph with the decode chunk's sampler.  Returns
         (logits — a device array whose row i is joins[i]'s last real
         token's (V,) float32 —, first tokens (len(joins),) on the
-        host).  One join runs the one-row program and the host's
-        draw."""
-        if len(joins) == 1:
-            row, suffix = joins[0]
-            logits = self.paged_append_prefill(cache, suffix, row)
-            return logits[None], np.array([self.sample(logits)], np.int32)
+        host)."""
         ids, n_valid, tables, lengths = self._round_inputs(cache, joins)
         rows, sb = ids.shape
         self._rng, sub = jax.random.split(self._rng)

@@ -71,7 +71,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..obs.devtime import DEVTIME
-from .decoder import CompletionModel, Decoder, _nucleus_logits
+from .decoder import (CompletionModel, Decoder, RowJoins,
+                      _nucleus_logits)
 
 
 def _filtered_probs(logits, top_p: float, temp: float):
@@ -222,6 +223,20 @@ class SpecPagedCache:
     def quantized(self) -> bool:
         return self.target.quantized
 
+    # paired pools attach no prefix tree, and the key/value decoder has
+    # neither a window group nor state slots: the target's answers
+    @property
+    def prefix_cache(self):
+        return self.target.prefix_cache
+
+    @property
+    def window(self):
+        return self.target.window
+
+    @property
+    def needs_state(self) -> bool:
+        return self.target.needs_state
+
     @property
     def packed(self) -> bool:
         return self.target.packed
@@ -286,7 +301,7 @@ class SpecPagedCache:
                      3)
 
 
-class SpeculativeCompletionModel:
+class SpeculativeCompletionModel(RowJoins):
     """generate_tokens-compatible front end over (target, draft) —
     AND a paged continuous-batching model (the CompletionModel paged
     surface) when both halves support it: the completion daemon's

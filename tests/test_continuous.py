@@ -1,6 +1,6 @@
-"""Continuous batched serving (completer.run_continuous +
-decoder.join_row): requests join the live batch at chunk boundaries,
-finished rows free their slots, and outputs stay token-exact.
+"""Continuous batched serving (completer.run_continuous): requests
+join the live batch at chunk boundaries, finished rows free their
+slots, and outputs stay token-exact.
 """
 from __future__ import annotations
 
@@ -8,58 +8,11 @@ import threading
 import time
 
 import jax.numpy as jnp
-import numpy as np
-import pytest
 
 from libsplinter_tpu import Store
 from libsplinter_tpu.engine import protocol as P
 from libsplinter_tpu.engine.completer import Completer
 from libsplinter_tpu.models.decoder import CompletionModel, DecoderConfig
-
-
-def test_join_row_token_exact():
-    """A row joining mid-decode produces exactly its serial tokens and
-    does not perturb the already-running row."""
-    m = CompletionModel(DecoderConfig.tiny(dtype=jnp.float32),
-                        buckets=(16, 32), temp=0.0)
-    A = np.arange(1, 8, dtype=np.int32)
-    Bp = np.array([9, 2, 6], np.int32)
-    sa = [int(x) for x in m.generate_tokens(A, 16, chunk=4)]
-    m.reset()
-    sb = [int(x) for x in m.generate_tokens(Bp, 10, chunk=4)]
-    m.reset()
-
-    logits = m.prefill_batch([A, np.array([1], np.int32)])
-    toks = np.array([int(np.argmax(logits[0])), 0], np.int32)
-    out_a = [int(toks[0])]
-    blk = m.decode_chunk_batch(toks, 6)
-    out_a += [int(x) for x in blk[0]]
-    jl = m.join_row(Bp, row=1)
-    tok_b = int(np.argmax(jl))
-    out_b = [tok_b]
-    toks = np.array([int(blk[0][-1]), tok_b], np.int32)
-    for _ in range(3):
-        blk = m.decode_chunk_batch(toks, 3)
-        out_a += [int(x) for x in blk[0]]
-        out_b += [int(x) for x in blk[1]]
-        toks = blk[:, -1].astype(np.int32)
-    m.reset()
-    assert out_a[:16] == sa[:16]
-    assert out_b[:10] == sb[:10]
-
-
-def test_join_row_clips_to_position():
-    """A joiner whose prompt is longer than the batch position keeps
-    only the most recent context instead of reaching behind pos."""
-    m = CompletionModel(DecoderConfig.tiny(dtype=jnp.float32),
-                        buckets=(16,), temp=0.0)
-    m.prefill_batch([np.array([1, 2, 3], np.int32),
-                     np.array([1], np.int32)])    # pos = 16
-    long_prompt = np.arange(1, 40, dtype=np.int32) % 900 + 1
-    logits = m.join_row(long_prompt, row=1)
-    assert np.isfinite(np.asarray(logits)).all()
-    assert int(np.asarray(m._start)[1]) == 0      # 16 recent tokens kept
-    m.reset()
 
 
 def test_continuous_serves_staggered_arrivals(tmp_path):
@@ -112,15 +65,15 @@ def test_continuous_serves_staggered_arrivals(tmp_path):
 
 
 def test_continuous_defers_oversized_joiner(tmp_path):
-    """A prompt longer than the live batch's join budget must NOT be
-    clipped into the running batch — it waits for a fresh batch and
-    then completes with its full context."""
+    """A prompt longer than a dense live batch's position must NOT be
+    clipped into the running batch — it completes with its full
+    context."""
     name = f"/spt-defer-{tmp_path.name}"
     Store.unlink(name)
     st = Store.create(name, nslots=128, max_val=4096, vec_dim=8)
     try:
-        # window 128, buckets (16, 64): a fresh short batch sits at
-        # pos=16, so a ~40-token joiner exceeds join_budget()=16
+        # window 128, buckets (16, 64): a dense short batch would sit
+        # at pos=16, behind which a ~40-token joiner cannot reach
         model = CompletionModel(DecoderConfig.tiny(max_len=128),
                                 buckets=(16, 64), temp=0.0)
         comp = Completer(st, model=model, max_new_tokens=30,
@@ -160,7 +113,7 @@ def test_continuous_defers_oversized_joiner(tmp_path):
 
 def test_continuous_over_quantized_model(tmp_path):
     """Feature lattice: the slot scheduler serves an int8-resident
-    model (join_row included) with the full protocol."""
+    model (its joins included) with the full protocol."""
     name = f"/spt-contq-{tmp_path.name}"
     Store.unlink(name)
     st = Store.create(name, nslots=64, max_val=2048, vec_dim=8)
@@ -199,7 +152,7 @@ def test_continuous_over_quantized_model(tmp_path):
 
 
 def test_continuous_falls_back_for_serial_models(tmp_path):
-    """Models without join_row (speculative) serve through run()."""
+    """Models without the paged surface serve through run()."""
     from libsplinter_tpu.models import SpeculativeCompletionModel
 
     name = f"/spt-contfb-{tmp_path.name}"

@@ -432,6 +432,16 @@ class TestSearcherWindows:
         store = store_2k
         sr, vecs = self._searcher(store)
         prog, _ = self._program(sr)
+
+        def topk_progs():
+            return [p for p in DEVTIME._progs.values()
+                    if p.lane == "searcher" and "topk" in p.short]
+        # the registry is the PROCESS's: a file that calls a top-k
+        # program directly (tests/test_fused_topk.py: no collect point
+        # takes the mark) leaves one open on this worker, and on the
+        # CPU this searcher never dispatches the fused program that
+        # would take it over.  Those are not this searcher's.
+        stale = [p.last_mark for p in topk_progs()]
         for site in ("searcher.dispatch", "searcher.select"):
             n0 = prog.hist.n
             faults.arm(f"{site}:raise@1")
@@ -442,8 +452,8 @@ class TestSearcherWindows:
                 faults.disarm()
             assert sr.stats.retried_unfused >= 1
             assert all(p.last_mark is None
-                       for p in DEVTIME._progs.values()
-                       if p.lane == "searcher" and "topk" in p.short)
+                       or any(p.last_mark is m for m in stale)
+                       for p in topk_progs())
             # dispatch failed: the retry's window only; fetch failed:
             # the batch's own window and the retry's
             assert prog.hist.n - n0 == (1 if "dispatch" in site else 2)

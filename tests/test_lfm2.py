@@ -27,7 +27,7 @@ import reference_lfm2 as R
 from libsplinter_tpu import Store
 from libsplinter_tpu.engine import completer as C
 from libsplinter_tpu.engine.client import submit_completion
-from libsplinter_tpu.engine.prefix_cache import PrefixCache
+from libsplinter_tpu.engine.prefix_cache import Join, PrefixCache
 from libsplinter_tpu.models import lfm2, mla
 from libsplinter_tpu.models.decoder import PagedKVCache
 from libsplinter_tpu.models.moe import (router_bias_swaps, router_gates,
@@ -648,12 +648,21 @@ def test_a_round_in_one_program_is_its_joins_one_by_one(model, name):
 
 def test_a_round_of_one_is_the_one_row_program_and_bad_rows_are_refused(
         model):
+    """`join` decides it: one join runs the one-row program and leaves
+    the draw to the lane; the rows program refuses what it cannot
+    hold."""
     m, cache, rows, snaps = _seated(model, 6, _JOINS[1:2], False)
     want_m, want_c, *_ = _seated(model, 6, _JOINS[1:2], False)
-    logits, firsts = m.paged_append_prefill_rows(cache, rows, snaps)
+    (row, suffix), match = rows[0], int(cache.lengths[0])
+    one = Join(row, np.concatenate([np.zeros(match, np.int32), suffix]),
+               match, True, snaps[0])
+    assert m.rides_round(one) and m.round_cap(cache) == 6
+    logits, firsts = m.join(cache, [one])
     np.testing.assert_array_equal(
-        logits, _one_by_one(want_m, want_c, rows, snaps))
-    assert firsts.tolist() == [int(np.argmax(logits[0]))]
+        logits[None], _one_by_one(want_m, want_c, rows, snaps))
+    assert firsts is None
+    assert not any(k[0] == "suffix" and len(k) > 2
+                   for k in m._paged_progs)
     for (got,), (ref,) in zip(cache.states, want_c.states):
         np.testing.assert_array_equal(got[snaps[0][0]], ref[snaps[0][0]])
     m, cache, rows, snaps = _seated(model, 6, _JOINS[:2], False)

@@ -38,7 +38,7 @@ import pytest
 from libsplinter_tpu import Store
 from libsplinter_tpu.engine import completer as C
 from libsplinter_tpu.engine.client import submit_completion
-from libsplinter_tpu.engine.prefix_cache import PrefixCache
+from libsplinter_tpu.engine.prefix_cache import Join, PrefixCache
 from libsplinter_tpu.models import afmoe, mla
 from libsplinter_tpu.models.moe import sparse_moe
 from libsplinter_tpu.ops.paged_attention import (
@@ -855,17 +855,27 @@ def test_a_round_in_one_program_is_its_joins_one_by_one(round_model,
 
 def test_a_round_of_one_is_the_one_row_program_and_bad_rows_are_refused(
         round_model):
+    """`join` decides it: one join runs the one-row program and leaves
+    the draw to the lane; a suffix wider than the rows program does
+    not ride a round, and the rows program refuses it."""
     m, cache, rows = _seated_round(round_model, ROUND, 4)
     want_m, want_c, _ = _seated_round(round_model, ROUND, 4)
-    logits, firsts = m.paged_append_prefill_rows(cache, rows[1:2])
+
+    def hit(row, suffix):
+        match = int(cache.lengths[row])
+        return Join(row, np.concatenate(
+            [np.zeros(match, np.int32), suffix]), match, True)
+    logits, firsts = m.join(cache, [hit(*rows[1])])
     np.testing.assert_array_equal(
-        logits[0], want_m.paged_append_prefill(want_c, *rows[1][::-1]))
-    assert firsts.tolist() == [int(np.argmax(logits[0]))]
+        logits, want_m.paged_append_prefill(want_c, *rows[1][::-1]))
+    assert firsts is None and m.round_cap(cache) == 4
     assert not any(k[0] == "suffix" and len(k) > 2
                    for k in m._paged_progs)
     # a suffix wider than the rows program: the completer serves it as
     # a round of one, a piece at a time
     wide = np.ones((PAGE + 1,), np.int32)
+    assert m.rides_round(hit(*rows[0])) \
+        and not m.rides_round(hit(2, wide))
     with pytest.raises(ValueError, match=f"{PAGE + 1} tokens in a "
                                          f"{PAGE}-token program"):
         m.paged_append_prefill_rows(cache, [rows[0], (2, wide)])

@@ -23,6 +23,7 @@ import reference_mla as R
 from libsplinter_tpu import Store
 from libsplinter_tpu.engine import completer as C
 from libsplinter_tpu.engine.client import submit_completion
+from libsplinter_tpu.engine.prefix_cache import Join
 from libsplinter_tpu.models import mla
 from libsplinter_tpu.models.decoder import PagedKVCache
 from libsplinter_tpu.models.moe import (grouped_matmul, router_gates,
@@ -147,8 +148,9 @@ def _seat_hits(model, spans):
         "full-rung-of-8"])
 def test_row_batched_suffix_prefill_is_the_one_row_programs(
         model, ref_logits, spans, interpret):
-    """An admission round's hits in ONE dispatch of the suffix program
-    (ragged suffix lengths, the rung's other rows dead) against the
+    """An admission round's hits through `join` — several in ONE
+    dispatch of the suffix program
+    (ragged suffix lengths, the rung's other rows dead) — against the
     one-row program a request at a time: the same logits, the same
     latents on every live page, and no other write but the trash
     block's; the first tokens are drawn in graph (greedy here)."""
@@ -159,8 +161,13 @@ def test_row_batched_suffix_prefill_is_the_one_row_programs(
     rows, _ = _seat_hits(m, spans)
     np.testing.assert_array_equal(one.tables, rows.tables)
     before = [np.asarray(p) for p in rows.pools[0]]
-    logits, toks = m.paged_append_prefill_rows(rows, joins)
+    logits, toks = m.join(rows, [Join(r, IDS[:b], a, True)
+                                 for r, (a, b) in enumerate(spans)])
     logits = np.asarray(logits)
+    if len(spans) == 1:
+        # a round of one: the one-row program, the draw left to the lane
+        assert toks is None and logits.shape == want[0].shape
+        logits, toks = logits[None], logits.argmax(-1)[None]
     assert toks.shape == (len(spans),)
     assert logits.shape[0] == (1 if len(spans) == 1 else 8)
     for i, (a, b) in enumerate(spans):

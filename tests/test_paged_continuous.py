@@ -385,7 +385,7 @@ def test_paged_continuous_churn_no_leak(tmp_path):
 
 
 # ---- admission rounds (run_continuous.fill_rows / join_round) over a
-# model whose suffix program has a row axis (models/mla.py join_rungs)
+# model whose suffix program has a row axis (models/mla.py join)
 
 _DOC = "the quick brown fox jumps over the lazy "   # + BOS: 41 tokens
 
@@ -675,8 +675,113 @@ def test_backpressure_inside_a_round_leaves_the_denied_request_waiting(
         assert s.completions == 4 and s.faults == 0
 
 
+def test_a_sampling_lane_draws_in_todays_order(tmp_path):
+    """temp > 0 and a fixed seed: a burst that makes one round of
+    several and one round of one emits the tokens that a same-seed
+    model emits when it is driven BY HAND through today's call sequence
+    in the lane's order — each request seated (Seat: walk, plan, map),
+    a round of several through paged_append_prefill_rows (its first
+    tokens drawn in graph), a round of one through paged_prefill_row /
+    paged_append_prefill and the host's `sample`, the tree's insert,
+    the decode chunks.  `join` and the seat consume the model's key in
+    that order and no other."""
+    from libsplinter_tpu.engine.prefix_cache import PrefixCache, Seat
+    from libsplinter_tpu.models import mla
+
+    cfg = mla.LatentMoeConfig.tiny(dtype=jnp.float32, experts_first=2,
+                                   experts_held=4)
+    lane_m, hand_m = (mla.LatentCompletionModel(cfg, seed=3, temp=0.7)
+                      for _ in range(2))
+    events = []
+
+    def record(obj, name, what):
+        inner = getattr(obj, name)
+
+        def wrapped(*a, **k):
+            events.append(what(*a, **k))
+            return inner(*a, **k)
+        setattr(obj, name, wrapped)
+
+    prompts = {"q/0": _DOC + "who?", "q/1": _DOC + "what now?",
+               "wide": _DOC + "w" * 60, "d": _DOC}
+    with _RoundLane(tmp_path, lane_m, "draws") as ln:
+        for name, what in (
+                ("paged_prefill_row", lambda c, ids, row, **k: ("one", row)),
+                ("paged_append_prefill",
+                 lambda c, ids, row, **k: ("one", row)),
+                ("paged_append_prefill_rows",
+                 lambda c, joins: ("rows", [r for r, _ in joins])),
+                ("sample", lambda logits: ("sample",)),
+                ("paged_decode_chunk_async",
+                 lambda c, toks, n, carry=None:
+                 ("chunk", np.array(toks), n, carry is None))):
+            record(lane_m, name, what)
+        record(ln.comp.prefix_cache, "insert",
+               lambda ids, c, row, *a, **k: ("insert", row, list(ids)))
+        record(ln.comp._paged_cache, "free_row", lambda row: ("free", row))
+        got, _ = ln.burst({"d": prompts["d"]})
+        more, _ = ln.burst({k: prompts[k] for k in ("q/0", "q/1", "wide")})
+        got.update(more)
+        tok = ln.comp._tok
+    assert [len(e[1]) for e in events if e[0] == "rows"] == [2]
+    assert sum(e[0] == "one" for e in events) == 2   # the document, `wide`
+
+    cache = hand_m.init_paged(6, page=16, pool_pages=40)
+    pc = PrefixCache(16)
+    pc.attach(cache)
+    cache.prefix_cache = pc
+    key_of = {tuple(tok.encode(p)): k for k, p in prompts.items()}
+    seated, tokens, logits, last = {}, {}, None, None
+
+    def seat(row, at):
+        """Seat in `row` the prompt whose insert follows event `at`."""
+        ids = next(e[2] for e in events[at:]
+                   if e[0] == "insert" and e[1] == row)
+        st = Seat(cache, ids)
+        st.walk()
+        # max_new 4 in chunks of 4: one chunk past the prompt
+        assert st.plan(len(ids) + 4, len(ids) + 8) is None and st.map(row)
+        seated[row] = key_of[tuple(ids)]
+        return ids, st
+
+    for at, e in enumerate(events):
+        if e[0] == "one":
+            ids, st = seat(e[1], at)
+            logits = (hand_m.paged_append_prefill(
+                cache, np.asarray(st.suffix, np.int32), e[1])
+                if st.hit_bids else hand_m.paged_prefill_row(
+                    cache, np.asarray(ids, np.int32), e[1]))
+            row = e[1]
+        elif e[0] == "rows":
+            joins = [(r, np.asarray(seat(r, at)[1].suffix, np.int32))
+                     for r in e[1]]
+            _, firsts = hand_m.paged_append_prefill_rows(cache, joins)
+            for r, t in zip(e[1], firsts):
+                tokens[seated[r]] = [int(t)]
+        elif e[0] == "sample":
+            tokens[seated[row]] = [hand_m.sample(logits)]
+        elif e[0] == "insert":
+            pc.insert(e[2], cache, e[1], 0)
+        elif e[0] == "chunk":
+            pend = hand_m.paged_decode_chunk_async(
+                cache, e[1], e[2], carry=None if e[3] else last)
+            out, last = pend.block(), pend.last
+            for r, k in seated.items():
+                tokens[k] += [int(t) for t in out[r]]
+        else:
+            seated.pop(e[1], None)
+            cache.free_row(e[1])
+    for k, p in prompts.items():
+        want = p.encode()
+        for t in tokens[k][:4]:
+            if t == tok.eos_id:
+                break
+            want += tok.token_to_piece(t)
+        assert got[k] == want.rstrip(b"\0"), k
+
+
 # ---- the same rounds over a model WITH STATE SLOTS whose rows program
-# leaves the snapshots (models/lfm2.py join_snapshots)
+# leaves the snapshots (models/lfm2.py)
 
 @pytest.fixture(scope="module")
 def conv_models():
@@ -687,8 +792,6 @@ def conv_models():
     from libsplinter_tpu.models import lfm2
 
     class OneRow(lfm2.ConvCompletionModel):
-        join_snapshots = False
-
         def join_rungs(self, cache):
             return (1,)
 

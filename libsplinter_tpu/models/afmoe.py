@@ -7,10 +7,11 @@ feed-forward and its chunk hand-off).  The block is a DESCRIPTION A
 KIND of layer (`AttnKind`: key/value heads, key and value widths,
 rotated dims and their base, window, sink) plus the block's own
 switches (`out_gate`, `qk_norm`, `sandwich_norm`, `mup`,
-`value_scale`, `n_shared_experts`), and two public families are
-settings of it: AFMoE (Trinity-Mini; the equations just below) and
-MiMo-V2-Flash (further down).  Every switch is static: a setting
-compiles the operations it names and no others.
+`value_scale`, `n_shared_experts`, `indexer`), and three public
+families are settings of it: AFMoE (Trinity-Mini; the equations just
+below), MiMo-V2-Flash and Keye-VL-2.0's language block (further down).
+Every switch is static: a setting compiles the operations it names and
+no others.
 
 AFMoE's layer (x: hidden; matrices without bias; RMSNorm eps
 `rms_norm_eps`; four norms a layer):
@@ -46,6 +47,32 @@ q/k norm, no muP, no shared expert; eps `layernorm_epsilon`):
                  (exp(b_h) + sum_j exp(s_ij)), b_h learned, one a head
                  — a key with no value (ops/paged_attention)
              Attn = concat_h(sum_j p_ij v_j) W_O        (heads x dv -> H)
+
+INDEXER (Keye-VL-2.0's language block, `mla.FAMILIES["KeyeVL2"]`: the
+"lightning indexer" of the DeepSeek-V3.2-Exp report with `sa_config`'s
+sizes).  Every layer global, MiMo's plain pre-norm, q/k RMSNorm a head
+and RoPE on the whole head; in front of the attention, from the same
+normed input u:
+
+    qI = u W_qI (indexer heads x dim);  kI = LayerNorm(u W_kI) (ONE
+         head of dim: scale ki_norm, bias ki_bias);  both rotated like
+         q and k;  w = u W_w / sqrt(heads x dim)
+    I[t, s] = sum_j w[t, j] ReLU(qI[t, j] . kI[s])            s <= t
+    S_t = the `topk` positions of largest I[t, .] — every s <= t while
+          t < topk; of equal scores the lower position first
+    o_h = softmax over s in S_t of (q_h . k / sqrt(d)) v
+
+one selection a token a layer, shared by all heads.  The indexer's key
+is what a token leaves behind BESIDE its K and V: the global group's
+page holds a third pool, `ik` (n_blocks, L, 1, dim, page) — a token a
+column —, under the same table entry, so allocation, a prefix hit's
+mapping, copy-on-write and eviction move all three and nothing is ever
+re-indexed.  ops/sparse_attention.py holds the three device stages
+(scan, exact selection, attention over the selected keys) and the
+DENSE path a row takes while its last token sees `topk` keys or fewer:
+window_paged_attention itself, bit for bit the layer without an
+indexer.  There is no window group; the serving class is
+IndexedCompletionModel.
 
 PAGES IN TWO GROUPS.  The cache holds K (after its norm and its
 rotation, where it has them) and V a token a layer, each in a pool of
@@ -111,6 +138,29 @@ recipe) and the embedding has std 1:
     than a value: 0.082 a window layer, 0.117 a global one at 32.9k
     keys; CPU arithmetic, PERF.md section 6), so that the attention
     branch writes at 2 s as AFMoE's does.
+Under an INDEXER (Keye) the same pre-norm recipe with three changes,
+each from CPU arithmetic at the published widths before any limit was
+read (PR 44; PERF.md section 6):
+    a norm behind w_q (q_norm) takes any gain w_q carries, so the gain
+    of 3 sits on q_norm's scale (3 +- 0.3) and w_q stays at 1/sqrt(H);
+    w_o: std INDEXED_ATTN_OUT x s x INDEXED_O_UNIT / sqrt(fan_in) —
+    INDEXED_O_UNIT 3.2, the inverse of a head's output std over 2,048
+    selected keys at scores of std 3 (0.32), and INDEXED_ATTN_OUT 0.25,
+    not AFMoE's 2: a seeded indexer is INDEPENDENT of the attention it
+    selects for, so a key that a bfloat16 rounding flips in or out at
+    the topk-th rank carries as much of a query's mass as any other
+    (a trained indexer ranks by that mass: its flips carry none), the
+    attention output moves by sqrt(2 x flips / topk) and the flips
+    follow the stream's own error — a square-root map whose fixed
+    point is 4 b^2 of the stream for a branch that writes at b.  At
+    2 s (b = 0.2) four layers read a median logit error of 1.03 of the
+    logits' spread against the float32 reference, at 0.5 s 0.086, at
+    0.25 s 0.041 (the activations' own roundings), while the wrong
+    selection reads 2.8 / 0.84 / 0.43: 0.25 keeps the fault ten times
+    the sound run;
+    w_qi, w_ki, w_wi at 1/sqrt(H), ki_norm 1 +- 0.1, ki_bias 0 +- 0.1:
+    I is scale-free (every score and every rounding error scales with
+    the three alike), so no scale widens its spread against bfloat16.
 """
 from __future__ import annotations
 
@@ -125,6 +175,7 @@ import numpy as np
 
 from ..obs.devtime import DEVTIME, close_mark
 from ..ops.paged_attention import kv_append, window_paged_attention
+from ..ops.sparse_attention import indexed_attention, write_pages
 from .decoder import PageLayout, PagedKVCache, _sample_rows
 from .encoder import _rotary_angles_at
 from .mla import (LatentCompletionModel, LatentPendingChunk, _ffn, _rms,
@@ -139,6 +190,10 @@ ATTN_OUT, MLP_OUT = 2.0, 0.5
 PRE_Q_GAIN = {"window": 1.0, "full": 3.0}
 PRE_O_UNIT = {"window": 12.0, "full": 8.5}
 SINK_RANGE = (2.0, 5.0)
+# under an indexer (module docstring, INDEXER): the inverse of a head's
+# output std over `topk` = 2,048 keys at scores of std 3, and the
+# attention branch's scale in units of 1/sqrt(2 x layers)
+INDEXED_O_UNIT, INDEXED_ATTN_OUT = 3.2, 0.25
 # identical layers in a row that `plan` puts under a scan as periods
 # of one: from four on (a cut stack whose pattern repeats nowhere still
 # compiles its run of window layers once)
@@ -166,6 +221,15 @@ class AttnKind:
     # for a key width that is no multiple of the 128-lane tile
     # (ops/paged_attention, "KEYS A TOKEN A COLUMN")
     k_cols: bool = False
+
+
+@dataclasses.dataclass(frozen=True)
+class Indexer:
+    """The learned selection in front of the GLOBAL layers' attention
+    (module docstring, INDEXER)."""
+    heads: int                    # indexer query heads
+    dim: int                      # an indexer head's width
+    topk: int                     # keys a token attends
 
 
 @dataclasses.dataclass(frozen=True)
@@ -205,6 +269,8 @@ class WindowMoeConfig:
     # ((kind, AttnKind), ...): None is AFMoE's — one kv_heads, one
     # head_dim, RoPE(rope_base) on the window layers' whole heads only
     attn_kinds: tuple | None = None
+    # None: every key a layer's mask lets through is attended
+    indexer: Indexer | None = None
 
     def __post_init__(self):
         object.__setattr__(self, "kinds", tuple(self.kinds))
@@ -232,6 +298,13 @@ class WindowMoeConfig:
                              "at least one full layer")
         if "window" in self.kinds and self.window < 1:
             raise ValueError("a window layer needs sliding_window >= 1")
+        if self.indexer is not None and (
+                "window" in self.kinds or self.indexer.dim % 2
+                or self.indexer.topk < 1 or self.attn("full").k_cols):
+            raise ValueError(
+                "an indexer selects among a GLOBAL layer's keys: every "
+                "layer full attention over row-major key pages, an even "
+                "indexer_head_dim (RoPE pairs) and topk >= 1")
         for kind in set(self.kinds):
             a = self.attn(kind)
             if self.heads % a.kv_heads or a.rotary_dim % 2 \
@@ -312,13 +385,20 @@ class WindowMoeConfig:
         in one page, and the window layers'."""
         out = []
         for kind in ("full", "window"):
-            n, a = self.kinds.count(kind), self.attn(kind)
+            n = self.kinds.count(kind)
             if n:
+                a = self.attn(kind)
+                # the indexer's keys ride the global group as a THIRD
+                # pool, one head a token a column: a table entry names
+                # a page of all three
+                ix = self.indexer if kind == "full" else None
                 out.append(PageLayout(
                     (("k", (n, a.kv_heads, a.qk_dim, page) if a.k_cols
                       else (n, a.kv_heads, page, a.qk_dim)),
-                     ("v", (n, a.kv_heads, page, a.v_dim))),
-                    token_values=n * a.kv_heads * (a.qk_dim + a.v_dim),
+                     ("v", (n, a.kv_heads, page, a.v_dim)),
+                     *((("ik", (n, 1, ix.dim, page)),) if ix else ())),
+                    token_values=n * (a.kv_heads * (a.qk_dim + a.v_dim)
+                                      + (ix.dim if ix else 0)),
                     window=a.window, layers=n))
         return tuple(out)
 
@@ -333,6 +413,9 @@ def _layer_params(cfg: WindowMoeConfig, seed: int, i: int) -> dict:
     # without a norm behind the branches the matrices that write into
     # the stream carry the scale (module docstring, WEIGHTS)
     pre = not cfg.sandwich_norm
+    # a norm behind w_q takes any gain w_q carries: there the q norm's
+    # scale carries it
+    q_gain = PRE_Q_GAIN[kind] if pre else 1.0
 
     def mat(name, shape, gain=1.0):
         return seed_tensor(seed, name, shape,
@@ -342,6 +425,7 @@ def _layer_params(cfg: WindowMoeConfig, seed: int, i: int) -> dict:
         return seed_tensor(seed, name, (n,), 0.1 * mean, jnp.float32,
                            mean)
 
+    ix = cfg.indexer if kind == "full" else None
     lp = {"ln_attn_in": norm(p + "ln_attn_in", H),
           "ln_mlp_in": norm(p + "ln_mlp_in", H),
           # kept TRANSPOSED, (out, hidden): the layout the chip's
@@ -349,20 +433,28 @@ def _layer_params(cfg: WindowMoeConfig, seed: int, i: int) -> dict:
           # it copied all three at every dispatch
           # (tests/test_chip_compile.py)
           "w_q": mat(p + "w_q", (H, cfg.heads * a.qk_dim),
-                     PRE_Q_GAIN[kind] if pre else 1.0).T,
+                     1.0 if cfg.qk_norm else q_gain).T,
           "w_k": mat(p + "w_k", (H, a.kv_heads * a.qk_dim)).T,
           "w_v": mat(p + "w_v", (H, a.kv_heads * a.v_dim)).T,
           "w_o": mat(p + "w_o", (cfg.heads * a.v_dim, H),
-                     ATTN_OUT * out_mean * PRE_O_UNIT[kind] if pre
-                     else 1.0)}
+                     out_mean * (INDEXED_ATTN_OUT * INDEXED_O_UNIT if ix
+                                 else ATTN_OUT * PRE_O_UNIT[kind])
+                     if pre else 1.0)}
     if cfg.sandwich_norm:
         lp["ln_attn_out"] = norm(p + "ln_attn_out", H, ATTN_OUT * out_mean)
         lp["ln_mlp_out"] = norm(p + "ln_mlp_out", H, MLP_OUT * out_mean)
     if cfg.out_gate:
         lp["w_g"] = mat(p + "w_g", (H, cfg.heads * a.v_dim))
     if cfg.qk_norm:
-        lp["q_norm"] = norm(p + "q_norm", a.qk_dim)
+        lp["q_norm"] = norm(p + "q_norm", a.qk_dim, q_gain)
         lp["k_norm"] = norm(p + "k_norm", a.qk_dim)
+    if ix:
+        lp["w_qi"] = mat(p + "w_qi", (H, ix.heads * ix.dim)).T
+        lp["w_ki"] = mat(p + "w_ki", (H, ix.dim))
+        lp["w_wi"] = mat(p + "w_wi", (H, ix.heads))
+        lp["ki_norm"] = norm(p + "ki_norm", ix.dim)
+        lp["ki_bias"] = seed_tensor(seed, p + "ki_bias", (ix.dim,), 0.1,
+                                    jnp.float32)
     if a.sink:
         lo, hi = SINK_RANGE
         lp["sink"] = seed_tensor(seed, p + "sink", (cfg.heads,),
@@ -465,14 +557,38 @@ def _layer(cfg: WindowMoeConfig, lp, kind: str, gl, x, pos, live, pools,
             _rotate(k.astype(f32), cos, sin)
     if cfg.value_scale != 1.0:
         v = v.astype(f32) * cfg.value_scale
-    kp, vp = pools[kind]
+    kp, vp, *ikp = pools[kind]
     kp = write[kind](kp, k.astype(kp.dtype), gl, ak.k_cols)
     vp = write[kind](vp, v.astype(vp.dtype), gl, False)
-    pools = {**pools, kind: (kp, vp)}
-    o = window_paged_attention(
-        q.astype(cfg.dtype), kp, vp, tables[kind], att_len, layer=gl,
-        window=ak.window, sinks=lp["sink"] if ak.sink else None,
-        k_cols=ak.k_cols, interpret=interpret)
+    if ikp:
+        # the indexer (module docstring, INDEXER): its queries, head
+        # weights and the token's ONE key, rotated like q and k, the
+        # key into the group's third pool
+        ix = cfg.indexer
+        qi = proj(lp["w_qi"], ix.heads, ix.dim).astype(f32)
+        ki = jnp.dot(xn, lp["w_ki"], preferred_element_type=f32)
+        ki = ki - jnp.mean(ki, -1, keepdims=True)
+        ki = (ki * jax.lax.rsqrt(jnp.mean(ki * ki, -1, keepdims=True)
+                                 + cfg.rms_eps) * lp["ki_norm"]
+              + lp["ki_bias"])[:, :, None]
+        icos, isin = _rotary_angles_at(pos.reshape(-1), ix.dim,
+                                       ak.rope_base)
+        icos, isin = icos.reshape(B, S, -1), isin.reshape(B, S, -1)
+        ikp = write[kind](ikp[0], _rotate(ki, icos, isin)
+                          .astype(ikp[0].dtype), gl, True)
+        wi = jnp.dot(xn, lp["w_wi"], preferred_element_type=f32) \
+            / math.sqrt(ix.heads * ix.dim)
+        pools = {**pools, kind: (kp, vp, ikp)}
+        o = indexed_attention(
+            q.astype(cfg.dtype), _rotate(qi, icos, isin).astype(cfg.dtype),
+            wi, kp, vp, ikp, tables[kind], att_len, live[:, 0], layer=gl,
+            topk=ix.topk, interpret=interpret)
+    else:
+        pools = {**pools, kind: (kp, vp)}
+        o = window_paged_attention(
+            q.astype(cfg.dtype), kp, vp, tables[kind], att_len, layer=gl,
+            window=ak.window, sinks=lp["sink"] if ak.sink else None,
+            k_cols=ak.k_cols, interpret=interpret)
     o = o.reshape(B, S, cfg.heads * ak.v_dim)
     if cfg.out_gate:
         gate = jax.nn.sigmoid(jnp.dot(xn, lp["w_g"]).astype(f32))
@@ -576,6 +692,16 @@ def forward_decode(cfg: WindowMoeConfig, params, toks, pools, tables,
     return x[:, 0], pools, slots
 
 
+def _set_pages(cfg: WindowMoeConfig, pool, bids, gl, pages,
+               interpret: bool):
+    """A suffix's whole pages into layer gl of a group's pool: an XLA
+    scatter, and under an indexer the page-write kernel
+    (ops/sparse_attention.write_pages has the reason)."""
+    if cfg.indexer is None:
+        return pool.at[bids, gl].set(pages)
+    return write_pages(pool, pages, bids, layer=gl, interpret=interpret)
+
+
 def forward_suffix(cfg: WindowMoeConfig, params, ids, pools, tables,
                    length, n_valid, *, interpret: bool = False):
     """S new tokens of ONE row atop the `length` tokens its tables map
@@ -595,9 +721,9 @@ def forward_suffix(cfg: WindowMoeConfig, params, ids, pools, tables,
 
         def put(pool, new, gl, cols, bids=bids):
             rows = new[0].reshape(n_p, page, *new.shape[2:])
-            return pool.at[bids, gl].set(
-                rows.transpose(0, 2, 3, 1) if cols
-                else rows.transpose(0, 2, 1, 3))
+            return _set_pages(cfg, pool, bids, gl,
+                              rows.transpose(0, 2, 3, 1) if cols
+                              else rows.transpose(0, 2, 1, 3), interpret)
         write[kind] = put
     x, pools, _ = _stack(cfg, params, _embed(cfg, params, ids), pos, ok,
                          pools, write, tables, pos[:, 0] + 1, interpret)
@@ -632,9 +758,9 @@ def forward_suffix_rows(cfg: WindowMoeConfig, params, ids, pools, tables,
 
         def put(pool, new, gl, cols, bids=bids):
             pages = new.reshape(R * n_p, page, *new.shape[2:])
-            return pool.at[bids, gl].set(
-                pages.transpose(0, 2, 3, 1) if cols
-                else pages.transpose(0, 2, 1, 3))
+            return _set_pages(cfg, pool, bids, gl,
+                              pages.transpose(0, 2, 3, 1) if cols
+                              else pages.transpose(0, 2, 1, 3), interpret)
         write[kind] = put
     x, pools, _ = _stack(cfg, params, _embed(cfg, params, ids), pos, ok,
                          pools, write, tables, pos[:, 0] + 1, interpret,
@@ -646,25 +772,27 @@ def forward_suffix_rows(cfg: WindowMoeConfig, params, ids, pools, tables,
 
 class GroupPagePrograms:
     """The buffers and the copy-on-write of a cache whose pages come
-    in GROUPS — one K and one V pool a group, every layer of the group
-    side by side in a page (PageLayout.layers): the global group
-    alone (models/lfm2.py), or the global group and a window group
-    beside it (this module)."""
+    in GROUPS — one K and one V pool a group (and, under an indexer,
+    its keys' pool beside them), every layer of the group side by
+    side in a page (PageLayout.layers): the global group alone
+    (models/lfm2.py), or the global group and a window group beside
+    it (this module).  Whatever pools a group's layout names travel
+    together: one table entry, one page copy."""
 
     @staticmethod
     def _pools(cache: PagedKVCache) -> dict:
-        out = {"full": (cache.pools[0][0], cache.pools[1][0])}
+        out = {"full": tuple(p[0] for p in cache.pools)}
         if cache.window is not None:
-            w = cache.window
-            out["window"] = (w.pools[0][0], w.pools[1][0])
+            out["window"] = tuple(p[0] for p in cache.window.pools)
         return out
 
     @staticmethod
     def _keep(cache: PagedKVCache, pools: dict) -> None:
-        (cache.pools[0][0], cache.pools[1][0]) = pools["full"]
+        for kept, new in zip(cache.pools, pools["full"]):
+            kept[0] = new
         if cache.window is not None:
-            w = cache.window
-            (w.pools[0][0], w.pools[1][0]) = pools["window"]
+            for kept, new in zip(cache.window.pools, pools["window"]):
+                kept[0] = new
 
     @staticmethod
     def _tables(cache: PagedKVCache, row: int | None = None) -> dict:
@@ -685,9 +813,10 @@ class GroupPagePrograms:
         return self._program(("cow",), "cow_copy", build, donate=(0,))
 
     def _copy_page(self, pools, src: int, dst: int) -> None:
-        """One page of a group, every layer of it, K and V."""
-        pools[0][0], pools[1][0] = self._cow_program()(
-            [pools[0][0], pools[1][0]], jnp.int32(src), jnp.int32(dst))
+        """One page of a group, every layer of it, every pool."""
+        for kept, new in zip(pools, self._cow_program()(
+                [p[0] for p in pools], jnp.int32(src), jnp.int32(dst))):
+            kept[0] = new
 
     def _cow_fixups(self, cache) -> int:
         """Copy-on-write pass before a decode dispatch, a group at a
@@ -884,6 +1013,13 @@ class WindowCompletionModel(GroupPagePrograms,
         close_mark(mark)
         return out
 
+    def _count_decode(self, ctx: np.ndarray) -> None:
+        """attn_work of one decode chunk: ctx (rows, steps), the keys
+        each live row's token of each step attends."""
+        self.attn_work["decode_keys"] += int(ctx.sum())
+        self.attn_work["decode_window_keys"] += int(
+            np.minimum(ctx, self.cfg.window).sum())
+
     def _count_prefill(self, pos: int, n: int) -> None:
         """attn_work of one suffix piece: n real tokens atop pos."""
         ctx = pos + 1 + np.arange(n)              # keys a token attends
@@ -929,14 +1065,17 @@ class WindowCompletionModel(GroupPagePrograms,
                     f"a suffix starts at a page boundary; row {row} holds "
                     f"{cache.lengths[row]} tokens")
         ids, n_valid, full, lengths = self._round_inputs(cache, joins)
-        window = np.zeros_like(full)
+        tables = {"full": full}
+        if cache.window is not None:
+            tables["window"] = np.zeros_like(full)
         for i, (row, _) in enumerate(joins):
-            window[i] = cache.window.tables[row]
+            if cache.window is not None:
+                tables["window"][i] = cache.window.tables[row]
             self._count_prefill(int(lengths[i]), int(n_valid[i]))
         self._rng, sub = jax.random.split(self._rng)
         pools, logits, toks = self._suffix_rows_program(*ids.shape)(
             self.params, self._pools(cache),
-            {"full": jnp.asarray(full), "window": jnp.asarray(window)},
+            {k: jnp.asarray(v) for k, v in tables.items()},
             jnp.asarray(lengths), jnp.asarray(ids), jnp.asarray(n_valid),
             sub)
         mark = DEVTIME.take_mark(self._devname("suffix_prefill"))
@@ -968,7 +1107,15 @@ class WindowCompletionModel(GroupPagePrograms,
                     logits = _head(cfg, params, x)
                     rng, sub = jax.random.split(rng)
                     nxt = _sample_rows(sub, logits, top_p, temp)
-                    return ((pools, lengths + 1, rng, nxt, slots + s),
+                    # under an indexer a dead row stays at length 0:
+                    # counted up with the others it would be a LIVE
+                    # row under topk from the chunk's second step on,
+                    # and the whole batch would walk the dense kernel's
+                    # grid for it (0.26 s of a 3 s capture: PERF.md
+                    # section 6)
+                    grown = lengths + 1 if cfg.indexer is None \
+                        else lengths + (lengths > 0)
+                    return ((pools, grown, rng, nxt, slots + s),
                             (nxt, logits[row]))
 
                 zero = jnp.zeros((max(cfg.experts_held, 1),), jnp.int32)
@@ -988,10 +1135,7 @@ class WindowCompletionModel(GroupPagePrograms,
                                                      carry)
         self._rng, sub = jax.random.split(self._rng)
         live = cache.lengths[cache.lengths > 0].astype(np.int64)
-        ctx = live[:, None] + 1 + np.arange(n)[None, :]
-        self.attn_work["decode_keys"] += int(ctx.sum())
-        self.attn_work["decode_window_keys"] += int(
-            np.minimum(ctx, self.cfg.window).sum())
+        self._count_decode(live[:, None] + 1 + np.arange(n)[None, :])
         pools, out, last, slots, kept = self._chunk_program(n, bp)(
             self.params, self._pools(cache), self._tables(cache),
             jnp.asarray(np.array(cache.lengths)), sub, jnp.asarray(toks),
@@ -1051,7 +1195,7 @@ class WindowCompletionModel(GroupPagePrograms,
         args = jax.tree_util.tree_map(
             spec, (self.params, self._pools(cache))) + (
             {k: i32(rows, cache.tables.shape[1])
-             for k in ("full", "window")},
+             for k in self._pools(cache)},
             i32(rows), i32(rows, sb), i32(rows), spec(self._rng))
         failed = []
 
@@ -1069,3 +1213,78 @@ class WindowCompletionModel(GroupPagePrograms,
             if failed:
                 raise failed[0]
         return wait
+
+
+class IndexedCompletionModel(WindowCompletionModel):
+    """WindowCompletionModel over ONE page group whose layers attend
+    the keys an indexer selects (`cfg.indexer`; module docstring,
+    INDEXER): the pools k, v and ik of a page travel together through
+    GroupPagePrograms, there is no window group, and the programs a
+    start compiles are the decode chunk, the one-page and the widest
+    suffix width and the round's rung."""
+
+    needs_window = False
+    program_prefix = "dsa"
+    refused_options = {
+        **WindowCompletionModel.refused_options,
+        "kv_dtype": "the page group is stored in the model's dtype: "
+                    "the int8/int4 page codecs know a key and a value "
+                    "pool, and this model keeps the indexer's keys in "
+                    "a third",
+        "kv_tier_pages": "the host tier's page wire carries a key and a "
+                         "value pool, and this model keeps the "
+                         "indexer's keys in a third",
+        "phase": "the disaggregated hand-off's page wire carries a key "
+                 "and a value pool, and this model keeps the indexer's "
+                 "keys in a third",
+    }
+
+    def __init__(self, cfg: WindowMoeConfig, **kw):
+        if cfg.indexer is None:
+            raise ValueError("this model serves a configuration with "
+                             "an indexer")
+        super().__init__(cfg, **kw)
+        # what the three stages were asked to do, summed over rows,
+        # layers and steps — running totals the heartbeat carries
+        # (benchmark/work_dsa.py turns them into rooflines): indexer
+        # keys scored, by the decode steps and by the joins; keys the
+        # tokens saw and keys they attended (`topk` at the most), the
+        # decode steps' share of the latter; distinct tokens whose K
+        # and V a join's attention read; row-layers that took the
+        # dense path (every key selected)
+        self.attn_work = dict.fromkeys(
+            ("index_keys_decode", "index_keys_join", "keys_in_context",
+             "keys_selected", "keys_selected_decode", "join_kv",
+             "select_dense_rows"), 0)
+
+    def _set_page(self, page: int) -> None:
+        """Two suffix widths: one page (a question behind a document
+        the tree holds) and the widest (a cold prompt loops in it)."""
+        super()._set_page(page)
+        self.suffix_buckets = tuple(sorted({self.suffix_buckets[0],
+                                            self.suffix_buckets[-1]}))
+        self.buckets = self.suffix_buckets
+
+    def _count(self, ctx: np.ndarray, dense: np.ndarray, where: str):
+        """ctx: keys each token sees; dense: which of them took the
+        dense path (they score no indexer key)."""
+        aw, n, k = self.attn_work, self.cfg.layers, self.cfg.indexer.topk
+        picked = n * int(np.minimum(ctx, k).sum())
+        aw["index_keys_" + where] += n * int(ctx[~dense].sum())
+        aw["keys_in_context"] += n * int(ctx.sum())
+        aw["keys_selected"] += picked
+        return picked
+
+    def _count_decode(self, ctx: np.ndarray) -> None:
+        dense = ctx <= self.cfg.indexer.topk
+        self.attn_work["keys_selected_decode"] += self._count(
+            ctx, dense, "decode")
+        self.attn_work["select_dense_rows"] += \
+            self.cfg.layers * int(dense.sum())
+
+    def _count_prefill(self, pos: int, n: int) -> None:
+        ctx = pos + 1 + np.arange(n)
+        dense = pos + n <= self.cfg.indexer.topk  # the whole piece
+        self._count(ctx, np.full(n, dense), "join")
+        self.attn_work["join_kv"] += self.cfg.layers * (pos + n)
+        self.attn_work["select_dense_rows"] += self.cfg.layers * int(dense)

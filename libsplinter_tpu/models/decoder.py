@@ -582,10 +582,13 @@ class PagedKVCache:
         # allocator are identical to int8's
         self.packed = store_dtype == jnp.uint8
         if not key_value and (self.quantized or sharding is not None):
+            described = describe(page)
+            if isinstance(described, PageLayout):
+                described = (described,)
             raise ValueError(
                 "quantized (int8/int4) and kv-head-sharded pools need "
                 "the key/value page layout; this model describes "
-                f"{[n for n, _ in describe(page).pools]}")
+                f"{sorted({n for lo in described for n, _ in lo.pools})}")
         if self.packed and cfg.head_dim % 2:
             raise ValueError(
                 f"kv_dtype=\"int4\" packs two codes per byte along "

@@ -371,8 +371,40 @@ FAMILIES: dict[str, dict] = {
                                          float),
             "use_expert_bias": Key("expert_bias", False, bool),
         }},
+    # the Keye-VL-2.0 language block's key set: models/afmoe.py with an
+    # INDEXER in front of every (global) layer's attention — grouped
+    # queries that attend the `sa_config.topk` keys it selects; every
+    # layer an expert layer, softmax routing, no shared expert
+    "KeyeVL2": {
+        "window": "max_position_embeddings", "experts": "num_experts",
+        "dense": lambda arch: 0,
+        "keys": {
+            **_COMMON,
+            "max_position_embeddings": Key(None, None),
+            "num_key_value_heads": Key("kv_heads"),
+            "head_dim": Key("head_dim"),
+            "rope_theta": Key("rope_base", 10000.0, float),
+            "rope_scaling": Key(None, cast=dict),
+            "sa_config": Key(None, cast=dict),
+            "num_experts": Key("n_routed_experts"),
+            "num_local_experts": Key(None),
+            "num_experts_per_tok": Key("top_k"),
+            "norm_topk_prob": Key("norm_topk_prob", True, bool),
+            "decoder_sparse_step": Key(
+                None, 1, only=(1,), why="every layer is an expert "
+                "layer: decoder_sparse_step must be 1"),
+            "mlp_only_layers": Key(None, cast=list),
+            "use_sliding_window": _served("use_sliding_window is not "
+                                          "served"),
+            "sliding_window": Key(None, None, lambda v: v, only=(None,),
+                                  why="sliding_window must be null"),
+            "max_window_layers": Key(None, None),    # unused without one
+        }},
 }
 LAYER_TYPES = {"sliding_attention": "window", "full_attention": "full"}
+SA_CONFIG_KEYS = frozenset(("indexer_head_dim", "indexer_num_heads",
+                            "indexer_num_kv_heads", "kv_chunk_size",
+                            "q_chunk_size", "topk"))
 LINEAR_ATTN_KEYS = frozenset(("full_attn_layers", "kda_layers", "head_dim",
                               "num_heads", "short_conv_kernel_size"))
 
@@ -510,17 +542,54 @@ def _finish_conv(arch, fields, path):
         rope_base=float(rope.get("rope_theta", 10000.0)), **fields)
 
 
+def _finish_indexed(arch, fields, path):
+    from .afmoe import AttnKind, Indexer, WindowMoeConfig
+    sa, rope = arch["sa_config"], arch["rope_scaling"]
+    if set(sa) != SA_CONFIG_KEYS or int(sa["indexer_num_kv_heads"]) != 1:
+        raise ValueError(
+            f"{path}: sa_config must hold exactly {sorted(SA_CONFIG_KEYS)}"
+            " with indexer_num_kv_heads 1 (the indexer's keys are one "
+            "head a token; q_chunk_size / kv_chunk_size are the "
+            "published kernels' tiling and change no value)")
+    d = fields["head_dim"]
+    # a text token's three position ids are equal, so the sections of
+    # a multimodal rotary embedding are one plain rotation
+    if set(rope) - {"mrope_section", "rope_type", "type"} \
+            or {rope.get("rope_type", "default"),
+                rope.get("type", "default")} != {"default"} \
+            or sum(rope.get("mrope_section", [d // 2])) != d // 2:
+        raise ValueError(
+            f"{path}: rope_scaling must be the default type, its "
+            f"mrope_section adding up to head_dim / 2 = {d // 2}")
+    if arch["mlp_only_layers"] or int(arch["num_local_experts"]) \
+            != fields["n_routed_experts"]:
+        raise ValueError(f"{path}: mlp_only_layers must be empty and "
+                         "num_local_experts equal num_experts")
+    kind = AttnKind(fields["kv_heads"], d, d, d, fields["rope_base"], 0)
+    return WindowMoeConfig(
+        kinds=("full",) * fields.pop("layers"), window=0,
+        model_layers=int(arch["num_hidden_layers"]),
+        attn_kinds=(("full", kind),), n_shared_experts=0,
+        score_fn="softmax", out_gate=False, qk_norm=True,
+        sandwich_norm=False, mup=False,
+        indexer=Indexer(int(sa["indexer_num_heads"]),
+                        int(sa["indexer_head_dim"]), int(sa["topk"])),
+        **fields)
+
+
 FAMILIES["pangu_ultra_moe"]["finish"] = _finish_latent
 FAMILIES["kimi_linear"]["finish"] = _finish_hybrid
 FAMILIES["afmoe"]["finish"] = _finish_window
 FAMILIES["mimo_v2_flash"]["finish"] = _finish_sink_window
 FAMILIES["lfm2_moe"]["finish"] = _finish_conv
+FAMILIES["KeyeVL2"]["finish"] = _finish_indexed
 
 
 def load_model_description(path: str, *, max_len: int | None = None):
     """A model description file -> (config, seed): a LatentMoeConfig,
     a models/kda.HybridMoeConfig, a models/afmoe.WindowMoeConfig
-    (AFMoE's setting or MiMo-V2-Flash's) or a models/lfm2.ConvMoeConfig,
+    (AFMoE's setting, MiMo-V2-Flash's or Keye-VL-2.0's, the last with
+    an indexer) or a models/lfm2.ConvMoeConfig,
     by the architecture's `model_type` (FAMILIES).
 
     {"architecture": {published keys verbatim, at their published
@@ -596,9 +665,11 @@ def completion_model_class(cfg):
     """The paged serving model of a loaded description's config."""
     if isinstance(cfg, LatentMoeConfig):
         return LatentCompletionModel
-    from .afmoe import WindowCompletionModel, WindowMoeConfig
+    from .afmoe import (IndexedCompletionModel, WindowCompletionModel,
+                        WindowMoeConfig)
     if isinstance(cfg, WindowMoeConfig):
-        return WindowCompletionModel
+        return WindowCompletionModel if cfg.indexer is None \
+            else IndexedCompletionModel
     from .lfm2 import ConvCompletionModel, ConvMoeConfig
     if isinstance(cfg, ConvMoeConfig):
         return ConvCompletionModel

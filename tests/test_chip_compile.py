@@ -891,6 +891,128 @@ def test_window_suffix_rows_program(one_chip, monkeypatch, family, rows):
         assert _no_pool_copied(compiled, pool.shape)
 
 
+# ---- the global stack under an indexer at published widths
+# (benchmark/configs/keye-vl2-30b-a3b-ep8-stage0.json: 8 identical
+# layers as ONE scanned body, 32 heads over 4 key/value heads of 128,
+# an indexer of 16 heads of 64 that selects 2,048 keys a token, its
+# keys the third pool of a page: 2,304 pages of 8 layers, a 258-page
+# table, 16 held experts of 2,048 x 768 of 128, 32 rows)
+
+DSA_H, DSA_KH, DSA_D, DSA_HI, DSA_DI, DSA_TOPK = 32, 4, 128, 16, 64, 2048
+DSA_P, DSA_ROWS, DSA_LAYERS, DSA_BLOCKS = 258, 32, 8, 2305
+DSA_POOLS = ((DSA_BLOCKS, DSA_LAYERS, DSA_KH, PAGE, DSA_D),) * 2 + (
+    (DSA_BLOCKS, DSA_LAYERS, 1, DSA_DI, PAGE),)
+
+
+@pytest.mark.parametrize("stage", ["scan", "select", "attend"])
+@pytest.mark.parametrize("q_tokens, rows", [(1, 32), (128, 16), (640, 1)],
+                         ids=["decode", "round", "cold"])
+def test_sparse_attention_kernels(one_chip, stage, q_tokens, rows):
+    """The three stages of ops/sparse_attention at the benchmark's
+    widths — a decode step of 32 rows, a round of 16 joins a page
+    wide, a cold prompt's 5-page piece — each over a 32.9k-token row:
+    they compile and copy no pool."""
+    from libsplinter_tpu.ops import sparse_attention as sa
+    bf, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
+    k, v, ik = (_spec(one_chip, s, bf) for s in DSA_POOLS)
+    keys = sa.scan_width(DSA_P, PAGE)
+    tab, ln = _spec(one_chip, (rows, DSA_P), i32), \
+        _spec(one_chip, (rows,), i32)
+    lay, sel = _spec(one_chip, (), i32), \
+        _spec(one_chip, (rows, q_tokens, keys), f32)
+    if stage == "scan":
+        compiled = _compile(
+            lambda qi, w, ik, tab, ln, lay: sa.index_scores(
+                qi, w, ik, tab, ln, layer=lay, force_pallas=True),
+            _spec(one_chip, (rows, q_tokens, DSA_HI, DSA_DI), bf),
+            _spec(one_chip, (rows, q_tokens, DSA_HI), f32), ik, tab, ln,
+            lay)
+    elif stage == "select":
+        compiled = _compile(
+            lambda sc, lim: sa.select_topk(sc, lim, topk=DSA_TOPK,
+                                           force_pallas=True),
+            sel, _spec(one_chip, (rows, q_tokens), i32))
+    else:
+        compiled = _compile(
+            lambda q, k, v, sel, tab, ln, lay: sa.sparse_paged_attention(
+                q, k, v, sel, tab, ln, layer=lay, force_pallas=True),
+            _spec(one_chip, (rows, q_tokens, DSA_H, DSA_D), bf), k, v, sel,
+            tab, ln, lay)
+    assert "tpu_custom_call" in compiled.as_text()
+    for shape in DSA_POOLS:
+        assert _no_pool_copied(compiled, shape)
+
+
+def _indexed_case(one_chip, program):
+    """(program, its arguments as shapes on the described chip, bytes
+    of weights) of the benchmark's Keye configuration: "chunk",
+    "suffix-<width>" (one row) or "rows-<rung>"."""
+    from libsplinter_tpu.models import afmoe
+    cfg = afmoe.WindowMoeConfig(
+        vocab_size=18992, hidden=2048, kinds=("full",) * DSA_LAYERS,
+        heads=DSA_H, kv_heads=DSA_KH, head_dim=DSA_D, window=0,
+        dense_layers=0, dense_mlp_dim=6144, moe_mlp_dim=768,
+        n_routed_experts=128, top_k=8, experts_first=0, experts_held=16,
+        n_shared_experts=0, score_fn="softmax", max_len=33024,
+        model_layers=48, rms_eps=1e-6, mup=False, out_gate=False,
+        qk_norm=True, sandwich_norm=False,
+        attn_kinds=(("full", afmoe.AttnKind(DSA_KH, DSA_D, DSA_D, DSA_D,
+                                            1e7, 0)),),
+        indexer=afmoe.Indexer(DSA_HI, DSA_DI, DSA_TOPK))
+    assert cfg.plan == (0, 1, DSA_LAYERS)     # ONE scanned body
+    params = jax.tree_util.tree_map(
+        lambda a: _spec(one_chip, a.shape, a.dtype),
+        jax.eval_shape(lambda: afmoe.init_params(cfg, 0)))
+    weights = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                  for a in jax.tree_util.tree_leaves(params))
+    m = afmoe.IndexedCompletionModel(cfg, params=params)
+    pools = {"full": tuple(_spec(one_chip, s, jnp.bfloat16)
+                           for s in DSA_POOLS)}
+    i32 = _spec(one_chip, (), jnp.int32)
+    if program == "chunk":
+        fn = m._chunk_program(8, DSA_ROWS)
+        tables = {"full": _spec(one_chip, (DSA_ROWS, DSA_P), jnp.int32)}
+        args = (_spec(one_chip, (DSA_ROWS,), jnp.int32),
+                _spec(one_chip, (2,), jnp.uint32),
+                _spec(one_chip, (DSA_ROWS,), jnp.int32),
+                _spec(one_chip, (DSA_ROWS,), jnp.bool_),
+                _spec(one_chip, (DSA_ROWS,), jnp.int32),
+                _spec(one_chip, (2,), jnp.int32))
+    elif program.startswith("rows-"):
+        fn, tables, args = _window_rows(one_chip, m, int(program[5:]),
+                                        DSA_P, ("full",))
+    else:
+        width = int(program.split("-")[1])
+        fn = m._suffix_program(width)
+        tables = {"full": _spec(one_chip, (1, DSA_P), jnp.int32)}
+        args = (_spec(one_chip, (1,), jnp.int32),
+                _spec(one_chip, (1, width), jnp.int32), i32)
+    return getattr(fn, "__wrapped__", fn), (params, pools, tables,
+                                            *args), weights
+
+
+@pytest.mark.parametrize("program", ["chunk", "suffix-128", "suffix-640",
+                                     "rows-16"])
+def test_indexed_programs_at_published_widths(one_chip, monkeypatch,
+                                              program):
+    """The 8-step decode chunk of 32 rows, the one-page and the widest
+    suffix prefill and the round's rung of the benchmark's Keye
+    configuration (8 layers as one scanned body; 1.71 GB of weights
+    beside 5.13 GB of pages in three pools): each compiles, fits the
+    chip beside its arguments and copies none of the three pools."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    fn, args, weights = _indexed_case(one_chip, program)
+    assert 1.70e9 < weights < 1.72e9          # 853M parameters
+    compiled = fn.lower(*args).compile()
+    mem = compiled.memory_analysis()
+    print("keye", program, "arguments", mem.argument_size_in_bytes,
+          "temporaries", mem.temp_size_in_bytes, "weights", weights)
+    assert mem.argument_size_in_bytes > 6.8e9    # weights + three pools
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+    for shape in DSA_POOLS:
+        assert _no_pool_copied(compiled, shape)
+
+
 # the lowered text (kernel payloads, which carry source lines, left
 # out) of the sibling families' programs as PR 39 left them: a change
 # to code they share with the stack above that alters their programs
@@ -901,6 +1023,11 @@ SIBLING_PROGRAMS = {
     ("hybrid", "suffix-640"): "defcd610c0408b44",
     ("window", "chunk"): "ffe29bfc3cbbf3a7",
     ("window", "suffix-640"): "5b6661e293abaabe",
+    # the same stack in MiMo's setting, as PR 43 left it: the indexer
+    # (PR 44) is a static switch of the code the three share
+    ("sink", "chunk"): "8851522810dddde6",
+    ("sink", "suffix-128"): "2e657f381a632f4f",
+    ("sink", "rows-16"): "060c4bc9ab027d5c",
     # the latent family's round (its expert layer runs in chunks of
     # 2,048 token slots: moe.sparse_moe without live_chunk) and chunk
     ("latent", "rows-64"): "30435683c3a2a679",
@@ -920,7 +1047,8 @@ def test_sibling_programs_are_the_parents(one_chip, monkeypatch, family,
     import re
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     case = {"hybrid": _hybrid_case, "window": _window_case,
-            "conv": _conv_case, "latent": _latent_case}[family]
+            "sink": _sink_case, "conv": _conv_case,
+            "latent": _latent_case}[family]
     fn, args, _ = case(one_chip, program)
     text = re.sub(r'backend_config = "(?:[^"\\]|\\.)*"',
                   'backend_config = ""', fn.lower(*args).as_text())

@@ -140,7 +140,8 @@ def test_live_tree_is_clean(runner):
                        "libsplinter_tpu/ops/flash_attention.py",
                        "libsplinter_tpu/ops/latent_attention.py",
                        "libsplinter_tpu/ops/paged_attention.py",
-                       "libsplinter_tpu/ops/similarity.py"}
+                       "libsplinter_tpu/ops/similarity.py",
+                       "libsplinter_tpu/ops/sparse_attention.py"}
 
 
 def test_baseline_has_no_engine_entries(core):

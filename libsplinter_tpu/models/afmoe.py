@@ -174,8 +174,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..obs.devtime import DEVTIME, close_mark
+from ..ops.page_groups import decode_groups
 from ..ops.paged_attention import kv_append, window_paged_attention
-from ..ops.sparse_attention import indexed_attention, write_pages
+from ..ops.sparse_attention import (ATTEND_PAGES, indexed_attention,
+                                    write_pages)
 from .decoder import PageLayout, PagedKVCache, _sample_rows
 from .encoder import _rotary_angles_at
 from .mla import (LatentCompletionModel, LatentPendingChunk, _ffn, _rms,
@@ -582,7 +584,7 @@ def _layer(cfg: WindowMoeConfig, lp, kind: str, gl, x, pos, live, pools,
         o = indexed_attention(
             q.astype(cfg.dtype), _rotate(qi, icos, isin).astype(cfg.dtype),
             wi, kp, vp, ikp, tables[kind], att_len, live[:, 0], layer=gl,
-            topk=ix.topk, interpret=interpret)
+            topk=ix.topk, groups=tables.get("walk"), interpret=interpret)
     else:
         pools = {**pools, kind: (kp, vp)}
         o = window_paged_attention(
@@ -673,14 +675,18 @@ def forward_decode(cfg: WindowMoeConfig, params, toks, pools, tables,
     """One new token a row over the pages its tables map.  toks: (B,);
     pools: {"full": (k, v), "window": (k, v)}, each (n_blocks, L,
     kv_heads, page, width), the kind's own kv_heads and the pool's own
-    width; tables: the same keys, (B, P); lengths: (B,).
+    width; tables: the same keys, (B, P) — and, under an indexer,
+    "walk": the rows that share pages, grouped for the attention's
+    walk (ops/page_groups.decode_groups' arrays of these tables);
+    lengths: (B,).
     Returns (hidden (B, H), pools, slots each held expert received)."""
     page = pools["full"][1].shape[3]
     pos = jnp.minimum(lengths, cfg.max_len - 1).astype(jnp.int32)
     offs = pos % page
     write = {}
-    for kind, tab in tables.items():
-        bids = jnp.take_along_axis(tab, (pos // page)[:, None], axis=1)
+    for kind in pools:
+        bids = jnp.take_along_axis(tables[kind], (pos // page)[:, None],
+                                   axis=1)
 
         def put(pool, new, gl, cols, bids=bids[:, 0]):
             return kv_append(pool, new[:, 0], bids, offs, layer=gl,
@@ -1127,6 +1133,10 @@ class WindowCompletionModel(GroupPagePrograms,
         return self._program(("chunk", n, bp, top_p, temp),
                              "paged_chunk", build)
 
+    def _chunk_tables(self, cache: PagedKVCache, n: int) -> dict:
+        """The tables a chunk of n steps rides on."""
+        return self._tables(cache)
+
     def paged_decode_chunk_async(self, cache: PagedKVCache, tokens,
                                  n: int, carry=None
                                  ) -> LatentPendingChunk:
@@ -1137,7 +1147,7 @@ class WindowCompletionModel(GroupPagePrograms,
         live = cache.lengths[cache.lengths > 0].astype(np.int64)
         self._count_decode(live[:, None] + 1 + np.arange(n)[None, :])
         pools, out, last, slots, kept = self._chunk_program(n, bp)(
-            self.params, self._pools(cache), self._tables(cache),
+            self.params, self._pools(cache), self._chunk_tables(cache, n),
             jnp.asarray(np.array(cache.lengths)), sub, jnp.asarray(toks),
             jnp.asarray(fresh_mask), carry,
             jnp.asarray(self.audit_rows, jnp.int32))
@@ -1251,11 +1261,14 @@ class IndexedCompletionModel(WindowCompletionModel):
         # tokens saw and keys they attended (`topk` at the most), the
         # decode steps' share of the latter; distinct tokens whose K
         # and V a join's attention read; row-layers that took the
-        # dense path (every key selected)
+        # dense path (every key selected); pages the decoding rows'
+        # tables held and pages the attention's walk read for them — a
+        # page rows share once a group (_chunk_tables)
         self.attn_work = dict.fromkeys(
             ("index_keys_decode", "index_keys_join", "keys_in_context",
              "keys_selected", "keys_selected_decode", "join_kv",
-             "select_dense_rows"), 0)
+             "select_dense_rows", "walk_pages_held", "walk_pages_read"),
+            0)
 
     def _set_page(self, page: int) -> None:
         """Two suffix widths: one page (a question behind a document
@@ -1264,6 +1277,18 @@ class IndexedCompletionModel(WindowCompletionModel):
         self.suffix_buckets = tuple(sorted({self.suffix_buckets[0],
                                             self.suffix_buckets[-1]}))
         self.buckets = self.suffix_buckets
+
+    def _chunk_tables(self, cache: PagedKVCache, n: int) -> dict:
+        """Beside the tables, the rows that hold the same document
+        grouped for the attention's walk (ops/page_groups: the tables
+        do not move inside a chunk), and what the grouping saves."""
+        walk = decode_groups(cache.tables, cache.lengths, page=cache.page,
+                             steps=n, chunk=ATTEND_PAGES[0])
+        for k in ("held", "read"):
+            self.attn_work["walk_pages_" + k] += \
+                self.cfg.layers * n * walk.pop(k)
+        return {**self._tables(cache),
+                "walk": {k: jnp.asarray(v) for k, v in walk.items()}}
 
     def _count(self, ctx: np.ndarray, dense: np.ndarray, where: str):
         """ctx: keys each token sees; dense: which of them took the

@@ -25,11 +25,24 @@ kernel with a name a device trace keeps (benchmark/readers/trace_dsa):
                 `dsa_sparse_stack` (a suffix's tokens): the page
                 group's online softmax (ops/paged_attention's window
                 kernel without a window or a sink) under the
-                selection's mask — it WALKS every page of the row and
-                attends the selected keys alone.  Reading the selected
-                token columns only (a gather of `topk` x 2 KB a row a
-                layer) is what a later change may bring: the mask and
-                the gather compute the same sums.
+                selection's mask — it walks the pages and attends the
+                selected keys alone.  A suffix's tokens walk every page
+                of their row.  A decode step walks by GROUPS
+                (ops/page_groups, made on the host a chunk dispatch at
+                a time): the rows whose tables begin with the same run
+                of pages — the same document of the prefix tree — read
+                that run ONCE, 8 pages a program, their queries stacked
+                against it (members x heads-a-kv-head rows in the two
+                products), each member under its own selection row and
+                its own length, its own softmax in its own scratch
+                rows; the pages behind the run are a member's own and
+                are read for it alone, by the program a row without a
+                sharer runs for all its pages.  The same sums a row,
+                whatever the grouping.  Reading the selected token
+                columns only is NOT what the pool's layout offers: a
+                selected key of one kv head is 256 contiguous bytes, a
+                layer-step of 32 rows would be 524,288 copies of 256 B
+                (PERF.md section 7).
 
 `indexed_attention` puts them together for one layer and keeps the
 DENSE path beside them: a row whose last query sees `topk` keys or
@@ -52,6 +65,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .page_groups import FIRST, GROUP_ROWS, LAST, LIVE
 from .paged_attention import NEG_INF, stack_block, window_paged_attention
 
 # pages of a row one program of the index scan reads
@@ -353,21 +367,21 @@ def _sparse_kernel(tab_ref, len_ref, layer_ref, q_ref, *rest, page: int,
     "block_tokens", "q_tokens", "interpret"))
 def _sparse_pallas(q4, k_pool, v_pool, sel, tables, lengths, layer, *,
                    block_tokens: int, q_tokens: int, interpret: bool):
-    """q4: (B, KH, q_tokens x rep, D), head-major within each query
-    block; k_pool / v_pool: (n_blocks, L, KH, page, D | Dv); sel: (B,
-    q_tokens, T) f32; tables (B, P); lengths (B,); layer (1,).
-    Returns (B, KH, q_tokens x rep, Dv)."""
+    """A suffix's tokens (a decode step is _walk_pallas).  q4: (B, KH,
+    q_tokens x rep, D), head-major within each query block; k_pool /
+    v_pool: (n_blocks, L, KH, page, D | Dv); sel: (B, q_tokens, T)
+    f32; tables (B, P); lengths (B,); layer (1,).  Returns (B, KH,
+    q_tokens x rep, Dv)."""
     B, KH, RT, D = q4.shape
     page, Dv = v_pool.shape[3:]
     rep = RT // q_tokens
     P = tables.shape[1]
     R = block_tokens * rep
-    # a decode step carries every kv head in one program, a stack of
-    # tokens one (ops/paged_attention._window_pallas); both read
-    # several pages a program: the grid step, not the page's bytes, is
-    # what one page a step costs (PERF.md section 5)
-    hb = KH if q_tokens == 1 else 1
-    pages = ATTEND_PAGES[q_tokens > 1]
+    # a stack of tokens carries one kv head a program
+    # (ops/paged_attention._window_pallas) and reads several pages: the
+    # grid step, not the page's bytes, is what one page a step costs
+    # (PERF.md section 5)
+    hb, pages = 1, ATTEND_PAGES[1]
 
     def _q_map(b, g, qb, w, *pre):
         return (b, g, qb, 0)
@@ -407,23 +421,24 @@ def _sparse_pallas(q4, k_pool, v_pool, sel, tables, lengths, layer, *,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=KERNEL_VMEM),
         interpret=interpret,
-        name=("dsa_sparse_decode" if q_tokens == 1
-              else "dsa_sparse_stack"),
+        name="dsa_sparse_stack",
     )(tables, lengths, layer, q4, *([k_pool] * pages), *([v_pool] * pages),
       sel)
 
 
 def sparse_paged_attention(q, k_pool, v_pool, sel, tables, lengths, *,
-                           layer, interpret: bool = False,
+                           layer, groups=None, interpret: bool = False,
                            force_pallas: bool = False):
     """Attention of S new tokens a row over the keys their selections
     name, in ONE LAYER of a page group's pool.  q: (B, S, H, D), token
     t at position lengths[b] - 1 + t; k_pool / v_pool: (n_blocks, L,
     KH, page, D | Dv); sel: (B, S, T >= P x page) float32 of 0 / 1;
-    tables: (B, P); lengths: (B,); layer: int32 scalar.  Token t
-    attends {j < lengths[b] + t: sel[b, t, j] = 1}; a token whose
-    selection names no such key reads zeros.  Returns (B, S, H, Dv) in
-    q's dtype."""
+    tables: (B, P); lengths: (B,); layer: int32 scalar; groups: for a
+    decode step (S = 1), ops/page_groups.decode_groups' arrays of
+    these tables — the rows that share pages read them together —, or
+    None: every row walks its table alone.  Token t attends {j <
+    lengths[b] + t: sel[b, t, j] = 1}; a token whose selection names
+    no such key reads zeros.  Returns (B, S, H, Dv) in q's dtype."""
     B, S, H, D = q.shape
     KH, page, Dv = v_pool.shape[2:]
     rep = H // KH
@@ -449,6 +464,12 @@ def sparse_paged_attention(q, k_pool, v_pool, sel, tables, lengths, *,
         out = jnp.einsum("bskrt,bktd->bskrd", probs.astype(vseq.dtype),
                          vseq)
         return out.reshape(B, S, H, Dv).astype(q.dtype)
+    if S == 1:
+        out = _walk_pallas(
+            q.reshape(B, KH, rep, D), k_pool, v_pool, sel,
+            rows_alone(tables) if groups is None else groups, lengths,
+            layer.reshape(1), interpret=interpret)
+        return out.reshape(B, 1, H, Dv)
     tq = stack_block(S, rep)
     # head-major rows within a query block: (B, KH, blocks, rep, tq, D)
     q4 = q.reshape(B, S // tq, tq, KH, rep, D).transpose(0, 3, 1, 4, 2, 5) \
@@ -458,6 +479,194 @@ def sparse_paged_attention(q, k_pool, v_pool, sel, tables, lengths, *,
                          interpret=interpret)
     return out.reshape(B, KH, S // tq, rep, tq, Dv) \
         .transpose(0, 2, 4, 1, 3, 5).reshape(B, S, H, Dv)
+
+
+# --------------------------------------- the decode walk, a group a program
+
+def _walk_kernel(item_ref, page_ref, rows_ref, len_ref, layer_ref, q_ref,
+                 qrow_ref, *rest, page: int, pages: int, scale: float,
+                 rep: int):
+    """One ITEM of a decode step's walk (ops/page_groups): `pages`
+    pages of K and V, attended by every member of the item's group —
+    a chunk of the group's shared run, the members' queries stacked
+    into one product — or by the one member whose own pages they are,
+    which is the program a row alone runs.
+
+      item_ref (4, W): the item's group slot, chunk of the members'
+      tables, member (-1: shared) and flags; rows_ref (members, B): a
+      slot's member rows; len_ref (B,): keys a row's token sees
+      q_ref: (1, hb, members x rep, D), the slot's queries, member-
+      major; qrow_ref: (B, hb, rep, D), every row's
+      rest: `pages` key blocks then as many value blocks, each (1, 1,
+      hb, page, D | Dv); sel_ref (B, 1, pages x page) f32, every
+      row's selection over the chunk's keys; out_ref (1, hb, members x
+      rep, Dv); the scratch m_s / l_s (hb, members x rep, 1), acc_s
+      (hb, members x rep, Dv) f32, a member's rows its own softmax
+
+    A member attends the keys j < its length that ITS selection
+    names; a pad and a row of length 0 attend nothing and read
+    zeros."""
+    k_refs, v_refs = rest[:pages], rest[pages: 2 * pages]
+    sel_ref, out_ref, m_s, l_s, acc_s = rest[2 * pages:]
+    w = pl.program_id(0)
+    g, c, mem, flag = (item_ref[i, w] for i in range(4))
+    hb = q_ref.shape[1]
+    members = q_ref.shape[2] // rep
+    span = pages * page
+    j = c * span + jax.lax.broadcasted_iota(jnp.int32, (1, span), 1)
+
+    @pl.when((flag & FIRST) != 0)
+    def _init():
+        m_s[...] = jnp.full_like(m_s, NEG_INF)
+        l_s[...] = jnp.zeros_like(l_s)
+        acc_s[...] = jnp.zeros_like(acc_s)
+
+    def keep_of(row):
+        """(rep, span) f32 of 0 / 1: the keys of the chunk `row`
+        attends (no key where row < 0)."""
+        at = jnp.maximum(row, 0)
+        length = jnp.where(row >= 0, len_ref[at], 0)
+        keep = jnp.where(jnp.logical_and(j < length, sel_ref[at] > 0.5),
+                         1.0, 0.0)
+        return jnp.broadcast_to(keep, (rep, span))
+
+    def attend(q_of, keep, at):
+        """The online softmax of the scratch rows `at` over the
+        item's keys.  q_of(h): (R, D); keep: (R, span)."""
+        valid = keep > 0.5
+        for h in range(hb):
+            k = jnp.concatenate([r[0, 0, h] for r in k_refs], 0)
+            v = jnp.concatenate([r[0, 0, h] for r in v_refs], 0)
+            logits = jax.lax.dot_general(
+                q_of(h), k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            logits = jnp.where(valid, logits, NEG_INF)
+            m_prev, l_prev = m_s[h, at], l_s[h, at]
+            m_new = jnp.maximum(m_prev,
+                                jnp.max(logits, -1, keepdims=True))
+            corr = jnp.exp(m_prev - m_new)
+            pexp = jnp.where(valid, jnp.exp(logits - m_new), 0.0)
+            m_s[h, at] = m_new
+            l_s[h, at] = l_prev * corr + jnp.sum(pexp, -1, keepdims=True)
+            acc_s[h, at] = acc_s[h, at] * corr + jnp.dot(
+                pexp.astype(v.dtype), v,
+                preferred_element_type=jnp.float32)
+
+    live = (flag & LIVE) != 0
+
+    @pl.when(jnp.logical_and(live, mem < 0))
+    def _shared():
+        attend(lambda h: q_ref[0, h],
+               jnp.concatenate([keep_of(rows_ref[i, g])
+                                for i in range(members)], 0),
+               slice(None))
+
+    row = rows_ref[jnp.maximum(mem, 0), g]
+
+    @pl.when(jnp.logical_and(jnp.logical_and(live, mem >= 0),
+                             c * span < len_ref[jnp.maximum(row, 0)]))
+    def _own():
+        attend(lambda h: qrow_ref[jnp.maximum(row, 0), h], keep_of(row),
+               pl.ds(pl.multiple_of(mem * rep, rep), rep))
+
+    @pl.when((flag & LAST) != 0)
+    def _write():
+        l = l_s[...]
+        out = jnp.where(l > 0.0, acc_s[...] / jnp.maximum(l, 1e-30), 0.0)
+        out_ref[0] = out.astype(out_ref.dtype)
+
+
+def rows_alone(tables):
+    """ops/page_groups.decode_groups' arrays for a batch in which
+    every row is a group of one and reads every chunk of its table:
+    the walk a row, for a caller that has no groups (traced: the
+    tables may be a program's argument).  A dead row's items run and
+    attend nothing."""
+    B, P = tables.shape
+    pages = ATTEND_PAGES[0]
+    chunks = -(-P // pages)
+    b = jnp.repeat(jnp.arange(B, dtype=jnp.int32), chunks)
+    c = jnp.tile(jnp.arange(chunks, dtype=jnp.int32), B)
+    flags = LIVE | jnp.where(c == 0, FIRST, 0) \
+        | jnp.where(c == chunks - 1, LAST, 0)
+    at = jnp.minimum(c[None, :] * pages
+                     + jnp.arange(pages, dtype=jnp.int32)[:, None], P - 1)
+    rows = jnp.full((GROUP_ROWS, B), -1, jnp.int32).at[0].set(
+        jnp.arange(B, dtype=jnp.int32))
+    return {"item": jnp.stack([b, c, jnp.zeros_like(b), flags]),
+            "pages": tables[b[None, :], at], "rows": rows,
+            "slot": jnp.arange(B, dtype=jnp.int32) * GROUP_ROWS}
+
+
+# splint: ignore[SPL205] reason=runs inside the registered paged programs; the outer program is the attribution point
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _walk_pallas(q4, k_pool, v_pool, sel, groups, lengths, layer, *,
+                 interpret: bool):
+    """q4: (B, KH, rep, D); k_pool / v_pool: (n_blocks, L, KH, page, D
+    | Dv); sel: (B, 1, T) f32; groups: ops/page_groups.decode_groups'
+    arrays; lengths (B,); layer (1,).  Returns (B, KH, rep, Dv)."""
+    B, KH, rep, D = q4.shape
+    page, Dv = v_pool.shape[3:]
+    pages = ATTEND_PAGES[0]
+    item, ids, rows, slot = (jnp.asarray(groups[k], jnp.int32) for k in (
+        "item", "pages", "rows", "slot"))
+    members = rows.shape[0]
+    R = members * rep
+    # a slot's queries stacked member-major; a pad reads row 0's
+    stacked = q4[jnp.maximum(rows.T, 0)].transpose(0, 2, 1, 3, 4) \
+        .reshape(B, KH, R, D)
+
+    def _slot_map(w, item, *pre):
+        return (item[0, w], 0, 0, 0)
+
+    def _kv_map(i):
+        def at(w, item, ids, rows, lens, lay):
+            return (ids[i, w], lay[0], 0, 0, 0)
+        return at
+
+    def kv_specs(width):
+        return [pl.BlockSpec((1, 1, KH, page, width), _kv_map(i),
+                             memory_space=pltpu.VMEM)
+                for i in range(pages)]
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,
+        # the live items alone, where the groups say how many (at
+        # least one: a dead item runs and does nothing)
+        grid=(jnp.maximum(groups["live"], 1) if "live" in groups
+              else item.shape[1],),
+        in_specs=[
+            pl.BlockSpec((1, KH, R, D), _slot_map,
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((B, KH, rep, D), lambda w, *pre: (0, 0, 0, 0),
+                         memory_space=pltpu.VMEM),
+            *kv_specs(D), *kv_specs(Dv),
+            pl.BlockSpec((B, 1, pages * page),
+                         lambda w, item, *pre: (0, 0, item[1, w]),
+                         memory_space=pltpu.VMEM)],
+        out_specs=pl.BlockSpec((1, KH, R, Dv), _slot_map,
+                               memory_space=pltpu.VMEM),
+        scratch_shapes=[pltpu.VMEM((KH, R, 1), jnp.float32),
+                        pltpu.VMEM((KH, R, 1), jnp.float32),
+                        pltpu.VMEM((KH, R, Dv), jnp.float32)],
+    )
+    out = pl.pallas_call(
+        functools.partial(_walk_kernel, page=page, pages=pages,
+                          scale=1.0 / float(np.sqrt(D)), rep=rep),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, KH, R, Dv), q4.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=KERNEL_VMEM),
+        interpret=interpret,
+        # the name a device trace keeps (benchmark/readers/trace_dsa)
+        name="dsa_sparse_decode",
+    )(item, ids, rows, lengths, layer, stacked, q4,
+      *([k_pool] * pages), *([v_pool] * pages), sel)
+    # a row's answer out of its slot; a dead row reads zeros
+    out = out.reshape(B, KH, members, rep, Dv).transpose(0, 2, 1, 3, 4) \
+        .reshape(B * members, KH, rep, Dv)
+    return jnp.where((slot >= 0)[:, None, None, None],
+                     out[jnp.maximum(slot, 0)], 0)
 
 
 # ------------------------------------------------------- whole-page write
@@ -511,7 +720,8 @@ def write_pages(pool, pages, bids, *, layer, interpret: bool = False,
 # ------------------------------------------------------ one layer's call
 
 def indexed_attention(q, qi, w, k_pool, v_pool, ik_pool, tables, lengths,
-                      live, *, layer, topk: int, interpret: bool = False):
+                      live, *, layer, topk: int, groups=None,
+                      interpret: bool = False):
     """A layer's attention under its indexer.  q: (B, S, H, D); qi:
     (B, S, HI, DI) and w: (B, S, HI) float32, the indexer's queries and
     head weights; the three pools of the page group, every new token's
@@ -520,7 +730,8 @@ def indexed_attention(q, qi, w, k_pool, v_pool, ik_pool, tables, lengths,
     is read.  A live row whose LAST token sees at most `topk` keys
     takes the dense kernel (window_paged_attention: the layer without
     an indexer, bit for bit); every other live row scans, selects a
-    token and attends its selection.  Returns (B, S, H, Dv)."""
+    token and attends its selection — a decode step's rows by the
+    `groups` of sparse_paged_attention.  Returns (B, S, H, Dv)."""
     B, S, H, _ = q.shape
     Dv = v_pool.shape[-1]
     lengths = jnp.asarray(lengths, jnp.int32)
@@ -541,7 +752,8 @@ def indexed_attention(q, qi, w, k_pool, v_pool, ik_pool, tables, lengths,
                            own[:, None] + jnp.arange(S)[None, :], 0)
         sel = select_topk(scores, limits, topk=topk, interpret=interpret)
         return sparse_paged_attention(q, k_pool, v_pool, sel, tables, own,
-                                      layer=layer, interpret=interpret)
+                                      layer=layer, groups=groups,
+                                      interpret=interpret)
 
     o_dense = jax.lax.cond(jnp.any(live & short), dense, lambda: zeros)
     o_sparse = jax.lax.cond(jnp.any(live & ~short), sparse, lambda: zeros)

@@ -904,6 +904,18 @@ DSA_POOLS = ((DSA_BLOCKS, DSA_LAYERS, DSA_KH, PAGE, DSA_D),) * 2 + (
     (DSA_BLOCKS, DSA_LAYERS, 1, DSA_DI, PAGE),)
 
 
+def _walk_specs(one_chip, rows):
+    """ops/page_groups.decode_groups' arrays for `rows` tables of
+    DSA_P pages, as shapes."""
+    from libsplinter_tpu.ops import page_groups as pg
+    from libsplinter_tpu.ops.sparse_attention import ATTEND_PAGES
+    got = pg.decode_groups(np.zeros((rows, DSA_P), np.int32),
+                           np.zeros((rows,), np.int32), page=PAGE,
+                           steps=8, chunk=ATTEND_PAGES[0])
+    return {k: _spec(one_chip, v.shape, v.dtype) for k, v in got.items()
+            if k not in ("held", "read")}
+
+
 @pytest.mark.parametrize("stage", ["scan", "select", "attend"])
 @pytest.mark.parametrize("q_tokens, rows", [(1, 32), (128, 16), (640, 1)],
                          ids=["decode", "round", "cold"])
@@ -933,11 +945,15 @@ def test_sparse_attention_kernels(one_chip, stage, q_tokens, rows):
                                            force_pallas=True),
             sel, _spec(one_chip, (rows, q_tokens), i32))
     else:
+        # a decode step's walk by the host's groups (an item a grid
+        # step: ops/page_groups); a suffix's tokens have none
+        walk = _walk_specs(one_chip, rows) if q_tokens == 1 else None
         compiled = _compile(
-            lambda q, k, v, sel, tab, ln, lay: sa.sparse_paged_attention(
-                q, k, v, sel, tab, ln, layer=lay, force_pallas=True),
+            lambda q, k, v, sel, tab, ln, lay, walk:
+            sa.sparse_paged_attention(q, k, v, sel, tab, ln, layer=lay,
+                                      groups=walk, force_pallas=True),
             _spec(one_chip, (rows, q_tokens, DSA_H, DSA_D), bf), k, v, sel,
-            tab, ln, lay)
+            tab, ln, lay, walk)
     assert "tpu_custom_call" in compiled.as_text()
     for shape in DSA_POOLS:
         assert _no_pool_copied(compiled, shape)
@@ -971,7 +987,8 @@ def _indexed_case(one_chip, program):
     i32 = _spec(one_chip, (), jnp.int32)
     if program == "chunk":
         fn = m._chunk_program(8, DSA_ROWS)
-        tables = {"full": _spec(one_chip, (DSA_ROWS, DSA_P), jnp.int32)}
+        tables = {"full": _spec(one_chip, (DSA_ROWS, DSA_P), jnp.int32),
+                  "walk": _walk_specs(one_chip, DSA_ROWS)}
         args = (_spec(one_chip, (DSA_ROWS,), jnp.int32),
                 _spec(one_chip, (2,), jnp.uint32),
                 _spec(one_chip, (DSA_ROWS,), jnp.int32),
@@ -1009,6 +1026,12 @@ def test_indexed_programs_at_published_widths(one_chip, monkeypatch,
           "temporaries", mem.temp_size_in_bytes, "weights", weights)
     assert mem.argument_size_in_bytes > 6.8e9    # weights + three pools
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+    if program == "chunk":
+        # the chunk as PR 44 left it (a walk a row: 6,846,360,576 B of
+        # arguments, 12,535,296 B of temporaries) with the walk's
+        # groups beside the tables: under 0.1 GB more
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+            < 6_846_360_576 + 12_535_296 + 0.1e9
     for shape in DSA_POOLS:
         assert _no_pool_copied(compiled, shape)
 

@@ -39,6 +39,7 @@ import numpy as np
 import pytest
 
 import reference_keye
+from test_page_groups import batch
 from libsplinter_tpu import Store
 from libsplinter_tpu.engine import completer as C
 from libsplinter_tpu.engine.client import submit_completion
@@ -47,6 +48,7 @@ from libsplinter_tpu.models import afmoe, mla
 from libsplinter_tpu.models.encoder import _rotary_angles_at
 from libsplinter_tpu.models.moe import sparse_moe
 from libsplinter_tpu.ops import sparse_attention as sa
+from libsplinter_tpu.ops.page_groups import decode_groups
 from libsplinter_tpu.ops.paged_attention import window_paged_attention
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -307,6 +309,140 @@ def test_attention_over_a_selection_matches_the_masked_softmax(
                                    np.asarray(want[b], np.float32),
                                    atol=2e-2 if dtype == jnp.bfloat16
                                    else 1e-5)
+
+
+# rows of a decode batch as (document | None, tokens behind its whole
+# pages) or None (dead): documents of 16 and 24 pages (two and three
+# programs of 8 pages), PAGE 16, a chunk of 8 steps
+WALK_DOCS = (16, 24)
+WALK_CASES = {
+    "a_group_of_one": [(0, 20)],
+    "four_rows_of_one_document_in_scattered_slots":
+        [(None, 90), (0, 3), None, (0, 20), (0, 40), (None, 300), (0, 9)],
+    "nine_rows_of_one_document_split_at_eight": [(0, 5 + 3 * i)
+                                                 for i in range(9)],
+    "two_documents_interleaved":
+        [(0, 7), (1, 30), (0, 22), (1, 2), (0, 31), (1, 17)],
+    "a_dead_row_between_members": [(1, 12), None, (1, 25), None, (1, 3)],
+    "members_with_one_two_and_three_own_pages":
+        [(0, 2), (0, 16 + 5), (0, 32 + 7), (0, 1)],
+    "an_answer_crosses_a_page_edge_inside_the_chunk":
+        [(1, 16 - 3), (1, 16 - 8), (1, 5), (1, 32 - 1)],
+    "no_sharing_at_all": [(None, 260), (None, 131), (None, 17), None,
+                          (None, 396)],
+}
+
+
+def _walk_batch(rng, rows):
+    """(pool blocks, tables, lengths at dispatch) of WALK_CASES' rows
+    over a table of 28 pages (tests/test_page_groups.batch)."""
+    tables, lengths = batch(rng, rows, 28, doc_pages=list(WALK_DOCS))
+    return int(tables.max()) + 1, tables, lengths
+
+
+@pytest.mark.parametrize("case", sorted(WALK_CASES))
+def test_rows_that_share_pages_walk_them_together(case):
+    """A decode step's attention by GROUPS (ops/page_groups) — a shared
+    page read once, the members' queries stacked against it, each
+    under its own selection and length — at the first and the last
+    step of a chunk, against the plain masked softmax and against the
+    walk a row alone (groups=None: the parent's program, an item a
+    (row, chunk)).  In float32 pools the grouped walk equals the walk
+    alone to 2e-6 (the same sums in the same order; a product of 8 x
+    rep rows may round its last bit otherwise than one of rep) and
+    the plain softmax to 1e-5; in bfloat16 to one rounding of the
+    output (8e-3).  A row whose selection names ONE key reads that
+    key's value bit for bit: no member attends under another's
+    selection."""
+    rows = WALK_CASES[case]
+    rng = np.random.default_rng(sorted(WALK_CASES).index(case))
+    nb, tables, lengths = _walk_batch(rng, rows)
+    B, H, D, steps = len(rows), 4, 16, 8
+    T = sa.scan_width(tables.shape[1], PAGE)
+    groups = decode_groups(tables, lengths, page=PAGE, steps=steps,
+                           chunk=sa.ATTEND_PAGES[0])
+    sizes = sorted(int((col >= 0).sum()) for col in groups["rows"].T
+                   if (col >= 0).any())
+    assert sum(sizes) == sum(r is not None for r in rows)
+    if "no_sharing" in case or "group_of_one" in case:
+        assert set(sizes) == {1} and groups["read"] == groups["held"]
+    else:
+        assert max(sizes) > 1 and groups["read"] < groups["held"]
+    if "nine" in case:
+        assert sizes == [1, 8]
+    walk = {k: v for k, v in groups.items() if k not in ("held", "read")}
+    for dtype, tol_alone, tol_plain in ((jnp.float32, 2e-6, 1e-5),
+                                        (jnp.bfloat16, 8e-3, 2e-2)):
+        kp, vp, _ = _pools(rng, nb=nb, dtype=dtype)
+        q = jnp.asarray(rng.standard_normal((B, 1, H, D)), dtype)
+        for step in (0, steps - 1):
+            # keys the step's token sees; a dead row stays at 0
+            seen = jnp.asarray(np.where(lengths > 0, lengths + step + 1,
+                                        0), jnp.int32)
+            sel = jnp.asarray(rng.random((B, 1, T)) < 0.3, jnp.float32)
+            args = (q, kp, vp, sel, jnp.asarray(tables), seen)
+            got = sa.sparse_paged_attention(*args, layer=1, groups=walk,
+                                            interpret=True)
+            alone = sa.sparse_paged_attention(*args, layer=1,
+                                              interpret=True)
+            plain = sa.sparse_paged_attention(*args, layer=1)
+            np.testing.assert_allclose(np.asarray(got, np.float32),
+                                       np.asarray(alone, np.float32),
+                                       atol=tol_alone)
+            np.testing.assert_allclose(np.asarray(got, np.float32),
+                                       np.asarray(plain, np.float32),
+                                       atol=tol_plain)
+            assert not np.asarray(got, np.float32)[lengths == 0].any()
+        # ONE selected key a row, every row another: its value's bits
+        at = np.where(lengths > 0, rng.integers(0, 2 ** 30, B)
+                      % np.maximum(lengths, 1), 0)
+        one = np.zeros((B, 1, T), np.float32)
+        one[np.arange(B), 0, at] = 1.0
+        got = sa.sparse_paged_attention(
+            q, kp, vp, jnp.asarray(one), jnp.asarray(tables),
+            jnp.asarray(lengths), layer=1, groups=walk, interpret=True)
+        for b in np.flatnonzero(lengths > 0):
+            v = np.asarray(vp[tables[b, at[b] // PAGE], 1, :,
+                              at[b] % PAGE], np.float32)      # (KH, D)
+            np.testing.assert_array_equal(
+                np.asarray(got[b, 0], np.float32).reshape(2, 2, D),
+                np.broadcast_to(v[:, None], (2, 2, D)))
+
+
+def test_a_member_under_topk_is_dense_beside_members_that_select():
+    """indexed_attention over a group: a member whose token sees at
+    most topk keys takes the dense kernel's own bits while the others
+    attend their selections — the same answers as without groups, and
+    the selection is not the grouping's to touch."""
+    rng = np.random.default_rng(7)
+    rows = [(0, 2), (0, 5), (0, 60), (0, 33)]      # 16 pages = 256 keys
+    topk = 256 + 8                                 # rows 0 and 1 under it
+    nb, tables, lengths = _walk_batch(rng, rows)
+    kp, vp, ik = _pools(rng, nb=nb)
+    B, H, D, HI, DI = 4, 4, 16, 4, 8
+    q = jnp.asarray(rng.standard_normal((B, 1, H, D)), jnp.bfloat16)
+    qi = jnp.asarray(rng.standard_normal((B, 1, HI, DI)), jnp.bfloat16)
+    w = jnp.asarray(rng.standard_normal((B, 1, HI)), jnp.float32)
+    groups = decode_groups(tables, lengths, page=PAGE, steps=1,
+                           chunk=sa.ATTEND_PAGES[0])
+    assert (groups["rows"][:, 0] >= 0).sum() == 4   # one group of four
+    walk = {k: v for k, v in groups.items() if k not in ("held", "read")}
+    seen = jnp.asarray(lengths + 1)
+    args = (q, qi, w, kp, vp, ik, jnp.asarray(tables), seen, seen > 0)
+    got = sa.indexed_attention(*args, layer=0, topk=topk, groups=walk,
+                               interpret=True)
+    alone = sa.indexed_attention(*args, layer=0, topk=topk, interpret=True)
+    dense = window_paged_attention(q, kp, vp, jnp.asarray(tables), seen,
+                                   layer=0, interpret=True)
+    for b in (0, 1):
+        np.testing.assert_array_equal(np.asarray(got[b], np.float32),
+                                      np.asarray(dense[b], np.float32))
+    for b in (2, 3):
+        np.testing.assert_allclose(np.asarray(got[b], np.float32),
+                                   np.asarray(alone[b], np.float32),
+                                   atol=8e-3)
+        assert np.abs(np.asarray(got[b], np.float32)
+                      - np.asarray(dense[b], np.float32)).max() > 0.02
 
 
 def test_a_layer_under_topk_is_the_dense_kernel_and_long_rows_select():
@@ -583,6 +719,54 @@ def test_a_hit_maps_the_indexer_pages_and_equals_a_cold_prefill(model32):
     cache.free_row(1)
     assert cache.free_pages + pc.evictable_count() == 40
     assert pc.reclaim(4) and cache.free_pages == 40
+
+
+def test_a_chunk_over_a_shared_document_decodes_what_private_pages_do(
+        model32):
+    """Three rows behind ONE 9-page document of the tree (a shared run
+    of one 8-page program of the walk) and a row alone decode a chunk
+    of 8 steps — the kernels interpreted — to the tokens and logits the
+    same prompts decode from pages of their own; the heartbeat's
+    counters say what the walk read of what the rows held."""
+    doc = IDS[:9 * PAGE]
+    asks = [IDS[200:207], IDS[300:311], IDS[250:252]]
+    lone = IDS[150:150 + 9 * PAGE + 5]
+    got = {}
+    for shared in (True, False):
+        m = afmoe.IndexedCompletionModel(model32.cfg, temp=0.0,
+                                         params=model32.params,
+                                         interpret=True)
+        cache, pc = _tree(m, pool_pages=80, batch=5)
+        if shared:
+            m.paged_prefill_row(cache, doc, 0)
+            pc.insert(doc, cache, 0)
+            cache.free_row(0)
+        firsts = np.full((5,), -1, np.int32)
+        for row, q in zip((0, 2, 4), asks):
+            if shared:
+                _, match = _hit(cache, pc, np.concatenate([doc, q]), row)
+                assert match == 9 * PAGE
+                logits = m.paged_append_prefill(cache, q, row)
+            else:
+                logits = m.paged_prefill_row(
+                    cache, np.concatenate([doc, q]), row)
+            firsts[row] = int(np.argmax(logits))
+        firsts[3] = int(np.argmax(m.paged_prefill_row(cache, lone, 3)))
+        m.audit_seat(0, 2)
+        pend = m.paged_decode_chunk_async(cache, firsts, 8)
+        got[shared] = (pend.block(), np.asarray(pend.audit)[:, 0],
+                       dict(m.attn_work))
+    (toks, logits, work), (want_toks, want_logits, alone) = \
+        got[True], got[False]
+    np.testing.assert_array_equal(toks[[0, 2, 3, 4]],
+                                  want_toks[[0, 2, 3, 4]])
+    np.testing.assert_allclose(logits, want_logits, atol=2e-4)
+    layers = model32.cfg.layers
+    # rows of 151, 155, 146 and 149 tokens + 8 hold 10, 11, 10, 10 pages
+    held = layers * 8 * (10 + 11 + 10 + 10)
+    assert alone["walk_pages_held"] == alone["walk_pages_read"] == held
+    assert work["walk_pages_held"] == held
+    assert work["walk_pages_read"] == held - layers * 8 * 2 * 8
 
 
 # ------------------------------------------------------ an admission round

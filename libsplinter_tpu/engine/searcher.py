@@ -126,15 +126,18 @@ class SearcherStats:
     returned_next_drain: int = 0  # gathered requests whose slot the
                                   # previous serviced drain answered
     dispatches: int = 0          # device top-k program calls
+    select_passes: int = 0       # selection passes the fused kernel ran
+    select_tiles: int = 0        # over this many lane tiles (grid steps)
     coalesced_max: int = 0       # most requests in one dispatch
     parse_errors: int = 0        # malformed / vectorless requests
     raced: int = 0               # slot changed mid-service; retried
     # -- how the lane and the mask learn what moved (StagedLane) -----
     lane_slots_scanned: int = 0  # epochs the drains' refreshes and
-                                 # mask patches looked at (not audits)
-    journal_rows: int = 0        # distinct rows the journal delivered
-    journal_fallbacks: int = 0   # refreshes that scanned every slot
-    lane_audit_rows: int = 0     # rows only the beat's audit found: 0
+                                 # mask patches looked at (not audits).
+                                 # The lane's journal_rows, journal_
+                                 # fallbacks and lane_audit_rows are in
+                                 # the heartbeat's `lane` section only:
+                                 # a 2 KB record has no room for copies
     # -- how the gather learns who asks (store.LabelCursor) ----------
     gather_slots_scanned: int = 0  # slots whose labels the gathers
                                    # read (a walk: every slot; not audits)
@@ -692,13 +695,10 @@ class Searcher:
         stats.gather_audit_rows = asking.audit_rows
 
     def _note_lane(self) -> None:
-        """The lane's journal counters into the heartbeat's own."""
-        lane, stats = self.lane, self.stats
-        stats.lane_slots_scanned = (lane.lane_slots_scanned
-                                    + self._mask_slots)
-        stats.journal_rows = lane.journal_rows
-        stats.journal_fallbacks = lane.journal_fallbacks
-        stats.lane_audit_rows = lane.lane_audit_rows
+        """The epochs the lane's refreshes and the mask patches looked
+        at, as one counter of the heartbeat's own."""
+        self.stats.lane_slots_scanned = (self.lane.lane_slots_scanned
+                                         + self._mask_slots)
 
     def _dispatch_groups(self, arr, groups: dict, masks: dict,
                          win) -> None:
@@ -753,7 +753,9 @@ class Searcher:
                 fault("searcher.select")
                 if pend is None:
                     raise RuntimeError("batch dispatch failed")
-                s_all, i_all = jax.device_get(pend)
+                s_all, i_all, (passes, tiles) = jax.device_get(pend)
+                self.stats.select_passes += int(passes)
+                self.stats.select_tiles += int(tiles)
                 ok = None
             except Exception as ex:
                 s_all, i_all, ok = self._score_degraded(
@@ -800,7 +802,7 @@ class Searcher:
                               use_pallas=self.use_pallas,
                               mxu_bf16=False, block_n=self.block_n,
                               fused=False, interpret=self.interpret)
-            s_all, i_all = _fetch(fn, arr, q, mask, norms)
+            s_all, i_all, _ = _fetch(fn, arr, q, mask, norms)
             self.stats.retried_unfused += 1
             return s_all, i_all, None
         except Exception as ex2:
@@ -819,7 +821,7 @@ class Searcher:
                                   use_pallas=self.use_pallas,
                                   mxu_bf16=False, block_n=self.block_n,
                                   fused=False, interpret=self.interpret)
-                s1, i1 = _fetch(fn, arr, q1, mask, norms)
+                s1, i1, _ = _fetch(fn, arr, q1, mask, norms)
                 s_out[i], i_out[i] = s1[0], i1[0]
                 ok[i] = True
                 self.stats.retried_single += 1

@@ -260,7 +260,10 @@ def _topk_fn(k: int, batch: bool, use_pallas: bool, mxu_bf16: bool,
                                use_pallas=use_pallas, mxu_bf16=mxu_bf16,
                                vnorm=vnorm, block_n=block_n)
         if batch:
-            return jax.lax.top_k(scores.T, k)
+            # the batched programs have one shape: no selection passes
+            # here, so the fused program's [passes, tiles] reads 0, 0
+            return (*jax.lax.top_k(scores.T, k),
+                    jnp.zeros(2, jnp.int32))
         return jax.lax.top_k(scores[:, 0], k)
 
     return DEVTIME.register("searcher.topk", jax.jit(run))
@@ -281,17 +284,20 @@ FUSED_K_MAX = 128
 # the kernel computes after the matmul — the (TN, Q) scores, the
 # (K, Q) accumulator, the k_pad selection passes — and a vector
 # register is 8 x 128, so 8 query rows and 128 fill the same
-# registers; the second lane tile at 256 is what costs.  On the v5e
-# over 1,572,864 x 768 rows a dispatch takes 17.5 ms at 8, 32 or 64
-# queries, 20.0 at 128 and 31.3 at 256 (PERF.md section 6, PR 27).
-# The search daemon's middle batch width is this name
-# (engine/searcher.qb_buckets).
+# registers; a second lane tile at 256 doubles every selection pass.
+# With k_pad passes a tile, on the v5e over 1,572,864 x 768 rows, a
+# dispatch took 17.6 ms at 8 queries, 20.1 at 128 and 31.4 at 256;
+# since the selection runs only where a tile has entrants (~1 pass a
+# tile) the scan is bound by the lane's bytes at every width: 8.82 ms
+# at 8, 8.83 at 128, 8.89 at 256, 8.99 at k 64 where the fixed 64
+# passes took 74.2 (PERF.md section 6, PR 46).  The search daemon's
+# middle batch width is this name (engine/searcher.qb_buckets).
 FUSED_Q_LANE = 128
 
 
 def _fused_topk_kernel(vec_ref, q_ref, qnorm_ref, mask_ref,
-                       out_s_ref, out_i_ref, *, k_pad: int,
-                       block_n: int, mxu_bf16: bool):
+                       out_s_ref, out_i_ref, passes_ref, tile_ref, *,
+                       k_pad: int, block_n: int, mxu_bf16: bool):
     """One N-tile of the streaming top-k.
 
     vec_ref:  (TN, D) f32 vectors tile
@@ -300,23 +306,43 @@ def _fused_topk_kernel(vec_ref, q_ref, qnorm_ref, mask_ref,
     mask_ref: (TN, 1) f32 1.0 = candidate, 0.0 = filtered out
     out_s_ref:(K, Q)  f32 running top-k scores, sorted desc per query
     out_i_ref:(K, Q)  i32 matching GLOBAL row indices (-1 = filler)
+    passes_ref:(1, 1) i32 selection passes run so far (SMEM)
+    tile_ref: (TN, Q) f32 VMEM scratch: the tile's scores, taken rows
+              masked out as the passes go
 
     The output blocks map every grid step to block (0, 0), so they
-    stay resident in VMEM across the sequential N-tiles and act as the
-    running accumulator: each tile computes its fused cosine scores,
-    concatenates them under the accumulator, and re-selects the top
-    k_pad by k_pad max/mask passes — pure VPU reductions, no sort, no
-    (N, Q) score matrix ever leaving the chip.  Ties resolve to the
-    smallest global row index (accumulator rows come from earlier
-    tiles and precede tile rows in scan order), matching lax.top_k's
-    stable tie-break, so the fused path is rank-identical to the
-    reference score-matrix path."""
+    stay resident across the sequential N-tiles and act as the running
+    accumulator.  A tile's score ENTERS a query's accumulator iff it
+    is strictly greater than the query's k_pad-th score (the
+    accumulator's last row; NEG_INF while it holds fillers, which a
+    masked or zero-norm row's NEG_INF never beats).  Accumulator rows
+    come from earlier rows of the scan and win ties, so strict `>` is
+    lax.top_k's stable smallest-index tie-break, and the fused path is
+    rank-identical to the reference score-matrix path.
+
+    The selection runs as many passes as the tile has entrants, not
+    k_pad of them: one column max over the tile says whether any query
+    has one (none: the accumulator is not touched); then, while some
+    query still has one, a pass takes each query's largest remaining
+    score (first row on ties), inserts it into that query's sorted
+    column — its place is the count of accumulator scores >= it, the
+    rows below shift down one, the last falls off — and masks it out
+    of the tile.  The threshold rises with every insert, so a tile
+    runs at most k_pad passes.  In a lane in no particular order that
+    is k_pad in the first tile and none or one in most later ones
+    (~0.8 a tile at 48 live queries over 1,536 tiles), and up to ~4
+    passes hide under the tile's DMA.  The worst case, a lane ASCENDING
+    in a query's score, runs k_pad passes in every tile and costs what
+    the old fixed k_pad passes did (19.3 ms against 20.1 on the v5e at
+    1,572,864 x 768, k_pad 16; PERF.md section 6, PR 46)."""
     i = pl.program_id(0)
+    last = k_pad - 1
 
     @pl.when(i == 0)
     def _init():
         out_s_ref[:] = jnp.full(out_s_ref.shape, NEG_INF, jnp.float32)
         out_i_ref[:] = jnp.full(out_i_ref.shape, -1, jnp.int32)
+        passes_ref[0, 0] = 0
 
     v = vec_ref[:]
     if mxu_bf16:
@@ -330,40 +356,43 @@ def _fused_topk_kernel(vec_ref, q_ref, qnorm_ref, mask_ref,
     cos = dots / denom
     keep = (mask_ref[:] > 0.0) & (vnorm > 0.0)
     scores = jnp.where(keep, cos, NEG_INF)                       # (TN,Q)
+    tile_ref[:] = scores
 
-    rows = (jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0)
-            + i * block_n)
-    comb_s = jnp.concatenate([out_s_ref[:], scores], axis=0)
-    comb_i = jnp.concatenate([out_i_ref[:], rows], axis=0)
-    pos = jax.lax.broadcasted_iota(jnp.int32, comb_s.shape, 0)
-    kpos = jax.lax.broadcasted_iota(jnp.int32, out_s_ref.shape, 0)
-    past_end = comb_s.shape[0]
+    def entrants(best):
+        # does any query's best remaining score beat its k_pad-th
+        return jnp.max((best > out_s_ref[last:, :]).astype(jnp.int32))
 
-    def select(j, carry):
-        # one selection pass: global max per query, first (smallest
-        # pos) occurrence wins — float-equality against the max is
-        # exact, and "first pos" is what makes ties index-stable
-        cs, acc_s, acc_i = carry
-        m = jnp.max(cs, axis=0)                                  # (Q,)
-        first = jnp.min(jnp.where(cs == m[None, :], pos, past_end),
-                        axis=0)                                  # (Q,)
-        sel = pos == first[None, :]
-        picked = jnp.sum(jnp.where(sel, comb_i, 0), axis=0)      # (Q,)
-        # candidates exhausted: the max is the NEG_INF filler — mark
-        # the index -1 (a consumed slot's stale index lives at pos 0)
-        picked = jnp.where(m > NEG_INF, picked, -1)
-        put = kpos == j
-        acc_s = jnp.where(put, m[None, :], acc_s)
-        acc_i = jnp.where(put, picked[None, :], acc_i)
-        return jnp.where(sel, NEG_INF, cs), acc_s, acc_i
+    def one_pass(carry):
+        n, best, _ = carry                                       # best (1,Q)
+        cs = tile_ref[:]
+        pos = jax.lax.broadcasted_iota(jnp.int32, cs.shape, 0)
+        # float-equality against the max is exact, and "first pos" is
+        # what makes ties index-stable
+        first = jnp.min(jnp.where(cs == best, pos, block_n), axis=0,
+                        keepdims=True)                           # (1,Q)
+        cs = jnp.where(pos == first, NEG_INF, cs)
+        tile_ref[:] = cs
+        acc_s, acc_i = out_s_ref[:], out_i_ref[:]
+        kpos = jax.lax.broadcasted_iota(jnp.int32, acc_s.shape, 0)
+        place = jnp.sum((acc_s >= best).astype(jnp.int32), axis=0,
+                        keepdims=True)                           # (1,Q)
+        # a query whose best is no entrant has place == k_pad: its
+        # column is written back as it was
+        stay = kpos < place
+        put = kpos == place
+        out_s_ref[:] = jnp.where(
+            stay, acc_s, jnp.where(put, best, pltpu.roll(acc_s, 1, 0)))
+        out_i_ref[:] = jnp.where(
+            stay, acc_i, jnp.where(put, first + i * block_n,
+                                   pltpu.roll(acc_i, 1, 0)))
+        best = jnp.max(cs, axis=0, keepdims=True)
+        return n + 1, best, entrants(best)
 
-    _, acc_s, acc_i = jax.lax.fori_loop(
-        0, k_pad, select,
-        (comb_s,
-         jnp.full(out_s_ref.shape, NEG_INF, jnp.float32),
-         jnp.full(out_i_ref.shape, -1, jnp.int32)))
-    out_s_ref[:] = acc_s
-    out_i_ref[:] = acc_i
+    best = jnp.max(scores, axis=0, keepdims=True)
+    n, _, _ = jax.lax.while_loop(
+        lambda carry: carry[2] > 0, one_pass,
+        (jnp.int32(0), best, entrants(best)))
+    passes_ref[0, 0] += n
 
 
 @functools.lru_cache(maxsize=32)
@@ -372,10 +401,11 @@ def _fused_topk_fn(k: int, block_n: int, mxu_bf16: bool,
     """Compiled fused score+select program, cached per static config
     (query count and lane shape retrace under the same jit).  Returns
     run(vectors, queries, mask, vnorm) -> ((Q, k) scores, (Q, k)
-    GLOBAL indices), filler entries (fewer than k candidates) carry
-    score NEG_INF and index -1.  vnorm is accepted for signature
-    parity with _topk_fn and ignored — the kernel gets row norms for
-    free from the VMEM tile."""
+    GLOBAL indices, (2,) int32 [selection passes run, tiles scanned]),
+    filler entries (fewer than k candidates) carry score NEG_INF and
+    index -1.  vnorm is accepted for signature parity with _topk_fn
+    and ignored — the kernel gets row norms for free from the VMEM
+    tile."""
     k_pad = max(8, -(-k // 8) * 8)
 
     def run(vectors, queries, mask, vnorm):
@@ -399,7 +429,7 @@ def _fused_topk_fn(k: int, block_n: int, mxu_bf16: bool,
         qnorm = jnp.linalg.norm(qs, axis=-1, keepdims=True).T    # (1,Qp)
         block = min(block_n, n_pad)
         grid = (n_pad // block,)
-        out_s, out_i = pl.pallas_call(
+        out_s, out_i, passes = pl.pallas_call(
             functools.partial(_fused_topk_kernel, k_pad=k_pad,
                               block_n=block, mxu_bf16=mxu_bf16),
             grid=grid,
@@ -418,14 +448,19 @@ def _fused_topk_fn(k: int, block_n: int, mxu_bf16: bool,
                              memory_space=pltpu.VMEM),
                 pl.BlockSpec((k_pad, q_pad), lambda i: (0, 0),
                              memory_space=pltpu.VMEM),
+                pl.BlockSpec((1, 1), lambda i: (0, 0),
+                             memory_space=pltpu.SMEM),
             ],
             out_shape=[
                 jax.ShapeDtypeStruct((k_pad, q_pad), jnp.float32),
                 jax.ShapeDtypeStruct((k_pad, q_pad), jnp.int32),
+                jax.ShapeDtypeStruct((1, 1), jnp.int32),
             ],
+            scratch_shapes=[pltpu.VMEM((block, q_pad), jnp.float32)],
             interpret=interpret,
         )(v, qs, qnorm, m)
-        return out_s[:k, :q].T, out_i[:k, :q].T
+        select = jnp.stack([passes[0, 0], jnp.int32(grid[0])])
+        return out_s[:k, :q].T, out_i[:k, :q].T, select
 
     return DEVTIME.register("searcher.fused_topk", jax.jit(run))
 
@@ -436,7 +471,9 @@ def topk_program(k: int, *, batched: bool = True,
                  interpret: bool = False):
     """The compiled (vectors, queries, mask, vnorm) -> (scores, indices)
     top-k program — the surface the search daemon pre-compiles its
-    QB-bucketed batch programs from.
+    QB-bucketed batch programs from.  A batched program returns a
+    third value, the (2,) int32 [selection passes, tiles] the fused
+    kernel ran (0, 0 from the score-matrix path, which runs none).
 
     fused=None auto-selects: the streaming Pallas kernel whenever the
     pallas path is on and k <= FUSED_K_MAX — the (N, Q) score matrix
@@ -463,7 +500,7 @@ def topk_program(k: int, *, batched: bool = True,
         return fn
 
     def single(vectors, queries, mask, vnorm):
-        s, i = fn(vectors, queries, mask, vnorm)
+        s, i, _ = fn(vectors, queries, mask, vnorm)
         return s[0], i[0]
 
     return single
@@ -508,7 +545,7 @@ def cosine_topk_batch(vectors, queries, k: int, mask=None, *,
     fn = topk_program(k, batched=True, use_pallas=use_pallas,
                       mxu_bf16=mxu_bf16, block_n=block_n, fused=fused,
                       interpret=interpret)
-    top_s, top_i = fn(vectors, queries, mask, vnorm)
+    top_s, top_i, _ = fn(vectors, queries, mask, vnorm)
     out = tuple(jax.device_get((top_s, top_i)))
     close_mark(DEVTIME.take_mark("searcher.topk"))
     close_mark(DEVTIME.take_mark("searcher.fused_topk"))

@@ -32,9 +32,9 @@ def _topk_program(mesh: Mesh, axis: str, local_n: int, d: int, nq: int,
 
     def local_then_merge(v_local, q, m_local):
         if fused:
-            ls, li = _fused_topk_fn(k_local, 1024, mxu_bf16,
-                                    interpret)(v_local, q, m_local,
-                                               None)
+            ls, li, _ = _fused_topk_fn(k_local, 1024, mxu_bf16,
+                                       interpret)(v_local, q, m_local,
+                                                  None)
             s, i = ls[0], li[0]
         else:
             # local fused scores + top-k on this shard

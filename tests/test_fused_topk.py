@@ -179,8 +179,9 @@ def test_program_selection():
     rng = np.random.default_rng(0)
     v = rng.normal(size=(FUSED_K_MAX + 50, 16)).astype(np.float32)
     q = rng.normal(size=(1, 16)).astype(np.float32)
-    sf, _ = fused(v, q, None, None)
-    sl, _ = legacy(v, q, None, None)
+    sf, _, _ = fused(v, q, None, None)
+    sl, _, none_run = legacy(v, q, None, None)
+    assert np.asarray(none_run).tolist() == [0, 0]   # one shape for both
     assert np.asarray(sf).shape == (1, 8)
     assert np.asarray(sl).shape == (1, FUSED_K_MAX + 1)
 
@@ -195,7 +196,8 @@ def test_fused_output_is_o_of_kq():
     q = rng.normal(size=(3, 32)).astype(np.float32)
     shapes = [np.asarray(x).shape
               for x in jax.tree_util.tree_leaves(fn(v, q, None, None))]
-    assert shapes == [(3, 5), (3, 5)]
+    # scores, indices, and the [passes, tiles] count
+    assert shapes == [(3, 5), (3, 5), (2,)]
     # and the jaxpr-level output of the pallas_call itself is k*Q
     # padded, never (N, Q): the kernel's out_shape is (k_pad, q_pad)
     from libsplinter_tpu.ops.similarity import _fused_topk_fn
@@ -215,4 +217,131 @@ def test_fused_output_is_o_of_kq():
     assert eqns, "fused path must lower through pallas_call"
     for eqn in eqns:
         for var in eqn.outvars:
-            assert var.aval.shape[0] == 8      # k=5 padded to 8, not N
+            # k=5 padded to 8, not N; the pass counter is one word
+            assert var.aval.shape in ((8, 8), (1, 1))
+
+
+# ---------------------------------------------------------------------------
+# the selection follows the tile's entrants (PR 46): lanes that drive
+# the pass loop to its ends, and the pass count itself
+# ---------------------------------------------------------------------------
+
+TILES = 6
+K_PAD = 16          # k = 10 rounds up to two sublane groups
+
+
+def _ramp(n, d, ascending):
+    """Rows whose cosine with the first axis falls strictly with the
+    row index (or rises): every tile of an ascending lane is full of
+    entrants, no tile of a descending one after the first has any."""
+    theta = np.linspace(0.1, 1.4, n)
+    v = np.zeros((n, d), np.float32)
+    v[:, 0], v[:, 1] = np.cos(theta), np.sin(theta)
+    return v[::-1].copy() if ascending else v
+
+
+def _axis_query(d, nq=1):
+    q = np.zeros((nq, d), np.float32)
+    q[:, 0] = 1.0
+    return q
+
+
+def _selection_case(name):
+    """(vectors, queries, mask, k, mxu_bf16) of one named lane."""
+    rng = np.random.default_rng(46)
+    n, d, k = BLOCK * TILES, 32, 10
+    vectors = rng.normal(size=(n, d)).astype(np.float32)
+    queries = rng.normal(size=(5, d)).astype(np.float32)
+    mask, bf16 = None, False
+    if name in ("descending", "ascending"):
+        vectors = _ramp(n, d, name == "ascending")
+        queries = _axis_query(d, 3)
+        queries[1, 1] = 0.5           # the same order, other scores
+        queries[2, 0] = -1.0          # and the opposite order
+    elif name == "all_equal":
+        vectors = np.tile(vectors[:1], (n, 1))
+    elif name == "pad_query":
+        queries[[1, 3]] = 0.0         # zero norm: every score 0.0
+    elif name == "mask_few":
+        mask = np.zeros(n, np.float32)
+        mask[[3, BLOCK + 1, 2 * BLOCK, n - 1]] = 1.0
+    elif name == "short_lane":
+        vectors = vectors[:20]
+    elif name == "mxu_bf16":
+        vectors = rng.standard_normal((n, 128)).astype(np.float32)
+        queries = rng.standard_normal((5, 128)).astype(np.float32)
+        bf16 = True
+    elif name == "near_rows":
+        # the cell's own queries: stored rows with a little noise
+        queries = (vectors[[7, 200, 383]]
+                   + 0.05 * rng.normal(size=(3, d))).astype(np.float32)
+    else:
+        raise ValueError(name)
+    return vectors, queries, mask, k, bf16
+
+
+@pytest.mark.parametrize("name", [
+    "descending", "ascending", "all_equal", "pad_query", "mask_few",
+    "short_lane", "mxu_bf16", "near_rows"])
+def test_selection_cases(name):
+    # the reference's stable argsort is "smallest index wins" for
+    # all_equal, and _assert_parity holds mask_few's places past its
+    # four candidates to the (NEG_INF, -1) filler
+    vectors, queries, mask, k, bf16 = _selection_case(name)
+    _assert_parity(vectors, queries, mask, k, mxu_bf16=bf16)
+
+
+def _select_count(vectors, queries, mask=None, k=10):
+    """[passes, tiles] of one fused dispatch."""
+    fn = topk_program(k, fused=True, interpret=True, use_pallas=True,
+                      block_n=BLOCK)
+    return [int(x) for x in np.asarray(fn(vectors, queries, mask,
+                                          None)[2])]
+
+
+@pytest.mark.parametrize("name,passes,tiles", [
+    # k_pad in tile 0 (its 16 largest, one a pass) and none after
+    ("descending", K_PAD, TILES),
+    ("descending_first_tile", K_PAD, 1),
+    # every tile's 16 largest beat everything before them
+    ("ascending", K_PAD * TILES, TILES),
+    # ties with the k_pad-th never enter: tile 0 fills, then nothing
+    ("all_equal", K_PAD, TILES),
+    # nothing is a candidate: the accumulator is never touched
+    ("all_masked", 0, TILES),
+    # a tile with fewer candidates than k_pad runs one pass each
+    ("short_lane", 5, 1),
+])
+def test_pass_count(name, passes, tiles):
+    d = 32
+    mask = None
+    queries = _axis_query(d)
+    if name.startswith("descending") or name == "ascending":
+        vectors = _ramp(BLOCK * TILES, d, name == "ascending")
+        if name.endswith("first_tile"):
+            vectors = vectors[:BLOCK]
+    elif name == "all_equal":
+        vectors = np.tile(_ramp(1, d, False), (BLOCK * TILES, 1))
+    elif name == "all_masked":
+        vectors = _ramp(BLOCK * TILES, d, False)
+        mask = np.zeros(len(vectors), np.float32)
+    else:
+        # 5 rows: the pad queries beside the live one (all scores 0.0)
+        # have their 5 entrants in the same 5 passes
+        vectors = _ramp(5, d, False)
+    assert _select_count(vectors, queries, mask) == [passes, tiles]
+
+
+@pytest.mark.parametrize("k", [1, 10, 64])
+def test_pass_count_bounds(k):
+    """A lane in no order: at least the first tile's fill, never more
+    than k_pad a tile, and far fewer than that once the accumulator
+    holds real scores."""
+    rng = np.random.default_rng(k)
+    vectors = rng.normal(size=(BLOCK * 16, 32)).astype(np.float32)
+    queries = rng.normal(size=(4, 32)).astype(np.float32)
+    k_pad = max(8, -(-k // 8) * 8)
+    passes, tiles = _select_count(vectors, queries, k=k)
+    assert tiles == 16
+    assert min(k_pad, BLOCK) <= passes <= k_pad * tiles
+    assert passes < k_pad * tiles // 2

@@ -998,12 +998,12 @@ def test_64_dirty_drain_of_100k_slots_scans_under_1000():
         assert sr.run_once() == 64
         per_drain = sr.stats.lane_slots_scanned - first
         assert 64 <= per_drain < 1000, per_drain
-        assert sr.stats.journal_fallbacks == 1   # the first attach
-        assert sr.stats.journal_rows >= 128
+        assert sr.lane.journal_fallbacks == 1    # the first attach
+        assert sr.lane.journal_rows >= 128
         sr._publish_beat()
         snap = json.loads(st.get(P.KEY_SEARCH_STATS).rstrip(b"\0"))
-        assert snap["lane_audit_rows"] == 0
-        assert snap["journal_fallbacks"] == 1
+        assert snap["lane"]["lane_audit_rows"] == 0
+        assert snap["lane"]["journal_fallbacks"] == 1
         assert snap["lane_slots_scanned"] == sr.stats.lane_slots_scanned
         assert snap["lane_slots_scanned"] - first < 1000   # no audit in it
         for k in ("lane_slots_scanned", "journal_rows",
@@ -1057,12 +1057,49 @@ def test_the_beat_audits_the_lane_and_publishes_what_it_found(store_2k):
     assert row not in _result(store, "__sqtmp_a")["i"]
     sr._publish_beat()
     snap = json.loads(store.get(P.KEY_SEARCH_STATS).rstrip(b"\0"))
-    assert snap["lane_audit_rows"] == 1
     assert snap["lane"]["lane_audit_rows"] == 1
+    # once in the record: the lane's counters are not copied beside
+    # `served` (the 2 KB heartbeat has no room for both)
+    assert "lane_audit_rows" not in snap and "journal_rows" not in snap
     _request(store, "__sqtmp_a", q)
     assert sr.run_once() == 1
     assert sr._live[row] == 1.0                  # the audit's rows too
     assert _result(store, "__sqtmp_a")["i"][0] == row
+
+
+def test_a_serviced_drain_counts_the_kernels_passes(store_2k):
+    """The fused program returns the selection passes it ran and the
+    tiles it scanned; the drain's fetch adds both to the stats, and
+    the heartbeat publishes them (the unfused program counts none)."""
+    store = store_2k
+    rng = np.random.default_rng(46)
+    _fill_docs(store, 40, rng)
+    sr = Searcher(store, fused=True, interpret=True, use_pallas=True,
+                  block_n=64)
+    sr.attach()
+    qs = rng.normal(size=(3, store.vec_dim)).astype(np.float32)
+    for i, q in enumerate(qs):
+        _request(store, f"__sqtmp_p{i}", q)
+    assert sr.run_once() == 3
+    assert sr.stats.dispatches == 1
+    tiles = store.nslots // 64
+    assert sr.stats.select_tiles == tiles
+    # at least the first tile with rows fills k_pad = 16 places, and a
+    # tile never runs more than k_pad passes
+    assert 16 <= sr.stats.select_passes <= 16 * tiles
+    lane = np.array(store.vectors)
+    hidden = {store.find_index(f"__sqtmp_p{i}") for i in range(3)}
+    for i, q in enumerate(qs):
+        ref = _dense_ref(lane, q, exclude=hidden)
+        assert _result(store, f"__sqtmp_p{i}")["i"] == \
+            list(np.argsort(-ref)[:5])
+    sr._publish_beat()
+    snap = json.loads(store.get(P.KEY_SEARCH_STATS).rstrip(b"\0"))
+    assert snap["select_passes"] == sr.stats.select_passes
+    assert snap["select_tiles"] == tiles
+    _request(store, "__sqtmp_p0", qs[0])
+    assert sr.run_once() == 1
+    assert sr.stats.select_tiles == 2 * tiles
 
 
 # ------------------------- the gather reads the journal (LabelCursor)
@@ -1142,7 +1179,7 @@ def test_steady_drains_walk_no_slots():
         assert calls == {"enumerate_indices": 0, "epochs": 0}
         assert 0 < sr.stats.gather_slots_scanned - scanned0 < 8 * asked
         assert sr.stats.gather_fallbacks == 1
-        assert sr.stats.journal_fallbacks == 1
+        assert sr.lane.journal_fallbacks == 1
     finally:
         sr.stop()
         if t.is_alive():

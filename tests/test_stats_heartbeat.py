@@ -173,6 +173,8 @@ def _searcher_payload():
 
     stats = {f.name: 98765 for f in dataclasses.fields(SearcherStats)}
     stats["sweep_keys"] = 98765 * 1_572_864
+    # every dispatch scans the lane's 1,536 tiles, about a pass a tile
+    stats["select_tiles"] = stats["select_passes"] = 98765 * 1_536
     names = [f"search.{p}" for p in (*P.SEARCH_LOOP_PHASES,
                                      *P.SEARCH_STAGES, "drain_cycle")]
     spans = {n: {"n": 98765, "total_ms": 98765.4, "max_ms": 17232.9}
@@ -247,6 +249,7 @@ def test_searcher_heartbeat_keeps_what_is_read_at_2k(tmp_path):
         assert snap["spans"]["search.commit"]["total_ms"] == 98765.4
         assert snap["devtime"]["fused_topk"]["total_ms"] == 98765.4
         assert snap["startup_ms"]["total"] == 131183.0
+        assert snap["select_passes"] == snap["select_tiles"] == 151703040
     finally:
         st.close()
         Store.unlink(name)

@@ -15,7 +15,10 @@ decode path.  A model may keep more than one row's logits a step
 own admissions — models/afmoe.py keeps two, lane 0 for admissions that
 resumed from the prefix cache and lane 1 for those that prefilled from
 nothing, so that a queue of both kinds of request is sampled on both
-paths whatever order they arrive in.
+paths whatever order they arrive in; models/nemotron_h.py keeps three,
+by the answer's BUDGET (short, middle, long): a long answer holds its
+lane for hundreds of steps, and the short ones would never be sampled
+behind it.
 
 A record is `<dir>/<n>.npz` (written under a temporary name first):
 key, wall-clock `t_admit` / `t_done`, `prompt`, `n_prefix`, `tokens`

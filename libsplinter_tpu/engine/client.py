@@ -171,7 +171,8 @@ def wait_with_repulse(store, key: str, left_ms: float, check, *,
 
 
 def _stamp_qos(store, key: str, tenant: int,
-               deadline_ts: float | None, trace=None) -> None:
+               deadline_ts: float | None, trace=None,
+               max_new_tokens: int | None = None) -> None:
     """Tag a freshly-written request with its tenant, absolute
     deadline, and trace context (after set, before the bump — the
     stamp discipline).  `trace` follows protocol.stamp_trace_ctx:
@@ -182,6 +183,8 @@ def _stamp_qos(store, key: str, tenant: int,
         P.stamp_tenant(store, key, tenant)
     if deadline_ts is not None:
         P.stamp_deadline(store, key, deadline_ts)
+    if max_new_tokens is not None:
+        P.stamp_max_new(store, key, max_new_tokens)
     if trace:
         P.stamp_trace_ctx(store, key, trace)
 
@@ -191,7 +194,8 @@ def submit_completion(store, key: str, prompt: str | bytes, *,
                       tenant: int = 0,
                       deadline_ms: float | None = None,
                       trace=None,
-                      retry: bool = True):
+                      retry: bool = True,
+                      max_new_tokens: int | None = None):
     """The completer-lane client: write `prompt` to `key`, raise the
     INFER request, wait for READY.
 
@@ -201,7 +205,9 @@ def submit_completion(store, key: str, prompt: str | bytes, *,
     "deadline_expired"} for a deadline the daemon declined), or None
     on timeout / down lane.  `deadline_ms` (relative) stamps an
     absolute wall-clock deadline the daemon fast-fails behind;
-    `tenant` tags the request for per-tenant admission.
+    `tenant` tags the request for per-tenant admission;
+    `max_new_tokens` stamps the request's own answer budget, which
+    the continuous lane honours up to its --max-new-tokens.
     """
     deadline_ts = (time.time() + deadline_ms / 1e3
                    if deadline_ms is not None else None)
@@ -212,7 +218,8 @@ def submit_completion(store, key: str, prompt: str | bytes, *,
         # previous completion/shed — left set, the wait loop below
         # would return the raw prompt instantly as the "completion"
         store.label_clear(key, P.LBL_READY | P.LBL_SERVICING)
-        _stamp_qos(store, key, tenant, deadline_ts, trace)
+        _stamp_qos(store, key, tenant, deadline_ts, trace,
+                   max_new_tokens)
         store.label_or(key, P.LBL_INFER_REQ | P.LBL_WAITING)
         store.bump(key)
 

@@ -170,6 +170,12 @@ class CompleterStats:
     state_restores: int = 0
     state_snapshots: int = 0
     state_cut_tokens: int = 0
+    # answers by the request (protocol.stamp_max_new): requests that
+    # carried a budget of their own, tokens emitted by the continuous
+    # lane and the answers it finished
+    budgeted_requests: int = 0
+    answer_tokens: int = 0
+    answers_finished: int = 0
     # -- models with a window page group (models/afmoe.py): prefix
     # hits that mapped their whole match because the window's tail was
     # still held, and prefix tokens a hit gave up for want of a tail
@@ -502,6 +508,20 @@ class Completer:
                 deadline = None
         return P.read_tenant(labels), deadline
 
+    def _max_new_of(self, idx: int) -> int:
+        """The answer budget of a waiting slot: the request's own
+        stamp (protocol.stamp_max_new) capped at the daemon's
+        --max-new-tokens, or the daemon's where it carries none — one
+        bit-test of the label word then, never a lookup."""
+        st = self.store
+        try:
+            if not st.labels_at(idx) & P.LBL_MAX_NEW:
+                return self.max_new
+            n = P.read_max_new(st, idx, epoch=st.epoch_at(idx))
+        except (KeyError, OSError):
+            return self.max_new
+        return self.max_new if n is None else min(n, self.max_new)
+
     def _terminal_reject(self, idx: int, payload: bytes,
                          counter: str, tenant: int) -> bool:
         """Claim-and-reject a waiting request without spending a batch
@@ -792,6 +812,11 @@ class Completer:
             self.tenants.bump(P.read_tenant(labels_now), "admitted")
             if labels_now & P.LBL_DEADLINE:
                 P.clear_deadline(st, idx)
+        if labels_now & P.LBL_MAX_NEW:
+            # the answer's budget was read before the claim
+            # (_max_new_of); its stamp must not outlive the request
+            P.clear_max_new(st, idx)
+            self.stats.budgeted_requests += 1
 
         # WAITING → SERVICING, visible to watchers immediately
         st.label_clear(key, P.LBL_INFER_REQ | P.LBL_WAITING)
@@ -1266,8 +1291,10 @@ class Completer:
         bp_memo = self._bp_memo
         bp_memo.clear()
 
-        def worst_len(n_ids: int, replay: int = 0) -> int:
-            """Worst-case cache length for an admitted prompt.  Decode
+        def worst_len(n_ids: int, replay: int = 0,
+                      max_new: int | None = None) -> int:
+            """Worst-case cache length for an admitted prompt whose
+            answer budget is `max_new` (None: the daemon's).  Decode
             appends whole `step`-token chunks (paged_decode_chunk),
             so the final chunk can grow the cache up to step-1 tokens
             PAST the prompt + max_new budget — the admission
@@ -1277,8 +1304,8 @@ class Completer:
             the prefill sample; the remaining max_new - 1 arrive in
             whole chunks; a fully cached prompt adds the `replay` of
             its last token (prefix_cache.Seat.plan)."""
-            chunks = (-(-(self.max_new - 1) // step)
-                      if self.max_new > 1 else 0)
+            budget = self.max_new if max_new is None else max_new
+            chunks = -(-(budget - 1) // step) if budget > 1 else 0
             return min(n_ids + chunks * step + replay, cfg.max_len)
 
         def span(row: dict | None, name: str, ms: float) -> None:
@@ -1435,9 +1462,12 @@ class Completer:
                     # in the next
                     break
                 with tracer.span("infer.gather", leaf=True):
+                    # the request's own budget, where it stamped one
+                    budget = self._max_new_of(idx)
                     if len(ids):
-                        short = seat.plan(worst_len(len(ids)),
-                                          worst_len(len(ids), step))
+                        short = seat.plan(
+                            worst_len(len(ids), 0, budget),
+                            worst_len(len(ids), step, budget))
                         if short is not None:
                             self.stats.join_backpressure += 1
                             bp_memo[idx] = (e, short)
@@ -1454,7 +1484,7 @@ class Completer:
                         continue
                 r = free.pop(0)
                 rows[r] = {"key": key, "t0": t0, "n_tok": 0,
-                           "pending": b"", "remaining": self.max_new,
+                           "pending": b"", "remaining": budget,
                            "stamp": stamp,
                            # deadline retained for the chunk-edge
                            # mid-decode abort (the __dl_ stamp itself
@@ -1466,7 +1496,7 @@ class Completer:
                            # decode steps still dispatchable before
                            # every budgeted token is in flight
                            "serial": next(serial),
-                           "disp_left": self.max_new - 1,
+                           "disp_left": budget - 1,
                            "spans": ([] if traced and stamp is not None
                                      else None),
                            "wall0": time.perf_counter()}
@@ -1510,6 +1540,19 @@ class Completer:
                     if traced:
                         span(rows[r], "state_restore",
                              (time.perf_counter() - t_s) * 1e3)
+                zeroed = False
+                if seat.state_src is None and not seat.hit_bids \
+                        and cache.needs_state:
+                    # a prompt from nothing of a model with per-row
+                    # recurrent state starts from the zero state: the
+                    # slot still holds what its last row left
+                    t_s = time.perf_counter()
+                    with tracer.annotation("infer.state_zero"):
+                        m.state_zero(cache, r)
+                    zeroed = True
+                    if traced:
+                        span(rows[r], "state_zero",
+                             (time.perf_counter() - t_s) * 1e3)
                 suffix = seat.suffix
                 if cache.quantized and suffix:
                     # the quantized append/commit path: the commit
@@ -1535,7 +1578,8 @@ class Completer:
                             span(rows[r], "state_snapshot",
                                  (time.perf_counter() - t_s) * 1e3)
                     join = {"join": Join(r, ids, seat.match,
-                                         bool(seat.hit_bids), snap),
+                                         bool(seat.hit_bids), snap,
+                                         zeroed),
                             "key": key, "reserve": seat.reserve,
                             "tenant": tenant, "w_s0": w_s0}
                     if m.rides_round(join["join"]):
@@ -1563,7 +1607,7 @@ class Completer:
                     if traced:
                         span(rows[r], "join",
                              (time.perf_counter() - ta) * 1e3)
-                    rows[r]["disp_left"] = self.max_new
+                    rows[r]["disp_left"] = budget
                     fresh[r] = int(ids[-1])
                 n += 1
             if round_joins:
@@ -1624,7 +1668,9 @@ class Completer:
                                 j["tenant"], "prefix_cached_pages", ins)
                     # a model with two audit lanes (engine/audit.py)
                     # keeps one for each way a prompt is served
-                    lane = m.audit_lane(match, len(ids) - match) \
+                    lane = m.audit_lane(match, len(ids) - match,
+                                        rows[r]["remaining"]
+                                        / max(self.max_new, 1)) \
                         if self.audit is not None \
                         and self.audit.lanes > 1 else 0
                     if self.audit is not None and self.audit.wants(lane):
@@ -1693,6 +1739,8 @@ class Completer:
                     stages[name] = stages.get(name, 0.0) + ms
             self._finalize(row["key"], row["t0"], row["n_tok"],
                            truncated, vanished, stages=stages)
+            self.stats.answers_finished += 1
+            self.stats.answer_tokens += row["n_tok"]
             if row.get("stamp") is not None \
                     and row.get("spans") is not None:
                 tid, ts = row["stamp"]
@@ -2233,6 +2281,13 @@ class Completer:
             payload["tenants"] = tenants
         prune_idle_counters(
             payload, bool(self.qos.high_water is not None or tenants))
+        if not self.stats.budgeted_requests:
+            # no request ever stamped a budget of its own: the
+            # heartbeat stays as it was (tokens / completions say the
+            # same of answers that all run to the daemon's budget)
+            for k in ("budgeted_requests", "answer_tokens",
+                      "answers_finished"):
+                payload.pop(k, None)
         if not self._bp_memo and self._paged_cache is None:
             payload.pop("bp_memo", None)  # dense lane: dead gauge
         acc = self._spec_acceptance()

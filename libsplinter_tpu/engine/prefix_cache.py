@@ -848,14 +848,18 @@ class Join:
     """One seated request as its model's `join` sees it: the batch
     row, the prompt's ids, how many of them the row's table maps
     already, whether that is a prefix hit (the suffix prefills atop
-    it) or a miss (the whole prompt prefills), and the snapshot the
-    prefill leaves — (state slot, token count) or None."""
+    it) or a miss (the whole prompt prefills), the snapshot the
+    prefill leaves — (state slot, token count) or None —, and whether
+    the lane has zeroed the row's state slot already (a miss of a
+    model with state slots, zeroed at its seat under a span of its
+    own)."""
 
     row: int
     ids: list
     match: int
     hit: bool
     snap: tuple[int, int] | None = None
+    zeroed: bool = False
 
 
 class Seat:

@@ -24,6 +24,7 @@ LBL_SEARCH_REQ = 0x1 << 57     # "search me" — wakes the search daemon
 LBL_TRACED = 0x1 << 58         # request carries a trace stamp (obs)
 LBL_DEADLINE = 0x1 << 52       # request carries a deadline stamp (QoS)
 LBL_DECODE_READY = 0x1 << 53   # prefill committed; awaiting decode adoption
+LBL_MAX_NEW = 0x1 << 54        # request carries its own answer budget
 LBL_DEBUG = 0x1 << 59          # debug channel (sidecar watches this)
 LBL_INFER_REQ = 0x1 << 60      # "complete me" — wakes the completion daemon
 LBL_SERVICING = 0x1 << 61      # completion in progress
@@ -37,6 +38,7 @@ BIT_SCRIPT_REQ = 56
 BIT_SEARCH_REQ = 57
 BIT_DEADLINE = 52
 BIT_DECODE_READY = 53
+BIT_MAX_NEW = 54
 BIT_DEBUG = 59
 BIT_INFER_REQ = 60
 
@@ -200,6 +202,9 @@ CONT_INFER_STAGES = ("join", "sample", "decode", "collect", "flush",
                      # snapshot into the joining row, and finding the
                      # slot its own snapshot goes to (leaf spans)
                      "state_restore", "state_snapshot",
+                     # ... and zeroing the slot of a row that starts
+                     # from nothing (leaf span)
+                     "state_zero",
                      # a model with a window page group: giving back
                      # the pages a row has slid past, after a join's
                      # prefill pieces and after a chunk (leaf span)
@@ -215,7 +220,7 @@ CONT_INFER_STAGES = ("join", "sample", "decode", "collect", "flush",
 # (a whole admission round, enclosing) gather = finding the waiting
 # rows, QoS order, the backpressure memo and the reservation check;
 # prepare = render + tokenize + the WAITING->SERVICING claim; then the
-# stages prefix_hit, state_restore, state_snapshot, join, sample (a
+# stages prefix_hit, state_restore, state_zero, state_snapshot, join, sample (a
 # prefill lane: handoff; a decode lane: adopt); emit = the per-token
 # host work behind a join's sample or a collected chunk
 # (token_to_piece, streaming appends, finalize, pages freed); inside
@@ -990,6 +995,71 @@ def consume_deadline(store, idx: int,
     return ts
 
 
+# --- an answer's budget by the request -------------------------------------
+# A client that wants fewer new tokens than the daemon's
+# --max-new-tokens stamps its own budget next to its request (the
+# deadline stamp's discipline: after set, before the bump):
+# "<n>:<slot_epoch>" in the slot-indexed companion key max_new_key(idx),
+# flagged by LBL_MAX_NEW on the request key so unstamped rows cost one
+# bit-test, never a lookup.  The completion daemon's continuous lane
+# seats the row with min(stamp, its own budget) and reserves pages for
+# that; the stamp is consumed at the claim.  No stamp: the daemon's
+# budget, as ever.
+MAX_NEW_STAMP_PREFIX = "__mn_"
+
+
+def max_new_key(idx: int) -> str:
+    return f"{MAX_NEW_STAMP_PREFIX}{idx}"
+
+
+def stamp_max_new(store, key: str, n: int) -> bool:
+    """Client-side: attach an answer budget of `n` new tokens (>= 1)
+    to the pending request on `key`.  Returns True if the stamp
+    landed; never raises (a budget must never fail the request it
+    shortens)."""
+    try:
+        idx = store.find_index(key)
+        mk = max_new_key(idx)
+        store.set(mk, f"{max(1, int(n))}:{store.epoch_at(idx)}")
+        store.label_or(mk, LBL_DEBUG)
+        store.label_or(key, LBL_MAX_NEW)
+        return True
+    except (KeyError, OSError, ValueError):
+        return False
+
+
+def read_max_new(store, idx: int, epoch: int | None = None) -> int | None:
+    """Daemon-side: the answer budget stamped for slot idx, or None.
+    With `epoch` given (the gathered request's epoch), a stamp from a
+    different epoch is stale: consumed, and None returned."""
+    try:
+        raw = store.get(max_new_key(idx)).rstrip(b"\0").decode()
+        parts = raw.split(":")
+        n = int(parts[0])
+        e_stamp = int(parts[1]) if len(parts) > 1 and parts[1] else None
+    except (KeyError, OSError, ValueError, IndexError):
+        return None
+    if epoch is not None and e_stamp is not None and e_stamp != epoch:
+        clear_max_new(store, idx)
+        return None
+    return max(1, n)
+
+
+def clear_max_new(store, idx: int) -> None:
+    """Retire slot idx's budget stamp (companion key + LBL_MAX_NEW on
+    the slot's key).  Never raises."""
+    try:
+        store.unset(max_new_key(idx))
+    except (KeyError, OSError):
+        pass
+    try:
+        key = store.key_at(idx)
+        if key is not None:
+            store.label_clear(key, LBL_MAX_NEW)
+    except (KeyError, OSError):
+        pass
+
+
 # --- typed overload / expiry records --------------------------------------
 # The shed contract: a saturated lane past its high-water mark fails
 # overflow with THIS record instead of queueing unboundedly or
@@ -1225,6 +1295,9 @@ def shed_orphan_stamp(store, idx: int, labels: int) -> bool:
     if labels & LBL_DEADLINE and not labels & _REQ_LABELS:
         clear_deadline(store, idx)
         shed = True
+    if labels & LBL_MAX_NEW and not labels & _REQ_LABELS:
+        clear_max_new(store, idx)
+        shed = True
     if shed:
         return True
     if labels & LBL_DEBUG:
@@ -1232,16 +1305,16 @@ def shed_orphan_stamp(store, idx: int, labels: int) -> bool:
             key = store.key_at(idx)
         except (KeyError, OSError):
             return False
-        for pfx, retire in ((TRACE_STAMP_PREFIX, consume_trace_stamp),
-                            (DEADLINE_STAMP_PREFIX, clear_deadline)):
+        for pfx, flag, retire in (
+                (TRACE_STAMP_PREFIX, LBL_TRACED, consume_trace_stamp),
+                (DEADLINE_STAMP_PREFIX, LBL_DEADLINE, clear_deadline),
+                (MAX_NEW_STAMP_PREFIX, LBL_MAX_NEW, clear_max_new)):
             if key and key.startswith(pfx):
                 try:
                     tgt = int(key[len(pfx):])
                     tl = store.labels_at(tgt)
                 except (ValueError, KeyError, OSError):
                     return False
-                flag = LBL_TRACED if pfx == TRACE_STAMP_PREFIX \
-                    else LBL_DEADLINE
                 if tl & flag and not tl & _REQ_LABELS:
                     retire(store, tgt)
                     return True

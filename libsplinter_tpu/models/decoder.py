@@ -1271,7 +1271,10 @@ def join_one(model, cache, join) -> np.ndarray:
     decides what a round of one runs."""
     skw = ({"snap_at": join.snap[1], "snap_slot": join.snap[0]}
            if join.snap else {})
-    if join.hit:
+    if join.hit or join.zeroed:
+        # a hit's suffix atop the mapped prefix — or a miss whose state
+        # slot the lane zeroed at its seat: the same program from an
+        # empty table (models/kda.StateSlotPrograms)
         return model.paged_append_prefill(
             cache, np.asarray(join.ids[join.match:], np.int32), join.row,
             **skw)

@@ -440,7 +440,9 @@ class StateSlotPrograms:
     passes `snap_at` leaving the snapshot.  The family brings
     `_suffix_piece`, the dispatch of ONE piece, and `snap_granule`,
     the token count a snapshot's distance from the mapped length is a
-    multiple of."""
+    multiple of.  The warm-up below runs every program such a lane can
+    dispatch; a family with more of them (models/lfm2.py's rungs)
+    brings its own."""
 
     needs_state = True
     snap_granule = 1
@@ -535,6 +537,26 @@ class StateSlotPrograms:
         out = np.asarray(logits)
         close_mark(mark)
         return out
+
+    # -- warm-up -----------------------------------------------------------
+
+    def _warmup_paged_impl(self, cache: PagedKVCache, chunk: int,
+                           max_prompt: int | None) -> None:
+        """Every program the lane can dispatch: the suffix widths (the
+        first from nothing, so the zeroing runs too), a restore, the
+        decode chunk, the page copy."""
+        chunk_done = False
+        for sb in self.suffix_buckets:
+            n = max(1, min(sb, self.cfg.max_len - 1 - chunk))
+            self.sample(self.paged_prefill_row(
+                cache, np.ones((n,), np.int32), 0))
+            if not chunk_done and n + chunk < self.cfg.max_len:
+                self.paged_decode_chunk(
+                    cache, np.ones((cache.batch,), np.int32), chunk)
+                chunk_done = True
+            cache.free_row(0)
+        self.state_restore(cache, cache.state_spare, 0)
+        self._warm_cow(cache)
 
 
 class HybridCompletionModel(StateSlotPrograms, LatentCompletionModel):
@@ -668,23 +690,3 @@ class HybridCompletionModel(StateSlotPrograms, LatentCompletionModel):
         return LatentPendingChunk(
             out, last, n, DEVTIME.take_mark(self._devname("paged_chunk")),
             slots, kept)
-
-    # -- warm-up -----------------------------------------------------------
-
-    def _warmup_paged_impl(self, cache: PagedKVCache, chunk: int,
-                           max_prompt: int | None) -> None:
-        """Every program the lane can dispatch: the suffix widths (the
-        first from nothing, so the zeroing runs too), a restore, the
-        decode chunk, the page copy."""
-        chunk_done = False
-        for sb in self.suffix_buckets:
-            n = max(1, min(sb, self.cfg.max_len - 1 - chunk))
-            self.sample(self.paged_prefill_row(
-                cache, np.ones((n,), np.int32), 0))
-            if not chunk_done and n + chunk < self.cfg.max_len:
-                self.paged_decode_chunk(
-                    cache, np.ones((cache.batch,), np.int32), chunk)
-                chunk_done = True
-            cache.free_row(0)
-        self.state_restore(cache, cache.state_spare, 0)
-        self._warm_cow(cache)

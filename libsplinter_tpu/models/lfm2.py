@@ -532,7 +532,8 @@ class ConvCompletionModel(StateSlotPrograms, GroupPagePrograms,
     def audit_seat(self, lane: int, row: int) -> None:
         self.audit_rows[lane] = row
 
-    def audit_lane(self, match: int, n_suffix: int) -> int:
+    def audit_lane(self, match: int, n_suffix: int,
+                   budget_share: float = 1.0) -> int:
         return int(n_suffix > SHORT_SUFFIX)
 
     def _set_page(self, page: int) -> None:

@@ -400,6 +400,67 @@ FAMILIES: dict[str, dict] = {
                                   why="sliding_window must be null"),
             "max_window_layers": Key(None, None),    # unused without one
         }},
+    # the Nemotron-H key set: models/nemotron_h.py (ONE mixer a layer
+    # by a pattern: Mamba-2 state-space layers with state slots, un-
+    # gated relu^2 experts, grouped-query attention without positions)
+    "nemotron_h": {
+        "window": "max_position_embeddings", "experts": "n_routed_experts",
+        "dense": lambda arch: 0,
+        "keys": {
+            **{k: v for k, v in _COMMON.items()
+               if k not in ("hidden_act", "rms_norm_eps",
+                            "moe_intermediate_size")},
+            "norm_eps": Key("rms_eps", 1e-5, float),
+            "layer_norm_epsilon": Key(None, None, float),
+            "max_position_embeddings": Key(None, None),
+            "num_key_value_heads": Key("kv_heads"),
+            "head_dim": Key("head_dim"),
+            "hybrid_override_pattern": Key(None, cast=str),
+            "mamba_num_heads": Key("ssm_heads"),
+            "mamba_head_dim": Key("ssm_head_dim"),
+            "n_groups": Key("ssm_groups"),
+            "ssm_state_size": Key("ssm_state"),
+            "conv_kernel": Key("conv_kernel"),
+            "chunk_size": Key("chunk"),
+            # d_inner is mamba_num_heads x mamba_head_dim, whatever
+            # `expand` says
+            "expand": Key(None, None),
+            "time_step_min": Key("time_step_min", 0.001, float),
+            "time_step_max": Key("time_step_max", 0.1, float),
+            "time_step_floor": Key("time_step_floor", 1e-4, float),
+            "mamba_hidden_act": _served("only mamba_hidden_act silu is "
+                                        "served", "silu", str),
+            "mlp_hidden_act": _served("only the un-gated relu2 experts "
+                                      "are served: mlp_hidden_act relu2",
+                                      "relu2", str),
+            "use_conv_bias": _served("the convolution carries its bias: "
+                                     "use_conv_bias must be true", True),
+            "mamba_proj_bias": _served("mamba_proj_bias is not served"),
+            "mlp_bias": _served("mlp_bias is not served"),
+            "use_bias": _served("use_bias is not served"),
+            "moe_intermediate_size": Key("moe_mlp_dim"),
+            "moe_shared_expert_intermediate_size": Key("shared_mlp_dim"),
+            "n_routed_experts": Key("n_routed_experts"),
+            "num_experts_per_tok": Key("top_k"),
+            "n_shared_experts": Key("n_shared_experts", **_SHARED_EXPERT),
+            "norm_topk_prob": Key("norm_topk_prob", True, bool),
+            "routed_scaling_factor": Key("routed_scaling_factor", 1.0,
+                                         float),
+            "n_group": Key(None, **_ONE_GROUP),
+            "topk_group": Key(None, **_ONE_GROUP),
+            "sliding_window": Key(None, None, lambda v: v, only=(None,),
+                                  why="sliding_window must be null"),
+            # vestigial in this family: its attention applies no
+            # rotary embedding
+            "rope_theta": Key(None, None, float),
+            "partial_rotary_factor": Key(None, None, float),
+            # training and implementation hints: accepted, unused (the
+            # served stream is float32 whatever residual_in_fp32 says)
+            "rescale_prenorm_residual": Key(None, None, bool),
+            "residual_in_fp32": Key(None, None, bool),
+            "use_mamba_kernels": Key(None, None, bool),
+            "num_logits_to_keep": Key(None, None),
+        }},
 }
 LAYER_TYPES = {"sliding_attention": "window", "full_attention": "full"}
 SA_CONFIG_KEYS = frozenset(("indexer_head_dim", "indexer_num_heads",
@@ -577,19 +638,42 @@ def _finish_indexed(arch, fields, path):
         **fields)
 
 
+def _finish_ssm(arch, fields, path):
+    from .nemotron_h import PATTERN, SsmMoeConfig
+    n_layers = int(arch["num_hidden_layers"])
+    pattern = arch["hybrid_override_pattern"]
+    if len(pattern) != n_layers or set(pattern) - set(PATTERN):
+        raise ValueError(
+            f"{path}: hybrid_override_pattern must name {n_layers} "
+            f"layers, each among {sorted(PATTERN)} (M: Mamba-2, E: "
+            "experts, *: attention; a dense '-' layer is not served)")
+    eps = arch.get("layer_norm_epsilon")
+    if eps is not None and float(eps) != fields["rms_eps"]:
+        raise ValueError(f"{path}: layer_norm_epsilon and norm_eps "
+                         "must agree")
+    fields.pop("dense_mlp_dim")       # the `-` layers' width: none kept
+    fields.pop("dense_layers")
+    # the share keeps the first `layers`
+    return SsmMoeConfig(
+        kinds=tuple(PATTERN[c] for c in pattern[:fields.pop("layers")]),
+        model_layers=n_layers, **fields)
+
+
 FAMILIES["pangu_ultra_moe"]["finish"] = _finish_latent
 FAMILIES["kimi_linear"]["finish"] = _finish_hybrid
 FAMILIES["afmoe"]["finish"] = _finish_window
 FAMILIES["mimo_v2_flash"]["finish"] = _finish_sink_window
 FAMILIES["lfm2_moe"]["finish"] = _finish_conv
 FAMILIES["KeyeVL2"]["finish"] = _finish_indexed
+FAMILIES["nemotron_h"]["finish"] = _finish_ssm
 
 
 def load_model_description(path: str, *, max_len: int | None = None):
     """A model description file -> (config, seed): a LatentMoeConfig,
     a models/kda.HybridMoeConfig, a models/afmoe.WindowMoeConfig
     (AFMoE's setting, MiMo-V2-Flash's or Keye-VL-2.0's, the last with
-    an indexer) or a models/lfm2.ConvMoeConfig,
+    an indexer), a models/lfm2.ConvMoeConfig or a
+    models/nemotron_h.SsmMoeConfig,
     by the architecture's `model_type` (FAMILIES).
 
     {"architecture": {published keys verbatim, at their published
@@ -673,6 +757,9 @@ def completion_model_class(cfg):
     from .lfm2 import ConvCompletionModel, ConvMoeConfig
     if isinstance(cfg, ConvMoeConfig):
         return ConvCompletionModel
+    from .nemotron_h import SsmCompletionModel, SsmMoeConfig
+    if isinstance(cfg, SsmMoeConfig):
+        return SsmCompletionModel
     from .kda import HybridCompletionModel
     return HybridCompletionModel
 
@@ -1086,10 +1173,12 @@ class LatentCompletionModel:
         none)."""
         self.audit_row = row
 
-    def audit_lane(self, match: int, n_suffix: int) -> int:
+    def audit_lane(self, match: int, n_suffix: int,
+                   budget_share: float = 1.0) -> int:
         """The audit lane of a join that mapped `match` tokens and
-        prefilled `n_suffix`, for a model that keeps several
-        (`audit_lanes`): a resumed row 0, one from nothing 1."""
+        prefilled `n_suffix`, its answer's budget `budget_share` of the
+        daemon's, for a model that keeps several (`audit_lanes`): a
+        resumed row 0, one from nothing 1."""
         return int(not match)
 
     def resident_bytes(self) -> int:

@@ -15,7 +15,8 @@ the TPU side.  `sparse_moe` is the layer:
     axis of the stacked (count, hidden, width) tensors — and computes
     the part of the result its own experts give, for only the tokens
     routed to them: (token, expert) slots are sorted by held expert,
-    the rows gathered, three GROUPED matrix products run over the
+    the rows gathered, three GROUPED matrix products (two where
+    the experts have no gate matrix) run over the
     ragged groups (jax.experimental.pallas.ops.tpu.megablox on a TPU,
     jax.lax.ragged_dot elsewhere), and the gated rows scatter-add
     back.  Work follows the slots that landed here, not tokens x
@@ -126,16 +127,21 @@ def _tile(dim: int, want: int) -> int:
     return dim
 
 
-def grouped_matmul(lhs, rhs, group_sizes, *, interpret: bool = False):
+def grouped_matmul(lhs, rhs, group_sizes, *, interpret: bool = False,
+                   transpose_rhs: bool = False):
     """lhs[rows of group g] @ rhs[g] for consecutive row groups.
-    lhs: (M, K); rhs: (G, K, N); group_sizes: (G,) int32, sum <= M;
+    lhs: (M, K); rhs: (G, K, N) — (G, N, K) where `transpose_rhs`:
+    lhs[rows of g] @ rhs[g]^T, for a matrix whose output width N is no
+    whole number of 128-lane tiles (the chip keeps such a tensor with
+    N off the lanes and would copy it for the kernel at every call,
+    tests/test_chip_compile.py) —; group_sizes: (G,) int32, sum <= M;
     rows past the last group come back zero.  The megablox Pallas
     kernel on a TPU (its grid follows the rows present, not M x G),
     jax.lax.ragged_dot elsewhere."""
     if interpret or jax.default_backend() == "tpu":
         from jax.experimental.pallas.ops.tpu.megablox import gmm
         M, K = lhs.shape
-        N = rhs.shape[2]
+        N = rhs.shape[1 if transpose_rhs else 2]
         tm = GMM_TILES[0]
         pad = (-M) % tm
         if pad:
@@ -143,19 +149,22 @@ def grouped_matmul(lhs, rhs, group_sizes, *, interpret: bool = False):
         out = gmm(lhs, rhs, group_sizes, preferred_element_type=lhs.dtype,
                   tiling=(tm, _tile(K, GMM_TILES[1]),
                           _tile(N, GMM_TILES[2])),
-                  interpret=interpret)
+                  transpose_rhs=transpose_rhs, interpret=interpret)
         return out[:M] if pad else out
-    return jax.lax.ragged_dot(lhs, rhs, group_sizes)
+    return jax.lax.ragged_dot(
+        lhs, rhs.swapaxes(1, 2) if transpose_rhs else rhs, group_sizes)
 
 
 def _dispatch(x, ids, gates, live, wg, wu, wd, first: int,
-              interpret: bool, bank=None):
+              interpret: bool, bank=None, up_rows: bool = False):
     """One chunk of tokens through the held experts.  x: (T, H);
-    ids/gates: (T, k); live: (T,) bool.  Returns ((T, H) f32 partial
+    ids/gates: (T, k); live: (T,) bool; up_rows: wu is (count, M, H),
+    an output column a ROW (sparse_moe).  Returns ((T, H) f32 partial
     sum, (count,) int32 slots each held expert received)."""
     T, H = x.shape
     k = ids.shape[1]
-    count = wg.shape[-3]
+    gated = wg is not None
+    count = wd.shape[-3]
     local = ids - first
     held = (local >= 0) & (local < count) & live[:, None]
     # slots sorted by held expert; the rest sort behind under `count`
@@ -174,15 +183,20 @@ def _dispatch(x, ids, gates, live, wg, wu, wd, first: int,
         # (banks, count, ...): every other bank an empty group, so
         # that the grouped product reads the stack where it lies — a
         # slice of it would be copied for the kernel at every layer
-        banks = wg.shape[0]
+        banks = wu.shape[0]
         groups = jax.lax.dynamic_update_slice(
             jnp.zeros((banks * count,), jnp.int32), sizes,
             (bank * count,))
-        wg, wu, wd = (w.reshape(banks * count, *w.shape[2:])
+        wg, wu, wd = (w if w is None
+                      else w.reshape(banks * count, *w.shape[2:])
                       for w in (wg, wu, wd))
-    h = grouped_matmul(xs, wg, groups, interpret=interpret)
-    u = grouped_matmul(xs, wu, groups, interpret=interpret)
-    y = grouped_matmul((nn.silu(h) * u).astype(x.dtype), wd, groups,
+    h = grouped_matmul(xs, wg, groups, interpret=interpret) \
+        if gated else None
+    u = grouped_matmul(xs, wu, groups, interpret=interpret,
+                       transpose_rhs=up_rows)
+    # SwiGLU, or without a gate matrix down(relu(up(x))^2)
+    mid = nn.silu(h) * u if gated else jnp.square(nn.relu(u))
+    y = grouped_matmul(mid.astype(x.dtype), wd, groups,
                        interpret=interpret)
     # rows past the last group belong to no expert held here, and the
     # grouped product leaves them unwritten: mask, do not multiply
@@ -196,12 +210,17 @@ def sparse_moe(x, router, wg, wu, wd, *, top_k: int, first: int = 0,
                score: str = "softmax", norm_topk: bool = True,
                scale: float = 1.0, shared=None, live=None,
                interpret: bool = False, bank=None, route_x=None,
-               bias=None, live_chunk: int | None = None):
+               bias=None, live_chunk: int | None = None,
+               up_rows: bool = False):
     """The expert layer over the experts held here.
 
     x: (..., H) activations; router: (H, E) over ALL E experts of the
     model; wg/wu: (count, H, M), wd: (count, M, H) — experts
-    first..first+count-1; shared: None or (gate (H, Ms), up (H, Ms),
+    first..first+count-1; wg None: UN-GATED experts, down(relu(up(x))^2)
+    (two grouped products, not three; the shared expert's gate is then
+    None too); up_rows: wu is (count, M, H), the transpose — for a
+    width M that is no whole number of lane tiles (grouped_matmul);
+    shared: None or (gate (H, Ms), up (H, Ms),
     down (Ms, H)) of an expert every token passes through, counted
     here in full; live: None or a bool mask of x's leading shape —
     tokens outside it are routed nowhere and counted nowhere (the
@@ -234,7 +253,7 @@ def sparse_moe(x, router, wg, wu, wd, *, top_k: int, first: int = 0,
     chunk = live_chunk or MOE_CHUNK_TOKENS
     if T <= chunk:
         out, sizes = _dispatch(x2, ids, gates, live2, wg, wu, wd,
-                               first, interpret, bank)
+                               first, interpret, bank, up_rows)
     else:
         n = -(-T // chunk)
         pad = n * chunk - T
@@ -244,13 +263,14 @@ def sparse_moe(x, router, wg, wu, wd, *, top_k: int, first: int = 0,
             return a.reshape(n, chunk, *a.shape[1:])
 
         def one(c):
-            return _dispatch(*c, wg, wu, wd, first, interpret, bank)
+            return _dispatch(*c, wg, wu, wd, first, interpret, bank,
+                             up_rows)
 
         def one_if_live(c):
             return jax.lax.cond(
                 c[3].any(), one,
                 lambda c: (jnp.zeros((chunk, H), jnp.float32),
-                           jnp.zeros((wg.shape[-3],), jnp.int32)), c)
+                           jnp.zeros((wd.shape[-3],), jnp.int32)), c)
         args = (x2, ids, gates, live2)
         if live_chunk:
             order = jnp.argsort(~live2, stable=True)
@@ -263,8 +283,9 @@ def sparse_moe(x, router, wg, wu, wd, *, top_k: int, first: int = 0,
         sizes = sizes.sum(0)
     if shared is not None:
         sg, su, sd = shared
-        out = out + jnp.dot(nn.silu(jnp.dot(x2, sg)) * jnp.dot(x2, su),
-                            sd).astype(jnp.float32)
+        mid = jnp.square(nn.relu(jnp.dot(x2, su))) if sg is None \
+            else nn.silu(jnp.dot(x2, sg)) * jnp.dot(x2, su)
+        out = out + jnp.dot(mid, sd).astype(jnp.float32)
     return out.astype(x.dtype).reshape(*lead, H), sizes
 
 

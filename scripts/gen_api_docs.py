@@ -350,12 +350,12 @@ not size the per-request flight record):
 | `infer.loop` | one pass of the run loop: an admission round, or one dispatched chunk and the collect of the oldest in flight | no |
 | `infer.idle` | blocked in `signal_wait` with no row live and no request waiting | yes |
 | `infer.beat` | the 2 s beat at the head of a pass: the speculative-demotion check, the backpressure memo's sweep, `publish_stats`, the tier checkpoint | yes |
-| `infer.admit` | one whole admission round (`admit()`); its leaves are the next five rows, `infer.state_restore` / `infer.state_snapshot`, the first token's `infer.emit`, and a decode lane's `infer.adopt` | no |
+| `infer.admit` | one whole admission round (`admit()`); its leaves are the next five rows, `infer.state_restore` / `infer.state_zero` / `infer.state_snapshot`, the first token's `infer.emit`, and a decode lane's `infer.adopt` | no |
 | `infer.gather` | finding the waiting rows (`enumerate_indices` over every slot), the QoS order, the backpressure memo, the reservation check: everything of a round that is no other leaf's | yes |
 | `infer.prepare` | render + tokenize (`_read_rendered`, `encode`) and the WAITING → SERVICING claim (`_prepare`): two spans a request | yes |
 | `infer.prefix_hit` | the prefix cache's and the page allocator's part of a join: the radix walk (one span), then mapping the hit's pages and reserving the row's own (`ensure`; a second span, less the state restore inside it) | yes |
 | `infer.join` / `infer.sample` | a join's prefill from its dispatch to its logits (a fully cached prompt: its copy-on-write pass) — where the model's suffix program has a row axis, ONE dispatch for the hits of an admission round, recorded once a ROW at the round's wall over its rows (`n` stays the joined requests, the mean the amortised ms of one); from there to the row's first token — the window group's reserve, the tree's insert, the audit's copy, the draw (a round's: made in graph, nothing left of it here) | yes |
-| `infer.state_restore` / `infer.state_snapshot` | a model with recurrent state: copying a snapshot into the joining row; finding the slot its own snapshot goes to | yes |
+| `infer.state_restore` / `infer.state_zero` / `infer.state_snapshot` | a model with recurrent state: copying a snapshot into the joining row; zeroing the slot of a row that starts from nothing; finding the slot its own snapshot goes to | yes |
 | `infer.chunk` | one chunk round of a pass with rows live: deadline kills and the edge scan (its own bookkeeping), then `infer.decode`, `infer.rebid`, and `infer.collect` + `infer.emit` of the oldest chunk in flight | no |
 | `infer.emit` | the host work behind sampled tokens — pieces, streaming appends (`infer.flush`, a sum inside it), finalize, pages freed: one span a collected CHUNK and one a join's first token, never one a token | yes |
 | `infer.decode` / `infer.collect` | the async dispatch of a chunk; the blocked wait for the oldest chunk in flight (`pend.block()`) | yes |

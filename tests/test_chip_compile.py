@@ -1041,6 +1041,116 @@ def test_indexed_programs_at_published_widths(one_chip, monkeypatch,
 # to code they share with the stack above that alters their programs
 # shows here, and a PR that means to change them says so by changing
 # the line
+
+# ------------------------------------- the state-space / expert family
+
+SSM_ROWS, SSM_SLOTS, SSM_P, SSM_BLOCKS = 128, 161, 16, 2561
+SSM_POOL = (SSM_BLOCKS, 4, 2, PAGE, 128)
+SSM_STATE = (SSM_SLOTS, 64, 64, 128)
+
+
+def test_ssd_decode_step_kernel(one_chip):
+    """The state-space step over the benchmark's 128 rows of 161 state
+    slots (64 heads of 64 x 128 float32 a slot and layer, 338 MB):
+    compiles for the chip and keeps the slots in place."""
+    from libsplinter_tpu.ops.ssd_scan import ssd_decode_step
+    f32 = jnp.float32
+    compiled = jax.jit(
+        lambda x, dt, a, b, c, s: ssd_decode_step(
+            x, dt, a, b, c, s, force_pallas=True),
+        donate_argnums=(5,)).lower(
+        _spec(one_chip, (SSM_ROWS, 64, 64), f32),
+        _spec(one_chip, (SSM_ROWS, 64), f32), _spec(one_chip, (64,), f32),
+        _spec(one_chip, (SSM_ROWS, 8, 128), f32),
+        _spec(one_chip, (SSM_ROWS, 8, 128), f32),
+        _spec(one_chip, SSM_STATE, f32)).compile()
+    assert "ssd_decode_step" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 64e6
+
+
+@pytest.mark.parametrize("tokens", [128, 1024])
+def test_ssd_chunk_prefill_kernel(one_chip, tokens):
+    """The chunked prefill of one row (chunk 128, 8 groups of 8 heads a
+    program, bfloat16 operands) at the narrowest and the widest suffix
+    width."""
+    from libsplinter_tpu.ops.ssd_scan import ssd_chunk_prefill
+    f32 = jnp.float32
+    compiled = jax.jit(
+        lambda x, dt, a, b, c, s, n: ssd_chunk_prefill(
+            x, dt, a, b, c, s, n_snap=n, dot_dtype=jnp.bfloat16,
+            force_pallas=True)).lower(
+        _spec(one_chip, (tokens, 64, 64), f32),
+        _spec(one_chip, (tokens, 64), f32), _spec(one_chip, (64,), f32),
+        _spec(one_chip, (tokens, 8, 128), f32),
+        _spec(one_chip, (tokens, 8, 128), f32),
+        _spec(one_chip, (64, 64, 128), f32),
+        _spec(one_chip, (), jnp.int32)).compile()
+    assert "ssd_chunk_prefill" in compiled.as_text()
+
+
+def _ssm_case(one_chip, program):
+    """(program, its arguments as shapes on the described chip, bytes
+    of weights) of the benchmark's Nemotron configuration: "chunk" or
+    "suffix-<width>" (one row)."""
+    from libsplinter_tpu.models import nemotron_h as nh
+    cfg = nh.SsmMoeConfig(
+        vocab_size=16384, hidden=2688,
+        kinds=tuple(nh.PATTERN[c] for c in "MEMEM*EMEMEM*EMEMEM*EMEMEM*"),
+        heads=32, kv_heads=2, head_dim=128, ssm_heads=64, ssm_head_dim=64,
+        ssm_groups=8, ssm_state=128, conv_kernel=4, moe_mlp_dim=1856,
+        shared_mlp_dim=3712, n_routed_experts=128, top_k=6,
+        experts_first=0, experts_held=16, routed_scaling_factor=2.5,
+        max_len=2048, model_layers=52)
+    params = jax.tree_util.tree_map(
+        lambda a: _spec(one_chip, a.shape, a.dtype),
+        jax.eval_shape(lambda: nh.init_params(cfg, 0)))
+    weights = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                  for a in jax.tree_util.tree_leaves(params))
+    m = nh.SsmCompletionModel(cfg, params=params)
+    pools = (_spec(one_chip, SSM_POOL, jnp.bfloat16),) * 2
+    states = [[_spec(one_chip, SSM_STATE, jnp.float32),
+               _spec(one_chip, (SSM_SLOTS, 3, 6144), jnp.bfloat16)]
+              for _ in range(12)]
+    i32 = _spec(one_chip, (), jnp.int32)
+
+    def vec(n, dtype=jnp.int32):
+        return _spec(one_chip, (n,), dtype)
+    kind, _, n = program.partition("-")
+    if kind == "chunk":
+        fn = m._chunk_program(8, SSM_ROWS)
+        args = (_spec(one_chip, (SSM_ROWS, SSM_P), jnp.int32),
+                vec(SSM_ROWS), vec(2, jnp.uint32), vec(SSM_ROWS),
+                vec(SSM_ROWS, jnp.bool_), vec(SSM_ROWS), vec(3))
+    else:
+        fn = m._suffix_program(int(n))
+        args = (_spec(one_chip, (1, SSM_P), jnp.int32), vec(1),
+                _spec(one_chip, (1, int(n)), jnp.int32), i32, i32, i32, i32)
+    return getattr(fn, "__wrapped__", fn), (params, pools, states,
+                                            *args), weights
+
+
+@pytest.mark.parametrize("program", ["chunk", "suffix-128", "suffix-1024"])
+def test_ssm_programs_at_published_widths(one_chip, monkeypatch, program):
+    """The 8-step decode chunk of 128 rows, the one-page suffix prefill
+    and the widest (a cold 1,024-token prompt in one call) of the
+    benchmark's Nemotron configuration (27 layers unrolled; 5.25 GB of
+    weights, 4.06 GB of state slots, 1.34 GB of pages): each compiles,
+    fits the chip beside its arguments, and keeps the page group's
+    pools and the state slots in place."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    fn, args, weights = _ssm_case(one_chip, program)
+    assert 5.24e9 < weights < 5.27e9      # 2,626M parameters
+    compiled = fn.lower(*args).compile()
+    mem = compiled.memory_analysis()
+    print(program, "arguments", mem.argument_size_in_bytes, "temporaries",
+          mem.temp_size_in_bytes)
+    assert mem.argument_size_in_bytes > 10.6e9   # weights, pages, slots
+    assert mem.temp_size_in_bytes < 1.5e9        # no pool or slot copies
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14.5e9
+    assert _no_pool_copied(compiled, SSM_POOL)
+    assert _no_pool_copied(compiled, SSM_STATE)
+
+
 SIBLING_PROGRAMS = {
     ("hybrid", "chunk"): "87a092d2efbb4081",
     ("hybrid", "suffix-640"): "defcd610c0408b44",

@@ -151,7 +151,8 @@ def test_single_guarded_cache_call_site():
         "benchmark/reference/hybrid_kda_block.py",
         "benchmark/reference/latent_moe_block.py",
         "benchmark/reference/sparse_gqa_moe_block.py",
-        "benchmark/reference/window_gqa_moe_block.py",
+        "benchmark/reference/ssm_gqa_moe_block.py",
+            "benchmark/reference/window_gqa_moe_block.py",
         "benchmark/reference/window_sink_gqa_moe_block.py",
         "libsplinter_tpu/utils/jaxplatform.py"], hits
 

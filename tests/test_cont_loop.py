@@ -23,8 +23,9 @@ from libsplinter_tpu.utils import trace as tmod
 
 # the CONT_INFER_STAGES that are disjoint in time on the loop's thread
 # (flush is a sum inside emit, window_release inside join and decode)
-STAGE_LEAVES = ("prefix_hit", "state_restore", "state_snapshot", "join",
-                "sample", "decode", "collect", "handoff", "adopt")
+STAGE_LEAVES = ("prefix_hit", "state_restore", "state_zero",
+                "state_snapshot", "join", "sample", "decode", "collect",
+                "handoff", "adopt")
 ENCLOSING = ("loop", "admit", "chunk")
 LEAVES = tuple(p for p in P.CONT_LOOP_PHASES if p not in ENCLOSING) \
     + STAGE_LEAVES

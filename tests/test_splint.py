@@ -102,7 +102,7 @@ def test_registry_extracts_live_protocol(splint):
     assert reg.stages["CONT_INFER_STAGES"] == (
         "join", "sample", "decode", "collect", "flush", "prefix_hit",
         "handoff", "adopt", "state_restore", "state_snapshot",
-        "window_release")
+        "state_zero", "window_release")
     assert reg.keys["KEY_SEARCH_STATS"] == "__searcher_stats"
     assert reg.prefixes["SEARCH_RESULT_PREFIX"] == "__sr_"
     assert reg.prefixes["DEADLINE_STAMP_PREFIX"] == "__dl_"
@@ -141,7 +141,8 @@ def test_live_tree_is_clean(runner):
                        "libsplinter_tpu/ops/latent_attention.py",
                        "libsplinter_tpu/ops/paged_attention.py",
                        "libsplinter_tpu/ops/similarity.py",
-                       "libsplinter_tpu/ops/sparse_attention.py"}
+                       "libsplinter_tpu/ops/sparse_attention.py",
+                       "libsplinter_tpu/ops/ssd_scan.py"}
 
 
 def test_baseline_has_no_engine_entries(core):

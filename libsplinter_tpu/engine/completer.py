@@ -2322,6 +2322,13 @@ class Completer:
             if self._paged_cache.used_pages > self._pages_used_peak:
                 self._pages_used_peak = self._paged_cache.used_pages
             payload["pages_used_peak"] = self._pages_used_peak
+            if hasattr(getattr(m_now, "cfg", None), "kv_lora_rank"):
+                # a family with latent pages: table pages a grid step
+                # of its decode kernel attends — turns the kernel's
+                # seconds in a reduced trace into seconds a grid step
+                from ..ops.latent_attention import pages_per_step
+                payload["latent_decode_pages_per_step"] = \
+                    pages_per_step(1, self._paged_cache.page)
         if getattr(self._paged_cache, "needs_state", False):
             # state slots (live rows + snapshots) of a model with
             # recurrent state, beside the page gauges above

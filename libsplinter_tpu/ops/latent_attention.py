@@ -36,10 +36,29 @@ wholly past a row's length skipped.  q may carry S stacked tokens a
 row (the suffix prefill of a prefix-cache hit, whose S tokens were
 appended before the call): token t attends j < lengths[b] + t.
 
-Grid (B, H // G, P): G heads x S tokens share one program's
+Grid (B, H // G, ceil(P / N)): G heads x S tokens share one program's
 (S*G, width) query block, token-major (row // G == token), with G
 chosen so that the block stays near 1,024 rows — the whole 128 heads
-for a decode step (S == 1), 16 heads for a 64-token suffix stack.
+for a decode step (S == 1), 16 heads for a 64-token suffix stack —,
+and a program attends a CHUNK of N table pages: the pool is handed to
+the kernel N times, copy i routed to table entry chunk * N + i.  N is
+`pages_per_step` of the call's shape and nothing else.  A decode step
+takes DECODE_PAGES = 8 (fewer of pages wider than 128 columns: a
+chunk spans 1,024 at most): its query block is a row's heads (128 or 32
+rows), so a 147 KB page's two products and its bytes are each ~0.2 us
+of the chip, and at one page a step the grid step, the mask and the
+rescale of the (R, kv_rank) float32 accumulator cost more than the
+page did (0.77 us a live page; 0.34 at 8 — PERF.md section 6, PR 49).
+The chunk's pages share ONE online-softmax update (one running max,
+one `corr`, one rescale; their scores and weighted sums are reduced
+elementwise first), unmasked where the row covers the whole chunk; the
+chunk that holds the row's end takes its live pages one at a time
+under the mask, as every page was taken before, and its pages past the
+end do no products.  A stack of tokens keeps N = 1: ~1,024 query rows
+make a page's products 2.3 us of a 4.3 us step and its float32 score
+tile 512 KB a page.  A table whose width is no multiple of N (66 at
+8) is padded with the trash block, whose columns lie past every
+length.
 Where the rows of one call bring suffixes of their own lengths in one
 width (an admission round's hits, models/mla.py), `q_valid` says how
 many of a row's S tokens are real and a program skips its query rows'
@@ -89,72 +108,130 @@ def q_blocks(q_tokens: int, group: int) -> int:
     return math.gcd(q_tokens, max(1, q_tokens * group // Q_BLOCK_ROWS))
 
 
-def _latent_kernel(tab_ref, len_ref, *refs, page: int, scale: float,
-                   group: int, kv_rank: int, blocks: int):
-    """One (batch row, head group, page) program.
+# table pages one grid step of the DECODE face attends (one token a
+# row).  On the chip, a call of 64 rows at pangu's shape (128 heads,
+# 65 live pages a row) / kimi's (32 heads, 70-100 live pages): 3.29 /
+# 3.20 ms at 1, 2.07 / 2.14 at 2, 1.59 / 1.61 at 4, 1.40 / 1.43 at 8,
+# 1.38 / 1.54 at 16 (PERF.md section 6, PR 49) — at pages of 128
+# columns: a chunk spans DECODE_PAGES x 128 columns at most, so that
+# wider pages keep its blocks and score tile in VMEM (8 pages of 1,024
+# columns do not fit: compiled for a described v5e)
+DECODE_PAGES = 8
+DECODE_COLUMNS = DECODE_PAGES * 128
+
+
+def pages_per_step(q_tokens: int, page: int) -> int:
+    """Table pages a grid step attends, by the call's shape alone:
+    for a decode step (one token a row) DECODE_PAGES, fewer where
+    they would span more than DECODE_COLUMNS columns; one for a stack
+    of tokens (the module's docstring says why)."""
+    if q_tokens > 1:
+        return 1
+    return max(1, min(DECODE_PAGES, DECODE_COLUMNS // page))
+
+
+def _latent_kernel(tab_ref, len_ref, *refs, page: int, pages: int,
+                   scale: float, group: int, kv_rank: int, blocks: int):
+    """One (batch row, head group, chunk of `pages` table pages)
+    program.
 
     tab_ref: (B, P) SMEM block table;  len_ref: (B,) SMEM lengths;
     with blocks > 1 a third prefetched operand, (B,) SMEM: how many of
     the row's stacked tokens are real
     q_ref:   (1, 1, R, W) this row's folded queries, R = S*group,
-             token-major;  kv_ref: (1, W, page) the page the table
-             routed here (a token a column), W = kv_rank + rope_dim
+             token-major;  kv_refs: `pages` blocks (1, W, page), the
+             pages the table routed here (a token a column), W =
+             kv_rank + rope_dim
     out_ref: (1, 1, R, kv_rank)
     m_s/l_s: (R, 1) f32 running max / sum;  acc_s: (R, kv_rank) f32
+
+    A chunk every query sees whole takes ONE online-softmax update
+    over its pages, unmasked; the chunk that holds a row's end takes
+    its live pages one at a time under the mask; a chunk past the end
+    is skipped.
     """
     nv_ref = refs[0] if blocks > 1 else None
-    q_ref, kv_ref, out_ref, m_s, l_s, acc_s = refs[-6:]
+    q_ref = refs[-(pages + 5)]
+    kv_refs = refs[-(pages + 4):-4]
+    out_ref, m_s, l_s, acc_s = refs[-4:]
     b = pl.program_id(0)
-    p = pl.program_id(2)
-    n_pages = pl.num_programs(2)
+    c = pl.program_id(2)
     length = len_ref[b]
     R = q_ref.shape[2]
     q_tokens = R // group
     # the row's real tokens: the last of them attends furthest
     n_real = q_tokens if nv_ref is None else nv_ref[b]
+    first = c * (pages * page)          # the chunk's first column
+    end = length + (n_real - 1)         # columns the last token sees
 
-    @pl.when(p == 0)
+    @pl.when(c == 0)
     def _init():
         m_s[...] = jnp.full_like(m_s, NEG_INF)
         l_s[...] = jnp.zeros_like(l_s)
         acc_s[...] = jnp.zeros_like(acc_s)
 
-    def accumulate(rows, t0: int):
-        """The page into the running softmax of query rows `rows`,
-        whose first token is the row's t0-th."""
+    def accumulate(rows, t0: int, cols, masked: bool):
+        """The chunk's pages `cols` into the running softmax of query
+        rows `rows`, whose first token is the row's t0-th: one max,
+        one rescale of the accumulator, whatever the pages."""
         n = rows.stop - rows.start
         q = q_ref[0, 0, rows]                           # (n, W)
-        kv = kv_ref[0]                                  # (W, page)
-        logits = jnp.dot(q, kv, preferred_element_type=jnp.float32) \
-            * scale                                     # (n, page)
-        j = jax.lax.broadcasted_iota(jnp.int32, (n, page), 1)
-        t = t0 + jax.lax.broadcasted_iota(jnp.int32, (n, page), 0) \
-            // group
-        valid = (p * page + j) < (length + t)
-        logits = jnp.where(valid, logits, NEG_INF)
+        kvs = [kv_refs[i][0] for i in cols]             # (W, page) each
+        logits = [jnp.dot(q, kv, preferred_element_type=jnp.float32)
+                  * scale for kv in kvs]                # (n, page) each
+        if masked:
+            j = jax.lax.broadcasted_iota(jnp.int32, (n, page), 1)
+            t = t0 + jax.lax.broadcasted_iota(jnp.int32, (n, page), 0) \
+                // group
+            valid = [(first + i * page + j) < (length + t) for i in cols]
+            logits = [jnp.where(v, x, NEG_INF)
+                      for v, x in zip(valid, logits)]
         m_prev, l_prev = m_s[rows], l_s[rows]
-        m_new = jnp.maximum(m_prev, jnp.max(logits, -1, keepdims=True))
+        m_new = jnp.maximum(m_prev, jnp.max(
+            functools.reduce(jnp.maximum, logits), -1, keepdims=True))
         corr = jnp.exp(m_prev - m_new)
-        pexp = jnp.where(valid, jnp.exp(logits - m_new), 0.0)
+        pexp = [jnp.exp(x - m_new) for x in logits]
+        if masked:
+            pexp = [jnp.where(v, x, 0.0) for v, x in zip(valid, pexp)]
         m_s[rows] = m_new
-        l_s[rows] = l_prev * corr + jnp.sum(pexp, -1, keepdims=True)
-        acc_s[rows] = acc_s[rows] * corr + jax.lax.dot_general(
-            pexp.astype(kv.dtype), kv[:kv_rank],
-            (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        l_s[rows] = l_prev * corr + jnp.sum(
+            functools.reduce(jnp.add, pexp), -1, keepdims=True)
+        acc = acc_s[rows] * corr
+        for x, kv in zip(pexp, kvs):
+            acc = acc + jax.lax.dot_general(
+                x.astype(kv.dtype), kv[:kv_rank],
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+        acc_s[rows] = acc
 
-    @pl.when(p * page < length + (n_real - 1))
-    def _accumulate():
+    def attend(cols, masked: bool):
         if blocks == 1:
-            accumulate(slice(0, R), 0)
+            accumulate(slice(0, R), 0, cols, masked)
         else:
             tb = q_tokens // blocks
             for i in range(blocks):
                 pl.when(i * tb < n_real)(functools.partial(
                     accumulate, slice(i * tb * group,
-                                      (i + 1) * tb * group), i * tb))
+                                      (i + 1) * tb * group), i * tb,
+                    cols, masked))
 
-    @pl.when(p == n_pages - 1)
+    if pages == 1:
+        pl.when(first < end)(functools.partial(attend, (0,), True))
+    else:
+        # every query of the block sees every column of the chunk
+        whole = first + pages * page <= length
+
+        @pl.when(whole)
+        def _inside():
+            attend(range(pages), False)
+
+        @pl.when(jnp.logical_and(jnp.logical_not(whole), first < end))
+        def _boundary():
+            for i in range(pages):
+                pl.when(first + i * page < end)(functools.partial(
+                    attend, (i,), True))
+
+    @pl.when(c == pl.num_programs(2) - 1)
     def _write():
         l = l_s[...]
         out = jnp.where(l > 0.0, acc_s[...] / jnp.maximum(l, 1e-30),
@@ -177,20 +254,32 @@ def _latent_pallas(q4, pool, tables, lengths, q_valid=None, *,
     blocks = 1 if q_valid is None else q_blocks(R // group, group)
     prefetch = (tables, lengths) if blocks == 1 \
         else (tables, lengths, q_valid)
+    pages = pages_per_step(R // group, page)
+    chunks = -(-tables.shape[1] // pages)
+    if chunks * pages != tables.shape[1]:
+        # a table that is no whole number of chunks ends in the trash
+        # block, whose columns lie past every length
+        prefetch = (jnp.pad(tables, ((0, 0), (
+            0, chunks * pages - tables.shape[1]))),) + prefetch[1:]
 
-    def _q_map(b, g, p, *pre):
+    def _q_map(b, g, c, *pre):
         return (b, g, 0, 0)
 
-    def _kv_map(b, g, p, *pre):
-        return (pre[0][b, p], 0, 0)
+    def _kv_map(i):
+        def at(b, g, c, *pre):
+            return (pre[0][b, c * pages + i], 0, 0)
+        return at
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
-        grid=(B, NG, tables.shape[1]),
+        grid=(B, NG, chunks),
         in_specs=[
             pl.BlockSpec((1, 1, R, W), _q_map, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, W, page), _kv_map,
-                         memory_space=pltpu.VMEM),
+            # the pool once a page of the chunk, each copy routed to
+            # its own entry of the table
+            *(pl.BlockSpec((1, W, page), _kv_map(i),
+                           memory_space=pltpu.VMEM)
+              for i in range(pages)),
         ],
         out_specs=pl.BlockSpec((1, 1, R, kv_rank), _q_map,
                                memory_space=pltpu.VMEM),
@@ -201,8 +290,9 @@ def _latent_pallas(q4, pool, tables, lengths, q_valid=None, *,
         ],
     )
     return pl.pallas_call(
-        functools.partial(_latent_kernel, page=page, scale=scale,
-                          group=group, kv_rank=kv_rank, blocks=blocks),
+        functools.partial(_latent_kernel, page=page, pages=pages,
+                          scale=scale, group=group, kv_rank=kv_rank,
+                          blocks=blocks),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, NG, R, kv_rank), q4.dtype),
         interpret=interpret,
@@ -210,7 +300,7 @@ def _latent_pallas(q4, pool, tables, lengths, q_valid=None, *,
         # apart by name in a device trace (benchmark/readers)
         name=("latent_decode_attention" if R == group
               else "latent_stack_attention"),
-    )(*prefetch, q4, pool)
+    )(*prefetch, q4, *([pool] * pages))
 
 
 def _latent_ref(q, pool, tables, lengths, *, kv_rank: int, scale: float):

@@ -594,6 +594,7 @@ section) and `spt metrics` renders everything flat as
 |---|---|---|
 | `inflight_depth` | all | configured K: un-awaited device dispatches the lane may hold (`--inflight-depth`) |
 | `inflight_peak` | all | max un-awaited depth observed; pinned at `inflight_depth` = the overlap window saturates |
+| `latent_decode_pages_per_step` | completer, a family with latent (MLA) pages | table pages a grid step of `latent_decode_attention` attends (`ops/latent_attention.pages_per_step`): the kernel's seconds in a trace over rows x ceil(table pages / this) are seconds a grid step |
 | `ring_depth` | embedder | configured resident-ring depth (`--ring-depth`; ≤1 = per-call dispatch) |
 | `ring_occupancy` / `ring_occupancy_peak` | embedder | occupied slots of the last / fullest resident ring dispatch |
 | `ring_dispatches` / `resident_iterations` | embedder | resident programs dispatched / batches serviced inside them — `resident_iterations ÷ ring_dispatches` is the live dispatch-floor amortization factor |

@@ -470,6 +470,8 @@ def test_a_session_resumes_on_its_tail_through_run_continuous(tmp_path,
         assert hb["window_pages_live"] == 0 == hb["global_pages_live"]
         assert hb["window_pages_used"] == pc.window_pages() > 0
         assert "state_restores" not in hb
+        # no latent pages: the latent decode kernel's gauge is absent
+        assert "latent_decode_pages_per_step" not in hb
         assert {"paged_chunk", "suffix_prefill"} <= set(hb["devtime"])
         # every page in either group: free, or the tree's at zero refs
         cache = comp._paged_cache

@@ -231,19 +231,24 @@ def test_paged_attention_tp4(topo, kind):
 LAT_W, LAT_RANK, LAT_HEADS, LAT_POOL = 576, 512, 128, (2561, 576, 128)
 
 
-@pytest.mark.parametrize("q_tokens, rows",
-                         [(1, 64), (16, 1), (64, 1), (64, 8), (64, 64)],
+@pytest.mark.parametrize("q_tokens, rows, heads, table",
+                         [(1, 64, 128, 66), (16, 1, 128, 66),
+                          (64, 1, 128, 66), (64, 8, 128, 66),
+                          (64, 64, 128, 66), (1, 64, 32, 128)],
                          ids=["decode-64-rows", "suffix-16", "suffix-64",
                               "suffix-64-of-8-rows",
-                              "suffix-64-of-64-rows"])
-def test_latent_attention_kernel(one_chip, q_tokens, rows):
-    """The latent decode kernel (S == 1, all 128 heads a program) and
-    the suffix stacks (S x 64 / 16 heads), over transposed pages: the
-    576-wide contraction and the 512-wide output are no multiples of
-    what interpret mode checks."""
+                              "suffix-64-of-64-rows",
+                              "decode-64-rows-32-heads-128-pages"])
+def test_latent_attention_kernel(one_chip, q_tokens, rows, heads, table):
+    """The latent decode kernel (S == 1, all 128 heads a program,
+    DECODE_PAGES table pages a grid step: pangu's 66-page table, which
+    is no whole number of chunks, and kimi's 32 heads over 128 pages)
+    and the suffix stacks (S x 64 / 16 heads, one page a step), over
+    transposed pages: the 576-wide contraction and the 512-wide output
+    are no multiples of what interpret mode checks."""
     from libsplinter_tpu.ops.latent_attention import (_latent_pallas,
                                                       head_group)
-    g = head_group(LAT_HEADS, q_tokens)
+    g = head_group(heads, q_tokens)
     # the rows of a round bring their own lengths (q_valid): the kernel
     # that skips the pad tokens' blocks
     ragged = rows > 1 and q_tokens > 1
@@ -251,10 +256,10 @@ def test_latent_attention_kernel(one_chip, q_tokens, rows):
         lambda q4, pool, t, l, *nv: _latent_pallas(
             q4, pool, t, l, *nv, kv_rank=LAT_RANK, scale=192 ** -0.5,
             group=g, interpret=False),
-        _spec(one_chip, (rows, LAT_HEADS // g, q_tokens * g, LAT_W),
+        _spec(one_chip, (rows, heads // g, q_tokens * g, LAT_W),
               jnp.bfloat16),
         _spec(one_chip, LAT_POOL, jnp.bfloat16),
-        _spec(one_chip, (rows, 66), jnp.int32),
+        _spec(one_chip, (rows, table), jnp.int32),
         *[_spec(one_chip, (rows,), jnp.int32)] * (2 if ragged else 1))
     txt = compiled.as_text()
     assert "tpu_custom_call" in txt
@@ -1152,7 +1157,11 @@ def test_ssm_programs_at_published_widths(one_chip, monkeypatch, program):
 
 
 SIBLING_PROGRAMS = {
-    ("hybrid", "chunk"): "87a092d2efbb4081",
+    # PR 49: the latent decode kernel takes its pool once a page of a
+    # grid step's chunk (ops/latent_attention.DECODE_PAGES operands
+    # where one was; kimi's 128-page table needs no padding); the
+    # suffix programs run the stack face, whose call did not change
+    ("hybrid", "chunk"): "f393daedf2180a38",
     ("hybrid", "suffix-640"): "defcd610c0408b44",
     ("window", "chunk"): "ffe29bfc3cbbf3a7",
     ("window", "suffix-640"): "5b6661e293abaabe",
@@ -1164,7 +1173,9 @@ SIBLING_PROGRAMS = {
     # the latent family's round (its expert layer runs in chunks of
     # 2,048 token slots: moe.sparse_moe without live_chunk) and chunk
     ("latent", "rows-64"): "30435683c3a2a679",
-    ("latent", "chunk"): "478b7f8b2a180501",
+    # (PR 49: DECODE_PAGES pool operands and a 66-page table padded
+    # to 72 in the chunk program; the round's program is the parent's)
+    ("latent", "chunk"): "c4221038bb65705e",
     # this family's own chunk and one-row suffix programs, as PR 40
     # left them: the row axis (PR 41) is a program beside them
     ("conv", "chunk"): "c3ecbe24226ac7b8",

@@ -548,6 +548,7 @@ def test_a_session_resumes_from_its_snapshots_through_run_continuous(
         assert hb["state_restores"] == 2 and hb["state_snapshots"] == 3
         assert hb["state_evictions"] == 1 and hb["state_cut_tokens"] == 0
         assert hb["state_slots_used"] == 2 and hb["state_slots"] == 4
+        assert hb["latent_decode_pages_per_step"] == 8   # latent layers
         assert {"paged_chunk", "suffix_prefill", "state_copy",
                 "state_zero"} <= set(hb["devtime"])
 

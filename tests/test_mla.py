@@ -294,6 +294,90 @@ def test_latent_kernel_and_append_match_the_gathered_reference():
     np.testing.assert_array_equal(np.asarray(got)[4, :, 4], lat[1])
 
 
+# lengths of the rows of one decode call, by what they try (pages of 16
+# columns, DECODE_PAGES = 8: a chunk is 128 columns): a row's last
+# token in the first / the last column of a page, in the last page of a
+# chunk and the first page of the next, a row of one token, a dead row
+DECODE_ROWS = {
+    "page-edges": [17, 32, 1, 0, 16, 33, 209, 224],
+    "chunk-edges": [113, 128, 129, 144, 112, 127, 256, 257],
+    "ragged": [1, 1000, 0, 5, 640, 77, 1024, 300],
+}
+
+
+def _own_pages(n_pages, width: int) -> np.ndarray:
+    """A block table (rows, width) in which row b maps n_pages[b]
+    pages of its own, from block 1 on; the rest is the trash block."""
+    tables = np.zeros((len(n_pages), width), np.int32)
+    at = 1
+    for b, n in enumerate(n_pages):
+        tables[b, :n] = np.arange(at, at + n)
+        at += n
+    return tables
+
+
+@pytest.mark.parametrize("rows", sorted(DECODE_ROWS))
+@pytest.mark.parametrize("heads, width", [(128, 66), (32, 128)])
+def test_latent_decode_kernel_attends_chunks_of_pages(heads, width, rows):
+    """The decode face attends DECODE_PAGES table pages a grid step
+    under one online-softmax update: every live key of every row, a
+    table that is no whole number of chunks (66) padded with the trash
+    block, zeros for a dead row — at pangu's 128 heads and kimi's 32."""
+    from libsplinter_tpu.ops.latent_attention import (DECODE_PAGES,
+                                                      pages_per_step)
+    assert pages_per_step(1, 16) == pages_per_step(1, 128) \
+        == DECODE_PAGES == 8
+    # wider pages: a chunk spans 1,024 columns at most
+    assert [pages_per_step(1, p) for p in (256, 512, 1024, 2048)] \
+        == [4, 2, 1, 1]
+    rng = np.random.default_rng(heads + width)
+    W, rank, page = 48, 32, 16
+    lengths = np.asarray(DECODE_ROWS[rows], np.int32)
+    B = len(lengths)
+    nb = 1 + int(-(-lengths // page).sum())
+    pool = jnp.asarray(rng.normal(size=(nb, W, page)), jnp.float32)
+    tables = _own_pages(-(-lengths // page), width)
+    q = jnp.asarray(rng.normal(size=(B, heads, W)), jnp.float32)
+    want = np.asarray(latent_paged_attention(
+        q, pool, tables, lengths, kv_rank=rank, scale=0.2))
+    got = np.asarray(latent_paged_attention(
+        q, pool, tables, lengths, kv_rank=rank, scale=0.2,
+        interpret=True))
+    live = lengths > 0
+    np.testing.assert_allclose(got[live], want[live], atol=3e-6)
+    # a dead row reads zeros (the reference's softmax over no key is
+    # uniform over the table)
+    assert not got[~live].any()
+    assert np.abs(got[live]).max(axis=(1, 2)).min() > 1e-3
+
+
+@pytest.mark.parametrize("ragged", [False, True],
+                         ids=["whole", "q_valid"])
+def test_latent_stack_kernel_keeps_one_page_a_step(ragged):
+    """A stack of tokens attends ONE page a grid step, as it did: over
+    a table of 66 pages, with and without q_valid, what the gathered
+    reference gives."""
+    from libsplinter_tpu.ops.latent_attention import pages_per_step
+    assert pages_per_step(4, 16) == pages_per_step(64, 128) == 1
+    rng = np.random.default_rng(11)
+    B, S, H, W, rank, page, P = 3, 32, 32, 48, 32, 16, 66
+    lengths = np.asarray([1, 500, 1024], np.int32)
+    nb = 1 + int(-(-(lengths + S) // page).sum())
+    pool = jnp.asarray(rng.normal(size=(nb, W, page)), jnp.float32)
+    tables = _own_pages(-(-(lengths + S - 1) // page), P)
+    valid = np.asarray([32, 9, 17], np.int32)
+    kw = dict(kv_rank=rank, scale=0.2)
+    if ragged:
+        kw["q_valid"] = valid
+    q = jnp.asarray(rng.normal(size=(B, S, H, W)), jnp.float32)
+    want = np.asarray(latent_paged_attention(q, pool, tables, lengths,
+                                             kv_rank=rank, scale=0.2))
+    got = np.asarray(latent_paged_attention(q, pool, tables, lengths,
+                                            interpret=True, **kw))
+    for b, n in enumerate(valid if ragged else [S] * B):
+        np.testing.assert_allclose(got[b, :n], want[b, :n], atol=3e-6)
+
+
 def test_latent_kernel_skips_the_blocks_of_pad_tokens():
     """Rows that bring suffixes of their own lengths in one width say
     how many of their stacked tokens are real (q_valid): the kernel
@@ -643,6 +727,9 @@ def test_main_serves_submit_completion_and_its_audit_matches(
         assert hb["prompt_tokens"] == 2 * 49 + 5 + 19
         assert hb["prefix_tokens"] == 48 and hb["prefix_hits"] == 1
         assert hb["kv_dtype"] == "bf16"
+        # the latent decode kernel's pages a grid step, beside the
+        # overlap gauge (ops/latent_attention.pages_per_step)
+        assert hb["latent_decode_pages_per_step"] == 8
         assert hb["audit_records"] == 2
         assert len(hb["expert_totals"]) == 8
         assert sum(hb["expert_totals"]) == hb["expert_slots"] > 0
